@@ -62,16 +62,20 @@ class KVCache(NamedTuple):
 
 def init_kv_cache(
     cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
-    kv_quant: str = "none",
+    kv_quant: str = "none", sharding=None,
 ) -> KVCache:
+    """``sharding`` (a jax Sharding over the kv-head axis,
+    ModelSharding.cache_sharding) makes the pool born sharded: a pool
+    sized for a mesh never has to fit one device first."""
     shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
+    zeros = functools.partial(jnp.zeros, device=sharding)
     if kv_quant == "int8":
         sshape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads)
         return KVCache(
-            jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-            jnp.zeros(sshape, jnp.float32), jnp.zeros(sshape, jnp.float32),
+            zeros(shape, jnp.int8), zeros(shape, jnp.int8),
+            zeros(sshape, jnp.float32), zeros(sshape, jnp.float32),
         )
-    return KVCache(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    return KVCache(zeros(shape, dtype), zeros(shape, dtype))
 
 
 def kv_quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -615,9 +619,9 @@ def multi_decode_impl(
     device, so the host fetches once per num_steps×B tokens instead of
     per token — and with the engine's window pipeline, consecutive
     windows chain through ``last_toks`` so the device never waits for a
-    host fetch either. THE latency lever when the host↔device link is slow (remote
-    TPU tunnels ~100ms/roundtrip) and a dispatch saver everywhere; the
-    same trick as vLLM's multi-step scheduling, expressed as lax.scan.
+    host fetch either. It saves a host sync (a device round trip) and a
+    dispatch per token; the same trick as vLLM's multi-step scheduling,
+    expressed as lax.scan.
 
     Sampler modes (static → three compiled variants per shape):
     - "greedy": every row argmax; no RNG at all.
@@ -784,6 +788,7 @@ def spec_verify_impl(
         paged_spec_attention,
         paged_spec_attention_xla,
         resolve_attn_impl,
+        spec_kernel_fits,
     )
 
     B, T = tokens.shape
@@ -822,11 +827,14 @@ def spec_verify_impl(
         # Fused spec-verify gather (ops.paged_spec_attention): one Pallas
         # kernel walks each row's true pages for all T queries and
         # dequantizes in-register — no materialized relayout copy of the
-        # gathered table (the ~9ms/layer XLA tax). Falls back to the XLA
-        # gather when the query columns exceed the 128-lane budget or the
-        # backend is not TPU-like.
+        # gathered table (the ~9ms/layer XLA tax). Takes the XLA gather
+        # when the query columns exceed the 128-lane budget (the runner's
+        # start line names the T values that will) or off the TPU.
         impl = resolve_attn_impl(attn_impl)
-        use_kernel = impl in ("pallas", "pallas_interpret") and KVH * T * G <= 128
+        use_kernel = (
+            impl in ("pallas", "pallas_interpret")
+            and spec_kernel_fits(cfg.num_heads, T)
+        )
 
         def layer(carry, xs):
             x, k_cache, v_cache, k_scale, v_scale = carry

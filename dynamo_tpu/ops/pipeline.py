@@ -68,8 +68,8 @@ def pipeline_apply_local(
         recv_next = lax.ppermute(out, axis_name, perm)
         return (recv_next, outputs)
 
-    recv0 = lax.pvary(jnp.zeros_like(x_mb[0]), (axis_name,))
-    out0 = lax.pvary(jnp.zeros_like(x_mb), (axis_name,))
+    recv0 = lax.pcast(jnp.zeros_like(x_mb[0]), (axis_name,), to="varying")
+    out0 = lax.pcast(jnp.zeros_like(x_mb), (axis_name,), to="varying")
     _, outputs = lax.fori_loop(0, M + n - 1, step, (recv0, out0))
     # Only the last stage holds real outputs; zeros elsewhere → psum
     # broadcasts them to the whole group.
@@ -86,8 +86,6 @@ def pipeline_apply(
 ) -> jax.Array:
     """GPipe-microbatched forward of a stacked-layer network with the
     layer axis sharded over ``axis_name``. Returns [B, D]."""
-    from jax.experimental.shard_map import shard_map
-
     B, D = x.shape
     M = num_microbatches
     if B % M:
@@ -95,7 +93,7 @@ def pipeline_apply(
     x_mb = x.reshape(M, B // M, D)
 
     layer_spec = jax.tree.map(lambda _: P(axis_name), params_stacked)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(pipeline_apply_local, layer_fn=layer_fn, axis_name=axis_name),
         mesh=mesh,
         in_specs=(P(), layer_spec),

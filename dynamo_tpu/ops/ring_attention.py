@@ -80,11 +80,14 @@ def ring_attention_local(
         v_nxt = lax.ppermute(v_cur, axis_name, perm)
         return (k_nxt, v_nxt, m_new, l_new, acc_new)
 
-    # pvary: constants start replicated under shard_map; the carry becomes
+    # Constants start replicated under shard_map; the carry becomes
     # device-varying after step 1, so the loop types must match up front.
-    m0 = lax.pvary(jnp.full((Tc, KVH, G), NEG_INF, jnp.float32), (axis_name,))
-    l0 = lax.pvary(jnp.zeros((Tc, KVH, G), jnp.float32), (axis_name,))
-    acc0 = lax.pvary(jnp.zeros((Tc, KVH, G, hd), jnp.float32), (axis_name,))
+    def varying(x):
+        return lax.pcast(x, (axis_name,), to="varying")
+
+    m0 = varying(jnp.full((Tc, KVH, G), NEG_INF, jnp.float32))
+    l0 = varying(jnp.zeros((Tc, KVH, G), jnp.float32))
+    acc0 = varying(jnp.zeros((Tc, KVH, G, hd), jnp.float32))
     _, _, _, l, acc = lax.fori_loop(0, n, step, (k, v, m0, l0, acc0))
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return out.reshape(Tc, H, hd).astype(q.dtype)
@@ -101,10 +104,8 @@ def ring_prefill(
 ) -> jax.Array:
     """Causal attention for a long sequence sharded over ``axis_name``.
     T must divide evenly by the axis size."""
-    from jax.experimental.shard_map import shard_map
-
     spec = P(axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention_local, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

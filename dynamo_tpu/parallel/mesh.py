@@ -81,7 +81,7 @@ def build_mesh(tp: int = 1, dp: int = 1, ep: int = 1, devices=None,
 
 class ModelSharding:
     """Sharding rules for one model on one mesh. Passed to TpuEngine;
-    ``shard_params``/``shard_cache`` place arrays, ``batch_spec`` shards
+    ``shard_params``/``cache_sharding`` place arrays, ``batch_spec`` shards
     engine step inputs over dp."""
 
     def __init__(self, mesh: Mesh, cfg: ModelConfig):
@@ -177,6 +177,21 @@ class ModelSharding:
     def batch_spec(self) -> P:
         return P(DP_AXIS)
 
+    def born_sharded(self, build) -> Any:
+        """Run a zero-argument params builder under ``jit`` with this
+        mesh's ``out_shardings``: each device materializes only its own
+        shards, so a model sized for the mesh never has to fit device 0
+        (or the host) first. Every process of a multi-host mesh runs the
+        same program and gets its addressable shards."""
+        shardings = self.param_shardings(jax.eval_shape(build))
+        return jax.jit(build, out_shardings=shardings)()
+
+    def cache_sharding(self) -> NamedSharding:
+        """For ``init_kv_cache(sharding=)``. Pages and int8 scale arrays
+        ([L, N, bs, KVH]) alike: their last axis is the kv-head axis, split
+        over tp_kv, so each shard dequantizes its own heads locally."""
+        return self._ns(*self.cache_spec())
+
     def shard_params(self, params: Any) -> Any:
         if jax.process_count() > 1:
             # Cross-process device_put of committed device arrays is not
@@ -187,17 +202,3 @@ class ModelSharding:
             params = jax.tree.map(np.asarray, params)
         return jax.device_put(params, self.param_shardings(params))
 
-    def shard_cache(self, cache) -> tuple:
-        """→ the cache's arrays, sharded, in KVCache field order. int8
-        caches carry [L, N, bs, KVH] scale arrays whose last axis is the
-        kv-head axis — the same tp_kv split as the merged page lanes, so
-        each shard dequantizes its own heads locally."""
-        ns = self._ns(*self.cache_spec())
-        out = [jax.device_put(cache.k, ns), jax.device_put(cache.v, ns)]
-        k_scale = getattr(cache, "k_scale", None)
-        if k_scale is not None:
-            out += [
-                jax.device_put(k_scale, ns),
-                jax.device_put(cache.v_scale, ns),
-            ]
-        return tuple(out)

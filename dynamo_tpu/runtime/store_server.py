@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
+import signal
 
 from dynamo_tpu.runtime.store_net import StoreServer
 
@@ -16,10 +18,17 @@ from dynamo_tpu.runtime.store_net import StoreServer
 async def _main(host: str, port: int) -> None:
     server = await StoreServer(host, port).start()
     print(f"dynamo_tpu store server: {server.url}", flush=True)
-    try:
-        await asyncio.Event().wait()
-    finally:
-        await server.close()
+    # Same contract as the worker and the frontend: SIGTERM exits 0. It
+    # exits at once: returning lets asyncio.run cancel the per-connection
+    # handlers, which drops the clients. Waiting for them to leave first
+    # (Server.wait_closed) would hold the store up for as long as any
+    # client lives.
+    stop = asyncio.Event()
+    loop = asyncio.get_running_loop()
+    for sig in (signal.SIGINT, signal.SIGTERM):
+        with contextlib.suppress(NotImplementedError):
+            loop.add_signal_handler(sig, stop.set)
+    await stop.wait()
 
 
 def main() -> None:

@@ -17,7 +17,7 @@ from dynamo_tpu.llm.protocols import PreprocessedRequest
 from dynamo_tpu.runtime.distributed import DistributedRuntime
 from dynamo_tpu.runtime.engine import Context
 from dynamo_tpu.runtime.push_router import RouterMode
-from dynamo_tpu.worker.__main__ import dp_rank_ports
+from dynamo_tpu.worker.__main__ import dp_rank_chip_env, dp_rank_ports, parse_args
 
 from procutil import ManagedProcess
 
@@ -31,6 +31,27 @@ def test_dp_rank_ports_disjoint_and_deterministic():
     assert blocks[0]["system"] == 29600
     assert blocks[1]["system"] == 29604
     assert dp_rank_ports(29600, 3) == dp_rank_ports(29600, 3)
+
+
+def test_dp_rank_chip_env_gives_each_rank_its_own_chips():
+    """Each rank is a TPU world of its own chips: disjoint
+    TPU_VISIBLE_CHIPS, and the bounds that TPU_VISIBLE_CHIPS alone lacks
+    (without them ranks 1..N-1 die on libtpu's multi-process lock)."""
+    envs = [dp_rank_chip_env(r, 1) for r in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    two = dp_rank_chip_env(1, 2)
+    assert two["TPU_VISIBLE_CHIPS"] == "2,3"
+    assert two["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,2,1"
+    assert two["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    # A rank gets --tp chips, the size of its mesh. Sizes that have not
+    # come up this way on a chip are refused before anything is spawned;
+    # a rank launched from outside is not the spawner's to pin.
+    assert parse_args(["--dp-size", "2", "--tp", "2"]).tp == 2
+    for tp in ("3", "4"):
+        with pytest.raises(SystemExit):
+            parse_args(["--dp-size", "2", "--tp", tp])
+    assert parse_args(["--dp-size", "2", "--dp-rank", "1", "--tp", "4"]).tp == 4
 
 
 @pytest.mark.e2e

@@ -1,0 +1,139 @@
+"""The attention kernel meets the TPU compiler in tier-1, on the CPU.
+
+Interpret mode (tests/test_ops_attention.py) checks the kernel's numbers
+and none of the compiler's rules: PR 7's ``(1, H)`` block of ``[B, H]``
+passed every test and could not be lowered for a TPU at all. Two levels:
+
+- ``jax.export`` for ``platforms=["tpu"]`` runs Pallas's own TPU lowering
+  (BlockSpec legality) and needs nothing but JAX.
+- Where libtpu can describe a v5e topology without a chip, the real
+  XLA:TPU and Mosaic compilers run: vector layouts, VMEM and semaphore
+  budgets. It executes nothing, so numbers stay the chip's business
+  (``chip_smoke.py``, kernel phase).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from dynamo_tpu.engine import model as M
+from dynamo_tpu.engine.config import ModelConfig
+from dynamo_tpu.ops.paged_attention import (
+    kernel_unsupported,
+    paged_decode_attention,
+    paged_spec_attention,
+)
+
+GEOMETRIES = ("llama-8b", "qwen2-7b")
+VARIANTS = ("decode", "int8", "spec", "tree")
+BS = 16
+
+
+def _kernel_case(cfg: ModelConfig, variant: str, *, ctx: int = 4096, B: int = 16,
+                 sharding=None):
+    """→ (fn, abstract args) for one kernel variant at ``cfg``'s geometry."""
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    KVH, hd = cfg.num_kv_heads, cfg.head_dim
+    G = cfg.num_heads // KVH
+    W, L = ctx // BS, 2
+    N = 2 * W
+    quant = variant == "int8"
+    pages = S((L, N, BS, KVH * hd), jnp.int8 if quant else jnp.bfloat16)
+    scales = S((L, N, BS, KVH), jnp.float32) if quant else None
+    layer, tables = S((), jnp.int32), S((B, W), jnp.int32)
+    if variant in ("decode", "int8"):
+        q, lengths = S((B, KVH, G, hd), jnp.bfloat16), S((B,), jnp.int32)
+        return paged_decode_attention, (q, pages, pages, layer, tables, lengths,
+                                        scales, scales)
+    T = 4
+    q, lengths = S((B, T, KVH, G, hd), jnp.bfloat16), S((B, T), jnp.int32)
+    anc = S((B, T, T), jnp.int8) if variant == "tree" else None
+    return paged_spec_attention, (q, pages, pages, layer, tables, lengths,
+                                  None, None, anc)
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+@pytest.mark.parametrize("preset", GEOMETRIES)
+def test_kernel_lowers_for_tpu(preset, variant):
+    fn, args = _kernel_case(ModelConfig.preset(preset), variant)
+    exported = jax.export.export(fn, platforms=["tpu"])(*args)
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One abstract v5e device to compile for, or skip with the reason."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or one that wants a chip
+        pytest.skip(f"no v5e topology without a chip: {type(e).__name__}: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+@pytest.mark.parametrize("preset", GEOMETRIES)
+def test_kernel_compiles_for_v5e(v5e, preset, variant):
+    fn, args = _kernel_case(ModelConfig.preset(preset), variant, sharding=v5e)
+    fn.lower(*args).compile()
+
+
+def test_int8_kv_kernel_limits_repaired(v5e):
+    """The two int8-KV limits the compiler used to enforce and the code
+    did not: head_dim 64 (the in-kernel scale broadcast) and a scale block
+    that grew with the context until VMEM ran out (from 16k tokens)."""
+    fn, args = _kernel_case(ModelConfig.preset("llama-1b"), "int8", sharding=v5e)
+    fn.lower(*args).compile()
+    fn, args = _kernel_case(
+        ModelConfig.preset("qwen2-7b"), "int8", ctx=131072, B=8, sharding=v5e
+    )
+    fn.lower(*args).compile()
+
+
+def test_kernel_unsupported_agrees_with_the_compiler(v5e):
+    """A geometry ``kernel_unsupported`` names is one Mosaic refuses: the
+    engine turns it away at start instead of inside a request."""
+    tiny = ModelConfig.preset("test-tiny")
+    assert "128" in kernel_unsupported(tiny, BS)
+    fn, args = _kernel_case(tiny, "decode", ctx=512, sharding=v5e)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        fn.lower(*args).compile()
+    for preset in GEOMETRIES + ("llama-1b", "llama-70b"):
+        assert kernel_unsupported(ModelConfig.preset(preset), BS) is None
+
+
+def test_multi_decode_window_compiles_for_v5e(v5e):
+    """One decode window of the smoke model (chip_smoke.py: qwen2-7b int8,
+    full width and depth, 16 rows over a 4096-token table) through the
+    compiled kernel: the whole jitted step, not the kernel alone."""
+    from dynamo_tpu.engine.quant import random_int8_params_device
+
+    cfg = ModelConfig.preset("qwen2-7b")
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: random_int8_params_device(cfg, 0)),
+    )
+    B, K, W, N = 16, 8, 4096 // BS, 4096
+    pages = S((cfg.num_layers, N, BS, cfg.kv_size), jnp.bfloat16)
+    i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
+    flags = S((B,), jnp.bool_)
+    compiled = M.multi_decode.lower(
+        cfg, K, "greedy", 0, params, M.KVCache(pages, pages),
+        i32(B), i32(B), i32(B, W), flags,            # tokens, positions, tables, active
+        f32(B), S((B,), jnp.uint32), i32(B),         # temperature, seeds, steps0
+        i32(B), f32(B), f32(B), f32(B), i32(B, 1),   # top_k, top_p, penalties
+        flags, i32(B), i32(B + 1),                   # chain mask/src, last_toks
+        None, None, attn_impl="pallas",
+    ).compile()
+    # Weights + pool are arguments; what the step adds must leave room in
+    # a 16 GB chip beside them.
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9

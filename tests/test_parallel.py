@@ -66,7 +66,7 @@ def test_tp_beyond_kv_heads_matches_single_device():
     ref = run(params, M.init_kv_cache(CFG, 16, bs, jnp.float32))
     mesh = build_mesh(tp=4, cfg=CFG)
     sh = ModelSharding(mesh, CFG)
-    got = run(sh.shard_params(params), M.KVCache(*sh.shard_cache(M.init_kv_cache(CFG, 16, bs, jnp.float32))))
+    got = run(sh.shard_params(params), M.init_kv_cache(CFG, 16, bs, jnp.float32, sharding=sh.cache_sharding()))
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -100,7 +100,7 @@ def test_tp_sharded_prefill_and_decode_match_single_device():
     mesh = build_mesh(tp=2, dp=1)
     sh = ModelSharding(mesh, CFG)
     sharded_params = sh.shard_params(params)
-    cache = M.KVCache(*sh.shard_cache(M.init_kv_cache(CFG, 16, bs, jnp.float32)))
+    cache = M.init_kv_cache(CFG, 16, bs, jnp.float32, sharding=sh.cache_sharding())
     got_p, got_d = run(sharded_params, cache)
 
     np.testing.assert_allclose(got_p, ref_p, rtol=2e-4, atol=2e-4)
@@ -139,7 +139,7 @@ mesh = build_mesh(tp=16, cfg=cfg)
 assert mesh.shape == {"dp": 1, "ep": 1, "tp_kv": 8, "tp_rep": 2}, mesh.shape
 sh = ModelSharding(mesh, cfg)
 params = sh.shard_params(M.init_params(cfg, jax.random.PRNGKey(0), jnp.float32))
-cache = M.KVCache(*sh.shard_cache(M.init_kv_cache(cfg, 16, 4, jnp.float32)))
+cache = M.init_kv_cache(cfg, 16, 4, jnp.float32, sharding=sh.cache_sharding())
 toks = np.zeros((8,), np.int32); toks[:6] = [3,4,5,6,7,8]
 table = np.zeros((4,), np.int32); table[:2] = [1,2]
 logits, cache = M.prefill(cfg, params, cache, jnp.asarray(toks), jnp.asarray(table),

@@ -186,7 +186,7 @@ def test_peer_fetch_skipped_when_local_cache_covers():
 @pytest.mark.e2e
 def test_worker_cli_peer_fetch_spawned_processes():
     """The full CLI wiring: two real-engine worker processes (CPU-forced
-    via DYNTPU_JAX_PLATFORM), prefix seeded on A through the runtime,
+    via JAX_PLATFORMS), prefix seeded on A through the runtime,
     then B serves the same prompt from a peer_prefix hint — B's log must
     show the fetch and the token streams must match."""
     import socket
@@ -203,7 +203,7 @@ def test_worker_cli_peer_fetch_spawned_processes():
         "--block-size", str(BS), "--num-kv-blocks", "64", "--max-num-seqs", "4",
         "--max-model-len", "128", "--decode-steps", "2", "--host-kv-blocks", "32",
     ]
-    env = {"DYNTPU_JAX_PLATFORM": "cpu"}
+    env = {"JAX_PLATFORMS": "cpu"}
 
     with ManagedProcess(
         ["-m", "dynamo_tpu.runtime.store_server", "--host", "127.0.0.1",

@@ -16,7 +16,11 @@ import jax.numpy as jnp
 
 from dynamo_tpu.engine import model as M
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
-from dynamo_tpu.engine.quant import quantize_np, quantize_params_np, random_int8_params
+from dynamo_tpu.engine.quant import (
+    quantize_np,
+    quantize_params_np,
+    random_int8_params_device,
+)
 
 CFG = ModelConfig()  # test-tiny
 
@@ -132,7 +136,22 @@ def test_engine_runs_with_int8_quant():
 
 
 def test_random_int8_params_shapes():
-    p = random_int8_params(CFG, 0)
+    p = random_int8_params_device(CFG, 0)
     assert p["layers"]["w_down"].shape == (CFG.num_layers, CFG.intermediate_size, CFG.hidden_size)
     assert p["layers"]["w_down"].dtype == np.int8
     assert p["embed_scale"].shape == (CFG.vocab_size,)
+
+
+def test_random_int8_params_born_sharded_match_single_device():
+    """The tp path builds the tree under jit with out_shardings: no device
+    holds the whole model, and the values do not depend on the mesh."""
+    from dynamo_tpu.parallel.mesh import ModelSharding, build_mesh
+
+    sh = ModelSharding(build_mesh(tp=2, cfg=CFG), CFG)
+    one = random_int8_params_device(CFG, 3)
+    two = random_int8_params_device(CFG, 3, sharding=sh)
+    wq = two["layers"]["wq"]
+    assert len(wq.sharding.device_set) == 2
+    assert wq.addressable_shards[0].data.shape[-1] == CFG.q_size // 2
+    for a, b in zip(jax.tree.leaves(one), jax.tree.leaves(two)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -49,7 +49,7 @@ from tools.analysis.core import (
 STATIC_ANNOTATIONS = {"int", "float", "bool", "str", "bytes", "type", "Callable"}
 TRACER_META_ATTRS = {"shape", "dtype", "ndim", "size", "sharding", "at"}
 SCAN_LIKE = {
-    "lax.scan", "jax.lax.scan", "shard_map", "jax.experimental.shard_map.shard_map",
+    "lax.scan", "jax.lax.scan", "shard_map", "jax.shard_map",
     "jax.vmap", "vmap", "pl.pallas_call", "pallas_call", "lax.fori_loop",
     "jax.lax.fori_loop", "lax.while_loop", "jax.lax.while_loop", "lax.cond",
     "jax.lax.cond", "jax.checkpoint", "jax.remat",

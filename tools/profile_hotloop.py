@@ -12,12 +12,11 @@ import time
 
 import numpy as np
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"))
-
 import jax
 import jax.numpy as jnp
 
 from dynamo_tpu.engine import model as M
+from dynamo_tpu.engine.compile_cache import configure_compile_cache
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 
 
@@ -36,6 +35,7 @@ def main():
     p.add_argument("--model", default="llama-1b")
     p.add_argument("--bs", type=int, default=16)
     args = p.parse_args()
+    configure_compile_cache()
 
     cfg = ModelConfig.preset(args.model)
     bs = args.bs

@@ -36,14 +36,14 @@ def parse_args():
 async def sweep(args):
     import jax
 
+    from dynamo_tpu.engine.compile_cache import configure_compile_cache
     from dynamo_tpu.engine.config import EngineArgs, ModelConfig
     from dynamo_tpu.engine.engine import TpuEngine
     from dynamo_tpu.llm.protocols import PreprocessedRequest
     from dynamo_tpu.planner.interpolate import DecodeInterpolator, PrefillInterpolator, save_profile
     from dynamo_tpu.runtime.engine import Context
 
-    cache_dir = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    configure_compile_cache()
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
         model = ModelConfig.preset("test-tiny")
