@@ -1,5 +1,5 @@
-"""The benchmark's arithmetic: percentiles, per-request latencies, rates, and
-reading a Prometheus exposition. ``pctl`` and ``slo_attribution`` are after ``bench.py:199-227``.
+"""The benchmark's arithmetic: percentiles, per-request latencies, rates, the
+spread of repeated runs, and reading a Prometheus exposition. ``pctl`` and ``slo_attribution`` are after ``bench.py:199-227``.
 
 A request record (``client.py`` writes them) is a dict with:
 
@@ -13,6 +13,7 @@ A request record (``client.py`` writes them) is a dict with:
 from __future__ import annotations
 
 import math
+import statistics
 
 
 def pctl(values: list[float], q: float) -> float:
@@ -71,6 +72,70 @@ def finite_or_cap(x: float, cap: float) -> float:
     """A percentile that landed on a failed request reads as ``cap`` (the
     window's length): a number the driver can compare, and far off."""
     return cap if not math.isfinite(x) else x
+
+
+# -- spread of repeated runs, and where a batch sits in its buckets -------------
+
+
+def quartile_spread(values: list[float]) -> float:
+    """Distance between the first and third quartile over the median. With
+    six runs the quartiles lie a quarter of the way from the second value to
+    the first and from the fifth to the sixth: an end counts for a quarter."""
+    if len(values) < 2:
+        return float("nan")
+    q = statistics.quantiles(values, n=4)
+    med = statistics.median(values)
+    return (q[2] - q[0]) / med if med else float("nan")
+
+
+def ledger_spread(values: list[float]) -> float:
+    """The spread as the driver's ledger has it: highest less lowest over the
+    median of all the runs, after leaving out the one run farthest from that
+    median where that narrows it. One far run does no harm, two do. With
+    fewer than three runs none is left out."""
+    if not values:
+        return float("nan")
+    med = statistics.median(values)
+    if not med:
+        return float("nan")
+    width = max(values) - min(values)
+    if len(values) >= 3:
+        rest = sorted(values, key=lambda v: abs(v - med))[:-1]
+        width = min(width, max(rest) - min(rest))
+    return width / med
+
+
+def aa_word(side_a: list[float], side_b: list[float], bound: float) -> dict:
+    """Two sides of one tree, judged as the driver judges parent and change:
+    ``judgeable`` when each side's ledger spread is at most half the bound and
+    the medians differ by less than half the bound (as a share of A's)."""
+    sa, sb = ledger_spread(side_a), ledger_spread(side_b)
+    ma, mb = statistics.median(side_a), statistics.median(side_b)
+    apart = abs(mb - ma) / abs(ma) if ma else float("nan")
+    ok = sa <= bound / 2 and sb <= bound / 2 and apart < bound / 2
+    return {"spread_a": sa, "spread_b": sb, "median_a": ma, "median_b": mb,
+            "medians_apart": apart, "word": "judgeable" if ok else "unsteady"}
+
+
+def bucket_shares(samples: list[float], buckets: list[int]) -> dict[int, float] | None:
+    """Share (%) of ``samples`` (running sequences, one a poll) that a decode
+    batch bucket serves: the smallest bucket that holds the sample. A sample
+    over the last bucket counts there. None without samples or buckets."""
+    if not samples or not buckets:
+        return None
+    buckets = sorted(buckets)
+    counts = dict.fromkeys(buckets, 0)
+    for v in samples:
+        counts[next((b for b in buckets if v <= b), buckets[-1])] += 1
+    return {b: 100.0 * n / len(samples) for b, n in counts.items()}
+
+
+def main_bucket_share(samples: list[float], buckets: list[int]) -> float | None:
+    """Share (%) of the polls that fall in the bucket most polls fall in: near
+    100 the batch sits inside one bucket, near 50 it sits on an edge and a
+    decode step costs now one bucket's time and now the next one's."""
+    shares = bucket_shares(samples, buckets)
+    return max(shares.values()) if shares else None
 
 
 # -- Prometheus text ----------------------------------------------------------

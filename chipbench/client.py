@@ -136,6 +136,7 @@ async def run_closed(session, url, model, plan: dict, t0: float, seconds: float,
                 history = list(plan["system_prompts"][turn["base"]])
             prompt = history + turn["new"]
             rec = new_record("closed", None, len(prompt), turn["max_tokens"])
+            rec["history_tokens"] = len(history)  # sent before: the prefix cache may hold it
             records.append(rec)
             await complete(session, url, model, prompt, rec, seconds + 30.0, keep_answer=True)
             # A failed turn leaves a shorter history; the session goes on.

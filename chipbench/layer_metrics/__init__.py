@@ -8,7 +8,10 @@ parsed ``/metrics``), ``gauges`` (the exporter's page polled every 0.5 s in
 the window), ``stats`` ({"<rank>.<k>"} -> the launcher's snapshots, k = 0 at
 the window's start and 1 at its end), ``trace`` (``trace_reduce.reduce``'s
 result for rank 0, or None), ``config``, ``traffic``, ``seconds``,
-``replicas``, ``t0``, ``t_end``, ``worker_logs``, ``here``.
+``replicas``, ``t0``, ``t_end``, ``worker_logs``, ``here``, ``decode_buckets``
+(the configuration's ``EngineArgs.decode_buckets``, as warm-up took them).
+A plain run fills ``records``, ``gauges``, ``config``, ``traffic``,
+``seconds``, ``replicas`` and ``decode_buckets`` too, for its log line.
 
 A reader that finds nothing to read returns None (or raises): the harness
 then leaves the metric out of the line and says so on an earlier one.
@@ -45,6 +48,16 @@ def gauge_series(ctx: dict, name: str) -> list[float]:
             v = arith.prom_sum({k: x for k, x in sample.items() if k != "t"}, name)
             if v is not None:
                 out.append(v)
+    return out
+
+
+def gauge_samples(ctx: dict, name: str) -> list[float]:
+    """The exporter's gauge ``name``, one value a worker a poll: what each
+    worker saw, where ``gauge_series`` gives the fleet's sum."""
+    out = []
+    for sample in ctx["gauges"]:
+        if 0.0 <= sample["t"] <= ctx["seconds"]:
+            out += [v for key, v in sample.items() if key.partition("{")[0] == name]
     return out
 
 

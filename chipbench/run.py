@@ -44,6 +44,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench import arith, client, generators  # noqa: E402
+from chipbench.layer_metrics import gauge_samples, gauge_series  # noqa: E402
 from chipbench.procs import Stack, free_port, log  # noqa: E402
 
 HERE = os.path.join(ROOT, "chipbench")
@@ -205,10 +206,9 @@ class Run:
     def __init__(self, opts: argparse.Namespace, spec: dict):
         self.opts, self.spec = opts, spec
         self.config, self.traffic = spec["config"], dict(spec["traffic"])
-        if opts.rate is not None:
-            self.traffic["rate_rps"] = opts.rate
-        if opts.clients is not None:
-            self.traffic["clients"] = opts.clients
+        for item in opts.param:
+            key, _, value = item.partition("=")
+            self.traffic[key] = json.loads(value)
         self.name = self.config["name"]
         self.replicas = int(self.config.get("replicas", 1))
         self.out_dir = os.path.join(OUT_ROOT, opts.workload, "trace" if opts.trace else "plain")
@@ -222,6 +222,8 @@ class Run:
         self.workers: list = []
         self.start_lines: list[dict] = []
         self.trace_marks: dict = {}
+        self.decode_buckets: list[int] = []
+        self.phases: dict[str, float] = {}   # set-up's parts, seconds
 
     def incorrect(self, why: str) -> None:
         self.notes.append(why)
@@ -263,6 +265,7 @@ class Run:
                                       str(self.ports["exporter"]), "--interval", "0.5"], base_env)
         for rank in range(self.replicas):
             self.await_worker(rank, flags, base_env)
+        self.phases["worker_ready_s"] = time.monotonic() - T_PROCESS_START
 
     def start_worker(self, rank: int, flags: list[str], base_env: dict):
         env = {**base_env, "DYNTPU_SYSTEM_ENABLED": "1",
@@ -375,6 +378,7 @@ class Run:
         (``warm_up_prompts``), one at a time on an idle server; then each
         decode batch bucket at full batch, at both table widths."""
         eargs = engine_args(self.config)
+        self.decode_buckets = list(eargs.decode_buckets)
         url = self.url("http", "/v1/completions")
         rng = random.Random(7)
         vocab = self.config["vocab_size"]
@@ -408,8 +412,9 @@ class Run:
                 wave = [one(ids(plen), f"decode B={nb} prompt={plen}", 3 * eargs.decode_steps + 2)
                         for _ in range(nb * self.replicas)]
                 await asyncio.gather(*wave)
+        self.phases["warm_up_s"] = time.monotonic() - t_w
         log(f"warm-up: {len(shapes)} prefill prompts x {self.replicas} replicas, decode buckets "
-            f"{list(eargs.decode_buckets)} in {time.monotonic() - t_w:.1f} s, {self.counts['warmup_failed']} failed")
+            f"{self.decode_buckets} in {self.phases['warm_up_s']:.1f} s, {self.counts['warmup_failed']} failed")
 
     async def prefill_sessions(self, session, plan: dict) -> None:
         """Closed loop: put every client's starting history into the cache."""
@@ -427,9 +432,10 @@ class Run:
                 log(f"pre-fill request failed: {rec['error']}")
 
         await asyncio.gather(*(one(c["prefill"]) for c in plan["clients"]))
+        self.phases["pre_fill_s"] = time.monotonic() - t_p
         log(f"pre-fill: {len(plan['clients'])} histories, "
             f"{sum(len(c['prefill']) for c in plan['clients'])} tokens in "
-            f"{time.monotonic() - t_p:.1f} s, {self.counts['prefill_failed']} failed")
+            f"{self.phases['pre_fill_s']:.1f} s, {self.counts['prefill_failed']} failed")
 
     # -- the window -----------------------------------------------------------
 
@@ -561,12 +567,49 @@ def end_to_end(run: Run, setup_s: float) -> dict[str, float]:
     }
 
 
-def per_layer(run: Run, names: list[str], trace: dict | None) -> dict[str, float]:
-    ctx = {"records": run.records, "prom": run.prom, "gauges": run.gauges, "stats": run.stats,
-           "trace": trace, "config": run.config, "traffic": run.traffic, "t0": run.t0,
-           "t_end": run.t_end, "seconds": run.opts.seconds, "replicas": run.replicas,
-           "worker_logs": [w.log_text() for w in run.workers], "here": HERE,
-           "trace_marks": run.trace_marks, "t0_unix": run.t0_unix}
+def reader_ctx(run: Run, trace: dict | None) -> dict:
+    """What a per-layer reader gets (``layer_metrics/__init__.py``)."""
+    return {"records": run.records, "prom": run.prom, "gauges": run.gauges, "stats": run.stats,
+            "trace": trace, "config": run.config, "traffic": run.traffic, "t0": run.t0,
+            "t_end": run.t_end, "seconds": run.opts.seconds, "replicas": run.replicas,
+            "worker_logs": [w.log_text() for w in run.workers], "here": HERE,
+            "trace_marks": run.trace_marks, "t0_unix": run.t0_unix,
+            "decode_buckets": run.decode_buckets}
+
+
+def unjudged(run: Run, e2e: dict[str, float], ctx: dict) -> dict:
+    """What every run, plain or traced, says beside its judged metrics, for
+    ``prove.py`` and the reader of a log; the driver ignores it. Set-up's
+    parts; where the running batch sat among the decode batch buckets
+    (``batch_bucket_main_share``'s own function on this run's polls); every
+    end-to-end number the harness knows; requests whose first token took over
+    1.5 s (a closed loop's turn that found its history evicted recomputes it);
+    and the share of prompt blocks a session had sent before, which is what
+    the prefix cache could have held had it evicted nothing."""
+    active = gauge_samples(ctx, "dynamo_tpu_fleet_worker_active_slots")
+    kv_total = max(gauge_series(ctx, "dynamo_tpu_fleet_worker_kv_total_blocks"), default=0)
+    kv_used = [100.0 * v / kv_total
+               for v in gauge_series(ctx, "dynamo_tpu_fleet_worker_kv_active_blocks") if kv_total]
+    waiting = gauge_series(ctx, "dynamo_tpu_fleet_worker_waiting")
+    shares = arith.bucket_shares(active, run.decode_buckets) or {}
+    ttft = arith.ttft_samples(run.records, run.t_end)
+    bs = int(run.config["served"]["block_size"])
+    sent = sum(-(-r["prompt_tokens"] // bs) for r in run.records)
+    held = sum(r.get("history_tokens", 0) // bs for r in run.records)
+    batch = {"batch_bucket_shares": {f"<={b}": v for b, v in shares.items()},
+             "batch_bucket_main_share": arith.main_bucket_share(active, run.decode_buckets),
+             "polls": len(active), "active_mean": sum(active) / len(active) if active else None,
+             "active_min_max": [min(active), max(active)] if active else None,
+             "kv_used_mean_max": [sum(kv_used) / len(kv_used), max(kv_used)] if kv_used else None,
+             "waiting_mean": sum(waiting) / len(waiting) if waiting else None,
+             "completed": sum(1 for r in run.records if r["status"] == "ok"),
+             "ttft_over_1500ms": sum(1 for x in ttft if x > 1.5),
+             "resent_block_share": 100.0 * held / sent if sent else None}
+    return {"setup_phases": dict(run.phases), "batch": batch,
+            "end_to_end_all": {k: v for k, v in e2e.items() if math.isfinite(v)}}
+
+
+def per_layer(run: Run, names: list[str], ctx: dict) -> dict[str, float]:
     out = {}
     for name in names:
         try:
@@ -603,8 +646,9 @@ def breakdown(run: Run, trace: dict) -> dict:
 
 
 def make_result(correct: bool, attempted: int, failed: int, values: dict, units: dict,
-                device: dict, breakdown_: dict | None) -> dict:
-    """The result line: exactly the keys the driver's contract names."""
+                device: dict, breakdown_: dict | None, unjudged_: dict | None = None) -> dict:
+    """The result line: the keys the driver's contract names, and under
+    ``unjudged`` what the driver ignores and ``prove.py`` reads."""
     result = {
         "correct": bool(correct), "attempted": int(attempted), "failed": int(failed),
         "metrics": {n: {"value": v, "unit": units[n]} for n, v in values.items()},
@@ -612,6 +656,8 @@ def make_result(correct: bool, attempted: int, failed: int, values: dict, units:
     }
     if breakdown_ is not None:
         result["breakdown"] = breakdown_
+    if unjudged_ is not None:
+        result["unjudged"] = unjudged_
     return result
 
 
@@ -625,8 +671,8 @@ def parse(argv: list[str]) -> argparse.Namespace:
     p.add_argument("--seconds", type=float, default=None)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--rehearse", action="store_true", help="CPU, toy size, never correct")
-    p.add_argument("--rate", type=float, default=None, help="sweep.py: override the mix's rate_rps")
-    p.add_argument("--clients", type=int, default=None, help="sweep.py: override the mix's clients")
+    p.add_argument("--param", action="append", default=[], metavar="KEY=JSON",
+                   help="sweep.py, prove.py: override one parameter of the mix (rate_rps=6, clients=64)")
     return p.parse_args(argv)
 
 
@@ -695,16 +741,21 @@ def main(argv: list[str]) -> int:
         + json.dumps({k: round(v, 4) for k, v in e2e.items()}))
     log("slo: " + json.dumps(arith.slo_attribution(
         arith.ttft_samples(run.records, run.t_end), arith.tpot_samples(run.records), 1.0, 0.05)))
+    ctx = reader_ctx(run, trace)
+    extra = unjudged(run, e2e, ctx)
+    log("batch buckets: " + json.dumps(extra["batch"]))
+    log("set-up phases: " + json.dumps({k: round(v, 2) for k, v in run.phases.items()}))
     if opts.trace:
         names = [m["name"] for m in spec["per_layer"]]
-        values = per_layer(run, names, trace)
+        values = per_layer(run, names, ctx)
         units = {m["name"]: m["unit"] for m in spec["per_layer"]}
     else:
         names = [m["name"] for m in spec["end_to_end"]]
         values = {n: e2e[n] for n in names if n in e2e and math.isfinite(e2e[n])}
         units = {m["name"]: m["unit"] for m in spec["end_to_end"]}
     result = make_result(not run.notes, len(run.records), status["failed"], values, units,
-                         run.device(trace), breakdown(run, trace) if opts.trace and trace else None)
+                         run.device(trace), breakdown(run, trace) if opts.trace and trace else None,
+                         extra)
     log("notes: " + json.dumps(run.notes) + " counts: " + json.dumps(
         {**run.counts, **status, "unclean_children": len(run.stack.unclean)}))
     with open(os.path.join(run.out_dir, "result.json"), "w") as f:

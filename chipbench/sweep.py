@@ -5,7 +5,7 @@
     python3 chipbench/sweep.py --workload <cell> --clients 32,48,64     (closed-loop mixes)
 
 Runs ``run.py`` once per rate, one after another (a chip belongs to one
-process at a time), with ``--rate`` overriding the mix's ``rate_rps``, and
+process at a time), with ``--param`` overriding the mix's ``rate_rps`` or ``clients``, and
 prints one line per rate: offered and completed requests per second, tokens
 per second, TTFT and TPOT tails, failures. The knee is the last rate at which
 completed keeps up with offered and the TTFT tail has not taken off; a cell
@@ -35,11 +35,11 @@ def main(argv: list[str]) -> int:
     p.add_argument("--seed", type=int, default=1)
     p.add_argument("--rehearse", action="store_true")
     o = p.parse_args(argv)
-    flag, values = ("--rate", o.rates) if o.rates else ("--clients", o.clients)
+    key, values = ("rate_rps", o.rates) if o.rates else ("clients", o.clients)
     rows = []
     for v in values.split(","):
         cmd = [sys.executable, os.path.join(HERE, "run.py"), "--workload", o.workload, "--seed",
-               str(o.seed), "--seconds", str(o.seconds), "--trace", "0", flag, v]
+               str(o.seed), "--seconds", str(o.seconds), "--trace", "0", "--param", f"{key}={v}"]
         if o.rehearse:
             cmd.append("--rehearse")
         proc = subprocess.run(cmd, capture_output=True, text=True)

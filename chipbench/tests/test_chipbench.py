@@ -176,3 +176,144 @@ def test_operation_names_are_cut_from_the_hlo_text():
     assert trace_reduce.short("%while.35 = (s32[]{:T(128)}, bf16[32,3584]{1,0}) while(...)") == "while.35 s32[]"
     assert trace_reduce.kind("while.35 s32[]") in trace_reduce.CONTAINERS
     assert trace_reduce.short("jit_multi_decode_impl(123)") == "jit_multi_decode_impl(123)"
+
+
+# -- spread as the driver has it, the word, the bucket share, BENCHMARK.json ------
+# (ISSUE 25 asked for these under tests/ too; a benchmark PR may add no file there.)
+
+
+@pytest.mark.parametrize("values, want", [
+    ([100, 101, 102, 103, 104, 150], 4 / 102.5),    # one far run is left out
+    ([100, 101, 102, 103, 140, 150], 40 / 102.5),   # two far runs are not: 150 goes, 140 stays
+    ([50, 100, 101, 102, 103, 104], 4 / 101.5),     # the far run may be the lowest
+    ([100, 100, 100, 100], 0.0),                    # leaving one out narrows nothing: same width
+    ([100, 110], 10 / 105),                         # fewer than three: none is left out
+    ([100], 0.0),
+])
+def test_ledger_spread_leaves_out_the_one_farthest_run(values, want):
+    assert arith.ledger_spread(values) == pytest.approx(want)
+
+
+def test_ledger_spread_of_nothing_is_not_a_number():
+    assert math.isnan(arith.ledger_spread([]))
+    assert math.isnan(arith.quartile_spread([1.0]))
+
+
+def test_the_two_spreads_disagree_on_far_runs():
+    # Quartiles of six runs lie a quarter of the way from the second value to
+    # the first and from the fifth to the sixth: an end counts for a quarter.
+    one_far = [100, 100.5, 101, 101.5, 102, 120]
+    assert arith.quartile_spread(one_far) == pytest.approx(6.125 / 101.25)
+    assert arith.ledger_spread(one_far) == pytest.approx(2 / 101.25)       # left out
+    two_ends = [90, 100, 100.5, 101, 101.5, 112]
+    assert arith.quartile_spread(two_ends) == pytest.approx(6.625 / 100.75)
+    assert arith.ledger_spread(two_ends) == pytest.approx(11.5 / 100.75)   # 112 goes, 90 stays
+
+
+@pytest.mark.parametrize("side_a, side_b, bound, word", [
+    ([100, 101, 102.5], [100, 101, 102.5], 0.05, "judgeable"),     # spread 2.5/101 < 2.5%
+    ([100, 101, 102.6, 110], [100, 101, 102], 0.05, "unsteady"),   # A spreads 2.6/101.8 > 2.5%
+    ([100, 100, 100], [100, 100, 102.5], 0.05, "judgeable"),       # exactly half the bound passes
+    ([100, 100, 100], [102.4, 102.4, 102.4], 0.05, "judgeable"),   # medians 2.4% apart
+    ([100, 100, 100], [102.5, 102.5, 102.5], 0.05, "unsteady"),    # medians half the bound apart
+    ([100, 100, 100], [97.5, 97.5, 97.5], 0.05, "unsteady"),       # better or worse alike
+])
+def test_the_word_turns_at_half_the_bound(side_a, side_b, bound, word):
+    assert arith.aa_word(side_a, side_b, bound)["word"] == word
+
+
+def gauges(active_by_poll, seconds=10.0):
+    key = 'dynamo_tpu_fleet_worker_active_slots{worker="1"}'
+    return {"gauges": [{"t": 0.5 * i, key: v} for i, v in enumerate(active_by_poll)],
+            "seconds": seconds, "decode_buckets": [8, 32, 64]}
+
+
+@pytest.mark.parametrize("active, want", [
+    ([40, 45, 50, 64, 33], 100.0),                   # all polls in one bucket
+    ([30, 31, 32, 33, 40, 20, 1, 8, 9, 32], 60.0),   # 6 of 10 in <=32, 2 in <=8, 2 in <=64
+    ([], None),                                      # no polls
+])
+def test_batch_bucket_main_share_on_a_made_up_series(active, want):
+    from chipbench.layer_metrics import batch_bucket_main_share
+    got = batch_bucket_main_share.read(gauges(active))
+    assert got is None if want is None else got == pytest.approx(want)
+
+
+def test_bucket_shares_count_each_worker_and_only_the_window():
+    from chipbench.layer_metrics import batch_bucket_main_share, gauge_samples
+    ctx = gauges([], seconds=1.0)
+    ctx["gauges"] = [{"t": -0.5, 'dynamo_tpu_fleet_worker_active_slots{worker="1"}': 1.0},
+                     {"t": 0.5, 'dynamo_tpu_fleet_worker_active_slots{worker="1"}': 40.0,
+                      'dynamo_tpu_fleet_worker_active_slots{worker="2"}': 20.0,
+                      'dynamo_tpu_fleet_worker_total_slots{worker="1"}': 64.0},
+                     {"t": 1.5, 'dynamo_tpu_fleet_worker_active_slots{worker="1"}': 2.0}]
+    assert gauge_samples(ctx, "dynamo_tpu_fleet_worker_active_slots") == [40.0, 20.0]
+    assert batch_bucket_main_share.read(ctx) == pytest.approx(50.0)
+    assert arith.bucket_shares([70.0], [8, 32, 64]) == {8: 0.0, 32: 0.0, 64: 100.0}
+
+
+def benchmark_json() -> dict:
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("group", ["end_to_end", "per_layer"])
+def test_every_cell_a_metric_lists_exists(group):
+    bench = benchmark_json()
+    cells = {w["name"] for w in bench["workloads"]}
+    for m in bench[group]:
+        assert set(m.get("workloads", [])) <= cells, m["name"]
+        if group == "per_layer":
+            moved = next(e for e in bench["end_to_end"] if e["name"] == m["moves"])
+            assert set(m.get("workloads", cells)) <= set(moved.get("workloads", cells)), m["name"]
+            reader = m["name"].replace("-", "_").replace(".", "_") + ".py"
+            assert os.path.isfile(os.path.join(BENCH, "layer_metrics", reader)), m["name"]
+
+
+def test_every_judged_metric_has_a_bound_within_the_contract():
+    for m in benchmark_json()["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.1, m["name"]
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in benchmark_json()["workloads"]])
+def test_every_cell_is_judged_and_its_files_exist(cell):
+    bench = benchmark_json()
+    w = next(x for x in bench["workloads"] if x["name"] == cell)
+    judged = [m["name"] for m in bench["end_to_end"] if cell in m.get("workloads", [cell])]
+    assert "setup_s" in judged and len(judged) >= 2, judged
+    assert any(cell in m.get("workloads", [cell]) for m in bench["per_layer"])
+    assert os.path.isfile(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
+    cfg = next(c for c in bench["configs"] if c["name"] == w["config"])
+    assert os.path.isfile(os.path.join(os.path.dirname(BENCH), cfg["file"]))
+
+
+@pytest.mark.parametrize("pairs, order", [
+    (1, "AB"), (2, "ABBA"), (3, "ABBAAB"), (6, "ABBAABBAABBA"),
+])
+def test_aa_pairs_take_turns_to_go_first_and_share_a_seed(pairs, order):
+    from chipbench import prove
+    plan = prove.aa_plan(pairs)
+    assert "".join(side for side, _, _ in plan) == order
+    assert [i for _, i, _ in plan] == [i for i in range(pairs) for _ in "AB"]
+    assert all(trace == 0 for _, _, trace in plan)
+
+
+def test_aa_summary_prints_the_word_for_judged_metrics_and_not_for_phases():
+    from chipbench import prove
+    by_set = {"A": {"out_tok_s": [800.0, 801.0, 802.0], "warm_up_s": [40.0, 41.0, 50.0]},
+              "B": {"out_tok_s": [800.0, 830.0, 860.0], "warm_up_s": [40.0, 41.0, 42.0]}}
+    lines = prove.summary(by_set, {"out_tok_s": 0.015}, aa=True)
+    assert any(ln.startswith("aa out_tok_s:") and ln.endswith("unsteady") for ln in lines)
+    assert any(ln.startswith("aa warm_up_s:") and ln.endswith("not judged") for ln in lines)
+
+
+def test_the_result_line_carries_what_prove_reads_under_one_ignored_key():
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "memory_peak_bytes": 1}
+    doc = make_result(True, 1, 0, {}, {}, dev, None, {"setup_phases": {"warm_up_s": 40.0}})
+    assert set(doc) == {"correct", "attempted", "failed", "metrics", "device", "unjudged"}
+
+
+def test_every_line_of_text_in_benchmark_json_is_within_the_contract():
+    bench = benchmark_json()
+    for entry in bench["workloads"] + bench["configs"]:
+        assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"], entry["name"]
