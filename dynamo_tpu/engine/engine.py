@@ -30,12 +30,14 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import random
 import threading
 import time
 from typing import Any, AsyncIterator
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from dynamo_tpu.block_manager.adapters import AdapterSlotPool
 from dynamo_tpu.block_manager.pool import BlockPool, NoFreeBlocksError
@@ -74,6 +76,7 @@ from dynamo_tpu.llm.protocols import (
 from dynamo_tpu.runtime import tracing
 from dynamo_tpu.runtime.engine import Context
 from dynamo_tpu.runtime.logging import get_logger
+from dynamo_tpu.runtime.metrics import PREFIX
 from dynamo_tpu.runtime.qos import DEFAULT_CLASS, QOS_CLASSES, qos_rank
 from dynamo_tpu.tokens import (
     TokenBlockSequence,
@@ -147,6 +150,8 @@ class _Seq:
         "cancelled", "preempted", "prefix_hit_blocks", "sample_seed",
         "kv_written", "export", "export_meta", "inject", "dead",
         "slot", "first_pend", "t_admit",
+        "t_blocked", "blocked_why", "chunks", "t_dispatched", "wave",
+        "t_first", "first_waited",
         "spec_ema", "spec_cool", "draft_state",
         "export_handle", "export_stream", "export_pub_blocks",
         "grammar", "grammar_state", "grammar_eos_bits",
@@ -183,6 +188,22 @@ class _Seq:
         # request wins admission): splits queue-wait from prefill in the
         # consumer coroutine's retroactive spans.
         self.t_admit: float | None = None
+        # The first token's timeline, stamped by the scheduler thread
+        # like t_admit and read by the consumer coroutine only at the
+        # first delta: the first admission attempt that failed for want
+        # of a slot or of blocks (t_blocked, blocked_why), the prefill
+        # programs this sequence dispatched (chunks), the return of
+        # _dispatch_prefills for its wave (t_dispatched) with
+        # wave = (sequences in the wave, decode windows in flight then),
+        # and the first-token sample's arrival on the host (t_first;
+        # first_waited = the host had to wait for the fetch).
+        self.t_blocked: float | None = None
+        self.blocked_why = ""
+        self.chunks = 0
+        self.t_dispatched: float | None = None
+        self.wave = (0, 0)
+        self.t_first: float | None = None
+        self.first_waited = False
         # Finished/cancelled (set by _finish). In-flight decode windows
         # drain after the fact; dead rows' outputs are discarded.
         self.dead = False
@@ -391,13 +412,11 @@ class _First:
 BLOCKING_PHASES = ("first_sample", "drain_sync", "drain_ready", "single_step")
 
 
-def register_engine_metrics(registry):
+def register_engine_metrics(registry) -> dict:
     """Register the engine gauges/counters on a MetricsRegistry →
-    (inflight windows, pending first fetches, prefill pad ratio,
-    spec proposed counter, spec accepted counter, spec accept-rate gauge,
-    tokens-per-weight-pass gauge). Shared by the worker (bind_metrics)
-    and the tools/check_metrics.py catalog guard."""
-    return (
+    {name without the registry's prefix: metric}. Shared by the worker
+    (bind_metrics) and the tools/check_metrics.py catalog guard."""
+    metrics = (
         registry.gauge(
             "engine_inflight_windows",
             "Decode windows dispatched on device but not yet drained",
@@ -516,7 +535,38 @@ def register_engine_metrics(registry):
             "G4 puts/spill-adoptions skipped because a peer engine "
             "already wrote the identical salted-hash block file",
         ),
+        registry.counter(
+            "engine_step_phase_seconds_total",
+            "Scheduler-thread wall seconds by step-loop phase (idle, "
+            "admission, prefill_dispatch, first_dispatch, decode_dispatch, "
+            "drain_sync, drain_ready, first_sample, emit, ...): "
+            "drain_sync and first_sample wait on a device fetch, and a "
+            "dispatch phase blocks while the device's queue is full",
+        ),
+        registry.counter(
+            "engine_sched_cpu_seconds_total",
+            "CPU seconds the scheduler thread itself burned "
+            "(time.thread_time): host work, as against waiting on the "
+            "device or for the GIL",
+        ),
+        registry.counter(
+            "engine_decode_row_steps_total",
+            "Decode rows x steps by kind: dispatched = batch-bucket rows x "
+            "steps of every decode window dispatched, emitted = tokens "
+            "those windows delivered to a live sequence (no padding row, "
+            "nothing past a stop, no finished, cancelled or preempted row)",
+        ),
+        registry.counter(
+            "kv_pool_hit_blocks_total",
+            "Prompt blocks an admission found in the G1 prefix cache",
+        ),
+        registry.counter(
+            "kv_pool_miss_blocks_total",
+            "Matchable prompt blocks an admission did not find in the G1 "
+            "prefix cache (hit + miss = (prompt_len - 1) // block_size)",
+        ),
     )
+    return {m.name.removeprefix(PREFIX + "_"): m for m in metrics}
 
 
 class TpuEngine:
@@ -544,7 +594,7 @@ class TpuEngine:
         "_embed_jobs", "_host_jobs", "_offload_pending", "_exports",
         "_export_fetches", "_drafter", "_step_no", "_spec_ticked",
         "phase_s", "phase_n", "_ctr_pushed", "_spec_depth_hist",
-        "_migrations",
+        "_migrations", "_anno", "_slots_blocked_sig",
     })
 
     def __init__(
@@ -703,11 +753,9 @@ class TpuEngine:
         # the (class, age) scheduling key; assigned under _wakeup at
         # submission, read by the scheduler thread afterwards) and
         # recompute-preemption counts by victim class (racy-total
-        # contract like the other total_* counters; _preempt_pushed
-        # tracks what _update_gauges already fed the labeled counter).
+        # contract like the other total_* counters).
         self._arrival_no = 0
         self.total_preemptions_by: collections.Counter = collections.Counter()
-        self._preempt_pushed: dict[str, int] = {}
         # Cumulative counters for metrics/bench.
         self.total_generated = 0
         self.total_prefilled = 0
@@ -716,91 +764,111 @@ class TpuEngine:
         # accounting (bench.py roofline breakdown).
         self.total_prefill_padded = 0
         self.total_decode_steps = 0  # device substeps incl. padded/zombie work
+        # Decode rows x steps: what every dense decode dispatch paid for
+        # (batch-bucket rows x steps, padding and zombie rows included) and
+        # the tokens those dispatches delivered to a live sequence.
+        self.total_decode_rows_dispatched = 0
+        self.total_decode_rows_emitted = 0
         # Host-side phase accounting (bench.py --breakdown; VERDICT r4
         # weak #1: where the non-device half of the step time goes).
         # Keys: idle / admission / prefill_dispatch / first_sample /
         # decode_dispatch / drain_sync / emit / other.
         self.phase_s: dict[str, float] = collections.defaultdict(float)
         self.phase_n: dict[str, int] = collections.defaultdict(int)
-        # Optional Prometheus gauges (worker bind_metrics): in-flight
-        # windows / pending first fetches / prefill pad ratio / spec
-        # series. _ctr_pushed tracks what the monotonic counters have
-        # already been fed (engine keeps plain ints; registry counters
-        # get the delta once per step).
-        self._gauges = None
-        # (proposed, accepted, tree passes, protected tier evictions,
-        # budget reallocs, lora page-ins) already inc'd into the
-        # registry counters.
-        self._ctr_pushed = [0] * 9
+        # Optional Prometheus series (worker bind_metrics), by name.
+        # The engine keeps plain running totals; _ctr_pushed remembers
+        # what each registry counter series has been fed, so _feed gives
+        # it the growth once per step.
+        self._gauges: dict | None = None
+        self._anno: TraceAnnotation | None = None  # the open phase's
+        self._ctr_pushed: dict[tuple, float] = {}
+        # _waiting as it was when its head was last stamped blocked for a slot
+        self._slots_blocked_sig: tuple | None = None
 
     def bind_metrics(self, registry) -> None:
         """Attach the engine gauges to a MetricsRegistry; updated once
         per scheduler step (never per token)."""
         self._gauges = register_engine_metrics(registry)
 
+    def _feed(self, name: str, total: float, **labels: str) -> None:
+        """Give counter ``name`` what its running total grew by since it
+        was last fed."""
+        key = (name, *labels.values())
+        grown = total - self._ctr_pushed.get(key, 0)
+        if grown > 0:
+            self._gauges[name].inc(grown, **labels)
+            self._ctr_pushed[key] = total
+
     def _update_gauges(self) -> None:
-        if self._gauges is None:
+        g = self._gauges
+        if g is None:
             return
-        (g_win, g_first, g_pad, c_prop, c_acc, g_rate, g_tpp,
-         g_kvb, g_kvq, c_tree, g_tree_depth, c_tier_prot, g_tier_hit,
-         g_gram_seqs, g_gram_mask, c_budget,
-         g_lora_res, c_lora_swap, g_lora_s, c_preempt,
-         c_g4_hit, c_g4_evict, c_g4_dedup) = self._gauges
-        g_kvb.set(self.args.kv_bytes_per_block() * self.args.num_kv_blocks)
-        g_kvq.set(1 if self.args.kv_quant == "int8" else 0)
-        g_win.set(sum(1 for it in self._fetchq if isinstance(it, _Window)))
-        g_first.set(sum(1 for it in self._fetchq if isinstance(it, _First)))
-        g_pad.set(self.total_prefill_padded / max(1, self.total_prefilled))
-        if self.total_spec_proposed > self._ctr_pushed[0]:
-            c_prop.inc(self.total_spec_proposed - self._ctr_pushed[0])
-            self._ctr_pushed[0] = self.total_spec_proposed
-        if self.total_spec_accepted > self._ctr_pushed[1]:
-            c_acc.inc(self.total_spec_accepted - self._ctr_pushed[1])
-            self._ctr_pushed[1] = self.total_spec_accepted
-        g_rate.set(self.total_spec_accepted / max(1, self.total_spec_proposed))
-        g_tpp.set(self.total_row_tokens / max(1, self.total_row_passes))
-        if self.total_spec_tree_passes > self._ctr_pushed[2]:
-            c_tree.inc(self.total_spec_tree_passes - self._ctr_pushed[2])
-            self._ctr_pushed[2] = self.total_spec_tree_passes
-        g_tree_depth.set(
+        feed = self._feed
+        g["engine_kv_cache_bytes"].set(self.args.kv_bytes_per_block() * self.args.num_kv_blocks)
+        g["engine_kv_quant_enabled"].set(1 if self.args.kv_quant == "int8" else 0)
+        g["engine_inflight_windows"].set(self._inflight_windows())
+        g["engine_pending_first_fetches"].set(
+            sum(1 for it in self._fetchq if isinstance(it, _First)))
+        g["engine_prefill_pad_ratio"].set(
+            self.total_prefill_padded / max(1, self.total_prefilled))
+        feed("engine_spec_proposed_total", self.total_spec_proposed)
+        feed("engine_spec_accepted_total", self.total_spec_accepted)
+        g["engine_spec_accept_rate"].set(
+            self.total_spec_accepted / max(1, self.total_spec_proposed))
+        g["engine_tokens_per_weight_pass"].set(
+            self.total_row_tokens / max(1, self.total_row_passes))
+        feed("engine_spec_tree_passes_total", self.total_spec_tree_passes)
+        g["engine_spec_tree_accept_depth"].set(
             self.total_spec_tree_depth / max(1, self.total_spec_tree_rows)
         )
-        prot = self.tiers.protected_evictions
-        if prot > self._ctr_pushed[3]:
-            c_tier_prot.inc(prot - self._ctr_pushed[3])
-            self._ctr_pushed[3] = prot
-        g_tier_hit.set(self.tiers.hit_rate)
-        g_gram_seqs.set(sum(1 for s in self._running if s.grammar is not None))
-        g_gram_mask.set(self.total_grammar_mask_s)
-        if self.total_spec_budget_reallocs > self._ctr_pushed[4]:
-            c_budget.inc(self.total_spec_budget_reallocs - self._ctr_pushed[4])
-            self._ctr_pushed[4] = self.total_spec_budget_reallocs
+        feed("tier_protected_evictions_total", self.tiers.protected_evictions)
+        g["tier_hit_rate"].set(self.tiers.hit_rate)
+        g["engine_grammar_active_seqs"].set(
+            sum(1 for s in self._running if s.grammar is not None))
+        g["engine_grammar_mask_seconds"].set(self.total_grammar_mask_s)
+        feed("engine_spec_budget_reallocs_total", self.total_spec_budget_reallocs)
         if self._lora_pool is not None:
-            g_lora_res.set(self._lora_pool.resident)
-            if self._lora_pool.pageins > self._ctr_pushed[5]:
-                c_lora_swap.inc(self._lora_pool.pageins - self._ctr_pushed[5])
-                self._ctr_pushed[5] = self._lora_pool.pageins
-        g_lora_s.set(self.total_lora_s)
+            g["engine_lora_resident_adapters"].set(self._lora_pool.resident)
+            feed("engine_lora_swap_total", self._lora_pool.pageins)
+        g["engine_lora_gather_seconds"].set(self.total_lora_s)
         if self.tiers.fleet is not None:
             fl = self.tiers.fleet
-            for i, (ctr, cur) in enumerate(
-                ((c_g4_hit, fl.hits), (c_g4_evict, fl.evictions),
-                 (c_g4_dedup, fl.dedup_blocks)), start=6,
-            ):
-                if cur > self._ctr_pushed[i]:
-                    ctr.inc(cur - self._ctr_pushed[i])
-                    self._ctr_pushed[i] = cur
+            feed("tier_g4_hits_total", fl.hits)
+            feed("tier_g4_evictions_total", fl.evictions)
+            feed("tier_g4_dedup_blocks_total", fl.dedup_blocks)
         for cls, n in self.total_preemptions_by.items():
-            pushed = self._preempt_pushed.get(cls, 0)
-            if n > pushed:
-                c_preempt.inc(n - pushed, **{"class": cls})
-                self._preempt_pushed[cls] = n
+            feed("engine_preemptions_total", n, **{"class": cls})
+        # What the step loop did with its time, and the decode rows and
+        # prompt blocks it paid for: counters, so that a scrape before and
+        # one after give a window's own figures.
+        for phase, secs in self.phase_s.items():
+            feed("engine_step_phase_seconds_total", secs, phase=phase)
+        feed("engine_sched_cpu_seconds_total", time.thread_time())
+        feed("engine_decode_row_steps_total", self.total_decode_rows_dispatched,
+             kind="dispatched")
+        feed("engine_decode_row_steps_total", self.total_decode_rows_emitted,
+             kind="emitted")
+        feed("kv_pool_hit_blocks_total", self.pool.hit_blocks)
+        feed("kv_pool_miss_blocks_total", self.pool.miss_blocks)
 
-    def _phase(self, key: str, t0: float) -> float:
-        """Accumulate perf_counter()-t0 into phase `key`; → new t0."""
+    def _phase_open(self, key: str) -> float:
+        """Begin step-loop phase `key` → its t0. Opens the profiler
+        annotation ``sched.<key>``, so that a profiler trace holds the
+        scheduler thread's phases on the clock of the device's
+        operations; one check and no record while no trace is taken."""
+        self._anno = TraceAnnotation("sched." + key)
+        return time.perf_counter()
+
+    def _phase(self, key: str, t0: float, then: str | None = None) -> float:
+        """Accumulate perf_counter()-t0 into phase `key` and close its
+        annotation; → new t0, the start of phase `then` if one follows
+        at once."""
         t1 = time.perf_counter()
         self.phase_s[key] += t1 - t0
         self.phase_n[key] += 1
+        if self._anno is not None:
+            self._anno.__exit__(None, None, None)
+        self._anno = TraceAnnotation("sched." + then) if then else None
         return t1
 
     @staticmethod
@@ -1213,21 +1281,29 @@ class TpuEngine:
                 if first:
                     first = False
                     if tracing.enabled() and context.trace is not None:
-                        # Queue/prefill phases from the scheduler thread's
-                        # admission stamp, recorded retroactively at first
-                        # delta; decode is live from here.
+                        # The first token's timeline from the scheduler
+                        # thread's stamps, recorded retroactively at first
+                        # delta; decode is live from here. engine.prefill
+                        # is admission to first delta: the sum of
+                        # engine.dispatch, engine.first_wait and
+                        # engine.deliver, most of it waiting, not compute.
                         now = time.perf_counter()
                         t_admit = seq.t_admit or now
-                        tracing.record_interval(
-                            "engine.queue", context.trace,
-                            start=t_submit, end=t_admit,
-                        )
-                        tracing.record_interval(
-                            "engine.prefill", context.trace,
-                            start=t_admit, end=now,
-                            prompt_tokens=seq.prompt_len,
-                            cached_blocks=seq.prefix_hit_blocks,
-                        )
+                        span = functools.partial(tracing.record_interval, parent=context.trace)
+                        span("engine.queue", start=t_submit, end=t_admit)
+                        if seq.t_blocked is not None:
+                            span("engine.blocked", start=seq.t_blocked, end=t_admit,
+                                 reason=seq.blocked_why)
+                        if seq.t_dispatched is not None and seq.t_first is not None:
+                            span("engine.dispatch", start=t_admit, end=seq.t_dispatched,
+                                 chunks=seq.chunks, wave=seq.wave[0],
+                                 windows_in_flight=seq.wave[1])
+                            span("engine.first_wait", start=seq.t_dispatched,
+                                 end=seq.t_first, blocked=seq.first_waited)
+                            span("engine.deliver", start=seq.t_first, end=now)
+                        span("engine.prefill", start=t_admit, end=now,
+                             prompt_tokens=seq.prompt_len,
+                             cached_blocks=seq.prefix_hit_blocks)
                         dspan = tracing.start_span(
                             "engine.decode", parent=context.trace
                         )
@@ -1247,7 +1323,7 @@ class TpuEngine:
         crashed = False
         try:
             while True:
-                t0 = time.perf_counter()
+                t0 = self._phase_open("idle")
                 with self._wakeup:
                     while (
                         not self._stopping
@@ -1344,7 +1420,7 @@ class TpuEngine:
         # longer inherit a blocking drain's worth of queueing delay).
         # The wave is budgeted to ~one max_prefill_tokens chunk so running
         # decodes are not starved by a long burst of arrivals.
-        t0 = time.perf_counter()
+        t0 = self._phase_open("admission")
         allocated: list[tuple[_Seq, int]] = []  # (seq, suffix start)
         wave_budget = self.args.admission_budget_tokens or (1 << 62)
         # Frozen mid-cutover sequences are out of _running but still hold
@@ -1365,6 +1441,7 @@ class TpuEngine:
                 start = self._admit_alloc(seq)
             except NoFreeBlocksError:
                 self._waiting.appendleft(seq)  # try again when blocks free up
+                self._stamp_blocked(seq, "blocks")
                 if not self._running and not allocated and not self._migrations:
                     # Deadlock: nothing to free. Fail the request.
                     # (A frozen migration is NOT a deadlock — its blocks
@@ -1385,7 +1462,17 @@ class TpuEngine:
                 continue
             seq.t_admit = time.perf_counter()
             allocated.append((seq, start))
-        t0 = self._phase("admission", t0)
+        if self._waiting and (
+            len(self._running) + len(allocated) + frozen >= self.args.max_num_seqs
+        ):
+            # The head of the queue waits for a slot. Finding it is a pass
+            # over _waiting, so look again only when the queue has changed
+            # (its length or either end: a backlog adds nothing to a step).
+            sig = (len(self._waiting), self._waiting[0], self._waiting[-1])
+            if sig != self._slots_blocked_sig:
+                self._slots_blocked_sig = sig
+                self._stamp_blocked(self._next_waiting(), "slots")
+        t0 = self._phase("admission", t0, then="prefill_dispatch" if allocated else None)
         admitted: list[tuple[_Seq, Any, int]] = []  # (seq, logits array, row)
         if allocated:
             try:
@@ -1396,7 +1483,12 @@ class TpuEngine:
                     self.pool.free_sequence(seq.block_ids)
                     seq.block_ids = []
                     self._finish(seq, FinishReason.ERROR, error=f"prefill failed: {e}")
-            t0 = self._phase("prefill_dispatch", t0)
+            t0 = self._phase("prefill_dispatch", t0,
+                             then="first_dispatch" if admitted else None)
+            # Every chunk of the wave is on the device's queue from here.
+            wave = (len(allocated), self._inflight_windows())
+            for seq, _ in allocated:
+                seq.t_dispatched, seq.wave = t0, wave
         if admitted:
             # Async admission: sample first tokens ON DEVICE, fold them
             # into each sequence's chain slot, and enqueue the host fetch
@@ -1665,9 +1757,19 @@ class TpuEngine:
         admission itself drains oldest-first), so min arrival IS the
         leftmost element and this selection is byte-identical to the
         popleft it replaces."""
-        best = max(self._waiting, key=lambda s: (s.qos_rank, -s.arrival))
+        best = self._next_waiting()
         self._waiting.remove(best)
         return best
+
+    def _next_waiting(self) -> _Seq:
+        return max(self._waiting, key=lambda s: (s.qos_rank, -s.arrival))
+
+    @staticmethod
+    def _stamp_blocked(seq: _Seq, why: str) -> None:
+        """The first admission attempt of `seq` that failed for want of a
+        slot or of blocks: where its ``engine.blocked`` span starts."""
+        if seq.t_blocked is None:
+            seq.t_blocked, seq.blocked_why = time.perf_counter(), why
 
     def _admit_alloc(self, seq: _Seq) -> int:
         """Phase 1 of admission: allocate KV blocks, resolve prefix hits
@@ -1835,6 +1937,7 @@ class TpuEngine:
         ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots)
         self.total_prefill_padded += Bp * t_pad
         for seq, start in members:
+            seq.chunks = 1
             self._finish_prefill_bookkeeping(seq, start)
         return ref
 
@@ -1853,7 +1956,7 @@ class TpuEngine:
         logits = None
         pos = start
         max_chunk = self.args.max_prefill_tokens
-        ci = 0
+        ci = n_chunks = 0
         while pos < plen:
             if chunks is not None:
                 n = chunks[ci]
@@ -1870,6 +1973,7 @@ class TpuEngine:
             )
             self.total_prefill_padded += t_pad
             pos += len(chunk)
+            n_chunks += 1
             # Streaming export: the blocks this chunk completed can ship
             # while the NEXT chunks compute — dispatch their gather with
             # an async D2H now, and harvest whatever earlier gathers
@@ -1882,6 +1986,7 @@ class TpuEngine:
                 if done > seq.export_pub_blocks:
                     self._start_export_extract(seq, seq.export_pub_blocks, done)
                 self._drain_export_fetches()
+        seq.chunks = n_chunks
         self._finish_prefill_bookkeeping(seq, start)
         assert logits is not None  # plen >= 1 → at least one chunk ran
         return logits
@@ -2535,17 +2640,19 @@ class TpuEngine:
 
     def _drain_first(self, f: _First, blocked: bool = True) -> None:
         """Fetch + emit one admission wave's first-token samples."""
-        t0 = time.perf_counter()
+        key = "first_sample" if blocked else "drain_ready"
+        t0 = self._phase_open(key)
         toks = np.asarray(f.out_d)
         lps = np.asarray(f.lps_d)
         tvals_l = tids_l = None
         if f.top_ref is not None:
             tvals_l = np.asarray(f.top_ref.arrs[0]).tolist()
             tids_l = np.asarray(f.top_ref.arrs[1]).tolist()
-        t0 = self._phase("first_sample" if blocked else "drain_ready", t0)
+        t0 = self._phase(key, t0, then="emit")
         toks_l, lps_l = toks.tolist(), lps.tolist()
         for seq, row in f.entries:
             seq.first_pend = False
+            seq.t_first, seq.first_waited = t0, blocked
             if seq.dead:
                 continue  # cancelled while the sample was in flight
             tops = None
@@ -2712,7 +2819,7 @@ class TpuEngine:
             if any(s.sampling.top_logprobs for s in batch) else 0
         )
         aslots = self._adapter_row_slots(batch, B)
-        t0 = time.perf_counter()
+        t0 = self._phase_open("decode_dispatch")
         ref = self._runner.multi_decode(
             K, mode, tokens, wchain, positions, tables, active,
             temps, seeds, steps0, tks, tps, freqs, press, pen, fold_slots,
@@ -2720,12 +2827,14 @@ class TpuEngine:
         )
         w = _Window(batch, pos0, K, ref, top_n)
         start_host_fetch(w.fetch_arrays())
+        self.total_decode_rows_dispatched += B * K
         self._phase("decode_dispatch", t0)
         return w
 
     def _drain_window(self, w: "_Window", blocked: bool = True) -> None:
         self.total_decode_steps += w.K
-        t0 = time.perf_counter()
+        key = "drain_sync" if blocked else "drain_ready"
+        t0 = self._phase_open(key)
         toks_np = np.asarray(w.ref.arrs[0])  # [K, B] — the one host fetch
         logps_np = np.asarray(w.ref.arrs[1])
         tvals_l = tids_l = None
@@ -2734,7 +2843,7 @@ class TpuEngine:
             # int()/float() at K·B·n scale was measurable emit cost).
             tvals_l = np.asarray(w.ref.arrs[2]).transpose(1, 0, 2).tolist()
             tids_l = np.asarray(w.ref.arrs[3]).transpose(1, 0, 2).tolist()
-        t0 = self._phase("drain_sync" if blocked else "drain_ready", t0)
+        t0 = self._phase(key, t0, then="emit")
         toks_l = toks_np.T.tolist()    # [B][K] python ints
         logps_l = logps_np.T.tolist()  # [B][K] python floats
         for i, seq in enumerate(w.rows):
@@ -2753,7 +2862,8 @@ class TpuEngine:
                     [list(p) for p in zip(tids_l[i][j][:n], tvals_l[i][j][:n])]
                     for j in range(w.K)
                 ]
-            self._emit_tokens(seq, toks_l[i], logps_l[i], tops)
+            self.total_decode_rows_emitted += self._emit_tokens(
+                seq, toks_l[i], logps_l[i], tops)
         self._phase("emit", t0)
 
     # -- speculative decoding ---------------------------------------------
@@ -2869,7 +2979,7 @@ class TpuEngine:
             for s in self._running:
                 if s.spec_cool > 0:
                     s.spec_cool -= 1
-        t0 = time.perf_counter()
+        t0 = self._phase_open("draft")
         drafts = self._draft_all(S)
         if not self._spec_gate_passes(drafts):
             self._phase("draft", t0)
@@ -2882,7 +2992,7 @@ class TpuEngine:
             self._drain_completed(force=True)
             if not self._running:
                 return True
-            t0 = time.perf_counter()
+            t0 = self._phase_open("draft")
             drafts = self._draft_all(S)
         t0 = self._phase("draft", t0)
         if not self._spec_gate_passes(drafts):
@@ -2894,6 +3004,7 @@ class TpuEngine:
         for seq in batch:
             if not self._ensure_block(seq, lookahead=len(drafts[seq]) + 1):
                 return False
+        self._phase_open("spec_dispatch")  # accounted from the draft's end (t0)
         B = self.args.bucket_decode(len(batch))
         # Verify-shape bucket: the uniform S+1 covers every draft at or
         # under the per-row allowance; an adaptive reallocation that let
@@ -3066,7 +3177,8 @@ class TpuEngine:
         self.total_spec_passes += 1
         if sp.tree:
             self.total_spec_tree_passes += 1
-        t0 = time.perf_counter()
+        key = "drain_sync" if blocked else "drain_ready"
+        t0 = self._phase_open(key)
         out_l = np.asarray(sp.ref.arrs[0]).tolist()     # [B][S1]
         n_emit_l = np.asarray(sp.ref.arrs[1]).tolist()  # [B]
         logps_l = np.asarray(sp.ref.arrs[2]).tolist()   # [B][S1]
@@ -3075,7 +3187,7 @@ class TpuEngine:
         if sp.top_n:
             tvals_l = np.asarray(sp.ref.arrs[4]).tolist()  # [B][S1][n]
             tids_l = np.asarray(sp.ref.arrs[5]).tolist()
-        t0 = self._phase("drain_sync" if blocked else "drain_ready", t0)
+        t0 = self._phase(key, t0, then="emit")
         alpha = self.args.spec_ema_alpha
         for i, seq in enumerate(sp.rows):
             if seq.dead:
@@ -3137,7 +3249,7 @@ class TpuEngine:
         self._drain_completed(force=True)
         if not self._running:
             return
-        t_start = time.perf_counter()
+        t_start = self._phase_open("single_step")
         batch = list(self._running)
         B = self.args.bucket_decode(len(batch))
         W = self.args.bucket_table(max(len(s.block_ids) for s in batch))
@@ -3155,6 +3267,7 @@ class TpuEngine:
             self._adapter_row_slots(batch, B),
         )
         self.total_decode_steps += 1
+        self.total_decode_rows_dispatched += B
         self.total_row_passes += len(batch)
         self.total_row_tokens += len(batch)
         # The step just wrote each sequence's KV at `positions[i]`.
@@ -3176,7 +3289,8 @@ class TpuEngine:
             if tvals is not None and seq.sampling.top_logprobs:
                 n = seq.sampling.top_logprobs
                 tops = [[[int(tids[i, r]), float(tvals[i, r])] for r in range(n)]]
-            self._emit_tokens(seq, [int(sampled[i])], [float(logps[i])], tops)
+            self.total_decode_rows_emitted += self._emit_tokens(
+                seq, [int(sampled[i])], [float(logps[i])], tops)
         self._phase("single_step", t_start)
 
     @staticmethod
@@ -3246,11 +3360,11 @@ class TpuEngine:
     # -- token emission / finish ------------------------------------------
 
     def _emit_tokens(self, seq: _Seq, toks: list[int], logps: list[float] | None = None,
-                     tops: list | None = None) -> None:
+                     tops: list | None = None) -> int:
         """Append sampled tokens (a multi-step window or a single token),
         truncating at the first stop condition. Posts ONE output delta with
         the kept tokens — tokens past a mid-window stop are wasted device
-        work, never surfaced."""
+        work, never surfaced. → the number of tokens kept."""
         kept: list[int] = []
         finish: FinishReason | None = None
         for token in toks:
@@ -3301,6 +3415,7 @@ class TpuEngine:
         )
         if finish is not None:
             self._finish(seq, finish, already_posted=True)
+        return len(kept)
 
     def _finish(
         self,

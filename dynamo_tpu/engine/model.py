@@ -332,7 +332,8 @@ def prefill_batch_impl(
     suffix_positions = start_pos[:, None] + sfx[None, :]          # [Bp, T]
 
     compute_dtype = params["layers"]["attn_norm"].dtype
-    x = _embed_rows(params, tokens, compute_dtype)  # [Bp, T, D]
+    with jax.named_scope("embed"):
+        x = _embed_rows(params, tokens, compute_dtype)  # [Bp, T, D]
 
     # Masks (fp32 additive), fixed for all layers.
     neg = jnp.float32(-1e9)
@@ -368,71 +369,75 @@ def prefill_batch_impl(
             lp, ll, layer_idx = xs
         else:
             (lp, layer_idx), ll = xs, None
-        h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv_lora(h, lp, cfg, ll, adapter_slots)
-        q = q.reshape(Bp, T, cfg.num_heads, hd)
-        k = k.reshape(Bp, T, KVH, hd)
-        v = v.reshape(Bp, T, KVH, hd)
-        q = _rope(q, suffix_positions, cfg.rope_theta)
-        k = _rope(k, suffix_positions, cfg.rope_theta)
+        with jax.named_scope("attn_qkv"):
+            h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _qkv_lora(h, lp, cfg, ll, adapter_slots)
+            q = q.reshape(Bp, T, cfg.num_heads, hd)
+            k = k.reshape(Bp, T, KVH, hd)
+            v = v.reshape(Bp, T, KVH, hd)
+            q = _rope(q, suffix_positions, cfg.rope_theta)
+            k = _rope(k, suffix_positions, cfg.rope_theta)
 
-        # Write all rows' suffix KV pages in one scatter (rows own
-        # disjoint blocks; duplicates only at garbage block 0).
-        # int8 storage: quantize at page-write time, scales ride a
-        # parallel scatter; the suffix still self-attends its exact
-        # register values below (only LATER readers see the rounding).
-        if k_scale is not None:
-            kq, ksc = kv_quantize(k)
-            vq, vsc = kv_quantize(v)
-            k_cache = k_cache.at[layer_idx, flat_ids].set(
-                kq.reshape(Bp * nb, bs, KVH * hd)
-            )
-            v_cache = v_cache.at[layer_idx, flat_ids].set(
-                vq.reshape(Bp * nb, bs, KVH * hd)
-            )
-            k_scale = k_scale.at[layer_idx, flat_ids].set(
-                ksc.reshape(Bp * nb, bs, KVH)
-            )
-            v_scale = v_scale.at[layer_idx, flat_ids].set(
-                vsc.reshape(Bp * nb, bs, KVH)
-            )
-        else:
-            k_cache = k_cache.at[layer_idx, flat_ids].set(
-                k.reshape(Bp * nb, bs, KVH * hd)
-            )
-            v_cache = v_cache.at[layer_idx, flat_ids].set(
-                v.reshape(Bp * nb, bs, KVH * hd)
-            )
+        with jax.named_scope("kv_write"):
+            # Write all rows' suffix KV pages in one scatter (rows own
+            # disjoint blocks; duplicates only at garbage block 0).
+            # int8 storage: quantize at page-write time, scales ride a
+            # parallel scatter; the suffix still self-attends its exact
+            # register values below (only LATER readers see the rounding).
+            if k_scale is not None:
+                kq, ksc = kv_quantize(k)
+                vq, vsc = kv_quantize(v)
+                k_cache = k_cache.at[layer_idx, flat_ids].set(
+                    kq.reshape(Bp * nb, bs, KVH * hd)
+                )
+                v_cache = v_cache.at[layer_idx, flat_ids].set(
+                    vq.reshape(Bp * nb, bs, KVH * hd)
+                )
+                k_scale = k_scale.at[layer_idx, flat_ids].set(
+                    ksc.reshape(Bp * nb, bs, KVH)
+                )
+                v_scale = v_scale.at[layer_idx, flat_ids].set(
+                    vsc.reshape(Bp * nb, bs, KVH)
+                )
+            else:
+                k_cache = k_cache.at[layer_idx, flat_ids].set(
+                    k.reshape(Bp * nb, bs, KVH * hd)
+                )
+                v_cache = v_cache.at[layer_idx, flat_ids].set(
+                    v.reshape(Bp * nb, bs, KVH * hd)
+                )
 
-        # Prefix pages (gathered dense, dequantized for int8 storage) +
-        # suffix (already in registers).
-        layer_k = lax.dynamic_index_in_dim(k_cache, layer_idx, 0, keepdims=False)
-        layer_v = lax.dynamic_index_in_dim(v_cache, layer_idx, 0, keepdims=False)
-        sk = sv = None
-        if k_scale is not None:
-            sk = lax.dynamic_index_in_dim(k_scale, layer_idx, 0, keepdims=False)
-            sv = lax.dynamic_index_in_dim(v_scale, layer_idx, 0, keepdims=False)
-        pk = gather_dequant_pages(layer_k, sk, block_tables, KVH, hd, x.dtype)
-        pv = gather_dequant_pages(layer_v, sv, block_tables, KVH, hd, x.dtype)
+        with jax.named_scope("attn"):
+            # Prefix pages (gathered dense, dequantized for int8 storage) +
+            # suffix (already in registers).
+            layer_k = lax.dynamic_index_in_dim(k_cache, layer_idx, 0, keepdims=False)
+            layer_v = lax.dynamic_index_in_dim(v_cache, layer_idx, 0, keepdims=False)
+            sk = sv = None
+            if k_scale is not None:
+                sk = lax.dynamic_index_in_dim(k_scale, layer_idx, 0, keepdims=False)
+                sv = lax.dynamic_index_in_dim(v_scale, layer_idx, 0, keepdims=False)
+            pk = gather_dequant_pages(layer_k, sk, block_tables, KVH, hd, x.dtype)
+            pv = gather_dequant_pages(layer_v, sv, block_tables, KVH, hd, x.dtype)
 
-        qg = q.reshape(Bp, T, KVH, G, hd)
-        # scores vs prefix pages / vs own suffix
-        s_p = jnp.einsum("btkgh,bckh->btkgc", qg, pk).astype(jnp.float32) * scale
-        s_s = jnp.einsum("btkgh,bskh->btkgs", qg, k).astype(jnp.float32) * scale
-        s_p = s_p + mask_sp[:, None, None, None, :]
-        s_s = s_s + mask_ss[:, :, None, None, :]
-        s = jnp.concatenate([s_p, s_s], axis=-1)
-        p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-        p_p, p_s = p[..., : W * bs], p[..., W * bs :]
-        o = (
-            jnp.einsum("btkgc,bckh->btkgh", p_p, pv)
-            + jnp.einsum("btkgs,bskh->btkgh", p_s, v)
-        )
-        o = o.reshape(Bp, T, cfg.q_size)
-        x = x + _wo_lora(o, lp, ll, adapter_slots)
-
-        h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(h, lp, cfg)
+            qg = q.reshape(Bp, T, KVH, G, hd)
+            # scores vs prefix pages / vs own suffix
+            s_p = jnp.einsum("btkgh,bckh->btkgc", qg, pk).astype(jnp.float32) * scale
+            s_s = jnp.einsum("btkgh,bskh->btkgs", qg, k).astype(jnp.float32) * scale
+            s_p = s_p + mask_sp[:, None, None, None, :]
+            s_s = s_s + mask_ss[:, :, None, None, :]
+            s = jnp.concatenate([s_p, s_s], axis=-1)
+            p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
+            p_p, p_s = p[..., : W * bs], p[..., W * bs :]
+            o = (
+                jnp.einsum("btkgc,bckh->btkgh", p_p, pv)
+                + jnp.einsum("btkgs,bskh->btkgh", p_s, v)
+            )
+            o = o.reshape(Bp, T, cfg.q_size)
+        with jax.named_scope("attn_out"):
+            x = x + _wo_lora(o, lp, ll, adapter_slots)
+        with jax.named_scope("ffn"):
+            h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            x = x + _ffn(h, lp, cfg)
         return (x, k_cache, v_cache, k_scale, v_scale), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
@@ -446,7 +451,8 @@ def prefill_batch_impl(
 
     last = jnp.clip(true_len - start_pos - 1, 0, T - 1)      # [Bp]
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [Bp, D]
-    logits = _logits(cfg, params, x_last)
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, x_last)
     return logits, KVCache(k_cache, v_cache, k_scale, v_scale)
 
 
@@ -512,7 +518,8 @@ def decode_step_impl(
     bs = cache.k.shape[2]
 
     compute_dtype = params["layers"]["attn_norm"].dtype
-    x = _embed_rows(params, tokens, compute_dtype)  # [B, D]
+    with jax.named_scope("embed"):
+        x = _embed_rows(params, tokens, compute_dtype)  # [B, D]
 
     blk = jnp.where(active, block_tables[jnp.arange(B), positions // bs], 0)
     off = jnp.where(active, positions % bs, 0)
@@ -527,46 +534,50 @@ def decode_step_impl(
             lp, ll, layer_idx = xs
         else:
             (lp, layer_idx), ll = xs, None
-        h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q, k, v = _qkv_lora(h, lp, cfg, ll, adapter_slots)
-        q = q.reshape(B, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(B, cfg.num_kv_heads, cfg.head_dim)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
-        qg = q.reshape(B, cfg.num_kv_heads, G, cfg.head_dim)
+        with jax.named_scope("attn_qkv"):
+            h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            q, k, v = _qkv_lora(h, lp, cfg, ll, adapter_slots)
+            q = q.reshape(B, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(B, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(B, cfg.num_kv_heads, cfg.head_dim)
+            q = _rope(q, positions, cfg.rope_theta)
+            k = _rope(k, positions, cfg.rope_theta)
+            qg = q.reshape(B, cfg.num_kv_heads, G, cfg.head_dim)
 
-        # In-place scatter of the new token's KV (inactive rows → garbage
-        # block 0), then paged attention over [0, positions]. int8
-        # storage quantizes the fresh row at write time, so this step's
-        # OWN token is read back dequantized — exactly what any later
-        # step would see, keeping the math write-order-independent.
-        if k_scale is not None:
-            kq, ksc = kv_quantize(k)
-            vq, vsc = kv_quantize(v)
-            k_cache = k_cache.at[layer_idx, blk, off].set(kq.reshape(B, cfg.kv_size))
-            v_cache = v_cache.at[layer_idx, blk, off].set(vq.reshape(B, cfg.kv_size))
-            k_scale = k_scale.at[layer_idx, blk, off].set(ksc)
-            v_scale = v_scale.at[layer_idx, blk, off].set(vsc)
-        else:
-            k_cache = k_cache.at[layer_idx, blk, off].set(k.reshape(B, cfg.kv_size))
-            v_cache = v_cache.at[layer_idx, blk, off].set(v.reshape(B, cfg.kv_size))
-        if impl == "xla":
-            o = paged_decode_attention_xla(
-                qg, k_cache, v_cache, layer_idx, block_tables, lengths,
-                k_scale, v_scale,
-            )
-        else:
-            o = paged_decode_attention(
-                qg, k_cache, v_cache, layer_idx, block_tables, lengths,
-                k_scale, v_scale,
-                interpret=(impl == "pallas_interpret"),
-            )
-        o = o.reshape(B, cfg.q_size)
-        x = x + _wo_lora(o, lp, ll, adapter_slots)
-
-        h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        x = x + _ffn(h, lp, cfg)
+        with jax.named_scope("kv_write"):
+            # In-place scatter of the new token's KV (inactive rows → garbage
+            # block 0), then paged attention over [0, positions]. int8
+            # storage quantizes the fresh row at write time, so this step's
+            # OWN token is read back dequantized — exactly what any later
+            # step would see, keeping the math write-order-independent.
+            if k_scale is not None:
+                kq, ksc = kv_quantize(k)
+                vq, vsc = kv_quantize(v)
+                k_cache = k_cache.at[layer_idx, blk, off].set(kq.reshape(B, cfg.kv_size))
+                v_cache = v_cache.at[layer_idx, blk, off].set(vq.reshape(B, cfg.kv_size))
+                k_scale = k_scale.at[layer_idx, blk, off].set(ksc)
+                v_scale = v_scale.at[layer_idx, blk, off].set(vsc)
+            else:
+                k_cache = k_cache.at[layer_idx, blk, off].set(k.reshape(B, cfg.kv_size))
+                v_cache = v_cache.at[layer_idx, blk, off].set(v.reshape(B, cfg.kv_size))
+        with jax.named_scope("attn"):
+            if impl == "xla":
+                o = paged_decode_attention_xla(
+                    qg, k_cache, v_cache, layer_idx, block_tables, lengths,
+                    k_scale, v_scale,
+                )
+            else:
+                o = paged_decode_attention(
+                    qg, k_cache, v_cache, layer_idx, block_tables, lengths,
+                    k_scale, v_scale,
+                    interpret=(impl == "pallas_interpret"),
+                )
+            o = o.reshape(B, cfg.q_size)
+        with jax.named_scope("attn_out"):
+            x = x + _wo_lora(o, lp, ll, adapter_slots)
+        with jax.named_scope("ffn"):
+            h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            x = x + _ffn(h, lp, cfg)
         return (x, k_cache, v_cache, k_scale, v_scale), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
@@ -578,7 +589,8 @@ def decode_step_impl(
         layer, (x, cache.k, cache.v, cache.k_scale, cache.v_scale), xs_in,
     )
 
-    logits = _logits(cfg, params, x)  # [B, V]
+    with jax.named_scope("logits"):
+        logits = _logits(cfg, params, x)  # [B, V]
     return logits, KVCache(k_cache, v_cache, k_scale, v_scale)
 
 
@@ -675,24 +687,25 @@ def multi_decode_impl(
             cfg, params, cache, tok, pos, block_tables, active,
             lora, adapter_slots, attn_impl=attn_impl,
         )
-        if mode == "greedy":
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        elif mode == "simple":
-            greedy = temperature < 1e-5
-            temp = jnp.where(greedy, 1.0, temperature)
-            scaled = logits / temp[:, None]
-            noisy = jnp.where(greedy[:, None], logits, scaled + row_gumbel(i))
-            nxt = jnp.argmax(noisy, axis=-1).astype(jnp.int32)
-        else:
-            penalized = apply_penalties(logits, counts, freq_penalty, pres_penalty)
-            nxt = sample_step(penalized, temperature, top_k, top_p, row_gumbel(i))
-            counts = counts.at[jnp.arange(B), nxt].add(1.0)
-        logp = token_logprobs(logits, nxt)
-        if top_n > 0:
-            tvals, tids = top_k_logprobs(logits, top_n)
-        else:
-            tvals = jnp.zeros((B, 0), jnp.float32)
-            tids = jnp.zeros((B, 0), jnp.int32)
+        with jax.named_scope("sample"):
+            if mode == "greedy":
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            elif mode == "simple":
+                greedy = temperature < 1e-5
+                temp = jnp.where(greedy, 1.0, temperature)
+                scaled = logits / temp[:, None]
+                noisy = jnp.where(greedy[:, None], logits, scaled + row_gumbel(i))
+                nxt = jnp.argmax(noisy, axis=-1).astype(jnp.int32)
+            else:
+                penalized = apply_penalties(logits, counts, freq_penalty, pres_penalty)
+                nxt = sample_step(penalized, temperature, top_k, top_p, row_gumbel(i))
+                counts = counts.at[jnp.arange(B), nxt].add(1.0)
+            logp = token_logprobs(logits, nxt)
+            if top_n > 0:
+                tvals, tids = top_k_logprobs(logits, top_n)
+            else:
+                tvals = jnp.zeros((B, 0), jnp.float32)
+                tids = jnp.zeros((B, 0), jnp.int32)
         return (cache, nxt, pos + 1, counts), (nxt, logp, tvals, tids)
 
     (cache, _, _, _), (toks, logps, top_vals, top_ids) = lax.scan(

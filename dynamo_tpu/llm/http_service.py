@@ -133,6 +133,13 @@ class HttpService:
             "deadline_expired_total",
             "Requests that ran out of budget, by enforcement point",
         )
+        self.m_loop_lag = scope.histogram(
+            "frontend_loop_lag_seconds",
+            "By how much a 50 ms sleep in this frontend's event loop "
+            "overslept: what any callback waits for the loop, and so "
+            "whether one Python frontend holds its workers back",
+        )
+        self._lag_task: asyncio.Task | None = None
         # SLO attribution plane: burn-rate EMAs fed by the ledger, read
         # back by the admission gate (burn-aware early rejection) and
         # exposed on /debug/slo — the planner/QoS evidence seam.
@@ -176,10 +183,20 @@ class HttpService:
             admin = web.TCPSite(self._runner, "127.0.0.1", self.admin_port)
             await admin.start()
             self.admin_port = admin._server.sockets[0].getsockname()[1]
+        self._lag_task = asyncio.get_running_loop().create_task(self._watch_loop_lag())
         log.info("http service listening on %s:%d", self.host, self.port)
         return self
 
+    async def _watch_loop_lag(self, period_s: float = 0.05) -> None:
+        while True:
+            t0 = time.perf_counter()
+            await asyncio.sleep(period_s)
+            self.m_loop_lag.observe(max(0.0, time.perf_counter() - t0 - period_s))
+
     async def close(self) -> None:
+        if self._lag_task is not None:
+            self._lag_task.cancel()
+            self._lag_task = None
         if self._runner is not None:
             await self._runner.cleanup()
 

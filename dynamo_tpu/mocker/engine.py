@@ -16,6 +16,7 @@ flat until a cliff. A ``speedup`` divides everything for fast tests.
 from __future__ import annotations
 
 import asyncio
+import functools
 import time
 from dataclasses import dataclass
 from typing import Any, AsyncIterator
@@ -105,10 +106,18 @@ class MockerEngine:
         qspan = tracing.start_span_if(
             context.trace, "engine.queue", waiting=self._waiting
         )
+        # The TPU engine's first-token timeline, with what a simulator
+        # knows: blocked = every slot was taken when the request arrived.
+        t_blocked = time.perf_counter() if self._slots.locked() else None
         try:
             await self._slots.acquire()
             acquired = True
             qspan.end()
+            if t_blocked is not None and context.trace is not None:
+                tracing.record_interval(
+                    "engine.blocked", context.trace, start=t_blocked,
+                    end=time.perf_counter(), reason="slots",
+                )
             self._waiting -= 1
             self._active += 1
             try:
@@ -128,6 +137,7 @@ class MockerEngine:
         bs = a.block_size
         prompt = req.token_ids
         plen = len(prompt)
+        t_admit = time.perf_counter()
         max_hit = (plen - 1) // bs
         hashes = compute_block_hashes(prompt, bs)[:max_hit]
         total_blocks = (plen + bs - 1) // bs
@@ -149,13 +159,23 @@ class MockerEngine:
             ttft = (a.ttft_ms + a.prefill_ms_per_token * uncached) * (
                 1.0 + a.prefill_contention * slot_frac
             )
+            t_disp = time.perf_counter()
             with tracing.start_span_if(
                 context.trace, "engine.prefill",
                 prompt_tokens=plen, uncached_tokens=uncached, cached_blocks=n_hit,
             ):
                 await asyncio.sleep(a.scaled(ttft))
+                t_first = time.perf_counter()
                 for i, blk in enumerate(block_seq.blocks):
                     self.pool.register_block(block_ids[i], blk.sequence_hash, blk.parent_sequence_hash)
+            if context.trace is not None:
+                # dispatch = the block allocation, first_wait = the
+                # simulated prefill, deliver = registering its blocks.
+                span = functools.partial(tracing.record_interval, parent=context.trace)
+                span("engine.dispatch", start=t_admit, end=t_disp,
+                     chunks=1, wave=1, windows_in_flight=0)
+                span("engine.first_wait", start=t_disp, end=t_first, blocked=True)
+                span("engine.deliver", start=t_first, end=time.perf_counter())
             dspan = tracing.start_span_if(context.trace, "engine.decode")
 
             max_tokens = req.stop.max_tokens or 64

@@ -12,6 +12,7 @@ Prometheus scrape format.
 from __future__ import annotations
 
 import math
+import os
 import threading
 from dataclasses import dataclass, field
 
@@ -248,4 +249,12 @@ class MetricsRegistry:
         for m in sorted(metrics, key=lambda m: m.name):
             lines.extend(m.render(with_header=m.name not in seen_names))
             seen_names.add(m.name)
+        # The process's own CPU time, under the name every Prometheus client
+        # gives it: read at render, so nothing on a hot path keeps it.
+        cpu = os.times()
+        lines += [
+            "# HELP process_cpu_seconds_total Total user and system CPU time spent in seconds.",
+            "# TYPE process_cpu_seconds_total counter",
+            f"process_cpu_seconds_total {_fmt_value(cpu.user + cpu.system)}",
+        ]
         return "\n".join(lines) + "\n"

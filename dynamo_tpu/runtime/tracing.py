@@ -74,6 +74,10 @@ PHASE_SPANS = {
     "router.attempt": "route",
     "wire.call": "wire",
     "engine.queue": "queue_wait",
+    # Admission to first delta: the sum of engine.dispatch,
+    # engine.first_wait and engine.deliver (which are not ledger phases of
+    # their own, or the ledger would count that time twice), and mostly
+    # waiting behind decode windows, not prefill compute.
     "engine.prefill": "prefill",
     "engine.decode": "decode",
     # Disagg data plane (llm/disagg.py): dispatch + streamed KV pull.
@@ -405,14 +409,7 @@ def _env_enabled() -> bool:
     )
 
 
-_recorder: SpanRecorder | None = (
-    SpanRecorder(
-        capacity=int(os.environ.get("DYNTPU_TRACING_CAPACITY", "4096")),
-        ledger_capacity=int(os.environ.get("DYNTPU_TRACING_LEDGER", "1024")),
-    )
-    if _env_enabled()
-    else None
-)
+_recorder: SpanRecorder | None = SpanRecorder() if _env_enabled() else None
 
 
 def recorder() -> SpanRecorder | None:

@@ -1,0 +1,341 @@
+"""The first token's timeline as spans, the step loop and the decode rows as
+counters, the names the benchmark's reducers search for, and the scheduler's
+phases in a profiler trace (ISSUE 26). CPU, test-tiny."""
+
+import asyncio
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine import model as M
+from dynamo_tpu.engine.config import EngineArgs, ModelConfig
+from dynamo_tpu.engine.engine import TpuEngine
+from dynamo_tpu.llm.protocols import PreprocessedRequest
+from dynamo_tpu.mocker.engine import MockerArgs, MockerEngine
+from dynamo_tpu.ops import paged_attention
+from dynamo_tpu.runtime import tracing
+from dynamo_tpu.runtime.engine import Context
+from dynamo_tpu.runtime.metrics import MetricsRegistry
+
+CFG = ModelConfig()  # test-tiny
+TIMELINE = ("engine.dispatch", "engine.first_wait", "engine.deliver")
+
+
+@pytest.fixture
+def fresh_recorder():
+    rec = tracing.SpanRecorder(capacity=512, ledger_capacity=8)
+    prev = tracing.set_recorder(rec)
+    yield rec
+    tracing.set_recorder(prev)
+
+
+def make_args(**kw) -> EngineArgs:
+    defaults = dict(
+        model=CFG, block_size=4, num_kv_blocks=64, max_num_seqs=4,
+        max_model_len=128, max_prefill_tokens=64, dtype="float32",
+    )
+    defaults.update(kw)
+    return EngineArgs(**defaults)
+
+
+def greedy_request(prompt, max_tokens=8) -> PreprocessedRequest:
+    req = PreprocessedRequest(model="t", token_ids=list(prompt))
+    req.sampling.temperature = 0.0
+    req.sampling.seed = 0
+    req.stop.max_tokens = max_tokens
+    return req
+
+
+async def serve(engine, prompts, max_tokens=8, traced=True):
+    """Serve ``prompts`` at once, each under a ``wire.serve`` span of its own
+    as the endpoint server would open. → [(wire.serve span | None, outputs)]."""
+    async def one(prompt):
+        ws = tracing.start_span("wire.serve") if traced else None
+        ctx = Context(trace=ws.trace_context() if traced else None)
+        outs = [o async for o in engine.generate(greedy_request(prompt, max_tokens), ctx)]
+        if ws is not None:
+            ws.end()
+        return ws, outs
+
+    return await asyncio.gather(*(one(p) for p in prompts))
+
+
+async def settle(engine) -> None:
+    """Two empty jobs on the scheduler thread: the second runs in a step
+    after the one whose ``_update_gauges`` pushed the last counters."""
+    await engine.run_on_engine_thread(lambda: None)
+    await engine.run_on_engine_thread(lambda: None)
+
+
+def tokens_of(outs) -> list[int]:
+    return [t for o in outs for t in o.get("token_ids", [])]
+
+
+def spans_under(rec, ws) -> dict[str, list]:
+    by_name: dict[str, list] = {}
+    for s in rec.spans(ws.trace_id):
+        if s.parent_id == ws.span_id:
+            by_name.setdefault(s.name, []).append(s)
+    return by_name
+
+
+def test_timeline_spans_nest_under_wire_serve_and_sum_to_prefill(fresh_recorder):
+    async def go():
+        engine = await TpuEngine(make_args()).start()
+        try:
+            return await serve(engine, [range(1, 20), range(30, 45)], max_tokens=6)
+        finally:
+            await engine.stop()
+
+    for ws, outs in asyncio.run(go()):
+        assert len(tokens_of(outs)) == 6
+        got = spans_under(fresh_recorder, ws)
+        for name in ("engine.queue", "engine.prefill", "engine.decode", *TIMELINE):
+            assert len(got.get(name, [])) == 1, (name, sorted(got))
+        assert "engine.blocked" not in got
+        parts = sum(got[name][0].duration_s for name in TIMELINE)
+        assert parts == pytest.approx(got["engine.prefill"][0].duration_s, abs=1e-3)
+        assert all(got[name][0].duration_s >= 0 for name in TIMELINE)
+        disp = got["engine.dispatch"][0].attrs
+        assert disp["chunks"] == 1 and disp["wave"] in (1, 2) and disp["windows_in_flight"] >= 0
+        assert got["engine.first_wait"][0].attrs["blocked"] in (True, False)
+        # first_wait starts where dispatch ends, deliver where first_wait ends
+        d, w = got["engine.dispatch"][0], got["engine.first_wait"][0]
+        assert d.start_ts + d.duration_s == pytest.approx(w.start_ts, abs=5e-3)
+
+
+def test_a_long_prompt_counts_its_chunks(fresh_recorder):
+    async def go():
+        engine = await TpuEngine(make_args(max_prefill_tokens=16)).start()
+        try:
+            return await serve(engine, [range(1, 41)], max_tokens=2)
+        finally:
+            await engine.stop()
+
+    (ws, _outs), = asyncio.run(go())
+    assert spans_under(fresh_recorder, ws)["engine.dispatch"][0].attrs["chunks"] == 3
+
+
+@pytest.mark.parametrize("kw, n_prompts, reason", [
+    (dict(num_kv_blocks=10), 2, "blocks"),   # 9 usable blocks, a prompt takes 6 and grows
+    # Two slots and three prompts: one slot would compile test_engine_qos's shapes
+    # in this process, and that test's timing counts on compiling them itself.
+    (dict(max_num_seqs=2), 3, "slots"),
+    (dict(), 2, None),
+])
+def test_blocked_span_only_when_admission_had_to_wait(fresh_recorder, kw, n_prompts, reason):
+    async def go():
+        engine = await TpuEngine(make_args(**kw)).start()
+        try:
+            # Both requests arrive while the scheduler thread is held in a
+            # job, so one step sees both: the first is admitted, the second
+            # meets the full slot or the short pool whatever the machine's load.
+            hold = asyncio.ensure_future(engine.run_on_engine_thread(lambda: time.sleep(0.3)))
+            await asyncio.sleep(0.05)
+            prompts = [range(1, 25), range(40, 64), range(70, 94)][:n_prompts]
+            served = await serve(engine, prompts, max_tokens=8)
+            await hold
+            return served
+        finally:
+            await engine.stop()
+
+    served = asyncio.run(go())
+    blocked = []
+    for ws, outs in served:
+        assert len(tokens_of(outs)) == 8 and outs[-1]["finish_reason"] == "length"
+        got = spans_under(fresh_recorder, ws)
+        blocked += got.get("engine.blocked", [])
+        assert sum(got[n][0].duration_s for n in TIMELINE) == pytest.approx(
+            got["engine.prefill"][0].duration_s, abs=1e-3)
+    if reason is None:
+        assert blocked == []
+    else:
+        assert [s.attrs["reason"] for s in blocked] == [reason]
+        # blocked lies inside the queue wait of the request that was held back
+        ws = next(w for w, _ in served if w.trace_id == blocked[0].trace_id)
+        queue = spans_under(fresh_recorder, ws)["engine.queue"][0]
+        assert 0 < blocked[0].duration_s <= queue.duration_s + 1e-3
+
+
+def test_recorder_off_serves_the_same_and_records_nothing():
+    async def go(traced):
+        engine = await TpuEngine(make_args()).start()
+        try:
+            return await serve(engine, [range(1, 20)], max_tokens=6, traced=traced)
+        finally:
+            await engine.stop()
+
+    rec = tracing.SpanRecorder(capacity=64)
+    prev = tracing.set_recorder(rec)
+    try:
+        (_ws, with_rec), = asyncio.run(go(True))
+        n_spans = len(rec.spans())
+        tracing.set_recorder(None)
+        (_none, without), = asyncio.run(go(False))
+        assert tokens_of(without) == tokens_of(with_rec)
+        assert len(rec.spans()) == n_spans  # no stamp was turned into a span
+    finally:
+        tracing.set_recorder(prev)
+
+
+def counter(reg: MetricsRegistry, name: str, **labels) -> float:
+    return reg.counter(name).value(**labels)
+
+
+def test_step_loop_and_decode_row_counters(fresh_recorder):
+    reg = MetricsRegistry()
+    prompts = [range(1, 20), range(30, 45), range(50, 59)]
+    bs = 4
+
+    async def go():
+        engine = TpuEngine(make_args(block_size=bs))
+        engine.bind_metrics(reg)
+        await engine.start()
+        try:
+            first = await serve(engine, prompts[:1], max_tokens=7)
+            await settle(engine)
+            mid = {k: counter(reg, "engine_decode_row_steps_total", kind=k)
+                   for k in ("dispatched", "emitted")}
+            rest = await serve(engine, prompts[1:], max_tokens=11)
+            await settle(engine)
+            return first + rest, mid, engine
+        finally:
+            await engine.stop()
+
+    served, mid, engine = asyncio.run(go())
+    streamed = sum(len(tokens_of(outs)) for _, outs in served)
+    assert streamed == 7 + 2 * 11
+    emitted = counter(reg, "engine_decode_row_steps_total", kind="emitted")
+    dispatched = counter(reg, "engine_decode_row_steps_total", kind="dispatched")
+    # every token but a request's first (the admission sample) came out of a decode window
+    assert emitted == streamed - len(served)
+    assert 0 < mid["emitted"] == 7 - 1 and mid["dispatched"] < dispatched
+    assert emitted <= dispatched and dispatched % engine.args.decode_buckets[0] == 0
+
+    phases = {p: counter(reg, "engine_step_phase_seconds_total", phase=p) for p in engine.phase_s}
+    assert {"idle", "admission", "prefill_dispatch", "decode_dispatch", "emit"} <= set(phases)
+    assert all(v > 0 for v in phases.values())
+    for p, secs in engine.phase_s.items():
+        assert phases[p] <= secs  # pushed once a step
+    cpu = counter(reg, "engine_sched_cpu_seconds_total")
+    assert 0 < cpu <= sum(engine.phase_s.values()) + 60.0
+
+    hit = counter(reg, "kv_pool_hit_blocks_total")
+    miss = counter(reg, "kv_pool_miss_blocks_total")
+    assert hit + miss == sum((len(p) - 1) // bs for p in prompts)
+    text = reg.render()
+    assert 'dynamo_tpu_engine_decode_row_steps_total{kind="emitted"}' in text
+    assert "\nprocess_cpu_seconds_total " in text
+
+
+def test_pool_counts_hits_and_misses_across_an_eviction():
+    async def go():
+        # 15 usable blocks: the second distinct prompt evicts the first one's cached blocks.
+        engine = await TpuEngine(make_args(num_kv_blocks=16)).start()
+        try:
+            await serve(engine, [range(1, 34)], max_tokens=2, traced=False)
+            await serve(engine, [range(1, 34)], max_tokens=2, traced=False)   # 8 blocks hit
+            hits = engine.pool.hit_blocks
+            await serve(engine, [range(100, 140)], max_tokens=2, traced=False)
+            await serve(engine, [range(1, 34)], max_tokens=2, traced=False)   # evicted: all miss
+            return hits, engine.pool.hit_blocks, engine.pool.miss_blocks
+        finally:
+            await engine.stop()
+
+    hits, hits_after, misses = asyncio.run(go())
+    assert hits == hits_after == 8 and misses == 8 + 9 + 8
+
+
+def test_mocker_records_the_same_timeline(fresh_recorder):
+    async def go():
+        engine = MockerEngine(MockerArgs(block_size=4, num_kv_blocks=64, speedup=200.0,
+                                         max_num_seqs=1))
+        return await serve(engine, [range(1, 20), range(30, 50)], max_tokens=4)
+
+    served = asyncio.run(go())
+    reasons = []
+    for ws, _outs in served:
+        got = spans_under(fresh_recorder, ws)
+        for name in ("engine.queue", "engine.prefill", "engine.decode", *TIMELINE):
+            assert len(got.get(name, [])) == 1, (name, sorted(got))
+        parts = sum(got[name][0].duration_s for name in TIMELINE)
+        assert parts == pytest.approx(got["engine.prefill"][0].duration_s, abs=2e-3)
+        reasons += [s.attrs["reason"] for s in got.get("engine.blocked", [])]
+    assert reasons == ["slots"]  # one slot: the second request found it taken
+
+
+# -- the names the benchmark's reducers search for ------------------------------
+
+
+def scoped(lowered_text: str, scope: str) -> bool:
+    """Some operation's name in the lowered program lies under ``scope``."""
+    return f'"{scope}/' in lowered_text or f"/{scope}/" in lowered_text
+
+
+def test_jitted_steps_and_kernel_keep_the_names_the_reducers_search_for():
+    # chipbench/trace_reduce.py and the readers find the programs by
+    # "multi_decode", "prefill_batch", "prefill" in jit_<name> and the kernel
+    # by "paged_decode_attention"; none of them may be edited with the program.
+    assert M.multi_decode.__name__ == "multi_decode_impl"
+    assert M.prefill_batch.__name__ == "prefill_batch_impl"
+    assert M.prefill.__name__ == "prefill_impl"
+    assert paged_attention.paged_decode_attention.__name__ == "paged_decode_attention"
+
+    params = M.init_params(CFG, jax.random.PRNGKey(0), jnp.float32)
+    cache = M.init_kv_cache(CFG, 16, 4, jnp.float32)
+    B, W, K = 8, 4, 2
+    z = np.zeros((B,), np.int32)
+    lowered = M.multi_decode.lower(
+        CFG, K, "greedy", 0, params, cache, jnp.asarray(z), jnp.asarray(z),
+        jnp.zeros((B, W), jnp.int32), jnp.zeros((B,), bool), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.uint32), jnp.asarray(z), jnp.asarray(z), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.float32), jnp.full((B, 1), -1, jnp.int32),
+        jnp.zeros((B,), bool), jnp.asarray(z), jnp.zeros((5,), jnp.int32), None, None,
+        attn_impl="pallas_interpret",
+    )
+    text = lowered.as_text(debug_info=True)
+    assert "jit_multi_decode_impl" in text
+    assert "paged_decode_attention" in text
+    for scope in ("embed", "attn_qkv", "kv_write", "attn", "attn_out", "ffn", "logits", "sample"):
+        assert scoped(text, scope), scope
+
+    toks = jnp.zeros((2, 8), jnp.int32)
+    lowered = M.prefill_batch.lower(
+        CFG, params, cache, toks, jnp.zeros((2, W), jnp.int32),
+        jnp.zeros((2,), jnp.int32), jnp.full((2,), 5, jnp.int32), None, None)
+    text = lowered.as_text(debug_info=True)
+    assert "jit_prefill_batch_impl" in text
+    for scope in ("embed", "attn_qkv", "kv_write", "attn", "attn_out", "ffn", "logits"):
+        assert scoped(text, scope), scope
+
+
+def test_a_profiler_trace_holds_the_scheduler_phases(tmp_path):
+    from chipbench import trace_reduce
+
+    async def go():
+        engine = await TpuEngine(make_args()).start()
+        try:
+            await serve(engine, [range(1, 12)], max_tokens=4, traced=False)  # compile first
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                await serve(engine, [range(20, 40), range(50, 65)], max_tokens=10, traced=False)
+            finally:
+                jax.profiler.stop_trace()
+        finally:
+            await engine.stop()
+
+    asyncio.run(go())
+    doc = trace_reduce.load_events(str(tmp_path))
+    spans = [e for p in doc["planes"] for ln in p["lines"] for e in ln["events"]
+             if e[0].startswith("sched.")]
+    names = {e[0] for e in spans}
+    assert {"sched.idle", "sched.admission", "sched.prefill_dispatch", "sched.first_dispatch",
+            "sched.decode_dispatch", "sched.emit"} <= names, names
+    assert names & {"sched.drain_sync", "sched.drain_ready"}
+    assert all(e[2] >= 0 for e in spans)
+    # the phases of one thread do not overlap: each closes before the next opens
+    one = sorted((e[1], e[1] + e[2]) for e in spans)
+    assert all(a[1] <= b[0] + 1e3 for a, b in zip(one, one[1:]))

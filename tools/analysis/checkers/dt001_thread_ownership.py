@@ -222,5 +222,5 @@ _GLOBAL_OWNED = frozenset({
     "_embed_jobs", "_host_jobs", "_offload_pending", "_exports",
     "_export_fetches", "_drafter", "_step_no", "_spec_ticked",
     "phase_s", "phase_n", "_ctr_pushed", "_spec_depth_hist",
-    "_migrations",
+    "_migrations", "_anno", "_slots_blocked_sig",
 })
