@@ -410,14 +410,8 @@ def prefill_batch_impl(
         with jax.named_scope("attn"):
             # Prefix pages (gathered dense, dequantized for int8 storage) +
             # suffix (already in registers).
-            layer_k = lax.dynamic_index_in_dim(k_cache, layer_idx, 0, keepdims=False)
-            layer_v = lax.dynamic_index_in_dim(v_cache, layer_idx, 0, keepdims=False)
-            sk = sv = None
-            if k_scale is not None:
-                sk = lax.dynamic_index_in_dim(k_scale, layer_idx, 0, keepdims=False)
-                sv = lax.dynamic_index_in_dim(v_scale, layer_idx, 0, keepdims=False)
-            pk = gather_dequant_pages(layer_k, sk, block_tables, KVH, hd, x.dtype)
-            pv = gather_dequant_pages(layer_v, sv, block_tables, KVH, hd, x.dtype)
+            pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, x.dtype)
+            pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, x.dtype)
 
             qg = q.reshape(Bp, T, KVH, G, hd)
             # scores vs prefix pages / vs own suffix

@@ -18,8 +18,11 @@ Design notes (measured on v5e, see tools/profile_decode.py):
   native dense layout (a 5D [.., KVH, hd] layout forced a whole-cache
   relayout copy per pallas_call — ~9ms/layer measured on v5e, the reason
   the cache is stored heads-merged). The layer index is a scalar-prefetch
-  operand, which also
-  removes the per-layer ``dynamic_slice`` copies the gather path needs.
+  operand, so no layer of the pool is ever sliced out. The gather path
+  (``gather_dequant_pages``: prefill's prefix read and the XLA decode and
+  spec-verify below) no longer slices one either: it gathers pages from
+  the stacked pool by (layer, page). Until PR 28 it took a
+  ``dynamic_slice`` of the layer first, a copy of all N pages of it.
 - Grid ``(B, CMAX)``: chunk c of row b processes up to P pages.
   Cross-step software pipelining: every live step issues the DMAs of the
   *next* live step (double-buffered), so page fetch overlaps compute
@@ -99,23 +102,35 @@ def spec_kernel_fits(num_heads: int, positions: int) -> bool:
 
 
 def gather_dequant_pages(
-    layer_cache: jax.Array,   # [N, bs, KVH*hd] — one layer's pages
-    layer_scale: jax.Array | None,  # [N, bs, KVH] fp32 | None
+    cache: jax.Array,         # [L, N, bs, KVH*hd] — the stacked pool
+    scale: jax.Array | None,  # [L, N, bs, KVH] fp32 | None
+    layer_idx: jax.Array,     # scalar int32
     block_tables: jax.Array,  # [B, W] int32
     KVH: int, hd: int, dtype,
 ):
-    """Gather a batch's pages out of the pool and (for int8 storage)
-    dequantize with the per-position-per-head scales → [B, W*bs, KVH, hd]
-    in ``dtype``. The int8→float convert rides the gather output, so the
-    materialized copy stays half the bf16 path's bytes on the read side
-    (the write side — the gather itself — is what the Pallas kernels
-    remove entirely)."""
+    """Gather a batch's pages of one layer straight out of the stacked
+    pool and (for int8 storage) dequantize with the per-position-per-head
+    scales → [B, W*bs, KVH, hd] in ``dtype``. This is the one way the XLA
+    paths read pages, and no array of one layer of the pool is formed on
+    the way: slicing the layer out first copied all N pages of it (84 MB
+    at 5,120 blocks, 102 us at the HBM roofline) to read a few hundred.
+    What is materialized is the gathered ``[B, W, bs, KVH*hd]``, which
+    the Pallas kernels avoid too; the int8→float convert rides the
+    gather output, so that copy stays half the bf16 path's bytes."""
     B, W = block_tables.shape
-    bs = layer_cache.shape[1]
-    pages = layer_cache[block_tables].reshape(B, W * bs, KVH, hd)
-    if layer_scale is None:
+    L, N, bs, _ = cache.shape
+    # Pages: the pool viewed as [L*N, bs, KVH*hd], a bitcast of its dense
+    # layout, and one index a page. ``cache[layer_idx, block_tables]`` is
+    # copy-free too, but packs a two-component index vector every layer:
+    # 5.27 against 4.83 ms for 28 layers of K and V on v5e (PERF.md, PR 28).
+    pages = cache.reshape(L * N, bs, KVH * hd)[layer_idx * N + block_tables]
+    pages = pages.reshape(B, W * bs, KVH, hd)
+    if scale is None:
         return pages
-    sc = layer_scale[block_tables].reshape(B, W * bs, KVH)
+    # Scales: both indices at once. The chip lays their KVH-wide rows out
+    # position-minor, so the flat view is no bitcast there: it compiles to
+    # a relayout copy of the whole scale pool in every layer.
+    sc = scale[layer_idx, block_tables].reshape(B, W * bs, KVH)
     # Dequantize in f32 and round ONCE into ``dtype`` — multiplying in
     # bf16 would read the same stored byte back as a different value
     # than the Pallas kernel / host adapters (which also widen to f32),
@@ -138,14 +153,8 @@ def paged_decode_attention_xla(
     dequantizes in the same fused expression.  Returns [B, KVH, G, hd]
     in q.dtype."""
     B, KVH, G, hd = q.shape
-    layer_k = lax.dynamic_index_in_dim(k_cache, layer_idx, 0, keepdims=False)
-    layer_v = lax.dynamic_index_in_dim(v_cache, layer_idx, 0, keepdims=False)
-    sk = sv = None
-    if k_scale is not None:
-        sk = lax.dynamic_index_in_dim(k_scale, layer_idx, 0, keepdims=False)
-        sv = lax.dynamic_index_in_dim(v_scale, layer_idx, 0, keepdims=False)
-    pk = gather_dequant_pages(layer_k, sk, block_tables, KVH, hd, q.dtype)
-    pv = gather_dequant_pages(layer_v, sv, block_tables, KVH, hd, q.dtype)
+    pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, q.dtype)
+    pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
     scale = hd ** -0.5
     ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
     mask = jnp.where(ctx[None, :] < lengths[:, None], 0.0, jnp.float32(NEG_INF))
@@ -189,14 +198,8 @@ def paged_spec_attention_xla(
     the Pallas upgrade: the gather+dequant happen in-register, no
     materialized relayout copy.)"""
     B, T, KVH, G, hd = q.shape
-    layer_k = lax.dynamic_index_in_dim(k_cache, layer_idx, 0, keepdims=False)
-    layer_v = lax.dynamic_index_in_dim(v_cache, layer_idx, 0, keepdims=False)
-    sk = sv = None
-    if k_scale is not None:
-        sk = lax.dynamic_index_in_dim(k_scale, layer_idx, 0, keepdims=False)
-        sv = lax.dynamic_index_in_dim(v_scale, layer_idx, 0, keepdims=False)
-    pk = gather_dequant_pages(layer_k, sk, block_tables, KVH, hd, q.dtype)
-    pv = gather_dequant_pages(layer_v, sv, block_tables, KVH, hd, q.dtype)
+    pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, q.dtype)
+    pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
     scale = hd ** -0.5
     ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
     hist_mask = ctx[None, None, :] < lengths[:, :, None]    # [B, T, W*bs]

@@ -275,6 +275,32 @@ def test_engine_prefix_cache_hit_and_same_output():
     asyncio.run(go())
 
 
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_engine_cached_prefix_token_ids_pinned(kv_quant):
+    """A fixed prompt at temperature 0, served twice and then forked after
+    five blocks: the second and third answers are prefilled over cached
+    pages. The ids are the parent commit's (c34b524, recorded before PR 28
+    changed how prefill reads prefix pages out of the pool)."""
+    async def go():
+        engine = await TpuEngine(make_args(kv_quant=kv_quant)).start()
+        try:
+            prompt = [(7 * i + 3) % 97 + 1 for i in range(27)]
+            fork = prompt[:20] + [200 + 3 * i for i in range(9)]
+            a = await run_one(engine, greedy_request(prompt, 6))
+            assert engine.pool.hit_blocks == 0
+            b = await run_one(engine, greedy_request(prompt, 6))
+            assert engine.pool.hit_blocks == 6
+            c = await run_one(engine, greedy_request(fork, 6))
+            assert engine.pool.hit_blocks == 11
+            assert collect_tokens(a) == [495, 228, 109, 109, 109, 109]
+            assert collect_tokens(b) == [495, 228, 109, 109, 109, 109]
+            assert collect_tokens(c) == [109, 109, 109, 109, 109, 237]
+        finally:
+            await engine.stop()
+
+    asyncio.run(go())
+
+
 def test_engine_eos_stops_generation():
     async def go():
         engine = await TpuEngine(make_args()).start()

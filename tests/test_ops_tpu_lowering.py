@@ -15,6 +15,7 @@ passed every test and could not be lowered for a TPU at all. Two levels:
 from __future__ import annotations
 
 import functools
+import re
 
 import pytest
 
@@ -109,18 +110,23 @@ def test_kernel_unsupported_agrees_with_the_compiler(v5e):
         assert kernel_unsupported(ModelConfig.preset(preset), BS) is None
 
 
+def _int8_params(cfg: ModelConfig, S):
+    """The shapes of ``cfg``'s int8 weights, as ``S`` makes them."""
+    from dynamo_tpu.engine.quant import random_int8_params_device
+
+    return jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: random_int8_params_device(cfg, 0)),
+    )
+
+
 def test_multi_decode_window_compiles_for_v5e(v5e):
     """One decode window of the smoke model (chip_smoke.py: qwen2-7b int8,
     full width and depth, 16 rows over a 4096-token table) through the
     compiled kernel: the whole jitted step, not the kernel alone."""
-    from dynamo_tpu.engine.quant import random_int8_params_device
-
     cfg = ModelConfig.preset("qwen2-7b")
     S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
-    params = jax.tree.map(
-        lambda a: S(a.shape, a.dtype),
-        jax.eval_shape(lambda: random_int8_params_device(cfg, 0)),
-    )
+    params = _int8_params(cfg, S)
     B, K, W, N = 16, 8, 4096 // BS, 4096
     pages = S((cfg.num_layers, N, BS, cfg.kv_size), jnp.bfloat16)
     i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
@@ -137,3 +143,47 @@ def test_multi_decode_window_compiles_for_v5e(v5e):
     # a 16 GB chip beside them.
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_prefill_forms_no_layer_of_the_pool_on_v5e(v5e, kv_quant):
+    """What the chip's compiler makes of prefill's prefix read (PR 28):
+    at the sessions cell's widths (qwen2-7b int8, 5,120 blocks, 4 rows of
+    128 tokens over a 256-page table) no instruction's result is one layer
+    of the KV pool, or of its scales, and nothing but the in-place scatters
+    has the pool's own shape. The parent's layer slice compiled to
+    ``dynamic-slice_bitcast_fusion bf16[5120,16,512]``, an 84 MB copy twice
+    a layer; a flat view of the int8 scales compiles to a copy of the whole
+    scale pool every layer. The jaxpr (tests/test_prefill_page_gather.py)
+    shows neither."""
+    cfg = ModelConfig.preset("qwen2-7b")
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    N, Bp, T, W = 5120, 4, 128, 256
+    cache = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: M.init_kv_cache(cfg, N, BS, kv_quant=kv_quant)),
+    )
+    i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
+    hlo = M.prefill_batch.lower(
+        cfg, _int8_params(cfg, S), cache, i32(Bp, T), i32(Bp, W), i32(Bp), i32(Bp)
+    ).compile().as_text()
+
+    L, kv, KVH = cfg.num_layers, cfg.kv_size, cfg.num_kv_heads
+    layer = {f"[{N},{BS},{kv}]", f"[1,{N},{BS},{kv}]", f"[{N},{BS},{KVH}]", f"[1,{N},{BS},{KVH}]"}
+    pool = {f"[{L},{N},{BS},{kv}]", f"[{L * N},{BS},{kv}]", f"[{L},{N},{BS},{KVH}]",
+            f"[{L * N},{BS},{KVH}]"}
+    found, entry = [], False
+    for line in hlo.splitlines():
+        if re.match(r"(ENTRY )?%?\S+ \(.*\) -> .* \{$", line):  # a computation's header
+            entry = line.startswith("ENTRY")
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = \S*?(\[[\d,]*\])\S* ([\w-]+)\(", line)
+        if not m or m.group(2) in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+            continue
+        shape, opcode = m.groups()
+        # Inside the layer loop the pool's shape belongs to the in-place
+        # scatters (and the fusions that wrap them) alone. The entry may
+        # re-lay the int8 scale pools out once a call, as the parent's does.
+        if shape in layer or (shape in pool and not entry and opcode not in ("scatter", "fusion")):
+            found.append(line.strip()[:120])
+    assert not found, "\n".join(found)
