@@ -39,15 +39,18 @@ def new_record(kind: str, due: float | None, prompt_tokens: int, max_tokens: int
     return {"kind": kind, "due": due, "sent": None, "first": None, "last": None,
             "chunks": [], "status": "cut", "prompt_tokens": prompt_tokens,
             "max_tokens": max_tokens, "usage": None, "finish_reason": None,
-            "error": None, "answer": []}
+            "error": None, "prompt": None, "answer": []}
 
 
 async def complete(session: aiohttp.ClientSession, url: str, model: str, prompt: list[int],
-                   rec: dict, deadline_s: float, keep_answer: bool = False) -> dict:
-    """One streamed completion into ``rec``. Never raises, except for the
-    cancellation that cuts it at the end of the window."""
+                   rec: dict, deadline_s: float) -> dict:
+    """One streamed completion into ``rec``, which keeps the prompt and the
+    token ids that came back: the next turn of a session resends them, and
+    ``parity.py`` holds a sample of them against the reference. Never raises,
+    except for the cancellation that cuts it at the end of the window."""
     body = {"model": model, "prompt": prompt, "max_tokens": rec["max_tokens"],
             "temperature": 0, "ignore_eos": True, "stream": True}
+    rec["prompt"] = prompt
     rec["sent"] = time.monotonic()
     try:
         timeout = aiohttp.ClientTimeout(total=deadline_s, sock_connect=CONNECT_TIMEOUT_S)
@@ -74,8 +77,7 @@ async def complete(session: aiohttp.ClientSession, url: str, model: str, prompt:
                         rec["first"] = now
                     rec["last"] = now
                     rec["chunks"].append((now, len(ids)))
-                    if keep_answer:
-                        rec["answer"] += ids
+                    rec["answer"] += ids
                 if choice.get("finish_reason"):
                     rec["finish_reason"] = choice["finish_reason"]
                     rec["last"] = rec["last"] or now
@@ -138,10 +140,9 @@ async def run_closed(session, url, model, plan: dict, t0: float, seconds: float,
             rec = new_record("closed", None, len(prompt), turn["max_tokens"])
             rec["history_tokens"] = len(history)  # sent before: the prefix cache may hold it
             records.append(rec)
-            await complete(session, url, model, prompt, rec, seconds + 30.0, keep_answer=True)
+            await complete(session, url, model, prompt, rec, seconds + 30.0)
             # A failed turn leaves a shorter history; the session goes on.
             history = prompt + rec["answer"]
-            rec["answer"] = []
         rec_done.append(1)
 
     rec_done: list[int] = []

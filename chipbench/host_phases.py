@@ -27,6 +27,7 @@ process that owns the chip. ``attribute_dir`` does that in a child.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import subprocess
@@ -103,6 +104,15 @@ def attribute(doc: dict, device_index: int = 0, top: int = 20) -> dict:
     }
 
 
+def label_gaps(report: dict, top: int = 10) -> list[list]:
+    """[[label, seconds], ...] for the report's longest gaps: the phase that
+    covers most of a gap, ``no_sched_phase`` where none covers any of it."""
+    out = []
+    for _start_s, dur_s, phases in report["gaps"][:top]:
+        out.append([max(phases, key=phases.get) if phases else "no_sched_phase", dur_s])
+    return out
+
+
 def table(report: dict) -> str:
     idle = report["idle_s"]
     share = 100.0 * report["idle_host_busy_s"] / idle if idle else 0.0
@@ -125,9 +135,11 @@ def table(report: dict) -> str:
     return "\n".join(lines)
 
 
+@functools.lru_cache(maxsize=4)
 def attribute_dir(trace_dir: str, timeout_s: float = 240.0) -> dict | None:
     """``attribute`` of the trace under ``trace_dir``, computed in a child
-    that is held to the CPU. None when there is no trace or the child fails."""
+    that is held to the CPU. None when there is no trace or the child fails.
+    A run asks twice (a reader and the breakdown) and pays once."""
     if not os.path.isdir(trace_dir):
         return None
     with tempfile.TemporaryDirectory() as tmp:

@@ -5,7 +5,8 @@
 
 The only place the benchmark reaches into the program. The worker CLI takes
 a preset name or a real checkpoint and nothing else, so this wrapper builds
-a ``ModelConfig`` from the configuration's file, makes
+a ``ModelConfig`` from the configuration's file through the map the file names
+(``model_maps/<model_map>.py``), makes
 ``ModelConfig.preset(<name>)`` return it, and calls the worker's ``main()``
 unchanged: same scheduler, block manager, runner, kernels and endpoint.
 
@@ -19,7 +20,8 @@ the wrapper also answers two signals from the harness:
              next SIGUSR2 stops it and then writes ``trace_<rank>.done``.
 
 Both are debts listed in PERF.md: configurations as files and a profiler
-hook belong in the program.
+hook belong in the program. Nothing here names a model key: which keys a kind
+of block has is the map's to say.
 """
 
 from __future__ import annotations
@@ -34,32 +36,6 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-
-# Published key in the configuration's file -> ModelConfig field.
-MODEL_KEYS = {
-    "vocab_size": "vocab_size",
-    "hidden_size": "hidden_size",
-    "intermediate_size": "intermediate_size",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "rms_norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
-
-
-def model_fields(doc: dict) -> dict:
-    """ModelConfig keyword arguments from a configuration file's document."""
-    out = {field: doc[key] for key, field in MODEL_KEYS.items()}
-    assumed = doc.get("assumed", {})
-    out["head_dim"] = doc.get("head_dim") or assumed.get("head_dim") or (
-        doc["hidden_size"] // doc["num_attention_heads"])
-    out["attn_bias"] = bool(assumed.get("qkv_bias", False))
-    out["max_position"] = int(doc["served"]["max_model_len"])
-    out["name"] = doc["name"]
-    return out
-
 
 class Monitor:
     """Counts JAX's compile requests and cache hits; answers the signals."""
@@ -158,9 +134,14 @@ def main(argv: list[str]) -> int:
         doc = json.load(f)
     os.makedirs(out_dir, exist_ok=True)
 
+    from chipbench import lookup, model_maps
     from dynamo_tpu.engine.config import ModelConfig
 
-    model = ModelConfig(**model_fields(doc))
+    try:
+        model = model_maps.model_config(doc)
+    except lookup.Missing as e:
+        print(f"chipbench launcher: {e}", file=sys.stderr, flush=True)
+        return 2
     original = ModelConfig.preset
 
     def preset(name: str) -> ModelConfig:

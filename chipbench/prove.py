@@ -23,7 +23,9 @@ sets nothing.
 
 ``--sets S --runs R`` runs S sets of R runs with the same seeds in each set.
 Every result line goes to ``chiprun_out/prove_<cell>.jsonl`` as it comes, the
-children's logs of the last run to ``chiprun_out/logs/<cell>/``.
+children's logs of the last run to ``chiprun_out/logs/<cell>/``, and those of
+every run that is not correct to ``chiprun_out/logs/<cell>/<set>_<run>/``: the
+next run overwrites them where they were written.
 """
 
 from __future__ import annotations
@@ -93,6 +95,17 @@ def summary(by_set: dict, bounds: dict[str, float], aa: bool) -> list[str]:
     return out
 
 
+def keep_logs(workload: str, sub: str, dst: str) -> None:
+    """Copy what a run's children wrote (logs, stats, the sample and the reference's log)."""
+    src = os.path.join(ROOT, "chipbench_out", workload, sub)
+    if not os.path.isdir(src):
+        return
+    os.makedirs(dst, exist_ok=True)
+    for name in os.listdir(src):
+        if os.path.isfile(os.path.join(src, name)) and os.path.getsize(os.path.join(src, name)) < 8 << 20:
+            shutil.copy(os.path.join(src, name), dst)
+
+
 def main(argv: list[str]) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--workload", required=True)
@@ -158,6 +171,9 @@ def main(argv: list[str]) -> int:
         if proc.returncode != 0:
             print(proc.stderr[-1500:], flush=True)
             print("\n".join(lines[-15:]), flush=True)
+        if not row.get("result", {}).get("correct"):
+            keep_logs(o.workload, "trace" if trace else "plain",
+                      os.path.join(out_dir, "logs", o.workload, f"{set_no}_{i}"))
     if o.events_ms and o.traced:
         a, b = o.events_ms.split(",")
         subprocess.run([sys.executable, os.path.join(HERE, "trace_reduce.py"),
@@ -166,13 +182,7 @@ def main(argv: list[str]) -> int:
                         os.path.join(out_dir, "trace_small.json"), a, b],
                        env={**os.environ, "JAX_PLATFORMS": "cpu"}, check=False)
     for sub in ("plain", "trace"):
-        src = os.path.join(ROOT, "chipbench_out", o.workload, sub)
-        if os.path.isdir(src):
-            dst = os.path.join(out_dir, "logs", o.workload, sub)
-            os.makedirs(dst, exist_ok=True)
-            for name in os.listdir(src):
-                if os.path.isfile(os.path.join(src, name)) and os.path.getsize(os.path.join(src, name)) < 8 << 20:
-                    shutil.copy(os.path.join(src, name), dst)
+        keep_logs(o.workload, sub, os.path.join(out_dir, "logs", o.workload, sub))
     for line in summary(by_set, bounds_of(o.workload), o.aa is not None):
         print(line, flush=True)
     return 0

@@ -5,14 +5,18 @@
 
 One new process that starts store, worker(s), frontend and metrics exporter
 as children (this parent never imports JAX), warms the cell's shapes up,
-measures for ``--seconds``, stops every child, prints one JSON object as the
-last line of its output and exits 0.
+measures for ``--seconds``, stops every child, holds a sample of what the
+window served against the configuration's plain reference in one more child
+on the freed chip (``parity.py``), prints one JSON object as the last line of
+its output and exits 0.
 
 What may end a run with another code: no TPU where the cell needs one (the
 worker's start line is the probe), a checkout without the program, or a bug
 in the harness. A request that fails, times out or is refused, a scrape that
-fails, a compile inside the window, a child that dies or exits badly: each
-is a count or ``correct: false`` in the result line, never an exception.
+fails, a compile inside the window, a child that dies or exits badly, served
+tokens that the reference would not have put first by more than the
+configuration's limits: each is a count or ``correct: false`` in the result
+line, never an exception.
 
 ``--rehearse`` (the driver never passes it) runs the same code path on the
 CPU at a toy size: for debugging control flow here, not for numbers. Its
@@ -43,7 +47,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import arith, client, generators  # noqa: E402
+from chipbench import arith, client, generators, host_phases, lookup, model_maps, parity  # noqa: E402
 from chipbench.layer_metrics import gauge_samples, gauge_series  # noqa: E402
 from chipbench.procs import Stack, free_port, log  # noqa: E402
 
@@ -63,6 +67,7 @@ EXIT_NO_PROGRAM, EXIT_NO_CHIP, EXIT_NO_STACK = 2, 3, 4
 WORKER_START_S = 900.0   # a cold start makes 7.6 GB of weights and may compile
 WARM_REQUEST_S = 900.0   # any warm-up request may wait for a cold compile
 TRACE_S = 3.0            # length of the profiler's window in a traced run
+PARITY_CHILD_S = 120.0   # the reference over a run's sample: half a minute warm, a minute more while it compiles
 
 
 class NoResult(Exception):
@@ -76,30 +81,44 @@ class NoResult(Exception):
 # -- the cell's files ----------------------------------------------------------
 
 
+def load_json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
 def load_cell(workload: str, rehearse: bool) -> dict:
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    """The cell's entry, configuration, mix and metrics. ``rehearse`` puts the
+    toy that the configuration names for the CPU in the configuration's place."""
+    bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
         raise NoResult(EXIT_NO_PROGRAM, f"no workload {workload!r} in BENCHMARK.json; "
                        f"have {sorted(cells)}")
     cell = cells[workload]
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
-    cfg_file = os.path.join(ROOT, cfg_entry["file"])
-    if rehearse:
-        cfg_file = os.path.join(HERE, "configs", "rehearse-tiny.json")
-    with open(cfg_file) as f:
-        config = json.load(f)
-    if rehearse:  # the toy model, in the cell's own layout of replicas
-        with open(os.path.join(ROOT, cfg_entry["file"])) as f:
-            config["replicas"] = json.load(f).get("replicas", 1)
-    with open(os.path.join(HERE, "traffic", cell["traffic"] + ".json")) as f:
-        traffic = json.load(f)
+    try:
+        cfg_file = os.path.join(ROOT, cfg_entry["file"])
+        config = load_json(cfg_file)
+        if rehearse:  # the toy the configuration names, in the cell's own layout of replicas
+            if not config.get("rehearse"):
+                raise lookup.Missing(f"configuration {config['name']!r} names no \"rehearse\" file; "
+                                     f"there are {lookup.names('configs', '.json')}")
+            cfg_file = lookup.find("configs", config["rehearse"], ".json")
+            config = {**load_json(cfg_file), "replicas": config.get("replicas", 1)}
+        model_maps.fields(config)  # a file without a map, or with one that is not there, ends here
+        traffic = load_json(lookup.find("traffic", cell["traffic"], ".json"))
+        # What correct compares: the configuration says how (rows, and the toy its own limits);
+        # the limits are the cell's, in a file of its own that a PR adds with the cell.
+        limits = dict(config.get("parity") or {})
+        if not rehearse and workload in lookup.names("limits", ".json"):
+            limits.update(load_json(lookup.find("limits", workload, ".json")))
+    except lookup.Missing as e:
+        raise NoResult(EXIT_NO_PROGRAM, str(e)) from None
 
     def mine(metrics: list[dict]) -> list[dict]:
         return [m for m in metrics if workload in m.get("workloads", [workload])]
 
-    return {"cell": cell, "config": config, "config_file": cfg_file, "traffic": traffic,
+    return {"cell": cell, "config": config, "config_file": cfg_file, "traffic": traffic, "parity": limits,
             "end_to_end": mine(bench["end_to_end"]), "per_layer": mine(bench["per_layer"])}
 
 
@@ -123,12 +142,13 @@ def write_tokenizer(path: str, vocab_size: int) -> None:
 def engine_args(config: dict):
     """The program's own ``EngineArgs`` for this configuration's flags: its
     bucket tables and its prefill planner, without JAX."""
-    from chipbench.launch_worker import model_fields
-    from dynamo_tpu.engine.config import ModelConfig
     from dynamo_tpu.worker.__main__ import _engine_args, parse_args
 
     args = parse_args(worker_flags(config, "tcp://127.0.0.1:1", "/nonexistent"))
-    return _engine_args(args, ModelConfig(**model_fields(config)))
+    try:
+        return _engine_args(args, model_maps.model_config(config))
+    except lookup.Missing as e:
+        raise NoResult(EXIT_NO_PROGRAM, str(e)) from None
 
 
 def prefill_shapes(eargs, cached: int, suffix: int) -> frozenset:
@@ -206,9 +226,12 @@ class Run:
     def __init__(self, opts: argparse.Namespace, spec: dict):
         self.opts, self.spec = opts, spec
         self.config, self.traffic = spec["config"], dict(spec["traffic"])
-        for item in opts.param:
+        for item in opts.param:  # of the mix, or "served.<key>" of the configuration's served block
             key, _, value = item.partition("=")
-            self.traffic[key] = json.loads(value)
+            if key.startswith("served."):
+                self.config = {**self.config, "served": {**self.config["served"], key[7:]: json.loads(value)}}
+            else:
+                self.traffic[key] = json.loads(value)
         self.name = self.config["name"]
         self.replicas = int(self.config.get("replicas", 1))
         self.out_dir = os.path.join(OUT_ROOT, opts.workload, "trace" if opts.trace else "plain")
@@ -223,7 +246,8 @@ class Run:
         self.start_lines: list[dict] = []
         self.trace_marks: dict = {}
         self.decode_buckets: list[int] = []
-        self.phases: dict[str, float] = {}   # set-up's parts, seconds
+        self.phases: dict[str, float] = {}   # set-up's parts, seconds; parity_s lies after the window
+        self.parity: dict | None = None      # what compare() read, beside its limits
 
     def incorrect(self, why: str) -> None:
         self.notes.append(why)
@@ -460,30 +484,35 @@ class Run:
             await self.wait_listed(session)
             await self.warm_up(session, plan)
             await self.prefill_sessions(session, plan)
-            self.snapshot(0)
-            await self.scrape(session, "before")
-            url = self.url("http", "/v1/completions")
-            seconds = self.opts.seconds
-            t0 = time.monotonic() + 0.05
-            self.t0, self.t0_unix = t0, time.time() + 0.05
-            side = [asyncio.ensure_future(self.poll_gauges(session, t0))]
-            if self.opts.trace:
-                side.append(asyncio.ensure_future(self.tracer(t0)))
-            log(f"window opens: set-up took {t0 - T_PROCESS_START:.1f} s")
-            if plan["mode"] == "open":
-                await client.run_open(session, url, self.name, plan["requests"], t0, seconds,
-                                      self.records)
-            else:
-                await client.run_closed(session, url, self.name, plan, t0, seconds, self.records)
-            self.t_end = t0 + seconds
-            for task in side[:1]:
-                task.cancel()
-            await asyncio.gather(*side, return_exceptions=True)
-            log(f"window closed after {time.monotonic() - t0:.2f} s")
-            await self.scrape(session, "after")
-            if self.opts.trace:
-                self.await_traces()  # the launcher's thread is busy until the trace is written
-            self.snapshot(1)
+            return await self.window(session, plan)
+
+    async def window(self, session, plan: dict, k: int = 0) -> float:
+        """The measured window: snapshots ``k`` and ``k + 1`` around it.
+        Returns the seconds from the process's start to its opening."""
+        self.snapshot(k)
+        await self.scrape(session, "before")
+        url = self.url("http", "/v1/completions")
+        seconds = self.opts.seconds
+        t0 = time.monotonic() + 0.05
+        self.t0, self.t0_unix = t0, time.time() + 0.05
+        side = [asyncio.ensure_future(self.poll_gauges(session, t0))]
+        if self.opts.trace:
+            side.append(asyncio.ensure_future(self.tracer(t0)))
+        log(f"window opens: set-up took {t0 - T_PROCESS_START:.1f} s")
+        if plan["mode"] == "open":
+            await client.run_open(session, url, self.name, plan["requests"], t0, seconds,
+                                  self.records)
+        else:
+            await client.run_closed(session, url, self.name, plan, t0, seconds, self.records)
+        self.t_end = t0 + seconds
+        for task in side[:1]:
+            task.cancel()
+        await asyncio.gather(*side, return_exceptions=True)
+        log(f"window closed after {time.monotonic() - t0:.2f} s")
+        await self.scrape(session, "after")
+        if self.opts.trace:
+            self.await_traces()  # the launcher's thread is busy until the trace is written
+        self.snapshot(k + 1)
         return t0 - T_PROCESS_START
 
     def await_traces(self) -> None:
@@ -517,6 +546,33 @@ class Run:
             log(f"trace reduction failed: {type(e).__name__}: {e}")
             return None
 
+    def compare(self) -> None:
+        """Hold a sample of what the window finished against the
+        configuration's reference (``parity.py``), in a child on the freed
+        chip. Over a limit, no sample, or a child that fails: not correct."""
+        limits = self.spec["parity"]
+        if not limits.get("rows") or not self.config.get("reference"):
+            self.incorrect(f"parity: configuration {self.name!r} names no reference or no parity rows; "
+                           f"the references there are: {lookup.names('references', '.py')}")
+            return
+        t_p = time.monotonic()
+        seqs = parity.sequences(self.records)
+        sample, _ = parity.pick_sample(seqs, self.opts.seed, int(limits["rows"]), int(self.config["served"]["max_model_len"]))
+        doc, why = parity_child(self.spec["config_file"], {"run": sample}, self.out_dir, self.opts.rehearse,
+                                self.opts.parity_weights_seed) if sample else (None, None)
+        read = (doc or {}).get("groups", {}).get("run", {"tokens": 0})
+        platform = (doc or {}).get("platform")
+        if doc and platform != ("cpu" if self.opts.rehearse else "tpu"):
+            why = f"the reference ran on {platform!r}, not on the chip the worker left"
+        whys = [why] if why else parity.verdict(read, limits)
+        self.phases["parity_s"] = time.monotonic() - t_p
+        self.parity = {**{k: v for k, v in read.items() if k != "per_row"}, "limits": {k: limits.get(k) for k in parity.JUDGED},
+                       "finished": len(seqs), "ok": not whys, "platform": platform,
+                       "child_s": (doc or {}).get("seconds"), "weights_s": (doc or {}).get("weights_s")}
+        for w in whys:
+            self.incorrect(f"parity: {w}")
+        log("parity: " + json.dumps(self.parity))
+
     def judge(self, digests: tuple[str, str]) -> None:
         if self.opts.rehearse:
             self.incorrect("--rehearse: a CPU run at a toy size is never correct")
@@ -549,6 +605,42 @@ class Run:
 
 
 # -- metrics -------------------------------------------------------------------
+
+
+def parity_child(config_file: str, groups: dict, out_dir: str, rehearse: bool,
+                 weights_seed: int | None = None,
+                 timeout_s: float = PARITY_CHILD_S) -> tuple[dict | None, str | None]:
+    """``parity.py`` over ``groups`` in a child that owns the chip (the CPU
+    under ``--rehearse``). (its document, None), or (None, why there is none):
+    never an exception. A child that dies at once may have found the chip
+    still held by the worker that just stopped: once more after 5 s.
+    ``weights_seed`` (a control) gives the reference other weights than the
+    configuration's."""
+    sample, out = os.path.join(out_dir, "parity_sample.json"), os.path.join(out_dir, "parity.json")
+    with open(sample, "w") as f:
+        json.dump({"groups": groups}, f)
+    env = {**os.environ, **({"JAX_PLATFORMS": "cpu"} if rehearse else {})}
+    cmd = [sys.executable, os.path.join(HERE, "parity.py"), config_file, sample, out]
+    if weights_seed is not None:
+        cmd += ["--weights-seed", str(weights_seed)]
+    why = None
+    for attempt in (1, 2):
+        if os.path.exists(out):
+            os.remove(out)
+        t = time.monotonic()
+        try:
+            with open(os.path.join(out_dir, "parity.log"), "a") as err:
+                rc = subprocess.run(cmd, env=env, timeout=timeout_s, stdout=err, stderr=err).returncode
+        except subprocess.TimeoutExpired:
+            return None, f"the reference's child ran over {timeout_s:g} s"
+        if rc == 0 and os.path.exists(out):
+            return load_json(out), None
+        why = f"the reference's child ended with code {rc}; see parity.log"
+        if attempt == 2 or time.monotonic() - t > 60:
+            break
+        log(f"{why}; once more in 5 s")
+        time.sleep(5.0)
+    return None, why
 
 
 def end_to_end(run: Run, setup_s: float) -> dict[str, float]:
@@ -606,7 +698,8 @@ def unjudged(run: Run, e2e: dict[str, float], ctx: dict) -> dict:
              "ttft_over_1500ms": sum(1 for x in ttft if x > 1.5),
              "resent_block_share": 100.0 * held / sent if sent else None}
     return {"setup_phases": dict(run.phases), "batch": batch,
-            "end_to_end_all": {k: v for k, v in e2e.items() if math.isfinite(v)}}
+            "end_to_end_all": {k: v for k, v in e2e.items() if math.isfinite(v)},
+            **({"parity": run.parity} if run.parity is not None else {})}
 
 
 def per_layer(run: Run, names: list[str], ctx: dict) -> dict[str, float]:
@@ -626,22 +719,13 @@ def per_layer(run: Run, names: list[str], ctx: dict) -> dict[str, float]:
 
 
 def breakdown(run: Run, trace: dict) -> dict:
-    """The device operations that took most time, and the longest idle gaps
-    named by what the harness knows of them: how many requests were in
-    flight. Host phases inside the program are not annotated yet."""
-    gaps = []
-    for start_s, dur_s in trace.get("idle_gaps", [])[:10]:
-        t = None
-        if trace.get("t_start_unix") is not None:
-            t = trace["t_start_unix"] + start_s - run.t0_unix + run.t0
-        if t is None:
-            label = "unattributed"
-        else:
-            n = sum(1 for r in run.records
-                    if r["sent"] is not None and r["sent"] <= t
-                    and (r["last"] is None or r["last"] >= t) and r["status"] != "failed")
-            label = f"requests_in_flight={n}" if n else "no_request_in_flight"
-        gaps.append([label, dur_s])
+    """The device operations that took most time, and the longest idle gaps,
+    each named by the ``sched.*`` phase the scheduler thread spent most of it
+    in (``host_phases.py`` on rank 0's trace); ``unattributed`` where the
+    trace could not be read for phases."""
+    report = host_phases.attribute_dir(os.path.join(run.out_dir, "trace_0"))
+    gaps = host_phases.label_gaps(report) if report else [
+        ["unattributed", dur_s] for _, dur_s in trace.get("idle_gaps", [])[:10]]
     return {"device_ops": [[n, s] for n, s in trace.get("top_ops", [])[:10]], "idle_gaps": gaps}
 
 
@@ -671,8 +755,14 @@ def parse(argv: list[str]) -> argparse.Namespace:
     p.add_argument("--seconds", type=float, default=None)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--rehearse", action="store_true", help="CPU, toy size, never correct")
+    p.add_argument("--parity", type=int, choices=(0, 1), default=1,
+                   help="0 (sweep.py only): do not hold the served tokens against the reference")
+    p.add_argument("--parity-weights-seed", type=int, default=None,
+                   help="a control: the reference makes its weights from another seed than the worker")
     p.add_argument("--param", action="append", default=[], metavar="KEY=JSON",
-                   help="sweep.py, prove.py: override one parameter of the mix (rate_rps=6, clients=64)")
+                   help="sweep.py, prove.py, parity_seeds.py: override one parameter of the mix (rate_rps=6) or, "
+                        "as served.KEY, of the configuration's served block (a control: "
+                        "'served.extra_flags=[\"--kv-quant\",\"int8\"]')")
     return p.parse_args(argv)
 
 
@@ -701,8 +791,7 @@ def main(argv: list[str]) -> int:
         print(f"chipbench: {e}", file=sys.stderr)
         return e.code
     if opts.seconds is None:
-        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-            opts.seconds = float(json.load(f)["run_seconds"])
+        opts.seconds = float(load_json(os.path.join(ROOT, "BENCHMARK.json"))["run_seconds"])
     run = Run(opts, spec)
     gen = generators.load(run.traffic["kind"])
 
@@ -727,6 +816,8 @@ def main(argv: list[str]) -> int:
         run.stack.stop()
     if code:
         return code
+    if opts.parity:
+        run.compare()  # the chip is free now, and the worker's peak memory has been read
     if opts.trace:
         trace = run.reduce_trace()
     e2e = end_to_end(run, setup_s)
@@ -760,6 +851,15 @@ def main(argv: list[str]) -> int:
         {**run.counts, **status, "unclean_children": len(run.stack.unclean)}))
     with open(os.path.join(run.out_dir, "result.json"), "w") as f:
         json.dump(result, f)
+    # Each number compared beside its limit, as the last lines of the errors too.
+    line = "compared: nothing (--parity 0, or a configuration without a reference)"
+    if run.parity is not None:
+        line = ("compared: " + ", ".join(f"{k} {run.parity.get(k)} (limit {v})"
+                                        for k, v in run.parity["limits"].items())
+                + f" over {run.parity.get('tokens', 0)} served tokens of {run.parity.get('sequences', 0)} "
+                  f"sequences in {run.parity.get('rows', 0)} rows")
+    log(line)
+    print(f"chipbench: notes {json.dumps(run.notes)}\nchipbench: {line}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

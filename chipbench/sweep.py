@@ -39,7 +39,8 @@ def main(argv: list[str]) -> int:
     rows = []
     for v in values.split(","):
         cmd = [sys.executable, os.path.join(HERE, "run.py"), "--workload", o.workload, "--seed",
-               str(o.seed), "--seconds", str(o.seconds), "--trace", "0", "--param", f"{key}={v}"]
+               str(o.seed), "--seconds", str(o.seconds), "--trace", "0", "--param", f"{key}={v}",
+               "--parity", "0"]  # a sweep's lines are never results: the knee needs no reference
         if o.rehearse:
             cmd.append("--rehearse")
         proc = subprocess.run(cmd, capture_output=True, text=True)

@@ -2,8 +2,11 @@
 
     python3 -m pytest chipbench/tests -q -p no:cacheprovider
 
-(not under ``tests/``, which the benchmark PR may not touch). The kill test is
-a script of its own, ``chipbench/tests/kill_test.py``.
+(not under ``tests/``, which the benchmark PR may not touch; tier-1 collects
+every case here through ``tests/test_chipbench_spread.py``). The cases of this
+file need no JAX; those that do are in ``parity_cases.py``, imported at the
+end, and import JAX inside the case. The kill test is a script of its own,
+``chipbench/tests/kill_test.py``.
 """
 
 import hashlib
@@ -317,3 +320,221 @@ def test_every_line_of_text_in_benchmark_json_is_within_the_contract():
     bench = benchmark_json()
     for entry in bench["workloads"] + bench["configs"]:
         assert 1 <= len(entry["why"]) <= 200 and "\n" not in entry["why"], entry["name"]
+
+
+# -- configurations by name: maps, references, rehearsal files, parity limits (PR 29) --------
+
+from chipbench import host_phases, lookup, model_maps, parity  # noqa: E402
+from chipbench.model_maps import dense_gqa  # noqa: E402
+
+# What the parent's ``launch_worker.model_fields`` gave for each file (commit ebf62a4).
+PARENT_FIELDS = {
+    "qwen2.5-7b-int8": {"vocab_size": 152064, "hidden_size": 3584, "intermediate_size": 18944,
+                        "num_layers": 28, "num_heads": 28, "num_kv_heads": 4, "rope_theta": 1000000.0,
+                        "rms_norm_eps": 1e-06, "tie_embeddings": False, "head_dim": 128,
+                        "attn_bias": True, "max_position": 4096, "name": "qwen2.5-7b-int8"},
+    "mistral-7b-v0.3-int8": {"vocab_size": 32768, "hidden_size": 4096, "intermediate_size": 14336,
+                             "num_layers": 32, "num_heads": 32, "num_kv_heads": 8, "rope_theta": 1000000.0,
+                             "rms_norm_eps": 1e-05, "tie_embeddings": False, "head_dim": 128,
+                             "attn_bias": False, "max_position": 4096, "name": "mistral-7b-v0.3-int8"},
+    "qwen2.5-7b-int8-x4": {"vocab_size": 152064, "hidden_size": 3584, "intermediate_size": 18944,
+                           "num_layers": 28, "num_heads": 28, "num_kv_heads": 4, "rope_theta": 1000000.0,
+                           "rms_norm_eps": 1e-06, "tie_embeddings": False, "head_dim": 128,
+                           "attn_bias": True, "max_position": 4096, "name": "qwen2.5-7b-int8-x4"},
+    "rehearse-tiny": {"vocab_size": 512, "hidden_size": 128, "intermediate_size": 256, "num_layers": 2,
+                      "num_heads": 4, "num_kv_heads": 2, "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
+                      "tie_embeddings": True, "head_dim": 32, "attn_bias": False, "max_position": 4096,
+                      "name": "rehearse-tiny"},
+}
+
+
+def config_doc(name: str) -> dict:
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", sorted(PARENT_FIELDS))
+def test_the_dense_map_gives_what_the_launcher_gave(name):
+    doc = config_doc(name)
+    assert doc["model_map"] == "dense_gqa"
+    assert dense_gqa.fields(doc) == PARENT_FIELDS[name]
+    assert model_maps.fields(doc) == PARENT_FIELDS[name]
+
+
+def test_a_file_without_a_map_ends_with_the_maps_there_are():
+    doc = config_doc("rehearse-tiny")
+    del doc["model_map"]
+    with pytest.raises(lookup.Missing, match=r'no "model_map" key.*dense_gqa'):
+        model_maps.fields(doc)
+
+
+def test_an_unknown_map_ends_with_the_maps_there_are():
+    with pytest.raises(lookup.Missing, match=r"no model_maps/latent_moe\.py; there are \[.*'dense_gqa'"):
+        model_maps.fields({**config_doc("rehearse-tiny"), "model_map": "latent_moe"})
+
+
+def test_a_field_the_program_lacks_is_named_beside_the_fields_it_has(tmp_path, monkeypatch):
+    os.makedirs(tmp_path / "model_maps")
+    (tmp_path / "model_maps" / "latent.py").write_text(
+        "from chipbench.model_maps import dense_gqa\n"
+        "def fields(doc):\n    return {**dense_gqa.fields(doc), 'kv_lora_rank': 512}\n")
+    monkeypatch.setenv("CHIPBENCH_PATH", str(tmp_path))
+    with pytest.raises(lookup.Missing, match=r"\['kv_lora_rank'\].*ModelConfig does not have.*num_kv_heads"):
+        model_maps.model_config({**config_doc("rehearse-tiny"), "model_map": "latent"})
+
+
+def test_run_ends_without_a_result_on_a_configuration_without_a_map(tmp_path, monkeypatch):
+    from chipbench import run
+    doc = config_doc("rehearse-tiny")
+    del doc["model_map"]
+    os.makedirs(tmp_path / "configs")
+    (tmp_path / "configs" / "rehearse-tiny.json").write_text(json.dumps(doc))
+    monkeypatch.setenv("CHIPBENCH_PATH", str(tmp_path))
+    with pytest.raises(run.NoResult, match="model_map") as e:
+        run.load_cell("qwen2.5-7b-int8.chat", rehearse=True)
+    assert e.value.code == run.EXIT_NO_PROGRAM
+
+
+def test_a_control_overrides_the_served_block_and_leaves_the_rest():
+    """The program's own lower precision as a control: ``--param served.extra_flags=...``
+    reaches the worker's flags; the file, its limits and the mix are the cell's."""
+    from chipbench import run
+    spec = run.load_cell("qwen2.5-7b-int8.chat", rehearse=False)
+    opts = run.parse(["--workload", "qwen2.5-7b-int8.chat", "--param", 'served.extra_flags=["--kv-quant","int8"]',
+                      "--param", "rate_rps=2"])
+    control, cell = run.Run(opts, spec), run.Run(run.parse(["--workload", "qwen2.5-7b-int8.chat"]), spec)
+    flags = run.worker_flags(control.config, "tcp://s", "/t")
+    assert flags[-2:] == ["--kv-quant", "int8"] and "--kv-quant" not in run.worker_flags(cell.config, "tcp://s", "/t")
+    assert control.traffic["rate_rps"] == 2 and "served.extra_flags" not in control.traffic
+    assert {k: v for k, v in control.config.items() if k != "served"} == {
+        k: v for k, v in cell.config.items() if k != "served"}
+    assert spec["config"]["served"]["extra_flags"] == []  # the cell's own file is not touched
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in benchmark_json()["configs"]] + ["qwen2.5-7b-int8-x4"])
+def test_every_configuration_names_files_that_exist_and_how_it_is_compared(config):
+    doc = config_doc(config)
+    assert os.path.isfile(lookup.find("model_maps", doc["model_map"], ".py"))
+    assert os.path.isfile(lookup.find("references", doc["reference"], ".py"))
+    toy = config_doc(doc["rehearse"])
+    assert toy["model_map"] and toy["reference"]
+    assert all(toy["parity"][name] > 0 for name in parity.JUDGED)  # the toy's own limits, for --rehearse
+    assert doc["parity"]["rows"] >= 2 and len(doc["parity"]["why"]) > 40
+    assert doc["served"]["max_model_len"] % 512 == 0  # a row of the sample is that long
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in benchmark_json()["workloads"]])
+def test_every_cell_has_limits_of_its_own_and_a_rehearsal_takes_the_toys(cell):
+    from chipbench import run
+    with open(lookup.find("limits", cell, ".json")) as f:
+        own = json.load(f)
+    assert set(own) == {*parity.JUDGED, "why"} and len(own["why"]) > 200  # the readings it was set from
+    spec = run.load_cell(cell, rehearse=False)
+    assert all(spec["parity"][name] == own[name] > 0 for name in parity.JUDGED)
+    assert spec["parity"]["rows"] == spec["config"]["parity"]["rows"]
+    toy = run.load_cell(cell, rehearse=True)
+    assert toy["parity"] == toy["config"]["parity"] and toy["config"]["name"] == "rehearse-tiny"
+
+
+def test_a_cell_without_limits_does_not_pass():
+    """A cell whose PR forgot ``limits/<cell>.json`` is held to the configuration's
+    block alone, which gives no limit: the verdict says which are missing."""
+    bare = config_doc("qwen2.5-7b-int8")["parity"]
+    assert parity.verdict({"tokens": 3, "max_logit_gap": 0.0, "mean_logit_gap": 0.0}, bare) == [
+        "no limit for max_logit_gap: limits/<cell>.json has to give one",
+        "no limit for mean_logit_gap: limits/<cell>.json has to give one"]
+
+
+@pytest.mark.parametrize("read, why", [
+    ({"tokens": 10, "max_logit_gap": 0.5, "mean_logit_gap": 0.01}, []),                    # at the edge
+    ({"tokens": 10, "max_logit_gap": 0.49, "mean_logit_gap": 0.0}, []),                    # under
+    ({"tokens": 10, "max_logit_gap": 0.51, "mean_logit_gap": 0.01}, ["max_logit_gap"]),    # over one
+    ({"tokens": 10, "max_logit_gap": 0.1, "mean_logit_gap": 0.011}, ["mean_logit_gap"]),
+    ({"tokens": 10, "max_logit_gap": 9.0, "mean_logit_gap": 1.0}, ["max_logit_gap", "mean_logit_gap"]),
+    ({"tokens": 10, "max_logit_gap": float("nan"), "mean_logit_gap": 0.0}, ["max_logit_gap"]),
+    ({"tokens": 0}, ["no served token"]),
+])
+def test_the_verdict_turns_at_the_limit(read, why):
+    got = parity.verdict(read, {"max_logit_gap": 0.5, "mean_logit_gap": 0.01})
+    assert len(got) == len(why) and all(w in g for w, g in zip(why, got)), got
+
+
+def test_a_missing_limit_does_not_pass():
+    got = parity.verdict({"tokens": 3, "max_logit_gap": 0.0, "mean_logit_gap": 0.0}, {"max_logit_gap": 1.0})
+    assert got == ["no limit for mean_logit_gap: limits/<cell>.json has to give one"]
+
+
+def test_readings_from_hand_made_gaps():
+    read = parity.readings([0.0, 0.0, 0.25, 0.75])
+    assert read == {"tokens": 4, "max_logit_gap": 0.75, "mean_logit_gap": 0.25, "flipped_share": 0.5}
+    assert parity.readings([]) == {"tokens": 0}
+
+
+def finished(prompt: list[int], answer: list[int], status: str = "ok", history: int | None = None) -> dict:
+    return {"status": status, "prompt": prompt, "answer": answer,
+            **({} if history is None else {"history_tokens": history})}
+
+
+def test_the_sample_holds_the_longest_finished_request_and_follows_the_seed():
+    recs = [finished([n] * n, [2] * 8) for n in (5, 90, 7, 40, 12, 33, 21, 60)]
+    recs += [finished([1] * 500, [2] * 3, "cut"), finished([1] * 600, [], "failed")]
+    seqs = parity.sequences(recs)
+    assert [len(s["tokens"]) for s in seqs] == [13, 98, 15, 48, 20, 41, 29, 68]  # only finished requests
+    assert all(s["served"] == [[len(s["tokens"]) - 8, len(s["tokens"])]] for s in seqs)
+    for seed in (1, 2 ** 31 + 5):
+        rows, left = parity.pick_sample(seqs, seed, rows=2, row_tokens=128)
+        assert len(rows) == 2 and len(rows[0][0]["tokens"]) == 98  # the longest, first
+        assert all(sum(len(s["tokens"]) for s in row) <= 128 for row in rows)
+        assert sorted(map(id, left + rows[0] + rows[1])) == sorted(map(id, seqs))  # each once
+        assert (rows, left) == parity.pick_sample(seqs, seed, 2, 128)
+        again, _ = parity.pick_sample(left, seed, 2, 128)  # a second sample shares nothing with the first
+        assert again and not {id(s) for row in again for s in row} & {id(s) for row in rows for s in row}
+    picks = {tuple(len(s["tokens"]) for s in parity.pick_sample(seqs, s, 2, 128)[0][1]) for s in range(20)}
+    assert len(picks) > 1
+    assert parity.pick_sample(parity.sequences(recs[-2:]), 1, 2, 128) == ([], [])
+    assert parity.pick_sample(seqs, 1, 2, 12) == ([], [])  # no sequence fits a row
+
+
+def test_a_sessions_last_turn_carries_the_spans_of_the_turns_it_resends():
+    """Turn 2 resends turn 1's prompt and answer, turn 3 starts over from a system
+    prompt, another client's turn looks like nothing else, and a failed turn breaks
+    its chain: each served token is read once, behind the history it was served after."""
+    sys_p, a1, a2 = [9] * 6, [11, 12, 13], [21, 22]
+    t1 = finished(sys_p + [1, 1], a1, history=6)
+    t2 = finished(t1["prompt"] + a1 + [2, 2, 2], a2, history=len(t1["prompt"]) + 3)
+    t3 = finished(sys_p + [3], [31], history=6)
+    other = finished([7] * 4 + [5], [41, 42], history=4)
+    broken = finished(other["prompt"] + [41, 42] + [6], [51], "failed", history=7)
+    after = finished(broken["prompt"] + [51] + [8], [61], history=9)
+    seqs = parity.sequences([t1, other, t2, broken, t3, after])
+    assert [(s["tokens"], s["served"]) for s in seqs] == [
+        (other["prompt"] + [41, 42], [[5, 7]]),
+        (t2["prompt"] + a2, [[8, 11], [14, 16]]),
+        (t3["prompt"] + [31], [[7, 8]]),
+        (after["prompt"] + [61], [[10, 11]])]
+    for s in seqs:  # every span holds served tokens and nothing else
+        assert all(s["tokens"][a:b] in ([41, 42], a1, a2, [31], [61]) for a, b in s["served"])
+
+
+def test_idle_gaps_are_named_by_the_phase_over_them_on_the_recorded_trace():
+    with open(os.path.join(HERE, "trace_small.json")) as f:
+        doc = json.load(f)
+    bare = host_phases.attribute(doc)
+    labels = host_phases.label_gaps(bare)
+    assert labels and len(labels) <= 10 and all(name == "no_sched_phase" for name, _ in labels)
+    assert [s for _, s in labels] == sorted((s for _, s in labels), reverse=True)
+    # The same device plane under a scheduler thread that dispatched all through it,
+    # but for the second half of the longest gap, which it spent emitting.
+    dev = next(p for p in doc["planes"] if p["name"].startswith("/device:"))
+    events = [e for ln in dev["lines"] for e in ln["events"]]
+    t_min, t_max = min(e[1] for e in events), max(e[1] + e[2] for e in events)
+    start_s, dur_s, _ = bare["gaps"][0]
+    mid = t_min + (start_s + 0.4 * dur_s) * 1e9
+    host = {"name": "/host:CPU", "lines": [{"name": "sched", "events": [
+        ["sched.decode_dispatch", t_min, mid - t_min], ["sched.emit", mid, t_max - mid]]}]}
+    labelled = host_phases.label_gaps(host_phases.attribute({"planes": doc["planes"] + [host]}))
+    assert labelled[0] == ["sched.emit", pytest.approx(dur_s)]
+    assert {name for name, _ in labelled[1:]} <= {"sched.decode_dispatch", "sched.emit"}
+
+
+from chipbench.tests.parity_cases import *  # noqa: E402,F401,F403 - the cases that need JAX, on the CPU
