@@ -15,7 +15,14 @@ from dataclasses import dataclass, field
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Llama-family architecture hyperparameters."""
+    """Architecture hyperparameters of the two kinds of block the engine
+    runs. ``block="llama"`` (default): GQA attention then one FFN (dense
+    SwiGLU, or ``_moe`` when ``num_experts``). ``block="longcat"``
+    (LongCat-Flash, engine/longcat.py): per layer two latent-attention
+    (MLA) sub-blocks and two dense FFNs, with one shortcut-connected
+    expert block that reads the first sub-block's normed stream and is
+    added back at the layer's end; the cache holds one latent vector per
+    token and sub-block."""
 
     name: str = "test-tiny"
     vocab_size: int = 512
@@ -40,6 +47,29 @@ class ModelConfig:
     num_experts: int = 0
     num_experts_per_token: int = 2
     moe_intermediate_size: int | None = None  # per-expert FFN width (default: intermediate_size)
+    # -- block="longcat" ---------------------------------------------------
+    block: str = "llama"
+    # Latent attention (MLA), named as published: low-rank query and shared
+    # latent KV. The cache row is kv_lora_rank + qk_rope_head_dim values, no
+    # head axis.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_scale_q_lora: bool = False   # q *= sqrt(hidden / q_lora_rank)
+    mla_scale_kv_lora: bool = False  # c_kv *= sqrt(hidden / kv_lora_rank)
+    # The expert layer's share: ``num_experts`` routed experts are HELD
+    # here, indices [expert_offset, expert_offset + num_experts) of the
+    # published ``num_routed_experts``; the router keeps its published
+    # width (routed + zero-compute) and what absent experts would add is
+    # left out. ``zero_expert_num`` identity experts cost no weights.
+    num_routed_experts: int = 0
+    expert_offset: int = 0
+    zero_expert_num: int = 0
+    routed_scaling_factor: float = 1.0
+    # The published vocabulary beside the rows held here (``vocab_size``).
+    published_vocab_size: int = 0
 
     @property
     def q_size(self) -> int:
@@ -49,8 +79,47 @@ class ModelConfig:
     def kv_size(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    @property
+    def latent_dim(self) -> int:
+        """Values a token holds in the cache per attention sub-block."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_page_width(self) -> int:
+        """The cache row as stored: ``latent_dim`` padded to whole 128-lane
+        tiles (576 → 640), which page DMAs need."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def cache_layers(self) -> int:
+        return 2 * self.num_layers if self.block == "longcat" else self.num_layers
+
+    @property
+    def router_width(self) -> int:
+        return (self.num_routed_experts or self.num_experts) + self.zero_expert_num
+
+    def _longcat_layer_params(self, experts: int) -> int:
+        d, i, h = self.hidden_size, self.intermediate_size, self.num_heads
+        ie = self.moe_intermediate_size or i
+        mla = (
+            d * self.q_lora_rank + self.q_lora_rank
+            + self.q_lora_rank * h * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+            + d * self.latent_dim + self.kv_lora_rank
+            + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
+            + h * self.v_head_dim * d
+        )
+        return (
+            2 * (mla + 3 * d * i + 2 * d)            # two sub-blocks, four norms
+            + d * self.router_width + self.router_width  # router and its bias
+            + experts * 3 * d * ie
+        )
+
     def param_count(self) -> int:
         d, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        if self.block == "longcat":
+            head = 0 if self.tie_embeddings else d * v
+            return (v * d + d + head
+                    + self.num_layers * self._longcat_layer_params(self.num_experts))
         if self.num_experts:
             ie = self.moe_intermediate_size or i
             ffn = self.num_experts * 3 * d * ie + d * self.num_experts  # experts + router
@@ -71,6 +140,15 @@ class ModelConfig:
         if not self.num_experts:
             return self.param_count()
         d, v = self.hidden_size, self.vocab_size
+        if self.block == "longcat":
+            # Of a token's top-k, the share that lands on experts held here
+            # (zero-compute and absent ones touch no weights).
+            held = self.num_experts_per_token * self.num_experts / self.router_width
+            head = 0 if self.tie_embeddings else d * v
+            return int(v * d + d + head
+                       + self.num_layers * self._longcat_layer_params(0)
+                       + self.num_layers * held * 3 * d
+                       * (self.moe_intermediate_size or self.intermediate_size))
         ie = self.moe_intermediate_size or self.intermediate_size
         per_layer = (
             d * self.q_size + 2 * d * self.kv_size + self.q_size * d
@@ -127,6 +205,20 @@ class ModelConfig:
                 intermediate_size=8192, num_layers=12, num_heads=16,
                 num_kv_heads=4, head_dim=128, num_experts=64,
                 num_experts_per_token=8, moe_intermediate_size=1024,
+            ),
+            # LongCat-Flash block at toy widths (CPU tests): one of 4
+            # shares of 8 routed experts beside 4 zero-compute ones.
+            "longcat-tiny": ModelConfig(
+                name="longcat-tiny", block="longcat", vocab_size=512,
+                published_vocab_size=512, hidden_size=128,
+                intermediate_size=256, num_layers=2, num_heads=4,
+                num_kv_heads=1, head_dim=48, rope_theta=10000.0,
+                tie_embeddings=False, q_lora_rank=64, kv_lora_rank=96,
+                qk_nope_head_dim=32, qk_rope_head_dim=16, v_head_dim=32,
+                mla_scale_q_lora=True, mla_scale_kv_lora=True,
+                num_experts=2, num_routed_experts=8, expert_offset=2,
+                zero_expert_num=4, num_experts_per_token=3,
+                moe_intermediate_size=64, routed_scaling_factor=6.0,
             ),
             # Llama-3-70B-class (BASELINE.md north-star target, multi-host)
             "llama-70b": ModelConfig(
@@ -427,6 +519,26 @@ class EngineArgs:
             raise ValueError(
                 f"lora_rank must be positive when lora_slots > 0; got {self.lora_rank}"
             )
+        if self.model.block == "longcat":
+            # What cannot carry a latent page, or this block's layer, says
+            # so here by name instead of mis-shaping it inside a request.
+            refused = [
+                what for on, what in (
+                    (self.kv_quant != "none", "--kv-quant int8 (no int8 latent cache)"),
+                    (self.spec_tokens > 0, "speculation (--spec-tokens; spec_verify_impl)"),
+                    (self.lora_slots > 0, "LoRA banks (--lora-slots)"),
+                    (self.quant != "none", "--quant int8 (engine/quant.py)"),
+                    (self.tp > 1, "--tp (the latent kernel and the grouped expert product are single-device)"),
+                    (bool(self.host_kv_blocks or self.disk_kv_dir or self.fleet_kv_dir),
+                     "KV tiers (--host-kv-blocks, --disk-kv-dir, --fleet-kv-dir)"),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    f"model {self.model.name!r} has block='longcat' (latent "
+                    f"pages, shortcut-connected expert layer), which cannot "
+                    f"run with: {'; '.join(refused)}"
+                )
         if self.max_model_len % self.block_size:
             self.max_model_len = ((self.max_model_len // self.block_size) + 1) * self.block_size
         if self.max_prefill_tokens % self.block_size:
@@ -585,6 +697,10 @@ class EngineArgs:
         so the real cost is 1 byte/elem + 4/head_dim bytes/elem of scale
         overhead (~3% at head_dim=128 → ~1.94x more blocks per byte)."""
         m = self.model
+        if m.block == "longcat":
+            # One pool, 2L cache layers, the row padded to whole lane tiles.
+            itemsize = 2 if self.dtype == "bfloat16" else 4
+            return m.cache_layers * self.block_size * m.latent_page_width * itemsize
         elems = self.block_size * m.num_kv_heads * m.head_dim
         if self.kv_quant == "int8":
             # int8 page + fp32 scale per (position, kv head).

@@ -64,6 +64,7 @@ from dynamo_tpu.engine.grammar import (
     mask_words,
     pack_token_ids,
 )
+from dynamo_tpu.engine.model import refuse_block
 from dynamo_tpu.engine.runner import host_ready, start_host_fetch
 from dynamo_tpu.engine.sampler import needs_full, row_needs_full
 from dynamo_tpu.kv_router.protocols import ForwardPassMetrics, KvCacheEvent, KvStats, WorkerStats
@@ -317,7 +318,8 @@ class _Window:
         a = [self.ref.arrs[0], self.ref.arrs[1]]
         if self.top_n:
             a += [self.ref.arrs[2], self.ref.arrs[3]]
-        return a
+        # a block="longcat" model's routing histogram rides the same fetch
+        return a if self.ref.hist is None else a + [self.ref.hist]
 
 
 class _MigSt:
@@ -387,19 +389,23 @@ class _First:
     """One dispatched admission wave's first-token sample (not yet
     fetched). Entries: (seq, row) into the wave's padded sample batch."""
 
-    __slots__ = ("entries", "out_d", "lps_d", "top_ref")
+    __slots__ = ("entries", "out_d", "lps_d", "top_ref", "routed")
 
-    def __init__(self, entries: list[tuple[_Seq, int]], out_d, lps_d, top_ref):
+    def __init__(self, entries: list[tuple[_Seq, int]], out_d, lps_d, top_ref,
+                 routed: list | None = None):
         self.entries = entries
         self.out_d = out_d
         self.lps_d = lps_d
         self.top_ref = top_ref
+        # Routing histograms of the prefill dispatches since the last wave
+        # (engine/longcat.py; they ride this wave's fetch).
+        self.routed = routed or []
 
     def fetch_arrays(self) -> list:
         a = [self.out_d, self.lps_d]
         if self.top_ref is not None:
             a += [self.top_ref.arrs[0], self.top_ref.arrs[1]]
-        return a
+        return a + self.routed
 
 
 # Host-side phases during which the scheduler thread is (or may be)
@@ -564,6 +570,35 @@ def register_engine_metrics(registry) -> dict:
             "kv_pool_miss_blocks_total",
             "Matchable prompt blocks an admission did not find in the G1 "
             "prefix cache (hit + miss = (prompt_len - 1) // block_size)",
+        ),
+        registry.counter(
+            "moe_assignments_total",
+            "Expert assignments (token x top-k x layer) the expert layer "
+            "routed, by kind: held = to a routed expert this chip holds "
+            "(the grouped product), zero = to a zero-compute expert (adds "
+            "w*h, no weights), absent = to a routed expert another chip "
+            "holds (left out here). block='longcat' models only",
+        ),
+        registry.counter(
+            "moe_expert_tokens_total",
+            "Assignments to each routed expert held here, by layer and "
+            "published expert index: the load of the grouped product",
+        ),
+        registry.counter(
+            "moe_tokens_routed_total",
+            "Tokens x layers the router ran over (padding rows excluded)",
+        ),
+        registry.counter(
+            "moe_expert_calls_total",
+            "Calls of the grouped expert product, by program: one a layer "
+            "of a decode step (program=decode), one a layer of a prefill "
+            "part of up to 512 tokens (program=prefill)",
+        ),
+        registry.counter(
+            "moe_experts_touched_total",
+            "Held experts with at least one token, summed over the calls "
+            "of the grouped expert product, by program: the expert weight "
+            "reads those calls needed",
         ),
     )
     return {m.name.removeprefix(PREFIX + "_"): m for m in metrics}
@@ -784,6 +819,15 @@ class TpuEngine:
         self._ctr_pushed: dict[tuple, float] = {}
         # _waiting as it was when its head was last stamped blocked for a slot
         self._slots_blocked_sig: tuple | None = None
+        # A block="longcat" model's routing histograms [L, E + HIST_EXTRA]
+        # (engine/longcat.py) by program, summed from the arrays that ride the
+        # token fetches; None for a block that routes nothing.
+        self.moe_hist: dict[str, np.ndarray] | None = None
+        if self.cfg.block == "longcat":
+            from dynamo_tpu.engine.longcat import HIST_EXTRA
+
+            shape = (self.cfg.num_layers, self.cfg.num_experts + HIST_EXTRA)
+            self.moe_hist = {p: np.zeros(shape, np.int64) for p in ("prefill", "decode")}
 
     def bind_metrics(self, registry) -> None:
         """Attach the engine gauges to a MetricsRegistry; updated once
@@ -850,6 +894,20 @@ class TpuEngine:
              kind="emitted")
         feed("kv_pool_hit_blocks_total", self.pool.hit_blocks)
         feed("kv_pool_miss_blocks_total", self.pool.miss_blocks)
+        if self.moe_hist is not None:
+            E, off = self.cfg.num_experts, self.cfg.expert_offset
+            both = sum(self.moe_hist.values())
+            zero, absent, routed = both[:, E:E + 3].sum(axis=0)
+            feed("moe_assignments_total", int(both[:, :E].sum()), kind="held")
+            feed("moe_assignments_total", int(zero), kind="zero")
+            feed("moe_assignments_total", int(absent), kind="absent")
+            feed("moe_tokens_routed_total", int(routed))
+            for program, hist in self.moe_hist.items():
+                touched, calls = hist[:, E + 3:].sum(axis=0)
+                feed("moe_experts_touched_total", int(touched), program=program)
+                feed("moe_expert_calls_total", int(calls), program=program)
+            for (l, e), n in np.ndenumerate(both[:, :E]):
+                feed("moe_expert_tokens_total", int(n), layer=str(l), expert=str(off + e))
 
     def _phase_open(self, key: str) -> float:
         """Begin step-loop phase `key` → its t0. Opens the profiler
@@ -1129,6 +1187,15 @@ class TpuEngine:
         if not req.token_ids:
             yield LLMEngineOutput(
                 finish_reason=FinishReason.ERROR, error="empty prompt"
+            ).to_dict()
+            return
+        ktp = req.kv_transfer_params or {}
+        if self.cfg.block == "longcat" and any(k in ktp for k in (
+                "do_remote_decode", "peer_prefix", "stream_handle", "handle", "pages")):
+            yield LLMEngineOutput(
+                finish_reason=FinishReason.ERROR,
+                error="KV transfer (transfer/: disaggregated prefill, peer prefix "
+                      "fetch) cannot carry a block='longcat' model's latent pages",
             ).to_dict()
             return
         vocab = self.cfg.vocab_size
@@ -1525,7 +1592,8 @@ class TpuEngine:
                     seq.first_pend = True
                     self._running.append(seq)
                 first = _First(
-                    [(s, i) for i, s in enumerate(seqs)], out_d, lps_d, top_ref
+                    [(s, i) for i, s in enumerate(seqs)], out_d, lps_d, top_ref,
+                    self._runner.take_routed(),
                 )
                 start_host_fetch(first.fetch_arrays())
                 self._fetchq.append(first)
@@ -1556,6 +1624,7 @@ class TpuEngine:
         scheduler thread (device dispatch affinity)."""
         if not token_ids:
             raise RequestValidationError("empty input")
+        refuse_block(self.cfg, "embeddings (embed_impl)")
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         with self._wakeup:
@@ -2208,6 +2277,8 @@ class TpuEngine:
     def migration_begin(self, request_id: str) -> dict:
         """Start streaming a running decode's KV. → {"ok", "handle",
         "published"} or {"error"}. Scheduler thread only."""
+        if self.cfg.block == "longcat":
+            return {"error": "live migration cannot carry latent (MLA) pages"}
         seq = next(
             (s for s in self._running if s.request_id == request_id), None
         )
@@ -2648,6 +2719,8 @@ class TpuEngine:
         if f.top_ref is not None:
             tvals_l = np.asarray(f.top_ref.arrs[0]).tolist()
             tids_l = np.asarray(f.top_ref.arrs[1]).tolist()
+        for hist in f.routed:
+            self.moe_hist["prefill"] += np.asarray(hist)
         t0 = self._phase(key, t0, then="emit")
         toks_l, lps_l = toks.tolist(), lps.tolist()
         for seq, row in f.entries:
@@ -2837,6 +2910,8 @@ class TpuEngine:
         t0 = self._phase_open(key)
         toks_np = np.asarray(w.ref.arrs[0])  # [K, B] — the one host fetch
         logps_np = np.asarray(w.ref.arrs[1])
+        if w.ref.hist is not None:
+            self.moe_hist["decode"] += np.asarray(w.ref.hist)
         tvals_l = tids_l = None
         if w.top_n:
             # transpose → [B, K, top_n]; bulk-converted once (per-element

@@ -32,6 +32,7 @@ lib/llm/src/tokens.rs so KV identity is consistent framework-wide.
 from __future__ import annotations
 
 import functools
+import sys
 from typing import Any, NamedTuple
 
 import jax
@@ -52,10 +53,15 @@ class KVCache(NamedTuple):
     only on its own K/V vector, never on its block's other occupants, so
     speculative-rollback junk and partial blocks cannot perturb already-
     written positions and greedy streams stay byte-stable across
-    prefill/decode/spec write orders."""
+    prefill/decode/spec write orders.
+
+    A ``block="longcat"`` model (engine/longcat.py) has ONE pool: ``k`` is
+    ``[2L, N, bs, latent_page_width]`` (cache layer 2*layer + sub-block; a
+    row is the normed latent and the rotated rope key every head shares,
+    padded to whole lane tiles) and ``v`` is None."""
 
     k: jax.Array  # [L, N, bs, KVH*hd]
-    v: jax.Array
+    v: jax.Array | None
     k_scale: jax.Array | None = None  # [L, N, bs, KVH] fp32 — int8 only
     v_scale: jax.Array | None = None
 
@@ -167,6 +173,32 @@ def _dot_q(x: jax.Array, lp: dict, name: str) -> jax.Array:
         y = jnp.dot(x, w.astype(x.dtype))
         return y * lp[name + "_scale"].astype(x.dtype)
     return jnp.dot(x, w)
+
+
+def block_module(cfg: ModelConfig):
+    """The module that runs ``cfg.block``, chosen once (engine/runner.py):
+    this one, or engine/longcat.py. Both have ``init_params``,
+    ``init_kv_cache`` and the jitted ``prefill``, ``prefill_batch``,
+    ``decode_step`` and ``multi_decode`` under these names; longcat's
+    programs return a routing histogram after what these return."""
+    if cfg.block == "longcat":
+        from dynamo_tpu.engine import longcat
+
+        return longcat
+    if cfg.block != "llama":
+        raise ValueError(f"no module runs block={cfg.block!r} (llama, longcat)")
+    return sys.modules[__name__]
+
+
+def refuse_block(cfg: ModelConfig, mechanism: str) -> None:
+    """The mechanisms of this module that assume per-head K and V pages and
+    the attention-then-FFN layer say so by name for any other block
+    (EngineArgs refuses the flag-driven ones at construction)."""
+    if cfg.block != "llama":
+        raise ValueError(
+            f"{mechanism} cannot run a block={cfg.block!r} model: it assumes "
+            f"per-head K and V pages and the attention-then-FFN layer"
+        )
 
 
 def _embed_rows(params: Params, tokens: jax.Array, dtype) -> jax.Array:
@@ -647,6 +679,31 @@ def multi_decode_impl(
     model distribution — OpenAI reports model logprobs, not sampler-
     modified ones); top_* are the raw-distribution ranked alternatives
     (zero-sized when top_n == 0)."""
+    def step(cache, tok, pos):
+        logits, cache = decode_step_impl(
+            cfg, params, cache, tok, pos, block_tables, active,
+            lora, adapter_slots, attn_impl=attn_impl,
+        )
+        return logits, cache, None
+
+    return decode_window(
+        step, None, cfg.vocab_size, num_steps, mode, top_n, cache, tokens,
+        positions, temperature, seeds, steps0, top_k, top_p, freq_penalty,
+        pres_penalty, penalty_tokens, chain_mask, chain_src, last_toks,
+    )[:5]
+
+
+def decode_window(
+    step, aux0, V: int, num_steps: int, mode: str, top_n: int,
+    cache: KVCache, tokens, positions, temperature, seeds, steps0, top_k,
+    top_p, freq_penalty, pres_penalty, penalty_tokens,
+    chain_mask=None, chain_src=None, last_toks=None,
+):
+    """The fused window ``multi_decode_impl`` describes, over any block's
+    decode step: ``step(cache, tokens, positions) -> (logits, cache, aux)``.
+    ``aux`` is a pytree summed over the substeps from ``aux0`` (None for a
+    block that has nothing to count; engine/longcat.py sums its routing
+    histogram) and returned after the cache."""
     from dynamo_tpu.engine.sampler import (
         apply_penalties,
         sample_step,
@@ -656,7 +713,6 @@ def multi_decode_impl(
     )
 
     B = tokens.shape[0]
-    V = cfg.vocab_size
     if chain_mask is not None:
         # Window pipeline: chained rows take their input token from the
         # previous window's on-device output — composed INSIDE the jit so
@@ -676,11 +732,9 @@ def multi_decode_impl(
         return jax.vmap(noise)(seeds, steps0 + i)
 
     def substep(carry, i):
-        cache, tok, pos, counts = carry
-        logits, cache = decode_step_impl(
-            cfg, params, cache, tok, pos, block_tables, active,
-            lora, adapter_slots, attn_impl=attn_impl,
-        )
+        cache, tok, pos, counts, aux = carry
+        logits, cache, more = step(cache, tok, pos)
+        aux = jax.tree.map(jnp.add, aux, more)
         with jax.named_scope("sample"):
             if mode == "greedy":
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -700,12 +754,13 @@ def multi_decode_impl(
             else:
                 tvals = jnp.zeros((B, 0), jnp.float32)
                 tids = jnp.zeros((B, 0), jnp.int32)
-        return (cache, nxt, pos + 1, counts), (nxt, logp, tvals, tids)
+        return (cache, nxt, pos + 1, counts, aux), (nxt, logp, tvals, tids)
 
-    (cache, _, _, _), (toks, logps, top_vals, top_ids) = lax.scan(
-        substep, (cache, tokens, positions, counts0), jnp.arange(num_steps, dtype=jnp.int32)
+    (cache, _, _, _, aux), (toks, logps, top_vals, top_ids) = lax.scan(
+        substep, (cache, tokens, positions, counts0, aux0),
+        jnp.arange(num_steps, dtype=jnp.int32),
     )
-    return toks, logps, top_vals, top_ids, cache  # [num_steps, B(, top_n)]
+    return toks, logps, top_vals, top_ids, cache, aux  # [num_steps, B(, top_n)]
 
 
 def spec_verify_impl(
@@ -786,6 +841,7 @@ def spec_verify_impl(
     argmax predictions — free Jacobi-pool food for the drafter,
     top_vals [B, S1, top_n], top_ids [B, S1, top_n], last_tok [B] =
     out[b, n_emit-1] for the chain-buffer fold, cache)."""
+    refuse_block(cfg, "speculation (spec_verify_impl)")
     from dynamo_tpu.engine.sampler import (
         spec_acceptance,
         spec_tree_acceptance,
@@ -1005,6 +1061,7 @@ def embed_impl(
     """Mean-pooled final-norm hidden state over the true tokens → [D]
     fp32. Cache-free causal forward (serves /v1/embeddings; reference:
     lib/llm/src/http/service/openai.rs:302)."""
+    refuse_block(cfg, "embeddings (embed_impl)")
     T = tokens.shape[0]
     compute_dtype = params["layers"]["attn_norm"].dtype
     x = _embed_rows(params, tokens, compute_dtype)  # [T, D]

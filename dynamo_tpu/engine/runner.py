@@ -73,11 +73,14 @@ def _bank_write(bank_arr, update, slot):
 class StepRef:
     """Opaque handle to a dispatch's device-side results."""
 
-    __slots__ = ("rid", "arrs")
+    __slots__ = ("rid", "arrs", "hist")
 
-    def __init__(self, rid: int, arrs: tuple):
+    def __init__(self, rid: int, arrs: tuple, hist=None):
         self.rid = rid
         self.arrs = arrs
+        # The dispatch's expert-routing histogram (engine/longcat.py), which
+        # rides the fetch of ``arrs``; None for a block that routes nothing.
+        self.hist = hist
 
 
 def start_host_fetch(arrs) -> None:
@@ -112,6 +115,20 @@ def _unpack_np(d: dict) -> np.ndarray:
     return np.frombuffer(d["b"], np.dtype(d["d"])).reshape(d["s"])
 
 
+def _block_programs(cfg):
+    """→ (the module that runs ``cfg.block``, its jitted prefill_batch,
+    prefill, multi_decode and decode_step). The one place the block is
+    chosen. Every program returns its results and then a routing histogram
+    in the last place: None from the dense block, which has none."""
+    block = M.block_module(cfg)
+    programs = (block.prefill_batch, block.prefill, block.multi_decode, block.decode_step)
+    if block is M:
+        programs = tuple(
+            (lambda *a, _fn=fn, **kw: (*_fn(*a, **kw), None)) for fn in programs
+        )
+    return (block, *programs)
+
+
 class LocalRunner:
     """Owns device state (params, KV cache, sharding) and executes
     dispatches. Thread-affinity: engine/scheduler thread only."""
@@ -120,6 +137,8 @@ class LocalRunner:
                  seed: int = 0, sharding=None):
         self.args = args
         self.cfg = args.model
+        (self._block, self._prefill_batch, self._prefill, self._multi_decode,
+         self._decode_step) = _block_programs(self.cfg)
         self._seed = seed
         self.sharding = sharding
         self.params = params
@@ -140,6 +159,13 @@ class LocalRunner:
         # jitted impls; base-only batches pass None and trace the exact
         # pre-LoRA variant. None when lora_slots == 0.
         self.lora_bank: dict[str, jax.Array] | None = None
+        # Routing histograms of the prefill dispatches since the engine last
+        # took them: they ride its next first-token fetch.
+        self._routed: list[jax.Array] = []
+
+    def take_routed(self) -> list:
+        out, self._routed = self._routed, []
+        return out
 
     # -- lifecycle --------------------------------------------------------
 
@@ -182,13 +208,13 @@ class LocalRunner:
             )
         else:
             build = functools.partial(
-                M.init_params, self.cfg, jax.random.PRNGKey(self._seed), dtype
+                self._block.init_params, self.cfg, jax.random.PRNGKey(self._seed), dtype
             )
             self.params = build() if sh is None else sh.born_sharded(build)
         # Scale arrays shard over the same kv-head axis as the cache
         # lanes, so the (mesh-forced) XLA attention paths dequantize
         # with co-sharded scales — int8 KV composes with tp.
-        self.cache = M.init_kv_cache(
+        self.cache = self._block.init_kv_cache(
             self.cfg, self.args.num_kv_blocks, self.args.block_size, dtype,
             kv_quant=self.args.kv_quant,
             sharding=None if sh is None else sh.cache_sharding(),
@@ -215,6 +241,7 @@ class LocalRunner:
         request and a path that gives way says so in the start line."""
         from dynamo_tpu.ops.paged_attention import (
             kernel_unsupported,
+            latent_kernel_unsupported,
             resolve_attn_impl,
         )
 
@@ -223,7 +250,9 @@ class LocalRunner:
         impl = resolve_attn_impl(self.args.attn_impl)
         if impl != "pallas":
             return impl, ""
-        limit = kernel_unsupported(self.cfg, self.args.block_size)
+        limit = (
+            latent_kernel_unsupported if self.cfg.block == "longcat" else kernel_unsupported
+        )(self.cfg, self.args.block_size)
         if limit is None:
             return impl, ""
         if self.args.attn_impl == "pallas":
@@ -268,6 +297,8 @@ class LocalRunner:
             "" if None in used
             else " hbm_in_use_gb=" + ",".join(f"{u / 1e9:.2f}" for u in used)
         )
+        # The grouped expert product's path, where the block has one.
+        experts = f" experts={self._block.expert_impl()}" if hasattr(self._block, "expert_impl") else ""
         # A dp rank is pinned to its chips by the spawner; inside its own
         # TPU world every rank's device ids start at 0 again.
         pinned = os.environ.get("TPU_VISIBLE_CHIPS", "all")
@@ -276,7 +307,7 @@ class LocalRunner:
             f"devices={len(devs)} of {jax.device_count()} "
             f"ids={','.join(str(d.id) for d in devs)} visible_chips={pinned} "
             f"dtype={a.dtype} quant={a.quant} kv_quant={a.kv_quant} "
-            f"attention: prefill=xla decode={decode} spec_verify={spec}{hbm}"
+            f"attention: prefill=xla decode={decode} spec_verify={spec}{experts}{hbm}"
         )
 
     def stop(self) -> None:
@@ -284,11 +315,14 @@ class LocalRunner:
 
     # -- ref bookkeeping (must stay deterministic across hosts) -----------
 
-    def _new_ref(self, arrs: tuple, rid: int | None = None) -> StepRef:
+    def _new_ref(self, arrs: tuple, rid: int | None = None, hist=None,
+                 prefill: bool = False) -> StepRef:
+        if prefill and hist is not None:
+            self._routed.append(hist)
         if rid is None:
             rid = self._rid
         self._rid = rid + 1
-        ref = StepRef(rid, arrs)
+        ref = StepRef(rid, arrs, hist)
         self._refs[rid] = ref
         while len(self._refs) > _RETAIN:
             self._refs.popitem(last=False)
@@ -326,26 +360,26 @@ class LocalRunner:
     def prefill_batch(self, toks, tables, starts, tlens, adapter_slots=None,
                       *, rid=None) -> StepRef:
         bank, slots = self._lora_operands(adapter_slots)
-        logits, self.cache = M.prefill_batch(
+        logits, self.cache, hist = self._prefill_batch(
             self.cfg, self.params, self.cache,
             jnp.asarray(toks), jnp.asarray(tables),
             jnp.asarray(starts), jnp.asarray(tlens),
             bank, slots,
         )
-        return self._new_ref((logits,), rid)
+        return self._new_ref((logits,), rid, hist, prefill=True)
 
     def prefill_chunk(self, toks, table, pos, tlen, adapter_slot=None,
                       *, rid=None) -> StepRef:
         bank = slot = None
         if adapter_slot is not None and adapter_slot >= 0:
             bank, slot = self.lora_bank, jnp.int32(adapter_slot)
-        logits, self.cache = M.prefill(
+        logits, self.cache, hist = self._prefill(
             self.cfg, self.params, self.cache,
             jnp.asarray(toks), jnp.asarray(table),
             jnp.int32(pos), jnp.int32(tlen),
             bank, slot,
         )
-        return self._new_ref((logits,), rid)
+        return self._new_ref((logits,), rid, hist, prefill=True)
 
     def _ensure_last_toks(self) -> None:
         if self._last_toks is None:
@@ -374,7 +408,7 @@ class LocalRunner:
             mask[np.asarray(dst, np.int64)] = True
             srcmap[np.asarray(dst, np.int64)] = src
         bank, aslots = self._lora_operands(adapter_slots)
-        toks_d, logps_d, tvals_d, tids_d, self.cache = M.multi_decode(
+        toks_d, logps_d, tvals_d, tids_d, self.cache, hist = self._multi_decode(
             self.cfg, K, mode, int(top_n), self.params, self.cache,
             jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(tables), jnp.asarray(active),
@@ -390,19 +424,19 @@ class LocalRunner:
         self._last_toks = _fold_tokens(
             self._last_toks, toks_d[-1], jnp.asarray(fold_slots, jnp.int32)
         )
-        return self._new_ref((toks_d, logps_d, tvals_d, tids_d), rid)
+        return self._new_ref((toks_d, logps_d, tvals_d, tids_d), rid, hist)
 
     def decode_step(self, tokens, positions, tables, active,
                     adapter_slots=None, *, rid=None) -> StepRef:
         bank, aslots = self._lora_operands(adapter_slots)
-        logits, self.cache = M.decode_step(
+        logits, self.cache, hist = self._decode_step(
             self.cfg, self.params, self.cache,
             jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(tables), jnp.asarray(active),
             bank, aslots,
             attn_impl=self.attn_impl,
         )
-        return self._new_ref((logits,), rid)
+        return self._new_ref((logits,), rid, hist)
 
     def spec_verify(self, S1, mode, tokens, positions0, draft_len, tables,
                     active, temps, seeds, steps0, fold_slots=None, top_n=0,
@@ -496,6 +530,7 @@ class LocalRunner:
 
     def extract_pages(self, block_ids: list[int]) -> tuple:
         """→ (k, v) page arrays, plus (k_scale, v_scale) under int8 KV."""
+        M.refuse_block(self.cfg, "KV page export (transfer/, tiers, migration)")
         return kv_transfer.extract_pages(
             self.cache, block_ids, replicate=self.sharding
         )
@@ -506,6 +541,7 @@ class LocalRunner:
         (start_host_fetch) and harvests with ``finish_extract_pages``
         once host_ready — page copies overlap remaining prefill chunks
         instead of blocking the scheduler per chunk."""
+        M.refuse_block(self.cfg, "KV page export (transfer/, tiers, migration)")
         return kv_transfer.start_extract(
             self.cache, block_ids, replicate=self.sharding
         )
@@ -515,6 +551,7 @@ class LocalRunner:
         return kv_transfer.finish_extract(device_pages, n)
 
     def inject_pages(self, block_ids: list[int], *pages) -> None:
+        M.refuse_block(self.cfg, "KV page injection (transfer/, tiers, migration)")
         pages = kv_transfer.adapt_pages(pages, self.cache, self.cfg.num_kv_heads)
         self.cache = kv_transfer.inject_pages(self.cache, block_ids, *pages)
 

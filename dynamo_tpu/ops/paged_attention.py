@@ -248,14 +248,22 @@ def _mq_kernel(
     head_dim: int,
     quantized: bool,
     tree_slots: int = 0,
+    value_dim: int = 0,
 ):
+    # value_dim > 0: a latent (MLA) pool. One pool, no V: a page row is the
+    # shared key, and its first ``value_dim`` lanes are also the value, so
+    # each page is read once.
     refs = list(refs)
     qbd_ref, lenvec_ref = refs[:2]
     refs = refs[2:]
     anc_ref = None
     if tree_slots:
         anc_ref, refs = refs[0], refs[1:]
-    if quantized:
+    if value_dim:
+        (k_hbm, o_ref, kbuf, m_scr, l_scr, acc_scr, slot_ref, started_ref,
+         sem) = refs
+        kscale_ref = vscale_ref = v_hbm = vbuf = None
+    elif quantized:
         (kscale_ref, vscale_ref, k_hbm, v_hbm,
          o_ref, kbuf, vbuf, m_scr, l_scr, acc_scr, slot_ref, started_ref,
          sem) = refs
@@ -305,19 +313,20 @@ def _mq_kernel(
         out = []
         for p in range(P):
             page = tables_ref[row, chunk * P + p]
-            out.append((
-                p < npages,
-                pltpu.make_async_copy(k_hbm.at[layer, page], kbuf.at[slot, p], sem.at[slot, 0, p]),
-                pltpu.make_async_copy(v_hbm.at[layer, page], vbuf.at[slot, p], sem.at[slot, 1, p]),
-            ))
+            copies = [pltpu.make_async_copy(
+                k_hbm.at[layer, page], kbuf.at[slot, p], sem.at[slot, 0, p])]
+            if v_hbm is not None:
+                copies.append(pltpu.make_async_copy(
+                    v_hbm.at[layer, page], vbuf.at[slot, p], sem.at[slot, 1, p]))
+            out.append((p < npages, copies))
         return out
 
     def issue(row, chunk, slot):
-        for ok, dk, dv in chunk_dmas(row, chunk, slot):
+        for ok, copies in chunk_dmas(row, chunk, slot):
             @pl.when(ok)
             def _():
-                dk.start()
-                dv.start()
+                for dma in copies:
+                    dma.start()
 
     @pl.when(live)
     def _body():
@@ -363,11 +372,11 @@ def _mq_kernel(
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
         # Wait for this step's pages.
-        for ok, dk, dv in chunk_dmas(b, c, cur):
+        for ok, copies in chunk_dmas(b, c, cur):
             @pl.when(ok)
             def _():
-                dk.wait()
-                dv.wait()
+                for dma in copies:
+                    dma.wait()
         slot_ref[0] = nxt
 
         # Context-position validity, column orientation [P*bs, 1].
@@ -375,7 +384,10 @@ def _mq_kernel(
         valid = pos < length
 
         k_chunk = kbuf[cur].reshape(P * bs, D)
-        v_chunk = vbuf[cur].reshape(P * bs, D)
+        if value_dim:
+            v_chunk = k_chunk[:, :value_dim]
+        else:
+            v_chunk = vbuf[cur].reshape(P * bs, D)
         if quantized:
             # In-register dequant of the just-landed int8 pages: expand
             # this chunk's [P*bs, KVH] scales across each head's lanes and
@@ -462,9 +474,13 @@ def _paged_attention_mq(
     pages_per_chunk: int,
     interpret: bool,
     anc: jax.Array | None = None,  # [B, T, T] — tree topology mask
+    *,
+    value_dim: int = 0,            # latent pool: V = a row's first lanes
+    scale: float | None = None,    # softmax scale (default hd ** -0.5)
 ) -> jax.Array:
     """Shared Pallas driver: T query positions per row walk the row's
-    true pages once. Returns [B, T, KVH, G, hd] in q.dtype."""
+    true pages once. Returns [B, T, KVH, G, hd] in q.dtype
+    ([B, T, 1, G, value_dim] over a latent pool, ``v_cache`` None)."""
     B, T, KVH, G, hd = q.shape
     bs = k_cache.shape[2]
     assert k_cache.shape[3] == KVH * hd, "cache must be [L, N, bs, KVH*hd]"
@@ -487,7 +503,9 @@ def _paged_attention_mq(
     # Block-diagonal q with the softmax scale folded in:
     # qbd[b, j*hd+h, k*(T*G)+t*G+g] = q[b,t,k,g,h] * scale * (j==k).
     eye = jnp.eye(KVH, dtype=q.dtype)
-    qbd = jnp.einsum("btkgh,jk->bjhktg", q * (hd ** -0.5), eye)
+    qbd = jnp.einsum(
+        "btkgh,jk->bjhktg", q * (hd ** -0.5 if scale is None else scale), eye
+    )
     qbd = qbd.reshape(B, KVH * hd, H)
     # Per-column attend horizon, same (k, t, g) column order as qbd.
     # Carried [B, 1, H]: Mosaic wants a block's last two dims to be whole
@@ -534,27 +552,26 @@ def _paged_attention_mq(
             pl.BlockSpec((1, P, bs, KVH), lambda b, c, *_: (b, c, 0, 0)),
             pl.BlockSpec((1, P, bs, KVH), lambda b, c, *_: (b, c, 0, 0)),
         ]
-    operands += [k_cache, v_cache]
-    in_specs += [
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
+    pools = [k_cache] if value_dim else [k_cache, v_cache]
+    operands += pools
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY) for _ in pools]
+    out_rows = value_dim or KVH * hd
 
     kernel = functools.partial(
         _mq_kernel, pages_per_chunk=P, head_dim=hd, quantized=quantized,
-        tree_slots=T if anc is not None else 0,
+        tree_slots=T if anc is not None else 0, value_dim=value_dim,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, chunks_max),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, KVH * hd, H), lambda b, c, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, out_rows, H), lambda b, c, *_: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, P, bs, KVH * hd), k_cache.dtype),
-            pltpu.VMEM((2, P, bs, KVH * hd), v_cache.dtype),
+            pltpu.VMEM((2, P, bs, KVH * hd), pool.dtype) for pool in pools
+        ] + [
             pltpu.VMEM((8, 128), jnp.float32),
             pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((KVH * hd, H), jnp.float32),
+            pltpu.VMEM((out_rows, H), jnp.float32),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
             pltpu.SemaphoreType.DMA((2, 2, P)),
@@ -563,7 +580,7 @@ def _paged_attention_mq(
     o_t = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH * hd, H), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, out_rows, H), q.dtype),
         interpret=interpret,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
@@ -572,7 +589,7 @@ def _paged_attention_mq(
         *operands,
     )
     # [B, KVH*hd, KVH*T*G] → per-head diagonal → [B, T, KVH, G, hd].
-    o6 = o_t.reshape(B, KVH, hd, KVH, T, G)
+    o6 = o_t.reshape(B, KVH, out_rows // KVH, KVH, T, G)
     return jnp.einsum("bkhktg->btkgh", o6)
 
 
@@ -640,3 +657,68 @@ def paged_spec_attention(
         q, k_cache, v_cache, layer_idx, block_tables, lengths,
         k_scale, v_scale, pages_per_chunk, interpret, anc,
     )
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) pages: one pool ``[2L, N, bs, Dk]``, a row = the key every
+# head shares (the normed latent and the rotated rope key, padded to whole
+# lane tiles), its first ``value_dim`` lanes also the value. Decode attends
+# in the absorbed form, so the queries arrive already times W_uk.
+# ---------------------------------------------------------------------------
+
+
+def latent_kernel_unsupported(cfg, block_size: int) -> str | None:
+    """Why the compiled kernel cannot serve a latent ``cfg``, or None."""
+    if cfg.latent_page_width % 128 or cfg.kv_lora_rank % 128:
+        return (
+            f"latent page row {cfg.latent_page_width} / value {cfg.kv_lora_rank} "
+            f"lanes; page DMAs and the value slice need multiples of 128"
+        )
+    if cfg.num_heads > 128:
+        return f"{cfg.num_heads} query heads > 128 lanes"
+    if block_size < 8:
+        return f"block_size {block_size} < 8: a page is under one bf16 DMA tile"
+    return None
+
+
+def latent_decode_attention_xla(
+    q: jax.Array,            # [B, H, Dk] absorbed queries (zero in the padding lanes)
+    cache: jax.Array,        # [2L, N, bs, Dk]
+    layer_idx: jax.Array,    # scalar int32 — cache layer (2*layer + sub-block)
+    block_tables: jax.Array, # [B, W] int32
+    lengths: jax.Array,      # [B] int32
+    *, value_dim: int, scale: float,
+) -> jax.Array:
+    """Gather-based reference of the latent decode attention → [B, H, value_dim]."""
+    B, H, Dk = q.shape
+    pk = gather_dequant_pages(cache, None, layer_idx, block_tables, 1, Dk, q.dtype)[:, :, 0]
+    ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
+    mask = jnp.where(ctx[None, :] < lengths[:, None], 0.0, jnp.float32(NEG_INF))
+    s = jnp.einsum("bhd,bcd->bhc", q, pk).astype(jnp.float32) * scale
+    p = jax.nn.softmax(s + mask[:, None, :], axis=-1).astype(q.dtype)
+    return jnp.einsum("bhc,bcv->bhv", p, pk[..., :value_dim])
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("value_dim", "scale", "pages_per_chunk", "interpret"),
+)
+def latent_decode_attention(
+    q: jax.Array,            # [B, H, Dk]
+    cache: jax.Array,        # [2L, N, bs, Dk]
+    layer_idx: jax.Array,
+    block_tables: jax.Array,
+    lengths: jax.Array,
+    *, value_dim: int, scale: float,
+    pages_per_chunk: int = 0,
+    interpret: bool = False,
+) -> jax.Array:
+    """The multi-query kernel at the latent geometry: H query heads against
+    one shared key of Dk lanes, value = its first ``value_dim``; each page
+    is read once. Returns [B, H, value_dim]."""
+    o = _paged_attention_mq(
+        q[:, None, None], cache, None, layer_idx, block_tables,
+        jnp.asarray(lengths, jnp.int32)[:, None], None, None,
+        pages_per_chunk, interpret, value_dim=value_dim, scale=scale,
+    )
+    return o[:, 0, 0]

@@ -182,3 +182,52 @@ def test_overlap_sweeps_both_lists_once():
     spans = [(5.0, 25.0), (28.0, 29.0), (45.0, 100.0)]
     assert host_phases.overlap(gaps, spans) == [5.0, 6.0, 5.0]
     assert host_phases.overlap(gaps, []) == [0.0, 0.0, 0.0]
+
+
+# -- the grouped expert product's roofline, by program (PR 30) --------------------
+
+LONGCAT_CONFIG = os.path.join(ROOT, "chipbench", "configs", "longcat-flash-omni-ep32.json")
+
+
+def expert_ctx(**drop) -> dict:
+    """One worker whose counters grew over the window by 4,000 decode calls
+    touching 40,000 experts (10 a call) and 400 prefill calls touching 5,200
+    (13 a call); a trace of 10 decode windows (8 steps, 4 layers: 320 calls)
+    and 25 + 5 prefill executions (120 calls)."""
+    touched, calls = "dynamo_tpu_moe_experts_touched_total", "dynamo_tpu_moe_expert_calls_total"
+    after = page(**{f'{touched}{{program="decode"}}': 41000, f'{calls}{{program="decode"}}': 4100,
+                    f'{touched}{{program="prefill"}}': 5300, f'{calls}{{program="prefill"}}': 410})
+    before = page(**{f'{touched}{{program="decode"}}': 1000, f'{calls}{{program="decode"}}': 100,
+                     f'{touched}{{program="prefill"}}': 100, f'{calls}{{program="prefill"}}': 10})
+    trace = {"modules": {"jit_multi_decode_impl": [1.5, 10], "jit_prefill_batch_impl": [1.3, 25],
+                         "jit_prefill_impl": [0.1, 5]},
+             "ops_by_module": {"jit_multi_decode_impl": {"gmm": 0.4, "latent_decode_attention": 0.3},
+                               "jit_prefill_batch_impl": {"gmm": 0.15}, "jit_prefill_impl": {"gmm": 0.05}},
+             "op_counts": {"gmm": 1320}}
+    with open(LONGCAT_CONFIG) as f:
+        config = json.load(f)
+    c = ctx(replicas=1, trace=trace, config=config, stats={"0.0": {"kind": "TPU v5 lite"}})
+    c["prom"] = {"worker0.before": before, "worker0.after": after}
+    for who, mark in drop.items():
+        c["prom"][who] = {k: v for k, v in c["prom"][who].items() if mark not in k}
+    return c
+
+
+@pytest.mark.parametrize("name, calls, touched_a_call, gmm_s", [
+    ("moe_expert_roofline", 10 * 8 * 4, 10.0, 0.4),
+    ("moe_prefill_expert_roofline", (25 + 5) * 4, 13.0, 0.2),
+])
+def test_expert_roofline_counts_its_calls_in_the_trace(name, calls, touched_a_call, gmm_s):
+    """Calls from the trace's executions, experts touched a call from the
+    program's counters by program, one expert 3 x 6144 x 2048 x 2 B, over the
+    gmm kernel's seconds in those programs; nothing is scaled by how much of
+    the window the trace held."""
+    with open(os.path.join(ROOT, "chipbench", "peaks.json")) as f:
+        bw = json.load(f)["devices"]["TPU v5 lite"]["hbm_bytes_per_s"]
+    want = 100 * calls * touched_a_call * (3 * 6144 * 2048 * 2) / bw / gmm_s
+    assert read(name, expert_ctx()) == pytest.approx(want) and want < 100
+    longer = expert_ctx()
+    longer["seconds"] = 45.0  # the window's length is not in it
+    assert read(name, longer) == pytest.approx(want)
+    assert read(name, expert_ctx(**{"worker0.after": "moe_expert_calls"})) is None  # the parent's pages
+    assert read(name, {**expert_ctx(), "trace": None}) is None

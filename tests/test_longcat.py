@@ -1,0 +1,401 @@
+"""The LongCat-Flash block (ISSUE 30) on the CPU at a toy size with seeded
+weights: the program against the benchmark's plain reference, absorbed against
+expanded attention, the router's arithmetic, the shares of an expert-parallel
+layer adding up, latent pages under the prefix cache, and what refuses the block."""
+
+import asyncio
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import model_maps, references
+from dynamo_tpu.engine import longcat
+from dynamo_tpu.engine import model as M
+from dynamo_tpu.engine.config import EngineArgs, ModelConfig
+from dynamo_tpu.engine.engine import TpuEngine
+from dynamo_tpu.llm.protocols import PreprocessedRequest
+from dynamo_tpu.ops import paged_attention
+from dynamo_tpu.runtime.engine import Context
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+with open(os.path.join(HERE, "..", "chipbench", "configs", "rehearse-longcat-tiny.json")) as f:
+    DOC = json.load(f)
+CFG = model_maps.model_config(DOC)
+REF = references.load("longcat_scmoe")
+BS = 8
+
+
+def doc_for(dtype: str, **over) -> dict:
+    return {**DOC, **over, "served": {**DOC["served"], "dtype": dtype}}
+
+
+def prompt(n: int, seed: int = 0) -> list[int]:
+    return [int(t) for t in np.random.RandomState(seed).randint(0, CFG.vocab_size, n)]
+
+
+def serve_through_cache(params, dtype, toks, plen: int, mode: str, impl: str) -> np.ndarray:
+    # "pallas_interpret" takes both kernels in interpret mode: the latent
+    # decode attention and the megablox grouped product
+    """Prefill ``toks[:plen]`` (cold, in two chunks, or its second half behind
+    pages an earlier prefill cached) and decode the rest teacher-forced through
+    the paged cache → float32 logits at positions plen-1 .. len(toks)-1."""
+    cache = longcat.init_kv_cache(CFG, 32, BS, dtype)
+    table = jnp.arange(1, 9, dtype=jnp.int32)
+    kw = {"experts": "gmm_interpret"} if impl == "pallas_interpret" else {}
+    pad = lambda xs, n: jnp.zeros((n,), jnp.int32).at[:len(xs)].set(jnp.asarray(xs, jnp.int32))  # noqa: E731
+    if mode == "cold":
+        logits, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[:plen], 48), table, 0, plen, **kw)
+    else:
+        cut = 16  # whole blocks
+        _, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[:cut], 16), table, 0, cut, **kw)
+        if mode == "cached":  # another dispatch wrote the pages; only the table names them
+            cache = jax.tree.map(jnp.copy, cache)
+        logits, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[cut:plen], 32), table, cut, plen, **kw)
+    out = [logits]
+    for pos in range(plen, len(toks)):
+        step, cache, _ = longcat.decode_step(
+            CFG, params, cache, jnp.asarray([toks[pos], 0], jnp.int32), jnp.asarray([pos, 0], jnp.int32),
+            jnp.stack([table, table]), jnp.asarray([True, False]), attn_impl=impl, **kw)
+        out.append(step[0])
+    return np.asarray(jnp.stack(out), np.float32)
+
+
+# Tolerances, from these sizes on the CPU (seeds 0-2 read). float32 against the
+# float32 reference differs by summation order alone: the widest gap must stay
+# under 2e-4 (1e-5 read). bf16 weights are the same numbers on both sides, so
+# bf16 reads what rounding activations and cached latents to 8 bits of mantissa
+# costs: 0.011-0.019 in the mean over the logits; its widest gap (0.06-0.27)
+# swings with a near-tie in the router's top-k and is not held. The same
+# program on weights rounded to float8_e4m3 (3 bits) reads 0.127-0.142 in the
+# mean: the limit 0.045 tells bf16 from the precision under it.
+TOL = {"float32": ("max", 2e-4), "bfloat16": ("mean", 0.045)}
+
+
+def gap_of(got: np.ndarray, want: np.ndarray, dtype: str) -> float:
+    diff = np.abs(got - want[:len(got)])
+    return float(diff.max() if TOL[dtype][0] == "max" else diff.mean())
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("mode", ["cold", "chunked", "cached"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_prefill_then_decode_agrees_with_the_reference_forward(dtype, mode, impl):
+    doc = doc_for(dtype)
+    params = REF.weights(doc, 0)
+    toks, plen = prompt(46), 40
+    want = np.asarray(REF.forward(doc, params, toks), np.float32)[plen - 1:len(toks)]
+    got = serve_through_cache(params, jnp.dtype(dtype), toks, plen, mode, impl)
+    assert got.shape == want.shape
+    assert gap_of(got, want, dtype) < TOL[dtype][1]
+
+
+def test_a_precision_under_bf16_fails_the_bf16_tolerance():
+    doc = doc_for("bfloat16")
+    params = REF.weights(doc, 0)
+    toks, plen = prompt(46), 40
+    want = np.asarray(REF.forward(doc, params, toks), np.float32)[plen - 1:len(toks)]
+    low = jax.tree.map(
+        lambda a: a.astype(jnp.float8_e4m3fn).astype(a.dtype) if a.dtype == jnp.bfloat16 else a, params)
+    got = serve_through_cache(low, jnp.bfloat16, toks, plen, "cold", "xla")
+    assert gap_of(got, want, "bfloat16") > 2 * TOL["bfloat16"][1]
+
+
+def test_the_programs_initialiser_is_the_references():
+    mine = longcat.init_params(CFG, jax.random.PRNGKey(3), jnp.bfloat16)
+    theirs = REF.weights(doc_for("bfloat16"), 3)
+    assert jax.tree.structure(mine) == jax.tree.structure(theirs)
+    assert all(jax.tree.leaves(jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)), mine, theirs)))
+    bias = mine["layers"]["router_bias"]
+    assert float(jnp.std(bias)) > 0  # drawn, not zeros
+
+
+def _sub(params, layer: int, j: int) -> dict:
+    return {name: params["layers"][f"{name}_{j}"][layer] for name in longcat._SUB_KEYS}
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas_interpret"])
+def test_absorbed_attention_equals_expanded(kernel):
+    """One query over 37 cached latents: W_kvb multiplied into the keys and
+    values (expanded, as published) against W_uk absorbed into the query and
+    W_uv applied to the attended latents, over latent pages."""
+    params = longcat.init_params(CFG, jax.random.PRNGKey(1), jnp.float32)
+    sub = _sub(params, 0, 1)
+    n = 37
+    h = jax.random.normal(jax.random.PRNGKey(2), (1, n, CFG.hidden_size), jnp.float32)
+    pos = jnp.arange(n, dtype=jnp.int32)[None]
+    q_n, q_r, latent = longcat.mla_project(h, sub, CFG, pos)
+    mask = jnp.where(jnp.arange(n)[None, :] <= jnp.arange(n)[:, None], 0.0, -1e9)[None]
+    expanded = longcat.attend_expanded(q_n, q_r, latent, mask, sub, CFG)[0, -1]
+    pool = jnp.zeros((2, 8, BS, CFG.latent_page_width), jnp.float32)
+    table = jnp.arange(1, 6, dtype=jnp.int32)[None]
+    rows = longcat._pad_row(latent[0], CFG)
+    pool = pool.at[1, table[0, jnp.arange(n) // BS], jnp.arange(n) % BS].set(rows)
+    q = longcat.absorb_query(q_n[:, -1], q_r[:, -1], sub, CFG)
+    kw = dict(value_dim=CFG.kv_lora_rank, scale=(CFG.qk_nope_head_dim + CFG.qk_rope_head_dim) ** -0.5)
+    if kernel == "xla":
+        o = paged_attention.latent_decode_attention_xla(q, pool, 1, table, jnp.asarray([n]), **kw)
+    else:
+        o = paged_attention.latent_decode_attention(q, pool, 1, table, jnp.asarray([n]), interpret=True, **kw)
+    absorbed = longcat.unabsorb_output(o, sub, CFG)[0]
+    np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded), atol=2e-5)
+
+
+# -- the router ------------------------------------------------------------------
+
+
+def _router_case():
+    params = longcat.init_params(CFG, jax.random.PRNGKey(4), jnp.float32)
+    lp = {**{k: v[0] for k, v in params["layers"].items()}, "moe_layer": jnp.int32(0)}
+    h = jax.random.normal(jax.random.PRNGKey(5), (24, CFG.hidden_size), jnp.float32)
+    probs = jax.nn.softmax(h @ lp["w_router"], axis=-1)
+    return lp, h, np.asarray(probs)
+
+
+@pytest.mark.parametrize("what", ["bias_moves_the_choice", "not_the_weights", "no_renormalisation", "scaling"])
+def test_router(what):
+    lp, h, probs = _router_case()
+    k, R = CFG.num_experts_per_token, CFG.router_width
+    plain = {**lp, "router_bias": jnp.zeros((R,), jnp.float32)}
+    topi0, topw0 = (np.asarray(a) for a in longcat.route(h, plain, CFG))
+    if what == "bias_moves_the_choice":
+        assert (np.sort(topi0, -1) == np.sort(np.argsort(-probs, -1)[:, :k], -1)).all()
+        low = int(np.argmin(probs.sum(0)))  # the least likely expert, lifted over all
+        topi, _ = longcat.route(h, {**lp, "router_bias": jnp.zeros((R,)).at[low].set(1.0)}, CFG)
+        assert (np.asarray(topi) == low).any(-1).all() and not (topi0 == low).any(-1).all()
+    elif what == "not_the_weights":
+        bias = jnp.zeros((R,)).at[3].set(1.0)
+        topi, topw = (np.asarray(a) for a in longcat.route(h, {**lp, "router_bias": bias}, CFG))
+        got = np.take_along_axis(probs, topi, -1) * CFG.routed_scaling_factor
+        np.testing.assert_allclose(topw, got, rtol=1e-6)  # scaling * p, with no bias in it
+    elif what == "no_renormalisation":
+        want = np.take_along_axis(probs, topi0, -1).sum(-1) * CFG.routed_scaling_factor
+        np.testing.assert_allclose(topw0.sum(-1), want, rtol=1e-6)
+        assert np.abs(topw0.sum(-1) - 1.0).min() > 1e-3
+    else:
+        half = dataclasses.replace(CFG, routed_scaling_factor=CFG.routed_scaling_factor / 2)
+        np.testing.assert_allclose(np.asarray(longcat.route(h, plain, half)[1]) * 2, topw0, rtol=1e-6)
+
+
+def test_zero_compute_experts_add_w_times_h():
+    lp, h, probs = _router_case()
+    n_routed, R = CFG.num_routed_experts, CFG.router_width
+    bias = jnp.zeros((R,)).at[n_routed:].set(1.0)  # every choice a zero-compute expert
+    y, hist = longcat.moe(h, jnp.ones((24,), bool), {**lp, "router_bias": bias}, CFG, "ragged_dot")
+    topi, topw = longcat.route(h, {**lp, "router_bias": bias}, CFG)
+    assert (np.asarray(topi) >= n_routed).all()
+    np.testing.assert_allclose(np.asarray(y), np.asarray(topw.sum(-1, keepdims=True) * h), rtol=1e-5, atol=1e-6)
+    E = CFG.num_experts
+    assert hist[:E].sum() == 0 and hist[E] == 24 * CFG.num_experts_per_token and hist[E + 2] == 24
+    assert hist[E + 3] == 0 and hist[E + 4] == 1  # no held expert was touched, in one call
+
+
+@pytest.mark.parametrize("impl", ["ragged_dot", "gmm_interpret"])
+def test_no_token_is_dropped_whatever_the_imbalance(impl):
+    """Every token's every choice on ONE held expert's neighbours: the grouped
+    product has room for all of them, and padding rows count nowhere."""
+    lp, h, _ = _router_case()
+    R, off, E = CFG.router_width, CFG.expert_offset, CFG.num_experts
+    bias = jnp.zeros((R,)).at[off:off + E].set(1.0)   # both held experts always chosen
+    lp = {**lp, "router_bias": bias}
+    valid = jnp.arange(24) < 20
+    y, hist = longcat.moe(h, valid, lp, CFG, impl)
+    assert list(np.asarray(hist[:E])) == [20, 20] and hist[E + 2] == 20 and hist[E + 3] == E
+    topi, topw = longcat.route(h, lp, CFG)
+    want = np.zeros_like(np.asarray(h))
+    for e in range(E):
+        w_e = np.asarray(jnp.sum(jnp.where(topi == off + e, topw, 0.0), -1, keepdims=True))
+        g, u, d = (np.asarray(lp[n][e]) for n in ("moe_gate", "moe_up", "moe_down"))
+        x = np.asarray(h)
+        want += w_e * ((np.asarray(jax.nn.silu(x @ g)) * (x @ u)) @ d)
+    zero = np.asarray(jnp.sum(jnp.where(topi >= CFG.num_routed_experts, topw, 0.0), -1, keepdims=True) * h)
+    np.testing.assert_allclose(np.asarray(y)[:20], (want + zero)[:20], rtol=2e-4, atol=2e-5)
+
+
+# -- the shares add up -----------------------------------------------------------
+
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(monkeypatch):
+    """4 shares of 8 routed experts: over all shares, the held parts plus the
+    zero-compute part and the dense path counted once equal the uncut
+    reference's layer (every expert held)."""
+    uncut_doc = doc_for("float32", num_layers=1, n_routed_experts=8, first_expert_held=0)
+    full = REF.weights(uncut_doc, 7)
+    toks = prompt(16, seed=3)
+    T = len(toks)
+    x = REF._embed(full, jnp.asarray(toks, jnp.int32))
+    want = REF._layer(x, jnp.zeros((T,), jnp.int32), jnp.arange(T, dtype=jnp.int32), full["layers"],
+                      jnp.int32(0), REF.sizes(uncut_doc))
+
+    monkeypatch.setattr(longcat, "_logits", lambda _cfg, _params, x_last: x_last)  # the hidden state, before the final norm
+
+    def hidden(first: int, held: bool) -> np.ndarray:
+        cfg = dataclasses.replace(CFG, num_layers=1, expert_offset=first)
+        layers = dict(full["layers"])
+        for name in ("moe_gate", "moe_up", "moe_down"):
+            part = layers[name][:, first:first + cfg.num_experts]
+            layers[name] = part if held else jnp.zeros_like(part)
+        out, _, _ = longcat.prefill_batch_impl(
+            cfg, {**full, "layers": layers}, longcat.init_kv_cache(cfg, 8, BS, jnp.float32),
+            jnp.asarray([toks], jnp.int32), jnp.asarray([[1, 2]], jnp.int32),
+            jnp.asarray([0], jnp.int32), jnp.asarray([T], jnp.int32))
+        return np.asarray(out[0], np.float64)
+
+    once = hidden(0, held=False)  # the dense path and the zero-compute part, nothing held
+    total = once + sum(hidden(first, held=True) - once for first in range(0, 8, 2))
+    np.testing.assert_allclose(total, np.asarray(want[-1], np.float64), rtol=2e-4, atol=2e-4)
+    assert np.abs(total - once).max() > 1e-2  # the held experts do add something
+
+
+# -- latent pages under the block manager ------------------------------------------
+
+
+def greedy(prompt_ids, max_tokens=6, **ktp) -> PreprocessedRequest:
+    req = PreprocessedRequest(model="t", token_ids=list(prompt_ids))
+    req.sampling.temperature = 0.0
+    req.sampling.seed = 0
+    req.stop.max_tokens = max_tokens
+    if ktp:
+        req.kv_transfer_params = ktp
+    return req
+
+
+def engine_args(**kw) -> EngineArgs:
+    return EngineArgs(**{**dict(model=CFG, block_size=BS, num_kv_blocks=24, max_num_seqs=4, max_model_len=128,
+                                max_prefill_tokens=64, dtype="float32"), **kw})
+
+
+async def _tokens(engine, req) -> list[int]:
+    return [t async for o in engine.generate(req, Context()) for t in o.get("token_ids", [])]
+
+
+@pytest.mark.parametrize("case", ["prefix_hit", "eviction", "preemption"])
+def test_prefix_cache_eviction_and_preemption_on_latent_pages(case):
+    """The block manager's behaviour is unchanged on latent pages: a resent
+    prompt hits its cached blocks and gives the same tokens; blocks evicted
+    under pressure are recomputed to the same tokens; a sequence preempted for
+    want of blocks requeues and finishes with the tokens it would have had."""
+    first, other = prompt(40, seed=1), prompt(40, seed=2)
+
+    async def go():
+        if case == "preemption":  # 3 x (40 + 30) tokens want 27 blocks of a pool of 18
+            engine = await TpuEngine(engine_args(num_kv_blocks=18)).start()
+            try:
+                alone = [await _tokens(engine, greedy(prompt(40, seed=s), 30)) for s in (1, 2, 3)]
+                n0 = sum(engine.total_preemptions_by.values())
+                together = await asyncio.gather(*(_tokens(engine, greedy(prompt(40, seed=s), 30)) for s in (1, 2, 3)))
+                return alone, list(together), sum(engine.total_preemptions_by.values()) - n0
+            finally:
+                await engine.stop()
+        engine = await TpuEngine(engine_args()).start()
+        try:
+            a = await _tokens(engine, greedy(first))
+            hits0 = engine.pool.hit_blocks
+            if case == "eviction":  # fill the pool with other prompts until the first one's blocks go
+                for s in range(10, 16):
+                    await _tokens(engine, greedy(prompt(40, seed=s)))
+            b = await _tokens(engine, greedy(first))
+            return a, b, engine.pool.hit_blocks - hits0
+        finally:
+            await engine.stop()
+
+    a, b, n = asyncio.run(go())
+    assert a == b and len(b[0] if case == "preemption" else b) > 0
+    if case == "prefix_hit":
+        assert n == (40 - 1) // BS
+    elif case == "eviction":
+        assert n < (40 - 1) // BS  # some of the history was gone and was recomputed
+    else:
+        assert n > 0
+
+
+def test_pool_accounting_is_in_bytes_of_the_latent_page():
+    args = engine_args(dtype="bfloat16")
+    assert CFG.cache_layers == 2 * CFG.num_layers and CFG.latent_page_width == 128
+    assert args.kv_bytes_per_block() == CFG.cache_layers * BS * CFG.latent_page_width * 2
+    cache = longcat.init_kv_cache(CFG, args.num_kv_blocks, BS)
+    assert cache.v is None and cache.k.shape == (4, 24, BS, 128)
+    assert cache.k.nbytes == args.num_kv_blocks * args.kv_bytes_per_block()
+
+
+# -- what refuses the block --------------------------------------------------------
+
+
+@pytest.mark.parametrize("kw,names", [
+    (dict(kv_quant="int8"), "--kv-quant int8"),
+    (dict(spec_tokens=2), "speculation"),
+    (dict(lora_slots=2), "LoRA"),
+    (dict(quant="int8"), "--quant int8"),
+    (dict(host_kv_blocks=8), "KV tiers"),
+    (dict(tp=2), "--tp"),
+])
+def test_engine_args_refuse_what_cannot_carry_the_block(kw, names):
+    with pytest.raises(ValueError, match="longcat") as e:
+        engine_args(**kw)
+    assert names in str(e.value)
+
+
+@pytest.mark.parametrize("what", ["embed", "spec_verify", "extract_pages", "inject_pages", "transfer", "migration"])
+def test_mechanisms_refuse_the_block_by_name(what):
+    if what == "embed":
+        with pytest.raises(ValueError, match="embed_impl"):
+            M.embed_impl(CFG, {}, jnp.zeros((8,), jnp.int32), jnp.int32(4))
+    elif what == "spec_verify":
+        with pytest.raises(ValueError, match="spec_verify_impl"):
+            M.spec_verify_impl(CFG, 2, "greedy", 0, {}, None, *([None] * 8))
+    elif what in ("extract_pages", "inject_pages"):
+        from dynamo_tpu.engine.runner import LocalRunner
+
+        runner = LocalRunner(engine_args())
+        with pytest.raises(ValueError, match="transfer"):
+            runner.extract_pages([1]) if what == "extract_pages" else runner.inject_pages([1], None, None)
+    else:
+        async def go():
+            engine = await TpuEngine(engine_args()).start()
+            try:
+                if what == "transfer":
+                    outs = [o async for o in engine.generate(greedy(prompt(20), do_remote_decode=True), Context())]
+                    return outs[-1].get("error", "")
+                got = await engine.run_on_engine_thread(lambda: engine.migration_begin("any"))
+                return got.get("error", "")
+            finally:
+                await engine.stop()
+
+        assert "latent" in asyncio.run(go())
+
+
+# -- the block is chosen once, and every program has one shape ---------------------
+
+
+@pytest.mark.parametrize("preset, hist", [("test-tiny", False), ("longcat-tiny", True)])
+def test_the_runner_takes_its_programs_from_the_blocks_module(preset, hist):
+    """``model.block_module`` names the module; the runner's programs return a
+    routing histogram in the last place, None from the dense block."""
+    from dynamo_tpu.engine.runner import LocalRunner
+
+    cfg = ModelConfig.preset(preset)
+    assert M.block_module(cfg) is (longcat if hist else M)
+    runner = LocalRunner(EngineArgs(model=cfg, block_size=BS, num_kv_blocks=8, max_num_seqs=2,
+                                    max_model_len=64, dtype="float32"))
+    runner.start()
+    assert ("experts=ragged_dot" in runner._start_line("")) == hist
+    table = np.arange(1, 5, dtype=np.int32)
+    ref = runner.prefill_chunk(np.zeros((16,), np.int32), table, 0, 9)
+    assert (ref.hist is not None) == hist and len(ref.arrs) == 1
+    assert len(runner.take_routed()) == (1 if hist else 0)
+    step = runner.decode_step(np.zeros((2,), np.int32), np.asarray([9, 0], np.int32),
+                              np.stack([table, table]), np.asarray([True, False]))
+    assert (step.hist is not None) == hist
+    if hist:
+        E = cfg.num_experts
+        assert step.hist.shape == (cfg.num_layers, E + longcat.HIST_EXTRA)
+        assert int(step.hist[0, E + 2]) == 1 and int(step.hist[0, E + 4]) == 1  # one token, one call a layer
+
+
+def test_a_block_without_a_module_is_refused():
+    with pytest.raises(ValueError, match="no module runs block='mamba'"):
+        M.block_module(dataclasses.replace(CFG, block="mamba"))

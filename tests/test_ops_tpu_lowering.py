@@ -187,3 +187,89 @@ def test_prefill_forms_no_layer_of_the_pool_on_v5e(v5e, kv_quant):
         if shape in layer or (shape in pool and not entry and opcode not in ("scatter", "fusion")):
             found.append(line.strip()[:120])
     assert not found, "\n".join(found)
+
+
+# -- the LongCat block: latent pages and the grouped expert product ---------------
+
+
+def _longcat(num_layers: int = 1) -> ModelConfig:
+    """The benchmark's LongCat configuration at its published widths (a
+    chip's share: 16 of 512 experts, 16,384 vocabulary rows)."""
+    return ModelConfig(
+        name="longcat-share", block="longcat", vocab_size=16384, published_vocab_size=131072,
+        hidden_size=6144, intermediate_size=12288, num_layers=num_layers, num_heads=64,
+        num_kv_heads=1, head_dim=192, rope_theta=1e7, tie_embeddings=False,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, mla_scale_q_lora=True, mla_scale_kv_lora=True,
+        num_experts=16, num_routed_experts=512, expert_offset=0, zero_expert_num=256,
+        num_experts_per_token=12, moe_intermediate_size=2048, routed_scaling_factor=6.0,
+    )
+
+
+LBS = 32  # the latent configuration's block size
+
+
+def _abstract(tree, S):
+    return jax.tree.map(lambda a: S(a.shape, a.dtype), tree)
+
+
+def test_latent_decode_kernel_compiles_for_v5e(v5e):
+    """64 query heads against one shared 576-wide key padded to 640 lanes,
+    value = its first 512, 128 rows over a 4,096-token table."""
+    from dynamo_tpu.ops.paged_attention import (
+        latent_decode_attention,
+        latent_kernel_unsupported,
+    )
+
+    cfg = _longcat()
+    assert cfg.latent_page_width == 640 and latent_kernel_unsupported(cfg, LBS) is None
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    B, W = 128, 4096 // LBS
+    fn = functools.partial(latent_decode_attention, value_dim=512, scale=192 ** -0.5)
+    jax.jit(fn).lower(
+        S((B, 64, 640), jnp.bfloat16), S((8, 2 * W, LBS, 640), jnp.bfloat16),
+        S((), jnp.int32), S((B, W), jnp.int32), S((B,), jnp.int32),
+    ).compile()
+
+
+@pytest.mark.parametrize("rows,k,n", [(1536, 6144, 2048), (6144, 2048, 6144)])
+def test_grouped_expert_matmul_compiles_for_v5e(v5e, rows, k, n):
+    from dynamo_tpu.engine.longcat import grouped_expert_matmul
+
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    grouped_expert_matmul.lower(
+        S((rows, k), jnp.bfloat16), S((16, k, n), jnp.bfloat16), S((16,), jnp.int32),
+        impl="gmm",
+    ).compile()
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk_2048", "prefill_packed_256"])
+def test_longcat_programs_compile_for_v5e(v5e, program):
+    """The jitted programs the cell runs, at the published widths (one
+    layer of the four; the layer is one scan body): the decode window at
+    128 rows, a 2,048-token chunk and a 256-token packed prefill behind a
+    4,096-token table. What a program adds to its arguments must leave
+    room in 16 GB beside 10.4 GB of weights and a 3 GB pool."""
+    cfg = _longcat()
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    from dynamo_tpu.engine import longcat
+
+    params = _abstract(jax.eval_shape(lambda: longcat.init_params(cfg, jax.random.PRNGKey(0))), S)
+    W, N = 4096 // LBS, 2048
+    cache = _abstract(jax.eval_shape(lambda: longcat.init_kv_cache(cfg, N, LBS)), S)
+    i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
+    if program == "decode_window":
+        B = 128
+        flags = S((B,), jnp.bool_)
+        compiled = longcat.multi_decode.lower(
+            cfg, 8, "greedy", 0, params, cache,
+            i32(B), i32(B), i32(B, W), flags, f32(B), S((B,), jnp.uint32), i32(B),
+            i32(B), f32(B), f32(B), f32(B), i32(B, 1), flags, i32(B), i32(B + 1),
+            None, None, attn_impl="pallas", experts="gmm",
+        ).compile()
+    else:
+        T = int(program.rsplit("_", 1)[1])
+        compiled = longcat.prefill_batch.lower(
+            cfg, params, cache, i32(1, T), i32(1, W), i32(1), i32(1), experts="gmm"
+        ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9

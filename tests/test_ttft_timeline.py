@@ -339,3 +339,82 @@ def test_a_profiler_trace_holds_the_scheduler_phases(tmp_path):
     # the phases of one thread do not overlap: each closes before the next opens
     one = sorted((e[1], e[1] + e[2]) for e in spans)
     assert all(a[1] <= b[0] + 1e3 for a, b in zip(one, one[1:]))
+
+
+LONGCAT_SCOPES = ("mla_q", "mla_kv_write", "mla_attn", "mla_out", "ffn_dense",
+                  "moe_route", "moe_experts", "moe_zero")
+
+
+def test_longcat_programs_keep_their_scope_and_kernel_names():
+    """The LongCat block's programs under the same jit names, its scopes, and
+    the two kernels by the names chipbench/layer_metrics/_latent.py searches."""
+    from dynamo_tpu.engine import longcat
+
+    cfg = ModelConfig.preset("longcat-tiny")
+    assert paged_attention.latent_decode_attention.__name__ == "latent_decode_attention"
+    assert longcat.grouped_expert_matmul.__name__ == "grouped_expert_matmul"
+    params = longcat.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    cache = longcat.init_kv_cache(cfg, 16, 8, jnp.float32)
+    B, W, K = 8, 4, 2
+    z = np.zeros((B,), np.int32)
+    text = longcat.multi_decode.lower(
+        cfg, K, "greedy", 0, params, cache, jnp.asarray(z), jnp.asarray(z),
+        jnp.zeros((B, W), jnp.int32), jnp.zeros((B,), bool), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.uint32), jnp.asarray(z), jnp.asarray(z), jnp.ones((B,), jnp.float32),
+        jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.float32), jnp.full((B, 1), -1, jnp.int32),
+        jnp.zeros((B,), bool), jnp.asarray(z), jnp.zeros((5,), jnp.int32), None, None,
+        attn_impl="pallas_interpret", experts="gmm_interpret",
+    ).as_text(debug_info=True)
+    assert "jit_multi_decode_impl" in text
+    assert "latent_decode_attention" in text and "grouped_expert_matmul" in text
+    for scope in ("embed", *LONGCAT_SCOPES, "logits", "sample"):
+        assert scoped(text, scope), scope
+    text = longcat.prefill_batch.lower(
+        cfg, params, cache, jnp.zeros((2, 8), jnp.int32), jnp.zeros((2, W), jnp.int32),
+        jnp.zeros((2,), jnp.int32), jnp.full((2,), 5, jnp.int32), None, None,
+    ).as_text(debug_info=True)
+    assert "jit_prefill_batch_impl" in text
+    for scope in ("embed", *LONGCAT_SCOPES, "logits"):
+        assert scoped(text, scope), scope
+    assert "jit_prefill_impl" in longcat.prefill.lower(
+        cfg, params, cache, jnp.zeros((8,), jnp.int32), jnp.zeros((W,), jnp.int32), 0, 5,
+    ).as_text()
+
+
+def test_longcat_expert_counters_ride_the_token_fetches(fresh_recorder):
+    """moe_assignments_total{kind}, moe_expert_tokens_total{layer,expert},
+    moe_tokens_routed_total, and by program moe_expert_calls_total and
+    moe_experts_touched_total, fed from the histogram the prefill and decode
+    programs return with the tokens: every assignment is held, zero or absent."""
+    cfg = ModelConfig.preset("longcat-tiny")
+    reg = MetricsRegistry()
+
+    async def go():
+        engine = TpuEngine(make_args(model=cfg, block_size=8))
+        engine.bind_metrics(reg)
+        await engine.start()
+        try:
+            out = await serve(engine, [range(1, 20), range(30, 45)], max_tokens=7)
+            await settle(engine)
+        finally:
+            await engine.stop()
+        return out
+
+    out = asyncio.run(go())
+    assert all(len(tokens_of(o)) == 7 for _, o in out)
+    kinds = {k: counter(reg, "moe_assignments_total", kind=k) for k in ("held", "zero", "absent")}
+    routed = counter(reg, "moe_tokens_routed_total")
+    assert routed > 0 and all(v > 0 for v in kinds.values())
+    assert sum(kinds.values()) == routed * cfg.num_experts_per_token
+    held = sum(
+        counter(reg, "moe_expert_tokens_total", layer=str(l), expert=str(cfg.expert_offset + e))
+        for l in range(cfg.num_layers) for e in range(cfg.num_experts))
+    assert held == kinds["held"]
+    calls = {p: counter(reg, "moe_expert_calls_total", program=p) for p in ("prefill", "decode")}
+    assert calls["prefill"] == cfg.num_layers * 2  # two prompts, a part each
+    assert calls["decode"] >= cfg.num_layers * 6 and calls["decode"] % cfg.num_layers == 0
+    touched = sum(counter(reg, "moe_experts_touched_total", program=p) for p in calls)
+    assert 0 < touched <= min(held, sum(calls.values()) * cfg.num_experts)
+    # Prompt tokens and every decoded token but each request's last, a layer each
+    # (a window also routes the rows it runs past a stop: at least this many).
+    assert routed >= cfg.num_layers * (19 + 15 + 2 * 6)
