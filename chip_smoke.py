@@ -23,7 +23,9 @@ time and this parent must never import JAX:
 2. serve    store server + worker + frontend (+ metrics exporter), the
             README quick start as four processes; chats over HTTP.
 3. kernel   one child: the compiled Pallas kernel against the XLA
-            reference on the same chip, ragged lengths, four variants.
+            reference on the same chip, ragged lengths, four variants;
+            then the kernel alone at the benchmark cells' call shapes:
+            device time a call and its share of the chip's HBM peak.
 4. multichip  only when JAX reports four devices: the serve phase with
             ``--tp 4`` and a KV pool larger than one chip's HBM.
 
@@ -76,6 +78,25 @@ START_LINE = re.compile(
     r"devices=(?P<devices>\d+) of (?P<visible>\d+) .*?dtype=(?P<dtype>\S+) .*?"
     r"decode=(?P<decode>\S+)(?P<rest>.*)"
 )
+# The decode kernel's call shapes in the benchmark's cells (PERF.md section
+# 4), for the kernel phase's timings: rows x table width at the
+# configuration's geometry, a pool of the cell's size, and groups of
+# (live rows, fewest, most tokens of context) as the cells' traffic leaves
+# them. Lengths and pages are drawn from a fixed seed.
+HBM_PEAK_BYTES_PER_S = 819e9  # TPU v5e (Google Cloud documentation)
+KERNEL_CALLS = {
+    "qwen2.5-7b sessions": dict(B=64, W=256, bs=16, KVH=4, hd=128, G=7, L=28, N=5120,
+                                rows=[(46, 1000, 2600)]),
+    "qwen2.5-7b chat": dict(B=32, W=256, bs=16, KVH=4, hd=128, G=7, L=28, N=5120,
+                            rows=[(17, 150, 700)]),
+    "mistral-7b mixed": dict(B=32, W=256, bs=16, KVH=8, hd=128, G=4, L=32, N=2304,
+                             rows=[(16, 150, 700), (2, 2100, 3600)]),
+    "mistral-7b chat": dict(B=32, W=256, bs=16, KVH=8, hd=128, G=4, L=32, N=2304,
+                            rows=[(16, 150, 700)]),
+    "longcat latent sessions": dict(B=128, W=128, bs=32, Dk=640, H=64, Dv=512, L=8, N=5632,
+                                    rows=[(63, 1000, 2600)]),
+}
+
 # The kernel phase's bound on |kernel - reference|, fixed from bf16 before
 # any run: four units in the last place (eps = 2**-8) at the scale of the
 # outputs. A wrong mask or a wrong page is off by tenths.
@@ -552,7 +573,89 @@ def child_kernel() -> None:
         resident = d.memory_stats()["bytes_in_use"] - before
         print(f"[kernel] int8-KV scale array {sc.shape} f32: logical "
               f"{sc.nbytes / 1e6:.1f} MB, resident {resident / 1e6:.1f} MB", flush=True)
-    print(json.dumps({"variants": results}), flush=True)
+    print(json.dumps({"variants": results, "calls": kernel_times()}), flush=True)
+
+
+def kernel_times() -> dict:
+    """The decode kernel alone at ``KERNEL_CALLS``: one jitted loop over
+    the cell's layers, the kernel's device time a call from a profiler
+    trace, and the bytes it must read (live K and V once) over that time
+    as a share of the HBM peak. A kernel-alone number without a served
+    run; it passes or fails nothing."""
+    import glob
+    import tempfile
+
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    from dynamo_tpu.ops.paged_attention import latent_decode_attention, paged_decode_attention
+
+    out = {}
+    for name, c in KERNEL_CALLS.items():
+        rng = np.random.default_rng(0)
+        B, W, bs, L, N = c["B"], c["W"], c["bs"], c["L"], c["N"]
+        lens = np.zeros(B, np.int32)
+        order, at = rng.permutation(B), 0
+        for n, lo, hi in c["rows"]:
+            lens[order[at:at + n]] = rng.integers(lo, hi + 1, n)
+            at += n
+        # Scattered pages; the draw wraps, so rows may share pages as a
+        # shared prefix does.
+        pages, tables, at = rng.permutation(np.arange(1, N)), np.zeros((B, W), np.int32), 0
+        for b in range(B):
+            n = -(-int(lens[b]) // bs)
+            tables[b, :n] = pages[(at + np.arange(n)) % len(pages)]
+            at += n
+        tables, lengths = jnp.asarray(tables), jnp.asarray(lens)
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
+        if "Dk" in c:
+            pool = jax.random.normal(kk, (L, N, bs, c["Dk"]), jnp.bfloat16)
+            q = jax.random.normal(kq, (B, c["H"], c["Dk"]), jnp.bfloat16)
+            pools, kernel = (pool,), "latent_decode_attention"
+            need = float(lens.sum()) * c["Dk"] * 2
+
+            def call(i, q, pool):
+                return latent_decode_attention(
+                    q, pool, i, tables, lengths, value_dim=c["Dv"], scale=192 ** -0.5)
+        else:
+            D = c["KVH"] * c["hd"]
+            pools = (jax.random.normal(kk, (L, N, bs, D), jnp.bfloat16),
+                     jax.random.normal(kv, (L, N, bs, D), jnp.bfloat16))
+            q = jax.random.normal(kq, (B, c["KVH"], c["G"], c["hd"]), jnp.bfloat16)
+            kernel = "paged_decode_attention"
+            need = float(lens.sum()) * D * 2 * 2
+
+            def call(i, q, k, v):
+                return paged_decode_attention(q, k, v, i, tables, lengths)
+
+        @jax.jit
+        def layers(q, *pools):
+            def body(i, acc):
+                return acc + call(i, q, *pools).astype(jnp.float32)
+            return jax.lax.fori_loop(1, L, body, call(jnp.int32(0), q, *pools).astype(jnp.float32))
+
+        jax.block_until_ready(layers(q, *pools))  # compiles
+        with tempfile.TemporaryDirectory() as d:
+            jax.profiler.start_trace(d)
+            jax.block_until_ready(layers(q, *pools))
+            jax.profiler.stop_trace()
+            data = ProfileData.from_file(
+                sorted(glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True))[-1])
+        durations = [ev.duration_ns for plane in data.planes if plane.name.startswith("/device:")
+                     for line in plane.lines if line.name == "XLA Ops"
+                     for ev in line.events if ev.name.lstrip("%").startswith(kernel)]
+        check(len(durations) == L, f"{name}: {len(durations)} events of {kernel} in the trace, not {L}")
+        us = sum(durations) / len(durations) / 1e3
+        share = need / (us * 1e-6) / HBM_PEAK_BYTES_PER_S
+        out[name] = {"us_a_call": round(us, 1), "hbm_peak_share": round(share, 4)}
+        print(f"[kernel] {name} [{B} rows, {int((lens > 0).sum())} live, {int(lens.sum()):,} tokens, "
+              f"{need / 1e6:.1f} MB]: {us:.1f} us a call, {need / us / 1e3:.0f} GB/s = "
+              f"{100 * share:.1f}% of {HBM_PEAK_BYTES_PER_S / 1e9:.0f} GB/s", flush=True)
+        del pools, q
+    return out
 
 
 # -- main ---------------------------------------------------------------------

@@ -12,7 +12,9 @@ pages: one DMA per page (a page is contiguous ``[bs, KVH*hd]`` in the
 cache layout), online-softmax accumulation, work proportional to
 ``sum(lengths)`` rather than ``B*W*bs``.
 
-Design notes (measured on v5e, see tools/profile_decode.py):
+Design notes (measured on a v5e by PR 31, PERF.md section 6; the kernel
+alone at the benchmark cells' call shapes is ``chip_smoke.py``'s kernel
+phase):
 
 - The FULL cache ``[L, N, bs, KVH*hd]`` stays in HBM (`pl.ANY`) in its
   native dense layout (a 5D [.., KVH, hd] layout forced a whole-cache
@@ -23,27 +25,51 @@ Design notes (measured on v5e, see tools/profile_decode.py):
   spec-verify below) no longer slices one either: it gathers pages from
   the stacked pool by (layer, page). Until PR 28 it took a
   ``dynamic_slice`` of the layer first, a copy of all N pages of it.
-- Grid ``(B, CMAX)``: chunk c of row b processes up to P pages.
-  Cross-step software pipelining: every live step issues the DMAs of the
-  *next* live step (double-buffered), so page fetch overlaps compute
-  across rows, not just within a row.
+- Grid ``(B,)``: one step a row, and inside it a loop over the row's own
+  chunks of P pages. A padding row is one empty step and the table's
+  width costs nothing. (Until PR 31 the grid was ``(B, W // P)``: 512
+  steps for the 46 live rows of the sessions cell, 321 of them dead at
+  0.055 us each.) Software pipelining across the whole walk: every chunk
+  starts the DMAs of the next live chunk (double-buffered), which at a
+  row's end is chunk 0 of the next non-empty row, so page fetch overlaps
+  compute across rows, not just within a row.
 - **Block-diagonal q**: per-head lane slices of the KV buffer relayout
   on every access (hd=64 is sub-lane-tile) and measured ~15us/chunk.
   Instead the caller bakes q into a block-diagonal matrix
-  ``[KVH*hd, KVH*G]`` so ONE MXU op yields all heads' scores
-  ``[P*bs, KVH*G]``; the online softmax is column-wise (axis-0 reduces),
-  and the accumulator is kept transposed ``[KVH*hd, KVH*G]`` so every
-  correction is a row-vector broadcast. Zero relayouts, zero transposes
-  in the kernel; the per-head diagonal is extracted by XLA afterwards.
-- Dead steps (chunk beyond the row's length, padding rows) skip DMA and
-  compute entirely — padding costs ~grid-iteration overhead only.
-- Per-DMA cost measured ~0.6us: pages should be >=32KB to approach
-  bandwidth. Page bytes = block_size x KVH x hd x 2 (bf16), so for
-  8B-class geometries (KVH*hd = 1024) the default ``block_size=16``
-  already gives 32KB pages — r5 bench: decode substeps run AT the int8
-  weight-stream roofline (~9 ms vs the 9.8 ms floor) at bs=16, so
-  larger blocks buy nothing there. Prefer 64-256 only for SMALL kv
-  widths (e.g. KVH*hd <= 256) where bs=16 pages drop under 8KB.
+  ``[KVH*G, KVH*hd]``, a row a query column, so ONE MXU op yields all
+  heads' scores ``[KVH*G, P*bs]`` with the context along the lanes
+  (``q k^T``, the chunk as transposed right operand, which the MXU takes
+  as it is); the online softmax reduces along the lanes, the accumulator
+  is ``[KVH*G, KVH*hd]`` (``p v``) and every correction a column
+  broadcast; the per-head diagonal is extracted by XLA afterwards. No
+  product takes a transposed LEFT operand: the form before PR 31
+  (scores ``[P*bs, H]``, accumulator ``v^T p``) made Mosaic transpose
+  the whole ``[512, 512]`` chunk of V in every chunk (``tpu.transpose``
+  in ``--xla_mosaic_dump_to``'s output; 6.6% of a call), ran its softmax
+  over 28 of 128 lanes, and masked V over the whole chunk where only a
+  row's last chunk has a tail.
+- **What a call costs is its DMA descriptors, and nothing hides them.** A
+  DMA start occupies the core for about 23 ns (10.7k descriptors in the
+  242 us a sessions-shape call takes with its compute left out), and the
+  compute of a 1 MiB chunk 0.6 us; the two add up (318 us a call). A pure
+  delay in place of the compute adds its whole length; starts set between
+  the sub-products of a chunk, and a third buffer slot, both made a call
+  slower. So: a full chunk's starts are one straight line (no counter,
+  branch or table re-read between them: 64 ``pl.when``s a chunk cost the
+  old kernel 100 us a call), its waits one a pool (a DMA semaphore counts
+  bytes), and only a row's last chunk takes loops. What is left is the
+  page: 16 KB descriptors (Qwen, bs=16) cannot pass 720 GB/s and reach
+  553 with the compute beside them (67% of 819 GB/s), 32 KB ones
+  (Mistral) reach 656 (80%), the 40 KB latent ones 520 (63%: 64 query
+  heads make its chunk's compute twice as long). Page bytes =
+  block_size x KVH x hd x 2 (bf16): a larger ``--block-size`` is the
+  remaining lever, and a configuration's.
+- A chunk is about 1 MiB of pages (P from the page's bytes, a power of
+  two): 2 MiB chunks are no faster at long context and waste more on a
+  row's last chunk, which is computed whole however few pages it holds.
+- int8 pages keep a chunk axis on the grid around the same chunk body:
+  their scales ride as per-chunk BlockSpec blocks (see
+  ``_paged_attention_mq``).
 """
 
 from __future__ import annotations
@@ -59,8 +85,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-# Most DMA semaphores one chunk may hold: 2 slots x (k, v) x P pages must
-# fit the chip's semaphore memory (v5e refuses P=128).
+# Most pages a chunk may hold: a full chunk's DMA starts are unrolled, a
+# pool's P of them, three times over in the kernel's text.
 _MAX_PAGES_PER_CHUNK = 64
 
 
@@ -240,6 +266,7 @@ def _mq_kernel(
     # scalar prefetch
     layer_ref,    # [1] int32
     rowlen_ref,   # [B] int32 — max attend length per row (chunk walk bound)
+    nextrow_ref,  # [B] int32 — the first non-empty row after b (B if none)
     tables_ref,   # [B, W] int32
     # operands (anc present only in tree mode; kscale/vscale when quantized)
     *refs,
@@ -254,212 +281,292 @@ def _mq_kernel(
     # shared key, and its first ``value_dim`` lanes are also the value, so
     # each page is read once.
     refs = list(refs)
-    qbd_ref, lenvec_ref = refs[:2]
+    q_ref, lenvec_ref = refs[:2]
     refs = refs[2:]
     anc_ref = None
     if tree_slots:
         anc_ref, refs = refs[0], refs[1:]
+    kscale_ref = vscale_ref = ml_scr = v_hbm = vbuf = None
     if value_dim:
-        (k_hbm, o_ref, kbuf, m_scr, l_scr, acc_scr, slot_ref, started_ref,
-         sem) = refs
-        kscale_ref = vscale_ref = v_hbm = vbuf = None
+        k_hbm, o_ref, kbuf, acc_scr, slot_ref, started_ref, sem = refs
     elif quantized:
-        (kscale_ref, vscale_ref, k_hbm, v_hbm,
-         o_ref, kbuf, vbuf, m_scr, l_scr, acc_scr, slot_ref, started_ref,
-         sem) = refs
+        (kscale_ref, vscale_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, acc_scr,
+         slot_ref, started_ref, sem, ml_scr) = refs
     else:
-        (k_hbm, v_hbm,
-         o_ref, kbuf, vbuf, m_scr, l_scr, acc_scr, slot_ref, started_ref,
-         sem) = refs
-        kscale_ref = vscale_ref = None
-    # qbd_ref    VMEM [1, KVH*hd, H] — block-diag q, softmax scale folded in
-    # lenvec_ref VMEM [1, 1, H] int32 — per query COLUMN attend length; in
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, acc_scr, slot_ref, started_ref, sem = refs
+    # q_ref      VMEM [1, H, KVH*hd] — block-diag q, softmax scale folded in:
+    #            one ROW a query column (k, t, g), zero outside head k's lanes
+    # lenvec_ref VMEM [1, H, 1] int32 — per query column attend length; in
     #            tree mode the per-column HISTORY horizon (slots ride on top)
-    # anc_ref    VMEM [1, T, H] int8 — tree mode: anc[s, col] = query col
+    # anc_ref    VMEM [1, H, T] int32 — tree mode: anc[col, s] = query col
     #            may attend in-flight slot s (its ancestor-or-self set)
     # kscale_ref VMEM [1, P, bs, KVH] f32 — this chunk's per-position-per-head scales
     # k_hbm      ANY  [L, N, bs, KVH*hd]
-    # o_ref      VMEM [1, KVH*hd, H] — attention out, transposed
+    # o_ref      VMEM [1, H, Dv] — attention out, every head's lanes a row
     # kbuf/vbuf  VMEM [2, P, bs, KVH*hd] (cache dtype; int8 when quantized)
-    # m/l        VMEM [8, 128] f32 — row 0, first H lanes live
-    # acc        VMEM [KVH*hd, H] f32
-    # slot/started SMEM [1] int32; sem DMA sems [2, 2, P]
+    # acc        VMEM [H, Dv] f32
+    # slot/started SMEM [1] int32; sem DMA sems [2 slots, k | v]
+    # ml_scr     VMEM [2, H, 1] f32 — int8 only: max and sum between grid steps
     P = pages_per_chunk
     b = pl.program_id(0)
-    c = pl.program_id(1)
     B = pl.num_programs(0)
     layer = layer_ref[0]
     bs = kbuf.shape[2]
     D = kbuf.shape[3]       # KVH*hd
-    H = qbd_ref.shape[2]    # KVH*T*G (total query columns)
+    H = q_ref.shape[1]      # query columns KVH*T*G, padded to whole tiles
     hd = head_dim
     KVH = D // hd
     CH = P * bs             # tokens per chunk
 
     length = rowlen_ref[b]
     nchunks = lax.div(length + CH - 1, CH)
-    live = c < nchunks
 
-    @pl.when((b == 0) & (c == 0))
+    @pl.when((b == 0) & (pl.program_id(1) == 0) if quantized else b == 0)
     def _init_globals():
         slot_ref[0] = 0
         started_ref[0] = 0
 
-    def chunk_dmas(row, chunk, slot):
-        """DMA descriptors for (row, chunk) into buffer `slot`; page p is
-        guarded by the row's true page count."""
+    def chunk_pages(row, chunk):
+        """Pages of (row, chunk) that hold tokens: P but in a row's last."""
         rem = rowlen_ref[row] - chunk * CH
-        npages = jnp.minimum(lax.div(rem + bs - 1, bs), P)
-        out = []
-        for p in range(P):
-            page = tables_ref[row, chunk * P + p]
-            copies = [pltpu.make_async_copy(
-                k_hbm.at[layer, page], kbuf.at[slot, p], sem.at[slot, 0, p])]
-            if v_hbm is not None:
-                copies.append(pltpu.make_async_copy(
-                    v_hbm.at[layer, page], vbuf.at[slot, p], sem.at[slot, 1, p]))
-            out.append((p < npages, copies))
+        return jnp.minimum(lax.div(rem + bs - 1, bs), P)
+
+    def page_copies(page, slot, p):
+        """The DMA descriptors of pool page ``page`` into place p of buffer
+        ``slot``: one a pool, all of a pool's on one semaphore."""
+        out = [pltpu.make_async_copy(
+            k_hbm.at[layer, page], kbuf.at[slot, p], sem.at[slot, 0])]
+        if v_hbm is not None:
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[layer, page], vbuf.at[slot, p], sem.at[slot, 1]))
         return out
 
-    def issue(row, chunk, slot):
-        for ok, copies in chunk_dmas(row, chunk, slot):
-            @pl.when(ok)
-            def _():
-                for dma in copies:
-                    dma.start()
+    def issue(row, chunk, slot, full=False):
+        """Start the page copies of (row, chunk) into buffer ``slot``. A
+        chunk that holds P pages (``full``: known to when traced) starts
+        them in one straight line, with no counter or branch between the
+        descriptors; a row's last chunk starts what it holds in a loop."""
+        def start(p, carry=0):
+            for dma in page_copies(tables_ref[row, chunk * P + p], slot, p):
+                dma.start()
+            return carry
 
-    @pl.when(live)
-    def _body():
-        cur = slot_ref[0]
+        def unrolled():
+            for p in range(P):
+                start(p)
 
-        # Global warmup: the very first live step has no predecessor.
+        def rolled(npages):
+            lax.fori_loop(0, npages, start, 0)
+
+        if full:
+            unrolled()
+        else:
+            npages = chunk_pages(row, chunk)
+            pl.when(npages == P)(unrolled)
+            pl.when(npages < P)(functools.partial(rolled, npages))
+
+    def wait(npages, slot):
+        """Wait for the copies ``issue`` started. A DMA semaphore counts
+        bytes: a full chunk (``npages`` None: known to be) is one wait a
+        pool for all P pages' bytes, a row's last chunk one page-sized
+        wait a page."""
+        def whole():
+            pltpu.make_async_copy(
+                k_hbm.at[layer, pl.ds(0, P)], kbuf.at[slot], sem.at[slot, 0]).wait()
+            if v_hbm is not None:
+                pltpu.make_async_copy(
+                    v_hbm.at[layer, pl.ds(0, P)], vbuf.at[slot], sem.at[slot, 1]).wait()
+
+        def paged():
+            def one(p, carry):
+                for dma in page_copies(0, slot, 0):  # any page: its bytes count
+                    dma.wait()
+                return carry
+
+            lax.fori_loop(0, npages, one, 0)
+
+        if npages is None:
+            whole()
+        else:
+            pl.when(npages == P)(whole)
+            pl.when(npages < P)(paged)
+
+    def successor(row, ch):
+        """The live chunk after (row, ch) in the walk; row B: none."""
+        more = (ch + 1) * CH < rowlen_ref[row]
+        return jnp.where(more, row, nextrow_ref[row]), jnp.where(more, ch + 1, 0)
+
+    def begin_row():
+        # Global warmup: the very first live row has no predecessor.
         @pl.when(started_ref[0] == 0)
         def _():
-            issue(b, c, cur)
+            issue(b, 0, slot_ref[0])
             started_ref[0] = 1
 
-        # Software pipeline: issue the next live step's pages.
-        # Successor is (b, c+1) if this row continues, else chunk 0 of
-        # the next non-empty row (scalar search past padding rows).
-        nxt = 1 - cur
-        row_continues = c + 1 < nchunks
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        @pl.when(row_continues)
-        def _():
-            issue(b, c + 1, nxt)
-
-        @pl.when(~row_continues)
-        def _():
-            # First non-empty row after b (B if none). A fori_loop, not a
-            # while_loop: the scan is O(B) scalar work either way, and a
-            # while cond that reads a ref has no interpret-mode discharge
-            # rule — this form keeps the kernel CPU-interpret-testable.
-            def scan_row(r, best):
-                cand = (r > b) & (rowlen_ref[r] > 0) & (r < best)
-                return jnp.where(cand, r, best)
-
-            nxt_row = lax.fori_loop(0, B, scan_row, B)
-
-            @pl.when(nxt_row < B)
-            def _():
-                issue(nxt_row, 0, nxt)
-
-        # Init row accumulators at the row's first chunk.
-        @pl.when(c == 0)
-        def _():
-            m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[...] = jnp.zeros_like(l_scr)
-            acc_scr[...] = jnp.zeros_like(acc_scr)
-
-        # Wait for this step's pages.
-        for ok, copies in chunk_dmas(b, c, cur):
-            @pl.when(ok)
-            def _():
-                for dma in copies:
-                    dma.wait()
-        slot_ref[0] = nxt
-
-        # Context-position validity, column orientation [P*bs, 1].
-        pos = c * CH + lax.broadcasted_iota(jnp.int32, (P * bs, 1), 0)
-        valid = pos < length
-
-        k_chunk = kbuf[cur].reshape(P * bs, D)
+    def attend(c, cur, m_prev, l_prev):
+        """The chunk in buffer ``cur`` into the online softmax."""
+        k_chunk = kbuf[cur].reshape(CH, D)
         if value_dim:
             v_chunk = k_chunk[:, :value_dim]
         else:
-            v_chunk = vbuf[cur].reshape(P * bs, D)
+            v_chunk = vbuf[cur].reshape(CH, D)
         if quantized:
             # In-register dequant of the just-landed int8 pages: expand
-            # this chunk's [P*bs, KVH] scales across each head's lanes and
+            # this chunk's [CH, KVH] scales across each head's lanes and
             # multiply — the DMA moved half the bytes, the float page
             # never exists outside VMEM. The scales are flattened to 2D
             # first: Mosaic lowers the [CH, KVH, hd] -> [CH, D] merge at
             # hd=64 and hd=128, but not the 4D form of the same cast.
             def lane_scales(sc_ref):
-                sc = sc_ref[0].reshape(P * bs, KVH)
+                sc = sc_ref[0].reshape(CH, KVH)
                 return jnp.broadcast_to(
-                    sc[..., None], (P * bs, KVH, hd)
-                ).reshape(P * bs, D)
+                    sc[..., None], (CH, KVH, hd)
+                ).reshape(CH, D)
 
             k_chunk = (
                 k_chunk.astype(jnp.float32) * lane_scales(kscale_ref)
-            ).astype(qbd_ref.dtype)
+            ).astype(q_ref.dtype)
             v_chunk = (
                 v_chunk.astype(jnp.float32) * lane_scales(vscale_ref)
-            ).astype(qbd_ref.dtype)
-        # Unfetched tail pages hold garbage (possibly NaN): k is
-        # neutralized by the score mask, v must be zeroed (0*NaN=NaN).
-        v_chunk = jnp.where(valid, v_chunk, 0)
+            ).astype(q_ref.dtype)
+            held = c * CH + lax.broadcasted_iota(jnp.int32, (CH, 1), 0) < length
+            v_chunk = jnp.where(held, v_chunk, 0)
 
-        # All heads' scores in one MXU op via the block-diagonal q.
+        # All heads' scores in one MXU op via the block-diagonal q, the
+        # chunk as the transposed right operand (which the MXU takes as it
+        # is): context positions lie along the lanes.
         s = lax.dot_general(
-            k_chunk, qbd_ref[0], (((1,), (0,)), ((), ())),
+            q_ref[0], k_chunk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                  # [P*bs, H]
+        )                                                  # [H, CH]
         # Per-COLUMN causal horizon: column (k, t, g) attends positions
         # [0, lengths[b, t]) — for decode (T=1) every column carries the
-        # row length and this is exactly the old row mask. Tree mode
-        # adds the topology bits: in-flight slot s_i sits at paged
-        # position hist + s_i and column t attends it only when
-        # anc[s_i, col] is set (T compares on the VPU, T is small).
-        lenvec = lenvec_ref[0]                             # [1, H]
+        # row length and this is exactly the row mask. Tree mode adds the
+        # topology bits: in-flight slot s_i sits at paged position
+        # hist + s_i and column t attends it only when anc[col, s_i] is
+        # set (T compares on the VPU, T is small).
+        lenvec = lenvec_ref[0]                             # [H, 1]
+        pos = c * CH + lax.broadcasted_iota(jnp.int32, (1, CH), 1)
         att = pos < lenvec
         if tree_slots:
+            anc = anc_ref[0]                               # [H, T]
             for s_i in range(tree_slots):
-                att = att | (
-                    (pos == lenvec + s_i)
-                    & (anc_ref[0, s_i, :][None, :] != 0)
-                )
+                att = att | ((pos == lenvec + s_i) & (anc[:, s_i:s_i + 1] != 0))
         s = jnp.where(att, s, NEG_INF)
 
-        m_prev = m_scr[0:1, :H]                            # [1, H]
-        l_prev = l_scr[0:1, :H]
-        m_cur = jnp.max(s, axis=0, keepdims=True)          # [1, H]
+        m_cur = jnp.max(s, axis=1, keepdims=True)          # [H, 1]
         m_new = jnp.maximum(m_prev, m_cur)
-        corr = jnp.exp(m_prev - m_new)                     # [1, H]
-        p = jnp.exp(s - m_new)                             # [P*bs, H]
-        l_new = corr * l_prev + jnp.sum(p, axis=0, keepdims=True)
-        # Transposed accumulator [D, H]: corrections broadcast over rows.
+        corr = jnp.exp(m_prev - m_new)                     # [H, 1]
+        p = jnp.exp(s - m_new)                             # [H, CH]
+        l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
         pv = lax.dot_general(
-            v_chunk, p.astype(v_chunk.dtype), (((0,), (0,)), ((), ())),
+            p.astype(v_chunk.dtype), v_chunk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                  # [D, H]
+        )                                                  # [H, Dv]
         acc_scr[...] = acc_scr[...] * corr + pv
-        m_scr[0:1, :H] = m_new
-        l_scr[0:1, :H] = l_new
+        return m_new, l_new
 
-        # Row done → normalize and emit (still transposed; XLA takes the
-        # per-head diagonal outside).
-        @pl.when(c == nchunks - 1)
-        def _():
-            l = jnp.maximum(l_scr[0:1, :H], 1e-30)
-            o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+    def chunk(c, m_prev, l_prev):
+        """Chunk c of row b: up to P pages into the online softmax.
+        m, l: [H, 1] f32, the running max and sum. Software pipeline: the
+        next chunk's pages are started before this one's are waited for."""
+        cur = slot_ref[0]
+        nxt = 1 - cur
+        slot_ref[0] = nxt
+        after = length - (c + 1) * CH      # the row's tokens past this chunk
 
-    # Keep padding rows' output defined (their stale block is otherwise
-    # flushed as-is; harmless numerically but keep it clean).
-    @pl.when((~live) & (c == 0))
-    def _zero():
+        def steady():
+            # A full chunk whose successor is a full chunk of the same row:
+            # 2P starts, two waits and the compute in one straight line.
+            issue(b, c + 1, nxt, full=True)
+            wait(None, cur)
+            return attend(c, cur, m_prev, l_prev)
+
+        def edge():
+            # Near a row's end the successor is a short last chunk, or
+            # chunk 0 of the next non-empty row, so the fetch overlaps
+            # compute across rows too.
+            row, ch = successor(b, c)
+            pl.when(row < B)(functools.partial(issue, row, ch, nxt))
+
+            npages = chunk_pages(b, c)
+            wait(npages, cur)
+            # Unfetched tail pages hold garbage (possibly NaN): k is
+            # neutralized by the score mask, v must be zero (0*NaN=NaN).
+            # Only a row's last chunk has a tail: clear it in the buffer,
+            # whole pages, and in the last fetched page the positions past
+            # the row's length. (A latent page is its own value. int8 pages
+            # are cleared once they are dequantized: their scales are
+            # garbage there too.)
+            if not quantized:
+                tail_buf = kbuf if value_dim else vbuf
+
+                @pl.when(after < 0)
+                def _():
+                    def clear(p, carry):
+                        tail_buf[cur, p] = jnp.zeros(tail_buf.shape[2:], tail_buf.dtype)
+                        return carry
+
+                    lax.fori_loop(npages, P, clear, 0)
+                    held = length - c * CH - (npages - 1) * bs  # tokens in the last page
+                    row = lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
+                    page = tail_buf[cur, npages - 1]
+                    tail_buf[cur, npages - 1] = jnp.where(row < held, page, jnp.zeros_like(page))
+
+            return attend(c, cur, m_prev, l_prev)
+
+        return lax.cond(after >= CH, steady, edge)
+
+    def emit(l):
+        # Row done → normalize and emit (XLA takes the per-head diagonal
+        # outside).
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+    def zero_out():
+        # Keep padding rows' output defined (their stale block is otherwise
+        # flushed as-is; harmless numerically but keep it clean).
         o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    ml0 = (jnp.full((H, 1), NEG_INF, jnp.float32), jnp.zeros((H, 1), jnp.float32))
+    if quantized:
+        # int8 pages: the chunk axis stays on the grid, because a chunk's
+        # scales ride as a BlockSpec block that the chunk index picks (a
+        # manual DMA of the slab is refused, see _paged_attention_mq), and
+        # max and sum live in scratch between the steps. Steps past a row's
+        # last chunk skip DMA and compute.
+        c = pl.program_id(1)
+
+        @pl.when((c == 0) & (nchunks == 0))
+        def _():
+            zero_out()
+
+        @pl.when(c < nchunks)
+        def _():
+            @pl.when(c == 0)
+            def _():
+                begin_row()
+                ml_scr[0], ml_scr[1] = ml0
+
+            m, l = chunk(c, ml_scr[0], ml_scr[1])
+            ml_scr[0], ml_scr[1] = m, l
+
+            @pl.when(c == nchunks - 1)
+            def _():
+                emit(l)
+    else:
+        # One grid step a row, and inside it a loop over the row's OWN
+        # chunks. Its bound is read before the loop, so interpret mode can
+        # discharge it (a while whose condition reads a ref has no
+        # discharge rule).
+        pl.when(nchunks == 0)(zero_out)
+
+        @pl.when(nchunks > 0)
+        def _():
+            begin_row()
+            _, l = lax.fori_loop(0, nchunks, lambda c, ml: chunk(c, *ml), ml0)
+            emit(l)
 
 
 def _paged_attention_mq(
@@ -492,29 +599,34 @@ def _paged_attention_mq(
             f"or fall back to the XLA gather path"
         )
     quantized = k_scale is not None
-    P = pages_per_chunk or min(max(1, 512 // bs), _MAX_PAGES_PER_CHUNK)
-    P = min(P, W)
+    pools = [k_cache] if value_dim else [k_cache, v_cache]
+    # A chunk of about 1 MiB of pages, a power of two of them: what a chunk
+    # costs beside its bytes (a loop iteration, a softmax update) is small
+    # against their transfer, and a row's last chunk, computed whole
+    # however few pages it holds, wastes little (PERF.md, PR 31).
+    page_bytes = len(pools) * bs * KVH * hd * k_cache.dtype.itemsize
+    P = pages_per_chunk or min(1 << max(0, ((1 << 20) // page_bytes).bit_length() - 1),
+                               _MAX_PAGES_PER_CHUNK)
+    P = min(P, W, k_cache.shape[1])  # no larger than the table, or the pool
     if W % P:  # pad the table so chunks tile it exactly
-        pad = P - W % P
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
-        W += pad
-    chunks_max = W // P
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, P - W % P)))
 
-    # Block-diagonal q with the softmax scale folded in:
-    # qbd[b, j*hd+h, k*(T*G)+t*G+g] = q[b,t,k,g,h] * scale * (j==k).
+    # Block-diagonal q with the softmax scale folded in, a row a query
+    # column: qbd[b, k*(T*G)+t*G+g, j*hd+h] = q[b,t,k,g,h] * scale * (j==k).
+    # Rows are padded to whole (16, 128) tiles; a padding row attends
+    # nothing and is cut off again below.
+    Hp = -(-H // 16) * 16
     eye = jnp.eye(KVH, dtype=q.dtype)
     qbd = jnp.einsum(
-        "btkgh,jk->bjhktg", q * (hd ** -0.5 if scale is None else scale), eye
+        "btkgh,jk->bktgjh", q * (hd ** -0.5 if scale is None else scale), eye
     )
-    qbd = qbd.reshape(B, KVH * hd, H)
-    # Per-column attend horizon, same (k, t, g) column order as qbd.
-    # Carried [B, 1, H]: Mosaic wants a block's last two dims to be whole
-    # (8, 128) tiles or the array's own, and a (1, H) block of [B, H] is
-    # neither.
+    qbd = jnp.pad(qbd.reshape(B, H, KVH * hd), ((0, 0), (0, Hp - H), (0, 0)))
+    # Per-column attend horizon, same (k, t, g) order as qbd's rows.
     lengths = jnp.asarray(lengths, jnp.int32)
     lenvec = jnp.broadcast_to(
         lengths[:, None, :, None], (B, KVH, T, G)
-    ).reshape(B, 1, H)
+    ).reshape(B, H, 1)
+    lenvec = jnp.pad(lenvec, ((0, 0), (0, Hp - H), (0, 0)))
     rowlen = jnp.max(lengths, axis=1)  # chunk-walk bound per row
     if anc is not None:
         # Tree mode: the walk must also cover the T in-flight slots at
@@ -523,74 +635,82 @@ def _paged_attention_mq(
         # prefetch skip keeps them ~free.
         live_row = jnp.any(anc != 0, axis=(1, 2))
         rowlen = jnp.where(live_row, rowlen + T, 0)
+    # The first non-empty row after each row (B if none): where a row's
+    # last chunk prefetches from.
+    rows = jnp.arange(B, dtype=jnp.int32)
+    live_from = lax.cummin(jnp.where(rowlen > 0, rows, B), reverse=True)
+    nextrow = jnp.concatenate([live_from[1:], jnp.full((1,), B, jnp.int32)])
+
+    def row_block(*tail):
+        return pl.BlockSpec((1, *tail), lambda b, *_: (b, 0, 0))
 
     operands = [qbd, lenvec]
-    in_specs = [
-        pl.BlockSpec((1, KVH * hd, H), lambda b, c, *_: (b, 0, 0)),
-        pl.BlockSpec((1, 1, H), lambda b, c, *_: (b, 0, 0)),
-    ]
+    in_specs = [row_block(Hp, KVH * hd), row_block(Hp, 1)]
     if anc is not None:
-        # Column-order ancestor bits [B, T_slot, H]: anc_cols[b, s, col]
-        # with col = (k*T + t)*G + g — the same (k, t, g) layout as
-        # lenvec/qbd, prefetched per row block alongside the scales.
-        anc_b = jnp.asarray(anc != 0, jnp.int8).transpose(0, 2, 1)  # [B, Ts, Tq]
+        # Ancestor bits a query column [B, H, T_slot]: anc_cols[b, col, s]
+        # with col = (k*T + t)*G + g — the same (k, t, g) order as qbd's
+        # rows.
+        anc_b = jnp.asarray(anc != 0, jnp.int32)           # [B, Tq, Ts]
         anc_cols = jnp.broadcast_to(
-            anc_b[:, :, None, :, None], (B, T, KVH, T, G)
-        ).reshape(B, T, H)
-        operands.append(anc_cols)
-        in_specs.append(pl.BlockSpec((1, T, H), lambda b, c, *_: (b, 0, 0)))
+            anc_b[:, None, :, None, :], (B, KVH, T, G, T)
+        ).reshape(B, H, T)
+        operands.append(jnp.pad(anc_cols, ((0, 0), (0, Hp - H), (0, 0))))
+        in_specs.append(row_block(Hp, T))
     if quantized:
         # Scales are gathered OUTSIDE the kernel ([B, W, bs, KVH] fp32 is
         # 1/head_dim the page bytes) and ride as per-CHUNK VMEM blocks, so
         # their VMEM footprint does not grow with the context: a whole
         # row's block (KVH padded to 128 lanes) ran out of VMEM from 16k
-        # tokens.
+        # tokens. That block is why this variant's grid keeps a chunk axis.
+        # Fetching the slab beside the pages instead, from the gathered
+        # array left in HBM, Mosaic refuses: "Slice shape along dimension 3
+        # must be aligned to tiling (128), but is 8" (KVH lanes).
         sk = lax.dynamic_index_in_dim(k_scale, layer_idx, 0, keepdims=False)
         sv = lax.dynamic_index_in_dim(v_scale, layer_idx, 0, keepdims=False)
         operands += [sk[block_tables], sv[block_tables]]
-        in_specs += [
-            pl.BlockSpec((1, P, bs, KVH), lambda b, c, *_: (b, c, 0, 0)),
-            pl.BlockSpec((1, P, bs, KVH), lambda b, c, *_: (b, c, 0, 0)),
-        ]
-    pools = [k_cache] if value_dim else [k_cache, v_cache]
+        in_specs += [pl.BlockSpec((1, P, bs, KVH), lambda b, c, *_: (b, c, 0, 0))] * 2
     operands += pools
     in_specs += [pl.BlockSpec(memory_space=pl.ANY) for _ in pools]
-    out_rows = value_dim or KVH * hd
+    out_cols = value_dim or KVH * hd
+    scratch = [pltpu.VMEM((2, P, bs, KVH * hd), pool.dtype) for pool in pools] + [
+        pltpu.VMEM((Hp, out_cols), jnp.float32),
+        pltpu.SMEM((1,), jnp.int32),
+        pltpu.SMEM((1,), jnp.int32),
+        pltpu.SemaphoreType.DMA((2, 2)),
+    ]
+    # The grid is the rows alone: a row's chunks are a loop inside its step,
+    # so a padding row is one empty step and the table's width costs nothing.
+    grid = (B,)
+    if quantized:
+        grid = (B, block_tables.shape[1] // P)
+        scratch.append(pltpu.VMEM((2, Hp, 1), jnp.float32))
 
     kernel = functools.partial(
         _mq_kernel, pages_per_chunk=P, head_dim=hd, quantized=quantized,
         tree_slots=T if anc is not None else 0, value_dim=value_dim,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, chunks_max),
+        num_scalar_prefetch=4,
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, out_rows, H), lambda b, c, *_: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, P, bs, KVH * hd), pool.dtype) for pool in pools
-        ] + [
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((out_rows, H), jnp.float32),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2, P)),
-        ],
+        out_specs=row_block(Hp, out_cols),
+        scratch_shapes=scratch,
     )
-    o_t = pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, out_rows, H), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hp, out_cols), q.dtype),
         interpret=interpret,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         rowlen,
+        nextrow,
         jnp.asarray(block_tables, jnp.int32),
         *operands,
     )
-    # [B, KVH*hd, KVH*T*G] → per-head diagonal → [B, T, KVH, G, hd].
-    o6 = o_t.reshape(B, KVH, out_rows // KVH, KVH, T, G)
-    return jnp.einsum("bkhktg->btkgh", o6)
+    # [B, KVH*T*G, KVH*hd] → per-head diagonal → [B, T, KVH, G, hd].
+    o6 = o[:, :H].reshape(B, KVH, T, G, KVH, out_cols // KVH)
+    return jnp.einsum("bktgkh->btkgh", o6)
 
 
 @functools.partial(

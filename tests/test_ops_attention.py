@@ -17,6 +17,8 @@ import jax.numpy as jnp
 from dynamo_tpu.engine import model as M
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.ops.paged_attention import (
+    latent_decode_attention,
+    latent_decode_attention_xla,
     paged_decode_attention,
     paged_decode_attention_xla,
     paged_spec_attention,
@@ -50,6 +52,8 @@ def _dequant(cache_q, scales, KVH, hd):
     [96, 1, 0, 37, 80],      # mixed, incl. inactive + non-block-aligned
     [16, 16, 16, 16, 16],    # exactly one block each
     [0, 0, 5, 0, 0],         # empty rows on both sides (prefetch skip)
+    [0, 96, 0, 0, 33],       # empty rows first and between, the last row live
+    [1, 1, 1, 1, 1],         # one token a row
 ])
 def test_kernel_matches_xla(lengths):
     rng = np.random.default_rng(0)
@@ -401,6 +405,92 @@ def test_decode_step_int8_cache_logit_error_bound():
     np.testing.assert_allclose(
         np.asarray(out_x), np.asarray(out_p), atol=1e-4, rtol=1e-4
     )
+
+
+# ---------------------------------------------------------------------------
+# The walk's edges (PR 31): the grid is the rows and a row's chunks are a
+# loop inside its step (int8 pages keep the chunk axis on the grid around
+# the same body), so what can go wrong is at a row's first and last chunk
+# and at the hand-over between rows. Chunks of 2 pages of 8 tokens.
+# ---------------------------------------------------------------------------
+
+EDGE_P, EDGE_BS = 2, 8
+EDGES = {
+    # name: (lengths, table width)
+    "exactly_k_chunks": ([32, 16, 48], 6),
+    "k_chunks_and_a_token": ([33, 17, 1 + 2 * 16], 6),
+    "one_token": ([1, 1, 1], 6),
+    "empty_rows_first": ([0, 0, 20, 7], 6),
+    "empty_rows_last": ([20, 7, 0, 0], 6),
+    "empty_rows_between": ([20, 0, 0, 7, 0, 33], 6),
+    "every_row_empty": ([0, 0, 0], 6),
+    "table_width_no_multiple_of_P": ([40, 3, 25], 5),
+    "one_row": ([37], 6),
+}
+WALKS = ("decode", "decode-int8", "spec", "spec-int8", "tree", "tree-int8", "latent")
+
+
+@pytest.mark.parametrize("edge", list(EDGES))
+@pytest.mark.parametrize("walk", WALKS)
+def test_walk_edges_match_xla(walk, edge):
+    lengths, W = EDGES[edge]
+    lengths = np.asarray(lengths, np.int32)
+    B, bs = len(lengths), EDGE_BS
+    rng = np.random.default_rng(40 + len(edge))
+    L, N, KVH, hd, G, T = 2, 32, 2, 32, 2, 3
+    tables = jnp.asarray(rng.integers(1, N, size=(B, W)), jnp.int32)
+    kind, _, quant = walk.partition("-")
+    kw = dict(pages_per_chunk=EDGE_P, interpret=True)
+    if kind == "latent":
+        Dk, Dv, H = 96, 64, 4
+        pool = _mk(rng, (L, N, bs, Dk))
+        q = _mk(rng, (B, H, Dk))
+        geo = dict(value_dim=Dv, scale=Dk ** -0.5)
+        ref = latent_decode_attention_xla(q, pool, jnp.int32(1), tables, jnp.asarray(lengths), **geo)
+        out = latent_decode_attention(q, pool, jnp.int32(1), tables, jnp.asarray(lengths), **geo, **kw)
+        live = lengths > 0
+    else:
+        if quant:
+            cache = _mk_quant_cache(rng, L, N, bs, KVH, hd)
+            k, v, scales = cache[0], cache[1], cache[2:]
+        else:
+            k, v, scales = _mk(rng, (L, N, bs, KVH * hd)), _mk(rng, (L, N, bs, KVH * hd)), (None, None)
+        if kind == "decode":
+            q = _mk(rng, (B, KVH, G, hd))
+            lens = jnp.asarray(lengths)
+            ref = paged_decode_attention_xla(q, k, v, jnp.int32(1), tables, lens, *scales)
+            out = paged_decode_attention(q, k, v, jnp.int32(1), tables, lens, *scales, **kw)
+            live = lengths > 0
+        else:
+            q = _mk(rng, (B, T, KVH, G, hd))
+            if kind == "spec":
+                # Query t attends [0, length - T + 1 + t): the last query
+                # ends where the row does, so the row's walk has the
+                # edge's length; short rows leave their first queries dead.
+                lens2 = np.maximum(lengths[:, None] - (T - 1) + np.arange(T)[None, :], 0)
+                lens2 = np.where(lengths[:, None] > 0, lens2, 0).astype(np.int32)
+                anc = None
+                live = lens2 > 0
+            else:
+                # Tree: T in-flight slots on top of the history, so the
+                # history is the edge's length less T (a row that short
+                # has none); an empty row has no live node.
+                hist = np.maximum(lengths - T, 0)
+                lens2 = np.repeat(hist[:, None], T, axis=1).astype(np.int32)
+                anc1 = _tree_anc([0, 0], T)
+                anc_np = np.where(lengths[:, None, None] > 0, anc1[None], 0).astype(np.int8)
+                anc = jnp.asarray(anc_np)
+                live = np.repeat((lengths > 0)[:, None], T, axis=1)
+            ref = paged_spec_attention_xla(
+                q, k, v, jnp.int32(1), tables, jnp.asarray(lens2), *scales, anc=anc)
+            out = paged_spec_attention(
+                q, k, v, jnp.int32(1), tables, jnp.asarray(lens2), *scales, anc, **kw)
+    out = np.asarray(out)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(np.asarray(ref)[live], out[live], atol=2e-5, rtol=2e-5)
+    # A row that attends nothing comes out as zeros, whatever its buffers held.
+    dead_rows = lengths == 0
+    assert not out[dead_rows].any()
 
 
 def test_resolve_attn_impl():

@@ -86,6 +86,78 @@ def test_kernel_compiles_for_v5e(v5e, preset, variant):
     fn.lower(*args).compile()
 
 
+# The benchmark's cells call the decode kernel at these shapes (PERF.md
+# section 4): decode bucket x table width, at the configuration's attention
+# geometry. llama-8b's (32 query heads on 8 KV heads of 128) is Mistral-7B's.
+CELL_CALLS = {
+    "qwen2.5-7b-int8.sessions": ("qwen2-7b", 64, 256),
+    "qwen2.5-7b-int8.chat": ("qwen2-7b", 32, 256),
+    "mistral-7b-v0.3-int8.mixed": ("llama-8b", 32, 256),
+    "mistral-7b-v0.3-int8.chat.bucket8": ("llama-8b", 8, 256),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_CALLS))
+def test_decode_kernel_compiles_at_the_cells_call_shapes(v5e, cell):
+    preset, B, W = CELL_CALLS[cell]
+    fn, args = _kernel_case(ModelConfig.preset(preset), "decode", ctx=W * BS, B=B, sharding=v5e)
+    fn.lower(*args).compile()
+
+
+def _eqns(jaxpr, primitive: str) -> list:
+    """Every equation of ``primitive`` under ``jaxpr``, nested programs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    found += _eqns(inner, primitive)
+    return found
+
+
+def _latent_case(B: int = 128, W: int = 128, sharding=None):
+    """The LongCat cell's call: 64 query heads against one shared 576-wide
+    key padded to 640 lanes, value = its first 512, pages of 32 tokens."""
+    from dynamo_tpu.ops.paged_attention import latent_decode_attention
+
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    fn = functools.partial(latent_decode_attention, value_dim=512, scale=192 ** -0.5)
+    return jax.jit(fn), (S((B, 64, 640), jnp.bfloat16), S((8, 2 * W, 32, 640), jnp.bfloat16),
+                         S((), jnp.int32), S((B, W), jnp.int32), S((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("variant", VARIANTS + ("latent",))
+def test_the_walk_is_one_grid_step_a_row(variant):
+    """The structural pin of PR 31. The grid is the rows alone: a row's
+    chunks are a loop inside its step, so the table's width adds no grid
+    steps (before, ``(B, W // P)``: 512 steps for 46 live rows of the
+    sessions cell, 321 of them dead). int8 pages keep the chunk axis, for
+    their scale blocks, and nothing else may. And no product takes a
+    transposed LEFT operand: the old ``v^T p`` made Mosaic transpose the
+    whole ``[512, 512]`` chunk of V every chunk (``tpu.transpose`` in its
+    output); ``q k^T`` and ``p v`` contract the left operand's last
+    dimension."""
+    B, W = 16, 256
+    if variant == "latent":
+        fn, args = _latent_case(B, W)
+    else:
+        fn, args = _kernel_case(ModelConfig.preset("qwen2-7b"), variant, ctx=W * BS, B=B)
+    (call,) = _eqns(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call")
+    grid = tuple(call.params["grid_mapping"].grid)
+    if variant == "int8":
+        assert grid[0] == B and len(grid) == 2
+    else:
+        assert grid == (B,)
+    dots = _eqns(call.params["jaxpr"], "dot_general")
+    assert dots
+    for eqn in dots:
+        (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+        assert tuple(lhs_contract) == (eqn.invars[0].aval.ndim - 1,), eqn
+
+
 def test_int8_kv_kernel_limits_repaired(v5e):
     """The two int8-KV limits the compiler used to enforce and the code
     did not: head_dim 64 (the in-kernel scale broadcast) and a scale block
@@ -214,22 +286,13 @@ def _abstract(tree, S):
 
 
 def test_latent_decode_kernel_compiles_for_v5e(v5e):
-    """64 query heads against one shared 576-wide key padded to 640 lanes,
-    value = its first 512, 128 rows over a 4,096-token table."""
-    from dynamo_tpu.ops.paged_attention import (
-        latent_decode_attention,
-        latent_kernel_unsupported,
-    )
+    """The LongCat cell's own call: 128 rows over a 4,096-token table."""
+    from dynamo_tpu.ops.paged_attention import latent_kernel_unsupported
 
     cfg = _longcat()
     assert cfg.latent_page_width == 640 and latent_kernel_unsupported(cfg, LBS) is None
-    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
-    B, W = 128, 4096 // LBS
-    fn = functools.partial(latent_decode_attention, value_dim=512, scale=192 ** -0.5)
-    jax.jit(fn).lower(
-        S((B, 64, 640), jnp.bfloat16), S((8, 2 * W, LBS, 640), jnp.bfloat16),
-        S((), jnp.int32), S((B, W), jnp.int32), S((B,), jnp.int32),
-    ).compile()
+    fn, args = _latent_case(128, 4096 // LBS, sharding=v5e)
+    fn.lower(*args).compile()
 
 
 @pytest.mark.parametrize("rows,k,n", [(1536, 6144, 2048), (6144, 2048, 6144)])
