@@ -15,7 +15,7 @@ zombie rows of just-finished sequences still read the old weights).
 
 Thread affinity: acquire/release run on the engine's scheduler thread
 only (same contract as BlockPool); the integer stats are read racily by
-bench/metrics like every other monotonic counter.
+the metrics page like every other monotonic counter.
 """
 
 from __future__ import annotations

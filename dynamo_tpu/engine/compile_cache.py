@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
-One rule for every process that compiles (worker, ``run``, bench, tools,
-``chip_smoke.py``): ``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself,
+One rule for every process that compiles (worker, ``run``, ``chipbench/``,
+tools, ``chip_smoke.py``): ``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself,
 wins and nothing is set in code; without it the cache is
 ``<checkout>/.jax_cache``. The path is part of the cache key, so it must
 not move between runs. JAX's own 1.0 s minimum compile time decides what

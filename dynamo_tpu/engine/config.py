@@ -351,8 +351,8 @@ class EngineArgs:
     # worst-case pad; "coarse" is the legacy 2x/4x ladder (fewest
     # compiles); a comma list ("64,128,384") pins an explicit schedule
     # (values round up to block_size multiples; max_prefill_tokens is
-    # always appended). Each bucket × table-width pair is one compile —
-    # warm the lattice (bench.py --precompile-only) after widening.
+    # always appended). Each bucket × table-width pair is one compile,
+    # paid inside the first request that needs it unless the cache is warm.
     prefill_buckets_spec: str = "fine"
     # Split a suffix whose bucket pad is large into [bucket-sized chunk,
     # re-bucketed tail] chunked-prefill dispatches: a 600-token suffix
@@ -464,9 +464,9 @@ class EngineArgs:
     # every non-cooling row keeps a >= 1-node probe so it can re-heat).
     # Grammar-constrained rows are typically the hottest, so the whole
     # batch's weight-pass amortization improves at EQUAL total budget.
-    # False = the uniform per-row allowance (PR 10 behavior, the bench
-    # A/B baseline). Correctness is allocation-independent: greedy
-    # streams stay byte-identical to dense for any budget split.
+    # False = the uniform per-row allowance (PR 10 behavior). Correctness
+    # is allocation-independent: greedy streams stay byte-identical to
+    # dense for any budget split.
     spec_budget_adaptive: bool = True
     # Tokenizer spec dict ({"type": "byte"} / {"type": "hf", ...}) the
     # engine compiles grammar token-mask FSMs over (engine/grammar.py).

@@ -408,16 +408,6 @@ class _First:
         return a + self.routed
 
 
-# Host-side phases during which the scheduler thread is (or may be)
-# BLOCKED on a device fetch/sync — the bench.py host_blocked_frac
-# numerator. drain_ready is included conservatively: is_ready() reflects
-# device COMPUTE completion, not arrival of the async D2H copy, so a
-# "ready" drain's np.asarray can still wait out the transfer tail on a
-# slow link; counting it keeps the metric an honest upper bound (it is
-# ~µs when overlap works, which is the claim being measured).
-BLOCKING_PHASES = ("first_sample", "drain_sync", "drain_ready", "single_step")
-
-
 def register_engine_metrics(registry) -> dict:
     """Register the engine gauges/counters on a MetricsRegistry →
     {name without the registry's prefix: metric}. Shared by the worker
@@ -614,7 +604,7 @@ class TpuEngine:
     # Deliberately NOT owned: spec_tokens + spec_budget_adaptive
     # (documented idle-engine toggles, read once per scheduler
     # iteration), the total_* counters incl. total_grammar_mask_s
-    # (monotonic values read racily by bench/metrics — stale reads are
+    # (monotonic values read racily by the metrics page — stale reads are
     # harmless, total_lora_s included), _stopping (always mutex-guarded),
     # pool/tiers/_lora_pool (internally consistent; acquire/release on
     # the scheduler thread, cross-thread readers get point-in-time
@@ -682,7 +672,7 @@ class TpuEngine:
         self._embed_jobs: collections.deque = collections.deque()
         # (fn, future, loop) host jobs run on the engine thread between
         # steps — the device-dispatch-affinity seam for out-of-band work
-        # like AOT-warming the spec_verify compile lattice (bench).
+        # (migration's freeze/export, worker/migrate.py).
         self._host_jobs: collections.deque = collections.deque()
         # Disagg exports: handle → (KvPagePayload | KvStreamExport,
         # deadline). Host copies, so they survive cache donation; reaped
@@ -719,15 +709,15 @@ class TpuEngine:
         # wire sends overlap the remaining prefill chunks.
         self._export_fetches: list = []
         # Speculative decoding: host-side drafter + a runtime-togglable
-        # draft length (initialized from args; bench/tests flip it on an
+        # draft length (initialized from args; tests flip it on an
         # idle engine to compare dense vs speculative on one warmed
         # engine — it is read once per scheduler iteration, never mid-
         # dispatch).
         self._drafter = build_drafter(args)
         self.spec_tokens = args.spec_tokens
         # Batch-budget mode toggle: like spec_tokens, a documented
-        # idle-engine runtime switch (bench A/Bs adaptive vs uniform on
-        # one warmed engine); read once per _try_speculative call.
+        # idle-engine runtime switch (tests compare adaptive with uniform
+        # on one warmed engine); read once per _try_speculative call.
         self.spec_budget_adaptive = args.spec_budget_adaptive
         # Grammar-constrained decoding: the compiler (vocab + schema
         # cache) is built lazily on the first constrained request, OFF
@@ -791,12 +781,11 @@ class TpuEngine:
         # contract like the other total_* counters).
         self._arrival_no = 0
         self.total_preemptions_by: collections.Counter = collections.Counter()
-        # Cumulative counters for metrics/bench.
+        # Cumulative counters (the worker's /metrics page reads them).
         self.total_generated = 0
         self.total_prefilled = 0
         # Token-rows actually DISPATCHED for prefill (bucket padding and
-        # padded rows included) — the denominator for padding-efficiency
-        # accounting (bench.py roofline breakdown).
+        # padded rows included) — the numerator of engine_prefill_pad_ratio.
         self.total_prefill_padded = 0
         self.total_decode_steps = 0  # device substeps incl. padded/zombie work
         # Decode rows x steps: what every dense decode dispatch paid for
@@ -804,8 +793,8 @@ class TpuEngine:
         # the tokens those dispatches delivered to a live sequence.
         self.total_decode_rows_dispatched = 0
         self.total_decode_rows_emitted = 0
-        # Host-side phase accounting (bench.py --breakdown; VERDICT r4
-        # weak #1: where the non-device half of the step time goes).
+        # Host-side phase accounting: where the non-device half of the
+        # step time goes (engine_step_phase_seconds_total{phase}).
         # Keys: idle / admission / prefill_dispatch / first_sample /
         # decode_dispatch / drain_sync / emit / other.
         self.phase_s: dict[str, float] = collections.defaultdict(float)
@@ -1060,9 +1049,10 @@ class TpuEngine:
     ) -> None:
         """Register one serveable adapter. ``pages`` = pre-materialized
         factor pages (checkpoint loaders); None = deterministic random
-        factors from (name, seed) — the bench/test source. Write-through:
-        pages land in the tier economy now, so later slot eviction is
-        free and a cold re-page-in is a tier read, not a reload.
+        factors from (name, seed) — what the tests and the worker's
+        ``--lora NAME:RANK:SEED`` use. Write-through: pages land in the
+        tier economy now, so later slot eviction is free and a cold
+        re-page-in is a tier read, not a reload.
         Thread-safe; callable while serving (new tenants onboard live)."""
         if self._lora_pool is None:
             raise RequestValidationError(
@@ -1636,8 +1626,8 @@ class TpuEngine:
 
     async def run_on_engine_thread(self, fn):
         """Run ``fn()`` on the scheduler thread between steps (device
-        dispatch affinity) and await its result. Bench/warmup seam — not
-        a serving-path API."""
+        dispatch affinity) and await its result. Migration's freeze/export
+        seam (worker/migrate.py) — not a request-path API."""
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         with self._wakeup:
@@ -1658,91 +1648,6 @@ class TpuEngine:
             loop.call_soon_threadsafe(
                 lambda: fut.set_exception(err) if not fut.cancelled() else None
             )
-
-    async def warm_spec(self, modes: tuple[str, ...] = ("greedy",),
-                        top_ns: tuple[int, ...] = (0,),
-                        grammar: bool = False) -> int:
-        """AOT-compile the REQUESTED subset of the spec_verify variant
-        lattice: one inert dispatch (all rows inactive → KV writes land
-        in garbage block 0) per (decode bucket x table bucket x mode x
-        top_n x S1 shape). Drafts cannot be forced through real traffic
-        — they depend on the model looping — so cold variants would
-        otherwise compile mid-serving. The default covers the bench
-        shape (greedy, no top_logprobs); a serving worker expecting
-        sampled or top_logprobs traffic should pass modes=("greedy",
-        "simple") and top_ns=(0, args.top_logprobs_max), or rely on the
-        persistent compile cache (engine/compile_cache.py) like every
-        other variant family. Adaptive batch budgets add the 2S+1 shape
-        (hot rows drafting past S); ``grammar=True`` adds the
-        masked-tree variants constrained traffic dispatches. → number
-        of variants dispatched."""
-        S = self.spec_tokens
-        if S <= 0:
-            return 0
-        args = self.args
-        s1_list = spec_verify_widths(S, self.spec_budget_adaptive)
-
-        def _warm():
-            count = 0
-            for S1 in s1_list:
-                # Tree lattice rides the same loop when tree drafting is
-                # on: the topology arrays are traced by SHAPE only, so
-                # one inert chain-shaped dispatch warms every tree a
-                # real batch can produce at this (B, W, mode, top_n).
-                # Grammar masks are one more shape-only operand: the
-                # masked variant covers every schema.
-                shapes: list[tuple[bool, bool]] = [(False, False)]
-                if args.spec_tree_width > 1 or grammar:
-                    shapes.append((True, False))
-                if grammar:
-                    shapes.append((True, True))
-                chain_parents = np.maximum(
-                    np.arange(S1, dtype=np.int32) - 1, 0
-                )
-                chain_anc = np.tril(np.ones((S1, S1), np.int8))
-                chain_depth = np.arange(S1, dtype=np.int32)
-                W32 = mask_words(self.cfg.vocab_size)
-                # Adapter-slot operand is one more shape-only variant
-                # axis (mixed-adapter batches dispatch with it; base
-                # batches without).
-                lora_opts = [False, True] if args.lora_slots > 0 else [False]
-                for mode in modes:
-                    for top_n in top_ns:
-                        for B in args.decode_buckets:
-                            for W in args.table_buckets:
-                                for with_tree, with_mask in shapes:
-                                    for with_lora in lora_opts:
-                                        tree = masks = None
-                                        if with_tree:
-                                            tree = (
-                                                np.broadcast_to(chain_parents, (B, S1)).copy(),
-                                                np.broadcast_to(chain_anc, (B, S1, S1)).copy(),
-                                                np.broadcast_to(chain_depth, (B, S1)).copy(),
-                                            )
-                                        if with_mask:
-                                            masks = np.full(
-                                                (B, S1, W32), 0xFFFFFFFF, np.uint32
-                                            )
-                                        aslots = (
-                                            np.zeros((B,), np.int32)
-                                            if with_lora else None
-                                        )
-                                        self._runner.spec_verify(
-                                            S1, mode,
-                                            np.zeros((B, S1), np.int32),
-                                            np.zeros((B,), np.int32),
-                                            np.full((B,), S1 - 1, np.int32),
-                                            np.zeros((B, W), np.int32),
-                                            np.zeros((B,), bool),
-                                            np.ones((B,), np.float32),
-                                            np.zeros((B,), np.uint32),
-                                            np.zeros((B,), np.int32),
-                                            None, top_n, tree, masks, aslots,
-                                        )
-                                        count += 1
-            return count
-
-        return await self.run_on_engine_thread(_warm)
 
     def _serve_embed(self, token_ids: list[int], fut, loop) -> None:
         try:

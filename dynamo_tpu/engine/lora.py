@@ -65,9 +65,9 @@ class LoraError(Exception):
 class LoraAdapterSpec:
     """One registered adapter: identity + how to (re)materialize it.
 
-    ``seed``-based adapters generate deterministic random factors (the
-    bench/test source; real checkpoints plug in through ``pages`` at
-    registration). ``scaling`` is the classic alpha/rank multiplier,
+    ``seed``-based adapters generate deterministic random factors (what
+    the tests and the worker's ``--lora NAME:RANK:SEED`` use; real
+    checkpoints plug in through ``pages`` at registration). ``scaling`` is the classic alpha/rank multiplier,
     folded into B before upload."""
 
     name: str

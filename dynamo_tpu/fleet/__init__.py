@@ -3,9 +3,9 @@
 The reference architecture scales its ingress by running many stateless
 HTTP frontends over one routed request plane (PAPER.md §1-2; DistServe and
 Mooncake assume the same shape). One GIL-bound Python process tops out
-around ~5.3k tok/s at 128 streams (BENCH_FRONTEND_r06), so this package
-makes the frontend horizontally scalable while keeping the *semantics* of
-a single process:
+around ~5.3k tok/s at 128 streams (CPU, July, against the mocker), so
+this package makes the frontend horizontally scalable while keeping the
+*semantics* of a single process:
 
 - :mod:`~dynamo_tpu.fleet.supervisor` — spawns N frontend processes
   sharing one listen port (``SO_REUSEPORT``, inherited-listener fallback),

@@ -32,7 +32,7 @@ million-conversation traffic the lease TTL alone is not a memory bound
 the cap evict the coldest entry locally (the store copy still expires by
 lease; eviction is per-mirror, not fleet-wide). A per-worker key index
 makes the dead-worker tombstone sweep O(worker's entries) instead of a
-full-mirror scan (docs/performance.md "Control-plane scaling").
+full-mirror scan.
 """
 
 from __future__ import annotations

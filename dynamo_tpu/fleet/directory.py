@@ -199,7 +199,7 @@ class PrefixDirectory:
         # Inverted index, maintained incrementally by diffing snapshots
         # in _apply: hash → holder worker ids. Turns best_runs/holders/
         # heat from O(workers × chain) scans into O(chain + holders)
-        # walks (docs/performance.md "Control-plane scaling").
+        # walks.
         self._inv: dict[int, set[int]] = {}
         self._watch = None
         self._watch_task: asyncio.Task | None = None

@@ -56,7 +56,7 @@ def parse_args(argv=None):
                    help="placement candidate pruning: score only the index's "
                         "top-k holder shortlist + least-loaded workers instead "
                         "of the whole fleet (0 = full scan, the legacy "
-                        "byte-identical path; docs/performance.md)")
+                        "byte-identical path)")
     p.add_argument("--record-dir", default=None,
                    help="record response streams + routing events to JSONL here "
                         "(replayable offline; llm/recorder.py)")

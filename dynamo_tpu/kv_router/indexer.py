@@ -219,11 +219,11 @@ class ShardedRadixIndex:
     with ordered per-shard queues. The payoff here is less about raw
     events/s (the GIL bounds dict mutation) and more that event FLOODS
     never run on the routing asyncio loop — routing latency stays flat
-    while shard threads chew through bursts (tools/profile_indexer.py
-    measures both). Overflow policy matches the reference's gap story:
-    a shard queue past its bound drops that worker's state and reports
-    False so the subscription layer re-snapshots; all mutations ride the
-    queue, so drop → resnapshot ordering is preserved."""
+    while shard threads chew through bursts. Overflow policy matches the
+    reference's gap story: a shard queue past its bound drops that
+    worker's state and reports False so the subscription layer
+    re-snapshots; all mutations ride the queue, so drop → resnapshot
+    ordering is preserved."""
 
     def __init__(self, num_shards: int = 4, max_queue: int = 8192):
         import queue as _queue
@@ -254,8 +254,8 @@ class ShardedRadixIndex:
     def _shard_loop(self, i: int) -> None:
         # Ops are drained in batches under ONE lock acquisition, with an
         # explicit yield between batches: per-op lock cycling starves
-        # concurrent find_matches callers (measured p99 26→0.1 ms with
-        # batching, tools/profile_indexer.py).
+        # concurrent find_matches callers (p99 26→0.1 ms with batching;
+        # CPU, July, not measured on the chip).
         import queue as _queue
         import time as _time
 

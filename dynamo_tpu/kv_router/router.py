@@ -66,10 +66,10 @@ class KvRouterConfig:
     # "admit on the warm engine, balancer sheds later" over landing
     # cold. None = off (no balancer, load priced at face value).
     migrate_cost_blocks: float | None = None
-    # Cluster-scale candidate pruning (docs/performance.md
-    # "Control-plane scaling"): the index returns a ranked top-k holder
-    # shortlist and the scheduler scores only shortlist ∪ least-loaded-m
-    # ∪ sticky/directory hits — O(k) per placement instead of O(fleet).
+    # Cluster-scale candidate pruning: the index returns a ranked top-k
+    # holder shortlist and the scheduler scores only shortlist ∪
+    # least-loaded-m ∪ sticky/directory hits — O(k) per placement instead
+    # of O(fleet).
     # 0 = full scan, byte-for-byte the pre-shortlist behavior. Fleets no
     # larger than shortlist_k + least_loaded_m always take the full scan.
     shortlist_k: int = 16

@@ -57,9 +57,9 @@ class KvSchedulerConfig:
     # Cost of pulling one missing prefix block from a peer, in units of
     # recomputing one block locally (0 = transfers are free, 1 = no
     # cheaper than recompute — directory pricing effectively off).
-    # ~0.35 matches the measured peer-fetch vs prefill ratio on the
-    # loopback transfer plane (BENCH_DISAGG_r08 frame throughput vs
-    # prefill tok/s); a WAN-separated fleet wants it near 1.
+    # ~0.35 was the peer-fetch vs prefill ratio on the loopback transfer
+    # plane (CPU, July, not measured on the chip); a WAN-separated fleet
+    # wants it near 1.
     transfer_block_cost: float = 0.35
     # Migration-aware decode pricing: cap each candidate's decode-load
     # term at fleet_mean + migrate_cost_blocks (the amortized price of
@@ -112,8 +112,8 @@ class KvScheduler:
         survived index top-k pruning is in the set, and among the
         zero-overlap rest cost differs only by load — so when the index
         shortlist covers all holders the pruned argmin equals the
-        full-scan argmin exactly (docs/performance.md, shortlist recall
-        policy). ``workers_set`` (eligible-worker membership) avoids an
+        full-scan argmin exactly (tests/test_router_shortlist.py pins
+        it). ``workers_set`` (eligible-worker membership) avoids an
         O(fleet) set build when the caller already has one."""
         if not workers:
             raise ValueError("no workers")

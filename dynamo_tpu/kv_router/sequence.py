@@ -10,8 +10,7 @@ Cluster-scale addition: the ledger also maintains the *fleet aggregates*
 the scheduler used to recompute per request — a running total of active
 blocks (for the fleet-load mean) and a lazily-invalidated min-heap of
 (load, worker) for least-loaded-m candidate selection. Both are updated
-on load deltas, so placement stops paying O(fleet) per request
-(docs/performance.md "Control-plane scaling").
+on load deltas, so placement stops paying O(fleet) per request.
 """
 
 from __future__ import annotations

@@ -338,7 +338,6 @@ class DisaggDecodeHandler:
         self.local_fallbacks = 0
         self.fallback_reasons: dict[str, int] = {}
         self.transfer_bytes_total = 0
-        self.transfer_overlapped_total = 0
         self.last_transfer: dict = {}
         self._metrics = None
         # Per-pull inflight bytes (keyed by stream handle): concurrent
@@ -371,7 +370,6 @@ class DisaggDecodeHandler:
         span attributes (returned, not read back off the handler —
         ``last_transfer`` is a concurrently-clobbered informational slot)."""
         self.transfer_bytes_total += pulled.total_bytes
-        self.transfer_overlapped_total += pulled.overlapped_bytes
         attrs = {
             "bytes": pulled.total_bytes,
             "chunks": len(pulled.chunks),

@@ -381,8 +381,7 @@ class HttpService:
 
     async def handle_debug_slo(self, request: web.Request) -> web.Response:
         """SLO burn-rate state: per-class/per-phase burn EMAs, attainment
-        EMAs, and an attribution summary over the recent ledger window —
-        the same schema bench.py and the diurnal simulator emit."""
+        EMAs, and an attribution summary over the recent ledger window."""
         body = self.slo_burn.snapshot()
         rec = tracing.recorder()
         if rec is not None:

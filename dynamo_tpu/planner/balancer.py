@@ -11,9 +11,8 @@ siblings instead of stretching every resident stream's ITL.
 Split exactly like planner/operator.py:
 
 - :class:`BalancerLaw` — the pure decision core. Deterministic and
-  clock-injected, so the 120-engine discrete-event bench
-  (benchmarks/diurnal.py --balancer) and the unit suite drive the EXACT
-  production decision code.
+  clock-injected, so the unit suite (tests/test_balancer.py) drives the
+  EXACT production decision code.
 - :class:`FleetBalancer` — the async shell: observes per-engine load off
   the existing ``load_metrics`` plane, actuates through ``workerctl
   migrate_out`` admin RPCs, roots a ``planner.balance`` span per move
@@ -124,7 +123,7 @@ class BalanceMove:
 
 @dataclass
 class BalancerState:
-    """Introspectable decision state (surfaced by /fleet + the bench)."""
+    """Introspectable decision state (surfaced by /fleet)."""
 
     moves_proposed: int = 0
     moves_actuated: int = 0
@@ -268,7 +267,7 @@ def load_from_metrics(instance_id: int, m) -> EngineLoad:
 class FleetBalancer:
     """The async shell around :class:`BalancerLaw`.
 
-    Seams (all injectable — the bench and tests drive fakes):
+    Seams (all injectable — the tests drive fakes):
 
     - ``pools``: async () → {POOL_*: [WorkerInfo]} (planner/actuate.py
       ``read_pools`` in production); only the decode pool is balanced.

@@ -98,14 +98,14 @@ class OperatorConfig:
     idle_cycles_for_scale_down: int = 3
     scale_down_headroom: float = 1.3
     # False = fixed chip count: the law only MOVES engines between
-    # pools (the bench's equal-chip-count shape); True also scales the
+    # pools (an equal-chip-count deployment); True also scales the
     # replica total within [min_prefill+min_decode, max_engines].
     replica_scaling: bool = True
 
 
 @dataclass
 class LawState:
-    """Introspectable decision state (surfaced by /debug + the bench)."""
+    """Introspectable decision state (surfaced by /debug)."""
 
     last_prediction: float = 0.0
     idle_cycles: int = 0
@@ -116,8 +116,8 @@ class LawState:
 class ControlLaw:
     """Pure decision core: (observation, pool sizes, now) → actions.
 
-    Deterministic and clock-injected, so the discrete-event bench and
-    the unit suite drive the EXACT production decision code."""
+    Deterministic and clock-injected, so the unit suite drives the
+    EXACT production decision code."""
 
     def __init__(
         self,

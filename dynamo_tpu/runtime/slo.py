@@ -10,10 +10,9 @@ the control plane's evidence:
   gauges and consumed by the QoS admission gate (burn-aware early
   rejection) and anything else that wants to know *which pool* is
   spending the budget (Mooncake/DistServe framing — see PAPERS.md).
-- :func:`attribution_summary` — one aggregation of ledger-shaped records
-  into the shared attribution schema that ``bench.py``, the diurnal
-  simulator, and ``/debug/slo`` all emit, so a regression localizes to a
-  phase instead of a wall-clock delta.
+- :func:`attribution_summary` — one aggregation of ledger records into
+  the attribution schema that ``/debug/slo`` emits, so a regression
+  localizes to a phase instead of a wall-clock delta.
 
 Budget semantics: TTFT-phase burn divides by the class TTFT SLO;
 decode-window burn divides by the total ITL budget
@@ -166,75 +165,38 @@ def _percentile(sorted_vals: list[float], q: float) -> float:
     return sorted_vals[idx]
 
 
-def attribution_summary(
-    records: Iterable[dict],
-    *,
-    ttft_slo_s: float | None = None,
-    itl_slo_ms: float | None = None,
-) -> dict:
-    """Aggregate ledger-shaped records into the shared attribution schema.
+def attribution_summary(records: Iterable[dict]) -> dict:
+    """Aggregate ledger records into the attribution schema of ``/debug/slo``.
 
-    ``records`` need only be ledger-*shaped*: dicts with optional
-    ``ttft_s``, ``itl_s``, ``duration_s``, ``completion_tokens`` and a
-    ``phases`` mapping — bench.py and the diurnal simulator synthesize
-    them from their own bookkeeping; the HTTP ingress passes real ledger
-    records. Output schema (stable — emitted verbatim into bench/diurnal
-    result JSON and ``/debug/slo``)::
+    ``records`` are the tracing recorder's ledger records: dicts with
+    optional ``ttft_s`` and a ``phases`` mapping. Output schema (stable —
+    emitted verbatim into ``/debug/slo``)::
 
         {"schema": 2, "requests": N,
          "phases": {phase: {"total_s", "mean_s", "share"}},
-         "ttft": {"mean_s", "p99_s"},
-         "slo": {"ttft_slo_s", "ttft_attainment", "itl_slo_ms",
-                 "itl_attainment", "burn": {phase: mean_ratio}}}
+         "ttft": {"mean_s", "p99_s"}}
 
     ``share`` is each phase's fraction of summed phase time (where the
-    time went); ``burn`` divides by the budget (what it cost) — absent
-    without SLO targets.
+    time went); what it cost against a budget is :class:`SloBurnTracker`'s.
     """
     recs = [r for r in records if isinstance(r, dict)]
-    n = len(recs)
     phase_tot: dict[str, float] = {}
     phase_n: dict[str, int] = {}
     ttfts: list[float] = []
-    ttft_ok = 0
-    ttft_n = 0
-    itl_ok = 0
-    itl_n = 0
-    burn_tot: dict[str, float] = {}
-    burn_n: dict[str, int] = {}
     for r in recs:
-        phases = r.get("phases") or {}
-        completion = r.get("completion_tokens") or 0
-        itl_budget_s = (
-            (itl_slo_ms / 1000.0) * max(completion - 1, 1)
-            if itl_slo_ms else None
-        )
-        for phase, dur in phases.items():
+        for phase, dur in (r.get("phases") or {}).items():
             if dur is None:
                 continue
             phase_tot[phase] = phase_tot.get(phase, 0.0) + dur
             phase_n[phase] = phase_n.get(phase, 0) + 1
-            budget = (
-                itl_budget_s if phase in DECODE_PHASES else ttft_slo_s
-            )
-            if budget:
-                burn_tot[phase] = burn_tot.get(phase, 0.0) + dur / budget
-                burn_n[phase] = burn_n.get(phase, 0) + 1
         ttft = r.get("ttft_s")
         if ttft is not None:
             ttfts.append(ttft)
-            if ttft_slo_s:
-                ttft_n += 1
-                ttft_ok += 1 if ttft <= ttft_slo_s else 0
-        itl = r.get("itl_s")
-        if itl is not None and itl_slo_ms:
-            itl_n += 1
-            itl_ok += 1 if itl * 1000.0 <= itl_slo_ms else 0
     total_phase_s = sum(phase_tot.values())
     ttfts.sort()
-    out: dict[str, Any] = {
+    return {
         "schema": 2,
-        "requests": n,
+        "requests": len(recs),
         "phases": {
             phase: {
                 "total_s": round(tot, 6),
@@ -248,20 +210,3 @@ def attribution_summary(
             "p99_s": round(_percentile(ttfts, 0.99), 6) if ttfts else None,
         },
     }
-    if ttft_slo_s or itl_slo_ms:
-        slo: dict[str, Any] = {"burn": {
-            phase: round(burn_tot[phase] / burn_n[phase], 6)
-            for phase in sorted(burn_tot)
-        }}
-        if ttft_slo_s:
-            slo["ttft_slo_s"] = ttft_slo_s
-            slo["ttft_attainment"] = (
-                round(ttft_ok / ttft_n, 4) if ttft_n else None
-            )
-        if itl_slo_ms:
-            slo["itl_slo_ms"] = itl_slo_ms
-            slo["itl_attainment"] = (
-                round(itl_ok / itl_n, 4) if itl_n else None
-            )
-        out["slo"] = slo
-    return out

@@ -66,7 +66,7 @@ class CreditBudget:
     whatever of ``total_bytes`` the outstanding windows have left,
     floored at :data:`MIN_WINDOW_BYTES`. Rebalancing therefore shapes
     its own bandwidth around the disagg plane instead of competing with
-    it (ISSUE 19 tentpole (c); docs/performance.md has the budget math).
+    it.
 
     Thread-safe; windows are short-lived (acquire → one pull window →
     release), so a busy disagg plane throttles migrations within one
@@ -477,7 +477,7 @@ async def serve_kv_window(
 @dataclass
 class PulledKvStream:
     """Everything one completed pull produced, plus the overlap
-    accounting the bench/metrics report."""
+    accounting the metrics report."""
 
     chunks: list
     num_tokens: int

@@ -141,7 +141,7 @@ class MigrationCoordinator:
         # opening another stream (callers retry or keep the sequence).
         self.max_outbound = max(int(max_outbound), 1)
         self._outbound = 0
-        # In-process ledgers (tests/bench assert against these; the
+        # In-process ledgers (tests assert against these; the
         # metrics dict mirrors them when bound).
         self.outcomes: dict[str, int] = {}
         self.fallback_reasons: dict[str, int] = {}

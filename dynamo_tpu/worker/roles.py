@@ -61,8 +61,8 @@ class WorkerRoleManager:
     namespace (component names + disagg knobs); ``cards`` is the model
     card list the decode role publishes (base card first)."""
 
-    #: Max blocks a retiring replica pushes to survivors (drain-on-retire,
-    #: docs/performance.md "Fleet KV economy"). Bounds the retirement
+    #: Max blocks a retiring replica pushes to survivors (drain-on-retire).
+    #: Bounds the retirement
     #: latency the autoscaler observes: the drain is an optimization, not
     #: a durability guarantee — anything past the budget re-enters the
     #: fleet through G4 or recompute.

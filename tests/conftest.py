@@ -2,7 +2,7 @@
 
 Forces JAX onto a virtual 8-device CPU platform *before* jax is imported so
 multi-chip sharding (TP/DP/SP meshes) is exercised without TPU hardware.
-Real-TPU benchmarking lives in bench.py, not the test suite.
+The chip is measured by chipbench/run.py and chip_smoke.py, not by the test suite.
 """
 
 import os

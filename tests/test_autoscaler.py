@@ -7,6 +7,7 @@ points must produce explicit Holds, never NaN/negative pool sizes
 
 import asyncio
 import math
+import random
 
 import numpy as np
 import pytest
@@ -454,3 +455,48 @@ def test_worker_card_profile_discovery_end_to_end(tmp_path):
         await wrt.shutdown()
 
     asyncio.run(go())
+
+
+@pytest.mark.parametrize("seed", [3, 17, 404])
+def test_flash_crowd_moves_pools_and_never_flaps(seed):
+    """A prompt-heavy flash crowd over a fixed fleet of twelve engines, the
+    loop closed through a queueing toy (a pool's latency grows with its
+    demand over its size, observed with seeded noise): the law moves
+    engines toward prefill while the crowd lasts and back when a
+    decode-heavy evening follows, and no move is ever reversed within two control intervals."""
+    rng = random.Random(seed)
+    interval = 5.0
+    lw = law(max_engines=12, hysteresis_cycles=2, cooldown_s=30.0,
+             interval_s=interval, predictor="constant")
+    n_p, n_d = 3, 9
+    moves = []  # (tick, src, dst)
+    for tick in range(240):
+        crowd = 40 <= tick < 120
+        if crowd:
+            rate, isl, osl = 30.0, 900.0, 48.0
+        elif tick >= 120:  # a decode-heavy evening after it
+            rate, isl, osl = 14.0, 100.0, 400.0
+        else:
+            rate, isl, osl = 6.0, 200.0, 96.0
+        rate *= rng.uniform(0.9, 1.1)
+        pre_util = rate * isl / (n_p * 2560.0)
+        dec_util = rate * osl / (n_d * 1070.0)
+        obs = PlannerObservation(
+            request_rate=rate, input_token_rate=rate * isl,
+            output_token_rate=rate * osl,
+            ttft_ms=120.0 * max(1.0, pre_util) ** 2 * rng.uniform(0.9, 1.1),
+            itl_ms=8.0 * max(1.0, dec_util) ** 2 * rng.uniform(0.9, 1.1),
+        )
+        now = tick * interval
+        for mv in actions_of(lw.decide(obs, n_p, n_d, now=now), PoolMove):
+            n_p += 1 if mv.dst == POOL_PREFILL else -1
+            n_d += 1 if mv.dst == POOL_DECODE else -1
+            lw.notify_actuated(KIND_POOL_MOVE, now=now)
+            moves.append((tick, mv.src, mv.dst))
+        assert n_p >= 1 and n_d >= 1 and n_p + n_d == 12
+    to_prefill = [t for t, _s, d in moves if d == POOL_PREFILL]
+    assert to_prefill and 40 <= min(to_prefill) < 120, moves
+    assert any(d == POOL_DECODE and t >= 120 for t, _s, d in moves), moves
+    for (t0, s0, d0), (t1, s1, d1) in zip(moves, moves[1:]):
+        if (s1, d1) == (d0, s0):
+            assert t1 - t0 > 2, f"move reversed after {t1 - t0} intervals: {moves}"

@@ -288,7 +288,7 @@ def test_int8_tier_onboard_and_reuse(tmp_path):
     prefilling only the suffix. (Streams are not asserted byte-equal to
     the first run: the suffix prefill attends the prefix through
     quantized pages where the original prefill attended its own exact
-    registers — the documented int8 caveat, docs/performance.md.)"""
+    registers — the int8 KV caveat.)"""
 
     async def go():
         args = kv_args(

@@ -26,6 +26,7 @@ output branch-rich. Every request is explicitly seeded (PR 4 lesson).
 """
 
 import asyncio
+import random
 
 import pytest
 
@@ -144,14 +145,47 @@ def test_tree_greedy_byte_identity(width, depth):
     asyncio.run(go())
 
 
-def test_tree_branched_pass_dispatches():
-    """The branchy workload must actually exercise the TREE op (a
+POOL_SEED = 20260928
+
+
+class SeededPool(JacobiPool):
+    """A Jacobi pool that has two candidates for EVERY context: what was
+    recorded first, then two tokens drawn from the context and a seed.
+    Whatever the random-weight model emits, the context it lands on has
+    siblings to draft, so a branched pass is dispatched; a wrong draft is
+    only ever rejected, so the streams must still be the dense ones."""
+
+    def lookup(self, ctx):
+        rng = random.Random(hash((POOL_SEED,) + tuple(ctx)))
+        return super().lookup(ctx) + rng.sample(range(CFG.vocab_size), 2)
+
+
+class SeededTreeDrafter(TreeDrafter):
+    def new_state(self):
+        st = super().new_state()
+        st.pool = SeededPool(self.pool_g)
+        return st
+
+
+def test_tree_branched_pass_dispatches(monkeypatch):
+    """A draft with siblings goes to the device as a BRANCHED pass (the
+    tree op, counted in engine_spec_tree_passes_total), and rejected
+    branches leave the greedy streams byte-identical to dense. The draft
+    pool is seeded so that this holds whatever the weights emit (a
     suite-rot guard: every other test would pass vacuously if drafts
     always collapsed to chains)."""
+    from dynamo_tpu.engine import engine as engine_mod
 
     async def go():
-        _, stats = await run_workload(tree_args(8, width=2, depth=4))
+        dense, _ = await run_workload(tree_args(0))
+        monkeypatch.setattr(
+            engine_mod, "build_drafter",
+            lambda args: SeededTreeDrafter(args.spec_ngram, 2, 4),
+        )
+        spec, stats = await run_workload(tree_args(8, width=2, depth=4))
         assert stats["tree_passes"] > 0, "no branched pass ever dispatched"
+        assert _tokens_only(spec) == _tokens_only(dense)
+        assert stats["emitted"] == stats["rows"] + stats["accepted"]
 
     asyncio.run(go())
 

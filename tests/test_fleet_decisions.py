@@ -172,3 +172,34 @@ def test_router_ignores_cached_dead_worker():
     placement, _, scores, _, _ = r._place(tokens)
     assert placement.worker == 1
     assert 9 not in scores
+
+
+def test_mirror_is_a_bounded_lru_that_keeps_recent_hits_and_sweeps_by_worker():
+    """Ten times more conversations than ``max_entries``: the mirror never
+    grows past its cap, a conversation that keeps being looked up survives
+    the churn while cold ones are evicted, the per-worker index shrinks
+    with every eviction, and dropping a worker removes exactly its entries."""
+    async def go():
+        store = MemoryStore()
+        cap = 64
+        cache = await RouterDecisionCache(store, "f", max_entries=cap).start()
+        hot = [7, 8, 9]
+        cache.record("m", hot, worker=0xA)
+        for conv in range(10 * cap):
+            cache.record("m", [10_000 + conv, 20_000 + conv], worker=conv % 5)
+            assert len(cache._mirror) <= cap
+            if conv % 16 == 0:
+                assert cache.lookup("m", hot + [1234]) == (0xA, 3)
+        assert len(cache._mirror) == cap
+        assert cache.lookup("m", hot) == (0xA, 3)              # the recent hit stayed
+        assert cache.lookup("m", [10_000, 20_000]) is None       # the coldest went
+        newest = 10 * cap - 1
+        assert cache.lookup("m", [10_000 + newest, 20_000 + newest]) == (newest % 5, 2)
+        assert sum(len(v) for v in cache._by_worker.values()) == cap
+        mine = len(cache._by_worker[3])
+        cache.drop_worker(3)
+        assert len(cache._mirror) == cap - mine
+        assert all(w != 3 for w, _ in cache._mirror.values())
+        await cache.close()
+
+    asyncio.run(go())

@@ -10,6 +10,7 @@ import json
 
 import pytest
 
+from dynamo_tpu.engine.grammar import _ByteDfa, compile_response_format_regex
 from dynamo_tpu.kv_router.publisher import KvEventBroadcaster, serve_kv_endpoints
 from dynamo_tpu.llm.discovery import ModelManager, ModelWatcher
 from dynamo_tpu.llm.http_service import HttpService
@@ -24,6 +25,10 @@ from dynamo_tpu.runtime.push_router import RouterMode
 SCHEMA = {"type": "object", "properties": {
     "name": {"type": "string", "maxLength": 8},
     "ok": {"type": "boolean"},
+}}
+FORCED_SCHEMA = {"type": "object", "properties": {
+    "kind": {"const": "record"},
+    "ok": {"const": True},
 }}
 RESPONSE_FORMAT = {"type": "json_schema",
                    "json_schema": {"name": "extract", "schema": SCHEMA}}
@@ -89,14 +94,36 @@ def test_chat_response_format_returns_schema_valid_json():
                 assert choice["finish_reason"] == "stop"
                 _assert_schema_valid(choice["message"]["content"])
 
-                # json_object mode: any parseable JSON object
+                # a schema of constants leaves the model no choice: the
+                # whole completion is forced, whatever the weights emit
+                forced = await client.chat(
+                    [{"role": "user", "content": "say it"}],
+                    max_tokens=64, temperature=0.0, seed=4,
+                    response_format={"type": "json_schema", "json_schema": {
+                        "name": "forced", "schema": FORCED_SCHEMA}},
+                )
+                assert forced["choices"][0]["finish_reason"] == "stop"
+                assert json.loads(forced["choices"][0]["message"]["content"]) == {
+                    "kind": "record", "ok": True}
+
+                # json_object mode: strings have no length bound there, so
+                # a random-weight model may ramble to max_tokens. What the
+                # mask guarantees is that every served byte keeps the text
+                # a prefix of a JSON object, and a finished one parses.
                 resp2 = await client.chat(
                     [{"role": "user", "content": "give me json"}],
                     max_tokens=200, temperature=0.0, seed=1,
                     response_format={"type": "json_object"},
                 )
-                obj = json.loads(resp2["choices"][0]["message"]["content"])
-                assert isinstance(obj, dict)
+                choice2 = resp2["choices"][0]
+                text2 = choice2["message"]["content"]
+                dfa = _ByteDfa(compile_response_format_regex({"type": "json_object"}))
+                state = dfa.walk(dfa.start, text2.encode())
+                assert state is not None, f"not a prefix of a JSON object: {text2!r}"
+                assert choice2["finish_reason"] in ("stop", "length")
+                if choice2["finish_reason"] == "stop":
+                    assert dfa.accepting(state)
+                    assert isinstance(json.loads(text2), dict)
 
                 # streaming path: concatenated deltas are schema-valid too
                 parts = []

@@ -858,3 +858,59 @@ def test_fleet_stitched_trace_for_remote_prefill_plus_live_migration(fresh_recor
             await w2.stop()
 
     asyncio.run(asyncio.wait_for(go(), timeout=300))
+
+
+# -- /debug/slo: burn state + attribution over the ledger ----------------------
+
+
+def test_attribution_summary_shares_and_ttft_tail():
+    from dynamo_tpu.runtime.slo import attribution_summary
+
+    recs = [
+        {"ttft_s": 0.1 * (i + 1), "phases": {"prefill": 0.3, "decode": 0.1,
+                                              "route": None}}
+        for i in range(10)
+    ] + [{"phases": {"decode": 1.0}}, "junk"]
+    out = attribution_summary(recs)
+    assert out["schema"] == 2 and out["requests"] == 11   # non-dicts dropped
+    assert set(out["phases"]) == {"prefill", "decode"}     # a None phase is no phase
+    assert out["phases"]["prefill"] == {"total_s": 3.0, "mean_s": 0.3, "share": 0.6}
+    assert out["phases"]["decode"] == {"total_s": 2.0, "mean_s": round(2 / 11, 6),
+                                        "share": 0.4}
+    assert out["ttft"] == {"mean_s": 0.55, "p99_s": 1.0}
+    empty = attribution_summary([])
+    assert empty["requests"] == 0 and empty["phases"] == {}
+    assert empty["ttft"] == {"mean_s": None, "p99_s": None}
+
+
+def test_debug_slo_attributes_the_recent_ledger_to_phases(fresh_recorder):
+    async def go():
+        url = "memory://obs-slo"
+        wrt, _engine = await start_worker(url)
+        frt, manager, watcher, http = await start_frontend(url)
+        base = f"http://127.0.0.1:{http.port}"
+        try:
+            async with httpx.AsyncClient(timeout=30) as client:
+                await wait_model(client, base)
+                for i in range(6):
+                    r = await client.post(f"{base}/v1/chat/completions",
+                                          json=body(f"slo {i}", stream=i % 2 == 0))
+                    assert r.status_code == 200
+                slo = (await client.get(f"{base}/debug/slo")).json()
+            assert slo["schema"] == 2
+            assert sum(c.get("observed", 0) for c in slo["classes"].values()) == 6
+            att = slo["attribution"]
+            assert att["schema"] == 2 and att["requests"] == 6
+            assert att["phases"], "no phase reached the ledger"
+            assert abs(sum(p["share"] for p in att["phases"].values()) - 1.0) < 0.01
+            for p in att["phases"].values():
+                assert p["total_s"] >= p["mean_s"] >= 0.0
+            assert 0.0 < att["ttft"]["mean_s"] <= att["ttft"]["p99_s"]
+        finally:
+            await http.close()
+            await watcher.close()
+            await manager.close()
+            await frt.shutdown()
+            await wrt.shutdown()
+
+    asyncio.run(go())

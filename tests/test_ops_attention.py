@@ -1,7 +1,8 @@
 """Pallas paged-attention kernel vs the XLA gather reference.
 
 The kernel runs in interpreter mode on CPU (tests cannot assume a real
-TPU); the compiled path is exercised by bench.py / tools on hardware.
+TPU); the compiled path is lowered for v5e in test_ops_tpu_lowering.py
+and held against this reference on the chip by chip_smoke.py's kernel phase.
 Reference parity target: vLLM's paged-attention kernels vs its reference
 torch implementation (the reference delegates both to vLLM; SURVEY §2.4).
 """

@@ -9,6 +9,7 @@ import json
 import time
 
 import httpx
+import pytest
 
 from dynamo_tpu.kv_router.publisher import KvEventBroadcaster, serve_kv_endpoints
 from dynamo_tpu.llm.discovery import ModelManager, ModelWatcher
@@ -280,6 +281,60 @@ def test_responses_and_completions_carry_qos_fields():
                     "model": "mock-model", "input": "hi", "priority": "p9",
                 })
                 assert r.status_code == 400
+        finally:
+            await http.close()
+            await watcher.close()
+            await manager.close()
+            await frt.shutdown()
+            await wrt.shutdown()
+
+    asyncio.run(go())
+
+
+@pytest.mark.parametrize("streams", [16, 128])
+def test_concurrent_sse_streams_deliver_every_token_and_count_it(streams):
+    """N concurrent chat streams through one in-process frontend against
+    the mocker (window-burst deltas): none fails, each ends with a finish
+    chunk and ``[DONE]``, the content tokens delivered equal streams x
+    gen_len, and ``http_output_tokens_total`` counted exactly those."""
+    gen_len = 24
+
+    async def go():
+        url = f"memory://fe-streams-{streams}"
+        wrt = await start_worker(url, max_num_seqs=256, num_kv_blocks=4096,
+                                 delta_tokens=4)
+        frt, manager, watcher, http = await start_frontend(url)
+        base = f"http://127.0.0.1:{http.port}"
+        try:
+            limits = httpx.Limits(max_connections=streams)
+            async with httpx.AsyncClient(timeout=120, limits=limits) as client:
+                async def one(i):
+                    chars, finish, done = 0, None, False
+                    async with client.stream(
+                        "POST", f"{base}/v1/chat/completions",
+                        json=chat_body(stream=True, ignore_eos=True,
+                                       max_tokens=gen_len, seed=i),
+                    ) as resp:
+                        assert resp.status_code == 200
+                        async for line in resp.aiter_lines():
+                            if line == "data: [DONE]":
+                                done = True
+                            elif line.startswith("data: "):
+                                choice = json.loads(line[6:])["choices"][0]
+                                chars += len(choice["delta"].get("content") or "")
+                                finish = choice.get("finish_reason") or finish
+                    return chars, finish, done
+
+                results = await asyncio.gather(*(one(i) for i in range(streams)))
+            assert all(done and finish == "length" for _c, finish, done in results)
+            # the mocker's tokens are single printable bytes
+            assert sum(c for c, _f, _d in results) == streams * gen_len
+            page = frt.metrics.render()
+            counted = sum(
+                float(l.rsplit(" ", 1)[1]) for l in page.splitlines()
+                if l.startswith("dynamo_tpu_http_output_tokens_total")
+            )
+            assert counted == streams * gen_len
         finally:
             await http.close()
             await watcher.close()

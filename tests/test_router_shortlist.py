@@ -5,7 +5,7 @@ Tentpole coverage for the cluster-scale placement hot path:
 - randomized fleets: pruned scheduling (index top-k shortlist +
   incremental load state) picks the same argmin-cost worker as the
   full scan at temperature → 0 whenever the shortlist covers every
-  holder (the recall guarantee documented in docs/performance.md);
+  holder (the recall guarantee of KvScheduler.schedule's docstring);
 - ``shortlist_k=0`` is byte-identical through ``_place`` — hashes,
   scores, and the chosen placement match a straight-line reference
   implementation of the legacy loop, rng stream included;
@@ -16,6 +16,8 @@ Tentpole coverage for the cluster-scale placement hot path:
 """
 
 import random
+
+import pytest
 
 from dynamo_tpu.kv_router.approx import ApproxKvIndexer
 from dynamo_tpu.kv_router.indexer import OverlapScores, RadixIndex, ShardedRadixIndex
@@ -348,3 +350,53 @@ def test_active_sequences_heap_survives_churn():
     got = a.least_loaded(1)
     assert loads[got[0]] == loads[want[0]]
     assert abs(a.roster_mean_load() - sum(loads.values()) / 50) < 1e-9
+
+
+# -- the placement series at fleet scale -------------------------------------
+
+
+@pytest.mark.parametrize("fleet", [64, 1000])
+def test_placement_series_count_the_pruning_at_fleet_scale(fleet):
+    """A seeded prefix-heavy trace through ``_place`` at 64 and 1,000
+    engines, pruned against the full scan on the same index and loads:
+    the same worker every time, ``router_candidates_considered`` grows by
+    the whole fleet a placement without pruning and by at most k + m with
+    it, ``router_place_seconds`` counts every placement, and no pruned
+    placement falls back to the full scan."""
+    from dynamo_tpu.kv_router.router import register_router_metrics
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+
+    rng = random.Random(fleet)
+    workers = list(range(1, fleet + 1))
+    idx = RadixIndex()
+    prefixes = []
+    for t in range(12):
+        tokens = [t * 10_000 + i for i in range(48)]  # 12 blocks at block_size 4
+        hashes = compute_block_hashes(tokens, 4)
+        for w in rng.sample(workers, rng.randint(1, 6)):
+            _store_chain(idx, w, hashes[: rng.randint(3, len(hashes))])
+        prefixes.append(tokens)
+    loads = rng.sample(range(0, 8 * fleet), fleet)
+    requests = [rng.choice(prefixes) + [rng.randrange(10**6) for _ in range(8)]
+                for _ in range(200)]
+
+    def run(k):
+        r = _stub_router(idx, workers, shortlist_k=k, seed=5)
+        reg = MetricsRegistry()
+        r._m = register_router_metrics(reg)
+        for w, load in zip(workers, loads):
+            r.active.add_request(f"r{w}", w, load, 0, 0)
+        placed = [r._place(tokens)[0] for tokens in requests]
+        assert (f"dynamo_tpu_router_place_seconds_count {len(requests)}"
+                in reg.render().splitlines())
+        return placed, r._m
+
+    full, full_m = run(0)
+    pruned, pruned_m = run(16)
+    assert [p.worker for p in pruned] == [p.worker for p in full]
+    assert any(p.overlap_blocks >= 3 for p in pruned)  # holders win some
+    assert all(p.full_scan for p in full) and not any(p.full_scan for p in pruned)
+    k_m = 16 + KvSchedulerConfig().least_loaded_m
+    assert full_m["candidates_considered"].value() == fleet * len(requests)
+    assert 0 < pruned_m["candidates_considered"].value() <= k_m * len(requests)
+    assert pruned_m["shortlist_fallback"].value() == 0

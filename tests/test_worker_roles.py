@@ -281,3 +281,114 @@ def test_replica_scale_down_retires_distinct_victims():
         await ort.shutdown()
 
     asyncio.run(go())
+
+
+def test_closed_loop_scales_up_then_moves_a_pool_under_streaming_traffic():
+    """The live observe → decide → actuate stack, clean (its chaos twin is
+    tests/test_autoscaler_chaos.py): the production SlaAutoscaler over a
+    real RuntimeActuator and in-process workers takes ONE replica
+    scale-up (an ITL breach) and then ONE pool move (a TTFT breach at a
+    full fleet) while clients stream throughout. Both actions end ``ok``
+    in the journal and in ``planner_scale_actions_total``, no stream
+    fails or comes up short, and teardown leaves no key behind."""
+    from dynamo_tpu.planner.actions import ActionJournal
+    from dynamo_tpu.planner.core import PlannerObservation
+    from dynamo_tpu.planner.operator import (
+        ControlLaw,
+        OperatorConfig,
+        SlaAutoscaler,
+        register_planner_metrics,
+    )
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+
+    async def go():
+        url = "memory://roles-closed-loop"
+        workers = [await make_worker(url, POOL_PREFILL, itl_ms=2.0),
+                   await make_worker(url, POOL_DECODE, itl_ms=2.0)]
+        ort = await DistributedRuntime.create(store_url=url)
+        admin = await (
+            ort.namespace(NS).component(ADMIN_COMPONENT)
+            .endpoint(ADMIN_ENDPOINT).router(RouterMode.DIRECT)
+        )
+        gen = await (
+            ort.namespace(NS).component("backend").endpoint("generate")
+            .router(RouterMode.ROUND_ROBIN)
+        )
+
+        class Launcher:
+            async def launch(self, pool: str) -> None:
+                workers.append(await make_worker(url, pool, itl_ms=2.0))
+
+        act = RuntimeActuator(ort.store, NS, admin, launcher=Launcher(),
+                              converge_timeout_s=15)
+        cfg = OperatorConfig(
+            itl_sla_ms=20.0, ttft_sla_ms=300.0, mean_input_tokens=64.0,
+            mean_output_tokens=16.0, predictor="constant", max_engines=3,
+            hysteresis_cycles=1, cooldown_s=0.0, replica_scaling=True,
+            decode_tok_s=100.0, prefill_tok_s=1000.0, interval_s=0.1,
+        )
+        observations = [
+            # decode breached, room to grow: scale decode 1 → 2
+            PlannerObservation(request_rate=2.0, output_token_rate=150.0,
+                               itl_ms=90.0, ttft_ms=20.0),
+            # fleet full, prefill breached with decode headroom: move one
+            PlannerObservation(request_rate=2.0, output_token_rate=20.0,
+                               input_token_rate=1500.0, itl_ms=5.0, ttft_ms=900.0),
+        ]
+
+        async def observe():
+            return observations.pop(0)
+
+        reg = MetricsRegistry()
+        metrics = register_planner_metrics(reg)
+        auto = SlaAutoscaler(
+            ControlLaw(cfg), observe, pool_actuator=act,
+            journal=ActionJournal(ort.store, "loop", await ort.primary_lease()),
+            metrics=metrics,
+        )
+
+        stop = asyncio.Event()
+        done, short = [], []
+
+        async def client(i):
+            n = 0
+            while not stop.is_set():
+                tokens = 0
+                async for frame in gen.generate(req_dict(1000 * i + n, max_tokens=12),
+                                                Context()):
+                    if isinstance(frame, dict):
+                        tokens += len(frame.get("token_ids") or ())
+                (done if tokens == 12 else short).append(tokens)
+                n += 1
+
+        clients = [asyncio.get_running_loop().create_task(client(i)) for i in range(4)]
+        await asyncio.sleep(0.1)
+        await auto.step()
+        pools = await act.pools()
+        assert (len(pools[POOL_PREFILL]), len(pools[POOL_DECODE])) == (1, 2)
+        await auto.step()
+        pools = await act.pools()
+        assert (len(pools[POOL_PREFILL]), len(pools[POOL_DECODE])) == (2, 1)
+        await asyncio.sleep(0.1)
+        stop.set()
+        await asyncio.gather(*clients)   # a failed stream raises here
+
+        assert short == [] and len(done) >= 4
+        assert metrics["actions"].value(kind="replica_scale", outcome="ok") == 1
+        assert metrics["actions"].value(kind="pool_move", outcome="ok") == 1
+        entries = await auto.journal.entries()
+        assert [(e["kind"], e["phase"]) for e in entries
+                if e["phase"] != "started"] == [("replica_scale", "ok"),
+                                                ("pool_move", "ok")]
+        page = reg.render()
+        assert "planner_pool_size" in page and "planner_decision_lag_seconds" in page
+
+        for rt, mgr in workers:
+            await mgr.close()
+            await rt.shutdown()
+        for prefix in ("autoscaler/", "models/", f"instances/{NS}/"):
+            left = [e.key for e in await ort.store.get_prefix(prefix)]
+            assert left == [], (prefix, left)
+        await ort.shutdown()
+
+    asyncio.run(go())

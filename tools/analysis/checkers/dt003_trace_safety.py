@@ -289,7 +289,7 @@ class TraceSafetyChecker(Checker):
         "tracer coercion / numpy-on-tracer / tracer branching / "
         "donated-buffer reuse in jit-reachable code"
     )
-    scope = ("dynamo_tpu", "benchmarks", "tools")
+    scope = ("dynamo_tpu", "tools")
 
     def run_repo(self, modules) -> Iterable[Finding]:
         indexes: dict[str, _ModuleIndex] = {}

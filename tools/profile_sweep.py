@@ -1,7 +1,7 @@
 """Profiler sweep: engine → (batch → ITL/tok_s, prompt_len → TTFT) npz
 for the SLA planner's interpolators.
 
-Reference analogue: benchmarks/profiler/profile_sla.py (TP×load sweeps →
+Reference analogue: the reference profiler's profile_sla.py (TP×load sweeps →
 npz read by perf_interpolation.py). Run on the serving chip:
 
   python tools/profile_sweep.py --model llama-1b --out profile_llama1b.npz
