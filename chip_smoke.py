@@ -22,10 +22,12 @@ time and this parent must never import JAX:
 1. probe    one child asks JAX what it sees; no TPU, no run.
 2. serve    store server + worker + frontend (+ metrics exporter), the
             README quick start as four processes; chats over HTTP.
-3. kernel   one child: the compiled Pallas kernel against the XLA
-            reference on the same chip, ragged lengths, four variants;
-            then the kernel alone at the benchmark cells' call shapes:
-            device time a call and its share of the chip's HBM peak.
+3. kernel   one child: the compiled Pallas kernels (decode, spec, tree,
+            prefill) against their XLA references on the same chip,
+            ragged lengths; then each kernel alone at the benchmark
+            cells' call shapes: device time a call, the decode kernel's
+            share of the chip's HBM peak, the prefill kernel's share of
+            its bf16 peak beside the XLA form's time.
 4. multichip  only when JAX reports four devices: the serve phase with
             ``--tp 4`` and a KV pool larger than one chip's HBM.
 
@@ -95,6 +97,22 @@ KERNEL_CALLS = {
                             rows=[(16, 150, 700)]),
     "longcat latent sessions": dict(B=128, W=128, bs=32, Dk=640, H=64, Dv=512, L=8, N=5632,
                                     rows=[(63, 1000, 2600)]),
+}
+
+# The prefill kernel's call shapes in the dense cells (one row a call: T new
+# positions from ``start``, the row ``length`` tokens long over a 256-page
+# table), at both geometries: a sessions turn behind 1.7k cached tokens, a
+# fresh chat prompt, and the two chunks of a 4k document.
+MXU_PEAK_FLOPS = 197e12  # TPU v5e, bf16 (Google Cloud documentation)
+PREFILL_GEOMETRIES = {
+    "qwen2.5-7b": dict(KVH=4, G=7, hd=128, bs=16, L=28, N=5120),
+    "mistral-7b": dict(KVH=8, G=4, hd=128, bs=16, L=32, N=2304),
+}
+PREFILL_CALLS = {
+    "[1,192,ctx 1.9k]": dict(T=192, start=1712, length=1904),
+    "[1,256,ctx 220]": dict(T=256, start=0, length=220),
+    "[1,2048,start 0]": dict(T=2048, start=0, length=2048),
+    "[1,2048,start 2048]": dict(T=2048, start=2048, length=4096),
 }
 
 # The kernel phase's bound on |kernel - reference|, fixed from bf16 before
@@ -485,6 +503,8 @@ def child_kernel() -> None:
     from dynamo_tpu.ops.paged_attention import (
         paged_decode_attention,
         paged_decode_attention_xla,
+        paged_prefill_attention,
+        paged_prefill_attention_xla,
         paged_spec_attention,
         paged_spec_attention_xla,
     )
@@ -544,6 +564,9 @@ def child_kernel() -> None:
             got = paged_spec_attention(q, k, v, layer, bt, ln2, ks, vs, anc, interpret=False)
             ref = paged_spec_attention_xla(q, k, v, layer, bt, ln2, ks, vs, anc=anc)
             live = lengths > 0
+        compare(name, got, ref, live)
+
+    def compare(name: str, got, ref, live) -> None:
         got = np.asarray(jax.block_until_ready(got), np.float32)
         ref = np.asarray(ref, np.float32)
         check(np.isfinite(got).all(), f"{name}: kernel output is not finite")
@@ -554,6 +577,29 @@ def child_kernel() -> None:
               f"{scale:.2f} (bound {BF16_TOLERANCE * scale:.5f})", flush=True)
         check(err <= BF16_TOLERANCE * scale, f"{name}: kernel and XLA reference disagree by {err}")
 
+    def run_prefill(name: str, cfg: ModelConfig) -> None:
+        """Chunks of 256 positions: a fresh prompt, one behind 1,000 cached
+        tokens that ends inside a page, one row inactive, one a full chunk
+        behind a prefix that is no multiple of the kernel's chunk."""
+        KVH, hd = cfg.num_kv_heads, cfg.head_dim
+        G, Tp = cfg.num_heads // KVH, 256
+        start = np.array([0, 992, 0, 400], np.int32)
+        tlen = np.array([220, 992 + 141, 0, 400 + 256], np.int32)
+        kq, kk, kv = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(7), len(results)), 3)
+        k = jax.random.normal(kk, (L, N, bs, KVH * hd), jnp.float32).astype(jnp.bfloat16)
+        v = jax.random.normal(kv, (L, N, bs, KVH * hd), jnp.float32).astype(jnp.bfloat16)
+        q = jax.random.normal(kq, (4, Tp, KVH, G, hd), jnp.float32).astype(jnp.bfloat16)
+        wide = rng.permutation(np.arange(1, N))[: 4 * 96].reshape(4, 96).astype(np.int32)
+        bt, layer = jnp.asarray(wide), jnp.int32(1)
+        # The chunk's own K and V as its pages hold them (kv_write precedes attn).
+        pos = start[:, None] + np.arange(Tp)[None]
+        blk = jnp.asarray(np.take_along_axis(wide, pos // bs, axis=1))
+        own = lambda pool: pool[1, blk, jnp.asarray(pos % bs)].reshape(4, Tp, KVH, hd)  # noqa: E731
+        got = paged_prefill_attention(q, k, v, layer, bt, jnp.asarray(start), jnp.asarray(tlen))
+        ref = paged_prefill_attention_xla(
+            q, own(k), own(v), k, v, layer, bt, jnp.asarray(start), jnp.asarray(tlen))
+        compare(name, got, ref, pos < tlen[:, None])
+
     smoke = ModelConfig.preset(MODEL)
     run("decode bf16", smoke, False, "decode")
     run("decode int8-KV", smoke, True, "decode")
@@ -561,6 +607,8 @@ def child_kernel() -> None:
     run("tree T=4", smoke, False, "tree")
     # head_dim 64 takes another in-kernel scale layout (llama-1b geometry).
     run("decode int8-KV head_dim=64", ModelConfig.preset("llama-1b"), True, "decode")
+    run_prefill("prefill G=7 KVH=4", smoke)
+    run_prefill("prefill G=4 KVH=8", ModelConfig.preset("llama-8b"))
 
     d = jax.devices()[0]
     stats = d.memory_stats() or {}
@@ -573,7 +621,94 @@ def child_kernel() -> None:
         resident = d.memory_stats()["bytes_in_use"] - before
         print(f"[kernel] int8-KV scale array {sc.shape} f32: logical "
               f"{sc.nbytes / 1e6:.1f} MB, resident {resident / 1e6:.1f} MB", flush=True)
-    print(json.dumps({"variants": results, "calls": kernel_times()}), flush=True)
+    print(json.dumps({"variants": results, "calls": kernel_times(),
+                      "prefill_calls": prefill_kernel_times()}), flush=True)
+
+
+def traced_ns(run, line_name: str, prefix: str) -> list[int]:
+    """Device durations (ns) of the events on ``line_name`` ("XLA Ops",
+    "XLA Modules") whose name starts with ``prefix``, in one profiled call
+    of ``run`` after one that compiles it."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(run())  # compiles
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        jax.block_until_ready(run())
+        jax.profiler.stop_trace()
+        data = ProfileData.from_file(
+            sorted(glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True))[-1])
+    return [ev.duration_ns for plane in data.planes if plane.name.startswith("/device:")
+            for line in plane.lines if line.name == line_name
+            for ev in line.events if ev.name.lstrip("%").startswith(prefix)]
+
+
+def prefill_kernel_times() -> dict:
+    """The prefill kernel alone at ``PREFILL_CALLS``, both geometries: a
+    jitted loop over the cell's layers, the kernel's device time a call,
+    the causal attention's own operations (4 x heads x head_dim for every
+    pair of a live query and a position it attends) over that time as a
+    share of the bf16 peak, and the XLA form's time a layer at the same
+    call (its whole jitted loop over the layers). Passes or fails nothing."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops.paged_attention import (
+        paged_prefill_attention,
+        paged_prefill_attention_xla,
+    )
+
+    out = {}
+    for geom, g in PREFILL_GEOMETRIES.items():
+        KVH, G, hd, bs, L, N = (g[k] for k in ("KVH", "G", "hd", "bs", "L", "N"))
+        kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(2), 4)
+        pools = (jax.random.normal(kk, (L, N, bs, KVH * hd), jnp.bfloat16),
+                 jax.random.normal(kv, (L, N, bs, KVH * hd), jnp.bfloat16))
+        for shape, c in PREFILL_CALLS.items():
+            T, start, length = c["T"], c["start"], c["length"]
+            rng = np.random.default_rng(0)
+            table = np.zeros((1, 256), np.int32)
+            table[0, : -(-length // bs)] = rng.permutation(np.arange(1, N))[: -(-length // bs)]
+            table = jnp.asarray(table)
+            q = jax.random.normal(kq, (1, T, KVH, G, hd), jnp.bfloat16)
+            own = jax.random.normal(ks, (2, 1, T, KVH, hd), jnp.bfloat16)
+            s0, n = jnp.full((1,), start, jnp.int32), jnp.full((1,), length, jnp.int32)
+
+            def kernel(i, q, k, v):
+                return paged_prefill_attention(q, k, v, i, table, s0, n)
+
+            def xla(i, q, k, v):
+                return paged_prefill_attention_xla(q, own[0], own[1], k, v, i, table, s0, n)
+
+            def layers(call):
+                @jax.jit
+                def run(q, k, v):
+                    def body(i, acc):
+                        return acc + call(i, q, k, v).astype(jnp.float32)
+                    return jax.lax.fori_loop(1, L, body, call(jnp.int32(0), q, k, v).astype(jnp.float32))
+                return lambda: run(q, *pools)
+
+            ns = traced_ns(layers(kernel), "XLA Ops", "paged_prefill_attention")
+            name = f"{geom} {shape}"
+            check(len(ns) == L, f"{name}: {len(ns)} events of paged_prefill_attention, not {L}")
+            us = sum(ns) / len(ns) / 1e3
+            xla_us = sum(traced_ns(layers(xla), "XLA Modules", "jit_run")) / L / 1e3
+            live = np.arange(start, min(start + T, length))
+            flops = 4.0 * KVH * G * hd * float((live + 1).sum())
+            share = flops / (us * 1e-6) / MXU_PEAK_FLOPS
+            out[name] = {"us_a_call": round(us, 1), "mxu_peak_share": round(share, 4),
+                         "xla_us_a_layer": round(xla_us, 1)}
+            print(f"[kernel] prefill {name}: {us:.1f} us a call, {flops / 1e9:.2f} GFLOP = "
+                  f"{100 * share:.1f}% of {MXU_PEAK_FLOPS / 1e12:.0f} TFLOP/s; the XLA form "
+                  f"{xla_us:.1f} us a layer", flush=True)
+        del pools
+    return out
 
 
 def kernel_times() -> dict:
@@ -582,14 +717,10 @@ def kernel_times() -> dict:
     trace, and the bytes it must read (live K and V once) over that time
     as a share of the HBM peak. A kernel-alone number without a served
     run; it passes or fails nothing."""
-    import glob
-    import tempfile
-
     import numpy as np
 
     import jax
     import jax.numpy as jnp
-    from jax.profiler import ProfileData
 
     from dynamo_tpu.ops.paged_attention import latent_decode_attention, paged_decode_attention
 
@@ -637,16 +768,7 @@ def kernel_times() -> dict:
                 return acc + call(i, q, *pools).astype(jnp.float32)
             return jax.lax.fori_loop(1, L, body, call(jnp.int32(0), q, *pools).astype(jnp.float32))
 
-        jax.block_until_ready(layers(q, *pools))  # compiles
-        with tempfile.TemporaryDirectory() as d:
-            jax.profiler.start_trace(d)
-            jax.block_until_ready(layers(q, *pools))
-            jax.profiler.stop_trace()
-            data = ProfileData.from_file(
-                sorted(glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True))[-1])
-        durations = [ev.duration_ns for plane in data.planes if plane.name.startswith("/device:")
-                     for line in plane.lines if line.name == "XLA Ops"
-                     for ev in line.events if ev.name.lstrip("%").startswith(kernel)]
+        durations = traced_ns(lambda: layers(q, *pools), "XLA Ops", kernel)
         check(len(durations) == L, f"{name}: {len(durations)} events of {kernel} in the trace, not {L}")
         us = sum(durations) / len(durations) / 1e3
         share = need / (us * 1e-6) / HBM_PEAK_BYTES_PER_S

@@ -609,8 +609,11 @@ class EngineArgs:
         (VERDICT r2 weak #3). Two buckets only: the Pallas decode kernel
         does work proportional to TRUE lengths (padded table width costs
         ~one skipped grid step per dead chunk), so a wide table is nearly
-        free on TPU; the small bucket keeps short-prompt prefill (XLA
-        gather path) and CPU tests cheap."""
+        free on TPU; the small bucket keeps short-prompt prefill on the
+        XLA gather path (CPU, mesh, int8 KV) and CPU tests cheap. The
+        prefill kernel walks true lengths too (PR 34), so on a TPU the
+        small bucket buys nothing any more but a second set of programs
+        (PERF.md section 7)."""
         small = min(8, self.blocks_per_seq)
         return tuple(dict.fromkeys((small, self.blocks_per_seq)))
 

@@ -553,6 +553,13 @@ def register_engine_metrics(registry) -> dict:
             "nothing past a stop, no finished, cancelled or preempted row)",
         ),
         registry.counter(
+            "engine_prefill_attn_dispatch_total",
+            "Prefill dispatches by attention path: pallas = the kernel that "
+            "attends out of the pages and walks only the context a chunk "
+            "can see, xla = the gather form over the table's whole width "
+            "(off the TPU, under a mesh, int8 KV pages, a refused geometry)",
+        ),
+        registry.counter(
             "kv_pool_hit_blocks_total",
             "Prompt blocks an admission found in the G1 prefix cache",
         ),
@@ -881,6 +888,8 @@ class TpuEngine:
              kind="dispatched")
         feed("engine_decode_row_steps_total", self.total_decode_rows_emitted,
              kind="emitted")
+        feed("engine_prefill_attn_dispatch_total", self._runner.prefill_dispatches,
+             path="xla" if self._runner.prefill_attn_impl == "xla" else "pallas")
         feed("kv_pool_hit_blocks_total", self.pool.hit_blocks)
         feed("kv_pool_miss_blocks_total", self.pool.miss_blocks)
         if self.moe_hist is not None:

@@ -9,10 +9,12 @@ vLLM's CUDA kernels; here it is jnp/lax built for XLA:TPU):
   fast compiles even at 80 layers; the KV cache rides the scan carry and
   is updated with ``dynamic_update_index_in_dim`` so XLA keeps it
   in-place (callers donate it).
-- Paged attention is gather-based: KV pages are indexed out of the cache
-  with a block table and attended densely with masking. This is the
-  canonical XLA-friendly formulation; a Pallas flash/paged kernel slots
-  in behind the same signature (ops/ upgrade path).
+- Paged attention has two forms behind one signature an op
+  (ops/paged_attention.py): gather-based (KV pages indexed out of the
+  cache with a block table and attended densely with masking: the
+  canonical XLA-friendly formulation, and the CPU and mesh path), and
+  Pallas kernels that walk a row's own pages (decode, spec-verify,
+  prefill), chosen once by the runner from what it can observe.
 - GQA via reshape (no repeat): q [*, KVH, G, hd] against k [*, KVH, hd].
 - bf16 weights/activations; norms, rope, softmax and logits in fp32.
 
@@ -342,10 +344,18 @@ def prefill_batch_impl(
     true_len: jax.Array,      # [Bp] int32 — true total length (0 = inactive row)
     lora: dict | None = None,         # adapter bank {qa..ob: [L, S, ...]}
     adapter_slots: jax.Array | None = None,  # [Bp] int32, -1 = base row
+    *,
+    attn_impl: str = "auto",  # static: "auto" | "xla" | "pallas" | "pallas_interpret"
 ) -> tuple[jax.Array, KVCache]:
     """Packed prefill: run Bp sequences' suffixes through the model in ONE
     dispatch, each attending to its own cached prefix pages. Returns
     last-token logits [Bp, V] and the updated cache.
+
+    Attention (ops/paged_attention.py): the suffix's K and V are written
+    to their pages first, and the Pallas kernel attends out of the pages,
+    walking only the context a chunk can see; the XLA form gathers the
+    table's whole width and is the CPU, mesh and int8-KV path
+    (``resolve_prefill_impl``).
 
     One-at-a-time prefill was the r3 TTFT killer (VERDICT r3 weak #2):
     each admission paid its own dispatch and ran tiny matmuls alone.
@@ -357,7 +367,6 @@ def prefill_batch_impl(
     present in the blocks named by ``block_tables`` (whole blocks only);
     suffix positions [start_pos, true_len) are computed here."""
     Bp, T = tokens.shape
-    W = block_tables.shape[1]
     bs = cache.k.shape[2]
     KVH, hd = cfg.num_kv_heads, cfg.head_dim
     sfx = jnp.arange(T, dtype=jnp.int32)
@@ -366,16 +375,6 @@ def prefill_batch_impl(
     compute_dtype = params["layers"]["attn_norm"].dtype
     with jax.named_scope("embed"):
         x = _embed_rows(params, tokens, compute_dtype)  # [Bp, T, D]
-
-    # Masks (fp32 additive), fixed for all layers.
-    neg = jnp.float32(-1e9)
-    # suffix→suffix causal, masked beyond each row's true length
-    causal = (sfx[None, :] <= sfx[:, None]).astype(jnp.float32)   # [T, T]
-    valid_sfx = (suffix_positions < true_len[:, None]).astype(jnp.float32)
-    mask_ss = (1.0 - causal[None] * valid_sfx[:, None, :]) * neg  # [Bp, T, T]
-    # suffix→prefix: every suffix token sees all of its row's prefix
-    ctx = jnp.arange(W * bs, dtype=jnp.int32)
-    mask_sp = jnp.where(ctx[None, :] < start_pos[:, None], 0.0, neg)  # [Bp, W*bs]
 
     # Suffix block scatter targets per row: suffix-local block j lands in
     # table slot start_pos//bs + j (start_pos is block-aligned).
@@ -390,10 +389,15 @@ def prefill_batch_impl(
     sfx_block_ids = jnp.where(blk_start < true_len[:, None], sfx_block_ids, 0)
     flat_ids = sfx_block_ids.reshape(Bp * nb)
 
-    scale = hd ** -0.5
     G = cfg.num_heads // KVH
 
-    from dynamo_tpu.ops.paged_attention import gather_dequant_pages
+    from dynamo_tpu.ops.paged_attention import (
+        paged_prefill_attention,
+        paged_prefill_attention_xla,
+        resolve_prefill_impl,
+    )
+
+    impl, _ = resolve_prefill_impl(attn_impl, cfg, bs, cache.k_scale is not None)
 
     def layer(carry, xs):
         x, k_cache, v_cache, k_scale, v_scale = carry
@@ -440,24 +444,17 @@ def prefill_batch_impl(
                 )
 
         with jax.named_scope("attn"):
-            # Prefix pages (gathered dense, dequantized for int8 storage) +
-            # suffix (already in registers).
-            pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, x.dtype)
-            pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, x.dtype)
-
             qg = q.reshape(Bp, T, KVH, G, hd)
-            # scores vs prefix pages / vs own suffix
-            s_p = jnp.einsum("btkgh,bckh->btkgc", qg, pk).astype(jnp.float32) * scale
-            s_s = jnp.einsum("btkgh,bskh->btkgs", qg, k).astype(jnp.float32) * scale
-            s_p = s_p + mask_sp[:, None, None, None, :]
-            s_s = s_s + mask_ss[:, :, None, None, :]
-            s = jnp.concatenate([s_p, s_s], axis=-1)
-            p = jax.nn.softmax(s, axis=-1).astype(x.dtype)
-            p_p, p_s = p[..., : W * bs], p[..., W * bs :]
-            o = (
-                jnp.einsum("btkgc,bckh->btkgh", p_p, pv)
-                + jnp.einsum("btkgs,bskh->btkgh", p_s, v)
-            )
+            if impl == "xla":
+                o = paged_prefill_attention_xla(
+                    qg, k, v, k_cache, v_cache, layer_idx, block_tables,
+                    start_pos, true_len, k_scale, v_scale,
+                )
+            else:
+                o = paged_prefill_attention(
+                    qg, k_cache, v_cache, layer_idx, block_tables, start_pos, true_len,
+                    interpret=(impl == "pallas_interpret"),
+                )
             o = o.reshape(Bp, T, cfg.q_size)
         with jax.named_scope("attn_out"):
             x = x + _wo_lora(o, lp, ll, adapter_slots)
@@ -492,6 +489,8 @@ def prefill_impl(
     true_len: jax.Array,     # scalar int32 — true total length (prefix + suffix)
     lora: dict | None = None,
     adapter_slot: jax.Array | None = None,  # scalar int32, -1 = base
+    *,
+    attn_impl: str = "auto",
 ) -> tuple[jax.Array, KVCache]:
     """Single-sequence prefill: the Bp=1 case of ``prefill_batch_impl``
     (kept as the chunked-prefill / compatibility entry point)."""
@@ -503,6 +502,7 @@ def prefill_impl(
         lora,
         None if adapter_slot is None
         else jnp.asarray(adapter_slot, jnp.int32).reshape(1),
+        attn_impl=attn_impl,
     )
     return logits[0], cache
 
@@ -1098,8 +1098,12 @@ def embed_impl(
 
 
 # Jitted entry points (static model config / step count, donated cache).
-prefill = functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))(prefill_impl)
-prefill_batch = functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))(prefill_batch_impl)
+prefill = functools.partial(
+    jax.jit, static_argnums=(0,), static_argnames=("attn_impl",), donate_argnums=(2,)
+)(prefill_impl)
+prefill_batch = functools.partial(
+    jax.jit, static_argnums=(0,), static_argnames=("attn_impl",), donate_argnums=(2,)
+)(prefill_batch_impl)
 decode_step = functools.partial(
     jax.jit, static_argnums=(0,), static_argnames=("attn_impl",), donate_argnums=(2,)
 )(decode_step_impl)

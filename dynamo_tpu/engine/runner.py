@@ -144,6 +144,11 @@ class LocalRunner:
         self.params = params
         self.cache: M.KVCache | None = None
         self.attn_impl = "xla"
+        self.prefill_attn_impl = "xla"
+        # What the block's prefill programs take beside their operands: the
+        # dense block's attention path (the latent block's has one).
+        self._prefill_kw: dict = {}
+        self.prefill_dispatches = 0  # engine_prefill_attn_dispatch_total
         self._rid = 0
         self._refs: OrderedDict[int, StepRef] = OrderedDict()
         # Per-SLOT latest sampled token [max_num_seqs + 1], kept on
@@ -177,6 +182,9 @@ class LocalRunner:
         sh = self.sharding
         # Before anything is allocated: a refused configuration fails fast.
         self.attn_impl, attn_note = self._resolve_attention()
+        self.prefill_attn_impl, prefill_note = self._resolve_prefill_attention(attn_note)
+        if self._block is M:
+            self._prefill_kw = {"attn_impl": self.prefill_attn_impl}
         dtype = jnp.dtype(self.args.dtype)
         # Seeded params and the KV pool are BORN sharded (jit with
         # out_shardings): a model or pool sized for the mesh never has to
@@ -232,7 +240,7 @@ class LocalRunner:
                     self.cfg, self.args.lora_slots, self.args.lora_rank
                 ).items()
             }
-        log.info("engine start: %s", self._start_line(attn_note))
+        log.info("engine start: %s", self._start_line(attn_note, prefill_note))
 
     def _resolve_attention(self) -> tuple[str, str]:
         """→ (decode attention path, why it is not the one asked for).
@@ -259,7 +267,21 @@ class LocalRunner:
             raise ValueError(f"attn_impl='pallas' cannot serve {self.cfg.name}: {limit}")
         return "xla", limit
 
-    def _start_line(self, attn_note: str) -> str:
+    def _resolve_prefill_attention(self, attn_note: str) -> tuple[str, str]:
+        """→ (prefill's attention path, why it is the XLA form), from what
+        decode resolved to (platform, mesh, geometry) and what prefill's own
+        kernel adds: bf16 pages."""
+        from dynamo_tpu.ops.paged_attention import resolve_prefill_impl
+
+        if self.cfg.block == "longcat":
+            return "xla", "latent block: attend_expanded"
+        if self.attn_impl == "xla":
+            return "xla", attn_note
+        return resolve_prefill_impl(
+            self.attn_impl, self.cfg, self.args.block_size, self.args.kv_quant == "int8"
+        )
+
+    def _start_line(self, attn_note: str, prefill_note: str = "") -> str:
         """One line naming what this engine really runs on: platform,
         device kind and count, dtype, and the attention path of each op.
         ``chip_smoke.py`` reads it, and so should anyone who suspects a
@@ -272,6 +294,7 @@ class LocalRunner:
             else [jax.devices()[0]]
         )
         decode = self.attn_impl + (f" ({attn_note})" if attn_note else "")
+        prefill = self.prefill_attn_impl + (f" ({prefill_note})" if prefill_note else "")
         if a.spec_tokens <= 0:
             spec = "off"
         else:
@@ -307,7 +330,7 @@ class LocalRunner:
             f"devices={len(devs)} of {jax.device_count()} "
             f"ids={','.join(str(d.id) for d in devs)} visible_chips={pinned} "
             f"dtype={a.dtype} quant={a.quant} kv_quant={a.kv_quant} "
-            f"attention: prefill=xla decode={decode} spec_verify={spec}{experts}{hbm}"
+            f"attention: prefill={prefill} decode={decode} spec_verify={spec}{experts}{hbm}"
         )
 
     def stop(self) -> None:
@@ -360,11 +383,13 @@ class LocalRunner:
     def prefill_batch(self, toks, tables, starts, tlens, adapter_slots=None,
                       *, rid=None) -> StepRef:
         bank, slots = self._lora_operands(adapter_slots)
+        self.prefill_dispatches += 1
         logits, self.cache, hist = self._prefill_batch(
             self.cfg, self.params, self.cache,
             jnp.asarray(toks), jnp.asarray(tables),
             jnp.asarray(starts), jnp.asarray(tlens),
             bank, slots,
+            **self._prefill_kw,
         )
         return self._new_ref((logits,), rid, hist, prefill=True)
 
@@ -373,11 +398,13 @@ class LocalRunner:
         bank = slot = None
         if adapter_slot is not None and adapter_slot >= 0:
             bank, slot = self.lora_bank, jnp.int32(adapter_slot)
+        self.prefill_dispatches += 1
         logits, self.cache, hist = self._prefill(
             self.cfg, self.params, self.cache,
             jnp.asarray(toks), jnp.asarray(table),
             jnp.int32(pos), jnp.int32(tlen),
             bank, slot,
+            **self._prefill_kw,
         )
         return self._new_ref((logits,), rid, hist, prefill=True)
 

@@ -1,4 +1,4 @@
-"""Paged decode attention: Pallas TPU kernel + XLA reference.
+"""Paged attention, decode and prefill: Pallas TPU kernels + XLA references.
 
 The role vLLM's paged-attention CUDA kernels play for the reference
 (reference: components/backends/vllm/src/dynamo/vllm/main.py:90 delegates
@@ -70,6 +70,12 @@ phase):
 - int8 pages keep a chunk axis on the grid around the same chunk body:
   their scales ride as per-chunk BlockSpec blocks (see
   ``_paged_attention_mq``).
+- Prefill (``paged_prefill_attention``, PR 34) is what lies past the 128
+  query columns of the kernel above: the query axis tiled, a grid over
+  (row, query tile), the same page walk inside, and a KV head's G query
+  heads stacked under one another as one left operand. It is bound by the
+  MXU and the softmax's vector work, not by descriptors, so each grid step
+  fetches its own first chunk and nothing is prefetched across steps.
 """
 
 from __future__ import annotations
@@ -262,6 +268,77 @@ def paged_spec_attention_xla(
 # ---------------------------------------------------------------------------
 
 
+def _page_fetch(layer, tables_ref, pools, bufs, sem, P: int, unroll: bool = True):
+    """→ (issue, wait): the page DMAs a kernel walks a row's table with.
+    ``pools`` are the stacked pools in HBM ``[L, N, bs, lanes]``, ``bufs``
+    their double buffers ``[2, P, bs, lanes]``, ``sem`` DMA semaphores
+    ``[2 slots, a pool]``. A chunk is P consecutive entries of a row's table.
+    ``unroll`` False keeps every start and wait in a loop: some 7 ns a
+    descriptor slower (PERF.md, PR 31), which only a kernel bound by its
+    descriptors feels, and a kernel's text without 2P starts a call site."""
+
+    def page_copies(page, slot, p):
+        """The DMA descriptors of pool page ``page`` into place p of buffer
+        ``slot``: one a pool, all of a pool's on one semaphore."""
+        return [
+            pltpu.make_async_copy(pool.at[layer, page], buf.at[slot, p], sem.at[slot, i])
+            for i, (pool, buf) in enumerate(zip(pools, bufs))
+        ]
+
+    def issue(row, chunk, slot, npages=None):
+        """Start the copies of ``npages`` pages of (row, chunk) into buffer
+        ``slot``. A chunk that holds P pages (``npages`` None: known to
+        when traced) starts them in one straight line, with no counter or
+        branch between the descriptors; a short one starts what it holds
+        in a loop."""
+        def start(p, carry=0):
+            for dma in page_copies(tables_ref[row, chunk * P + p], slot, p):
+                dma.start()
+            return carry
+
+        def unrolled():
+            for p in range(P):
+                start(p)
+
+        def rolled():
+            lax.fori_loop(0, npages, start, 0)
+
+        if npages is None:
+            unrolled()
+        elif not unroll:
+            rolled()
+        else:
+            pl.when(npages == P)(unrolled)
+            pl.when(npages < P)(rolled)
+
+    def wait(slot, npages=None):
+        """Wait for the copies ``issue`` started. A DMA semaphore counts
+        bytes: a full chunk (``npages`` None: known to be) is one wait a
+        pool for all P pages' bytes, a short one a page-sized wait a page."""
+        def whole():
+            for i, (pool, buf) in enumerate(zip(pools, bufs)):
+                pltpu.make_async_copy(
+                    pool.at[layer, pl.ds(0, P)], buf.at[slot], sem.at[slot, i]).wait()
+
+        def paged():
+            def one(p, carry):
+                for dma in page_copies(0, slot, 0):  # any page: its bytes count
+                    dma.wait()
+                return carry
+
+            lax.fori_loop(0, npages, one, 0)
+
+        if npages is None:
+            whole()
+        elif not unroll:
+            paged()
+        else:
+            pl.when(npages == P)(whole)
+            pl.when(npages < P)(paged)
+
+    return issue, wait
+
+
 def _mq_kernel(
     # scalar prefetch
     layer_ref,    # [1] int32
@@ -331,65 +408,8 @@ def _mq_kernel(
         rem = rowlen_ref[row] - chunk * CH
         return jnp.minimum(lax.div(rem + bs - 1, bs), P)
 
-    def page_copies(page, slot, p):
-        """The DMA descriptors of pool page ``page`` into place p of buffer
-        ``slot``: one a pool, all of a pool's on one semaphore."""
-        out = [pltpu.make_async_copy(
-            k_hbm.at[layer, page], kbuf.at[slot, p], sem.at[slot, 0])]
-        if v_hbm is not None:
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[layer, page], vbuf.at[slot, p], sem.at[slot, 1]))
-        return out
-
-    def issue(row, chunk, slot, full=False):
-        """Start the page copies of (row, chunk) into buffer ``slot``. A
-        chunk that holds P pages (``full``: known to when traced) starts
-        them in one straight line, with no counter or branch between the
-        descriptors; a row's last chunk starts what it holds in a loop."""
-        def start(p, carry=0):
-            for dma in page_copies(tables_ref[row, chunk * P + p], slot, p):
-                dma.start()
-            return carry
-
-        def unrolled():
-            for p in range(P):
-                start(p)
-
-        def rolled(npages):
-            lax.fori_loop(0, npages, start, 0)
-
-        if full:
-            unrolled()
-        else:
-            npages = chunk_pages(row, chunk)
-            pl.when(npages == P)(unrolled)
-            pl.when(npages < P)(functools.partial(rolled, npages))
-
-    def wait(npages, slot):
-        """Wait for the copies ``issue`` started. A DMA semaphore counts
-        bytes: a full chunk (``npages`` None: known to be) is one wait a
-        pool for all P pages' bytes, a row's last chunk one page-sized
-        wait a page."""
-        def whole():
-            pltpu.make_async_copy(
-                k_hbm.at[layer, pl.ds(0, P)], kbuf.at[slot], sem.at[slot, 0]).wait()
-            if v_hbm is not None:
-                pltpu.make_async_copy(
-                    v_hbm.at[layer, pl.ds(0, P)], vbuf.at[slot], sem.at[slot, 1]).wait()
-
-        def paged():
-            def one(p, carry):
-                for dma in page_copies(0, slot, 0):  # any page: its bytes count
-                    dma.wait()
-                return carry
-
-            lax.fori_loop(0, npages, one, 0)
-
-        if npages is None:
-            whole()
-        else:
-            pl.when(npages == P)(whole)
-            pl.when(npages < P)(paged)
+    pools, bufs = ([k_hbm], [kbuf]) if v_hbm is None else ([k_hbm, v_hbm], [kbuf, vbuf])
+    issue, wait = _page_fetch(layer, tables_ref, pools, bufs, sem, P)
 
     def successor(row, ch):
         """The live chunk after (row, ch) in the walk; row B: none."""
@@ -400,7 +420,7 @@ def _mq_kernel(
         # Global warmup: the very first live row has no predecessor.
         @pl.when(started_ref[0] == 0)
         def _():
-            issue(b, 0, slot_ref[0])
+            issue(b, 0, slot_ref[0], chunk_pages(b, 0))
             started_ref[0] = 1
 
         acc_scr[...] = jnp.zeros_like(acc_scr)
@@ -480,8 +500,8 @@ def _mq_kernel(
         def steady():
             # A full chunk whose successor is a full chunk of the same row:
             # 2P starts, two waits and the compute in one straight line.
-            issue(b, c + 1, nxt, full=True)
-            wait(None, cur)
+            issue(b, c + 1, nxt)
+            wait(cur)
             return attend(c, cur, m_prev, l_prev)
 
         def edge():
@@ -489,10 +509,12 @@ def _mq_kernel(
             # chunk 0 of the next non-empty row, so the fetch overlaps
             # compute across rows too.
             row, ch = successor(b, c)
-            pl.when(row < B)(functools.partial(issue, row, ch, nxt))
+            @pl.when(row < B)
+            def _():
+                issue(row, ch, nxt, chunk_pages(row, ch))
 
             npages = chunk_pages(b, c)
-            wait(npages, cur)
+            wait(cur, npages)
             # Unfetched tail pages hold garbage (possibly NaN): k is
             # neutralized by the score mask, v must be zero (0*NaN=NaN).
             # Only a row's last chunk has a tail: clear it in the buffer,
@@ -777,6 +799,333 @@ def paged_spec_attention(
         q, k_cache, v_cache, layer_idx, block_tables, lengths,
         k_scale, v_scale, pages_per_chunk, interpret, anc,
     )
+
+
+# ---------------------------------------------------------------------------
+# Prefill: a chunk of T new positions a row, behind whatever the row's pages
+# already hold. The chunk's own K and V are in their pages before attention
+# runs (model.prefill_batch_impl: ``kv_write`` precedes ``attn``), so the
+# kernel attends entirely out of the pages, causal by absolute position.
+# ---------------------------------------------------------------------------
+
+
+def paged_prefill_attention_xla(
+    q: jax.Array,            # [B, T, KVH, G, hd] — positions start_pos .. start_pos+T
+    k: jax.Array,            # [B, T, KVH, hd] — the chunk's own keys, as computed
+    v: jax.Array,
+    k_cache: jax.Array,      # [L, N, bs, KVH*hd]
+    v_cache: jax.Array,
+    layer_idx: jax.Array,    # scalar int32
+    block_tables: jax.Array, # [B, W] int32
+    start_pos: jax.Array,    # [B] int32 — first position of the chunk (block-aligned)
+    true_len: jax.Array,     # [B] int32 — the row's true total length (0: inactive)
+    k_scale: jax.Array | None = None,  # [L, N, bs, KVH] fp32 — int8 cache only
+    v_scale: jax.Array | None = None,
+) -> jax.Array:
+    """The gather-based form: the table's whole width gathered dense as the
+    prefix (masked past ``start_pos``), the chunk attending its own K and V
+    as computed (with int8 pages only later readers see the rounding), one
+    softmax over prefix and chunk together. Its scores are float32
+    ``[B, T, KVH, G, W*bs + T]`` whatever the prefix is: the CPU, mesh and
+    int8-KV path. Returns [B, T, KVH, G, hd] in q.dtype."""
+    B, T, KVH, G, hd = q.shape
+    W, bs = block_tables.shape[1], k_cache.shape[2]
+    pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, q.dtype)
+    pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
+    # Masks (fp32 additive). chunk→chunk: causal, and nothing past the row's
+    # true length; chunk→prefix: every query sees all of its row's prefix.
+    neg = jnp.float32(-1e9)
+    sfx = jnp.arange(T, dtype=jnp.int32)
+    causal = (sfx[None, :] <= sfx[:, None]).astype(jnp.float32)   # [T, T]
+    valid = (start_pos[:, None] + sfx[None, :] < true_len[:, None]).astype(jnp.float32)
+    mask_ss = (1.0 - causal[None] * valid[:, None, :]) * neg      # [B, T, T]
+    ctx = jnp.arange(W * bs, dtype=jnp.int32)
+    mask_sp = jnp.where(ctx[None, :] < start_pos[:, None], 0.0, neg)  # [B, W*bs]
+    scale = hd ** -0.5
+    s_p = jnp.einsum("btkgh,bckh->btkgc", q, pk).astype(jnp.float32) * scale
+    s_s = jnp.einsum("btkgh,bskh->btkgs", q, k).astype(jnp.float32) * scale
+    s_p = s_p + mask_sp[:, None, None, None, :]
+    s_s = s_s + mask_ss[:, :, None, None, :]
+    p = jax.nn.softmax(jnp.concatenate([s_p, s_s], axis=-1), axis=-1).astype(q.dtype)
+    p_p, p_s = p[..., : W * bs], p[..., W * bs :]
+    return (
+        jnp.einsum("btkgc,bckh->btkgh", p_p, pv)
+        + jnp.einsum("btkgs,bskh->btkgh", p_s, v)
+    )
+
+
+def resolve_prefill_impl(requested: str, cfg, block_size: int, int8_pages: bool) -> tuple[str, str]:
+    """→ (prefill's attention path, why it is the XLA form where the kernel
+    was asked for). ``requested`` is an ``attn_impl``; the XLA form serves
+    int8 KV pages (there the chunk attends its exact values and only later
+    readers see the rounding: attending out of int8 pages would be another
+    result) and what the compiler refuses: ``kernel_unsupported``'s limits,
+    the page DMAs being the decode kernel's (head sizes 64, 128 and 256 and
+    pages of 4 to 64 tokens compiled for v5e, PR 34)."""
+    impl = resolve_attn_impl(requested)
+    if impl == "xla":
+        return impl, ""
+    if int8_pages:
+        return "xla", "int8 KV pages: the chunk attends its exact values"
+    limit = kernel_unsupported(cfg, block_size) if impl == "pallas" else None
+    return ("xla", limit) if limit else (impl, "")
+
+
+# The prefill kernel's sizes, from the chip (PERF.md section 6, PR 34). Most
+# query positions a tile holds: 128 (64 and 256 are both a sixth slower at
+# T 2,048). Tokens a chunk: what a chunk costs beside its products is fixed
+# a chunk and head (the two lane reductions a row of the softmax, the
+# accumulator's rescale), so 1,024 tokens run 1.4-1.7 times faster than 512
+# at long context; a prompt of a few hundred tokens pays about 10 us a call
+# for the one wide chunk. And the VMEM its scores, statistics and buffers
+# may take (Mosaic's default is 16 MiB of the 128 a v5e core has).
+_PREFILL_TILE = 128
+_PREFILL_CHUNK_TOKENS = 1024
+_PREFILL_VMEM_BYTES = 64 << 20
+_TABLE_WIDTH = 256
+
+
+def _prefill_tile(T: int) -> int:
+    """The query tile: the largest divisor of T that is a multiple of 16
+    (a packed bf16 sublane tile) and no more than ``_PREFILL_TILE``; T
+    itself where it has none."""
+    fits = [t for t in range(16, min(T, _PREFILL_TILE) + 1, 16) if T % t == 0]
+    return fits[-1] if fits else T
+
+
+def _prefill_kernel(
+    # scalar prefetch
+    layer_ref,    # [1] int32
+    start_ref,    # [B] int32 — the chunk's first position
+    len_ref,      # [B] int32 — the row's true length
+    tables_ref,   # [B, W] int32
+    # operands
+    q_ref,        # VMEM [1, tq, H*hd] — a tile of queries, softmax scale folded in
+    k_hbm,        # ANY  [L, N, bs, KVH*hd]
+    v_hbm,
+    o_ref,        # VMEM [1, tq, H*hd]
+    # scratch
+    kbuf,         # VMEM [2, P, bs, KVH*hd] — pages as they land
+    vbuf,
+    kh_scr,       # VMEM [KVH, CH, hd] — the chunk in hand, a head at a time
+    vh_scr,
+    qs_scr,       # VMEM [KVH, G*tq, hd] — the tile regrouped: a KV head's G query
+                  # heads stacked under one another (row g*tq + t)
+    acc_scr,      # VMEM [KVH, G*tq, hd] f32
+    m_scr,        # VMEM [KVH, G*tq, 1] f32 — running max
+    l_scr,        # VMEM [KVH, G*tq, 1] f32 — running sum
+    hz_scr,       # VMEM [G*tq, 1] int32 — each query row attends [0, horizon)
+    slot_ref,     # SMEM [1] int32
+    sem,          # DMA semaphores [2 slots, k | v]
+    *,
+    pages_per_chunk: int,
+):
+    """One grid step a (row, query tile). The tile's G*tq query rows of a KV
+    head are ONE left operand, so a page's lanes of that head are read once
+    for the group; the walk is the decode kernel's (chunks of P pages,
+    double-buffered) and stops at the last position the tile can see:
+    ``min(true_len, q0 + tq)``. Chunks wholly at or before the tile's first
+    position take no mask; the ones on the diagonal (and a row's last) build
+    one from positions. A tile past the row's true length does nothing.
+
+    Every prefill program of the (T, W) lattice traces and compiles one of
+    these, so its text is kept short (PERF.md section 6, PR 34). The heads
+    are a LOOP: a landed chunk is first copied a head apart (a leading
+    index can be dynamic, a lane offset cannot); unrolled over the heads
+    the kernel ran 5-7% faster at T 2,048 and took three to four times as
+    long to compile. The page DMAs are loops too: 2P unrolled starts a call
+    site ran 4% faster and cost 1-2 s of Python tracing a program, 19 s of
+    a warm start. The G*tq rows stay ONE operand: in blocks of tq rows the
+    kernel is 1.6 times slower."""
+    P = pages_per_chunk
+    b, j = pl.program_id(0), pl.program_id(1)
+    tq = q_ref.shape[1]
+    bs = kbuf.shape[2]
+    KVH, R, hd = qs_scr.shape
+    G, CH = R // tq, P * bs
+    layer = layer_ref[0]
+    q0 = start_ref[b] + j * tq               # the tile's first position
+    true_len = len_ref[b]
+    bound = jnp.minimum(true_len, q0 + tq)   # the tile sees context [0, bound)
+    nchunks = jnp.where(q0 < true_len, lax.div(bound + CH - 1, CH), 0)
+    # Chunks every query of the tile sees whole: all of it at or before q0.
+    nplain = jnp.minimum(lax.div(q0 + 1, CH), nchunks)
+
+    issue, wait = _page_fetch(
+        layer, tables_ref, [k_hbm, v_hbm], [kbuf, vbuf], sem, P, unroll=False)
+
+    def chunk_pages(c):
+        return jnp.minimum(lax.div(bound - c * CH + bs - 1, bs), P)
+
+    def head_lanes(h):
+        return pl.ds(h * hd, hd)
+
+    @pl.when(nchunks == 0)
+    def _():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(nchunks > 0)
+    def _():
+        slot_ref[0] = 0
+        issue(b, 0, 0, chunk_pages(0))
+        for k in range(KVH):
+            group = q_ref[0, :, pl.ds(k * G * hd, G * hd)]     # [tq, G*hd]
+            qs_scr[k] = jnp.concatenate(
+                [group[:, g * hd:(g + 1) * hd] for g in range(G)], axis=0)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        # Row r = g*tq + t is query position q0 + t.
+        t = lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+        for _ in range(1, G):
+            t = jnp.where(t >= tq, t - tq, t)
+        hz_scr[...] = jnp.minimum(q0 + t + 1, true_len)
+
+        def attend(c, masked: bool):
+            """The chunk in hand into every head's online softmax."""
+            if masked:
+                # Pages past the walk's end were not fetched and hold
+                # garbage (possibly NaN): k is neutralized by the score
+                # mask, v must be zero (0*NaN=NaN).
+                held = c * CH + lax.broadcasted_iota(jnp.int32, (1, CH, 1), 1) < bound
+                vh_scr[...] = jnp.where(held, vh_scr[...], jnp.zeros_like(vh_scr))
+
+            def head(k, carry):
+                s = lax.dot_general(
+                    qs_scr[k], kh_scr[k], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )                                              # [R, CH]
+                if masked:
+                    pos = c * CH + lax.broadcasted_iota(jnp.int32, (1, CH), 1)
+                    s = jnp.where(pos < hz_scr[...], s, NEG_INF)
+                m_prev = m_scr[k]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)                 # [R, 1]
+                p = jnp.exp(s - m_new)                         # [R, CH]
+                l_scr[k] = corr * l_scr[k] + jnp.sum(p, axis=1, keepdims=True)
+                pv = lax.dot_general(
+                    p.astype(vh_scr.dtype), vh_scr[k], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )                                              # [R, hd]
+                acc_scr[k] = acc_scr[k] * corr + pv
+                m_scr[k] = m_new
+                return carry
+
+            return lax.fori_loop(0, KVH, head, 0)
+
+        def chunk(c, carry):
+            # Software pipeline: the next chunk's pages are started before
+            # this one's are waited for.
+            cur = slot_ref[0]
+            nxt = 1 - cur
+            slot_ref[0] = nxt
+
+            @pl.when(c + 1 < nchunks)
+            def _():
+                issue(b, c + 1, nxt, chunk_pages(c + 1))
+
+            wait(cur, chunk_pages(c))
+            for k in range(KVH):
+                kh_scr[k] = kbuf[cur, :, :, head_lanes(k)].reshape(CH, hd)
+                vh_scr[k] = vbuf[cur, :, :, head_lanes(k)].reshape(CH, hd)
+            return lax.cond(c < nplain, lambda: attend(c, False), lambda: attend(c, True))
+
+        # The bound is read before the loop, so interpret mode can discharge
+        # it (see _mq_kernel).
+        lax.fori_loop(0, nchunks, chunk, 0)
+        for k in range(KVH):
+            o = (acc_scr[k] / jnp.maximum(l_scr[k], 1e-30)).astype(o_ref.dtype)
+            o_ref[0, :, pl.ds(k * G * hd, G * hd)] = jnp.concatenate(
+                [o[g * tq:(g + 1) * tq] for g in range(G)], axis=1)
+
+
+def paged_prefill_attention(
+    q: jax.Array,            # [B, T, KVH, G, hd] — positions start_pos .. start_pos+T
+    k_cache: jax.Array,      # [L, N, bs, KVH*hd] — the chunk's K and V already written
+    v_cache: jax.Array,
+    layer_idx: jax.Array,    # scalar int32
+    block_tables: jax.Array, # [B, W] int32
+    start_pos: jax.Array,    # [B] int32
+    true_len: jax.Array,     # [B] int32 — 0: an inactive row
+    *,
+    pages_per_chunk: int = 0,  # 0 → _PREFILL_CHUNK_TOKENS a chunk
+    q_tile: int = 0,           # 0 → _prefill_tile(T)
+    interpret: bool = False,
+) -> jax.Array:
+    """Prefill attention out of the pages: query ``t`` of row ``b`` sits at
+    position ``start_pos[b] + t`` and attends ``[0, min(true_len[b],
+    start_pos[b] + t + 1))``. Work follows the context a tile can see: the
+    table's padded width costs nothing, a row with ``true_len`` 0 nothing,
+    and no score tensor leaves VMEM. bf16 operands, float32 scores, maximum,
+    sum and accumulator, ``p`` in the pages' dtype for ``p v``: the XLA
+    form's precisions. Returns [B, T, KVH, G, hd] in q.dtype; rows of
+    queries at or past ``true_len`` are unspecified (the caller drops them)."""
+    B, T, KVH, G, hd = q.shape
+    bs = k_cache.shape[2]
+    assert k_cache.shape[3] == KVH * hd, "cache must be [L, N, bs, KVH*hd]"
+    tq = q_tile or _prefill_tile(T)
+    assert T % tq == 0, (T, tq)
+    P = pages_per_chunk or min(max(_PREFILL_CHUNK_TOKENS // bs, 1), _MAX_PAGES_PER_CHUNK)
+    P = min(P, k_cache.shape[1])
+    # The walk stops at a row's true length, so the table's width is nothing
+    # to the kernel but a shape: padded here, ahead of the jitted call, to
+    # whole ``_TABLE_WIDTH``s, the narrow and the wide table bucket trace one
+    # kernel between them (a trace is 0.3 s of a worker's start, warm or cold).
+    W = block_tables.shape[1]
+    Wp = -(-W // _TABLE_WIDTH) * _TABLE_WIDTH
+    Wp = -(-Wp // P) * P  # whole chunks, as the decode kernel's table
+    if Wp != W:
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, Wp - W)))
+    return _paged_prefill_call(
+        q, k_cache, v_cache, layer_idx, block_tables, start_pos, true_len,
+        P=P, tq=tq, interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("P", "tq", "interpret"))
+def _paged_prefill_call(q, k_cache, v_cache, layer_idx, block_tables, start_pos, true_len,
+                        *, P: int, tq: int, interpret: bool):
+    B, T, KVH, G, hd = q.shape
+    bs = k_cache.shape[2]
+    R, H = G * tq, KVH * G
+
+    tile = pl.BlockSpec((1, tq, H * hd), lambda b, j, *_: (b, j, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, T // tq),
+        in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile,
+        scratch_shapes=[
+            pltpu.VMEM((2, P, bs, KVH * hd), k_cache.dtype),
+            pltpu.VMEM((2, P, bs, KVH * hd), v_cache.dtype),
+            pltpu.VMEM((KVH, P * bs, hd), k_cache.dtype),
+            pltpu.VMEM((KVH, P * bs, hd), v_cache.dtype),
+            pltpu.VMEM((KVH, R, hd), q.dtype),
+            pltpu.VMEM((KVH, R, hd), jnp.float32),
+            pltpu.VMEM((KVH, R, 1), jnp.float32),
+            pltpu.VMEM((KVH, R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    o = pl.pallas_call(
+        functools.partial(_prefill_kernel, pages_per_chunk=P),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, T, H * hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=interpret,
+        name="paged_prefill_attention",
+    )(
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        jnp.asarray(start_pos, jnp.int32),
+        jnp.asarray(true_len, jnp.int32),
+        jnp.asarray(block_tables, jnp.int32),
+        (q * hd ** -0.5).reshape(B, T, H * hd),
+        k_cache,
+        v_cache,
+    )
+    return o.reshape(B, T, KVH, G, hd)
 
 
 # ---------------------------------------------------------------------------

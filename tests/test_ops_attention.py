@@ -22,9 +22,12 @@ from dynamo_tpu.ops.paged_attention import (
     latent_decode_attention_xla,
     paged_decode_attention,
     paged_decode_attention_xla,
+    paged_prefill_attention,
+    paged_prefill_attention_xla,
     paged_spec_attention,
     paged_spec_attention_xla,
     resolve_attn_impl,
+    resolve_prefill_impl,
 )
 
 
@@ -499,3 +502,175 @@ def test_resolve_attn_impl():
     assert resolve_attn_impl("pallas") == "pallas"
     # On the CPU test backend, auto → xla.
     assert resolve_attn_impl("auto") == "xla"
+
+
+# ---------------------------------------------------------------------------
+# Prefill: the kernel that attends out of the pages against the XLA form
+# ``prefill_batch_impl`` keeps (gather of the table's whole width + the
+# chunk's own K and V as computed + one softmax over both).
+# ---------------------------------------------------------------------------
+
+# name -> geometry (KVH, G, hd, bs), T, table width, (start_pos, true_len) a
+# row, and the kernel's chunk and tile where the case wants several of them.
+PREFILL_CASES = {
+    # a fresh prompt: every prefix column of the XLA form is dead
+    "prefix_0": dict(geom=(4, 7, 32, 16), T=64, W=8, rows=[(0, 64)]),
+    # the sessions cell's turn: ~130 new tokens behind a block-aligned 1.7k
+    "prefix_1712_block_aligned": dict(geom=(4, 7, 32, 16), T=128, W=128, rows=[(1712, 1840)]),
+    # a second chunk of a long prompt (start 2,048, T 2,048, cut to what the
+    # CPU bears): four query tiles over sixteen chunks, most of them plain
+    "second_chunk": dict(geom=(4, 7, 32, 16), T=256, W=32, rows=[(256, 512)], P=2, tq=64),
+    # the row ends inside a page, and inside the tile
+    "true_len_inside_a_page": dict(geom=(2, 2, 32, 16), T=64, W=8, rows=[(32, 32 + 21)]),
+    # inactive rows beside live ones
+    "inactive_row_Bp2": dict(geom=(2, 2, 32, 16), T=32, W=8, rows=[(0, 0), (48, 75)]),
+    "inactive_rows_Bp4": dict(geom=(2, 2, 32, 16), T=32, W=8,
+                              rows=[(16, 40), (0, 0), (0, 32), (0, 0)], P=1, tq=16),
+    # the benchmark's two geometries at their head size
+    "qwen_G7_KVH4_bs16": dict(geom=(4, 7, 128, 16), T=32, W=8, rows=[(64, 90)]),
+    "mistral_G4_KVH8_bs16": dict(geom=(8, 4, 128, 16), T=32, W=8, rows=[(64, 96), (0, 17)]),
+    # the served dtype: bf16 operands and p, float32 statistics
+    "bf16": dict(geom=(4, 7, 32, 16), T=64, W=16, rows=[(128, 180)], dtype=jnp.bfloat16, tol=2e-2),
+}
+
+
+def _prefill_case(geom, T, W, rows, dtype=jnp.float32, **_):
+    """Random pages and queries for ``rows`` → (q, the chunk's own k and v
+    as the pages hold them, caches, layer, tables, start_pos, true_len)."""
+    KVH, G, hd, bs = geom
+    rng = np.random.default_rng(11)
+    B, L = len(rows), 2
+    N = B * W + 1
+    k_cache = jnp.asarray(rng.standard_normal((L, N, bs, KVH * hd)), dtype)
+    v_cache = jnp.asarray(rng.standard_normal((L, N, bs, KVH * hd)), dtype)
+    # Each row owns W distinct blocks; block 0 stays the garbage sink.
+    tables = rng.permutation(np.arange(1, N))[: B * W].reshape(B, W).astype(np.int32)
+    q = jnp.asarray(rng.standard_normal((B, T, KVH, G, hd)), dtype)
+    start = np.asarray([r[0] for r in rows], np.int32)
+    tlen = np.asarray([r[1] for r in rows], np.int32)
+    layer = 1
+    pos = start[:, None] + np.arange(T)[None]
+    wide = np.concatenate([tables, np.zeros((B, T // bs + 1), np.int32)], axis=1)
+    blk = np.take_along_axis(wide, pos // bs, axis=1)
+    k = np.asarray(k_cache)[layer, blk, pos % bs].reshape(B, T, KVH, hd)
+    v = np.asarray(v_cache)[layer, blk, pos % bs].reshape(B, T, KVH, hd)
+    return (q, jnp.asarray(k), jnp.asarray(v), k_cache, v_cache, jnp.int32(layer),
+            jnp.asarray(tables), jnp.asarray(start), jnp.asarray(tlen))
+
+
+@pytest.mark.parametrize("name", list(PREFILL_CASES))
+def test_prefill_kernel_matches_the_xla_form(name):
+    case = PREFILL_CASES[name]
+    q, k, v, k_cache, v_cache, layer, tables, start, tlen = _prefill_case(**case)
+    ref = paged_prefill_attention_xla(q, k, v, k_cache, v_cache, layer, tables, start, tlen)
+    out = paged_prefill_attention(
+        q, k_cache, v_cache, layer, tables, start, tlen,
+        pages_per_chunk=case.get("P", 0), q_tile=case.get("tq", 0), interpret=True,
+    )
+    assert out.dtype == q.dtype and out.shape == q.shape
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    assert np.isfinite(out).all()
+    T = q.shape[1]
+    # Queries at or past a row's true length are padding: the caller drops them.
+    live = np.asarray(start)[:, None] + np.arange(T)[None] < np.asarray(tlen)[:, None]
+    tol = case.get("tol", 2e-5)
+    np.testing.assert_allclose(ref[live], out[live], atol=tol, rtol=tol)
+    # An inactive row does nothing and comes out as zeros.
+    assert not out[np.asarray(tlen) == 0].any()
+
+
+def test_prefill_batch_pallas_matches_xla():
+    """The whole packed prefill (scatter + attention + mlp + logits), a
+    cached prefix behind one row, a fresh prompt, and an inactive row."""
+    cfg = ModelConfig()  # test-tiny
+    rng = np.random.default_rng(5)
+    params = M.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    N, bs, Bp, T, W = 40, 4, 3, 16, 12
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size - 1, (Bp, T)), jnp.int32)
+    tables = jnp.asarray(rng.permutation(np.arange(1, N))[: Bp * W].reshape(Bp, W), jnp.int32)
+    start = jnp.asarray([24, 0, 0], jnp.int32)
+    tlen = jnp.asarray([24 + 13, 16, 0], jnp.int32)
+    cache = M.init_kv_cache(cfg, N, bs, jnp.float32)
+    cache = M.KVCache(
+        jnp.asarray(rng.standard_normal(cache.k.shape), jnp.float32),
+        jnp.asarray(rng.standard_normal(cache.v.shape), jnp.float32),
+    )
+    ref_logits, ref_cache = M.prefill_batch_impl(
+        cfg, params, cache, tokens, tables, start, tlen, attn_impl="xla")
+    out_logits, out_cache = M.prefill_batch_impl(
+        cfg, params, cache, tokens, tables, start, tlen, attn_impl="pallas_interpret")
+    np.testing.assert_allclose(
+        np.asarray(ref_logits)[:2], np.asarray(out_logits)[:2], atol=1e-4, rtol=1e-4)
+    for got, want in ((out_cache.k, ref_cache.k), (out_cache.v, ref_cache.v)):
+        np.testing.assert_allclose(np.asarray(want)[:, 1:], np.asarray(got)[:, 1:], atol=1e-4)
+
+
+def test_a_prompt_served_through_the_prefill_kernel_emits_the_xla_paths_tokens():
+    """Engine level, test-tiny: packed prefill, a chunked long prompt and a
+    second turn behind a cached prefix, greedy; the start line and the
+    dispatch counter say which path ran."""
+    import asyncio
+
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.engine.engine import TpuEngine
+    from dynamo_tpu.llm.protocols import PreprocessedRequest
+    from dynamo_tpu.runtime.engine import Context
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+
+    rng = np.random.default_rng(3)
+    first = [int(t) for t in rng.integers(1, 500, 21)]
+    prompts = [first, [int(t) for t in rng.integers(1, 500, 90)], [7, 8, 9]]
+
+    async def run(attn_impl: str):
+        args = EngineArgs(
+            model=ModelConfig(), block_size=4, num_kv_blocks=96, max_num_seqs=4,
+            max_model_len=160, max_prefill_tokens=32, dtype="float32", attn_impl=attn_impl,
+        )
+        engine = TpuEngine(args)
+        reg = MetricsRegistry()
+        engine.bind_metrics(reg)
+        await engine.start()
+
+        async def one(prompt):
+            req = PreprocessedRequest(model="t", token_ids=list(prompt))
+            req.sampling.temperature = 0.0
+            req.sampling.seed = 0
+            req.stop.max_tokens = 6
+            outs = [o async for o in engine.generate(req, Context())]
+            return [t for o in outs for t in o.get("token_ids", [])]
+
+        try:
+            toks = list(await asyncio.gather(*(one(p) for p in prompts)))
+            # a second turn: the first prompt and its answer are cached blocks
+            toks.append(await one(first + toks[0] + [11, 12, 13, 14, 15]))
+            await engine.run_on_engine_thread(lambda: None)
+            await engine.run_on_engine_thread(lambda: None)
+            line = engine._runner._start_line("")
+        finally:
+            await engine.stop()
+        return toks, line, reg.render()
+
+    want, xla_line, xla_page = asyncio.run(run("xla"))
+    got, line, page = asyncio.run(run("pallas_interpret"))
+    assert all(len(t) == 6 for t in want)
+    assert got == want
+    assert "prefill=pallas_interpret decode=pallas_interpret" in line
+    assert "prefill=xla decode=xla" in xla_line
+    assert 'engine_prefill_attn_dispatch_total{path="pallas"}' in page
+    assert 'engine_prefill_attn_dispatch_total{path="xla"}' not in page
+    assert 'engine_prefill_attn_dispatch_total{path="xla"}' in xla_page
+
+
+def test_resolve_prefill_impl():
+    """The XLA form is reached by what the code observes alone: platform
+    (through ``resolve_attn_impl``), int8 KV pages, a refused geometry."""
+    qwen, tiny = ModelConfig.preset("qwen2-7b"), ModelConfig()
+    assert resolve_prefill_impl("auto", qwen, 16, False) == ("xla", "")  # the CPU backend
+    assert resolve_prefill_impl("pallas", qwen, 16, False) == ("pallas", "")
+    impl, why = resolve_prefill_impl("pallas", qwen, 16, True)
+    assert impl == "xla" and "int8" in why
+    impl, why = resolve_prefill_impl("pallas", tiny, 16, False)
+    assert impl == "xla" and "128" in why
+    assert resolve_prefill_impl("pallas", ModelConfig.preset("llama-1b"), 16, False) == ("pallas", "")
+    # interpret mode checks none of the compiler's rules
+    assert resolve_prefill_impl("pallas_interpret", tiny, 4, False) == ("pallas_interpret", "")

@@ -178,6 +178,9 @@ def test_kernel_unsupported_agrees_with_the_compiler(v5e):
     fn, args = _kernel_case(tiny, "decode", ctx=512, sharding=v5e)
     with pytest.raises(Exception, match="aligned to tiling"):
         fn.lower(*args).compile()
+    fn, args = _prefill_kernel_case(tiny, 32, W=32, sharding=v5e)  # the same page DMAs
+    with pytest.raises(Exception, match="aligned to tiling"):
+        fn.lower(*args).compile()
     for preset in GEOMETRIES + ("llama-1b", "llama-70b"):
         assert kernel_unsupported(ModelConfig.preset(preset), BS) is None
 
@@ -259,6 +262,80 @@ def test_prefill_forms_no_layer_of_the_pool_on_v5e(v5e, kv_quant):
         if shape in layer or (shape in pool and not entry and opcode not in ("scatter", "fusion")):
             found.append(line.strip()[:120])
     assert not found, "\n".join(found)
+
+
+def _prefill_kernel_case(cfg: ModelConfig, T: int, *, Bp: int = 1, W: int = 256, sharding=None):
+    from dynamo_tpu.ops.paged_attention import paged_prefill_attention
+
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    KVH, hd = cfg.num_kv_heads, cfg.head_dim
+    pages = S((2, 512, BS, KVH * hd), jnp.bfloat16)
+    return jax.jit(paged_prefill_attention), (
+        S((Bp, T, KVH, cfg.num_heads // KVH, hd), jnp.bfloat16), pages, pages,
+        S((), jnp.int32), S((Bp, W), jnp.int32), S((Bp,), jnp.int32), S((Bp,), jnp.int32))
+
+
+@pytest.mark.parametrize("T", [48, 192, 1024])
+@pytest.mark.parametrize("preset", GEOMETRIES)
+def test_prefill_kernel_compiles_for_v5e(v5e, preset, T):
+    """One kernel body for both dense geometries, the query tile from T
+    (48, 96 and 128 here): a packed wave of short prompts, one row of a
+    long one. The dense cells' whole "fine" ladder (32 .. 2,048) compiled
+    in PR 34's scratch run; the prefill program below holds 256 and 2,048."""
+    fn, args = _prefill_kernel_case(
+        ModelConfig.preset(preset), T, Bp=4 if T <= 256 else 1, sharding=v5e)
+    fn.lower(*args).compile()
+
+
+def test_prefill_kernel_walks_tiles_not_the_table():
+    """The structural pin: the grid is rows x query tiles, whatever the
+    table's width, and neither product takes a transposed left operand."""
+    cfg = ModelConfig.preset("qwen2-7b")
+    calls = []
+    for W in (8, 256):
+        fn, args = _prefill_kernel_case(cfg, 256, Bp=2, W=W)
+        (call,) = _eqns(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call")
+        assert tuple(call.params["grid_mapping"].grid) == (2, 2)
+        calls.append(call)
+    # Both table buckets call ONE kernel: the table is padded ahead of the
+    # jitted call, so a worker traces it once a T and not once a (T, W).
+    assert str(calls[0].params["jaxpr"]) == str(calls[1].params["jaxpr"])
+    assert [v.aval for v in calls[0].invars] == [v.aval for v in calls[1].invars]
+    dots = _eqns(call.params["jaxpr"], "dot_general")
+    # q k^T and p v, once for plain and once for masked chunks: the heads
+    # are a loop (unrolled, the kernel's text took four times as long to compile)
+    assert len(dots) == 4
+    for eqn in dots:
+        (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+        assert tuple(lhs_contract) == (eqn.invars[0].aval.ndim - 1,), eqn
+
+
+@pytest.mark.parametrize("T", [256, 2048])
+def test_prefill_attends_out_of_the_pages_on_v5e(v5e, T):
+    """The prefill program at the sessions cell's widths (qwen2-7b int8,
+    5,120 blocks, a 256-page table), one row of a 256-token turn and of a
+    2,048-token chunk: the kernel is in it, nothing in it has the table's
+    ``W*bs`` rows (the two gathers) or ``W*bs + T`` columns (the float32
+    scores and their softmax), and what the program adds to its arguments
+    stays under 100 MB (the XLA form: 77 MB and 2.1 GB of temporaries,
+    the scores 125 MB and 1.4 GB of them)."""
+    cfg = ModelConfig.preset("qwen2-7b")
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    N, W = 5120, 256
+    cache = jax.tree.map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(lambda: M.init_kv_cache(cfg, N, BS)))
+    i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
+    compiled = M.prefill_batch.lower(
+        cfg, _int8_params(cfg, S), cache, i32(1, T), i32(1, W), i32(1), i32(1),
+        attn_impl="pallas",
+    ).compile()
+    hlo = compiled.as_text()
+    assert "paged_prefill_attention" in hlo
+    assert "paged_decode_attention" not in hlo
+    wide = [ln.strip()[:120] for ln in hlo.splitlines()
+            if re.search(rf"[\[,]({W * BS}|{W * BS + T})[\],]", ln)]
+    assert not wide, "\n".join(wide[:10])
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 
 # -- the LongCat block: latent pages and the grouped expert product ---------------
