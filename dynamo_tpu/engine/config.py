@@ -15,9 +15,15 @@ from dataclasses import dataclass, field
 
 @dataclass(frozen=True)
 class ModelConfig:
-    """Architecture hyperparameters of the two kinds of block the engine
+    """Architecture hyperparameters of the three kinds of block the engine
     runs. ``block="llama"`` (default): GQA attention then one FFN (dense
-    SwiGLU, or ``_moe`` when ``num_experts``). ``block="longcat"``
+    SwiGLU, or ``_moe`` when ``num_experts``). ``block="lfm2"`` (LFM2-MoE,
+    engine/lfm2.py): a per-layer operator list (``layer_types``: a gated
+    short convolution or GQA attention with a norm on every query and key
+    head) and a per-layer feed-forward (dense for the first
+    ``num_dense_layers``, routed experts after); the cache holds K and V
+    pages for the attention layers and, beside them under the same block
+    ids, the convolution layers' last inputs. ``block="longcat"``
     (LongCat-Flash, engine/longcat.py): per layer two latent-attention
     (MLA) sub-blocks and two dense FFNs, with one shortcut-connected
     expert block that reads the first sub-block's normed stream and is
@@ -70,6 +76,21 @@ class ModelConfig:
     routed_scaling_factor: float = 1.0
     # The published vocabulary beside the rows held here (``vocab_size``).
     published_vocab_size: int = 0
+    # The router's arithmetic (engine/longcat.py:route). "softmax": choice on
+    # ``p + bias``, weights ``scaling * p``. "sigmoid": ``s = sigmoid(logits)``,
+    # choice on ``s + bias`` where ``use_expert_bias``, weights ``s`` there,
+    # divided by their sum where ``norm_topk_prob``, times the scaling.
+    router_scoring: str = "softmax"
+    use_expert_bias: bool = False
+    norm_topk_prob: bool = False
+    # -- block="lfm2" ------------------------------------------------------
+    # Named as published (lfm2_moe): each layer's operator ("conv" or
+    # "full_attention"), how many leading layers carry a dense feed-forward,
+    # and the taps of the causal depthwise convolution. A sequence's state in
+    # a conv layer is that layer's last ``conv_L_cache - 1`` inputs.
+    layer_types: tuple[str, ...] = ()
+    num_dense_layers: int = 0
+    conv_L_cache: int = 3
 
     @property
     def q_size(self) -> int:
@@ -92,7 +113,28 @@ class ModelConfig:
 
     @property
     def cache_layers(self) -> int:
+        if self.block == "lfm2":
+            return len(self.attn_layers)
         return 2 * self.num_layers if self.block == "longcat" else self.num_layers
+
+    @property
+    def attn_layers(self) -> tuple[int, ...]:
+        """The layers of a ``block="lfm2"`` model that hold K and V pages."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "full_attention")
+
+    @property
+    def conv_layers(self) -> tuple[int, ...]:
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "conv")
+
+    @property
+    def expert_layers(self) -> tuple[int, ...]:
+        """The layers whose feed-forward is routed experts (lfm2: all past
+        the leading dense ones)."""
+        return tuple(range(self.num_dense_layers, self.num_layers))
+
+    @property
+    def conv_state_slots(self) -> int:
+        return self.conv_L_cache - 1
 
     @property
     def router_width(self) -> int:
@@ -114,8 +156,26 @@ class ModelConfig:
             + experts * 3 * d * ie
         )
 
+    def _lfm2_params(self, experts_counted: float) -> int:
+        """Embedding (tied), operators, norms, dense feed-forwards, routers
+        and ``experts_counted`` experts an expert layer."""
+        d, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        ie, E = self.moe_intermediate_size or i, self.num_experts
+        conv = 3 * d * d + d * d + d * self.conv_L_cache
+        attn = d * self.q_size + 2 * d * self.kv_size + self.q_size * d + 2 * self.head_dim
+        n_moe = len(self.expert_layers)
+        head = 0 if self.tie_embeddings else d * v
+        return int(
+            v * d + d + head + 2 * d * self.num_layers
+            + len(self.conv_layers) * conv + len(self.attn_layers) * attn
+            + self.num_dense_layers * 3 * d * i
+            + n_moe * (d * E + E + experts_counted * 3 * d * ie)
+        )
+
     def param_count(self) -> int:
         d, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        if self.block == "lfm2":
+            return self._lfm2_params(self.num_experts)
         if self.block == "longcat":
             head = 0 if self.tie_embeddings else d * v
             return (v * d + d + head
@@ -140,6 +200,8 @@ class ModelConfig:
         if not self.num_experts:
             return self.param_count()
         d, v = self.hidden_size, self.vocab_size
+        if self.block == "lfm2":
+            return self._lfm2_params(self.num_experts_per_token)
         if self.block == "longcat":
             # Of a token's top-k, the share that lands on experts held here
             # (zero-compute and absent ones touch no weights).
@@ -219,6 +281,19 @@ class ModelConfig:
                 num_experts=2, num_routed_experts=8, expert_offset=2,
                 zero_expert_num=4, num_experts_per_token=3,
                 moe_intermediate_size=64, routed_scaling_factor=6.0,
+            ),
+            # LFM2-MoE block at toy widths (CPU tests): the pattern's first 6
+            # layers (conv, conv, attention, conv, conv, conv), 1 dense layer,
+            # 8 experts with 2 a token.
+            "lfm2-tiny": ModelConfig(
+                name="lfm2-tiny", block="lfm2", vocab_size=512, hidden_size=128,
+                intermediate_size=256, num_layers=6, num_heads=4, num_kv_heads=2,
+                head_dim=32, rope_theta=1000000.0, tie_embeddings=True,
+                layer_types=("conv", "conv", "full_attention", "conv", "conv", "conv"),
+                num_dense_layers=1, conv_L_cache=3, num_experts=8,
+                num_routed_experts=8, num_experts_per_token=2,
+                moe_intermediate_size=64, routed_scaling_factor=1.0,
+                router_scoring="sigmoid", use_expert_bias=True, norm_topk_prob=True,
             ),
             # Llama-3-70B-class (BASELINE.md north-star target, multi-host)
             "llama-70b": ModelConfig(
@@ -539,6 +614,31 @@ class EngineArgs:
                     f"pages, shortcut-connected expert layer), which cannot "
                     f"run with: {'; '.join(refused)}"
                 )
+        if self.model.block == "lfm2":
+            m = self.model
+            if len(m.layer_types) != m.num_layers or set(m.layer_types) - {"conv", "full_attention"}:
+                raise ValueError(
+                    f"model {m.name!r}: layer_types must name 'conv' or 'full_attention' "
+                    f"for each of its {m.num_layers} layers; got {m.layer_types!r}")
+            refused = [
+                what for on, what in (
+                    (self.kv_quant != "none", "--kv-quant int8 (no int8 form of the conv-state pool)"),
+                    (self.quant != "none", "--quant int8 (engine/quant.py)"),
+                    (self.spec_tokens > 0, "speculation (--spec-tokens; a rejected draft cannot roll the conv state back)"),
+                    (self.lora_slots > 0, "LoRA banks (--lora-slots)"),
+                    (self.tp > 1, "--tp (the conv-state pool and the grouped expert product are single-device)"),
+                    (bool(self.host_kv_blocks or self.disk_kv_dir or self.fleet_kv_dir),
+                     "KV tiers (--host-kv-blocks, --disk-kv-dir, --fleet-kv-dir)"),
+                    (self.block_size % m.conv_state_slots != 0,
+                     f"--block-size {self.block_size} (a block ends on a whole turn of the "
+                     f"{m.conv_state_slots} conv-state slots)"),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    f"model {m.name!r} has block='lfm2' (conv state beside K and V "
+                    f"pages, routed experts), which cannot run with: {'; '.join(refused)}"
+                )
         if self.max_model_len % self.block_size:
             self.max_model_len = ((self.max_model_len // self.block_size) + 1) * self.block_size
         if self.max_prefill_tokens % self.block_size:
@@ -704,6 +804,8 @@ class EngineArgs:
             # One pool, 2L cache layers, the row padded to whole lane tiles.
             itemsize = 2 if self.dtype == "bfloat16" else 4
             return m.cache_layers * self.block_size * m.latent_page_width * itemsize
+        if m.block == "lfm2":
+            return sum(self.pool_bytes_per_block().values())
         elems = self.block_size * m.num_kv_heads * m.head_dim
         if self.kv_quant == "int8":
             # int8 page + fp32 scale per (position, kv head).
@@ -712,6 +814,19 @@ class EngineArgs:
             itemsize = 2 if self.dtype == "bfloat16" else 4
             per_layer = elems * itemsize
         return 2 * m.num_layers * per_layer
+
+    def pool_bytes_per_block(self) -> dict[str, int]:
+        """What a block costs in each pool it has a page in, by kind: "kv"
+        alone, and for a ``block="lfm2"`` model the K and V of its attention
+        layers under "kv" and its conv layers' state under "conv"."""
+        m = self.model
+        if m.block != "lfm2":
+            return {"kv": self.kv_bytes_per_block()}
+        itemsize = 2 if self.dtype == "bfloat16" else 4
+        return {
+            "kv": 2 * len(m.attn_layers) * self.block_size * m.kv_size * itemsize,
+            "conv": len(m.conv_layers) * m.conv_state_slots * m.hidden_size * itemsize,
+        }
 
     def replace(self, **kw) -> "EngineArgs":
         return dataclasses.replace(self, **kw)

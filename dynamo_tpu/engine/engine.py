@@ -64,7 +64,7 @@ from dynamo_tpu.engine.grammar import (
     mask_words,
     pack_token_ids,
 )
-from dynamo_tpu.engine.model import refuse_block
+from dynamo_tpu.engine.model import block_module, refuse_block
 from dynamo_tpu.engine.runner import host_ready, start_host_fetch
 from dynamo_tpu.engine.sampler import needs_full, row_needs_full
 from dynamo_tpu.kv_router.protocols import ForwardPassMetrics, KvCacheEvent, KvStats, WorkerStats
@@ -569,12 +569,27 @@ def register_engine_metrics(registry) -> dict:
             "prefix cache (hit + miss = (prompt_len - 1) // block_size)",
         ),
         registry.counter(
+            "engine_conv_state_resumes_total",
+            "Prefill rows of a model with convolution layers, by where their "
+            "conv state came from: cache = the block before the row's first "
+            "(a prefix hit, a returning preempted sequence), zero = position "
+            "0, recompute = K and V were cached further than the conv state "
+            "and the conv layers ran over those positions again",
+        ),
+        registry.gauge(
+            "kv_pool_bytes",
+            "HBM bytes of the G1 pool by kind of page: kv = K and V (or "
+            "latent) pages, conv = the convolution layers' state under the "
+            "same block ids (block='lfm2' models); their sum is "
+            "engine_kv_cache_bytes",
+        ),
+        registry.counter(
             "moe_assignments_total",
             "Expert assignments (token x top-k x layer) the expert layer "
             "routed, by kind: held = to a routed expert this chip holds "
             "(the grouped product), zero = to a zero-compute expert (adds "
             "w*h, no weights), absent = to a routed expert another chip "
-            "holds (left out here). block='longcat' models only",
+            "holds (left out here). Models whose block routes only",
         ),
         registry.counter(
             "moe_expert_tokens_total",
@@ -815,20 +830,29 @@ class TpuEngine:
         self._ctr_pushed: dict[tuple, float] = {}
         # _waiting as it was when its head was last stamped blocked for a slot
         self._slots_blocked_sig: tuple | None = None
-        # A block="longcat" model's routing histograms [L, E + HIST_EXTRA]
+        # A routing block's histograms [routed layers, E + HIST_EXTRA]
         # (engine/longcat.py) by program, summed from the arrays that ride the
-        # token fetches; None for a block that routes nothing.
+        # token fetches; None for a block that routes nothing. The block's
+        # module says which layers route (``routed_layers``).
         self.moe_hist: dict[str, np.ndarray] | None = None
-        if self.cfg.block == "longcat":
+        routed = getattr(block_module(self.cfg), "routed_layers", None)
+        self._routed_layers: tuple[int, ...] = routed(self.cfg) if routed else ()
+        if self._routed_layers:
             from dynamo_tpu.engine.longcat import HIST_EXTRA
 
-            shape = (self.cfg.num_layers, self.cfg.num_experts + HIST_EXTRA)
+            shape = (len(self._routed_layers), self.cfg.num_experts + HIST_EXTRA)
             self.moe_hist = {p: np.zeros(shape, np.int64) for p in ("prefill", "decode")}
+        # Prefill rows of a model with conv layers, by where their conv state
+        # came from (engine_conv_state_resumes_total); None without such layers.
+        self.conv_resumes: dict[str, int] | None = (
+            {"cache": 0, "zero": 0, "recompute": 0} if self.cfg.conv_layers else None)
 
     def bind_metrics(self, registry) -> None:
         """Attach the engine gauges to a MetricsRegistry; updated once
         per scheduler step (never per token)."""
         self._gauges = register_engine_metrics(registry)
+        for source in self.conv_resumes or ():  # every source is a series from the start, at 0
+            self._gauges["engine_conv_state_resumes_total"].inc(0, source=source)
 
     def _feed(self, name: str, total: float, **labels: str) -> None:
         """Give counter ``name`` what its running total grew by since it
@@ -845,6 +869,8 @@ class TpuEngine:
             return
         feed = self._feed
         g["engine_kv_cache_bytes"].set(self.args.kv_bytes_per_block() * self.args.num_kv_blocks)
+        for kind, per_block in self.args.pool_bytes_per_block().items():
+            g["kv_pool_bytes"].set(per_block * self.args.num_kv_blocks, kind=kind)
         g["engine_kv_quant_enabled"].set(1 if self.args.kv_quant == "int8" else 0)
         g["engine_inflight_windows"].set(self._inflight_windows())
         g["engine_pending_first_fetches"].set(
@@ -892,6 +918,8 @@ class TpuEngine:
              path="xla" if self._runner.prefill_attn_impl == "xla" else "pallas")
         feed("kv_pool_hit_blocks_total", self.pool.hit_blocks)
         feed("kv_pool_miss_blocks_total", self.pool.miss_blocks)
+        for source, n in (self.conv_resumes or {}).items():
+            feed("engine_conv_state_resumes_total", n, source=source)
         if self.moe_hist is not None:
             E, off = self.cfg.num_experts, self.cfg.expert_offset
             both = sum(self.moe_hist.values())
@@ -905,7 +933,8 @@ class TpuEngine:
                 feed("moe_experts_touched_total", int(touched), program=program)
                 feed("moe_expert_calls_total", int(calls), program=program)
             for (l, e), n in np.ndenumerate(both[:, :E]):
-                feed("moe_expert_tokens_total", int(n), layer=str(l), expert=str(off + e))
+                feed("moe_expert_tokens_total", int(n), layer=str(self._routed_layers[l]),
+                     expert=str(off + e))
 
     def _phase_open(self, key: str) -> float:
         """Begin step-loop phase `key` → its t0. Opens the profiler
@@ -1189,12 +1218,13 @@ class TpuEngine:
             ).to_dict()
             return
         ktp = req.kv_transfer_params or {}
-        if self.cfg.block == "longcat" and any(k in ktp for k in (
+        if self.cfg.block != "llama" and any(k in ktp for k in (
                 "do_remote_decode", "peer_prefix", "stream_handle", "handle", "pages")):
+            pages = {"longcat": "latent pages", "lfm2": "conv-state pool"}[self.cfg.block]
             yield LLMEngineOutput(
                 finish_reason=FinishReason.ERROR,
                 error="KV transfer (transfer/: disaggregated prefill, peer prefix "
-                      "fetch) cannot carry a block='longcat' model's latent pages",
+                      f"fetch) cannot carry a block={self.cfg.block!r} model's {pages}",
             ).to_dict()
             return
         vocab = self.cfg.vocab_size
@@ -1868,6 +1898,12 @@ class TpuEngine:
         singles: list[tuple[_Seq, int, list[int] | None]] = []
         groups: dict[int, list[tuple[_Seq, int]]] = {}
         for seq, start in allocated:
+            if self.conv_resumes is not None:
+                # The state rides the pages, so a row starts where its K and V
+                # hits end; "recompute" counts if that ever stops being so.
+                cached_kv = seq.prefix_hit_blocks * self.args.block_size
+                self.conv_resumes[
+                    "recompute" if start < cached_kv else "cache" if start else "zero"] += 1
             sfx = len(seq.tokens) - start
             if sfx > self.args.max_prefill_tokens:
                 singles.append((seq, start, None))
@@ -2193,6 +2229,8 @@ class TpuEngine:
         "published"} or {"error"}. Scheduler thread only."""
         if self.cfg.block == "longcat":
             return {"error": "live migration cannot carry latent (MLA) pages"}
+        if self.cfg.block == "lfm2":
+            return {"error": "live migration cannot carry the conv-state pool"}
         seq = next(
             (s for s in self._running if s.request_id == request_id), None
         )

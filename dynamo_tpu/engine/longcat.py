@@ -282,8 +282,19 @@ def grouped_expert_matmul(x, w, group_sizes, *, impl: str = "ragged_dot"):
 def route(xt: jax.Array, lp: dict, cfg: ModelConfig):
     """xt [N, D] → (expert ids [N, k] over the published router width,
     weights [N, k] float32). Selection on ``p + bias``; weights
-    ``scaling * p``, not renormalised."""
+    ``scaling * p``, not renormalised. ``cfg.router_scoring == "sigmoid"``
+    (engine/lfm2.py) is the second arithmetic: ``s = sigmoid(logits)``,
+    selection on ``s + bias`` where ``use_expert_bias``, weights ``s`` there,
+    over their sum where ``norm_topk_prob``, times the scaling."""
     logits = jnp.dot(xt, lp["w_router"], preferred_element_type=jnp.float32)
+    if cfg.router_scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+        chosen_on = s + lp["router_bias"][None, :] if cfg.use_expert_bias else s
+        _, topi = lax.top_k(chosen_on, cfg.num_experts_per_token)
+        topw = jnp.take_along_axis(s, topi, axis=-1)
+        if cfg.norm_topk_prob:
+            topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-6)
+        return topi, topw * cfg.routed_scaling_factor
     probs = jax.nn.softmax(logits, axis=-1)
     _, topi = lax.top_k(probs + lp["router_bias"][None, :], cfg.num_experts_per_token)
     topw = jnp.take_along_axis(probs, topi, axis=-1) * cfg.routed_scaling_factor
@@ -339,6 +350,12 @@ def _moe_tokens(xt, valid, lp: dict, cfg: ModelConfig, impl: str):
 # touched (held experts with at least one token in a call of the grouped
 # product: whose weights that call had to read), and those calls.
 HIST_EXTRA = 5
+
+
+def routed_layers(cfg: ModelConfig) -> tuple[int, ...]:
+    """The layers that route, in the order of the histogram's rows (the
+    engine sizes and labels its ``moe_*`` counters by this)."""
+    return tuple(range(cfg.num_layers))
 
 
 def moe(h: jax.Array, valid: jax.Array, lp: dict, cfg: ModelConfig, impl: str):

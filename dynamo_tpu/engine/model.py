@@ -60,12 +60,18 @@ class KVCache(NamedTuple):
     A ``block="longcat"`` model (engine/longcat.py) has ONE pool: ``k`` is
     ``[2L, N, bs, latent_page_width]`` (cache layer 2*layer + sub-block; a
     row is the normed latent and the rotated rope key every head shares,
-    padded to whole lane tiles) and ``v`` is None."""
+    padded to whole lane tiles) and ``v`` is None.
+
+    A ``block="lfm2"`` model (engine/lfm2.py) has K and V pages for its
+    attention layers alone and a third pool under the same block ids,
+    ``conv`` ``[conv layers, K, N, hidden]``: the block that holds position
+    ``p`` keeps that layer's conv input ``z_p`` in slot ``p % K``."""
 
     k: jax.Array  # [L, N, bs, KVH*hd]
     v: jax.Array | None
     k_scale: jax.Array | None = None  # [L, N, bs, KVH] fp32 — int8 only
     v_scale: jax.Array | None = None
+    conv: jax.Array | None = None     # block="lfm2" only
 
 
 def init_kv_cache(
@@ -179,16 +185,21 @@ def _dot_q(x: jax.Array, lp: dict, name: str) -> jax.Array:
 
 def block_module(cfg: ModelConfig):
     """The module that runs ``cfg.block``, chosen once (engine/runner.py):
-    this one, or engine/longcat.py. Both have ``init_params``,
+    this one, engine/longcat.py or engine/lfm2.py. Each has ``init_params``,
     ``init_kv_cache`` and the jitted ``prefill``, ``prefill_batch``,
-    ``decode_step`` and ``multi_decode`` under these names; longcat's
-    programs return a routing histogram after what these return."""
+    ``decode_step`` and ``multi_decode`` under these names; a block that
+    routes (``routed_layers(cfg)``) returns a routing histogram after what
+    these return."""
     if cfg.block == "longcat":
         from dynamo_tpu.engine import longcat
 
         return longcat
+    if cfg.block == "lfm2":
+        from dynamo_tpu.engine import lfm2
+
+        return lfm2
     if cfg.block != "llama":
-        raise ValueError(f"no module runs block={cfg.block!r} (llama, longcat)")
+        raise ValueError(f"no module runs block={cfg.block!r} (llama, longcat, lfm2)")
     return sys.modules[__name__]
 
 

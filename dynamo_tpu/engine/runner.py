@@ -183,7 +183,7 @@ class LocalRunner:
         # Before anything is allocated: a refused configuration fails fast.
         self.attn_impl, attn_note = self._resolve_attention()
         self.prefill_attn_impl, prefill_note = self._resolve_prefill_attention(attn_note)
-        if self._block is M:
+        if self.cfg.block != "longcat":
             self._prefill_kw = {"attn_impl": self.prefill_attn_impl}
         dtype = jnp.dtype(self.args.dtype)
         # Seeded params and the KV pool are BORN sharded (jit with
@@ -322,6 +322,8 @@ class LocalRunner:
         )
         # The grouped expert product's path, where the block has one.
         experts = f" experts={self._block.expert_impl()}" if hasattr(self._block, "expert_impl") else ""
+        # A block added after the lines above were pinned names itself.
+        block = getattr(self._block, "START_LINE", "")
         # A dp rank is pinned to its chips by the spawner; inside its own
         # TPU world every rank's device ids start at 0 again.
         pinned = os.environ.get("TPU_VISIBLE_CHIPS", "all")
@@ -330,7 +332,7 @@ class LocalRunner:
             f"devices={len(devs)} of {jax.device_count()} "
             f"ids={','.join(str(d.id) for d in devs)} visible_chips={pinned} "
             f"dtype={a.dtype} quant={a.quant} kv_quant={a.kv_quant} "
-            f"attention: prefill={prefill} decode={decode} spec_verify={spec}{experts}{hbm}"
+            f"attention: prefill={prefill} decode={decode} spec_verify={spec}{block}{experts}{hbm}"
         )
 
     def stop(self) -> None:

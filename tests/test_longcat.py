@@ -399,3 +399,48 @@ def test_the_runner_takes_its_programs_from_the_blocks_module(preset, hist):
 def test_a_block_without_a_module_is_refused():
     with pytest.raises(ValueError, match="no module runs block='mamba'"):
         M.block_module(dataclasses.replace(CFG, block="mamba"))
+
+
+# -- the router's second arithmetic (engine/lfm2.py) leaves this block as it was ---
+
+
+WAS = {  # read on the parent of PR 37 (longcat-tiny, float32, the CPU)
+    "tokens": [[165, 447, 419, 311, 205, 234, 199, 199, 429, 468], [466, 232, 347, 242, 453, 494, 334, 249, 459, 51]],
+    "topi": [[3, 0, 8], [8, 9, 0], [5, 3, 0], [9, 8, 0], [8, 1, 7], [3, 10, 0]],
+    "topw": [[4.911398, 0.071298, 0.004912], [3.742863, 1.274542, 0.025198], [3.903823, 1.775397, 0.009748],
+             [3.511818, 1.405415, 0.096554], [2.402556, 1.07643, 1.191123], [3.790177, 1.631835, 0.203196]],
+}
+
+
+@pytest.mark.parametrize("what", ["route", "served_tokens"])
+def test_longcat_routes_and_serves_what_it_did_before_the_second_router_arithmetic(what):
+    """``route`` gained the sigmoid arithmetic behind a configuration field;
+    this block's choice (softmax, on ``p + bias``) and weights (``6 p``, not
+    renormalised) and the tokens it serves are the parent's."""
+    cfg = ModelConfig.preset("longcat-tiny")
+    assert cfg.router_scoring == "softmax" and not cfg.norm_topk_prob
+    if what == "route":
+        params = longcat.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        lp = {k: v[1] for k, v in params["layers"].items()}
+        xt = jax.random.normal(jax.random.PRNGKey(5), (6, cfg.hidden_size), jnp.float32)
+        topi, topw = longcat.route(xt, lp, cfg)
+        assert np.asarray(topi).tolist() == WAS["topi"]
+        np.testing.assert_allclose(np.asarray(topw), WAS["topw"], rtol=1e-4, atol=2e-6)
+        p = jax.nn.softmax(jnp.dot(xt, lp["w_router"], precision="highest"), axis=-1)
+        np.testing.assert_allclose(np.asarray(topw), 6.0 * np.take_along_axis(np.asarray(p), np.asarray(topi), -1), rtol=1e-4)
+        return
+
+    async def go():
+        engine = await TpuEngine(EngineArgs(model=cfg, block_size=BS, num_kv_blocks=24, max_num_seqs=4,
+                                            max_model_len=128, max_prefill_tokens=64, dtype="float32")).start()
+        try:
+            out = []
+            for s in (1, 2):
+                req = greedy([int(t) for t in np.random.RandomState(s).randint(0, cfg.vocab_size, 40)], 10)
+                req.stop.ignore_eos = True
+                out.append(await _tokens(engine, req))
+            return out
+        finally:
+            await engine.stop()
+
+    assert asyncio.run(go()) == WAS["tokens"]

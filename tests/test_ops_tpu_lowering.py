@@ -14,6 +14,7 @@ passed every test and could not be lowered for a TPU at all. Two levels:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import re
 
@@ -413,3 +414,94 @@ def test_longcat_programs_compile_for_v5e(v5e, program):
             cfg, params, cache, i32(1, T), i32(1, W), i32(1), i32(1), experts="gmm"
         ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+# -- the LFM2 block: head size 64, a conv-state pool beside K and V, 64 experts held --
+
+
+def _lfm2(num_layers: int = 10) -> ModelConfig:
+    """The benchmark's LFM2-24B-A2B configuration at its published widths: the
+    first ``num_layers`` of its 40 layers (conv, conv, attention, conv, ...)."""
+    pattern = ("conv", "conv", "full_attention", "conv") * 10
+    return ModelConfig(
+        name="lfm2-cut", block="lfm2", vocab_size=65536, hidden_size=2048, intermediate_size=11776,
+        num_layers=num_layers, num_heads=32, num_kv_heads=8, head_dim=64, rope_theta=1e6,
+        tie_embeddings=True, layer_types=pattern[:num_layers], num_dense_layers=2, conv_L_cache=3,
+        num_experts=64, num_routed_experts=64, num_experts_per_token=4, moe_intermediate_size=1536,
+        routed_scaling_factor=1.0, router_scoring="sigmoid", use_expert_bias=True, norm_topk_prob=True,
+    )
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill_256", "prefill_2048"])
+def test_paged_kernels_compile_for_v5e_at_head_size_64(v5e, kernel):
+    """A geometry no other cell has: G 4, KVH 8, hd 64, bs 32, so two heads
+    share a 128-lane tile of the page row. ``kernel_unsupported`` passes it
+    (a row of 512 lanes) and the chip's compiler has to agree."""
+    cfg = _lfm2()
+    assert kernel_unsupported(cfg, LBS) is None and (cfg.kv_size, cfg.num_heads // cfg.num_kv_heads) == (512, 4)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    KVH, G, hd, W = 8, 4, 64, 4096 // LBS
+    pages, layer = S((2, 2048, LBS, KVH * hd), jnp.bfloat16), S((), jnp.int32)
+    if kernel == "decode":
+        B = 128
+        paged_decode_attention.lower(S((B, KVH, G, hd), jnp.bfloat16), pages, pages, layer,
+                                     S((B, W), jnp.int32), S((B,), jnp.int32)).compile()
+        return
+    from dynamo_tpu.ops.paged_attention import paged_prefill_attention
+
+    T = int(kernel.rsplit("_", 1)[1])
+    jax.jit(paged_prefill_attention).lower(
+        S((1, T, KVH, G, hd), jnp.bfloat16), pages, pages, layer, S((1, W), jnp.int32),
+        S((1,), jnp.int32), S((1,), jnp.int32)).compile()
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk_2048", "prefill_packed_256"])
+def test_lfm2_programs_copy_no_pool_and_no_expert_stack_on_v5e(v5e, program):
+    """The jitted programs the LFM2 cell runs, at the published widths and the
+    cell's pool (four layers: a dense conv layer and three expert layers, one
+    of them attention): the decode window at 128 rows, a 2,048-token chunk and
+    a 256-token packed prefill behind a 4,096-token table. No instruction's
+    result is a pool, a layer of one, or a layer's expert stack: the pools
+    change in place and the stacks reach the grouped product as they lie."""
+    from dynamo_tpu.engine import lfm2
+
+    cfg = _lfm2(4)
+    cfg = dataclasses.replace(cfg, num_dense_layers=1)
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    params = _abstract(jax.eval_shape(lambda: lfm2.init_params(cfg, jax.random.PRNGKey(0))), S)
+    W, N = 4096 // LBS, 5632
+    cache = _abstract(jax.eval_shape(lambda: lfm2.init_kv_cache(cfg, N, LBS)), S)
+    i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
+    if program == "decode_window":
+        B = 128
+        flags = S((B,), jnp.bool_)
+        compiled = lfm2.multi_decode.lower(
+            cfg, 8, "greedy", 0, params, cache,
+            i32(B), i32(B), i32(B, W), flags, f32(B), S((B,), jnp.uint32), i32(B),
+            i32(B), f32(B), f32(B), f32(B), i32(B, 1), flags, i32(B), i32(B + 1),
+            None, None, attn_impl="pallas", experts="gmm",
+        ).compile()
+    else:
+        T = int(program.rsplit("_", 1)[1])
+        compiled = lfm2.prefill_batch.lower(
+            cfg, params, cache, i32(1, T), i32(1, W), i32(1), i32(1), attn_impl="pallas", experts="gmm"
+        ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    hlo = compiled.as_text()
+    assert hlo.count("paged_prefill_attention" if program != "decode_window" else "tpu_custom_call") >= 1
+    D, E, ie, kv = cfg.hidden_size, cfg.num_experts, cfg.moe_intermediate_size, cfg.kv_size
+    La, Lc, K = len(cfg.attn_layers), len(cfg.conv_layers), cfg.conv_state_slots
+    big = {f"[{La},{N},{LBS},{kv}]", f"[{N},{LBS},{kv}]", f"[1,{N},{LBS},{kv}]",          # K or V, a layer
+           f"[{Lc},{K},{N},{D}]", f"[{K},{N},{D}]", f"[{N},{D}]", f"[1,1,{N},{D}]",        # conv state, a layer, a slot
+           f"[{E},{D},{ie}]", f"[{E},{ie},{D}]"}                                          # a layer's expert stack
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = \S*?(\[[\d,]*\])\S* ([\w-]+)\(", line)
+        if not m or m.group(2) in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+            continue
+        shape, opcode = m.groups()
+        # a pool's own shape belongs to the in-place scatters, the fusions that wrap them, the
+        # kernels that alias it through and the loop that carries it
+        if shape in big and opcode not in ("scatter", "fusion", "custom-call", "while", "conditional", "call"):
+            found.append(line.strip()[:140])
+    assert not found, "\n".join(found)

@@ -418,3 +418,82 @@ def test_longcat_expert_counters_ride_the_token_fetches(fresh_recorder):
     # Prompt tokens and every decoded token but each request's last, a layer each
     # (a window also routes the rows it runs past a stop: at least this many).
     assert routed >= cfg.num_layers * (19 + 15 + 2 * 6)
+
+
+LFM2_SCOPES = ("conv_in", "conv_state", "conv_mix", "conv_out", "attn_qkv", "attn_qk_norm", "kv_write",
+               "attn", "attn_out", "ffn_dense", "moe_route", "moe_experts")
+
+
+@pytest.mark.parametrize("program", ["multi_decode", "prefill_batch"])
+def test_lfm2_programs_keep_their_scope_and_kernel_names(program):
+    """The LFM2 block's programs under the dense block's jit names, its
+    scopes, and the kernels by the names the readers search for
+    (``paged_decode_attention``, ``paged_prefill_attention``, ``gmm`` under
+    ``grouped_expert_matmul``: chipbench/layer_metrics/_whole.py)."""
+    from dynamo_tpu.engine import lfm2
+
+    cfg = ModelConfig.preset("lfm2-tiny")
+    params = lfm2.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    cache = lfm2.init_kv_cache(cfg, 16, 8, jnp.float32)
+    B, W, K = 8, 4, 2
+    z = np.zeros((B,), np.int32)
+    kw = dict(attn_impl="pallas_interpret", experts="gmm_interpret")
+    if program == "multi_decode":
+        text = lfm2.multi_decode.lower(
+            cfg, K, "greedy", 0, params, cache, jnp.asarray(z), jnp.asarray(z),
+            jnp.zeros((B, W), jnp.int32), jnp.zeros((B,), bool), jnp.ones((B,), jnp.float32),
+            jnp.zeros((B,), jnp.uint32), jnp.asarray(z), jnp.asarray(z), jnp.ones((B,), jnp.float32),
+            jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.float32), jnp.full((B, 1), -1, jnp.int32),
+            jnp.zeros((B,), bool), jnp.asarray(z), jnp.zeros((5,), jnp.int32), None, None, **kw,
+        ).as_text(debug_info=True)
+        assert "jit_multi_decode_impl" in text and "paged_decode_attention" in text
+        scopes = ("embed", *LFM2_SCOPES, "logits", "sample")
+    else:
+        text = lfm2.prefill_batch.lower(
+            cfg, params, cache, jnp.zeros((2, 16), jnp.int32), jnp.zeros((2, W), jnp.int32),
+            jnp.zeros((2,), jnp.int32), jnp.full((2,), 5, jnp.int32), None, None, **kw,
+        ).as_text(debug_info=True)
+        assert "jit_prefill_batch_impl" in text and "paged_prefill_attention" in text
+        assert "jit_prefill_impl" in lfm2.prefill.lower(
+            cfg, params, cache, jnp.zeros((16,), jnp.int32), jnp.zeros((W,), jnp.int32), 0, 5).as_text()
+        scopes = ("embed", *LFM2_SCOPES, "logits")
+    assert "grouped_expert_matmul" in text
+    for scope in scopes:
+        assert scoped(text, scope), scope
+
+
+def counter_of(page: str, name: str, label: str) -> float | None:
+    """A series' value on a rendered ``/metrics`` page; None where the page has no such series."""
+    for line in page.splitlines():
+        if line.startswith(f"dynamo_tpu_{name}{{") and label in line:
+            return float(line.rsplit(" ", 1)[1])
+    return None
+
+
+def test_conv_state_and_pool_series_are_pinned_names(fresh_recorder):
+    """engine_conv_state_resumes_total{source} (all three sources from the
+    start) and kv_pool_bytes{kind} for a model with conv layers; kv_pool_bytes
+    alone, one kind, for a dense one."""
+    pages = {}
+    for preset in ("lfm2-tiny", "test-tiny"):
+        reg = MetricsRegistry()
+
+        async def go():
+            engine = TpuEngine(make_args(model=ModelConfig.preset(preset), block_size=8))
+            engine.bind_metrics(reg)
+            await engine.start()
+            try:
+                await serve(engine, [range(1, 20), range(1, 20)], max_tokens=3)
+                await settle(engine)
+            finally:
+                await engine.stop()
+
+        asyncio.run(go())
+        pages[preset] = reg.render()
+    sources = {s: counter_of(pages["lfm2-tiny"], "engine_conv_state_resumes_total", f'source="{s}"')
+               for s in ("cache", "zero", "recompute")}
+    assert sources["recompute"] == 0 and sources["cache"] + sources["zero"] == 2 and sources["zero"] >= 1
+    assert counter_of(pages["lfm2-tiny"], "kv_pool_bytes", 'kind="conv"') > 0
+    assert counter_of(pages["lfm2-tiny"], "kv_pool_bytes", 'kind="kv"') > 0
+    assert counter_of(pages["test-tiny"], "kv_pool_bytes", 'kind="kv"') > 0
+    assert 'kind="conv"' not in pages["test-tiny"] and "engine_conv_state_resumes_total{" not in pages["test-tiny"]
