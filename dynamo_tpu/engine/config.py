@@ -326,6 +326,13 @@ def spec_verify_widths(spec_tokens: int, adaptive: bool) -> tuple[int, ...]:
     return tuple(widths)
 
 
+# Packed prefill (EngineArgs.pack_shapes): the row counts a pack may have, and
+# how many T buckets each gets. An admission wave under the token budget is a
+# handful of prompts; every (rows, T) is a program compiled at start.
+PACK_ROWS = (2, 4)
+PACK_T_BUCKETS = 2
+
+
 def _pow2_buckets(lo: int, hi: int, factor: int = 2) -> tuple[int, ...]:
     out = []
     b = lo
@@ -435,14 +442,6 @@ class EngineArgs:
     # (chunked prefill is exact); costs one extra dispatch, so only
     # splits that save ≥ 2 blocks of padding are taken.
     prefill_tail_split: bool = True
-    # Max sequences packed into one prefill dispatch (model.prefill_batch).
-    # Default 1 (singles): packing existed because r3 paid a host sync per
-    # admission, but async admission pipelines single-row prefills with no
-    # sync — and every extra row bucket multiplies the compile lattice
-    # that warmup must cover (a cold variant hit mid-run compiles inside
-    # the request; July record, remote chip: 609 vs 890 tok/s).
-    # Raise it only with a warmed cache covering the (T x Bp x W) matrix.
-    prefill_batch_max: int = 1
     # Alternative-logprob width: requests asking for top_logprobs get up
     # to this many ranked alternatives; ONE static width keeps the
     # compile matrix at 2x (with/without) instead of per-N variants.
@@ -772,14 +771,63 @@ class EngineArgs:
             return best
         return [sfx]
 
-    def bucket_prefill_rows(self, n: int) -> int:
-        # Pow2 row ladder: steady-state admission waves are small (1-3
-        # slots free per step), and padding a 2-seq wave to 8 rows cost
-        # 4x its prefill compute (each padded row runs the full model).
-        b = 1
-        while b < min(n, self.prefill_batch_max):
-            b *= 2
-        return min(b, self.prefill_batch_max)
+    def pack_shapes(self, limit: int, row_tokens: int = 0) -> tuple[tuple[int, int], ...]:
+        """The packed prefill programs (rows, T) a limit of ``limit`` padded
+        tokens a dispatch allows (the runner derives it from the model's
+        weight bytes against its operations a token, ``runner.pack_limit``),
+        a row counting ``row_tokens`` beside its T (``runner.pack_row_tokens``:
+        what the block spends on a row whatever its length):
+        for each row count of the pow2 ladder, the ``PACK_T_BUCKETS`` largest
+        T buckets with rows x (T + row_tokens) inside the limit and rows x T
+        inside ``max_prefill_tokens``
+        (so no pack's temporaries pass the largest single's). Under the
+        limit a dispatch is bound by the weights it streams and padding a row
+        up costs no second stream, so small T buckets would buy a pack
+        nothing but programs; each shape is one compile (wide table only).
+        A limit that cannot hold the ladder's top row count at the smallest
+        bucket gets no program at all. That floor is a choice, not
+        arithmetic: such a model reaches its ridge within a row or two of
+        the shortest suffixes, programs for pairs of those served 0 to 1
+        dispatch a window where they were tried on the chip (PERF.md s.6,
+        PR 38), and each is set-up time and device memory."""
+        if PACK_ROWS[-1] * (self.prefill_buckets[0] + row_tokens) > limit:
+            return ()
+        out = []
+        for rows in PACK_ROWS:
+            fits = [t for t in self.prefill_buckets
+                    if rows * (t + row_tokens) <= limit and rows * t <= self.max_prefill_tokens]
+            out += [(rows, t) for t in fits[-PACK_T_BUCKETS:]]
+        return tuple(out)
+
+    def plan_prefill_packs(
+        self, suffixes: list[int], shapes
+    ) -> list[tuple[list[int], int, int]]:
+        """A wave's single-chunk suffixes (token counts) as dispatches:
+        ``(indices into suffixes, rows, T bucket)`` each. Longest first, into
+        the largest program of ``shapes`` (the (rows, T) that exist:
+        ``pack_shapes`` of the model's limit, once compiled) that more than
+        half fill, at the smallest T that holds the longest member: 5 go as
+        4 + 1, and 3 as one program of 4 with an inactive row, which the
+        limit has already counted (one more weight stream would cost more).
+        A suffix no shape holds goes alone (rows 1) at its own bucket, as
+        every suffix does while ``shapes`` is empty."""
+        order = sorted(range(len(suffixes)), key=lambda i: -suffixes[i])
+        by_rows: dict[int, list[int]] = {}
+        for rows, t in shapes:
+            by_rows.setdefault(rows, []).append(t)
+        out: list[tuple[list[int], int, int]] = []
+        i = 0
+        while i < len(order):
+            longest, left = suffixes[order[i]], len(order) - i
+            rows, t_pad = 1, self.bucket_prefill(longest)
+            for r in sorted(by_rows, reverse=True):
+                holds = [t for t in by_rows[r] if t >= longest]
+                if r < 2 * left and holds:
+                    rows, t_pad = r, min(holds)
+                    break
+            out.append((order[i:i + rows], rows, t_pad))
+            i += rows
+        return out
 
     def bucket_decode(self, n: int) -> int:
         for b in self.decode_buckets:

@@ -560,6 +560,20 @@ def register_engine_metrics(registry) -> dict:
             "(off the TPU, under a mesh, int8 KV pages, a refused geometry)",
         ),
         registry.counter(
+            "engine_prefill_dispatch_rows_total",
+            "Prefill dispatches by the rows of their program: 1 = a prompt "
+            "or a chunk alone, 2 and 4 = an admission wave's single-chunk "
+            "suffixes packed into one program, which streams the weights "
+            "once for all of them (the start line's prefill_pack<=N tok is "
+            "the padded tokens a pack may hold)",
+        ),
+        registry.counter(
+            "engine_prefill_rows_total",
+            "Real rows (sequences) the prefill dispatches carried; three in "
+            "a program of four leave one row inactive. Over the sum of "
+            "engine_prefill_dispatch_rows_total it is the mean rows a dispatch",
+        ),
+        registry.counter(
             "kv_pool_hit_blocks_total",
             "Prompt blocks an admission found in the G1 prefix cache",
         ),
@@ -846,6 +860,10 @@ class TpuEngine:
         # came from (engine_conv_state_resumes_total); None without such layers.
         self.conv_resumes: dict[str, int] | None = (
             {"cache": 0, "zero": 0, "recompute": 0} if self.cfg.conv_layers else None)
+        # Prefill dispatches by their program's rows (a chunk of a chunked
+        # prefill is a dispatch of one row): engine_prefill_dispatch_rows_total.
+        self.prefill_dispatch_rows: dict[int, int] = collections.defaultdict(int)
+        self.prefill_rows_carried = 0   # real rows: engine_prefill_rows_total
 
     def bind_metrics(self, registry) -> None:
         """Attach the engine gauges to a MetricsRegistry; updated once
@@ -916,6 +934,9 @@ class TpuEngine:
              kind="emitted")
         feed("engine_prefill_attn_dispatch_total", self._runner.prefill_dispatches,
              path="xla" if self._runner.prefill_attn_impl == "xla" else "pallas")
+        for rows, n in self.prefill_dispatch_rows.items():
+            feed("engine_prefill_dispatch_rows_total", n, rows=str(rows))
+        feed("engine_prefill_rows_total", self.prefill_rows_carried)
         feed("kv_pool_hit_blocks_total", self.pool.hit_blocks)
         feed("kv_pool_miss_blocks_total", self.pool.miss_blocks)
         for source, n in (self.conv_resumes or {}).items():
@@ -1887,16 +1908,26 @@ class TpuEngine:
         self, allocated: list[tuple[_Seq, int]]
     ) -> list[tuple[_Seq, Any, int]]:
         """Phase 2 of admission: run the wave's prefills. Suffixes that fit
-        one chunk are PACKED by (T bucket) into prefill_batch dispatches;
-        longer prompts fall back to per-sequence chunked prefill, and
-        suffixes whose bucket pad is large split into [bucket chunk,
-        re-bucketed tail] chunked dispatches (plan_prefill_chunks) so the
-        remainder packs a small bucket instead of padding a whole row.
-        Returns (seq, logits array, row index) triples (logits not
-        synced)."""
+        one chunk go out as rows of prefill_batch dispatches, several to a
+        dispatch where the model's weight stream pays for it and the packed
+        program exists (EngineArgs.plan_prefill_packs over the runner's
+        packed_ready), alone otherwise; longer prompts fall back to
+        per-sequence chunked prefill, and a suffix left alone whose bucket
+        pad is large splits into [bucket chunk, re-bucketed tail] chunked
+        dispatches (plan_prefill_chunks). Returns (seq, logits array, row
+        index) triples (logits not synced)."""
         out: list[tuple[_Seq, Any, int]] = []
-        singles: list[tuple[_Seq, int, list[int] | None]] = []
-        groups: dict[int, list[tuple[_Seq, int]]] = {}
+        # Rows that may share a dispatch: one chunk, and no adapter (the
+        # program with a bank operand is compiled for one row only).
+        rows = [(seq, start) for seq, start in allocated
+                if len(seq.tokens) - start <= self.args.max_prefill_tokens and seq.adapter_slot < 0]
+        packs = [([rows[j] for j in idx], n_rows, t_pad)
+                 for idx, n_rows, t_pad in self.args.plan_prefill_packs(
+                     [len(seq.tokens) - start for seq, start in rows], self._runner.packed_ready)
+                 if n_rows > 1]
+        in_pack = {id(seq) for members, _, _ in packs for seq, _ in members}
+        chunked: list[tuple[_Seq, int, list[int] | None]] = []
+        singles: dict[int, list[tuple[_Seq, int]]] = {}
         for seq, start in allocated:
             if self.conv_resumes is not None:
                 # The state rides the pages, so a row starts where its K and V
@@ -1904,48 +1935,43 @@ class TpuEngine:
                 cached_kv = seq.prefix_hit_blocks * self.args.block_size
                 self.conv_resumes[
                     "recompute" if start < cached_kv else "cache" if start else "zero"] += 1
-            sfx = len(seq.tokens) - start
-            if sfx > self.args.max_prefill_tokens:
-                singles.append((seq, start, None))
+            if id(seq) in in_pack:
                 continue
-            plan = self.args.plan_prefill_chunks(sfx)
-            if len(plan) > 1:
-                singles.append((seq, start, plan))
+            sfx = len(seq.tokens) - start
+            # Alone, a suffix is padded at its own cost: split the tail off
+            # where that saves two blocks of padding.
+            plan = None if sfx > self.args.max_prefill_tokens else self.args.plan_prefill_chunks(sfx)
+            if plan is None or len(plan) > 1:
+                chunked.append((seq, start, plan))
             else:
-                groups.setdefault(self.args.bucket_prefill(sfx), []).append((seq, start))
+                singles.setdefault(self.args.bucket_prefill(sfx), []).append((seq, start))
 
-        for seq, start, plan in singles:
+        for seq, start, plan in chunked:
             # row=None: chunked prefill yields [V] logits, not a batch row.
             out.append((seq, self._prefill_chunked(seq, start, plan), None))
-
-        bmax = max(1, self.args.prefill_batch_max)
-        for t_pad, members in sorted(groups.items()):
-            # Greedy pow2 packs (5 → 4+1): every dispatch exactly fills
-            # its row bucket, so no padded row ever runs the model.
-            i = 0
-            while i < len(members):
-                take = min(bmax, len(members) - i)
-                p = 1
-                while p * 2 <= take:
-                    p *= 2
-                sub = members[i : i + p]
-                i += p
-                arr = self._prefill_packed(sub, t_pad)
-                for row, (seq, start) in enumerate(sub):
-                    out.append((seq, arr, row))
+        # What goes alone goes as it went before there were packs: by T
+        # bucket, in arrival order within one.
+        packs += [([one], 1, t_pad) for t_pad, members in sorted(singles.items()) for one in members]
+        for members, n_rows, t_pad in packs:
+            arr = self._prefill_packed(members, n_rows, t_pad)
+            for row, (seq, start) in enumerate(members):
+                out.append((seq, arr, row))
         return out
 
     def _prefill_packed(
-        self, members: list[tuple[_Seq, int]], t_pad: int
+        self, members: list[tuple[_Seq, int]], Bp: int, t_pad: int
     ) -> Any:
-        """One packed prefill dispatch for same-bucket suffixes. Returns
-        logits [Bp, V] (not synced)."""
-        Bp = self.args.bucket_prefill_rows(len(members))
-        W = self.args.bucket_table(max(len(s.block_ids) for s, _ in members))
+        """One prefill_batch dispatch of ``Bp`` rows, the first
+        ``len(members)`` of them real (a row left over is inactive: true_len
+        0, it writes only to garbage block 0). A pack runs at the wide
+        table, the only one its programs are compiled for. Returns logits
+        [Bp, V] (not synced)."""
+        W = (self.args.blocks_per_seq if Bp > 1
+             else self.args.bucket_table(len(members[0][0].block_ids)))
         toks = np.zeros((Bp, t_pad), np.int32)
         tables = np.zeros((Bp, W), np.int32)
         starts = np.zeros((Bp,), np.int32)
-        tlens = np.zeros((Bp,), np.int32)  # padding rows: true_len 0 → inactive
+        tlens = np.zeros((Bp,), np.int32)
         for r, (seq, start) in enumerate(members):
             sfx = seq.tokens[start:]
             toks[r, : len(sfx)] = sfx
@@ -1955,6 +1981,8 @@ class TpuEngine:
         aslots = self._adapter_row_slots([s for s, _ in members], Bp)
         ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots)
         self.total_prefill_padded += Bp * t_pad
+        self.prefill_dispatch_rows[Bp] += 1
+        self.prefill_rows_carried += len(members)
         for seq, start in members:
             seq.chunks = 1
             self._finish_prefill_bookkeeping(seq, start)
@@ -1991,6 +2019,8 @@ class TpuEngine:
                 seq.adapter_slot if seq.adapter_slot >= 0 else None,
             )
             self.total_prefill_padded += t_pad
+            self.prefill_dispatch_rows[1] += 1
+            self.prefill_rows_carried += 1
             pos += len(chunk)
             n_chunks += 1
             # Streaming export: the blocks this chunk completed can ship

@@ -62,9 +62,12 @@ from dynamo_tpu.ops.paged_attention import (
 
 Params = dict[str, Any]
 
-# Tokens routed at a time: the grouped product's rows are tokens x
-# min(top-k, experts held), so a 2,048-token chunk is routed in four parts.
-MOE_CHUNK = 512
+# Assignment rows the grouped product is given at a time (tokens x min(top-k,
+# experts held)): 512 tokens of this block's twelve choices, so its 2,048-token
+# chunk is routed in four parts; a block of four choices routes 1,536 tokens
+# at once, which covers any pack of prefill rows the engine forms
+# (runner.pack_limit), so a pack streams a layer's experts once.
+MOE_CHUNK_ROWS = 6144
 # Float32 score elements one head group of the prefill attention may hold.
 _SCORE_ELEMS = 48 << 20
 _GMM_TILING = (128, 1024, 1024)
@@ -234,6 +237,17 @@ def attend_expanded(q_n, q_r, ctx_latent, mask, sub: dict, cfg: ModelConfig) -> 
     return jnp.moveaxis(o, 0, 2).reshape(B, T, H, dv)
 
 
+def prefill_row_ops(cfg: ModelConfig, context: int) -> int:
+    """Operations a prefill row costs this block whatever its tokens, behind
+    a table of ``context`` positions: ``attend_expanded`` multiplies every
+    cached latent of the table's width out by W_kvb once a row (keys and
+    values of every head, both sub-blocks of a layer). The runner counts it
+    against the padded tokens a packed dispatch may hold
+    (``runner.pack_row_tokens``)."""
+    return (2 * 2 * cfg.num_layers * context * cfg.num_heads
+            * (cfg.qk_nope_head_dim + cfg.v_head_dim) * cfg.kv_lora_rank)
+
+
 def absorb_query(q_n, q_r, sub: dict, cfg: ModelConfig) -> jax.Array:
     """[.., H, dn], [.., H, dr] → the query against a cache row, [.., H,
     latent_page_width]: ``q_n W_uk^T`` beside ``q_r``, zero in the padding."""
@@ -366,10 +380,12 @@ def moe(h: jax.Array, valid: jax.Array, lp: dict, cfg: ModelConfig, impl: str):
     D = h.shape[-1]
     xt, vt = h.reshape(-1, D), valid.reshape(-1)
     N = xt.shape[0]
-    if N > MOE_CHUNK and N % MOE_CHUNK == 0:
+    per = MOE_CHUNK_ROWS // min(cfg.num_experts_per_token, cfg.num_experts)
+    parts = -(-N // per)
+    if parts > 1 and N % parts == 0:
         y, hist = lax.map(
             lambda a: _moe_tokens(a[0], a[1], lp, cfg, impl),
-            (xt.reshape(-1, MOE_CHUNK, D), vt.reshape(-1, MOE_CHUNK)),
+            (xt.reshape(parts, N // parts, D), vt.reshape(parts, N // parts)),
         )
         y, hist = y.reshape(N, D), jnp.sum(hist, axis=0)
     else:

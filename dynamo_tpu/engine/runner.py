@@ -28,6 +28,8 @@ import functools
 import os
 import socket
 import struct
+import threading
+import time
 from collections import OrderedDict
 from typing import Any
 
@@ -50,6 +52,35 @@ from dynamo_tpu.runtime.logging import get_logger
 log = get_logger("runner")
 
 _RETAIN = 128  # refs kept for chaining/sampling (identical on all hosts)
+
+# A chip's peak operations over its peak bytes a second, by ``device_kind``
+# (the v5e's published 197 TFLOP/s in bf16 over 819 GB/s of HBM, the chip every
+# cell is measured on): the one constant of ``pack_limit``. A device the table
+# lacks is taken as a v5e, and the start line says so.
+_OPS_PER_BYTE = {"TPU v5 lite": 197e12 / 819e9}
+_OPS_PER_BYTE_DEFAULT = _OPS_PER_BYTE["TPU v5 lite"]
+
+
+def pack_limit(cfg, weight_bytes: int, ops_per_byte: float = _OPS_PER_BYTE_DEFAULT) -> int:
+    """How many padded tokens one prefill dispatch may hold before their
+    operations take longer than the weights take to stream: ``weight_bytes``
+    (every leaf the call holds, experts included) x the chip's operations a
+    byte over 2 x the parameters a token is multiplied by. Under it a second
+    row rides the same weight stream; over it the rows are cheaper apart,
+    each padded to its own bucket. ~120 for a dense int8 7B, ~1,700 for a
+    model of many small experts all held."""
+    return int(weight_bytes * ops_per_byte / (2 * cfg.active_param_count()))
+
+
+def pack_row_tokens(cfg, context: int) -> int:
+    """What a row of a pack costs beside its padded tokens, in tokens: the
+    operations the block spends on a row whatever its length (the block
+    module's own ``prefill_row_ops`` over the table's ``context``, where it
+    has one) over the operations of a token. ~98 for the latent block behind
+    4,096 positions; 0 for the blocks whose attention walks only what a row
+    can see."""
+    row_ops = getattr(M.block_module(cfg), "prefill_row_ops", None)
+    return -(-row_ops(cfg, context) // (2 * cfg.active_param_count())) if row_ops else 0
 
 
 @jax.jit
@@ -115,18 +146,22 @@ def _unpack_np(d: dict) -> np.ndarray:
     return np.frombuffer(d["b"], np.dtype(d["d"])).reshape(d["s"])
 
 
+def _with_histogram(block, program):
+    """``program``'s results and then a routing histogram in the last place:
+    None from the dense block, which has none."""
+    if block is not M:
+        return program
+    return lambda *a, **kw: (*program(*a, **kw), None)
+
+
 def _block_programs(cfg):
     """→ (the module that runs ``cfg.block``, its jitted prefill_batch,
     prefill, multi_decode and decode_step). The one place the block is
     chosen. Every program returns its results and then a routing histogram
-    in the last place: None from the dense block, which has none."""
+    in the last place (``_with_histogram``)."""
     block = M.block_module(cfg)
     programs = (block.prefill_batch, block.prefill, block.multi_decode, block.decode_step)
-    if block is M:
-        programs = tuple(
-            (lambda *a, _fn=fn, **kw: (*_fn(*a, **kw), None)) for fn in programs
-        )
-    return (block, *programs)
+    return (block, *(_with_histogram(block, fn) for fn in programs))
 
 
 class LocalRunner:
@@ -149,6 +184,14 @@ class LocalRunner:
         # dense block's attention path (the latent block's has one).
         self._prefill_kw: dict = {}
         self.prefill_dispatches = 0  # engine_prefill_attn_dispatch_total
+        # Packed prefill: the padded tokens a dispatch may hold (pack_limit;
+        # 0 under a mesh) and the (rows, T) programs compiled so far, by a
+        # thread of this runner's own, off the request path.
+        self.pack_limit_tokens = 0
+        self.pack_row_tokens = 0  # and what a row counts beside its T
+        self._packed: dict[tuple[int, int], Any] = {}
+        self._pack_thread: threading.Thread | None = None
+        self._pack_stop = threading.Event()
         self._rid = 0
         self._refs: OrderedDict[int, StepRef] = OrderedDict()
         # Per-SLOT latest sampled token [max_num_seqs + 1], kept on
@@ -240,7 +283,67 @@ class LocalRunner:
                     self.cfg, self.args.lora_slots, self.args.lora_rank
                 ).items()
             }
+        if sh is None:
+            weight_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(self.params))
+            self.pack_limit_tokens = pack_limit(
+                self.cfg, weight_bytes,
+                _OPS_PER_BYTE.get(jax.devices()[0].device_kind, _OPS_PER_BYTE_DEFAULT))
+            self.pack_row_tokens = pack_row_tokens(self.cfg, self.args.max_model_len)
         log.info("engine start: %s", self._start_line(attn_note, prefill_note))
+        self._start_pack_compiles()
+
+    # -- packed prefill programs -------------------------------------------
+
+    def _start_pack_compiles(self) -> None:
+        """Compile the packed prefill programs the limit allows
+        (``EngineArgs.pack_shapes``) on a thread of their own, from shapes
+        alone: the engine dispatches a pack only once its program is in
+        ``packed_ready``, so none is ever built inside a request, and the
+        worker serves singles meanwhile."""
+        shapes = self.args.pack_shapes(self.pack_limit_tokens, self.pack_row_tokens)
+        if not shapes:
+            return
+
+        def spec(x):
+            # No sharding: a program lowered for a named device commits its
+            # results to it, and the jitted programs, compiled for the
+            # uncommitted cache they hand each other, would each compile
+            # again when they met the cache a pack left.
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+        params, cache = jax.tree.map(spec, (self.params, self.cache))
+        W = self.args.blocks_per_seq  # the wide table only
+        i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+
+        def work():
+            t0 = time.monotonic()
+            for rows, t in shapes:
+                if self._pack_stop.is_set():
+                    return
+                try:
+                    program = self._block.prefill_batch.lower(
+                        self.cfg, params, cache, i32((rows, t)), i32((rows, W)), i32((rows,)),
+                        i32((rows,)), None, None, **self._prefill_kw).compile()
+                    # stack_rows picks a sequence's row out of the pack's
+                    # logits with an eager index, a small program a
+                    # [rows, V] shape: that one too exists before a request
+                    # meets the shape.
+                    logits = program.out_info[0]
+                    jnp.zeros(logits.shape, logits.dtype)[0].block_until_ready()
+                except Exception:  # noqa: BLE001 - the worker serves singles without it
+                    log.exception("packed prefill %dx%d did not compile", rows, t)
+                    continue
+                self._packed[(rows, t)] = _with_histogram(self._block, program)
+            log.info("packed prefill programs ready: %s (%.1f s on their thread)",
+                     " ".join(f"{r}x{t}" for r, t in sorted(self._packed)), time.monotonic() - t0)
+
+        self._pack_thread = threading.Thread(target=work, name="prefill-pack-compile", daemon=True)
+        self._pack_thread.start()
+
+    @property
+    def packed_ready(self) -> frozenset:
+        """The (rows, T) packed prefill programs that exist by now."""
+        return frozenset(self._packed)
 
     def _resolve_attention(self) -> tuple[str, str]:
         """→ (decode attention path, why it is not the one asked for).
@@ -327,15 +430,23 @@ class LocalRunner:
         # A dp rank is pinned to its chips by the spawner; inside its own
         # TPU world every rank's device ids start at 0 again.
         pinned = os.environ.get("TPU_VISIBLE_CHIPS", "all")
+        # The limit's one constant is a chip's: say when it is not this one's.
+        assumed = ("" if devs[0].device_kind in _OPS_PER_BYTE or not self.pack_limit_tokens
+                   else " (at the v5e's operations a byte: this device_kind has no entry)")
         return (
             f"platform={devs[0].platform} device_kind={devs[0].device_kind!r} "
             f"devices={len(devs)} of {jax.device_count()} "
             f"ids={','.join(str(d.id) for d in devs)} visible_chips={pinned} "
             f"dtype={a.dtype} quant={a.quant} kv_quant={a.kv_quant} "
             f"attention: prefill={prefill} decode={decode} spec_verify={spec}{block}{experts}{hbm}"
+            f"{f' prefill_pack_row={self.pack_row_tokens}' if self.pack_row_tokens else ''}"
+            f" prefill_pack<={self.pack_limit_tokens} tok{assumed}"
         )
 
     def stop(self) -> None:
+        self._pack_stop.set()
+        if self._pack_thread is not None:
+            self._pack_thread.join()  # at most the compile it is in
         self._refs.clear()
 
     # -- ref bookkeeping (must stay deterministic across hosts) -----------
@@ -386,13 +497,17 @@ class LocalRunner:
                       *, rid=None) -> StepRef:
         bank, slots = self._lora_operands(adapter_slots)
         self.prefill_dispatches += 1
-        logits, self.cache, hist = self._prefill_batch(
-            self.cfg, self.params, self.cache,
-            jnp.asarray(toks), jnp.asarray(tables),
-            jnp.asarray(starts), jnp.asarray(tlens),
-            bank, slots,
-            **self._prefill_kw,
-        )
+        # A pack runs the program compiled at start from its shape
+        # (_start_pack_compiles: the wide table, no adapter bank); anything
+        # else the jitted entry point.
+        run = None
+        if bank is None and np.shape(tables)[1] == self.args.blocks_per_seq:
+            run = self._packed.get(np.shape(toks))
+        if run is None:
+            run = functools.partial(self._prefill_batch, self.cfg, **self._prefill_kw)
+        logits, self.cache, hist = run(
+            self.params, self.cache, jnp.asarray(toks), jnp.asarray(tables),
+            jnp.asarray(starts), jnp.asarray(tlens), bank, slots)
         return self._new_ref((logits,), rid, hist, prefill=True)
 
     def prefill_chunk(self, toks, table, pos, tlen, adapter_slot=None,

@@ -26,6 +26,29 @@ def _fresh_memory_stores():
     store_mod.reset_memory_stores()
 
 
+@pytest.fixture(autouse=True)
+def _packed_prefill_programs_exist_at_start(monkeypatch):
+    """The suite's engines run what a worker runs: an admission wave's
+    prefills go out packed where the model's limit allows it. A worker's
+    packed programs come up on a thread of the runner's within its first
+    minute and it sends singles until then; here ``start`` waits for them, so
+    two engines a test compares run the same programs. Which requests share
+    a wave is still the machine's timing, and a row's logits out of a packed
+    program differ in their last bits from the same row's alone (1e-6 in
+    float32 here): a test that holds streams byte for byte across engines
+    fixes its wave with ``engine_waves.one_wave``."""
+    from dynamo_tpu.engine.runner import LocalRunner
+
+    start = LocalRunner._start_pack_compiles
+
+    def start_and_wait(self):
+        start(self)
+        if self._pack_thread is not None:
+            self._pack_thread.join()
+
+    monkeypatch.setattr(LocalRunner, "_start_pack_compiles", start_and_wait)
+
+
 @pytest.fixture
 def anyio_backend():
     return "asyncio"

@@ -10,6 +10,7 @@ import asyncio
 
 import numpy as np
 import pytest
+from engine_waves import one_wave
 
 import jax
 import jax.numpy as jnp
@@ -628,28 +629,29 @@ def test_engine_embed_chunk_pools_long_input():
     asyncio.run(go())
 
 
-def test_engine_packed_prefill_matches_singles():
-    """prefill_batch_max>1 (the multi-row packed path, non-default since
-    async admission made singles the default) must produce the same
-    greedy tokens as the singles path."""
+def test_engine_packed_prefill_matches_singles(monkeypatch):
+    """A wave's suffixes packed as rows of one dispatch (the programs the
+    runner compiled at start) must produce the same greedy tokens as the
+    singles a runner without those programs falls back to."""
 
-    async def run_wave(batch_max):
-        engine = await TpuEngine(
-            make_args(prefill_batch_max=batch_max, max_num_seqs=8, num_kv_blocks=128)
-        ).start()
+    async def run_wave(packs: bool):
+        engine = TpuEngine(make_args(max_num_seqs=8, num_kv_blocks=128))
+        if not packs:
+            monkeypatch.setattr(engine._runner, "_start_pack_compiles", lambda: None)
+        await engine.start()
         try:
+            assert engine._runner.packed_ready == (frozenset({(2, 32)}) if packs else frozenset())
             prompts = [[(7 * j + i) % 500 + 1 for j in range(10 + i)] for i in range(5)]
-            outs = await asyncio.gather(
-                *(run_one(engine, greedy_request(p, 6)) for p in prompts)
-            )
-            return [collect_tokens(o) for o in outs]
+            outs = await one_wave(engine, [run_one(engine, greedy_request(p, 6)) for p in prompts])
+            return [collect_tokens(o) for o in outs], dict(engine.prefill_dispatch_rows)
         finally:
             await engine.stop()
 
     async def go():
-        packed = await run_wave(8)
-        singles = await run_wave(1)
+        packed, rows = await run_wave(True)
+        singles, rows_singles = await run_wave(False)
         assert packed == singles
         assert all(len(t) == 6 for t in packed)
+        assert rows == {2: 2, 1: 1} and rows_singles == {1: 5}
 
     asyncio.run(go())

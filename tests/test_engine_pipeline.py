@@ -12,6 +12,7 @@ window sizes, under preemption, mid-stream cancel, and prefill-only
 import asyncio
 
 import pytest
+from engine_waves import one_wave
 
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 from dynamo_tpu.engine.engine import TpuEngine
@@ -85,9 +86,9 @@ def mixed_workload(K: int):
 async def run_workload(eargs: EngineArgs, K: int):
     engine = await TpuEngine(eargs).start()
     try:
-        return await asyncio.gather(
-            *(run_stream(engine, r) for r in mixed_workload(K))
-        )
+        # One admission wave at every depth: which prefills share a packed
+        # dispatch is then the workload's, not the machine's timing.
+        return await one_wave(engine, [run_stream(engine, r) for r in mixed_workload(K)])
     finally:
         await engine.stop()
 
@@ -127,10 +128,10 @@ def test_pipeline_depth_preemption_golden():
             depth, max_num_seqs=2, num_kv_blocks=24, max_model_len=64,
         )).start()
         try:
-            return await asyncio.gather(
+            return await one_wave(engine, [
                 run_stream(engine, request([1, 2, 3, 4], 20, logprobs=True)),
                 run_stream(engine, request([9, 8, 7, 6], 20, logprobs=True)),
-            )
+            ])
         finally:
             await engine.stop()
 

@@ -21,6 +21,7 @@ backend sees the same truncated token stream either way.
 import asyncio
 
 import pytest
+from engine_waves import one_wave
 
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 from dynamo_tpu.engine.drafter import NgramDrafter
@@ -96,9 +97,9 @@ def mixed_workload():
 async def run_workload(eargs: EngineArgs, reqs=None):
     engine = await TpuEngine(eargs).start()
     try:
-        out = await asyncio.gather(
-            *(run_stream(engine, r) for r in (reqs or mixed_workload()))
-        )
+        # One admission wave, spec on or off: which prefills share a packed
+        # dispatch is then the workload's, not the machine's timing.
+        out = await one_wave(engine, [run_stream(engine, r) for r in (reqs or mixed_workload())])
         stats = {
             "rows": engine.total_spec_rows,
             "proposed": engine.total_spec_proposed,
@@ -197,10 +198,10 @@ def test_spec_preemption_golden():
             S, max_num_seqs=2, num_kv_blocks=24, max_model_len=64,
         )).start()
         try:
-            return await asyncio.gather(
+            return await one_wave(engine, [
                 run_stream(engine, request(LOOPY[0][:4], 20, logprobs=True)),
                 run_stream(engine, request(LOOPY[1][:4], 20, logprobs=True)),
-            )
+            ])
         finally:
             await engine.stop()
 

@@ -29,6 +29,7 @@ import asyncio
 import random
 
 import pytest
+from engine_waves import one_wave
 
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 from dynamo_tpu.engine.drafter import (
@@ -101,9 +102,9 @@ def mixed_workload():
 async def run_workload(eargs: EngineArgs, reqs=None):
     engine = await TpuEngine(eargs).start()
     try:
-        out = await asyncio.gather(
-            *(run_stream(engine, r) for r in (reqs or mixed_workload()))
-        )
+        # One admission wave, trees on or off: which prefills share a packed
+        # dispatch is then the workload's, not the machine's timing.
+        out = await one_wave(engine, [run_stream(engine, r) for r in (reqs or mixed_workload())])
         stats = {
             "rows": engine.total_spec_rows,
             "proposed": engine.total_spec_proposed,
@@ -250,10 +251,10 @@ def test_tree_preemption_golden():
             max_model_len=64,
         )).start()
         try:
-            return await asyncio.gather(
+            return await one_wave(engine, [
                 run_stream(engine, request(BRANCHY[0][:4], 20, seed=1)),
                 run_stream(engine, request(BRANCHY[1][:4], 20, seed=2)),
-            )
+            ])
         finally:
             await engine.stop()
 

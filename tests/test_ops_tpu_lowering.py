@@ -505,3 +505,39 @@ def test_lfm2_programs_copy_no_pool_and_no_expert_stack_on_v5e(v5e, program):
         if shape in big and opcode not in ("scatter", "fusion", "custom-call", "while", "conditional", "call"):
             found.append(line.strip()[:140])
     assert not found, "\n".join(found)
+
+
+@pytest.mark.parametrize("block,rows,t", [("lfm2", 4, 384), ("longcat", 4, 96)])
+def test_a_packed_wave_is_one_grouped_product_a_layer_and_no_larger_than_a_single(v5e, block, rows, t):
+    """A four-row prefill program of each expert block (the largest the LFM2
+    cell's limit allows, ``EngineArgs.pack_shapes``; the LongCat block packs
+    behind a narrower table than its cell's; ISSUE 38), behind a 4,096-token table: every
+    grouped product takes the whole pack's assignment rows (tokens x choices
+    in one call a layer and matrix, not one a 512-token part, which would
+    stream the layer's experts again), and the program's temporaries are no
+    larger than the 2,048-token single's, so ``hbm_peak_gb`` has no cause to
+    rise."""
+    from dynamo_tpu.engine import lfm2, longcat
+
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    if block == "lfm2":
+        cfg, mod, kw, N = dataclasses.replace(_lfm2(4), num_dense_layers=1), lfm2, {"attn_impl": "pallas"}, 5632
+        expert_layers = 3
+    else:
+        cfg, mod, kw, N, expert_layers = _longcat(), longcat, {}, 2048, 1
+    params = _abstract(jax.eval_shape(lambda: mod.init_params(cfg, jax.random.PRNGKey(0))), S)
+    cache = _abstract(jax.eval_shape(lambda: mod.init_kv_cache(cfg, N, LBS)), S)
+    W = 4096 // LBS
+
+    def i32(*s):
+        return S(s, jnp.int32)
+
+    def compiled(b, tt):
+        return mod.prefill_batch.lower(
+            cfg, params, cache, i32(b, tt), i32(b, W), i32(b), i32(b), experts="gmm", **kw).compile()
+
+    pack, single = compiled(rows, t), compiled(1, 2048)
+    products = re.findall(r"%gmm[.\d]* = f32\[(\d+),\d+\]", pack.as_text())
+    assignment_rows = rows * t * min(cfg.num_experts_per_token, cfg.num_experts)
+    assert products == [str(assignment_rows)] * (3 * expert_layers), products
+    assert pack.memory_analysis().temp_size_in_bytes <= single.memory_analysis().temp_size_in_bytes

@@ -237,26 +237,25 @@ def test_worker_cli_peer_fetch_spawned_processes():
 
             asyncio.run(drive())
             # One of the two workers logged the peer fetch (id→process
-            # mapping is arbitrary, so accept either; select-poll the
-            # pipes — logs may lag the stream end slightly).
-            import select
+            # mapping is arbitrary, so accept either; logs may lag the
+            # stream end slightly). Non-blocking reads of every line there
+            # is: a select on the pipe does not see the lines Python has
+            # already buffered, and a worker logs several at once.
+            import os
             import time
 
             needle = "peer prefix: fetched 5 blocks"
             deadline = time.monotonic() + 5
             found = False
+            for p in (wa, wb):
+                os.set_blocking(p.proc.stdout.fileno(), False)
             while not found and time.monotonic() < deadline:
-                found = any(needle in ln for p in (wa, wb) for ln in p.lines)
-                if found:
-                    break
-                ready, _, _ = select.select(
-                    [wa.proc.stdout, wb.proc.stdout], [], [], 0.2
-                )
                 for p in (wa, wb):
-                    if p.proc.stdout in ready:
-                        ln = p.proc.stdout.readline()
-                        if ln:
-                            p.lines.append(ln)
+                    while ln := p.proc.stdout.readline():
+                        p.lines.append(ln)
+                found = any(needle in ln for p in (wa, wb) for ln in p.lines)
+                if not found:
+                    time.sleep(0.1)
             assert found, "no worker logged the peer prefix fetch"
 
 

@@ -3,6 +3,7 @@ counters, the names the benchmark's reducers search for, and the scheduler's
 phases in a profiler trace (ISSUE 26). CPU, test-tiny."""
 
 import asyncio
+import re
 import time
 
 import jax
@@ -411,7 +412,9 @@ def test_longcat_expert_counters_ride_the_token_fetches(fresh_recorder):
         for l in range(cfg.num_layers) for e in range(cfg.num_experts))
     assert held == kinds["held"]
     calls = {p: counter(reg, "moe_expert_calls_total", program=p) for p in ("prefill", "decode")}
-    assert calls["prefill"] == cfg.num_layers * 2  # two prompts, a part each
+    # Two prompts: a part each, or one part for both where one wave admitted
+    # them and they went out packed (a pack is one grouped product a layer).
+    assert calls["prefill"] in (cfg.num_layers, cfg.num_layers * 2)
     assert calls["decode"] >= cfg.num_layers * 6 and calls["decode"] % cfg.num_layers == 0
     touched = sum(counter(reg, "moe_experts_touched_total", program=p) for p in calls)
     assert 0 < touched <= min(held, sum(calls.values()) * cfg.num_experts)
@@ -497,3 +500,33 @@ def test_conv_state_and_pool_series_are_pinned_names(fresh_recorder):
     assert counter_of(pages["lfm2-tiny"], "kv_pool_bytes", 'kind="kv"') > 0
     assert counter_of(pages["test-tiny"], "kv_pool_bytes", 'kind="kv"') > 0
     assert 'kind="conv"' not in pages["test-tiny"] and "engine_conv_state_resumes_total{" not in pages["test-tiny"]
+
+
+def test_prefill_dispatch_rows_series_are_pinned_names(fresh_recorder):
+    """engine_prefill_dispatch_rows_total{rows} and engine_prefill_rows_total
+    (docs/observability.md): a wave of two short prompts is one dispatch of
+    two rows once the runner has the program, and the start line names the
+    limit the pack stayed under."""
+    reg = MetricsRegistry()
+
+    async def go():
+        engine = TpuEngine(make_args())
+        engine.bind_metrics(reg)
+        await engine.start()
+        try:
+            assert engine._runner.packed_ready == {(2, 32)}
+            # Both arrive while the scheduler thread is held: one wave.
+            hold = asyncio.ensure_future(engine.run_on_engine_thread(lambda: time.sleep(0.3)))
+            await asyncio.sleep(0.05)
+            await serve(engine, [range(1, 20), range(3, 25)], max_tokens=3)
+            await hold
+            await settle(engine)
+            return engine._runner._start_line("")
+        finally:
+            await engine.stop()
+
+    line = asyncio.run(go())
+    page = reg.render()
+    assert counter_of(page, "engine_prefill_dispatch_rows_total", 'rows="2"') == 1
+    assert "dynamo_tpu_engine_prefill_rows_total 2" in page
+    assert re.search(r" prefill_pack<=\d+ tok( \(|$)", line)
