@@ -70,7 +70,24 @@ Params = dict[str, Any]
 MOE_CHUNK_ROWS = 6144
 # Float32 score elements one head group of the prefill attention may hold.
 _SCORE_ELEMS = 48 << 20
-_GMM_TILING = (128, 1024, 1024)
+# The grouped product's tiles come from its operands (``gmm_tiling``). Rows:
+# 128 a tile, which ``_moe_tokens`` pads its assignment rows to; at one K tile
+# 64 read within a point of it in a decode call and 2-3 points under in a
+# pack, 256 3-5 points under in a decode call (PERF.md section 6, PR 41).
+_GMM_ROW_TILE = 128
+# Bytes the kernel's buffers may take by ``gmm_tiling``'s count: the weight
+# tile and the activation tile twice each (the pipeline fetches the next beside
+# the one in use), the float32 output tile twice and the accumulator. The v5e
+# compiler gives a kernel 16.00 MiB of scoped VMEM and wants about 1.2 MiB of
+# its own beside these (a set counted at 15.75 MiB was refused at "16.95M and
+# limit 16.00M": tests/test_ops_tpu_lowering.py compiles without a chip), so
+# 12 MiB leaves it three times that.
+_GMM_VMEM_BUDGET = 12 << 20
+# The least bytes of a weight row a tile narrower than the row may take: a
+# tile's rows are fetched apart, ``tn x itemsize`` bytes each, and 512 B rows
+# ([6144, 256] of bf16 rows 4 KiB apart) read 3-5% under whole rows inside the
+# layer where 1 KiB and 1.5 KiB rows read level with them (PERF.md, PR 41).
+_GMM_MIN_FETCH_BYTES = 1024
 # The seeded router's logits have this deviation (a normed stream times
 # w_router): at 1 the softmax over 768 outputs is flat, twelve choices hold
 # 8% of the mass and the whole expert block moves 4% of the residual stream,
@@ -275,22 +292,68 @@ def expert_impl() -> str:
     return "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
 
 
+def _tile_sizes(d: int) -> list[int]:
+    """The tiles that leave no remainder in a dimension of ``d``, widest
+    first: ``d`` itself, then the multiples of 128 lanes that divide it."""
+    return [d] + [t for t in range(d // 128 * 128, 0, -128) if d % t == 0 and t != d]
+
+
+def _gmm_buffer_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """What ``_GMM_VMEM_BUDGET`` counts: the weight and activation tiles twice
+    each, the float32 output tile twice and the accumulator."""
+    return 2 * tk * tn * itemsize + 2 * tm * tk * itemsize + 3 * tm * tn * 4
+
+
+def gmm_tiling(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """The megablox kernel's (row, K, N) tiles for a product of ``[M, k]`` rows
+    by ``[k, n]`` groups of ``itemsize``-byte elements (M a multiple of the
+    row tile: ``_moe_tokens`` pads to it). The kernel's grid is (N tiles, the
+    row tiles that hold a real row once a group in them, K tiles) with K
+    innermost, and its pipeline skips a fetch whose block is the one it holds.
+
+    **One K tile** wherever a ``[k, tn]`` weight tile of rows worth fetching
+    (``_GMM_MIN_FETCH_BYTES``, or whole rows) fits ``_GMM_VMEM_BUDGET`` beside
+    the rest: then a row tile's activations are fetched once and not once a K
+    step, a group's weights once and not once a row tile it straddles (with K
+    steps between two visits of a group the block cycles and is read again),
+    no K step is a remainder the kernel has to mask in float32, and the grid
+    has a K-th of the steps. ``tn`` is the widest multiple of 128 that divides
+    ``n`` and fits, so no N tile is part padding either. **Elsewhere** (a K so
+    deep that only a sliver of N fits beside it): the widest such ``tn`` some
+    K tile fits beside, whole rows first, then the deepest ``tk`` that divides
+    ``k``: with K tiled the activations' re-read costs ``row tile / tn`` of
+    the weights' bytes, and a tile of whole rows is one contiguous fetch. On
+    the chip, inside the layer (PERF.md section 6, PR 41): (128, 2048, 768) and
+    (128, 1536, 1024) took 10.5% off a decode call's three products at
+    ``K, N`` 2048, 1536 and 17.5% off a pack's against (128, 1024, 1024);
+    (128, 1024, 2048) 1.5-2% at 6144, 2048, where (128, 6144, 256) added 3%."""
+    tm = _GMM_ROW_TILE
+
+    def fits(tk: int, tn: int) -> bool:
+        return _gmm_buffer_bytes(tm, tk, tn, itemsize) <= _GMM_VMEM_BUDGET
+
+    depths, widths = _tile_sizes(k), _tile_sizes(n)
+    one_k = [(k, tn) for tn in widths if tn == n or tn * itemsize >= _GMM_MIN_FETCH_BYTES]
+    shapes = one_k + [(tk, tn) for tn in widths for tk in depths]
+    tk, tn = next((s for s in shapes if fits(*s)), (depths[-1], widths[-1]))
+    return tm, tk, tn
+
+
 @functools.partial(jax.jit, static_argnames=("impl",))
 def grouped_expert_matmul(x, w, group_sizes, *, impl: str = "ragged_dot"):
     """x [M, K] sorted by group, w [E, K, N], ``group_sizes`` [E] → [M, N]
     float32: rows of group e times w[e]. Rows past the groups' total are not
     computed (and hold nothing to rely on). ``impl`` "gmm" (``expert_impl``
     on the chip) is the megablox grouped product: only tiles that hold a
-    real row run, so an expert no token chose has its weights left unread;
-    "gmm_interpret" runs that kernel in interpret mode (tests)."""
+    real row run, so an expert no token chose has its weights left unread,
+    and under ``gmm_tiling``'s tiles an expert some token chose has them read
+    once; "gmm_interpret" runs that kernel in interpret mode (tests)."""
     if impl == "ragged_dot":
         return lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    K, N = w.shape[1:]
-    tiling = (_GMM_TILING[0], min(_GMM_TILING[1], K), min(_GMM_TILING[2], N))
-    return gmm(x, w, group_sizes, preferred_element_type=jnp.float32, tiling=tiling,
-               interpret=(impl == "gmm_interpret"))
+    return gmm(x, w, group_sizes, preferred_element_type=jnp.float32,
+               tiling=gmm_tiling(*w.shape[1:], w.dtype.itemsize), interpret=(impl == "gmm_interpret"))
 
 
 def route(xt: jax.Array, lp: dict, cfg: ModelConfig):
@@ -339,7 +402,7 @@ def _moe_tokens(xt, valid, lp: dict, cfg: ModelConfig, impl: str):
         place = jnp.argsort(order).reshape(N, k)        # where each assignment went
         sizes = jnp.sum(flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :],
                         axis=0, dtype=jnp.int32)
-        rows = -(-N * min(k, E) // _GMM_TILING[0]) * _GMM_TILING[0]
+        rows = -(-N * min(k, E) // _GMM_ROW_TILE) * _GMM_ROW_TILE
         tok = jnp.pad(order, (0, max(0, rows - N * k)))[:rows] // k
         xs = xt[tok]                                     # [rows, D]
         # This layer's groups among every layer's, the rest empty.

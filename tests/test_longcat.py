@@ -216,6 +216,39 @@ def test_no_token_is_dropped_whatever_the_imbalance(impl):
     np.testing.assert_allclose(np.asarray(y)[:20], (want + zero)[:20], rtol=2e-4, atol=2e-5)
 
 
+# What a cell's groups look like to the kernel, at sizes the interpreter
+# walks in seconds: (K, N, group sizes, rows, the tiles the rule must give).
+GROUPED_CASES = {
+    # groups that end inside a row tile, an empty one between, rows past the total
+    "groups_straddle_row_tiles": (192, 384, [100, 60, 0, 200, 0, 30], 512, (128, 192, 384)),
+    # the stacked [L*E] form: every layer's groups empty but one layer's
+    "one_layer_of_a_stack": (128, 256, [0, 0, 0, 0, 130, 0, 5, 140, 0, 0, 0, 0], 384, (128, 128, 256)),
+    # published widths that 1,024 does not divide: one K tile, N tiles that divide N
+    "k_1536_n_2048": (1536, 2048, [7, 0, 250, 1, 90], 384, (128, 1536, 512)),
+    "k_2048_n_1536": (2048, 1536, [129, 0, 0, 127, 3], 384, (128, 2048, 512)),
+    # no [K, 128] tile fits: K in tiles that divide it, a group's rows over two of them
+    "k_in_two_tiles": (6144, 256, [60, 150, 0, 40], 256, (128, 3072, 256)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_the_grouped_product_under_its_own_tiles_is_the_ragged_product(case):
+    """``gmm_interpret`` under ``gmm_tiling``'s tiles against ``lax.ragged_dot``
+    in float32: every row of every group, whichever tiles it falls in."""
+    K, N, sizes, rows, tiles = GROUPED_CASES[case]
+    assert longcat.gmm_tiling(K, N, 4) == tiles
+    kx, kw = jax.random.split(jax.random.PRNGKey(11))
+    x = jax.random.normal(kx, (rows, K), jnp.float32)
+    w = jax.random.normal(kw, (len(sizes), K, N), jnp.float32) * K ** -0.5
+    groups = jnp.asarray(sizes, jnp.int32)
+    live = sum(sizes)
+    assert live < rows and any(sum(sizes[:i]) % tiles[0] for i in range(1, len(sizes)))
+    got = longcat.grouped_expert_matmul(x, w, groups, impl="gmm_interpret")
+    want = longcat.grouped_expert_matmul(x, w, groups, impl="ragged_dot")
+    assert got.shape == (rows, N) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got)[:live], np.asarray(want)[:live], rtol=2e-5, atol=2e-5)
+
+
 # -- the shares add up -----------------------------------------------------------
 
 

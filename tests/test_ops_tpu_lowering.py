@@ -16,6 +16,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import glob
+import json
+import os
 import re
 
 import pytest
@@ -373,15 +376,78 @@ def test_latent_decode_kernel_compiles_for_v5e(v5e):
     fn.lower(*args).compile()
 
 
-@pytest.mark.parametrize("rows,k,n", [(1536, 6144, 2048), (6144, 2048, 6144)])
-def test_grouped_expert_matmul_compiles_for_v5e(v5e, rows, k, n):
+# The grouped product's calls in the two expert cells: (assignment rows, K, N,
+# groups in the stack): LongCat's decode window (128 rows x 12) and a 512-token
+# part of a chunk, 16 experts of 4 layers in one stack; LFM2's decode window
+# (128 rows x 4) and a four-row pack or a 1,024-token part, a layer's 64 experts.
+GROUPED_CALLS = [(1536, 6144, 2048, 64), (6144, 2048, 6144, 64),
+                 (512, 2048, 1536, 64), (512, 1536, 2048, 64), (4096, 2048, 1536, 64), (4096, 1536, 2048, 64)]
+
+
+@pytest.mark.parametrize("rows,k,n,groups", GROUPED_CALLS)
+def test_grouped_expert_matmul_compiles_for_v5e(v5e, rows, k, n, groups):
     from dynamo_tpu.engine.longcat import grouped_expert_matmul
 
     S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
     grouped_expert_matmul.lower(
-        S((rows, k), jnp.bfloat16), S((16, k, n), jnp.bfloat16), S((16,), jnp.int32),
+        S((rows, k), jnp.bfloat16), S((groups, k, n), jnp.bfloat16), S((groups,), jnp.int32),
         impl="gmm",
     ).compile()
+
+
+EXPERT_CONFIGS = ["lfm2-24b-a2b-pp4", "longcat-flash-omni-ep32", "rehearse-lfm2-tiny", "rehearse-longcat-tiny"]
+
+
+@functools.cache
+def _expert_widths() -> dict[str, tuple[int, int]]:
+    """(hidden, expert intermediate) of every benchmark configuration that has experts."""
+    from chipbench import model_maps
+
+    out = {}
+    for path in sorted(glob.glob(os.path.join(os.path.dirname(__file__), "..", "chipbench", "configs", "*.json"))):
+        with open(path) as f:
+            cfg = model_maps.model_config(json.load(f))
+        if cfg.block in ("longcat", "lfm2"):
+            out[os.path.basename(path)[:-5]] = (cfg.hidden_size, cfg.moe_intermediate_size)
+    return out
+
+
+@pytest.mark.parametrize("matrix", ["gate_up", "down"])
+@pytest.mark.parametrize("config", EXPERT_CONFIGS)
+def test_the_grouped_products_tiles_come_from_its_operands(config, matrix):
+    """``longcat.gmm_tiling`` at every (K, N) a benchmark configuration runs,
+    in the bf16 the cells serve: one K tile beside an N tile that divides N,
+    is whole lane tiles and is at least the stated fetch wide (or N itself),
+    else whole rows of N beside a K tile that divides K; no remainder anywhere,
+    the row tile ``_moe_tokens`` pads to, and the buffers' bytes under the
+    stated budget."""
+    from dynamo_tpu.engine import longcat
+
+    widths = _expert_widths()
+    assert sorted(widths) == EXPERT_CONFIGS
+    D, ie = widths[config]
+    K, N = (D, ie) if matrix == "gate_up" else (ie, D)
+    tm, tk, tn = longcat.gmm_tiling(K, N, 2)
+    assert tm == longcat._GMM_ROW_TILE == 128
+    assert K % tk == 0 and N % tn == 0 and (tn % 128 == 0 or tn == N)
+    assert (tk == K and (tn == N or tn * 2 >= longcat._GMM_MIN_FETCH_BYTES)) or (tn == N and tk % 128 == 0)
+    assert longcat._gmm_buffer_bytes(tm, tk, tn, 2) <= longcat._GMM_VMEM_BUDGET < 16 << 20
+    want = {(2048, 1536): (2048, 768), (1536, 2048): (1536, 1024), (6144, 2048): (1024, 2048), (2048, 6144): (2048, 1024)}
+    assert (tk, tn) == want.get((K, N), (K, N))
+
+
+@pytest.mark.parametrize("k,n,itemsize,want", [
+    (16384, 2048, 2, (128, 1024, 2048)),   # no [K, 128] tile fits: whole rows of N, the deepest K tile that divides K
+    (6144, 2048, 4, (128, 512, 2048)),     # float32 weights: half the elements a tile
+    (2048, 1536, 1, (128, 2048, 1536)),    # int8 weights: the whole matrix is one tile
+    (96, 40, 4, (128, 96, 40)),            # narrower than a lane tile: the dimension itself
+])
+def test_the_tiling_rule_off_the_cells_shapes(k, n, itemsize, want):
+    from dynamo_tpu.engine import longcat
+
+    tm, tk, tn = got = longcat.gmm_tiling(k, n, itemsize)
+    assert got == want and k % tk == 0 and n % tn == 0
+    assert longcat._gmm_buffer_bytes(tm, tk, tn, itemsize) <= longcat._GMM_VMEM_BUDGET
 
 
 @pytest.mark.parametrize("program", ["decode_window", "prefill_chunk_2048", "prefill_packed_256"])
