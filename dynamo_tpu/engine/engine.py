@@ -152,7 +152,7 @@ class _Seq:
         "kv_written", "export", "export_meta", "inject", "dead",
         "slot", "first_pend", "t_admit",
         "t_blocked", "blocked_why", "chunks", "t_dispatched", "wave",
-        "t_first", "first_waited",
+        "t_first", "first_waited", "first_unread_s",
         "spec_ema", "spec_cool", "draft_state",
         "export_handle", "export_stream", "export_pub_blocks",
         "grammar", "grammar_state", "grammar_eos_bits",
@@ -197,7 +197,8 @@ class _Seq:
         # _dispatch_prefills for its wave (t_dispatched) with
         # wave = (sequences in the wave, decode windows in flight then),
         # and the first-token sample's arrival on the host (t_first;
-        # first_waited = the host had to wait for the fetch).
+        # first_waited = the host had to wait for the fetch; otherwise
+        # first_unread_s is how long the sample had lain ready by then).
         self.t_blocked: float | None = None
         self.blocked_why = ""
         self.chunks = 0
@@ -205,6 +206,7 @@ class _Seq:
         self.wave = (0, 0)
         self.t_first: float | None = None
         self.first_waited = False
+        self.first_unread_s = 0.0
         # Finished/cancelled (set by _finish). In-flight decode windows
         # drain after the fact; dead rows' outputs are discarded.
         self.dead = False
@@ -389,7 +391,7 @@ class _First:
     """One dispatched admission wave's first-token sample (not yet
     fetched). Entries: (seq, row) into the wave's padded sample batch."""
 
-    __slots__ = ("entries", "out_d", "lps_d", "top_ref", "routed")
+    __slots__ = ("entries", "out_d", "lps_d", "top_ref", "routed", "t_ready")
 
     def __init__(self, entries: list[tuple[_Seq, int]], out_d, lps_d, top_ref,
                  routed: list | None = None):
@@ -397,6 +399,9 @@ class _First:
         self.out_d = out_d
         self.lps_d = lps_d
         self.top_ref = top_ref
+        # When the scheduler thread first saw the sample ready on the device
+        # (_probe); None until then, so still None at a fetch that blocks.
+        self.t_ready: float | None = None
         # Routing histograms of the prefill dispatches since the last wave
         # (engine/longcat.py; they ride this wave's fetch).
         self.routed = routed or []
@@ -414,24 +419,8 @@ def register_engine_metrics(registry) -> dict:
     (bind_metrics) and the tools/check_metrics.py catalog guard."""
     metrics = (
         registry.gauge(
-            "engine_inflight_windows",
-            "Decode windows dispatched on device but not yet drained",
-        ),
-        registry.gauge(
-            "engine_pending_first_fetches",
-            "Admission first-token sample fetches in flight",
-        ),
-        registry.gauge(
             "engine_prefill_pad_ratio",
             "Cumulative dispatched/true prefill token ratio (bucket padding waste)",
-        ),
-        registry.counter(
-            "engine_spec_proposed_total",
-            "Draft tokens proposed to speculative verify passes",
-        ),
-        registry.counter(
-            "engine_spec_accepted_total",
-            "Proposed draft tokens accepted by speculative verification",
         ),
         registry.gauge(
             "engine_spec_accept_rate",
@@ -516,28 +505,69 @@ def register_engine_metrics(registry) -> dict:
             "byte-identical under greedy sampling)",
         ),
         registry.counter(
-            "tier_g4_hits_total",
-            "G4 fleet-shared pool lookups that found the block file "
-            "(possibly written by a PEER engine — the cross-engine "
-            "dedup payoff)",
-        ),
-        registry.counter(
-            "tier_g4_evictions_total",
-            "G4 fleet-pool files pruned by this engine's oldest-mtime "
-            "capacity sweep of the SHARED directory",
-        ),
-        registry.counter(
-            "tier_g4_dedup_blocks_total",
-            "G4 puts/spill-adoptions skipped because a peer engine "
-            "already wrote the identical salted-hash block file",
-        ),
-        registry.counter(
             "engine_step_phase_seconds_total",
             "Scheduler-thread wall seconds by step-loop phase (idle, "
-            "admission, prefill_dispatch, first_dispatch, decode_dispatch, "
-            "drain_sync, drain_ready, first_sample, emit, ...): "
-            "drain_sync and first_sample wait on a device fetch, and a "
-            "dispatch phase blocks while the device's queue is full",
+            "housekeeping, admission, admit_alloc, prefill_dispatch, "
+            "first_dispatch, stack_rows, plan, decode_dispatch, drain_sync, "
+            "drain_ready, first_sample, emit, gauges, ...). The thread is in "
+            "exactly one phase from its first line to its last, so they sum "
+            "to engine_sched_wall_seconds_total; drain_sync and first_sample "
+            "wait on a device fetch, and a dispatch phase blocks while the "
+            "device's queue is full",
+        ),
+        registry.counter(
+            "engine_step_phase_cpu_seconds_total",
+            "CPU seconds (time.thread_time) the scheduler thread burned in "
+            "each step-loop phase; they sum to engine_sched_cpu_seconds_total, "
+            "and a phase's wall seconds less these is what it waited",
+        ),
+        registry.counter(
+            "engine_step_phase_total",
+            "Times the scheduler thread left each step-loop phase "
+            "(decode_dispatch: one a decode window dispatched)",
+        ),
+        registry.counter(
+            "engine_sched_wall_seconds_total",
+            "Wall seconds (time.perf_counter) since the scheduler thread began",
+        ),
+        registry.counter(
+            "engine_admission_stops_total",
+            "Steps whose admission pass began with requests waiting, by why it "
+            "stopped: empty = the queue was drained, budget = "
+            "admission_budget_tokens ran out with slots and blocks to spare, "
+            "slots = max_num_seqs, blocks = the KV pool",
+        ),
+        registry.counter(
+            "engine_prefill_waves_total",
+            "Admission waves whose prefills were dispatched",
+        ),
+        registry.counter(
+            "engine_wave_windows_ahead_total",
+            "Decode windows dispatched and not yet drained when a wave's "
+            "prefills had gone out, summed over the waves: what a first token "
+            "waits behind on the device",
+        ),
+        registry.counter(
+            "engine_first_ready_unread_seconds_total",
+            "Seconds first-token samples lay ready on the device before the "
+            "scheduler thread had fetched them: from the first probe that saw "
+            "one ready to the end of its fetch (0 for a fetch that blocked)",
+        ),
+        registry.counter(
+            "engine_first_fetch_total",
+            "First-token sample fetches by who waited: device = the fetch "
+            "blocked on the device, host = the sample was ready before the "
+            "scheduler thread came for it",
+        ),
+        registry.counter(
+            "engine_device_dry_seconds_total",
+            "Seconds, while requests ran or waited, in which the device had "
+            "nothing dispatched left to run, bounded from both sides by a "
+            "probe of the last dispatched program's outputs: floor = from a "
+            "probe that found them ready to the next dispatch call, ceiling = "
+            "every interval between probes that did not end on a probe "
+            "finding them unready. The truth lies between; their gap is the "
+            "probes' spacing",
         ),
         registry.counter(
             "engine_sched_cpu_seconds_total",
@@ -656,6 +686,10 @@ class TpuEngine:
         "_export_fetches", "_drafter", "_step_no", "_spec_ticked",
         "phase_s", "phase_n", "_ctr_pushed", "_spec_depth_hist",
         "_migrations", "_anno", "_slots_blocked_sig",
+        "phase_cpu_s", "_cur", "_cur_t", "_cur_cpu", "_t_run",
+        "admission_stops", "prefill_waves", "wave_windows_ahead",
+        "first_ready_unread_s", "first_fetches", "device_dry_s",
+        "_dev_last", "_dev_open", "_dev_done", "_probe_t", "_probe_work",
     })
 
     def __init__(
@@ -829,12 +863,41 @@ class TpuEngine:
         # the tokens those dispatches delivered to a live sequence.
         self.total_decode_rows_dispatched = 0
         self.total_decode_rows_emitted = 0
-        # Host-side phase accounting: where the non-device half of the
-        # step time goes (engine_step_phase_seconds_total{phase}).
-        # Keys: idle / admission / prefill_dispatch / first_sample /
-        # decode_dispatch / drain_sync / emit / other.
+        # Host-side phase accounting: the scheduler thread is in exactly one
+        # phase (_enter) from _run's first line to its last, so phase_s sums
+        # to the thread's wall time and phase_cpu_s to its CPU time
+        # (engine_step_phase_{seconds,cpu_seconds}_total{phase}). Keys: idle /
+        # housekeeping / admission / admit_alloc / prefill_dispatch /
+        # first_dispatch / stack_rows / plan / decode_dispatch / drain_sync /
+        # drain_ready / first_sample / emit / gauges, and draft /
+        # spec_dispatch / single_step where those paths run.
         self.phase_s: dict[str, float] = collections.defaultdict(float)
+        self.phase_cpu_s: dict[str, float] = collections.defaultdict(float)
         self.phase_n: dict[str, int] = collections.defaultdict(int)
+        self._cur: str | None = None  # the open phase; None off the thread's run
+        self._cur_t = self._cur_cpu = self._t_run = 0.0
+        # Why an admission pass that began with requests waiting stopped.
+        self.admission_stops: dict[str, int] = dict.fromkeys(
+            ("empty", "budget", "slots", "blocks"), 0)
+        self.prefill_waves = 0
+        self.wave_windows_ahead = 0
+        # First-token samples: seconds they lay ready before their fetch
+        # ended, and fetches by who waited for whom.
+        self.first_ready_unread_s = 0.0
+        self.first_fetches: dict[str, int] = {"device": 0, "host": 0}
+        # The device's dry time (_probe): the outputs of the last program
+        # dispatched, whether a dispatch call is open (nothing reads as done
+        # until its outputs are here), and the last probe's time and findings.
+        self.device_dry_s: dict[str, float] = {"floor": 0.0, "ceiling": 0.0}
+        self._dev_last: Any = ()
+        self._dev_open = False
+        self._dev_done = False
+        self._probe_t = 0.0
+        self._probe_work = False
+        # runner.stack_rows, the eager slices that pick a wave's rows out of
+        # its prefills' logits, each behind the prefill just queued, runs
+        # inside runner.sample_rows: this engine's runner calls it in a phase.
+        self._runner.stack_rows = self._in_phase("stack_rows", self._runner.stack_rows)
         # Optional Prometheus series (worker bind_metrics), by name.
         # The engine keeps plain running totals; _ctr_pushed remembers
         # what each registry counter series has been fed, so _feed gives
@@ -890,13 +953,8 @@ class TpuEngine:
         for kind, per_block in self.args.pool_bytes_per_block().items():
             g["kv_pool_bytes"].set(per_block * self.args.num_kv_blocks, kind=kind)
         g["engine_kv_quant_enabled"].set(1 if self.args.kv_quant == "int8" else 0)
-        g["engine_inflight_windows"].set(self._inflight_windows())
-        g["engine_pending_first_fetches"].set(
-            sum(1 for it in self._fetchq if isinstance(it, _First)))
         g["engine_prefill_pad_ratio"].set(
             self.total_prefill_padded / max(1, self.total_prefilled))
-        feed("engine_spec_proposed_total", self.total_spec_proposed)
-        feed("engine_spec_accepted_total", self.total_spec_accepted)
         g["engine_spec_accept_rate"].set(
             self.total_spec_accepted / max(1, self.total_spec_proposed))
         g["engine_tokens_per_weight_pass"].set(
@@ -915,11 +973,6 @@ class TpuEngine:
             g["engine_lora_resident_adapters"].set(self._lora_pool.resident)
             feed("engine_lora_swap_total", self._lora_pool.pageins)
         g["engine_lora_gather_seconds"].set(self.total_lora_s)
-        if self.tiers.fleet is not None:
-            fl = self.tiers.fleet
-            feed("tier_g4_hits_total", fl.hits)
-            feed("tier_g4_evictions_total", fl.evictions)
-            feed("tier_g4_dedup_blocks_total", fl.dedup_blocks)
         for cls, n in self.total_preemptions_by.items():
             feed("engine_preemptions_total", n, **{"class": cls})
         # What the step loop did with its time, and the decode rows and
@@ -927,7 +980,19 @@ class TpuEngine:
         # one after give a window's own figures.
         for phase, secs in self.phase_s.items():
             feed("engine_step_phase_seconds_total", secs, phase=phase)
+            feed("engine_step_phase_cpu_seconds_total", self.phase_cpu_s[phase], phase=phase)
+            feed("engine_step_phase_total", self.phase_n[phase], phase=phase)
         feed("engine_sched_cpu_seconds_total", time.thread_time())
+        feed("engine_sched_wall_seconds_total", time.perf_counter() - self._t_run)
+        for reason, n in self.admission_stops.items():
+            feed("engine_admission_stops_total", n, reason=reason)
+        feed("engine_prefill_waves_total", self.prefill_waves)
+        feed("engine_wave_windows_ahead_total", self.wave_windows_ahead)
+        feed("engine_first_ready_unread_seconds_total", self.first_ready_unread_s)
+        for waited, n in self.first_fetches.items():
+            feed("engine_first_fetch_total", n, waited=waited)
+        for bound, secs in self.device_dry_s.items():
+            feed("engine_device_dry_seconds_total", secs, bound=bound)
         feed("engine_decode_row_steps_total", self.total_decode_rows_dispatched,
              kind="dispatched")
         feed("engine_decode_row_steps_total", self.total_decode_rows_emitted,
@@ -957,25 +1022,78 @@ class TpuEngine:
                 feed("moe_expert_tokens_total", int(n), layer=str(self._routed_layers[l]),
                      expert=str(off + e))
 
-    def _phase_open(self, key: str) -> float:
-        """Begin step-loop phase `key` → its t0. Opens the profiler
-        annotation ``sched.<key>``, so that a profiler trace holds the
-        scheduler thread's phases on the clock of the device's
-        operations; one check and no record while no trace is taken."""
-        self._anno = TraceAnnotation("sched." + key)
-        return time.perf_counter()
-
-    def _phase(self, key: str, t0: float, then: str | None = None) -> float:
-        """Accumulate perf_counter()-t0 into phase `key` and close its
-        annotation; → new t0, the start of phase `then` if one follows
-        at once."""
-        t1 = time.perf_counter()
-        self.phase_s[key] += t1 - t0
-        self.phase_n[key] += 1
-        if self._anno is not None:
+    def _enter(self, key: str | None) -> float:
+        """The scheduler thread leaves its phase for `key` (None at _run's
+        last line) → now. Adds what the two clocks, perf_counter and the
+        thread's own CPU time, gained since the phase opened to phase_s and
+        phase_cpu_s, closes the profiler annotation ``sched.<phase>`` and
+        opens the next, so that a profiler trace holds the thread's phases on
+        the clock of the device's operations (one check and no record while
+        no trace is taken), and probes the device. Something that interrupts
+        a phase (a drain, _admit_alloc) enters the phase it found when done."""
+        now, cpu = time.perf_counter(), time.thread_time()
+        cur = self._cur
+        if cur is not None:
+            self.phase_s[cur] += now - self._cur_t
+            self.phase_cpu_s[cur] += cpu - self._cur_cpu
+            self.phase_n[cur] += 1
             self._anno.__exit__(None, None, None)
-        self._anno = TraceAnnotation("sched." + then) if then else None
-        return t1
+        self._cur, self._cur_t, self._cur_cpu = key, now, cpu
+        self._anno = TraceAnnotation("sched." + key) if key else None
+        self._probe(now)
+        return now
+
+    def _in_phase(self, key: str, fn):
+        """`fn`, run in phase `key` between the caller's phase and itself."""
+        def run(*args):
+            back = self._cur
+            self._enter(key)
+            try:
+                return fn(*args)
+            finally:
+                self._enter(back)
+        return run
+
+    def _probe(self, now: float) -> None:
+        """Ask the device whether it has anything left to run. It runs what it
+        is sent in order, so it is done when the outputs of the last program
+        dispatched are ready, and busy while they are not. While requests run
+        or wait, the time since the last probe counts towards the dry time's
+        ceiling unless that program is still running now (then the device was
+        busy throughout: _dispatched asks before it takes the next program's
+        outputs), and towards its floor only if that probe found it done and
+        nothing was dispatched since: never an instant at which a dispatched
+        program could still run. Also stamps the first-token samples seen ready."""
+        ready = host_ready(self._dev_last)
+        work = bool(self._running or self._waiting)
+        if self._probe_work:
+            if ready:
+                self.device_dry_s["ceiling"] += now - self._probe_t
+            if self._dev_done and work:
+                self.device_dry_s["floor"] += now - self._probe_t
+        self._dev_done = ready and not self._dev_open
+        self._probe_t, self._probe_work = now, work
+        for item in self._fetchq:
+            if type(item) is _First and item.t_ready is None:
+                if not host_ready(item.fetch_arrays()):
+                    break
+                item.t_ready = now
+
+    def _dispatching(self) -> None:
+        """A program is about to go to the device: what was dry ends here, and
+        until _dispatched has its outputs no probe reads the device as done."""
+        self._probe(time.perf_counter())
+        self._dev_open, self._dev_done = True, False
+
+    def _dispatched(self, outputs) -> None:
+        """The dispatch call has returned with the program's `outputs` (a
+        dispatch that keeps none leaves the call open until the next that
+        does: the floor waits). One more probe of what was dispatched before
+        it: if that has finished by now, the device may have been dry for the
+        whole call, waiting for the new program, and the call goes to the
+        ceiling; if not, it ran throughout."""
+        self._probe(time.perf_counter())
+        self._dev_last, self._dev_open = outputs, False
 
     @staticmethod
     def _build_tiers(args: EngineArgs):
@@ -1193,6 +1311,7 @@ class TpuEngine:
         slot, needs_upload, _evicted = self._lora_pool.acquire(seq.adapter_id)
         if needs_upload:
             try:
+                self._dispatching()
                 self._runner.upload_adapter(
                     slot, self._adapter_pages(spec, pinned)
                 )
@@ -1416,7 +1535,8 @@ class TpuEngine:
                                  chunks=seq.chunks, wave=seq.wave[0],
                                  windows_in_flight=seq.wave[1])
                             span("engine.first_wait", start=seq.t_dispatched,
-                                 end=seq.t_first, blocked=seq.first_waited)
+                                 end=seq.t_first, blocked=seq.first_waited,
+                                 ready_unread_ms=round(1e3 * seq.first_unread_s, 3))
                             span("engine.deliver", start=seq.t_first, end=now)
                         span("engine.prefill", start=t_admit, end=now,
                              prompt_tokens=seq.prompt_len,
@@ -1438,9 +1558,9 @@ class TpuEngine:
 
     def _run(self) -> None:
         crashed = False
+        self._t_run = self._enter("idle")
         try:
             while True:
-                t0 = self._phase_open("idle")
                 with self._wakeup:
                     while (
                         not self._stopping
@@ -1461,14 +1581,15 @@ class TpuEngine:
                         break
                     while self._submissions:
                         self._waiting.append(self._submissions.popleft())
-                self._phase("idle", t0)
                 self._step()
+                self._enter("idle")
         except Exception:  # noqa: BLE001 — engine death must not be silent
             crashed = True
             log.exception("engine loop crashed")
         finally:
             # Flip stopping FIRST so late generate() calls are rejected
             # instead of queueing onto a dead thread.
+            self._enter("housekeeping")
             self._fetchq.clear()  # drop; leftovers get terminal posts below
             self._export_fetches.clear()
             with self._mutex:
@@ -1508,8 +1629,10 @@ class TpuEngine:
                 floop.call_soon_threadsafe(
                     lambda f=fut, e=exc: f.set_exception(e) if not f.cancelled() else None
                 )
+            self._enter(None)
 
     def _step(self) -> None:
+        self._enter("housekeeping")
         self._step_no += 1
         # Harvest whatever fetches completed while the host was away:
         # frees slots/KV and discovers stops as early as possible, and
@@ -1537,7 +1660,8 @@ class TpuEngine:
         # longer inherit a blocking drain's worth of queueing delay).
         # The wave is budgeted to ~one max_prefill_tokens chunk so running
         # decodes are not starved by a long burst of arrivals.
-        t0 = self._phase_open("admission")
+        self._enter("admission")
+        waited, stopped = bool(self._waiting), None
         allocated: list[tuple[_Seq, int]] = []  # (seq, suffix start)
         wave_budget = self.args.admission_budget_tokens or (1 << 62)
         # Frozen mid-cutover sequences are out of _running but still hold
@@ -1559,6 +1683,7 @@ class TpuEngine:
             except NoFreeBlocksError:
                 self._waiting.appendleft(seq)  # try again when blocks free up
                 self._stamp_blocked(seq, "blocks")
+                stopped = "blocks"
                 if not self._running and not allocated and not self._migrations:
                     # Deadlock: nothing to free. Fail the request.
                     # (A frozen migration is NOT a deadlock — its blocks
@@ -1585,13 +1710,18 @@ class TpuEngine:
             # The head of the queue waits for a slot. Finding it is a pass
             # over _waiting, so look again only when the queue has changed
             # (its length or either end: a backlog adds nothing to a step).
+            stopped = stopped or "slots"
             sig = (len(self._waiting), self._waiting[0], self._waiting[-1])
             if sig != self._slots_blocked_sig:
                 self._slots_blocked_sig = sig
                 self._stamp_blocked(self._next_waiting(), "slots")
-        t0 = self._phase("admission", t0, then="prefill_dispatch" if allocated else None)
+        if waited:
+            # The loop's own conditions: what ended it when neither the
+            # blocks, the slots nor the queue did is the wave's budget.
+            self.admission_stops[stopped or ("budget" if self._waiting else "empty")] += 1
         admitted: list[tuple[_Seq, Any, int]] = []  # (seq, logits array, row)
         if allocated:
+            self._enter("prefill_dispatch")
             try:
                 admitted = self._dispatch_prefills(allocated)
             except Exception as e:  # noqa: BLE001 — contain wave faults
@@ -1600,10 +1730,11 @@ class TpuEngine:
                     self.pool.free_sequence(seq.block_ids)
                     seq.block_ids = []
                     self._finish(seq, FinishReason.ERROR, error=f"prefill failed: {e}")
-            t0 = self._phase("prefill_dispatch", t0,
-                             then="first_dispatch" if admitted else None)
+            t0 = self._enter("first_dispatch")
             # Every chunk of the wave is on the device's queue from here.
             wave = (len(allocated), self._inflight_windows())
+            self.prefill_waves += 1
+            self.wave_windows_ahead += wave[1]
             for seq, _ in allocated:
                 seq.t_dispatched, seq.wave = t0, wave
         if admitted:
@@ -1636,7 +1767,6 @@ class TpuEngine:
                     seq.block_ids = []
                     self._finish(seq, FinishReason.ERROR, error=f"sampling failed: {e}")
                 seqs = []
-            t0 = self._phase("first_dispatch", t0)
             if seqs:
                 for seq in seqs:
                     seq.first_pend = True
@@ -1657,14 +1787,18 @@ class TpuEngine:
                     self._fetchq.pop()  # == first, just appended
                     self._drain_one(first)
         if self._running:
+            self._enter("plan")
             self._decode_iteration()
+            self._enter("housekeeping")
             self._flush_offloads()
         elif self._fetchq:
             # Every owner of the queued fetches died during a drain:
             # release them all (zombie rows; keeps StepRef/device arrays
             # from idling forever — the idle predicate ignores _fetchq —
             # and total_decode_steps honest).
+            self._enter("housekeeping")
             self._drain_completed(force=True)
+        self._enter("gauges")
         self._update_gauges()
 
     # -- embeddings (reference: http/service/openai.rs:302) ----------------
@@ -1730,6 +1864,7 @@ class TpuEngine:
                 t_pad = self.args.bucket_prefill(len(chunk))
                 toks = np.zeros((t_pad,), np.int32)
                 toks[: len(chunk)] = chunk
+                self._dispatching()
                 refs.append(self._runner.embed(toks, len(chunk)))
             acc: np.ndarray | None = None
             for chunk, ref in zip(chunks, refs):
@@ -1759,7 +1894,9 @@ class TpuEngine:
             return
         batch = self._offload_pending[: self.tiers.MAX_OFFLOAD_PER_STEP]
         del self._offload_pending[: len(batch)]
+        self._dispatching()
         pages = self._runner.extract_pages([b for b, _ in batch])
+        self._dispatched(pages)  # on the host: the device is through with them
         self.tiers.offload(
             [
                 (h, *(a[:, i : i + 1] for a in pages))
@@ -1810,25 +1947,28 @@ class TpuEngine:
         (local cache, disagg inject, tier onboard). Returns the suffix
         start position. Raises on resource/validation failure; no model
         dispatch happens here."""
-        # Flush queued offloads BEFORE allocating: allocation may evict and
-        # recycle exactly the pages still waiting to be copied out.
-        self._flush_offloads()
-        # Adapter residency first (before any block allocation, so a
-        # failure here has nothing to unwind): resolve adapter_id → a
-        # pinned bank slot, paging the adapter in on a cold miss. Only
-        # THIS request's admission blocks on the fetch — decode windows
-        # already in flight keep executing, and the upload is device-
-        # stream-ordered after them.
+        self._enter("admit_alloc")
         acquired = False
-        if seq.adapter_id is not None and seq.adapter_slot < 0:
-            self._acquire_adapter(seq)
-            acquired = True
         try:
+            # Flush queued offloads BEFORE allocating: allocation may evict and
+            # recycle exactly the pages still waiting to be copied out.
+            self._flush_offloads()
+            # Adapter residency first (before any block allocation, so a
+            # failure here has nothing to unwind): resolve adapter_id → a
+            # pinned bank slot, paging the adapter in on a cold miss. Only
+            # THIS request's admission blocks on the fetch — decode windows
+            # already in flight keep executing, and the upload is device-
+            # stream-ordered after them.
+            if seq.adapter_id is not None and seq.adapter_slot < 0:
+                self._acquire_adapter(seq)
+                acquired = True
             return self._admit_alloc_blocks(seq)
         except BaseException:
             if acquired:
                 self._release_adapter(seq)
             raise
+        finally:
+            self._enter("admission")
 
     def _admit_alloc_blocks(self, seq: _Seq) -> int:
         bs = self.args.block_size
@@ -1871,6 +2011,7 @@ class TpuEngine:
                     dtype=self.args.dtype,
                 )
                 n_onb = n_hit + len(run)
+                self._dispatching()
                 self._runner.inject_pages(seq.block_ids[n_hit:n_onb], *pages)
                 n_hit = n_onb
                 start = n_hit * bs
@@ -1979,7 +2120,9 @@ class TpuEngine:
             starts[r] = start
             tlens[r] = len(seq.tokens)
         aslots = self._adapter_row_slots([s for s, _ in members], Bp)
+        self._dispatching()
         ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots)
+        self._dispatched(ref.arrs)
         self.total_prefill_padded += Bp * t_pad
         self.prefill_dispatch_rows[Bp] += 1
         self.prefill_rows_carried += len(members)
@@ -2014,10 +2157,12 @@ class TpuEngine:
             t_pad = self.args.bucket_prefill(len(chunk))
             toks = np.zeros((t_pad,), np.int32)
             toks[: len(chunk)] = chunk
+            self._dispatching()
             logits = self._runner.prefill_chunk(
                 toks, table, pos, min(pos + len(chunk), plen),
                 seq.adapter_slot if seq.adapter_slot >= 0 else None,
             )
+            self._dispatched(logits.arrs)
             self.total_prefill_padded += t_pad
             self.prefill_dispatch_rows[1] += 1
             self.prefill_rows_carried += 1
@@ -2071,6 +2216,7 @@ class TpuEngine:
             # cache holds (blocks evicted between fetch and admission) —
             # injecting would leave a KV gap, so recompute instead.
             return n_hit * bs, n_hit
+        self._dispatching()
         self._runner.inject_pages(
             seq.block_ids[n_hit:n_inj],
             *(a[:, n_hit - off : n_inj - off] for a in payload.pages()),
@@ -2097,6 +2243,7 @@ class TpuEngine:
                 continue  # fully covered locally already
             if off > n_cur:
                 break  # gap — injecting past it would leave a KV hole
+            self._dispatching()
             self._runner.inject_pages(
                 seq.block_ids[n_cur:end],
                 *(a[:, n_cur - off : end - off] for a in payload.pages()),
@@ -2125,7 +2272,9 @@ class TpuEngine:
             return
         meta = {"remote_handle": seq.request_id, "num_tokens": n_exp * bs, "num_blocks": n_exp}
         if n_exp > 0:
+            self._dispatching()
             pages = self._runner.extract_pages(seq.block_ids[:n_exp])
+            self._dispatched(pages)
             # int8 KV: scale sidecars ride the same payload.
             payload = kv_transfer.KvPagePayload.from_pages(pages, n_exp * bs)
             with self._mutex:
@@ -2135,6 +2284,7 @@ class TpuEngine:
     def _start_export_extract(self, seq: _Seq, lo: int, hi: int) -> None:
         """Dispatch the gather for blocks [lo, hi) of a streaming export
         and start its async D2H copy; harvested by _drain_export_fetches."""
+        self._dispatching()
         arrs, n = self._runner.start_extract_pages(seq.block_ids[lo:hi])
         start_host_fetch(arrs)
         self._export_fetches.append((seq, lo, hi, arrs, n))
@@ -2444,6 +2594,7 @@ class TpuEngine:
             seq.kv_written, bs, mig.pub_blocks, len(seq.block_ids)
         )
         if hi > lo:
+            self._dispatching()
             arrs, n = self._runner.start_extract_pages(seq.block_ids[lo:hi])
             start_host_fetch(arrs)
             mig.fetches.append((lo, hi, arrs, n))
@@ -2693,8 +2844,8 @@ class TpuEngine:
 
     def _drain_first(self, f: _First, blocked: bool = True) -> None:
         """Fetch + emit one admission wave's first-token samples."""
-        key = "first_sample" if blocked else "drain_ready"
-        t0 = self._phase_open(key)
+        back = self._cur
+        t_fetch = self._enter("first_sample" if blocked else "drain_ready")
         toks = np.asarray(f.out_d)
         lps = np.asarray(f.lps_d)
         tvals_l = tids_l = None
@@ -2703,11 +2854,16 @@ class TpuEngine:
             tids_l = np.asarray(f.top_ref.arrs[1]).tolist()
         for hist in f.routed:
             self.moe_hist["prefill"] += np.asarray(hist)
-        t0 = self._phase(key, t0, then="emit")
+        t0 = self._enter("emit")
+        # How long the sample lay ready before it was read: from the probe
+        # that first saw it so (this fetch's start, if none had) to here.
+        unread = 0.0 if blocked else t0 - (f.t_ready or t_fetch)
+        self.first_ready_unread_s += unread
+        self.first_fetches["device" if blocked else "host"] += 1
         toks_l, lps_l = toks.tolist(), lps.tolist()
         for seq, row in f.entries:
             seq.first_pend = False
-            seq.t_first, seq.first_waited = t0, blocked
+            seq.t_first, seq.first_waited, seq.first_unread_s = t0, blocked, unread
             if seq.dead:
                 continue  # cancelled while the sample was in flight
             tops = None
@@ -2715,7 +2871,7 @@ class TpuEngine:
                 n = seq.sampling.top_logprobs
                 tops = [[list(p) for p in zip(tids_l[row][:n], tvals_l[row][:n])]]
             self._emit_tokens(seq, [toks_l[row]], [lps_l[row]], tops)
-        self._phase("emit", t0)
+        self._enter(back)
 
     def _plan_window(self) -> tuple[int, int]:
         """→ (K, depth). K=1 is the end-of-life tail near max_model_len;
@@ -2874,22 +3030,24 @@ class TpuEngine:
             if any(s.sampling.top_logprobs for s in batch) else 0
         )
         aslots = self._adapter_row_slots(batch, B)
-        t0 = self._phase_open("decode_dispatch")
+        self._enter("decode_dispatch")
+        self._dispatching()
         ref = self._runner.multi_decode(
             K, mode, tokens, wchain, positions, tables, active,
             temps, seeds, steps0, tks, tps, freqs, press, pen, fold_slots,
             top_n, aslots,
         )
+        self._dispatched(ref.arrs)
         w = _Window(batch, pos0, K, ref, top_n)
         start_host_fetch(w.fetch_arrays())
         self.total_decode_rows_dispatched += B * K
-        self._phase("decode_dispatch", t0)
+        self._enter("plan")
         return w
 
     def _drain_window(self, w: "_Window", blocked: bool = True) -> None:
         self.total_decode_steps += w.K
-        key = "drain_sync" if blocked else "drain_ready"
-        t0 = self._phase_open(key)
+        back = self._cur
+        self._enter("drain_sync" if blocked else "drain_ready")
         toks_np = np.asarray(w.ref.arrs[0])  # [K, B] — the one host fetch
         logps_np = np.asarray(w.ref.arrs[1])
         if w.ref.hist is not None:
@@ -2900,7 +3058,7 @@ class TpuEngine:
             # int()/float() at K·B·n scale was measurable emit cost).
             tvals_l = np.asarray(w.ref.arrs[2]).transpose(1, 0, 2).tolist()
             tids_l = np.asarray(w.ref.arrs[3]).transpose(1, 0, 2).tolist()
-        t0 = self._phase(key, t0, then="emit")
+        self._enter("emit")
         toks_l = toks_np.T.tolist()    # [B][K] python ints
         logps_l = logps_np.T.tolist()  # [B][K] python floats
         for i, seq in enumerate(w.rows):
@@ -2921,7 +3079,7 @@ class TpuEngine:
                 ]
             self.total_decode_rows_emitted += self._emit_tokens(
                 seq, toks_l[i], logps_l[i], tops)
-        self._phase("emit", t0)
+        self._enter(back)
 
     # -- speculative decoding ---------------------------------------------
     #
@@ -3036,22 +3194,21 @@ class TpuEngine:
             for s in self._running:
                 if s.spec_cool > 0:
                     s.spec_cool -= 1
-        t0 = self._phase_open("draft")
+        self._enter("draft")
         drafts = self._draft_all(S)
         if not self._spec_gate_passes(drafts):
-            self._phase("draft", t0)
+            self._enter("plan")
             return False
         # The gate passed on the visible history: drafting positions +
         # inputs need COMPLETE histories, so settle everything in flight,
         # then re-draft rows whose histories just advanced.
         if self._fetchq:
-            self._phase("draft", t0)
             self._drain_completed(force=True)
             if not self._running:
+                self._enter("plan")
                 return True
-            t0 = self._phase_open("draft")
             drafts = self._draft_all(S)
-        t0 = self._phase("draft", t0)
+        self._enter("plan")
         if not self._spec_gate_passes(drafts):
             return False
         batch = list(self._running)
@@ -3061,7 +3218,7 @@ class TpuEngine:
         for seq in batch:
             if not self._ensure_block(seq, lookahead=len(drafts[seq]) + 1):
                 return False
-        self._phase_open("spec_dispatch")  # accounted from the draft's end (t0)
+        self._enter("spec_dispatch")
         B = self.args.bucket_decode(len(batch))
         # Verify-shape bucket: the uniform S+1 covers every draft at or
         # under the per-row allowance; an adaptive reallocation that let
@@ -3133,11 +3290,13 @@ class TpuEngine:
         if any_gram:
             masks = self._build_tree_masks(batch, B, S1, node_tokens,
                                            node_parents)
+        aslots = self._adapter_row_slots(batch, B)
+        self._dispatching()
         ref = self._runner.spec_verify(
             S1, mode, tokens, pos0_arr, dlen, tables, active,
-            temps, seeds, steps0, fold_slots, top_n, tree, masks,
-            self._adapter_row_slots(batch, B),
+            temps, seeds, steps0, fold_slots, top_n, tree, masks, aslots,
         )
+        self._dispatched(ref.arrs)
         item = _Spec(
             batch, pos0, draft_lens, ref, top_n,
             potentials=potentials, tree=any_tree,
@@ -3145,7 +3304,7 @@ class TpuEngine:
         )
         start_host_fetch(item.fetch_arrays())
         self._fetchq.append(item)
-        self._phase("spec_dispatch", t0)
+        self._enter("plan")
         return True
 
     def _draft_all(self, S: int) -> dict:
@@ -3234,8 +3393,8 @@ class TpuEngine:
         self.total_spec_passes += 1
         if sp.tree:
             self.total_spec_tree_passes += 1
-        key = "drain_sync" if blocked else "drain_ready"
-        t0 = self._phase_open(key)
+        back = self._cur
+        self._enter("drain_sync" if blocked else "drain_ready")
         out_l = np.asarray(sp.ref.arrs[0]).tolist()     # [B][S1]
         n_emit_l = np.asarray(sp.ref.arrs[1]).tolist()  # [B]
         logps_l = np.asarray(sp.ref.arrs[2]).tolist()   # [B][S1]
@@ -3244,7 +3403,7 @@ class TpuEngine:
         if sp.top_n:
             tvals_l = np.asarray(sp.ref.arrs[4]).tolist()  # [B][S1][n]
             tids_l = np.asarray(sp.ref.arrs[5]).tolist()
-        t0 = self._phase(key, t0, then="emit")
+        self._enter("emit")
         alpha = self.args.spec_ema_alpha
         for i, seq in enumerate(sp.rows):
             if seq.dead:
@@ -3298,7 +3457,7 @@ class TpuEngine:
                     for j in range(n)
                 ]
             self._emit_tokens(seq, out_l[i][:n], logps_l[i][:n], tops)
-        self._phase("emit", t0)
+        self._enter(back)
 
     def _decode_single_step(self) -> None:
         # Per-step path needs host-visible tokens (inputs come from
@@ -3306,7 +3465,7 @@ class TpuEngine:
         self._drain_completed(force=True)
         if not self._running:
             return
-        t_start = self._phase_open("single_step")
+        self._enter("single_step")
         batch = list(self._running)
         B = self.args.bucket_decode(len(batch))
         W = self.args.bucket_table(max(len(s.block_ids) for s in batch))
@@ -3319,10 +3478,10 @@ class TpuEngine:
             positions[i] = seq.next_write_pos
             tables[i, : len(seq.block_ids)] = seq.block_ids
             active[i] = True
-        ref = self._runner.decode_step(
-            tokens, positions, tables, active,
-            self._adapter_row_slots(batch, B),
-        )
+        aslots = self._adapter_row_slots(batch, B)
+        self._dispatching()
+        ref = self._runner.decode_step(tokens, positions, tables, active, aslots)
+        self._dispatched(ref.arrs)
         self.total_decode_steps += 1
         self.total_decode_rows_dispatched += B
         self.total_row_passes += len(batch)
@@ -3348,7 +3507,7 @@ class TpuEngine:
                 tops = [[[int(tids[i, r]), float(tvals[i, r])] for r in range(n)]]
             self.total_decode_rows_emitted += self._emit_tokens(
                 seq, [int(sampled[i])], [float(logps[i])], tops)
-        self._phase("single_step", t_start)
+        self._enter("plan")
 
     @staticmethod
     def _needs_full_sampler(seq: _Seq) -> bool:
@@ -3409,10 +3568,13 @@ class TpuEngine:
         # every emitted token, host-visible because grammar batches
         # always run force-drained K=1).
         masks = self._grammar_row_masks(seqs, B)
-        return self._runner.sample_rows(
+        self._dispatching()
+        out = self._runner.sample_rows(
             srcs, temps, tks, tps, pen, freqs, press, seeds, steps, full,
             fold_slots, top_n, masks,
         )
+        self._dispatched(out[:2])
+        return out
 
     # -- token emission / finish ------------------------------------------
 

@@ -121,6 +121,75 @@ def test_a_scheduler_idle_all_window_has_no_wait_share():
     assert read("sched_wait_device_share", without(ctx(), "engine_sched_cpu")) is None
 
 
+# -- the scheduler thread's own account (ISSUE 43) --------------------------------
+
+
+def series(name: str, label: str, **values: float) -> dict:
+    return {f'dynamo_tpu_engine_{name}{{{label}="{k}"}}': v for k, v in values.items()}
+
+
+# What a worker of PR 43 adds to the pages above, before and after the window.
+ACCOUNT_BEFORE = {
+    **series("step_phase_cpu_seconds_total", "phase", admission=0.2, admit_alloc=0.2, plan=0.1,
+             decode_dispatch=0.5, emit=1.0, gauges=1.0),
+    **series("step_phase_total", "phase", decode_dispatch=40, emit=40),
+    "dynamo_tpu_engine_sched_wall_seconds_total": 50.0,
+    **series("admission_stops_total", "reason", empty=30, budget=5),
+    "dynamo_tpu_engine_prefill_waves_total": 20, "dynamo_tpu_engine_wave_windows_ahead_total": 10,
+    **series("first_fetch_total", "waited", device=15),
+    **series("device_dry_seconds_total", "bound", ceiling=1.0),
+}
+ACCOUNT_AFTER = {
+    # grown: admission 0.3 + admit_alloc 0.2; plan 0.4 + decode_dispatch 0.5 + stack_rows 0.1;
+    # emit 0.5 + drain_sync 0.25 (new in the window); gauges 0.25: 2.5 s in all
+    **series("step_phase_cpu_seconds_total", "phase", admission=0.5, admit_alloc=0.4, plan=0.5,
+             decode_dispatch=1.0, stack_rows=0.1, emit=1.5, drain_sync=0.25, gauges=1.25),
+    **series("step_phase_total", "phase", decode_dispatch=90, emit=100),
+    "dynamo_tpu_engine_sched_wall_seconds_total": 60.0,
+    **series("admission_stops_total", "reason", empty=80, budget=35, slots=8, blocks=2),
+    "dynamo_tpu_engine_prefill_waves_total": 60, "dynamo_tpu_engine_wave_windows_ahead_total": 70,
+    **series("first_fetch_total", "waited", device=35, host=20),
+    "dynamo_tpu_engine_first_ready_unread_seconds_total": 0.1,
+    **series("device_dry_seconds_total", "bound", floor=0.2, ceiling=1.5),
+}
+
+
+def with_account(c: dict) -> dict:
+    for r in (0, 1):
+        c["prom"][f"worker{r}.before"] = {**WORKER_BEFORE, **ACCOUNT_BEFORE}
+        c["prom"][f"worker{r}.after"] = {**WORKER_AFTER, **ACCOUNT_AFTER}
+    return c
+
+
+# By hand from the pages above, two workers alike; every one None on the parent's pages.
+@pytest.mark.parametrize("name, want", [
+    ("sched_cpu_per_window_ms", 1000 * 2.5 / 50),
+    ("sched_cpu_admission_share", 100 * 0.5 / 2.5),
+    ("sched_cpu_dispatch_share", 100 * 1.0 / 2.5),
+    ("sched_cpu_emit_share", 100 * 0.75 / 2.5),
+    ("admission_budget_stop_share", 100 * 30 / 40),
+    ("wave_windows_ahead_mean", 60 / 40),
+    ("first_ready_unread_mean_ms", 1000 * 0.1 / 40),
+    ("device_dry_floor_share", 100 * 0.2 / 10),
+    ("device_dry_ceiling_share", 100 * 0.5 / 10),
+])
+def test_reader_of_the_scheduler_threads_account(name, want):
+    assert read(name, with_account(ctx())) == pytest.approx(want)
+    assert read(name, ctx()) is None   # the parent's pages: the series are not there
+
+
+def test_a_thread_that_was_never_dry_reads_zero_not_nothing():
+    c = with_account(ctx())
+    for r in (0, 1):
+        c["prom"][f"worker{r}.after"] = {k: v for k, v in c["prom"][f"worker{r}.after"].items()
+                                         if "device_dry" not in k and "ready_unread" not in k}
+        c["prom"][f"worker{r}.before"] = {k: v for k, v in c["prom"][f"worker{r}.before"].items()
+                                          if "device_dry" not in k}
+    assert read("device_dry_floor_share", c) == 0.0
+    assert read("device_dry_ceiling_share", c) == 0.0
+    assert read("first_ready_unread_mean_ms", c) == 0.0
+
+
 def test_every_new_reader_is_listed_and_every_listed_reader_exists():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)

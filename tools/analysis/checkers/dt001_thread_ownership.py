@@ -223,4 +223,8 @@ _GLOBAL_OWNED = frozenset({
     "_export_fetches", "_drafter", "_step_no", "_spec_ticked",
     "phase_s", "phase_n", "_ctr_pushed", "_spec_depth_hist",
     "_migrations", "_anno", "_slots_blocked_sig",
+    "phase_cpu_s", "_cur", "_cur_t", "_cur_cpu", "_t_run",
+    "admission_stops", "prefill_waves", "wave_windows_ahead",
+    "first_ready_unread_s", "first_fetches", "device_dry_s",
+    "_dev_last", "_dev_open", "_dev_done", "_probe_t", "_probe_work",
 })
