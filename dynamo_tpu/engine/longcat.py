@@ -19,13 +19,13 @@ A layer is no longer "attention, then FFN":
   and sub-block, the normed-and-scaled latent ``c_kv`` (``kv_lora_rank``)
   and the rotated rope key all heads share (``qk_rope_head_dim``): one row
   of ``latent_dim`` values, padded to whole lane tiles, in ONE pool of
-  ``2L`` cache layers (cache layer ``2*layer + sub_block``). Prefill
-  attends in the expanded form (latents gathered from cached pages and
-  multiplied out by ``W_kvb``, a few heads at a time so the float32 scores
-  fit); decode absorbs ``W_kvb`` into the query and the output and attends
+  ``2L`` cache layers (cache layer ``2*layer + sub_block``). Prefill and
+  decode both absorb ``W_kvb`` into the query and the output and attend
   straight over the latent pages (ops/paged_attention.py
-  ``latent_decode_attention``). The sums are the same
-  (tests/test_longcat.py).
+  ``latent_prefill_attention``, ``latent_decode_attention``): a chunk's
+  latents are in their pages before its attention runs. The sums are those
+  of the published, expanded form (tests/test_longcat.py keeps it as the
+  reference).
 - **MoE**: a router over the PUBLISHED width (routed + zero-compute
   experts), float32 softmax, top-k chosen on ``p + bias`` with weights
   ``scaling * p`` (the bias moves the choice, not the weights; nothing is
@@ -54,10 +54,12 @@ from jax import lax
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.model import KVCache, _logits, decode_window
 from dynamo_tpu.ops.paged_attention import (
-    gather_dequant_pages,
     latent_decode_attention,
     latent_decode_attention_xla,
+    latent_prefill_attention,
+    latent_prefill_attention_xla,
     resolve_attn_impl,
+    resolve_prefill_impl,
 )
 
 Params = dict[str, Any]
@@ -68,8 +70,6 @@ Params = dict[str, Any]
 # at once, which covers any pack of prefill rows the engine forms
 # (runner.pack_limit), so a pack streams a layer's experts once.
 MOE_CHUNK_ROWS = 6144
-# Float32 score elements one head group of the prefill attention may hold.
-_SCORE_ELEMS = 48 << 20
 # The grouped product's tiles come from its operands (``gmm_tiling``). Rows:
 # 128 a tile, which ``_moe_tokens`` pads its assignment rows to; at one K tile
 # 64 read within a point of it in a decode call and 2-3 points under in a
@@ -219,50 +219,10 @@ def mla_project(h: jax.Array, sub: dict, cfg: ModelConfig, positions: jax.Array)
     return q_n, q_r, latent
 
 
-def _pad_row(latent: jax.Array, cfg: ModelConfig) -> jax.Array:
-    pad = cfg.latent_page_width - cfg.latent_dim
-    return jnp.pad(latent, [(0, 0)] * (latent.ndim - 1) + [(0, pad)]) if pad else latent
-
-
-def attend_expanded(q_n, q_r, ctx_latent, mask, sub: dict, cfg: ModelConfig) -> jax.Array:
-    """The published form. q_n [B, T, H, dn], q_r [B, T, H, dr] against the
-    latents of the whole context ``ctx_latent`` [B, C, latent_dim] under the
-    additive float32 ``mask`` [B, T, C] → [B, T, H, dv]. The latents are
-    multiplied out by W_kvb one group of heads at a time, so that the
-    float32 scores of a 2,048-token chunk against 6k positions fit."""
-    B, T, H, dn = q_n.shape
-    C = ctx_latent.shape[1]
-    rkv, dv = cfg.kv_lora_rank, cfg.v_head_dim
-    scale = (dn + cfg.qk_rope_head_dim) ** -0.5
-    g = H
-    while g > 1 and B * g * T * C > _SCORE_ELEMS:
-        g //= 2
-    c_kv, k_r = ctx_latent[..., :rkv], ctx_latent[..., rkv:]
-
-    def group(args):
-        qn_g, qr_g, uk_g, uv_g = args  # [B, T, g, dn], [B, T, g, dr], [g, dn, rkv], [g, rkv, dv]
-        k_n = jnp.einsum("bcl,gnl->bcgn", c_kv, uk_g)
-        s = jnp.einsum("btgn,bcgn->bgtc", qn_g, k_n, preferred_element_type=jnp.float32)
-        s = s + jnp.einsum("btgr,bcr->bgtc", qr_g, k_r, preferred_element_type=jnp.float32)
-        p = jax.nn.softmax(s * scale + mask[:, None], axis=-1).astype(q_n.dtype)
-        return jnp.einsum("bgtc,bcgv->btgv", p, jnp.einsum("bcl,glv->bcgv", c_kv, uv_g))
-
-    def split(a, axis):  # heads [.., H, ..] → [H/g, .., g, ..]
-        return jnp.moveaxis(a.reshape(*a.shape[:axis], H // g, g, *a.shape[axis + 1:]), axis, 0)
-
-    o = lax.map(group, (split(q_n, 2), split(q_r, 2), split(sub["w_uk"], 0), split(sub["w_uv"], 0)))
-    return jnp.moveaxis(o, 0, 2).reshape(B, T, H, dv)
-
-
-def prefill_row_ops(cfg: ModelConfig, context: int) -> int:
-    """Operations a prefill row costs this block whatever its tokens, behind
-    a table of ``context`` positions: ``attend_expanded`` multiplies every
-    cached latent of the table's width out by W_kvb once a row (keys and
-    values of every head, both sub-blocks of a layer). The runner counts it
-    against the padded tokens a packed dispatch may hold
-    (``runner.pack_row_tokens``)."""
-    return (2 * 2 * cfg.num_layers * context * cfg.num_heads
-            * (cfg.qk_nope_head_dim + cfg.v_head_dim) * cfg.kv_lora_rank)
+def _pad_row(x: jax.Array, cfg: ModelConfig, width: int = 0) -> jax.Array:
+    """Zero lanes after the last axis, to ``width`` (a cache row's by default)."""
+    pad = (width or cfg.latent_page_width) - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
 def absorb_query(q_n, q_r, sub: dict, cfg: ModelConfig) -> jax.Array:
@@ -509,7 +469,8 @@ def _scan_layers(cfg, params, x, pool, positions, valid, attend_for, moe_impl):
 
 
 def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true_len,
-                       lora=None, adapter_slots=None, *, experts: str | None = None):
+                       lora=None, adapter_slots=None, *, attn_impl: str = "auto",
+                       experts: str | None = None):
     """``model.prefill_batch_impl`` for this block: same arguments and contract
     (prefix pages cached in whole blocks, suffix computed here), and a third
     result, the layers' routing histogram [L, E + 5]. ``experts`` names the
@@ -517,22 +478,18 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
     if lora is not None:
         raise ValueError("LoRA banks cannot run a block='longcat' model")
     Bp, T = tokens.shape
-    W = block_tables.shape[1]
     bs, Wd = cache.k.shape[2], cache.k.shape[3]
-    sfx = jnp.arange(T, dtype=jnp.int32)
-    positions = start_pos[:, None] + sfx[None, :]                 # [Bp, T]
+    positions = start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]   # [Bp, T]
     valid = positions < true_len[:, None]
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
-
-    neg = jnp.float32(-1e9)
-    causal = sfx[None, :] <= sfx[:, None]                          # [T, T]
-    mask_ss = jnp.where(causal[None] & valid[:, None, :], 0.0, neg)
-    ctx = jnp.arange(W * bs, dtype=jnp.int32)
-    mask_sp = jnp.where(ctx[None, :] < start_pos[:, None], 0.0, neg)
-    mask = jnp.concatenate(
-        [jnp.broadcast_to(mask_sp[:, None, :], (Bp, T, W * bs)), mask_ss], axis=-1
-    )
+    impl, _ = resolve_prefill_impl(attn_impl, cfg, bs, False)
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if impl == "xla":
+        attention = functools.partial(latent_prefill_attention_xla, scale=scale)
+    else:
+        attention = functools.partial(
+            latent_prefill_attention, scale=scale, interpret=(impl == "pallas_interpret"))
 
     # Suffix pages' targets, as model.prefill_batch_impl derives them.
     nb = T // bs
@@ -548,9 +505,13 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
             with jax.named_scope("mla_kv_write"):
                 pool = pool.at[ci, flat_ids].set(_pad_row(latent, cfg).reshape(Bp * nb, bs, Wd))
             with jax.named_scope("mla_attn"):
-                prefix = gather_dequant_pages(pool, None, ci, block_tables, 1, Wd, x.dtype)
-                ctx_latent = jnp.concatenate([prefix[:, :, 0, :cfg.latent_dim], latent], axis=1)
-                return attend_expanded(q_n, q_r, ctx_latent, mask, sub, cfg), pool
+                # Out of the pages, prefix and chunk alike, in decode's form,
+                # head-major: a batch of heads is what both products make and
+                # take, so nothing is transposed around the attention.
+                q_lat = jnp.einsum("bthn,hnc->bhtc", q_n, sub["w_uk"])
+                q_rope = _pad_row(jnp.moveaxis(q_r, 2, 1), cfg, Wd - cfg.kv_lora_rank)
+                o = attention(q_lat, q_rope, pool, ci, block_tables, start_pos, true_len)
+                return jnp.einsum("bhtc,hcv->bthv", o, sub["w_uv"]), pool
         return attend
 
     x, pool, hist = _scan_layers(cfg, params, x, cache.k, positions, valid, attend_for,
@@ -563,12 +524,14 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
 
 
 def prefill_impl(cfg, params, cache, tokens, block_table, start_pos, true_len,
-                 lora=None, adapter_slot=None, *, experts: str | None = None):
+                 lora=None, adapter_slot=None, *, attn_impl: str = "auto",
+                 experts: str | None = None):
     """Single-sequence prefill: the Bp=1 case of ``prefill_batch_impl``."""
     logits, cache, hist = prefill_batch_impl(
         cfg, params, cache, tokens[None, :], block_table[None, :],
         jnp.asarray(start_pos, jnp.int32).reshape(1),
-        jnp.asarray(true_len, jnp.int32).reshape(1), lora, experts=experts,
+        jnp.asarray(true_len, jnp.int32).reshape(1), lora,
+        attn_impl=attn_impl, experts=experts,
     )
     return logits[0], cache, hist
 
@@ -638,13 +601,13 @@ def multi_decode_impl(cfg, num_steps, mode, top_n, params, cache, tokens, positi
 
 
 # The jitted programs, under engine/model.py's names and with its donation.
+_STATIC = ("attn_impl", "experts")
 prefill = functools.partial(
-    jax.jit, static_argnums=(0,), static_argnames=("experts",), donate_argnums=(2,))(prefill_impl)
+    jax.jit, static_argnums=(0,), static_argnames=_STATIC, donate_argnums=(2,))(prefill_impl)
 prefill_batch = functools.partial(
-    jax.jit, static_argnums=(0,), static_argnames=("experts",), donate_argnums=(2,))(prefill_batch_impl)
+    jax.jit, static_argnums=(0,), static_argnames=_STATIC, donate_argnums=(2,))(prefill_batch_impl)
 decode_step = functools.partial(
-    jax.jit, static_argnums=(0,), static_argnames=("attn_impl", "experts"), donate_argnums=(2,)
-)(decode_step_impl)
+    jax.jit, static_argnums=(0,), static_argnames=_STATIC, donate_argnums=(2,))(decode_step_impl)
 multi_decode = functools.partial(
-    jax.jit, static_argnums=(0, 1, 2, 3), static_argnames=("attn_impl", "experts"), donate_argnums=(5,)
+    jax.jit, static_argnums=(0, 1, 2, 3), static_argnames=_STATIC, donate_argnums=(5,)
 )(multi_decode_impl)

@@ -76,9 +76,10 @@ def pack_row_tokens(cfg, context: int) -> int:
     """What a row of a pack costs beside its padded tokens, in tokens: the
     operations the block spends on a row whatever its length (the block
     module's own ``prefill_row_ops`` over the table's ``context``, where it
-    has one) over the operations of a token. ~98 for the latent block behind
-    4,096 positions; 0 for the blocks whose attention walks only what a row
-    can see."""
+    has one) over the operations of a token. 0 for every block today: each
+    one's prefill attention walks only what a row can see (the latent block's
+    since PR 44; its XLA form multiplied the table's 4,096 latents out once a
+    row, 99 tokens' worth)."""
     row_ops = getattr(M.block_module(cfg), "prefill_row_ops", None)
     return -(-row_ops(cfg, context) // (2 * cfg.active_param_count())) if row_ops else 0
 
@@ -226,8 +227,7 @@ class LocalRunner:
         # Before anything is allocated: a refused configuration fails fast.
         self.attn_impl, attn_note = self._resolve_attention()
         self.prefill_attn_impl, prefill_note = self._resolve_prefill_attention(attn_note)
-        if self.cfg.block != "longcat":
-            self._prefill_kw = {"attn_impl": self.prefill_attn_impl}
+        self._prefill_kw = {"attn_impl": self.prefill_attn_impl}
         dtype = jnp.dtype(self.args.dtype)
         # Seeded params and the KV pool are BORN sharded (jit with
         # out_shardings): a model or pool sized for the mesh never has to
@@ -376,8 +376,6 @@ class LocalRunner:
         kernel adds: bf16 pages."""
         from dynamo_tpu.ops.paged_attention import resolve_prefill_impl
 
-        if self.cfg.block == "longcat":
-            return "xla", "latent block: attend_expanded"
         if self.attn_impl == "xla":
             return "xla", attn_note
         return resolve_prefill_impl(

@@ -859,15 +859,17 @@ def resolve_prefill_impl(requested: str, cfg, block_size: int, int8_pages: bool)
     was asked for). ``requested`` is an ``attn_impl``; the XLA form serves
     int8 KV pages (there the chunk attends its exact values and only later
     readers see the rounding: attending out of int8 pages would be another
-    result) and what the compiler refuses: ``kernel_unsupported``'s limits,
-    the page DMAs being the decode kernel's (head sizes 64, 128 and 256 and
-    pages of 4 to 64 tokens compiled for v5e, PR 34)."""
+    result) and what the compiler refuses: ``kernel_unsupported``'s limits
+    (``latent_kernel_unsupported``'s over latent pages), the page DMAs being
+    the decode kernel's (head sizes 64, 128 and 256 and pages of 4 to 64
+    tokens compiled for v5e, PR 34; the 640-lane latent row, PR 44)."""
     impl = resolve_attn_impl(requested)
     if impl == "xla":
         return impl, ""
     if int8_pages:
         return "xla", "int8 KV pages: the chunk attends its exact values"
-    limit = kernel_unsupported(cfg, block_size) if impl == "pallas" else None
+    unsupported = latent_kernel_unsupported if cfg.kv_lora_rank else kernel_unsupported
+    limit = unsupported(cfg, block_size) if impl == "pallas" else None
     return ("xla", limit) if limit else (impl, "")
 
 
@@ -1067,19 +1069,21 @@ def paged_prefill_attention(
     assert T % tq == 0, (T, tq)
     P = pages_per_chunk or min(max(_PREFILL_CHUNK_TOKENS // bs, 1), _MAX_PAGES_PER_CHUNK)
     P = min(P, k_cache.shape[1])
-    # The walk stops at a row's true length, so the table's width is nothing
-    # to the kernel but a shape: padded here, ahead of the jitted call, to
-    # whole ``_TABLE_WIDTH``s, the narrow and the wide table bucket trace one
-    # kernel between them (a trace is 0.3 s of a worker's start, warm or cold).
+    return _paged_prefill_call(
+        q, k_cache, v_cache, layer_idx, _whole_table(block_tables, P), start_pos, true_len,
+        P=P, tq=tq, interpret=interpret,
+    )
+
+
+def _whole_table(block_tables: jax.Array, P: int) -> jax.Array:
+    """The walk stops at a row's true length, so the table's width is nothing
+    to a prefill kernel but a shape: padded here, ahead of the jitted call, to
+    whole ``_TABLE_WIDTH``s, the narrow and the wide table bucket trace one
+    kernel between them (a trace is 0.3 s of a worker's start, warm or cold)."""
     W = block_tables.shape[1]
     Wp = -(-W // _TABLE_WIDTH) * _TABLE_WIDTH
     Wp = -(-Wp // P) * P  # whole chunks, as the decode kernel's table
-    if Wp != W:
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, Wp - W)))
-    return _paged_prefill_call(
-        q, k_cache, v_cache, layer_idx, block_tables, start_pos, true_len,
-        P=P, tq=tq, interpret=interpret,
-    )
+    return jnp.pad(block_tables, ((0, 0), (0, Wp - W))) if Wp != W else block_tables
 
 
 @functools.partial(jax.jit, static_argnames=("P", "tq", "interpret"))
@@ -1191,3 +1195,275 @@ def latent_decode_attention(
         pages_per_chunk, interpret, value_dim=value_dim, scale=scale,
     )
     return o[:, 0, 0]
+
+
+# Float32 score elements one head group of the XLA form may hold.
+_XLA_SCORE_ELEMS = 48 << 20
+
+
+def latent_prefill_attention_xla(
+    q_lat: jax.Array,        # [B, H, T, Dv] absorbed queries at positions start_pos ..: the
+    q_rope: jax.Array,       # [B, H, T, Dk - Dv]   latent lanes, and the rope lanes (zero-padded)
+    cache: jax.Array,        # [2L, N, bs, Dk] — the chunk's own rows already written
+    layer_idx: jax.Array,    # scalar int32 — cache layer (2*layer + sub-block)
+    block_tables: jax.Array, # [B, W] int32
+    start_pos: jax.Array,    # [B] int32
+    true_len: jax.Array,     # [B] int32 — 0: an inactive row
+    *, scale: float,
+) -> jax.Array:
+    """The gather-based form of ``latent_prefill_attention``: the table's
+    whole width gathered dense, prefix and chunk alike, and float32 scores
+    ``[B, h, T, W*bs]`` over it, a group of heads at a time so that they fit
+    (``_XLA_SCORE_ELEMS``). The CPU's path, and a refused geometry's.
+    Returns [B, H, T, Dv] in q_lat.dtype."""
+    B, H, T, Dv = q_lat.shape
+    Dk = cache.shape[3]
+    pk = gather_dequant_pages(cache, None, layer_idx, block_tables, 1, Dk, q_lat.dtype)[:, :, 0]
+    C = pk.shape[1]
+    horizon = jnp.minimum(start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None] + 1,
+                          true_len[:, None])                       # [B, T]
+    mask = jnp.where(jnp.arange(C, dtype=jnp.int32)[None, None] < horizon[..., None],
+                     0.0, jnp.float32(NEG_INF))                    # [B, T, C]
+    g = H
+    while g > 1 and B * g * T * C > _XLA_SCORE_ELEMS:
+        g //= 2
+
+    def group(qs):  # [B, g, T, Dv], [B, g, T, Dk - Dv]
+        s = jnp.einsum("bhtd,bcd->bhtc", qs[0], pk[..., :Dv], preferred_element_type=jnp.float32)
+        s = s + jnp.einsum("bhtd,bcd->bhtc", qs[1], pk[..., Dv:], preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(s * scale + mask[:, None], axis=-1).astype(q_lat.dtype)
+        return jnp.einsum("bhtc,bcv->bhtv", p, pk[..., :Dv])
+
+    def split(a):  # [B, H, T, d] → [H/g, B, g, T, d]
+        return jnp.moveaxis(a.reshape(B, H // g, g, T, a.shape[3]), 1, 0)
+
+    o = lax.map(group, (split(q_lat), split(q_rope)))
+    return jnp.moveaxis(o, 0, 1).reshape(B, H, T, Dv)
+
+
+# The latent prefill kernel's sizes, from the chip (PERF.md section 6, PR 44):
+# float32 score elements a grid step may hold (rows of the left operand x tokens
+# of a chunk), beside which Mosaic keeps the exponentials and their cast: at
+# 2 Mi the kernel is 1.5-1.9 times slower at every call shape. And the tokens
+# of a chunk: 512 and 1,024 read level at a 256-token turn behind 1.7k, and
+# 512 is 10-20% faster at a 64-token turn, in a pack and at T 2,048, because
+# a row's last chunk is computed whole however few pages it holds.
+_LATENT_SCORE_ELEMS = 1 << 20
+_LATENT_CHUNK_TOKENS = 512
+
+
+def _latent_prefill_tile(T: int, H: int, chunk_tokens: int) -> int:
+    """The query tile of the latent kernel, from the shapes: the largest
+    divisor of T whose ``H x tq`` rows (every head of a position is a row of
+    the one left operand) keep a chunk's float32 scores within
+    ``_LATENT_SCORE_ELEMS``; a multiple of 16 where T has one (a packed bf16
+    sublane tile: the tile's heads then stack with no relayout), else of 8."""
+    most = max(_LATENT_SCORE_ELEMS // (H * chunk_tokens), 1)
+    fits = [t for t in range(1, min(T, most) + 1) if T % t == 0]
+    for whole in (16, 8, 1):
+        aligned = [t for t in fits if t % whole == 0]
+        if aligned:
+            return aligned[-1]
+
+
+def _latent_prefill_kernel(
+    # scalar prefetch
+    layer_ref,    # [1] int32
+    start_ref,    # [B] int32 — the chunk's first position
+    len_ref,      # [B] int32 — the row's true length
+    tables_ref,   # [B, W] int32
+    # operands
+    ql_ref,       # VMEM [1, H, tq, Dv] — a tile of absorbed queries, head-major: the
+    qr_ref,       # VMEM [1, H, tq, Dk - Dv]   latent lanes, and the rope lanes (zero-padded)
+    k_hbm,        # ANY  [2L, N, bs, Dk]
+    o_ref,        # VMEM [1, H, tq, Dv]
+    # scratch
+    q_scr,        # VMEM [H*tq, Dk] — the two side by side, row h*tq + t: ONE left operand
+    kbuf,         # VMEM [2, P, bs, Dk] — pages as they land
+    acc_scr,      # VMEM [H*tq, Dv] f32
+    m_scr,        # VMEM [H*tq, 1] f32 — running max
+    l_scr,        # VMEM [H*tq, 1] f32 — running sum
+    hz_scr,       # VMEM [H*tq, 1] int32 — each query row attends [0, horizon)
+    slot_ref,     # SMEM [1] int32
+    sem,          # DMA semaphores [2 slots, 1]
+    *,
+    pages_per_chunk: int,
+    scale: float,
+):
+    """``_prefill_kernel``'s walk at the latent geometry: one pool, one key
+    row all heads share, its first Dv lanes the value. A tile's ``H x tq``
+    query rows are ONE left operand against a chunk (KVH 1, G = H), so there
+    is no head loop and no per-head copy of a landed chunk: the page buffer
+    is the right operand as it lies. Queries and output are head-major
+    ``[H, T, .]``, the layout the absorbing and unabsorbing products (a batch
+    of heads) have them in, so XLA transposes nothing around the call. Scores,
+    maximum, sum and accumulator are float32 and never leave VMEM; the softmax
+    scale multiplies the float32 scores (the published form's ``s * scale``),
+    not the bf16 queries."""
+    P = pages_per_chunk
+    b, j = pl.program_id(0), pl.program_id(1)
+    H, tq, Dv = ql_ref.shape[1:]
+    R = H * tq
+    bs = kbuf.shape[2]
+    CH = P * bs
+    layer = layer_ref[0]
+    q0 = start_ref[b] + j * tq               # the tile's first position
+    true_len = len_ref[b]
+    bound = jnp.minimum(true_len, q0 + tq)   # the tile sees context [0, bound)
+    nchunks = jnp.where(q0 < true_len, lax.div(bound + CH - 1, CH), 0)
+    # Chunks every query of the tile sees whole: all of it at or before q0.
+    nplain = jnp.minimum(lax.div(q0 + 1, CH), nchunks)
+
+    issue, wait = _page_fetch(layer, tables_ref, [k_hbm], [kbuf], sem, P, unroll=False)
+
+    def chunk_pages(c):
+        return jnp.minimum(lax.div(bound - c * CH + bs - 1, bs), P)
+
+    @pl.when(nchunks == 0)
+    def _():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(nchunks > 0)
+    def _():
+        slot_ref[0] = 0
+        issue(b, 0, 0, chunk_pages(0))
+        q_scr[:, :Dv] = ql_ref[0].reshape(R, Dv)
+        q_scr[:, Dv:] = qr_ref[0].reshape(R, qr_ref.shape[3])
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        # Row r = h*tq + t is query position q0 + t.
+        t = lax.rem(lax.broadcasted_iota(jnp.int32, (R, 1), 0), tq)
+        hz_scr[...] = jnp.minimum(q0 + t + 1, true_len)
+
+        def attend(c, cur, masked: bool):
+            """The chunk in buffer ``cur`` into the online softmax."""
+            k = kbuf[cur].reshape(CH, kbuf.shape[3])
+            if masked:
+                # Pages past the walk's end were not fetched and hold garbage
+                # (possibly NaN): as keys the score mask neutralizes them, as
+                # values they must be zero (0*NaN=NaN).
+                held = c * CH + lax.broadcasted_iota(jnp.int32, (CH, 1), 0) < bound
+                k = jnp.where(held, k, jnp.zeros_like(k))
+            s = lax.dot_general(
+                q_scr[...], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                          # [R, CH]
+            if masked:
+                pos = c * CH + lax.broadcasted_iota(jnp.int32, (1, CH), 1)
+                s = jnp.where(pos < hz_scr[...], s, NEG_INF)
+            m_prev = m_scr[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)                     # [R, 1]
+            p = jnp.exp(s - m_new)                             # [R, CH]
+            l_scr[...] = corr * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+            pv = lax.dot_general(
+                p.astype(k.dtype), k[:, :Dv], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                  # [R, Dv]
+            acc_scr[...] = acc_scr[...] * corr + pv
+            m_scr[...] = m_new
+
+        def chunk(c, carry):
+            # Software pipeline: the next chunk's pages are started before
+            # this one's are waited for.
+            cur = slot_ref[0]
+            nxt = 1 - cur
+            slot_ref[0] = nxt
+
+            @pl.when(c + 1 < nchunks)
+            def _():
+                issue(b, c + 1, nxt, chunk_pages(c + 1))
+
+            wait(cur, chunk_pages(c))
+            pl.when(c < nplain)(lambda: attend(c, cur, False))
+            pl.when(c >= nplain)(lambda: attend(c, cur, True))
+            return carry
+
+        # The bound is read before the loop, so interpret mode can discharge
+        # it (see _mq_kernel).
+        lax.fori_loop(0, nchunks, chunk, 0)
+        o = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+        o_ref[0] = o.astype(o_ref.dtype).reshape(H, tq, Dv)
+
+
+def latent_prefill_attention(
+    q_lat: jax.Array,        # [B, H, T, Dv] absorbed queries, head-major: the latent lanes,
+    q_rope: jax.Array,       # [B, H, T, Dk - Dv]   and the rope lanes (zero in the padding)
+    cache: jax.Array,        # [2L, N, bs, Dk] — the chunk's own rows already written
+    layer_idx: jax.Array,    # scalar int32 — cache layer (2*layer + sub-block)
+    block_tables: jax.Array, # [B, W] int32
+    start_pos: jax.Array,    # [B] int32
+    true_len: jax.Array,     # [B] int32 — 0: an inactive row
+    *, scale: float,
+    pages_per_chunk: int = 0,  # 0 → _LATENT_CHUNK_TOKENS a chunk
+    q_tile: int = 0,           # 0 → _latent_prefill_tile
+    interpret: bool = False,
+) -> jax.Array:
+    """``paged_prefill_attention`` over latent pages, in the absorbed form
+    decode attends in: query ``t`` of row ``b`` sits at ``start_pos[b] + t``
+    and attends ``[0, min(true_len[b], start_pos[b] + t + 1))`` straight out
+    of the pages, prefix and chunk alike. Work follows the context a tile
+    can see; the table's width costs nothing and a row with ``true_len`` 0
+    nothing. Operations a context position: 2 x T x H x (Dk + Dv), which at
+    this cache's widths is under the expanded form's (a position multiplied
+    out by W_kvb once, then 2 x T x H x 320) up to T ~ 157 and 1.6 times it
+    at T 256; against the expanded form as XLA ran it (the table's width,
+    float32 scores through HBM) it is less work at every T a 4,096-token
+    table allows. Returns [B, H, T, Dv] in q_lat.dtype; rows of queries at or
+    past ``true_len`` are unspecified."""
+    B, H, T, Dv = q_lat.shape
+    bs = cache.shape[2]
+    assert cache.shape[3] == Dv + q_rope.shape[3], "cache must be [2L, N, bs, Dk]"
+    P = pages_per_chunk or min(max(_LATENT_CHUNK_TOKENS // bs, 1), _MAX_PAGES_PER_CHUNK)
+    P = min(P, cache.shape[1])
+    tq = q_tile or _latent_prefill_tile(T, H, P * bs)
+    assert T % tq == 0, (T, tq)
+    return _latent_prefill_call(
+        q_lat, q_rope, cache, layer_idx, _whole_table(block_tables, P), start_pos, true_len,
+        scale=scale, P=P, tq=tq, interpret=interpret,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "P", "tq", "interpret"))
+def _latent_prefill_call(q_lat, q_rope, cache, layer_idx, block_tables, start_pos, true_len,
+                         *, scale: float, P: int, tq: int, interpret: bool):
+    B, H, T, Dv = q_lat.shape
+    bs, Dk = cache.shape[2:]
+    R = H * tq
+
+    def tile(lanes):
+        return pl.BlockSpec((1, H, tq, lanes), lambda b, j, *_: (b, 0, j, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, T // tq),
+        in_specs=[tile(Dv), tile(Dk - Dv), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=tile(Dv),
+        scratch_shapes=[
+            pltpu.VMEM((R, Dk), q_lat.dtype),
+            pltpu.VMEM((2, P, bs, Dk), cache.dtype),
+            pltpu.VMEM((R, Dv), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.float32),
+            pltpu.VMEM((R, 1), jnp.int32),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2, 1)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_prefill_kernel, pages_per_chunk=P, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, T, Dv), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_PREFILL_VMEM_BYTES),
+        interpret=interpret,
+        name="latent_prefill_attention",
+    )(
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        jnp.asarray(start_pos, jnp.int32),
+        jnp.asarray(true_len, jnp.int32),
+        jnp.asarray(block_tables, jnp.int32),
+        q_lat,
+        q_rope,
+        cache,
+    )

@@ -39,23 +39,24 @@ def prompt(n: int, seed: int = 0) -> list[int]:
 
 
 def serve_through_cache(params, dtype, toks, plen: int, mode: str, impl: str) -> np.ndarray:
-    # "pallas_interpret" takes both kernels in interpret mode: the latent
-    # decode attention and the megablox grouped product
+    # "pallas_interpret" takes every kernel in interpret mode: the latent
+    # prefill and decode attention and the megablox grouped product
     """Prefill ``toks[:plen]`` (cold, in two chunks, or its second half behind
     pages an earlier prefill cached) and decode the rest teacher-forced through
     the paged cache → float32 logits at positions plen-1 .. len(toks)-1."""
     cache = longcat.init_kv_cache(CFG, 32, BS, dtype)
     table = jnp.arange(1, 9, dtype=jnp.int32)
     kw = {"experts": "gmm_interpret"} if impl == "pallas_interpret" else {}
+    pkw = {**kw, "attn_impl": impl}
     pad = lambda xs, n: jnp.zeros((n,), jnp.int32).at[:len(xs)].set(jnp.asarray(xs, jnp.int32))  # noqa: E731
     if mode == "cold":
-        logits, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[:plen], 48), table, 0, plen, **kw)
+        logits, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[:plen], 48), table, 0, plen, **pkw)
     else:
         cut = 16  # whole blocks
-        _, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[:cut], 16), table, 0, cut, **kw)
+        _, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[:cut], 16), table, 0, cut, **pkw)
         if mode == "cached":  # another dispatch wrote the pages; only the table names them
             cache = jax.tree.map(jnp.copy, cache)
-        logits, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[cut:plen], 32), table, cut, plen, **kw)
+        logits, cache, _ = longcat.prefill(CFG, params, cache, pad(toks[cut:plen], 32), table, cut, plen, **pkw)
     out = [logits]
     for pos in range(plen, len(toks)):
         step, cache, _ = longcat.decode_step(
@@ -118,6 +119,37 @@ def _sub(params, layer: int, j: int) -> dict:
     return {name: params["layers"][f"{name}_{j}"][layer] for name in longcat._SUB_KEYS}
 
 
+def attend_expanded(q_n, q_r, ctx_latent, mask, sub: dict, cfg: ModelConfig):
+    """The published form, the reference the absorbed paths are held to (until
+    PR 44 the program's own prefill attention): q_n [B, T, H, dn], q_r
+    [B, T, H, dr] against the latents of the whole context ``ctx_latent``
+    [B, C, latent_dim] under the additive float32 ``mask`` [B, T, C] →
+    [B, T, H, dv], the latents multiplied out by W_kvb for every head."""
+    rkv = cfg.kv_lora_rank
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    c_kv, k_r = ctx_latent[..., :rkv], ctx_latent[..., rkv:]
+    k_n = jnp.einsum("bcl,hnl->bchn", c_kv, sub["w_uk"])
+    s = jnp.einsum("bthn,bchn->bhtc", q_n, k_n, preferred_element_type=jnp.float32)
+    s = s + jnp.einsum("bthr,bcr->bhtc", q_r, k_r, preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(s * scale + mask[:, None], axis=-1).astype(q_n.dtype)
+    return jnp.einsum("bhtc,bchv->bthv", p, jnp.einsum("bcl,hlv->bchv", c_kv, sub["w_uv"]))
+
+
+def _paged(latents, lengths, table_width: int):
+    """Rows of latents [B, n, latent_dim] into a pool's cache layer 1, row b's
+    first ``lengths[b]`` positions on pages of its own (from page 1) → (pool, tables)."""
+    B = latents.shape[0]
+    pool = jnp.zeros((2, 1 + B * table_width, BS, CFG.latent_page_width), latents.dtype)
+    tables = 1 + jnp.arange(B * table_width, dtype=jnp.int32).reshape(B, table_width)
+    for b, n in enumerate(lengths):
+        at = jnp.arange(n)
+        pool = pool.at[1, tables[b, at // BS], at % BS].set(longcat._pad_row(latents[b, :n], CFG))
+    return pool, tables
+
+
+_ATTN_KW = dict(value_dim=CFG.kv_lora_rank, scale=(CFG.qk_nope_head_dim + CFG.qk_rope_head_dim) ** -0.5)
+
+
 @pytest.mark.parametrize("kernel", ["xla", "pallas_interpret"])
 def test_absorbed_attention_equals_expanded(kernel):
     """One query over 37 cached latents: W_kvb multiplied into the keys and
@@ -130,19 +162,65 @@ def test_absorbed_attention_equals_expanded(kernel):
     pos = jnp.arange(n, dtype=jnp.int32)[None]
     q_n, q_r, latent = longcat.mla_project(h, sub, CFG, pos)
     mask = jnp.where(jnp.arange(n)[None, :] <= jnp.arange(n)[:, None], 0.0, -1e9)[None]
-    expanded = longcat.attend_expanded(q_n, q_r, latent, mask, sub, CFG)[0, -1]
-    pool = jnp.zeros((2, 8, BS, CFG.latent_page_width), jnp.float32)
-    table = jnp.arange(1, 6, dtype=jnp.int32)[None]
-    rows = longcat._pad_row(latent[0], CFG)
-    pool = pool.at[1, table[0, jnp.arange(n) // BS], jnp.arange(n) % BS].set(rows)
+    expanded = attend_expanded(q_n, q_r, latent, mask, sub, CFG)[0, -1]
+    pool, table = _paged(latent, [n], 5)
     q = longcat.absorb_query(q_n[:, -1], q_r[:, -1], sub, CFG)
-    kw = dict(value_dim=CFG.kv_lora_rank, scale=(CFG.qk_nope_head_dim + CFG.qk_rope_head_dim) ** -0.5)
     if kernel == "xla":
-        o = paged_attention.latent_decode_attention_xla(q, pool, 1, table, jnp.asarray([n]), **kw)
+        o = paged_attention.latent_decode_attention_xla(q, pool, 1, table, jnp.asarray([n]), **_ATTN_KW)
     else:
-        o = paged_attention.latent_decode_attention(q, pool, 1, table, jnp.asarray([n]), interpret=True, **kw)
+        o = paged_attention.latent_decode_attention(q, pool, 1, table, jnp.asarray([n]), interpret=True, **_ATTN_KW)
     absorbed = longcat.unabsorb_output(o, sub, CFG)[0]
     np.testing.assert_allclose(np.asarray(absorbed), np.asarray(expanded), atol=2e-5)
+
+
+# Prefill calls (T, table width, [(start_pos, true_len) a row]): positions
+# before ``start_pos`` are cached, the chunk's own rows are in their pages too.
+PREFILL_CALLS = {
+    "a_chunk_from_position_0": (24, 4, [(0, 21)]),
+    "a_suffix_behind_a_prefix_on_a_block_boundary": (16, 6, [(24, 37)]),
+    "a_suffix_behind_a_prefix_off_a_block_boundary": (16, 6, [(13, 27)]),   # the engine never asks; the kernel takes it
+    "the_second_chunk_of_a_chunked_prompt": (32, 8, [(32, 64)]),
+    "a_pack_of_2_at_their_own_start_pos": (16, 6, [(8, 20), (24, 40)]),
+    "a_pack_of_4_with_an_inactive_row": (16, 6, [(0, 9), (32, 45), (0, 0), (16, 32)]),
+    "a_table_wider_than_the_context": (8, 40, [(8, 13)]),
+}
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("call", list(PREFILL_CALLS))
+def test_absorbed_prefill_attention_equals_expanded(call, kernel):
+    """A chunk of T queries a row behind whatever the row's pages hold, out of
+    the pages (the kernel in interpret mode walks 2 pages a chunk and 8 queries
+    a tile, so every call crosses chunks and tiles), against the published form
+    over each row's own context under a causal mask. Queries at or past a
+    row's ``true_len`` are unspecified, an inactive row's all are."""
+    T, W, rows = PREFILL_CALLS[call]
+    B, C = len(rows), max(n for _, n in rows)
+    params = longcat.init_params(CFG, jax.random.PRNGKey(1), jnp.float32)
+    sub = _sub(params, 0, 1)
+    h = jax.random.normal(jax.random.PRNGKey(3), (B, C, CFG.hidden_size), jnp.float32)
+    q_n, q_r, latent = longcat.mla_project(h, sub, CFG, jnp.broadcast_to(jnp.arange(C), (B, C)))
+    pool, tables = _paged(latent, [n for _, n in rows], W)
+    start, true_len = (jnp.asarray(a, jnp.int32) for a in zip(*rows))
+    at = jnp.minimum(start[:, None] + jnp.arange(T)[None], C - 1)                # [B, T]
+    take = lambda a: jnp.take_along_axis(a, at[:, :, None, None], axis=1)       # noqa: E731
+    # head-major, the latent and the rope lanes apart: what prefill_batch_impl hands the attention
+    q_lat = jnp.einsum("bthn,hnc->bhtc", take(q_n), sub["w_uk"])
+    q_rope = longcat._pad_row(jnp.moveaxis(take(q_r), 2, 1), CFG, CFG.latent_page_width - CFG.kv_lora_rank)
+    scale = _ATTN_KW["scale"]
+    if kernel == "xla":
+        o = paged_attention.latent_prefill_attention_xla(q_lat, q_rope, pool, 1, tables, start, true_len, scale=scale)
+    else:
+        o = paged_attention.latent_prefill_attention(
+            q_lat, q_rope, pool, 1, tables, start, true_len, scale=scale,
+            pages_per_chunk=2, q_tile=8, interpret=True)
+    absorbed = jnp.einsum("bhtc,hcv->bthv", o, sub["w_uv"])
+    mask = jnp.where(jnp.arange(C)[None, None, :] <= at[:, :, None], 0.0, -1e9)
+    expanded = attend_expanded(take(q_n), take(q_r), latent, mask, sub, CFG)
+    live = np.asarray(start[:, None] + jnp.arange(T)[None] < true_len[:, None])
+    assert live.any(axis=1).tolist() == [n > 0 for _, n in rows]
+    np.testing.assert_allclose(np.asarray(absorbed)[live], np.asarray(expanded)[live], atol=2e-5)
+    assert np.isfinite(np.asarray(absorbed)).all()  # an inactive row's output is defined, not garbage
 
 
 # -- the router ------------------------------------------------------------------
@@ -416,6 +494,9 @@ def test_the_runner_takes_its_programs_from_the_blocks_module(preset, hist):
                                     max_model_len=64, dtype="float32"))
     runner.start()
     assert ("experts=ragged_dot" in runner._start_line("")) == hist
+    # both blocks' prefill resolves one way: the gather form off the TPU, with no reason of the block's own
+    assert "attention: prefill=xla decode=xla" in runner._start_line("")
+    assert runner._prefill_kw == {"attn_impl": "xla"}
     table = np.arange(1, 5, dtype=np.int32)
     ref = runner.prefill_chunk(np.zeros((16,), np.int32), table, 0, 9)
     assert (ref.hist is not None) == hist and len(ref.arrs) == 1

@@ -674,3 +674,10 @@ def test_resolve_prefill_impl():
     assert resolve_prefill_impl("pallas", ModelConfig.preset("llama-1b"), 16, False) == ("pallas", "")
     # interpret mode checks none of the compiler's rules
     assert resolve_prefill_impl("pallas_interpret", tiny, 4, False) == ("pallas_interpret", "")
+    # latent pages are asked about as latent pages: one 192-lane "KV head" is
+    # no geometry of theirs, their row and its value slice are
+    latent = ModelConfig(block="longcat", num_kv_heads=1, head_dim=192, num_heads=64,
+                         kv_lora_rank=512, qk_rope_head_dim=64, qk_nope_head_dim=128, v_head_dim=128)
+    assert latent.kv_size % 128 and resolve_prefill_impl("pallas", latent, 32, False) == ("pallas", "")
+    impl, why = resolve_prefill_impl("pallas", ModelConfig.preset("longcat-tiny"), 8, False)
+    assert impl == "xla" and "latent page row" in why

@@ -376,6 +376,55 @@ def test_latent_decode_kernel_compiles_for_v5e(v5e):
     fn.lower(*args).compile()
 
 
+# The LongCat cell's prefill calls (rows, T): singles at the turns' buckets and
+# a 2,048-token chunk, and the packs ``EngineArgs.pack_shapes`` gives its limit.
+LATENT_PREFILL_CALLS = [(1, 64), (1, 128), (1, 192), (1, 256), (1, 2048), (2, 128), (2, 192), (4, 64), (4, 96)]
+LW = 136  # the cell's table: 4,096 positions and a chunk's overhang, in blocks of 32
+
+
+def _latent_prefill_case(rows: int, T: int, W: int, sharding=None):
+    """Head-major absorbed queries (the latent lanes, and the rope lanes padded
+    to a lane tile) against the cell's pool of 640-lane rows."""
+    from dynamo_tpu.ops.paged_attention import latent_prefill_attention
+
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
+    fn = jax.jit(functools.partial(latent_prefill_attention, scale=192 ** -0.5))
+    return fn, (S((rows, 64, T, 512), jnp.bfloat16), S((rows, 64, T, 128), jnp.bfloat16),
+                S((8, 5632, LBS, 640), jnp.bfloat16), S((), jnp.int32),
+                S((rows, W), jnp.int32), S((rows,), jnp.int32), S((rows,), jnp.int32))
+
+
+@pytest.mark.parametrize("rows,T", LATENT_PREFILL_CALLS)
+def test_latent_prefill_kernel_compiles_for_v5e(v5e, rows, T):
+    """64 heads x 32 positions are the one left operand of a tile (2,048 rows
+    against a 512-token chunk: 4 MiB of float32 scores in VMEM, where the
+    dense kernel's 128 positions and 1,024 tokens would be 32), behind the
+    cell's table."""
+    from dynamo_tpu.ops.paged_attention import _latent_prefill_tile
+
+    assert _latent_prefill_tile(T, 64, 512) == 32
+    fn, args = _latent_prefill_case(rows, T, LW, sharding=v5e)
+    fn.lower(*args).compile()
+
+
+def test_latent_prefill_kernel_walks_tiles_not_the_table():
+    """The structural pin, as the dense kernel's: rows x query tiles whatever
+    the table's width, one kernel for both widths, and two products a chunk
+    (plain and masked), neither with a transposed left operand: no head loop."""
+    calls = []
+    for W in (8, LW):
+        fn, args = _latent_prefill_case(2, 64, W)
+        (call,) = _eqns(jax.make_jaxpr(fn)(*args).jaxpr, "pallas_call")
+        assert tuple(call.params["grid_mapping"].grid) == (2, 2)
+        calls.append(call)
+    assert str(calls[0].params["jaxpr"]) == str(calls[1].params["jaxpr"])
+    dots = _eqns(call.params["jaxpr"], "dot_general")
+    assert len(dots) == 4
+    for eqn in dots:
+        (lhs_contract, _), _ = eqn.params["dimension_numbers"]
+        assert tuple(lhs_contract) == (eqn.invars[0].aval.ndim - 1,), eqn
+
+
 # The grouped product's calls in the two expert cells: (assignment rows, K, N,
 # groups in the stack): LongCat's decode window (128 rows x 12) and a 512-token
 # part of a chunk, 16 experts of 4 layers in one stack; LFM2's decode window
@@ -462,7 +511,7 @@ def test_longcat_programs_compile_for_v5e(v5e, program):
     from dynamo_tpu.engine import longcat
 
     params = _abstract(jax.eval_shape(lambda: longcat.init_params(cfg, jax.random.PRNGKey(0))), S)
-    W, N = 4096 // LBS, 2048
+    W, N = LW, 2048
     cache = _abstract(jax.eval_shape(lambda: longcat.init_kv_cache(cfg, N, LBS)), S)
     i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
     if program == "decode_window":
@@ -477,8 +526,20 @@ def test_longcat_programs_compile_for_v5e(v5e, program):
     else:
         T = int(program.rsplit("_", 1)[1])
         compiled = longcat.prefill_batch.lower(
-            cfg, params, cache, i32(1, T), i32(1, W), i32(1), i32(1), experts="gmm"
+            cfg, params, cache, i32(1, T), i32(1, W), i32(1), i32(1), attn_impl="pallas", experts="gmm"
         ).compile()
+        # The prefill attends out of the pages in the kernel: nothing in the
+        # program has the table's ``W*bs`` rows (the gather of the latents) or
+        # columns (the float32 scores, which the XLA form wrote to HBM: 0.54 GB
+        # of temporaries at T 2,048). What is left, 0.476 GB at T 2,048 and
+        # 0.188 at 256, is the expert layer's 512-token part (at 256 tokens a
+        # part it reads 0.390, the absorbed queries and attended latents).
+        hlo = compiled.as_text()
+        assert "latent_prefill_attention" in hlo
+        wide = [ln.strip()[:120] for ln in hlo.splitlines()
+                if re.search(rf"[\[,]({W * LBS}|{W * LBS + T})[\],]", ln)]
+        assert not wide, "\n".join(wide[:10])
+        assert compiled.memory_analysis().temp_size_in_bytes < {2048: 0.5e9, 256: 0.2e9}[T]
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
 
 
@@ -590,7 +651,7 @@ def test_a_packed_wave_is_one_grouped_product_a_layer_and_no_larger_than_a_singl
         cfg, mod, kw, N = dataclasses.replace(_lfm2(4), num_dense_layers=1), lfm2, {"attn_impl": "pallas"}, 5632
         expert_layers = 3
     else:
-        cfg, mod, kw, N, expert_layers = _longcat(), longcat, {}, 2048, 1
+        cfg, mod, kw, N, expert_layers = _longcat(), longcat, {"attn_impl": "pallas"}, 2048, 1
     params = _abstract(jax.eval_shape(lambda: mod.init_params(cfg, jax.random.PRNGKey(0))), S)
     cache = _abstract(jax.eval_shape(lambda: mod.init_kv_cache(cfg, N, LBS)), S)
     W = 4096 // LBS

@@ -45,10 +45,13 @@ def published(name: str) -> tuple[EngineArgs, int]:
     return eargs, pack_limit(cfg, weight_bytes, V5E)
 
 
+LONGCAT_SHAPES = ((2, 128), (2, 192), (4, 64), (4, 96))
+
+
 @pytest.mark.parametrize("name,lo,hi,shapes", [
     ("qwen2.5-7b-int8", 110, 127, ()),
     ("mistral-7b-v0.3-int8", 110, 127, ()),
-    ("longcat-flash-omni-ep32", 400, 500, ()),
+    ("longcat-flash-omni-ep32", 400, 500, LONGCAT_SHAPES),
     ("lfm2-24b-a2b-pp4", 1500, 1900, ((2, 384), (2, 512), (4, 256), (4, 384))),
 ])
 def test_the_limit_comes_from_the_models_bytes_and_operations(name, lo, hi, shapes):
@@ -56,24 +59,34 @@ def test_the_limit_comes_from_the_models_bytes_and_operations(name, lo, hi, shap
     four rows of the smallest bucket: every suffix goes alone as it always
     did and no program is compiled; 64 small experts all held stay bound by
     their bytes to ~1,700, so four session turns share one weight stream; the
-    latent block's limit of ~445 has to hold, beside a row's tokens, the ~98
-    tokens' worth of operations that expanding the table's 4,096 latents costs
-    a row: four of those do not fit either. No program holds more than
-    ``max_prefill_tokens``."""
+    latent block's limit of ~445 holds four rows of its two smallest buckets
+    and pairs up to 192 tokens since its prefill attends out of the pages in a
+    kernel that walks only what a row can see (until PR 44 a row cost the
+    table's 4,096 latents multiplied out, ~99 tokens' worth, and four of those
+    did not fit). No block has a cost a row beside its tokens now. No program
+    holds more than ``max_prefill_tokens``."""
     eargs, limit = published(name)
     row = pack_row_tokens(eargs.model, eargs.max_model_len)
-    assert lo <= limit <= hi and row == (99 if name.startswith("longcat") else 0)
+    assert lo <= limit <= hi and row == 0
     assert eargs.pack_shapes(limit, row) == shapes
     assert all(rows * (t + row) <= limit and rows * t <= eargs.max_prefill_tokens for rows, t in shapes)
     turns = [150, 190, 170, 130]   # four session turns: the 192 bucket
     packs = eargs.plan_prefill_packs(turns, shapes)
     if name.startswith("lfm2"):
         assert packs == [([1, 2, 0, 3], 4, 256)]
+    elif name.startswith("longcat"):
+        # four rows of 192 pass the limit: two pairs, each one weight stream
+        assert packs == [([1, 2], 2, 192), ([0, 3], 2, 192)]
+        # a wave of that cell's suffixes (64-256 new tokens): the longest alone
+        # at its own bucket, the rest in pairs at the smallest T that holds them
+        assert eargs.plan_prefill_packs([100, 250, 180, 70, 140], shapes) == [
+            ([1], 1, 256), ([2, 4], 2, 192), ([0, 3], 2, 128)]
+        assert eargs.plan_prefill_packs([60, 90, 64, 33], shapes) == [([1, 2, 0, 3], 4, 96)]
     else:
         assert packs == [([i], 1, 192) for i in (1, 2, 0, 3)]
         assert [rows for _, rows, _ in eargs.plan_prefill_packs([40, 30], shapes)] == [1, 1]
         # The same weights in bf16 double the limit, and short suffixes pack.
-        assert name.startswith("longcat") or (4, 32) in eargs.pack_shapes(2 * limit, row)
+        assert (4, 32) in eargs.pack_shapes(2 * limit, row)
 
 
 def test_the_chip_is_looked_up_by_kind_and_an_unknown_one_is_a_v5e_and_says_so():
