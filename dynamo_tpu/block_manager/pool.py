@@ -16,6 +16,37 @@ States here:
 
 Block id 0 is reserved as the garbage sink for padded writes (model.py
 contract) and never allocated.
+
+**A second kind of cache: state slots** (``state_slots`` > 0; a
+``block="sala"`` model, engine/sala.py). A lightning layer's state is a matrix
+a head, megabytes a sequence, so it cannot ride every block as pages do: the
+pool hands out ``state_slots`` slots of the device's state pool (slot 0 is the
+sink of padded rows) under one policy, which has no knob:
+
+- A running sequence owns a **pair** (``acquire_state_pair``): its live state
+  rests in ``pair[(position // block_size) % 2]``, so the decode step that opens
+  a block leaves the block before's end state behind in the other slot.
+- A **snapshot** is a slot tied to a sealed block's hash: "the state after this
+  block's last token" (``take_snapshot``: the caller's next dispatch writes
+  it). A prefill takes one at the last block boundary of every chunk, in place
+  of the one its chunk before took (a sequence's own older snapshots are worth
+  nothing beside its newest), and one where its cached pages ended deeper than
+  any snapshot: there a chain that is already cached and this one part, a
+  shared prompt's end, which the next to share it resumes from. A sequence
+  that finishes or is preempted leaves the one its decode last left, or its
+  live state where it stopped on a boundary (``release_state_pair`` keeps that
+  slot and frees the other).
+- A prefix hit is only as deep as the deepest block of the chain that has its
+  pages **and** a snapshot (``snapshot_depth``); prefill resumes there from the
+  snapshot and recomputes the rest. A chain without one stays usable up to the
+  deepest snapshot before it.
+- Snapshots are evicted by their own LRU when a slot is needed (a hit makes
+  one the newest; a branch point, which two chains or more continue from, goes
+  only when nothing else is left), and with their block when its page is
+  evicted. A snapshot an admission of this wave
+  resumes from is pinned until the wave's prefills are dispatched
+  (``unpin_states``): the device's stream is serial, so what is dispatched
+  later cannot overwrite what an earlier dispatch still has to read.
 """
 
 from __future__ import annotations
@@ -50,6 +81,7 @@ class BlockPool:
         block_size: int,
         event_sink: EventSink | None = None,
         enable_prefix_caching: bool = True,
+        state_slots: int = 0,
     ):
         if num_blocks < 2:
             raise ValueError("need at least 2 blocks (block 0 is reserved)")
@@ -74,6 +106,14 @@ class BlockPool:
         # prefix-cache observability
         self.hit_blocks = 0
         self.miss_blocks = 0
+        # State slots (module docstring): the free ones, the snapshots by
+        # block hash (oldest first), those pinned for this wave's prefills.
+        self.state_slots = state_slots
+        self._state_free: list[int] = list(range(state_slots - 1, 0, -1))
+        self._snapshots: OrderedDict[int, int] = OrderedDict()  # seq_hash → slot
+        self._state_pinned: set[int] = set()
+        self.state_snapshots = {"chunk_end": 0, "decode_boundary": 0}
+        self.state_evictions = 0
 
     # -- events -----------------------------------------------------------
 
@@ -128,14 +168,18 @@ class BlockPool:
                 out.append(bid)
             return out
 
-    def allocate_sequence(self, seq_hashes: list[int], total_blocks: int) -> tuple[list[int], int]:
+    def allocate_sequence(self, seq_hashes: list[int], total_blocks: int,
+                          max_hit: int | None = None) -> tuple[list[int], int]:
         """Allocate ``total_blocks`` for a sequence whose complete-prompt
-        block hashes are ``seq_hashes``. Reuses cached prefix blocks.
+        block hashes are ``seq_hashes``. Reuses cached prefix blocks, at most
+        ``max_hit`` of them (a chain cut back to its deepest state snapshot:
+        the blocks past it are computed again, into fresh pages, and count
+        as misses).
 
         → (block_ids, num_hit_blocks). Raises NoFreeBlocksError (nothing
         allocated) if the pool can't satisfy the request."""
         with self._lock:
-            hits = self.match_prefix(seq_hashes)
+            hits = self.match_prefix(seq_hashes)[:max_hit]
             need_new = total_blocks - len(hits)
             if need_new > len(self._free) + len(self._lru) - self._lru_overlap(hits):
                 raise NoFreeBlocksError(f"need {need_new}, have {self.num_free}")
@@ -181,6 +225,7 @@ class BlockPool:
     def _evict(self, bid: int) -> None:
         b = self._blocks[bid]
         if b.seq_hash is not None:
+            self._drop_snapshot(b.seq_hash)
             self._cached.pop(b.seq_hash, None)
             self._emit(KvCacheEvent.removed([b.seq_hash]))
             self._drop_child(b.parent_hash)
@@ -213,6 +258,97 @@ class BlockPool:
         else:
             b.seq_hash = None
             self._free.append(bid)
+
+    # -- state slots --------------------------------------------------------
+
+    def snapshot_depth(self, seq_hashes: list[int]) -> tuple[int, int]:
+        """→ (blocks, slot): how deep into the chain of ``seq_hashes`` a
+        prefix hit may go, the deepest block that has its page and a state
+        snapshot, and the slot that holds it (0, 0: none, start from zero).
+        The slot is pinned until ``unpin_states`` and becomes the newest."""
+        with self._lock:
+            depth, slot = 0, 0
+            for i, h in enumerate(seq_hashes):
+                if h not in self._cached:
+                    break
+                if h in self._snapshots:
+                    depth, slot = i + 1, self._snapshots[h]
+            if depth:
+                self._snapshots.move_to_end(seq_hashes[depth - 1])
+                self._state_pinned.add(slot)
+            return depth, slot
+
+    def _pop_state(self) -> int:
+        if self._state_free:
+            return self._state_free.pop()
+        # Oldest first, a branch point's (a shared prompt's end, which several
+        # continuations diverge from) only when nothing else is left.
+        for spare_branches in (True, False):
+            for h, slot in self._snapshots.items():
+                if slot not in self._state_pinned and not (spare_branches and self._children.get(h, 0) >= 2):
+                    del self._snapshots[h]
+                    self.state_evictions += 1
+                    return slot
+        raise NoFreeBlocksError("no state slot is free and no snapshot can be evicted")
+
+    def _drop_snapshot(self, seq_hash: int) -> None:
+        slot = self._snapshots.get(seq_hash)
+        if slot is None or slot in self._state_pinned:
+            return  # none, or an admission still reads it: it stays until the LRU takes it
+        del self._snapshots[seq_hash]
+        self._state_free.append(slot)
+
+    def acquire_state_pair(self) -> tuple[int, int]:
+        """Two slots for a sequence that starts to run. Raises
+        NoFreeBlocksError (nothing taken) where they cannot be had."""
+        with self._lock:
+            a = self._pop_state()
+            try:
+                return a, self._pop_state()
+            except NoFreeBlocksError:
+                self._state_free.append(a)
+                raise
+
+    def take_snapshot(self, seq_hash: int, why: str, replaces: int | None = None) -> int:
+        """A slot for the snapshot of the block ``seq_hash``, which the caller's
+        next dispatch writes; 0 (the sink: no snapshot) where the block has one
+        already or no slot can be had. ``replaces``: the block of the snapshot
+        the same prefill took a chunk earlier, which goes (unless it has become
+        a branch point or an admission of this wave reads it)."""
+        with self._lock:
+            if seq_hash in self._snapshots or not self.enable_prefix_caching:
+                return 0
+            if replaces is not None and self._children.get(replaces, 0) < 2:
+                self._drop_snapshot(replaces)
+            try:
+                slot = self._pop_state()
+            except NoFreeBlocksError:
+                return 0
+            self._snapshots[seq_hash] = slot
+            self.state_snapshots[why] += 1
+            return slot
+
+    def release_state_pair(self, pair: tuple[int, int], keep: tuple[int, int] | None = None) -> None:
+        """A sequence stops running. ``keep`` = (slot of the pair, block hash):
+        that slot holds the state after that sealed block and stays as its
+        snapshot (unless the block has one); the rest of the pair is free."""
+        with self._lock:
+            for slot in pair:
+                if (keep is not None and slot == keep[0] and keep[1] not in self._snapshots
+                        and self.enable_prefix_caching):
+                    self._snapshots[keep[1]] = slot
+                else:
+                    self._state_free.append(slot)
+
+    def unpin_states(self) -> None:
+        """The wave's prefills are on the device's queue."""
+        with self._lock:
+            self._state_pinned.clear()
+
+    @property
+    def num_snapshots(self) -> int:
+        with self._lock:
+            return len(self._snapshots)
 
     # -- registration (block completion) ----------------------------------
 
@@ -279,6 +415,7 @@ class BlockPool:
                 self._lru.pop(bid)
                 b = self._blocks[bid]
                 if b.seq_hash is not None:
+                    self._drop_snapshot(b.seq_hash)
                     self._cached.pop(b.seq_hash, None)
                     dropped.append(b.seq_hash)
                     self._drop_child(b.parent_hash)

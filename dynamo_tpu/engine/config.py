@@ -91,6 +91,30 @@ class ModelConfig:
     layer_types: tuple[str, ...] = ()
     num_dense_layers: int = 0
     conv_L_cache: int = 3
+    # -- block="sala" (MiniCPM-SALA, engine/sala.py) -----------------------
+    # Each layer's mixer as published ("minicpm4": block-sparse GQA attention;
+    # "lightning-attn": linear attention with a [head_dim, head_dim] state a
+    # head), the lightning heads, the stream's scalings (x0 = scale_emb *
+    # embed; a branch times scale_depth / sqrt(layers); logits on the normed
+    # stream / (hidden / dim_model_base)), and the sparse layers' sizes
+    # (InfLLM-v2: compressed keys of ``sparse_kernel_size`` tokens every
+    # ``sparse_kernel_stride``, ``sparse_topk`` blocks of ``sparse_block_size``
+    # tokens kept past ``sparse_dense_len`` visible positions, the first
+    # ``sparse_init_blocks`` and those of the last ``sparse_window_size``
+    # positions among them).
+    mixer_types: tuple[str, ...] = ()
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    dim_model_base: int = 0
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window_size: int = 2048
+    sparse_dense_len: int = 8192
 
     @property
     def q_size(self) -> int:
@@ -113,6 +137,8 @@ class ModelConfig:
 
     @property
     def cache_layers(self) -> int:
+        if self.block == "sala":
+            return len(self.sparse_layers)
         if self.block == "lfm2":
             return len(self.attn_layers)
         return 2 * self.num_layers if self.block == "longcat" else self.num_layers
@@ -135,6 +161,34 @@ class ModelConfig:
     @property
     def conv_state_slots(self) -> int:
         return self.conv_L_cache - 1
+
+    @property
+    def sparse_layers(self) -> tuple[int, ...]:
+        """The layers of a ``block="sala"`` model that hold K and V pages."""
+        return tuple(i for i, t in enumerate(self.mixer_types) if t == "minicpm4")
+
+    @property
+    def lightning_layers(self) -> tuple[int, ...]:
+        """Its layers that carry a matrix state a head instead."""
+        return tuple(i for i, t in enumerate(self.mixer_types) if t == "lightning-attn")
+
+    @property
+    def lightning_size(self) -> int:
+        return self.lightning_heads * self.lightning_head_dim
+
+    @property
+    def state_values(self) -> int:
+        """The values of one sequence's lightning state over every such layer."""
+        return len(self.lightning_layers) * self.lightning_heads * self.lightning_head_dim ** 2
+
+    def _sala_params(self) -> int:
+        d, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        ffn = 3 * d * i + 2 * d
+        sparse = 3 * d * self.q_size + 2 * d * self.kv_size + 2 * self.head_dim
+        light = 5 * d * self.lightning_size + 2 * self.lightning_head_dim + self.lightning_size
+        head = 0 if self.tie_embeddings else d * v
+        return (v * d + d + head + len(self.sparse_layers) * (sparse + ffn)
+                + len(self.lightning_layers) * (light + ffn))
 
     @property
     def router_width(self) -> int:
@@ -174,6 +228,8 @@ class ModelConfig:
 
     def param_count(self) -> int:
         d, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
+        if self.block == "sala":
+            return self._sala_params()
         if self.block == "lfm2":
             return self._lfm2_params(self.num_experts)
         if self.block == "longcat":
@@ -294,6 +350,21 @@ class ModelConfig:
                 num_routed_experts=8, num_experts_per_token=2,
                 moe_intermediate_size=64, routed_scaling_factor=1.0,
                 router_scoring="sigmoid", use_expert_bias=True, norm_topk_prob=True,
+            ),
+            # MiniCPM-SALA block at toy widths (CPU tests): sparse, lightning x3,
+            # sparse, lightning; compressed keys of 4 tokens every 2, blocks of
+            # 8, 5 kept past 32 visible positions (the first and those of the
+            # last 8 positions among them).
+            "sala-tiny": ModelConfig(
+                name="sala-tiny", block="sala", vocab_size=512, hidden_size=128,
+                intermediate_size=256, num_layers=6, num_heads=4, num_kv_heads=2,
+                head_dim=32, rope_theta=10000.0, rms_norm_eps=1e-6, tie_embeddings=False,
+                mixer_types=("minicpm4", "lightning-attn", "lightning-attn", "lightning-attn",
+                             "minicpm4", "lightning-attn"),
+                lightning_heads=4, lightning_head_dim=32, scale_emb=12.0, scale_depth=1.4,
+                dim_model_base=32, sparse_kernel_size=4, sparse_kernel_stride=2,
+                sparse_block_size=8, sparse_topk=5, sparse_init_blocks=1,
+                sparse_window_size=8, sparse_dense_len=32,
             ),
             # Llama-3-70B-class (BASELINE.md north-star target, multi-host)
             "llama-70b": ModelConfig(
@@ -638,6 +709,41 @@ class EngineArgs:
                     f"model {m.name!r} has block='lfm2' (conv state beside K and V "
                     f"pages, routed experts), which cannot run with: {'; '.join(refused)}"
                 )
+        if self.model.block == "sala":
+            m = self.model
+            kinds = {"minicpm4", "lightning-attn"}
+            if len(m.mixer_types) != m.num_layers or set(m.mixer_types) - kinds or not m.sparse_layers \
+                    or m.mixer_types[0] != "minicpm4":
+                raise ValueError(
+                    f"model {m.name!r}: mixer_types must name 'minicpm4' or 'lightning-attn' for each "
+                    f"of its {m.num_layers} layers, the first a 'minicpm4'; got {m.mixer_types!r}")
+            forced = m.sparse_init_blocks + m.sparse_window_size // m.sparse_block_size + 1
+            if (m.sparse_kernel_size != 2 * m.sparse_kernel_stride
+                    or m.sparse_block_size != 4 * m.sparse_kernel_stride or forced > m.sparse_topk
+                    or m.sparse_dense_len % m.sparse_block_size):
+                raise ValueError(
+                    f"model {m.name!r}: the sparse layers take compressed keys of two strides, four "
+                    f"to a block, a dense_len of whole blocks and the {forced} forced blocks inside "
+                    f"the top-k; got kernel {m.sparse_kernel_size}, stride {m.sparse_kernel_stride}, "
+                    f"block {m.sparse_block_size}, topk {m.sparse_topk}, dense_len {m.sparse_dense_len}")
+            refused = [
+                what for on, what in (
+                    (self.kv_quant != "none", "--kv-quant int8 (no int8 form of the state pool)"),
+                    (self.spec_tokens > 0, "speculation (--spec-tokens; a rejected draft cannot roll the matrix state back)"),
+                    (self.lora_slots > 0, "LoRA banks (--lora-slots)"),
+                    (self.tp > 1, "--tp (the state pool and its step kernel are single-device)"),
+                    (bool(self.host_kv_blocks or self.disk_kv_dir or self.fleet_kv_dir),
+                     "KV tiers (--host-kv-blocks, --disk-kv-dir, --fleet-kv-dir)"),
+                    (self.block_size != m.sparse_block_size,
+                     f"--block-size {self.block_size} (a page is the sparse layers' block of "
+                     f"{m.sparse_block_size} tokens)"),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    f"model {m.name!r} has block='sala' (a matrix state a lightning layer beside "
+                    f"the sparse layers' pages), which cannot run with: {'; '.join(refused)}"
+                )
         if self.max_model_len % self.block_size:
             self.max_model_len = ((self.max_model_len // self.block_size) + 1) * self.block_size
         if self.max_prefill_tokens % self.block_size:
@@ -852,7 +958,7 @@ class EngineArgs:
             # One pool, 2L cache layers, the row padded to whole lane tiles.
             itemsize = 2 if self.dtype == "bfloat16" else 4
             return m.cache_layers * self.block_size * m.latent_page_width * itemsize
-        if m.block == "lfm2":
+        if m.block in ("lfm2", "sala"):
             return sum(self.pool_bytes_per_block().values())
         elems = self.block_size * m.num_kv_heads * m.head_dim
         if self.kv_quant == "int8":
@@ -868,13 +974,40 @@ class EngineArgs:
         alone, and for a ``block="lfm2"`` model the K and V of its attention
         layers under "kv" and its conv layers' state under "conv"."""
         m = self.model
+        itemsize = 2 if self.dtype == "bfloat16" else 4
+        if m.block == "sala":
+            # The state pool is slots, not blocks: state_pool_bytes.
+            per_layer = m.kv_size * itemsize * len(m.sparse_layers)
+            return {"kv": 2 * self.block_size * per_layer,
+                    "ckeys": (self.block_size // m.sparse_kernel_stride) * per_layer}
         if m.block != "lfm2":
             return {"kv": self.kv_bytes_per_block()}
-        itemsize = 2 if self.dtype == "bfloat16" else 4
         return {
             "kv": 2 * len(m.attn_layers) * self.block_size * m.kv_size * itemsize,
             "conv": len(m.conv_layers) * m.conv_state_slots * m.hidden_size * itemsize,
         }
+
+    @property
+    def state_slots(self) -> int:
+        """Slots of a ``block="sala"`` model's state pool (0 for any other),
+        sized from bytes as the pages are: the pool gets the bytes that
+        ``num_kv_blocks`` pages take (the snapshots an idle session leaves and
+        the pair it runs in weigh about what its pages do at the contexts such
+        a model is served for), and never fewer than two slots a running
+        sequence (its live state and the snapshot its decode left at the last
+        block boundary), two to spare and slot 0, the sink of padded rows. No
+        knob of its own: the policy is block_manager/pool.py's."""
+        if self.model.block != "sala":
+            return 0
+        by_bytes = self.num_kv_blocks * self.kv_bytes_per_block() // self.state_slot_bytes()
+        return max(by_bytes, 2 * self.max_num_seqs + 3)
+
+    def state_slot_bytes(self) -> int:
+        """One slot: a sequence's state in the cache's dtype (engine/sala.py)."""
+        return self.model.state_values * (2 if self.dtype == "bfloat16" else 4)
+
+    def state_pool_bytes(self) -> int:
+        return self.state_slots * self.state_slot_bytes()
 
     def replace(self, **kw) -> "EngineArgs":
         return dataclasses.replace(self, **kw)
@@ -883,5 +1016,7 @@ class EngineArgs:
     def auto_kv_blocks(hbm_bytes_free: int, args: "EngineArgs", utilization: float = 0.9) -> int:
         """vLLM-style: size the G1 pool from free HBM after weights."""
         per_block = args.kv_bytes_per_block()
+        if args.model.block == "sala":
+            per_block *= 2  # the state pool takes as many bytes again (state_slots)
         n = int(hbm_bytes_free * utilization) // per_block
         return max(n, args.blocks_per_seq * 2)

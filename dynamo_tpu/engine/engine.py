@@ -67,6 +67,7 @@ from dynamo_tpu.engine.grammar import (
 from dynamo_tpu.engine.model import block_module, refuse_block
 from dynamo_tpu.engine.runner import host_ready, start_host_fetch
 from dynamo_tpu.engine.sampler import needs_full, row_needs_full
+from dynamo_tpu.ops.sparse_attention import choice_counts
 from dynamo_tpu.kv_router.protocols import ForwardPassMetrics, KvCacheEvent, KvStats, WorkerStats
 from dynamo_tpu.llm.protocols import (
     FinishReason,
@@ -159,6 +160,7 @@ class _Seq:
         "adapter_id", "adapter_slot", "hash_seed",
         "qos", "qos_rank", "arrival",
         "step_base", "mig", "offer_deadline", "traceparent",
+        "state_pair", "state_src", "state_chunk", "state_diverge", "state_plen", "state_hi",
     )
 
     def __init__(self, request_id: str, req: PreprocessedRequest, queue: asyncio.Queue):
@@ -176,6 +178,17 @@ class _Seq:
         self.cancelled = False
         self.preempted = False
         self.prefix_hit_blocks = 0
+        # block="sala" (block_manager/pool.py, state slots): the pair this
+        # sequence's state lives in while it runs, the snapshot slot its
+        # prefill resumes from (0: from zero), the block of the snapshot its
+        # own prefill took last, its length at admission and the highest
+        # position a dispatch has written state for.
+        self.state_pair: tuple[int, int] | None = None
+        self.state_src = 0
+        self.state_chunk: int | None = None
+        self.state_diverge = 0  # where its cached pages ended, if deeper than any snapshot
+        self.state_plen = 0
+        self.state_hi = -1
         # Seeded requests are reproducible; others get a per-request seed.
         self.sample_seed = (
             req.sampling.seed if req.sampling.seed is not None else random.getrandbits(31)
@@ -624,8 +637,57 @@ def register_engine_metrics(registry) -> dict:
             "kv_pool_bytes",
             "HBM bytes of the G1 pool by kind of page: kv = K and V (or "
             "latent) pages, conv = the convolution layers' state under the "
-            "same block ids (block='lfm2' models); their sum is "
-            "engine_kv_cache_bytes",
+            "same block ids (block='lfm2' models), ckeys = the sparse layers' "
+            "compressed keys under them (block='sala'); their sum is "
+            "engine_kv_cache_bytes. state = the lightning layers' state pool "
+            "(block='sala'), slots beside the blocks and outside that sum",
+        ),
+        registry.counter(
+            "engine_state_snapshots_total",
+            "State snapshots taken (block='sala'), by why: chunk_end = a "
+            "prefill chunk ended on a block boundary and left a second copy "
+            "of its state; decode_boundary = a decode step opened a block and "
+            "left the block before's end state behind in the sequence's pair",
+        ),
+        registry.counter(
+            "engine_state_resumes_total",
+            "Admissions of a model with a state pool, by where the lightning "
+            "layers' state came from: snapshot = a cached block's, zero = "
+            "position 0",
+        ),
+        registry.counter(
+            "engine_state_cached_tokens_total",
+            "Prompt tokens an admission of such a model found pages for in "
+            "the prefix cache, whether or not a snapshot let it start there",
+        ),
+        registry.counter(
+            "engine_state_recomputed_tokens_total",
+            "Of those, the tokens past the chain's deepest snapshot: cached "
+            "pages the prefill computed again to rebuild the state",
+        ),
+        registry.counter(
+            "engine_state_snapshot_evictions_total",
+            "Snapshots evicted by their own LRU to free a state slot",
+        ),
+        registry.counter(
+            "engine_sparse_blocks_chosen_total",
+            "Blocks the sparse layers' attention went over, a query position "
+            "(prefill tokens and decode steps; every sparse layer and KV head "
+            "of a position counts the same, so a position counts once), as "
+            "the programs were dispatched: a decode step its chosen table, "
+            "the top-k past dense_len and every visible block under it; a "
+            "prefill that has a row past dense_len every page of its table's "
+            "width, the choice being a mask there, any other its visible "
+            "blocks",
+        ),
+        registry.counter(
+            "engine_sparse_blocks_visible_total",
+            "Blocks those positions could see (their context in blocks)",
+        ),
+        registry.counter(
+            "engine_sparse_dense_rows_total",
+            "Of those positions, the ones at or under dense_len, which took "
+            "the dense path",
         ),
         registry.counter(
             "moe_assignments_total",
@@ -712,6 +774,7 @@ class TpuEngine:
             args.block_size,
             event_sink=self._on_pool_event,
             enable_prefix_caching=args.prefix_caching,
+            state_slots=args.state_slots,
         )
         # G2/G3 KV tiers: sealed blocks write through to host (batched per
         # step); prefix misses in HBM onboard from the tiers instead of
@@ -923,6 +986,16 @@ class TpuEngine:
         # came from (engine_conv_state_resumes_total); None without such layers.
         self.conv_resumes: dict[str, int] | None = (
             {"cache": 0, "zero": 0, "recompute": 0} if self.cfg.conv_layers else None)
+        # block="sala": where admissions' states came from, the cached tokens
+        # they found and recomputed, and what the sparse layers chose
+        # (engine_state_*, engine_sparse_*); None for any other block.
+        self.state_stats: dict[str, int] | None = None
+        if args.state_slots:
+            from dynamo_tpu.ops.sparse_attention import SparseSizes
+
+            self._sparse_sizes = SparseSizes.of(self.cfg)
+            self.state_stats = {"snapshot": 0, "zero": 0, "cached_tokens": 0, "recomputed_tokens": 0,
+                                "chosen": 0, "visible": 0, "dense": 0}
         # Prefill dispatches by their program's rows (a chunk of a chunked
         # prefill is a dispatch of one row): engine_prefill_dispatch_rows_total.
         self.prefill_dispatch_rows: dict[int, int] = collections.defaultdict(int)
@@ -934,6 +1007,11 @@ class TpuEngine:
         self._gauges = register_engine_metrics(registry)
         for source in self.conv_resumes or ():  # every source is a series from the start, at 0
             self._gauges["engine_conv_state_resumes_total"].inc(0, source=source)
+        if self.state_stats is not None:  # every series from the start, at 0
+            for origin in ("snapshot", "zero"):
+                self._gauges["engine_state_resumes_total"].inc(0, **{"from": origin})
+            for why in self.pool.state_snapshots:
+                self._gauges["engine_state_snapshots_total"].inc(0, why=why)
 
     def _feed(self, name: str, total: float, **labels: str) -> None:
         """Give counter ``name`` what its running total grew by since it
@@ -952,6 +1030,19 @@ class TpuEngine:
         g["engine_kv_cache_bytes"].set(self.args.kv_bytes_per_block() * self.args.num_kv_blocks)
         for kind, per_block in self.args.pool_bytes_per_block().items():
             g["kv_pool_bytes"].set(per_block * self.args.num_kv_blocks, kind=kind)
+        if self.state_stats is not None:
+            st = self.state_stats
+            g["kv_pool_bytes"].set(self.args.state_pool_bytes(), kind="state")
+            for origin in ("snapshot", "zero"):
+                feed("engine_state_resumes_total", st[origin], **{"from": origin})
+            for why, n in self.pool.state_snapshots.items():
+                feed("engine_state_snapshots_total", n, why=why)
+            feed("engine_state_cached_tokens_total", st["cached_tokens"])
+            feed("engine_state_recomputed_tokens_total", st["recomputed_tokens"])
+            feed("engine_state_snapshot_evictions_total", self.pool.state_evictions)
+            feed("engine_sparse_blocks_chosen_total", st["chosen"])
+            feed("engine_sparse_blocks_visible_total", st["visible"])
+            feed("engine_sparse_dense_rows_total", st["dense"])
         g["engine_kv_quant_enabled"].set(1 if self.args.kv_quant == "int8" else 0)
         g["engine_prefill_pad_ratio"].set(
             self.total_prefill_padded / max(1, self.total_prefilled))
@@ -1360,7 +1451,8 @@ class TpuEngine:
         ktp = req.kv_transfer_params or {}
         if self.cfg.block != "llama" and any(k in ktp for k in (
                 "do_remote_decode", "peer_prefix", "stream_handle", "handle", "pages")):
-            pages = {"longcat": "latent pages", "lfm2": "conv-state pool"}[self.cfg.block]
+            pages = {"longcat": "latent pages", "lfm2": "conv-state pool",
+                     "sala": "state pool"}[self.cfg.block]
             yield LLMEngineOutput(
                 finish_reason=FinishReason.ERROR,
                 error="KV transfer (transfer/: disaggregated prefill, peer prefix "
@@ -1986,7 +2078,25 @@ class TpuEngine:
         max_hit = (plen - 1) // bs
         hashes_matchable = hashes[:max_hit]
         total_blocks = (plen + bs - 1) // bs
-        block_ids, n_hit = self.pool.allocate_sequence(hashes_matchable, total_blocks)
+        n_state = None
+        if self.state_stats is not None:
+            # A hit is only as deep as the chain's deepest state snapshot.
+            n_pages = len(self.pool.match_prefix(hashes_matchable))
+            n_state, seq.state_src = self.pool.snapshot_depth(hashes_matchable)
+        block_ids, n_hit = self.pool.allocate_sequence(hashes_matchable, total_blocks, max_hit=n_state)
+        if n_state is not None:
+            try:
+                seq.state_pair = self.pool.acquire_state_pair()
+            except NoFreeBlocksError:
+                self.pool.free_sequence(block_ids)
+                raise
+            seq.state_plen, seq.state_hi = plen, plen - 1
+            seq.state_chunk = None
+            seq.state_diverge = n_pages * bs if n_pages > n_hit else 0
+            st = self.state_stats
+            st["snapshot" if n_hit else "zero"] += 1
+            st["cached_tokens"] += n_pages * bs
+            st["recomputed_tokens"] += (n_pages - n_hit) * bs
         seq.block_ids = block_ids
         seq.prefix_hit_blocks = n_hit
         seq.block_seq = TokenBlockSequence(prompt, bs, seq.hash_seed)
@@ -2097,7 +2207,89 @@ class TpuEngine:
             arr = self._prefill_packed(members, n_rows, t_pad)
             for row, (seq, start) in enumerate(members):
                 out.append((seq, arr, row))
+        if self.state_stats is not None:
+            self.pool.unpin_states()  # every snapshot the wave resumes from has its reader queued
         return out
+
+    def _prefill_state(self, seq: _Seq, start: int, end: int) -> tuple[int, ...]:
+        """The state slots of one prefill dispatch over ``[start, end)`` of
+        ``seq`` (engine/sala.py's ``state_slots`` row): where its state is read
+        from (the snapshot it resumes from at admission's start, its own pair
+        in a later chunk), the slot of its pair the state after ``end - 1``
+        belongs in, and up to two snapshots, each a slot and the tokens of the
+        chunk up to it: where the sequence's cached pages ended (a shared
+        prompt's end) if that lies in this chunk, and the last block the chunk
+        seals."""
+        bs, pair = self.args.block_size, seq.state_pair
+        first = start == seq.prefix_hit_blocks * bs
+        src = seq.state_src if first else pair[((start - 1) // bs) % 2]
+        snaps: list[int] = []
+        for at in dict.fromkeys((seq.state_diverge, end // bs * bs)):
+            slot = 0
+            if start < at <= end:
+                sealed = seq.block_seq.blocks[at // bs - 1].sequence_hash
+                if at == seq.state_diverge:  # a branch point: it stays
+                    slot = self.pool.take_snapshot(sealed, "chunk_end")
+                else:
+                    slot = self.pool.take_snapshot(sealed, "chunk_end", replaces=seq.state_chunk)
+                    seq.state_chunk = sealed if slot else seq.state_chunk
+            snaps += [slot, at - start if slot else 0]
+        snaps += [0, 0] * (2 - len(snaps) // 2)
+        return (src, pair[((end - 1) // bs) % 2], *snaps)
+
+    def _count_choices(self, lengths, table_blocks: int | None = None) -> None:
+        """The sparse counters of one dispatch, whose query positions see
+        ``lengths`` positions each: a decode window's, or a prefill's behind
+        a page table ``table_blocks`` wide."""
+        st = self.state_stats
+        counts = choice_counts(np.asarray(lengths), self._sparse_sizes, table_blocks)
+        for key, n in zip(("chosen", "visible", "dense"), counts):
+            st[key] += n
+
+    def _decode_state(self, batch: list[_Seq], pos0: list[int], B: int, K: int) -> np.ndarray:
+        """The rows' state pairs for a decode dispatch of ``K`` steps from
+        ``pos0``, and the books: how far each row's state has been written,
+        the boundaries its steps cross, what its positions choose."""
+        bs = self.args.block_size
+        state = np.zeros((B, 3), np.int32)
+        lengths = []
+        for i, (seq, p0) in enumerate(zip(batch, pos0)):
+            # The last position anyone will want the state after: the token
+            # before the last the request may have. A finished sequence's
+            # zombie steps past it leave its pair, and its snapshot, alone.
+            stop = min(seq.prompt_len + (seq.stop.max_tokens or self.args.max_model_len),
+                       self.args.max_model_len) - 2
+            state[i] = (*seq.state_pair, stop)
+            last = min(p0 + K - 1, stop)
+            seq.state_hi = max(seq.state_hi, last)
+            self.pool.state_snapshots["decode_boundary"] += sum(
+                1 for p in range(p0, last + 1) if p % bs == 0)
+            lengths += range(p0 + 1, p0 + K + 1)
+        self._count_choices(lengths)
+        return state
+
+    def _release_state(self, seq: _Seq) -> None:
+        """``seq`` stops running (finished, failed or preempted): its pair
+        goes back, but for the slot that holds the state after its last
+        sealed block, which stays as that block's snapshot. That is the slot
+        of block b where the state after b's last position was written by
+        this residence (a decode step, or the prefill's last position) and
+        no later dispatch, a zombie window's among them, has written the
+        slot again: ``state_plen <= (b + 1) bs <= state_hi + 1`` and
+        ``state_hi < (b + 2) bs``."""
+        pair, seq.state_pair = seq.state_pair, None
+        if pair is None:
+            return
+        bs, keep = self.args.block_size, None
+        # The blocks the last window sealed: its drain registered before it
+        # emitted, and a snapshot is worth what its block's pages are.
+        self._register_written_blocks(seq)
+        n = min(len(seq.tokens), seq.kv_written, seq.registered_blocks * bs)
+        b = n // bs - 1
+        if (b >= 0 and seq.block_seq is not None
+                and seq.state_plen <= (b + 1) * bs <= seq.state_hi + 1 and seq.state_hi < (b + 2) * bs):
+            keep = (pair[b % 2], seq.block_seq.blocks[b].sequence_hash)
+        self.pool.release_state_pair(pair, keep)
 
     def _prefill_packed(
         self, members: list[tuple[_Seq, int]], Bp: int, t_pad: int
@@ -2120,8 +2312,14 @@ class TpuEngine:
             starts[r] = start
             tlens[r] = len(seq.tokens)
         aslots = self._adapter_row_slots([s for s, _ in members], Bp)
+        state_kw = {}  # a block with a state pool takes its rows' slots; any other runner is called as it was
+        if self.state_stats is not None:
+            state_kw["state"] = np.zeros((Bp, 6), np.int32)
+            for r, (seq, start) in enumerate(members):
+                state_kw["state"][r] = self._prefill_state(seq, start, len(seq.tokens))
+            self._count_choices(np.concatenate([np.arange(a + 1, len(s.tokens) + 1) for s, a in members]), W)
         self._dispatching()
-        ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots)
+        ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots, **state_kw)
         self._dispatched(ref.arrs)
         self.total_prefill_padded += Bp * t_pad
         self.prefill_dispatch_rows[Bp] += 1
@@ -2157,10 +2355,14 @@ class TpuEngine:
             t_pad = self.args.bucket_prefill(len(chunk))
             toks = np.zeros((t_pad,), np.int32)
             toks[: len(chunk)] = chunk
+            state_kw = {}
+            if self.state_stats is not None:
+                state_kw["state"] = np.asarray(self._prefill_state(seq, pos, pos + len(chunk)), np.int32)
+                self._count_choices(np.arange(pos + 1, pos + len(chunk) + 1), W)
             self._dispatching()
             logits = self._runner.prefill_chunk(
                 toks, table, pos, min(pos + len(chunk), plen),
-                seq.adapter_slot if seq.adapter_slot >= 0 else None,
+                seq.adapter_slot if seq.adapter_slot >= 0 else None, **state_kw,
             )
             self._dispatched(logits.arrs)
             self.total_prefill_padded += t_pad
@@ -2411,6 +2613,8 @@ class TpuEngine:
             return {"error": "live migration cannot carry latent (MLA) pages"}
         if self.cfg.block == "lfm2":
             return {"error": "live migration cannot carry the conv-state pool"}
+        if self.cfg.block == "sala":
+            return {"error": "live migration cannot carry the state pool"}
         seq = next(
             (s for s in self._running if s.request_id == request_id), None
         )
@@ -2769,6 +2973,7 @@ class TpuEngine:
             seq.export_handle = None
             seq.export_pub_blocks = 0
             seq.export = False
+        self._release_state(seq)
         self.pool.free_sequence(seq.block_ids)
         seq.block_ids = []
         seq.registered_blocks = 0
@@ -3030,12 +3235,13 @@ class TpuEngine:
             if any(s.sampling.top_logprobs for s in batch) else 0
         )
         aslots = self._adapter_row_slots(batch, B)
+        state_kw = {"state": self._decode_state(batch, pos0, B, K)} if self.state_stats is not None else {}
         self._enter("decode_dispatch")
         self._dispatching()
         ref = self._runner.multi_decode(
             K, mode, tokens, wchain, positions, tables, active,
             temps, seeds, steps0, tks, tps, freqs, press, pen, fold_slots,
-            top_n, aslots,
+            top_n, aslots, **state_kw,
         )
         self._dispatched(ref.arrs)
         w = _Window(batch, pos0, K, ref, top_n)
@@ -3479,8 +3685,11 @@ class TpuEngine:
             tables[i, : len(seq.block_ids)] = seq.block_ids
             active[i] = True
         aslots = self._adapter_row_slots(batch, B)
+        state_kw = {}
+        if self.state_stats is not None:
+            state_kw["state"] = self._decode_state(batch, [int(p) for p in positions[: len(batch)]], B, 1)
         self._dispatching()
-        ref = self._runner.decode_step(tokens, positions, tables, active, aslots)
+        ref = self._runner.decode_step(tokens, positions, tables, active, aslots, **state_kw)
         self._dispatched(ref.arrs)
         self.total_decode_steps += 1
         self.total_decode_rows_dispatched += B
@@ -3668,6 +3877,7 @@ class TpuEngine:
             self._offload_pending = [
                 (b, h) for b, h in self._offload_pending if b not in freed
             ]
+        self._release_state(seq)
         self.pool.free_sequence(seq.block_ids)
         seq.block_ids = []
         if not already_posted:

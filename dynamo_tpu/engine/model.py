@@ -65,13 +65,21 @@ class KVCache(NamedTuple):
     A ``block="lfm2"`` model (engine/lfm2.py) has K and V pages for its
     attention layers alone and a third pool under the same block ids,
     ``conv`` ``[conv layers, K, N, hidden]``: the block that holds position
-    ``p`` keeps that layer's conv input ``z_p`` in slot ``p % K``."""
+    ``p`` keeps that layer's conv input ``z_p`` in slot ``p % K``.
+
+    A ``block="sala"`` model (engine/sala.py) has K and V pages for its sparse
+    layers, their compressed keys under the same block ids (``ckeys``
+    ``[sparse layers, N, bs // stride, KVH*hd]``) and the lightning layers'
+    matrix states in slots of their own (``state`` ``[lightning layers, S, H,
+    d, d]`` in the cache's dtype), which block_manager/pool.py hands out."""
 
     k: jax.Array  # [L, N, bs, KVH*hd]
     v: jax.Array | None
     k_scale: jax.Array | None = None  # [L, N, bs, KVH] fp32 — int8 only
     v_scale: jax.Array | None = None
     conv: jax.Array | None = None     # block="lfm2" only
+    ckeys: jax.Array | None = None    # block="sala" only
+    state: jax.Array | None = None    # block="sala" only
 
 
 def init_kv_cache(
@@ -185,7 +193,7 @@ def _dot_q(x: jax.Array, lp: dict, name: str) -> jax.Array:
 
 def block_module(cfg: ModelConfig):
     """The module that runs ``cfg.block``, chosen once (engine/runner.py):
-    this one, engine/longcat.py or engine/lfm2.py. Each has ``init_params``,
+    this one, engine/longcat.py, engine/lfm2.py or engine/sala.py. Each has ``init_params``,
     ``init_kv_cache`` and the jitted ``prefill``, ``prefill_batch``,
     ``decode_step`` and ``multi_decode`` under these names; a block that
     routes (``routed_layers(cfg)``) returns a routing histogram after what
@@ -198,8 +206,12 @@ def block_module(cfg: ModelConfig):
         from dynamo_tpu.engine import lfm2
 
         return lfm2
+    if cfg.block == "sala":
+        from dynamo_tpu.engine import sala
+
+        return sala
     if cfg.block != "llama":
-        raise ValueError(f"no module runs block={cfg.block!r} (llama, longcat, lfm2)")
+        raise ValueError(f"no module runs block={cfg.block!r} (llama, longcat, lfm2, sala)")
     return sys.modules[__name__]
 
 

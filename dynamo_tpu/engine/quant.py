@@ -23,6 +23,10 @@ import numpy as np
 
 # Leaves quantized along their OUTPUT channel (last axis).
 _LAYER_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# A block="sala" layer's, sparse or lightning (engine/sala.py draws them int8,
+# one stack a name and kind; model._dot_q dequantizes them as it does the dense
+# block's): the dense block's and the mixer's output gate.
+SALA_LAYER_WEIGHTS = (*_LAYER_WEIGHTS[:4], "w_ogate", *_LAYER_WEIGHTS[4:])
 
 
 def quantize_np(w: np.ndarray, axis: int = -1) -> tuple[np.ndarray, np.ndarray]:
@@ -97,6 +101,13 @@ def random_int8_params_device(
         raise NotImplementedError("int8 random init not wired for MoE configs")
     import jax
     import jax.numpy as jnp
+
+    if cfg.block == "sala":
+        # The block's own stacks (SALA_LAYER_WEIGHTS of each kind of layer), a
+        # tensor at a time; single-device, as EngineArgs holds the block to.
+        from dynamo_tpu.engine import sala
+
+        return sala.init_params(cfg, jax.random.PRNGKey(seed), jnp.dtype(dtype), quant="int8")
 
     ndt = jnp.bfloat16 if dtype == "bfloat16" else jnp.dtype(dtype)
     d, i, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers

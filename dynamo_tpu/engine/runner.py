@@ -269,6 +269,7 @@ class LocalRunner:
             self.cfg, self.args.num_kv_blocks, self.args.block_size, dtype,
             kv_quant=self.args.kv_quant,
             sharding=None if sh is None else sh.cache_sharding(),
+            **({"state_slots": self.args.state_slots} if self.args.state_slots else {}),
         )
         if self.args.lora_slots > 0:
             from dynamo_tpu.engine.lora import bank_shapes
@@ -315,6 +316,9 @@ class LocalRunner:
         W = self.args.blocks_per_seq  # the wide table only
         i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
 
+        def state_kw(rows):  # a block with a state pool takes a row's slots beside its table
+            return {"state_slots": i32((rows, 6))} if self.args.state_slots else {}
+
         def work():
             t0 = time.monotonic()
             for rows, t in shapes:
@@ -323,7 +327,7 @@ class LocalRunner:
                 try:
                     program = self._block.prefill_batch.lower(
                         self.cfg, params, cache, i32((rows, t)), i32((rows, W)), i32((rows,)),
-                        i32((rows,)), None, None, **self._prefill_kw).compile()
+                        i32((rows,)), None, None, **self._prefill_kw, **state_kw(rows)).compile()
                     # stack_rows picks a sequence's row out of the pack's
                     # logits with an eager index, a small program a
                     # [rows, V] shape: that one too exists before a request
@@ -467,6 +471,12 @@ class LocalRunner:
 
     # -- dispatches -------------------------------------------------------
 
+    @staticmethod
+    def _state_kw(state) -> dict:
+        """``state``: a dispatch's state-pool slots a row (engine/sala.py's
+        ``state_slots``), None for a block without such a pool."""
+        return {} if state is None else {"state_slots": jnp.asarray(state, jnp.int32)}
+
     def _lora_operands(self, adapter_slots):
         """(bank, slots-array) for a dispatch, or (None, None) for the
         exact base-variant trace."""
@@ -492,7 +502,7 @@ class LocalRunner:
             )
 
     def prefill_batch(self, toks, tables, starts, tlens, adapter_slots=None,
-                      *, rid=None) -> StepRef:
+                      *, rid=None, state=None) -> StepRef:
         bank, slots = self._lora_operands(adapter_slots)
         self.prefill_dispatches += 1
         # A pack runs the program compiled at start from its shape
@@ -505,11 +515,11 @@ class LocalRunner:
             run = functools.partial(self._prefill_batch, self.cfg, **self._prefill_kw)
         logits, self.cache, hist = run(
             self.params, self.cache, jnp.asarray(toks), jnp.asarray(tables),
-            jnp.asarray(starts), jnp.asarray(tlens), bank, slots)
+            jnp.asarray(starts), jnp.asarray(tlens), bank, slots, **self._state_kw(state))
         return self._new_ref((logits,), rid, hist, prefill=True)
 
     def prefill_chunk(self, toks, table, pos, tlen, adapter_slot=None,
-                      *, rid=None) -> StepRef:
+                      *, rid=None, state=None) -> StepRef:
         bank = slot = None
         if adapter_slot is not None and adapter_slot >= 0:
             bank, slot = self.lora_bank, jnp.int32(adapter_slot)
@@ -519,7 +529,7 @@ class LocalRunner:
             jnp.asarray(toks), jnp.asarray(table),
             jnp.int32(pos), jnp.int32(tlen),
             bank, slot,
-            **self._prefill_kw,
+            **self._prefill_kw, **self._state_kw(state),
         )
         return self._new_ref((logits,), rid, hist, prefill=True)
 
@@ -530,7 +540,7 @@ class LocalRunner:
     def multi_decode(self, K, mode, tokens, chain, positions, tables, active,
                      temps, seeds, steps0, tks, tps, freqs, press, pen,
                      fold_slots=None, top_n=0, adapter_slots=None,
-                     *, rid=None) -> StepRef:
+                     *, rid=None, state=None) -> StepRef:
         """chain: None | (dst rows, src slots) — rows of this window whose
         input token is the latest on-device sample for that sequence SLOT
         (previous window fold or admission first-token fold; no host
@@ -559,7 +569,7 @@ class LocalRunner:
             jnp.asarray(freqs), jnp.asarray(press), jnp.asarray(pen),
             jnp.asarray(mask), jnp.asarray(srcmap), self._last_toks,
             bank, aslots,
-            attn_impl=self.attn_impl,
+            attn_impl=self.attn_impl, **self._state_kw(state),
         )
         if fold_slots is None:
             fold_slots = np.full((B,), self.args.max_num_seqs, np.int32)
@@ -569,14 +579,14 @@ class LocalRunner:
         return self._new_ref((toks_d, logps_d, tvals_d, tids_d), rid, hist)
 
     def decode_step(self, tokens, positions, tables, active,
-                    adapter_slots=None, *, rid=None) -> StepRef:
+                    adapter_slots=None, *, rid=None, state=None) -> StepRef:
         bank, aslots = self._lora_operands(adapter_slots)
         logits, self.cache, hist = self._decode_step(
             self.cfg, self.params, self.cache,
             jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(tables), jnp.asarray(active),
             bank, aslots,
-            attn_impl=self.attn_impl,
+            attn_impl=self.attn_impl, **self._state_kw(state),
         )
         return self._new_ref((logits,), rid, hist)
 

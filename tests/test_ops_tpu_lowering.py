@@ -668,3 +668,57 @@ def test_a_packed_wave_is_one_grouped_product_a_layer_and_no_larger_than_a_singl
     assignment_rows = rows * t * min(cfg.num_experts_per_token, cfg.num_experts)
     assert products == [str(assignment_rows)] * (3 * expert_layers), products
     assert pack.memory_analysis().temp_size_in_bytes <= single.memory_analysis().temp_size_in_bytes
+
+
+def _sala(mixers: tuple[str, ...]) -> ModelConfig:
+    """MiniCPM-SALA's published widths over ``mixers`` (the cell's has all 32 layers)."""
+    return ModelConfig(
+        name="sala", block="sala", vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+        num_layers=len(mixers), num_heads=32, num_kv_heads=2, head_dim=128, rms_norm_eps=1e-6,
+        tie_embeddings=False, mixer_types=mixers, lightning_heads=32, lightning_head_dim=128,
+        scale_emb=12.0, scale_depth=1.4, dim_model_base=256, max_position=24576)
+
+
+@pytest.mark.parametrize("program", ["step_kernel", "decode_window", "prefill_chunk_2048"])
+def test_sala_programs_compile_for_v5e(v5e, program):
+    """What the MiniCPM-SALA cell runs, at the published widths, pages of 64
+    tokens, the cell's pools (4,096 blocks, 41 state slots) and its 24,576-token
+    table, over a sparse layer, two lightning layers and a sparse layer: the
+    lightning step kernel alone (16 rows), the decode window of 16 rows and a
+    2,048-token prefill chunk. The pools change in place: a decode window's
+    temporaries stay far under one pool's size, and a chunk's under the room
+    the configuration's memory arithmetic leaves (its float32 scores are a
+    tile of 64 queries over the table's width)."""
+    from dynamo_tpu.engine import sala
+    from dynamo_tpu.ops.lightning import lightning_decode
+
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
+    B, W, N, slots = 16, 24576 // 64, 4096, 88
+    if program == "step_kernel":
+        vec = S((B, 32, 128), jnp.bfloat16)
+        compiled = lightning_decode.lower(vec, vec, vec, S((24, slots, 32, 128, 128), jnp.bfloat16),
+                                          i32(), i32(B), i32(B)).compile()
+        assert "lightning_decode" in compiled.as_text()
+        return
+    cfg = _sala(("minicpm4", "lightning-attn", "lightning-attn", "minicpm4"))
+    params = _abstract(jax.eval_shape(
+        lambda: sala.init_params(cfg, jax.random.PRNGKey(0), jnp.bfloat16, quant="int8")), S)
+    cache = _abstract(jax.eval_shape(lambda: sala.init_kv_cache(cfg, N, 64, state_slots=slots)), S)
+    if program == "decode_window":
+        flags = S((B,), jnp.bool_)
+        compiled = sala.multi_decode.lower(
+            cfg, 8, "greedy", 0, params, cache,
+            i32(B), i32(B), i32(B, W), flags, f32(B), S((B,), jnp.uint32), i32(B),
+            i32(B), f32(B), f32(B), f32(B), i32(B, 1), flags, i32(B), i32(B + 1),
+            None, None, attn_impl="pallas", state_slots=i32(B, 3),
+        ).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+        hlo = compiled.as_text()
+        assert "lightning_decode" in hlo and "paged_decode_attention" in hlo or hlo.count("tpu_custom_call") >= 2
+    else:
+        compiled = sala.prefill.lower(
+            cfg, params, cache, i32(2048), i32(W), i32(), i32(), None, None,
+            attn_impl="pallas", state_slots=i32(6)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+        assert "paged_prefill_attention" in compiled.as_text()  # the dense branch, under dense_len
