@@ -95,6 +95,11 @@ KERNEL_CALLS = {
                              rows=[(16, 150, 700), (2, 2100, 3600)]),
     "mistral-7b chat": dict(B=32, W=256, bs=16, KVH=8, hd=128, G=4, L=32, N=2304,
                             rows=[(16, 150, 700)]),
+    "lfm2 sessions-conv": dict(B=128, W=128, bs=32, KVH=8, hd=64, G=4, L=2, N=5632,
+                               rows=[(61, 1000, 2600)]),
+    # the sparse walk: a row a (session, KV head) over its 64 chosen pages
+    "sala sessions-long": dict(B=32, W=64, bs=64, KVH=2, hd=128, G=16, L=8, N=4352,
+                               rows=[(32, 4033, 4096)]),
     "longcat latent sessions": dict(B=128, W=128, bs=32, Dk=640, H=64, Dv=512, L=8, N=5632,
                                     rows=[(63, 1000, 2600)]),
 }
@@ -539,14 +544,14 @@ def child_kernel() -> None:
             v, vs = M.kv_quantize(v)
         else:
             k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
-        k, v = k.reshape(L, N, bs, KVH * hd), v.reshape(L, N, bs, KVH * hd)
+        pages = M.fuse_kv(k.reshape(L, N, bs, KVH * hd), v.reshape(L, N, bs, KVH * hd))
         layer = jnp.int32(1)
         bt = jnp.asarray(tables)
         if mode == "decode":
             q = jax.random.normal(kq, (B, KVH, G, hd), jnp.float32).astype(jnp.bfloat16)
             ln = jnp.asarray(lengths)
-            got = paged_decode_attention(q, k, v, layer, bt, ln, ks, vs, interpret=False)
-            ref = paged_decode_attention_xla(q, k, v, layer, bt, ln, ks, vs)
+            got = paged_decode_attention(q, pages, layer, bt, ln, ks, vs, interpret=False)
+            ref = paged_decode_attention_xla(q, pages, layer, bt, ln, ks, vs)
             live = lengths > 0
         else:
             q = jax.random.normal(kq, (B, T, KVH, G, hd), jnp.float32).astype(jnp.bfloat16)
@@ -561,8 +566,8 @@ def child_kernel() -> None:
                 anc_np = np.where(lengths[:, None, None] > 0, anc1[None], 0).astype(np.int8)
                 anc = jnp.asarray(anc_np)
             ln2 = jnp.asarray(ln2.astype(np.int32))
-            got = paged_spec_attention(q, k, v, layer, bt, ln2, ks, vs, anc, interpret=False)
-            ref = paged_spec_attention_xla(q, k, v, layer, bt, ln2, ks, vs, anc=anc)
+            got = paged_spec_attention(q, pages, layer, bt, ln2, ks, vs, anc, interpret=False)
+            ref = paged_spec_attention_xla(q, pages, layer, bt, ln2, ks, vs, anc=anc)
             live = lengths > 0
         compare(name, got, ref, live)
 
@@ -585,9 +590,8 @@ def child_kernel() -> None:
         G, Tp = cfg.num_heads // KVH, 256
         start = np.array([0, 992, 0, 400], np.int32)
         tlen = np.array([220, 992 + 141, 0, 400 + 256], np.int32)
-        kq, kk, kv = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(7), len(results)), 3)
-        k = jax.random.normal(kk, (L, N, bs, KVH * hd), jnp.float32).astype(jnp.bfloat16)
-        v = jax.random.normal(kv, (L, N, bs, KVH * hd), jnp.float32).astype(jnp.bfloat16)
+        kq, kk = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(7), len(results)))
+        pages = jax.random.normal(kk, (L, N, 2, bs, KVH * hd), jnp.float32).astype(jnp.bfloat16)
         q = jax.random.normal(kq, (4, Tp, KVH, G, hd), jnp.float32).astype(jnp.bfloat16)
         wide = rng.permutation(np.arange(1, N))[: 4 * 96].reshape(4, 96).astype(np.int32)
         bt, layer = jnp.asarray(wide), jnp.int32(1)
@@ -595,9 +599,10 @@ def child_kernel() -> None:
         pos = start[:, None] + np.arange(Tp)[None]
         blk = jnp.asarray(np.take_along_axis(wide, pos // bs, axis=1))
         own = lambda pool: pool[1, blk, jnp.asarray(pos % bs)].reshape(4, Tp, KVH, hd)  # noqa: E731
-        got = paged_prefill_attention(q, k, v, layer, bt, jnp.asarray(start), jnp.asarray(tlen))
+        k, v = M.split_kv(pages)
+        got = paged_prefill_attention(q, pages, layer, bt, jnp.asarray(start), jnp.asarray(tlen))
         ref = paged_prefill_attention_xla(
-            q, own(k), own(v), k, v, layer, bt, jnp.asarray(start), jnp.asarray(tlen))
+            q, own(k), own(v), pages, layer, bt, jnp.asarray(start), jnp.asarray(tlen))
         compare(name, got, ref, pos < tlen[:, None])
 
     smoke = ModelConfig.preset(MODEL)
@@ -667,9 +672,8 @@ def prefill_kernel_times() -> dict:
     out = {}
     for geom, g in PREFILL_GEOMETRIES.items():
         KVH, G, hd, bs, L, N = (g[k] for k in ("KVH", "G", "hd", "bs", "L", "N"))
-        kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(2), 4)
-        pools = (jax.random.normal(kk, (L, N, bs, KVH * hd), jnp.bfloat16),
-                 jax.random.normal(kv, (L, N, bs, KVH * hd), jnp.bfloat16))
+        kq, kk, ks = jax.random.split(jax.random.PRNGKey(2), 3)
+        pool = jax.random.normal(kk, (L, N, 2, bs, KVH * hd), jnp.bfloat16)
         for shape, c in PREFILL_CALLS.items():
             T, start, length = c["T"], c["start"], c["length"]
             rng = np.random.default_rng(0)
@@ -680,19 +684,19 @@ def prefill_kernel_times() -> dict:
             own = jax.random.normal(ks, (2, 1, T, KVH, hd), jnp.bfloat16)
             s0, n = jnp.full((1,), start, jnp.int32), jnp.full((1,), length, jnp.int32)
 
-            def kernel(i, q, k, v):
-                return paged_prefill_attention(q, k, v, i, table, s0, n)
+            def kernel(i, q, pool):
+                return paged_prefill_attention(q, pool, i, table, s0, n)
 
-            def xla(i, q, k, v):
-                return paged_prefill_attention_xla(q, own[0], own[1], k, v, i, table, s0, n)
+            def xla(i, q, pool):
+                return paged_prefill_attention_xla(q, own[0], own[1], pool, i, table, s0, n)
 
             def layers(call):
                 @jax.jit
-                def run(q, k, v):
+                def run(q, pool):
                     def body(i, acc):
-                        return acc + call(i, q, k, v).astype(jnp.float32)
-                    return jax.lax.fori_loop(1, L, body, call(jnp.int32(0), q, k, v).astype(jnp.float32))
-                return lambda: run(q, *pools)
+                        return acc + call(i, q, pool).astype(jnp.float32)
+                    return jax.lax.fori_loop(1, L, body, call(jnp.int32(0), q, pool).astype(jnp.float32))
+                return lambda: run(q, pool)
 
             ns = traced_ns(layers(kernel), "XLA Ops", "paged_prefill_attention")
             name = f"{geom} {shape}"
@@ -707,7 +711,7 @@ def prefill_kernel_times() -> dict:
             print(f"[kernel] prefill {name}: {us:.1f} us a call, {flops / 1e9:.2f} GFLOP = "
                   f"{100 * share:.1f}% of {MXU_PEAK_FLOPS / 1e12:.0f} TFLOP/s; the XLA form "
                   f"{xla_us:.1f} us a layer", flush=True)
-        del pools
+        del pool
     return out
 
 
@@ -741,11 +745,11 @@ def kernel_times() -> dict:
             tables[b, :n] = pages[(at + np.arange(n)) % len(pages)]
             at += n
         tables, lengths = jnp.asarray(tables), jnp.asarray(lens)
-        kq, kk, kv = jax.random.split(jax.random.PRNGKey(1), 3)
+        kq, kk = jax.random.split(jax.random.PRNGKey(1))
         if "Dk" in c:
             pool = jax.random.normal(kk, (L, N, bs, c["Dk"]), jnp.bfloat16)
             q = jax.random.normal(kq, (B, c["H"], c["Dk"]), jnp.bfloat16)
-            pools, kernel = (pool,), "latent_decode_attention"
+            kernel = "latent_decode_attention"
             need = float(lens.sum()) * c["Dk"] * 2
 
             def call(i, q, pool):
@@ -753,22 +757,21 @@ def kernel_times() -> dict:
                     q, pool, i, tables, lengths, value_dim=c["Dv"], scale=192 ** -0.5)
         else:
             D = c["KVH"] * c["hd"]
-            pools = (jax.random.normal(kk, (L, N, bs, D), jnp.bfloat16),
-                     jax.random.normal(kv, (L, N, bs, D), jnp.bfloat16))
+            pool = jax.random.normal(kk, (L, N, 2, bs, D), jnp.bfloat16)  # a page: K then V
             q = jax.random.normal(kq, (B, c["KVH"], c["G"], c["hd"]), jnp.bfloat16)
             kernel = "paged_decode_attention"
             need = float(lens.sum()) * D * 2 * 2
 
-            def call(i, q, k, v):
-                return paged_decode_attention(q, k, v, i, tables, lengths)
+            def call(i, q, pool):
+                return paged_decode_attention(q, pool, i, tables, lengths)
 
         @jax.jit
-        def layers(q, *pools):
+        def layers(q, pool):
             def body(i, acc):
-                return acc + call(i, q, *pools).astype(jnp.float32)
-            return jax.lax.fori_loop(1, L, body, call(jnp.int32(0), q, *pools).astype(jnp.float32))
+                return acc + call(i, q, pool).astype(jnp.float32)
+            return jax.lax.fori_loop(1, L, body, call(jnp.int32(0), q, pool).astype(jnp.float32))
 
-        durations = traced_ns(lambda: layers(q, *pools), "XLA Ops", kernel)
+        durations = traced_ns(lambda: layers(q, pool), "XLA Ops", kernel)
         check(len(durations) == L, f"{name}: {len(durations)} events of {kernel} in the trace, not {L}")
         us = sum(durations) / len(durations) / 1e3
         share = need / (us * 1e-6) / HBM_PEAK_BYTES_PER_S
@@ -776,7 +779,7 @@ def kernel_times() -> dict:
         print(f"[kernel] {name} [{B} rows, {int((lens > 0).sum())} live, {int(lens.sum()):,} tokens, "
               f"{need / 1e6:.1f} MB]: {us:.1f} us a call, {need / us / 1e3:.0f} GB/s = "
               f"{100 * share:.1f}% of {HBM_PEAK_BYTES_PER_S / 1e9:.0f} GB/s", flush=True)
-        del pools, q
+        del pool, q
     return out
 
 

@@ -969,6 +969,18 @@ class EngineArgs:
             per_layer = elems * itemsize
         return 2 * m.num_layers * per_layer
 
+    def kv_page_bytes(self) -> int:
+        """Bytes of one page of one cache layer: a block's K and V side by
+        side in the pool (``KVCache.kv[layer, block]``; the latent rows of a
+        ``block="longcat"`` model), which is what ONE DMA descriptor of the
+        paged kernels moves: the size the layout acts through (PERF.md
+        section 6, PR 46)."""
+        m = self.model
+        itemsize = 2 if self.dtype == "bfloat16" else 4
+        if m.block == "longcat":
+            return self.block_size * m.latent_page_width * itemsize
+        return 2 * self.block_size * m.kv_size * (1 if self.kv_quant == "int8" else itemsize)
+
     def pool_bytes_per_block(self) -> dict[str, int]:
         """What a block costs in each pool it has a page in, by kind: "kv"
         alone, and for a ``block="lfm2"`` model the K and V of its attention

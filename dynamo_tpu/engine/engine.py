@@ -642,6 +642,12 @@ def register_engine_metrics(registry) -> dict:
             "engine_kv_cache_bytes. state = the lightning layers' state pool "
             "(block='sala'), slots beside the blocks and outside that sum",
         ),
+        registry.gauge(
+            "kv_page_bytes",
+            "Bytes of one page of one cache layer, K and V side by side (a "
+            "latent page for block='longcat'): what one DMA descriptor of "
+            "the paged attention kernels moves",
+        ),
         registry.counter(
             "engine_state_snapshots_total",
             "State snapshots taken (block='sala'), by why: chunk_end = a "
@@ -1030,6 +1036,7 @@ class TpuEngine:
         g["engine_kv_cache_bytes"].set(self.args.kv_bytes_per_block() * self.args.num_kv_blocks)
         for kind, per_block in self.args.pool_bytes_per_block().items():
             g["kv_pool_bytes"].set(per_block * self.args.num_kv_blocks, kind=kind)
+        g["kv_page_bytes"].set(self.args.kv_page_bytes())
         if self.state_stats is not None:
             st = self.state_stats
             g["kv_pool_bytes"].set(self.args.state_pool_bytes(), kind="state")

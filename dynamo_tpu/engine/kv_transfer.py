@@ -8,10 +8,13 @@ equivalents are host DMA (device_get / device_put) for HBM↔host and the
 runtime's TCP response plane for host↔host. The same primitives back
 both disaggregated prefill→decode handoff and the G2 host offload tier.
 
-Layout: pages travel as ``[L, n, bs, KVH*hd]`` pairs (k, v) — a pure
-slice of the cache's native layout, so extract/inject are single
-gather/scatter ops XLA fuses well. ``n`` is bucketed pow2 (block id 0 is
-the garbage sink, so padding injects harmlessly).
+Layout: pages travel as ``[L, n, bs, KVH*hd]`` pairs (k, v). The cache
+keeps a page's K and V side by side in one pool (``KVCache.kv`` ``[L, N, 2,
+bs, KVH*hd]``, one DMA descriptor a page for the kernels); the split
+happens here, after the one gather, and the join before the one scatter,
+so the wire, the tiers and a peer that runs the two-pool layout see the
+bytes they always saw. ``n`` is bucketed pow2 (block id 0 is the garbage
+sink, so padding injects harmlessly).
 """
 
 from __future__ import annotations
@@ -24,7 +27,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.engine.model import KVCache
+from dynamo_tpu.engine.model import KVCache, fuse_kv, split_kv
 
 
 def _bucket(n: int) -> int:
@@ -34,9 +37,14 @@ def _bucket(n: int) -> int:
     return b
 
 
-@jax.jit
-def _extract_impl(arrs: tuple, ids: jax.Array):
-    return tuple(a[:, ids] for a in arrs)  # each [L, n, bs, ...]
+def _wire_pages(pools: tuple, ids: jax.Array) -> tuple:
+    """The named pages of ``_pools`` in wire order: each page gathered once,
+    then split → (k, v[, k_scale, v_scale]), each [L, n, bs, ...]."""
+    kv, *scales = pools
+    return (*split_kv(kv[:, ids]), *(s[:, ids] for s in scales))
+
+
+_extract_impl = jax.jit(_wire_pages)
 
 
 _extract_replicated_jits: dict = {}
@@ -53,27 +61,29 @@ def _extract_replicated(arrs: tuple, ids, sharding):
     fn = _extract_replicated_jits.get(key)
     if fn is None:
         rep = NamedSharding(mesh, PartitionSpec())
-        fn = jax.jit(
-            lambda xs, i: tuple(a[:, i] for a in xs),
-            out_shardings=tuple(rep for _ in arrs),
-        )
+        fn = jax.jit(_wire_pages, out_shardings=(rep,) * (len(arrs) + 1))
         _extract_replicated_jits[key] = fn
     return fn(arrs, ids)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _inject_impl(arrs: tuple, ids: jax.Array, pages: tuple):
-    return tuple(a.at[:, ids].set(p) for a, p in zip(arrs, pages))
+def _inject_impl(pools: tuple, ids: jax.Array, pages: tuple):
+    """Wire-order ``pages`` into the named blocks of ``_pools``: K and V
+    joined into whole pages first, then one scatter a pool."""
+    kv, *scales = pools
+    k, v, *scale_pages = pages
+    return (kv.at[:, ids].set(fuse_kv(k, v)),
+            *(s.at[:, ids].set(p) for s, p in zip(scales, scale_pages)))
 
 
-def _cache_arrays(cache: KVCache) -> tuple:
-    """The cache's page-parallel arrays in wire order: (k, v) or
-    (k, v, k_scale, v_scale) for int8 storage. Every tier/transfer hop
-    moves this tuple — int8 pages ship at half the bf16 bytes plus a
-    ~3% scale sidecar."""
+def _pools(cache: KVCache) -> tuple:
+    """The cache's page-parallel pools: (kv,), or (kv, k_scale, v_scale)
+    for int8 storage. What every tier/transfer hop moves is their pages in
+    wire order, (k, v) or (k, v, k_scale, v_scale) — int8 pages ship at
+    half the bf16 bytes plus a ~3% scale sidecar."""
     if cache.k_scale is not None:
-        return (cache.k, cache.v, cache.k_scale, cache.v_scale)
-    return (cache.k, cache.v)
+        return (cache.kv, cache.k_scale, cache.v_scale)
+    return (cache.kv,)
 
 
 def start_extract(cache: KVCache, block_ids: list[int], replicate=None) -> tuple:
@@ -88,7 +98,7 @@ def start_extract(cache: KVCache, block_ids: list[int], replicate=None) -> tuple
     nb = _bucket(n)
     ids = np.zeros((nb,), np.int32)
     ids[:n] = block_ids
-    arrs = _cache_arrays(cache)
+    arrs = _pools(cache)
     if replicate is not None:
         out = _extract_replicated(arrs, jnp.asarray(ids), replicate)
     else:
@@ -117,8 +127,8 @@ def inject_pages(cache: KVCache, block_ids: list[int], *pages) -> KVCache:
     ``pages`` is the tuple ``extract_pages`` produced: (k, v) or
     (k, v, k_scale, v_scale); the arity must match the cache's storage
     format (adapt_pages converts foreign payloads first)."""
-    arrs = _cache_arrays(cache)
-    if len(pages) != len(arrs):
+    arrs = _pools(cache)
+    if len(pages) != len(arrs) + 1:
         raise ValueError(
             f"page payload arity {len(pages)} does not match cache storage "
             f"({'int8' if cache.k_scale is not None else 'dense'}); "
@@ -134,13 +144,12 @@ def inject_pages(cache: KVCache, block_ids: list[int], *pages) -> KVCache:
             np.pad(p, [(0, 0), (0, nb - n)] + [(0, 0)] * (p.ndim - 2))
             for p in pages
         )
+    # k and v both take the pages' dtype, each scale page its pool's.
     dev = tuple(
-        jnp.asarray(p, a.dtype) for p, a in zip(pages, arrs)
+        jnp.asarray(p, a.dtype) for p, a in zip(pages, (arrs[0], *arrs))
     )
     out = _inject_impl(arrs, jnp.asarray(ids), dev)
-    if len(out) == 4:
-        return KVCache(*out)
-    return KVCache(out[0], out[1])
+    return KVCache(*out)
 
 
 def delta_blocks(kv_written: int, block_size: int, cursor: int, n_blocks: int) -> tuple[int, int]:
@@ -207,7 +216,7 @@ def adapt_pages(pages: tuple, cache: KVCache, num_kv_heads: int) -> tuple:
     if quant_cache:
         return quantize_pages_np(pages[0], pages[1], num_kv_heads)
     return dequantize_pages_np(
-        *pages, num_kv_heads=num_kv_heads, dtype=_dense_dtype(cache.k.dtype)
+        *pages, num_kv_heads=num_kv_heads, dtype=_dense_dtype(cache.kv.dtype)
     )
 
 

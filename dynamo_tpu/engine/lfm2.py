@@ -59,7 +59,9 @@ import jax.numpy as jnp
 from dynamo_tpu.engine import longcat
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.longcat import HIST_EXTRA, expert_impl  # noqa: F401 - the runner's start line asks expert_impl
-from dynamo_tpu.engine.model import KVCache, _logits, _rms_norm, _rope, decode_window
+from dynamo_tpu.engine.model import (
+    KVCache, _logits, _rms_norm, _rope, decode_window, pool_zeros, write_kv_pages, write_kv_tokens,
+)
 from dynamo_tpu.ops.paged_attention import (
     paged_decode_attention,
     paged_decode_attention_xla,
@@ -147,10 +149,10 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.
         raise ValueError("the conv-state pool has no int8 form (kv_quant)")
     if block_size % cfg.conv_state_slots:
         raise ValueError(f"block_size {block_size} is no multiple of the {cfg.conv_state_slots} conv-state slots")
-    zeros = functools.partial(jnp.zeros, device=sharding)
-    kv = (len(cfg.attn_layers), num_blocks, block_size, cfg.kv_size)
+    zeros = pool_zeros(sharding)
+    kv = (len(cfg.attn_layers), num_blocks, 2, block_size, cfg.kv_size)
     conv = (len(cfg.conv_layers), cfg.conv_state_slots, num_blocks, cfg.hidden_size)
-    return KVCache(zeros(kv, dtype), zeros(kv, dtype), None, None, zeros(conv, dtype))
+    return KVCache(zeros(kv, dtype), conv=zeros(conv, dtype))
 
 
 def routed_layers(cfg: ModelConfig) -> tuple[int, ...]:
@@ -247,7 +249,7 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
     third result, the routing histogram."""
     _no_lora(lora)
     Bp, T = tokens.shape
-    bs, K = cache.k.shape[2], cfg.conv_state_slots
+    bs, K = cache.block_size, cfg.conv_state_slots
     KVH, hd, G = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads
     sfx = jnp.arange(T, dtype=jnp.int32)
     positions = start_pos[:, None] + sfx[None, :]                 # [Bp, T]
@@ -292,20 +294,20 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
     def attn_op(ai, lp, cache, u):
         q, k, v = qkv_heads(u, lp, cfg, positions)
         with jax.named_scope("kv_write"):
-            k_cache = cache.k.at[ai, flat_ids].set(k.reshape(Bp * nb, bs, KVH * hd))
-            v_cache = cache.v.at[ai, flat_ids].set(v.reshape(Bp * nb, bs, KVH * hd))
+            kv_cache = write_kv_pages(cache.kv, ai, flat_ids, k.reshape(Bp * nb, bs, KVH * hd),
+                                      v.reshape(Bp * nb, bs, KVH * hd))
         with jax.named_scope("attn"):
             qg = q.reshape(Bp, T, KVH, G, hd)
             if impl == "xla":
                 o = paged_prefill_attention_xla(
-                    qg, k, v, k_cache, v_cache, ai, block_tables, start_pos, true_len)
+                    qg, k, v, kv_cache, ai, block_tables, start_pos, true_len)
             else:
                 o = paged_prefill_attention(
-                    qg, k_cache, v_cache, ai, block_tables, start_pos, true_len,
+                    qg, kv_cache, ai, block_tables, start_pos, true_len,
                     interpret=(impl == "pallas_interpret"))
         with jax.named_scope("attn_out"):
             y = jnp.dot(o.reshape(Bp, T, cfg.q_size), lp["wo"])
-        return y, cache._replace(k=k_cache, v=v_cache)
+        return y, cache._replace(kv=kv_cache)
 
     x, cache, hist = _layers(cfg, params, x, cache, valid, conv_op, attn_op,
                              experts or expert_impl())
@@ -338,7 +340,7 @@ def decode_step_impl(cfg, params, cache, tokens, positions, block_tables, active
     _no_lora(lora)
     impl = resolve_attn_impl(attn_impl)
     B = tokens.shape[0]
-    bs, K = cache.k.shape[2], cfg.conv_state_slots
+    bs, K = cache.block_size, cfg.conv_state_slots
     KVH, hd, G = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
@@ -369,18 +371,18 @@ def decode_step_impl(cfg, params, cache, tokens, positions, block_tables, active
     def attn_op(ai, lp, cache, u):
         q, k, v = qkv_heads(u, lp, cfg, positions)
         with jax.named_scope("kv_write"):
-            k_cache = cache.k.at[ai, blk, off].set(k.reshape(B, cfg.kv_size))
-            v_cache = cache.v.at[ai, blk, off].set(v.reshape(B, cfg.kv_size))
+            kv_cache = write_kv_tokens(cache.kv, ai, blk, off, k.reshape(B, cfg.kv_size),
+                                       v.reshape(B, cfg.kv_size))
         with jax.named_scope("attn"):
             qg = q.reshape(B, KVH, G, hd)
             if impl == "xla":
-                o = paged_decode_attention_xla(qg, k_cache, v_cache, ai, block_tables, lengths)
+                o = paged_decode_attention_xla(qg, kv_cache, ai, block_tables, lengths)
             else:
-                o = paged_decode_attention(qg, k_cache, v_cache, ai, block_tables, lengths,
+                o = paged_decode_attention(qg, kv_cache, ai, block_tables, lengths,
                                            interpret=(impl == "pallas_interpret"))
         with jax.named_scope("attn_out"):
             y = jnp.dot(o.reshape(B, cfg.q_size), lp["wo"])
-        return y, cache._replace(k=k_cache, v=v_cache)
+        return y, cache._replace(kv=kv_cache)
 
     x, cache, hist = _layers(cfg, params, x, cache, active, conv_op, attn_op,
                              experts or expert_impl())

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.engine.model import KVCache, _logits, decode_window
+from dynamo_tpu.engine.model import KVCache, _logits, decode_window, pool_zeros
 from dynamo_tpu.ops.paged_attention import (
     latent_decode_attention,
     latent_decode_attention_xla,
@@ -171,7 +171,7 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.
     if kv_quant != "none":
         raise ValueError("a latent (MLA) cache has no int8 form (kv_quant)")
     shape = (cfg.cache_layers, num_blocks, block_size, cfg.latent_page_width)
-    return KVCache(jnp.zeros(shape, dtype, device=sharding), None)
+    return KVCache(pool_zeros(sharding)(shape, dtype))
 
 
 _SUB_KEYS = ("attn_norm", "mlp_norm", "q_norm", "kv_norm", "w_qa", "w_qn", "w_qr", "w_kva",
@@ -478,7 +478,7 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
     if lora is not None:
         raise ValueError("LoRA banks cannot run a block='longcat' model")
     Bp, T = tokens.shape
-    bs, Wd = cache.k.shape[2], cache.k.shape[3]
+    bs, Wd = cache.block_size, cache.kv.shape[3]
     positions = start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]   # [Bp, T]
     valid = positions < true_len[:, None]
     with jax.named_scope("embed"):
@@ -514,13 +514,13 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
                 return jnp.einsum("bhtc,hcv->bthv", o, sub["w_uv"]), pool
         return attend
 
-    x, pool, hist = _scan_layers(cfg, params, x, cache.k, positions, valid, attend_for,
+    x, pool, hist = _scan_layers(cfg, params, x, cache.kv, positions, valid, attend_for,
                                  experts or expert_impl())
     last = jnp.clip(true_len - start_pos - 1, 0, T - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     with jax.named_scope("logits"):
         logits = _logits(cfg, params, x_last)
-    return logits, KVCache(pool, None), hist
+    return logits, KVCache(pool), hist
 
 
 def prefill_impl(cfg, params, cache, tokens, block_table, start_pos, true_len,
@@ -545,7 +545,7 @@ def decode_step_impl(cfg, params, cache, tokens, positions, block_tables, active
         raise ValueError("LoRA banks cannot run a block='longcat' model")
     impl = resolve_attn_impl(attn_impl)
     B = tokens.shape[0]
-    bs = cache.k.shape[2]
+    bs = cache.block_size
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
     blk = jnp.where(active, block_tables[jnp.arange(B), positions // bs], 0)
@@ -572,11 +572,11 @@ def decode_step_impl(cfg, params, cache, tokens, positions, block_tables, active
                 return unabsorb_output(o, sub, cfg), pool
         return attend
 
-    x, pool, hist = _scan_layers(cfg, params, x, cache.k, positions, active, attend_for,
+    x, pool, hist = _scan_layers(cfg, params, x, cache.kv, positions, active, attend_for,
                                  experts or expert_impl())
     with jax.named_scope("logits"):
         logits = _logits(cfg, params, x)
-    return logits, KVCache(pool, None), hist
+    return logits, KVCache(pool), hist
 
 
 def multi_decode_impl(cfg, num_steps, mode, top_n, params, cache, tokens, positions,

@@ -57,10 +57,20 @@ class KVCache(NamedTuple):
     written positions and greedy streams stay byte-stable across
     prefill/decode/spec write orders.
 
-    A ``block="longcat"`` model (engine/longcat.py) has ONE pool: ``k`` is
+    **A page is one contiguous region of one pool**: ``kv[layer, block]`` is
+    ``[2, bs, KVH*hd]``, the block's keys and then its values, so a kernel
+    that walks a table moves a page's K and V with ONE DMA descriptor (two
+    pools cost two, and a descriptor's start is core time nothing hides:
+    PERF.md section 6, PRs 31 and 46). The ``(bs, KVH*hd)`` tiles are what the
+    kernels hold in VMEM, and a mesh shards the last axis over KV heads.
+    ``block_size`` is the one place the pool's shape is read for it. On the
+    wire (engine/kv_transfer.py) a page still travels as ``(k, v[, k_scale,
+    v_scale])``: ``split_kv`` and ``fuse_kv`` are that boundary.
+
+    A ``block="longcat"`` model (engine/longcat.py) has no V: ``kv`` is
     ``[2L, N, bs, latent_page_width]`` (cache layer 2*layer + sub-block; a
     row is the normed latent and the rotated rope key every head shares,
-    padded to whole lane tiles) and ``v`` is None.
+    padded to whole lane tiles), a page one region as well.
 
     A ``block="lfm2"`` model (engine/lfm2.py) has K and V pages for its
     attention layers alone and a third pool under the same block ids,
@@ -73,31 +83,74 @@ class KVCache(NamedTuple):
     matrix states in slots of their own (``state`` ``[lightning layers, S, H,
     d, d]`` in the cache's dtype), which block_manager/pool.py hands out."""
 
-    k: jax.Array  # [L, N, bs, KVH*hd]
-    v: jax.Array | None
+    kv: jax.Array  # [L, N, 2, bs, KVH*hd] — a page is K then V
     k_scale: jax.Array | None = None  # [L, N, bs, KVH] fp32 — int8 only
     v_scale: jax.Array | None = None
     conv: jax.Array | None = None     # block="lfm2" only
     ckeys: jax.Array | None = None    # block="sala" only
     state: jax.Array | None = None    # block="sala" only
 
+    @property
+    def block_size(self) -> int:
+        """Tokens a page (the axis ahead of the lanes, in every layout)."""
+        return self.kv.shape[-2]
+
+
+def fuse_kv(k: jax.Array, v: jax.Array) -> jax.Array:
+    """k, v ``[..., bs, KVH*hd]`` (pools, or pages off the wire) → the pool's
+    layout ``[..., 2, bs, KVH*hd]``."""
+    return jnp.stack([k, v], axis=-3)
+
+
+def split_kv(kv: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """The pool's layout ``[..., 2, bs, KVH*hd]`` → (k, v), each
+    ``[..., bs, KVH*hd]``: the order the wire and the tiers keep."""
+    return kv[..., 0, :, :], kv[..., 1, :, :]
+
+
+def write_kv_pages(kv: jax.Array, layer_idx, block_ids: jax.Array, k: jax.Array, v: jax.Array):
+    """Whole pages of one layer: k, v ``[n, bs, KVH*hd]`` into pages
+    ``block_ids`` [n], K then V, in one scatter."""
+    return kv.at[layer_idx, block_ids].set(fuse_kv(k, v))
+
+
+def write_kv_tokens(kv: jax.Array, layer_idx, blk: jax.Array, off: jax.Array, k: jax.Array, v: jax.Array):
+    """One token a row: k, v ``[n, KVH*hd]`` at slot ``off`` [n] of page
+    ``blk`` [n] of one layer, in ONE scatter of 2n rows of ``KVH*hd`` lanes,
+    the K rows then the V rows. Not n windows of ``[2, KVH*hd]``: a window
+    that strides over the page's token axis makes the chip's compiler lay
+    the whole pool out token-major for the layer loop, behind a copy of the
+    pool at every call (4.38 GB of temporaries in the Qwen decode window on
+    a described v5e; PERF.md section 6, PR 46)."""
+    n = blk.shape[0]
+    part = jnp.repeat(jnp.arange(2, dtype=jnp.int32), n)
+    return kv.at[layer_idx, jnp.tile(blk, 2), part, jnp.tile(off, 2)].set(jnp.concatenate([k, v]))
+
+
+def pool_zeros(sharding):
+    """→ ``zeros(shape, dtype)``, a pool born under ``sharding``
+    (``ModelSharding.cache_sharding`` itself: rank → a jax Sharding over the
+    kv-head axis, the last; or None): a pool sized for a mesh never has to fit one
+    device first."""
+    def zeros(shape, dtype):
+        return jnp.zeros(shape, dtype, device=sharding and sharding(len(shape)))
+
+    return zeros
+
 
 def init_kv_cache(
     cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     kv_quant: str = "none", sharding=None,
 ) -> KVCache:
-    """``sharding`` (a jax Sharding over the kv-head axis,
-    ModelSharding.cache_sharding) makes the pool born sharded: a pool
-    sized for a mesh never has to fit one device first."""
-    shape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
-    zeros = functools.partial(jnp.zeros, device=sharding)
+    """``sharding``: see ``pool_zeros``."""
+    shape = (cfg.num_layers, num_blocks, 2, block_size, cfg.num_kv_heads * cfg.head_dim)
+    zeros = pool_zeros(sharding)
     if kv_quant == "int8":
         sshape = (cfg.num_layers, num_blocks, block_size, cfg.num_kv_heads)
         return KVCache(
-            zeros(shape, jnp.int8), zeros(shape, jnp.int8),
-            zeros(sshape, jnp.float32), zeros(sshape, jnp.float32),
+            zeros(shape, jnp.int8), zeros(sshape, jnp.float32), zeros(sshape, jnp.float32),
         )
-    return KVCache(zeros(shape, dtype), zeros(shape, dtype))
+    return KVCache(zeros(shape, dtype))
 
 
 def kv_quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -390,7 +443,7 @@ def prefill_batch_impl(
     present in the blocks named by ``block_tables`` (whole blocks only);
     suffix positions [start_pos, true_len) are computed here."""
     Bp, T = tokens.shape
-    bs = cache.k.shape[2]
+    bs = cache.block_size
     KVH, hd = cfg.num_kv_heads, cfg.head_dim
     sfx = jnp.arange(T, dtype=jnp.int32)
     suffix_positions = start_pos[:, None] + sfx[None, :]          # [Bp, T]
@@ -423,7 +476,7 @@ def prefill_batch_impl(
     impl, _ = resolve_prefill_impl(attn_impl, cfg, bs, cache.k_scale is not None)
 
     def layer(carry, xs):
-        x, k_cache, v_cache, k_scale, v_scale = carry
+        x, kv_cache, k_scale, v_scale = carry
         if lora is not None:
             lp, ll, layer_idx = xs
         else:
@@ -438,44 +491,35 @@ def prefill_batch_impl(
             k = _rope(k, suffix_positions, cfg.rope_theta)
 
         with jax.named_scope("kv_write"):
-            # Write all rows' suffix KV pages in one scatter (rows own
+            # Write all rows' suffix pages, K and V, in one scatter (rows own
             # disjoint blocks; duplicates only at garbage block 0).
             # int8 storage: quantize at page-write time, scales ride a
             # parallel scatter; the suffix still self-attends its exact
             # register values below (only LATER readers see the rounding).
+            k_w, v_w = k, v
             if k_scale is not None:
-                kq, ksc = kv_quantize(k)
-                vq, vsc = kv_quantize(v)
-                k_cache = k_cache.at[layer_idx, flat_ids].set(
-                    kq.reshape(Bp * nb, bs, KVH * hd)
-                )
-                v_cache = v_cache.at[layer_idx, flat_ids].set(
-                    vq.reshape(Bp * nb, bs, KVH * hd)
-                )
+                (k_w, ksc), (v_w, vsc) = kv_quantize(k), kv_quantize(v)
                 k_scale = k_scale.at[layer_idx, flat_ids].set(
                     ksc.reshape(Bp * nb, bs, KVH)
                 )
                 v_scale = v_scale.at[layer_idx, flat_ids].set(
                     vsc.reshape(Bp * nb, bs, KVH)
                 )
-            else:
-                k_cache = k_cache.at[layer_idx, flat_ids].set(
-                    k.reshape(Bp * nb, bs, KVH * hd)
-                )
-                v_cache = v_cache.at[layer_idx, flat_ids].set(
-                    v.reshape(Bp * nb, bs, KVH * hd)
-                )
+            kv_cache = write_kv_pages(
+                kv_cache, layer_idx, flat_ids,
+                k_w.reshape(Bp * nb, bs, KVH * hd), v_w.reshape(Bp * nb, bs, KVH * hd),
+            )
 
         with jax.named_scope("attn"):
             qg = q.reshape(Bp, T, KVH, G, hd)
             if impl == "xla":
                 o = paged_prefill_attention_xla(
-                    qg, k, v, k_cache, v_cache, layer_idx, block_tables,
+                    qg, k, v, kv_cache, layer_idx, block_tables,
                     start_pos, true_len, k_scale, v_scale,
                 )
             else:
                 o = paged_prefill_attention(
-                    qg, k_cache, v_cache, layer_idx, block_tables, start_pos, true_len,
+                    qg, kv_cache, layer_idx, block_tables, start_pos, true_len,
                     interpret=(impl == "pallas_interpret"),
                 )
             o = o.reshape(Bp, T, cfg.q_size)
@@ -484,22 +528,22 @@ def prefill_batch_impl(
         with jax.named_scope("ffn"):
             h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _ffn(h, lp, cfg)
-        return (x, k_cache, v_cache, k_scale, v_scale), None
+        return (x, kv_cache, k_scale, v_scale), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     xs_in = (
         (params["layers"], lora, layer_ids) if lora is not None
         else (params["layers"], layer_ids)
     )
-    (x, k_cache, v_cache, k_scale, v_scale), _ = lax.scan(
-        layer, (x, cache.k, cache.v, cache.k_scale, cache.v_scale), xs_in,
+    (x, kv_cache, k_scale, v_scale), _ = lax.scan(
+        layer, (x, cache.kv, cache.k_scale, cache.v_scale), xs_in,
     )
 
     last = jnp.clip(true_len - start_pos - 1, 0, T - 1)      # [Bp]
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [Bp, D]
     with jax.named_scope("logits"):
         logits = _logits(cfg, params, x_last)
-    return logits, KVCache(k_cache, v_cache, k_scale, v_scale)
+    return logits, KVCache(kv_cache, k_scale, v_scale)
 
 
 def prefill_impl(
@@ -564,7 +608,7 @@ def decode_step_impl(
     impl = resolve_attn_impl(attn_impl)
     B = tokens.shape[0]
     W = block_tables.shape[1]
-    bs = cache.k.shape[2]
+    bs = cache.block_size
 
     compute_dtype = params["layers"]["attn_norm"].dtype
     with jax.named_scope("embed"):
@@ -578,7 +622,7 @@ def decode_step_impl(
     G = cfg.num_heads // cfg.num_kv_heads
 
     def layer(carry, xs):
-        x, k_cache, v_cache, k_scale, v_scale = carry
+        x, kv_cache, k_scale, v_scale = carry
         if lora is not None:
             lp, ll, layer_idx = xs
         else:
@@ -599,25 +643,23 @@ def decode_step_impl(
             # storage quantizes the fresh row at write time, so this step's
             # OWN token is read back dequantized — exactly what any later
             # step would see, keeping the math write-order-independent.
+            k_w, v_w = k, v
             if k_scale is not None:
-                kq, ksc = kv_quantize(k)
-                vq, vsc = kv_quantize(v)
-                k_cache = k_cache.at[layer_idx, blk, off].set(kq.reshape(B, cfg.kv_size))
-                v_cache = v_cache.at[layer_idx, blk, off].set(vq.reshape(B, cfg.kv_size))
+                (k_w, ksc), (v_w, vsc) = kv_quantize(k), kv_quantize(v)
                 k_scale = k_scale.at[layer_idx, blk, off].set(ksc)
                 v_scale = v_scale.at[layer_idx, blk, off].set(vsc)
-            else:
-                k_cache = k_cache.at[layer_idx, blk, off].set(k.reshape(B, cfg.kv_size))
-                v_cache = v_cache.at[layer_idx, blk, off].set(v.reshape(B, cfg.kv_size))
+            kv_cache = write_kv_tokens(
+                kv_cache, layer_idx, blk, off,
+                k_w.reshape(B, cfg.kv_size), v_w.reshape(B, cfg.kv_size))
         with jax.named_scope("attn"):
             if impl == "xla":
                 o = paged_decode_attention_xla(
-                    qg, k_cache, v_cache, layer_idx, block_tables, lengths,
+                    qg, kv_cache, layer_idx, block_tables, lengths,
                     k_scale, v_scale,
                 )
             else:
                 o = paged_decode_attention(
-                    qg, k_cache, v_cache, layer_idx, block_tables, lengths,
+                    qg, kv_cache, layer_idx, block_tables, lengths,
                     k_scale, v_scale,
                     interpret=(impl == "pallas_interpret"),
                 )
@@ -627,20 +669,20 @@ def decode_step_impl(
         with jax.named_scope("ffn"):
             h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _ffn(h, lp, cfg)
-        return (x, k_cache, v_cache, k_scale, v_scale), None
+        return (x, kv_cache, k_scale, v_scale), None
 
     layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
     xs_in = (
         (params["layers"], lora, layer_ids) if lora is not None
         else (params["layers"], layer_ids)
     )
-    (x, k_cache, v_cache, k_scale, v_scale), _ = lax.scan(
-        layer, (x, cache.k, cache.v, cache.k_scale, cache.v_scale), xs_in,
+    (x, kv_cache, k_scale, v_scale), _ = lax.scan(
+        layer, (x, cache.kv, cache.k_scale, cache.v_scale), xs_in,
     )
 
     with jax.named_scope("logits"):
         logits = _logits(cfg, params, x)  # [B, V]
-    return logits, KVCache(k_cache, v_cache, k_scale, v_scale)
+    return logits, KVCache(kv_cache, k_scale, v_scale)
 
 
 def multi_decode_impl(
@@ -878,7 +920,7 @@ def spec_verify_impl(
     )
 
     B, T = tokens.shape
-    bs = cache.k.shape[2]
+    bs = cache.block_size
     KVH, hd = cfg.num_kv_heads, cfg.head_dim
     tree = tree_parents is not None
     slot = jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -923,7 +965,7 @@ def spec_verify_impl(
         )
 
         def layer(carry, xs):
-            x, k_cache, v_cache, k_scale, v_scale = carry
+            x, kv_cache, k_scale, v_scale = carry
             if lora is not None:
                 lp, ll, layer_idx = xs
             else:
@@ -942,37 +984,27 @@ def spec_verify_impl(
             # 0..j through the same path the dense step does
             # (write-then-attend) — including the same quantization
             # rounding when the cache is int8.
+            k_w, v_w = k, v
             if k_scale is not None:
-                kq, ksc = kv_quantize(k)
-                vq, vsc = kv_quantize(v)
-                k_cache = k_cache.at[layer_idx, blk.reshape(-1), off.reshape(-1)].set(
-                    kq.reshape(B * T, cfg.kv_size)
-                )
-                v_cache = v_cache.at[layer_idx, blk.reshape(-1), off.reshape(-1)].set(
-                    vq.reshape(B * T, cfg.kv_size)
-                )
+                (k_w, ksc), (v_w, vsc) = kv_quantize(k), kv_quantize(v)
                 k_scale = k_scale.at[layer_idx, blk.reshape(-1), off.reshape(-1)].set(
                     ksc.reshape(B * T, KVH)
                 )
                 v_scale = v_scale.at[layer_idx, blk.reshape(-1), off.reshape(-1)].set(
                     vsc.reshape(B * T, KVH)
                 )
-            else:
-                k_cache = k_cache.at[layer_idx, blk.reshape(-1), off.reshape(-1)].set(
-                    k.reshape(B * T, cfg.kv_size)
-                )
-                v_cache = v_cache.at[layer_idx, blk.reshape(-1), off.reshape(-1)].set(
-                    v.reshape(B * T, cfg.kv_size)
-                )
+            kv_cache = write_kv_tokens(
+                kv_cache, layer_idx, blk.reshape(-1), off.reshape(-1),
+                k_w.reshape(B * T, cfg.kv_size), v_w.reshape(B * T, cfg.kv_size))
             if use_kernel:
                 o = paged_spec_attention(
-                    qg, k_cache, v_cache, layer_idx, block_tables, lengths,
+                    qg, kv_cache, layer_idx, block_tables, lengths,
                     k_scale, v_scale, anc,
                     interpret=(impl == "pallas_interpret"),
                 )
             else:
                 o = paged_spec_attention_xla(
-                    qg, k_cache, v_cache, layer_idx, block_tables, lengths,
+                    qg, kv_cache, layer_idx, block_tables, lengths,
                     k_scale, v_scale, anc=anc,
                 )
             o = o.reshape(B, T, cfg.q_size)
@@ -980,18 +1012,18 @@ def spec_verify_impl(
 
             h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
             x = x + _ffn(h, lp, cfg)
-            return (x, k_cache, v_cache, k_scale, v_scale), None
+            return (x, kv_cache, k_scale, v_scale), None
 
         layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
         xs_in = (
             (params["layers"], lora, layer_ids) if lora is not None
             else (params["layers"], layer_ids)
         )
-        (x, k_cache, v_cache, k_scale, v_scale), _ = lax.scan(
-            layer, (x, cache.k, cache.v, cache.k_scale, cache.v_scale), xs_in,
+        (x, kv_cache, k_scale, v_scale), _ = lax.scan(
+            layer, (x, cache.kv, cache.k_scale, cache.v_scale), xs_in,
         )
         logits = _logits(cfg, params, x)  # [B, T, V] fp32
-        cache = KVCache(k_cache, v_cache, k_scale, v_scale)
+        cache = KVCache(kv_cache, k_scale, v_scale)
     else:
         def substep(c, xs):
             tok_j, pos_j, use_j = xs
@@ -1039,10 +1071,15 @@ def spec_verify_impl(
             keep, jnp.take_along_axis(block_tables, dst_pos // bs, axis=1), 0
         )
         dst_off = jnp.where(keep, dst_pos % bs, 0)
-        k_cache, v_cache = cache.k, cache.v
         k_scale, v_scale = cache.k_scale, cache.v_scale
-        k_cache = k_cache.at[:, dst_blk, dst_off].set(k_cache[:, src_blk, src_off])
-        v_cache = v_cache.at[:, dst_blk, dst_off].set(v_cache[:, src_blk, src_off])
+        # A token's K and V of every layer move as rows of KVH*hd lanes, each
+        # by its own (layer, block, part, slot) index: a window over layers
+        # or parts would have the chip's compiler re-lay the pool out behind
+        # a copy of it (``write_kv_tokens``).
+        layers = jnp.arange(cache.kv.shape[0], dtype=jnp.int32)[:, None, None, None]
+        part = jnp.arange(2, dtype=jnp.int32)[None, :, None, None]
+        kv_cache = cache.kv.at[layers, dst_blk[None, None], part, dst_off[None, None]].set(
+            cache.kv[layers, src_blk[None, None], part, src_off[None, None]])
         if k_scale is not None:
             k_scale = k_scale.at[:, dst_blk, dst_off].set(
                 k_scale[:, src_blk, src_off]
@@ -1050,7 +1087,7 @@ def spec_verify_impl(
             v_scale = v_scale.at[:, dst_blk, dst_off].set(
                 v_scale[:, src_blk, src_off]
             )
-        cache = KVCache(k_cache, v_cache, k_scale, v_scale)
+        cache = KVCache(kv_cache, k_scale, v_scale)
     else:
         drafts = tokens[:, 1:]
         out, n_emit = spec_acceptance(

@@ -268,7 +268,7 @@ class LocalRunner:
         self.cache = self._block.init_kv_cache(
             self.cfg, self.args.num_kv_blocks, self.args.block_size, dtype,
             kv_quant=self.args.kv_quant,
-            sharding=None if sh is None else sh.cache_sharding(),
+            sharding=None if sh is None else sh.cache_sharding,
             **({"state_slots": self.args.state_slots} if self.args.state_slots else {}),
         )
         if self.args.lora_slots > 0:
@@ -440,6 +440,7 @@ class LocalRunner:
             f"devices={len(devs)} of {jax.device_count()} "
             f"ids={','.join(str(d.id) for d in devs)} visible_chips={pinned} "
             f"dtype={a.dtype} quant={a.quant} kv_quant={a.kv_quant} "
+            f"kv_page_bytes={a.kv_page_bytes()} "
             f"attention: prefill={prefill} decode={decode} spec_verify={spec}{block}{experts}{hbm}"
             f"{f' prefill_pack_row={self.pack_row_tokens}' if self.pack_row_tokens else ''}"
             f" prefill_pack<={self.pack_limit_tokens} tok{assumed}"

@@ -22,8 +22,8 @@ dim_model_base))``.
   blocks chosen on compressed keys past it, the output times ``sigmoid(W_ogate
   u)`` before ``W_o``.
 
-**The cache has four pools** (``KVCache``): ``k`` and ``v`` ``[sparse layers, N,
-bs, KVH*hd]`` with the page the sparse block; ``ckeys`` ``[sparse layers, N,
+**The cache has three pools** (``KVCache``): ``kv`` ``[sparse layers, N, 2,
+bs, KVH*hd]`` with the page the sparse block, its K and then its V; ``ckeys`` ``[sparse layers, N,
 bs // stride, KVH*hd]``, the compressed keys under the same block ids, each in
 the page that holds its last token; and ``state`` ``[lightning layers, S, H, d,
 d]`` in the cache's dtype, S slots that the block manager hands out (block_manager/pool.py
@@ -57,7 +57,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from dynamo_tpu.engine.config import ModelConfig
-from dynamo_tpu.engine.model import KVCache, _dot_q, _embed_rows, _logits, _mlp, _rms_norm, _rope, decode_window
+from dynamo_tpu.engine.model import (
+    KVCache, _dot_q, _embed_rows, _logits, _mlp, _rms_norm, _rope, decode_window, pool_zeros,
+    write_kv_pages, write_kv_tokens,
+)
 from dynamo_tpu.engine.quant import SALA_LAYER_WEIGHTS
 from dynamo_tpu.ops import sparse_attention as sparse
 from dynamo_tpu.ops.lightning import PREFILL_CHUNK, lightning_decode, lightning_decode_xla, lightning_prefill
@@ -174,13 +177,13 @@ def init_kv_cache(cfg: ModelConfig, num_blocks: int, block_size: int, dtype=jnp.
         raise ValueError("the state pool has no int8 form (kv_quant)")
     if block_size != cfg.sparse_block_size:
         raise ValueError(f"block_size {block_size} is not the sparse block of {cfg.sparse_block_size} tokens")
-    zeros = functools.partial(jnp.zeros, device=sharding)
+    zeros = pool_zeros(sharding)
     n = len(cfg.sparse_layers)
-    kv = (n, num_blocks, block_size, cfg.kv_size)
+    kv = (n, num_blocks, 2, block_size, cfg.kv_size)
     ck = (n, num_blocks, block_size // cfg.sparse_kernel_stride, cfg.kv_size)
     state = (len(cfg.lightning_layers), state_slots, cfg.lightning_heads,
              cfg.lightning_head_dim, cfg.lightning_head_dim)
-    return KVCache(zeros(kv, dtype), zeros(kv, dtype), ckeys=zeros(ck, dtype),
+    return KVCache(zeros(kv, dtype), ckeys=zeros(ck, dtype),
                    state=zeros(state, dtype))
 
 
@@ -282,10 +285,10 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
     ``state_slots[:, 0]``; the suffix is computed here)."""
     _no_lora(lora)
     Bp, T = tokens.shape
-    bs, sp = cache.k.shape[2], sparse.SparseSizes.of(cfg)
+    bs, sp = cache.block_size, sparse.SparseSizes.of(cfg)
     W = block_tables.shape[1]
     KVH, hd, G = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads
-    dtype = cache.k.dtype
+    dtype = cache.kv.dtype
     sfx = jnp.arange(T, dtype=jnp.int32)
     positions = start_pos[:, None] + sfx[None, :]                 # [Bp, T]
     x = _embed(cfg, params, tokens, dtype)
@@ -305,36 +308,40 @@ def prefill_batch_impl(cfg, params, cache, tokens, block_tables, start_pos, true
     # The block before the chunk: its last ``stride`` keys open the chunk's
     # first compressed key (nothing does at position 0).
     prev_blk = jnp.take_along_axis(block_tables, jnp.maximum(start_pos // bs - 1, 0)[:, None], axis=1)[:, 0]
+    tail = jnp.arange(bs - sp.stride, bs, dtype=jnp.int32)
     every_row_dense = W * bs <= sp.dense_len  # static: the table cannot hold a sparse row
 
-    def dense_attend(q, k_cache, v_cache, si):
+    def dense_attend(q, kv_cache, si):
         if impl == "xla":
             return sparse.sparse_prefill_attention(  # every block kept under dense_len: plain causal attention
-                q, k_cache, v_cache, cache.ckeys, si, block_tables, start_pos, true_len,
+                q, kv_cache, cache.ckeys, si, block_tables, start_pos, true_len,
                 sp._replace(dense_len=W * bs))
-        return paged_prefill_attention(q, k_cache, v_cache, si, block_tables, start_pos, true_len,
+        return paged_prefill_attention(q, kv_cache, si, block_tables, start_pos, true_len,
                                        interpret=(impl == "pallas_interpret"))
 
     def sparse_op(si, lp, cache, u):
         q, k, v = sparse_qkv(u, lp, cfg)                           # [Bp, T, KVH, G, hd], [Bp, T, KVH*hd] x2
         with jax.named_scope("kv_write"):
-            k_cache = cache.k.at[si, flat_ids].set(k.reshape(Bp * nb, bs, KVH * hd))
-            v_cache = cache.v.at[si, flat_ids].set(v.reshape(Bp * nb, bs, KVH * hd))
-            before = cache.k[si, prev_blk, bs - sp.stride:]        # [Bp, stride, KVH*hd]
+            kv_cache = write_kv_pages(cache.kv, si, flat_ids, k.reshape(Bp * nb, bs, KVH * hd),
+                                      v.reshape(Bp * nb, bs, KVH * hd))
+            # K's tail, [Bp, stride, KVH*hd], as rows of lanes each with its own slot: a
+            # window over part of the page's token axis (``[..., bs - stride:]``) made the
+            # chip's compiler copy the whole pool token-major wherever Bp > 1.
+            before = cache.kv[si, prev_blk[:, None], 0, tail[None, :]]
             ck = sparse.compress_keys(k, before, sp.stride)
             ckeys = cache.ckeys.at[si, flat_ids].set(ck.reshape(Bp * nb, sp.per_block, KVH * hd))
         if every_row_dense:
             with jax.named_scope("attn"):
-                o = dense_attend(q, k_cache, v_cache, si)
+                o = dense_attend(q, kv_cache, si)
         else:
             with jax.named_scope("sparse_attn"):
                 o = lax.cond(
                     jnp.max(true_len) <= sp.dense_len,
-                    lambda: dense_attend(q, k_cache, v_cache, si),
+                    lambda: dense_attend(q, kv_cache, si),
                     lambda: sparse.sparse_prefill_attention(
-                        q, k_cache, v_cache, ckeys, si, block_tables, start_pos, true_len, sp))
+                        q, kv_cache, ckeys, si, block_tables, start_pos, true_len, sp))
         y = _gate(o.reshape(Bp, T, cfg.q_size), u, lp)
-        return y, cache._replace(k=k_cache, v=v_cache, ckeys=ckeys)
+        return y, cache._replace(kv=kv_cache, ckeys=ckeys)
 
     def lightning_op(li, lp, cache, u):
         q, k, v = lightning_qkv(u, lp, cfg, positions)
@@ -374,10 +381,10 @@ def decode_step_impl(cfg, params, cache, tokens, positions, block_tables, active
     _no_lora(lora)
     impl = resolve_attn_impl(attn_impl)
     B = tokens.shape[0]
-    bs, sp = cache.k.shape[2], sparse.SparseSizes.of(cfg)
+    bs, sp = cache.block_size, sparse.SparseSizes.of(cfg)
     W = block_tables.shape[1]
     KVH, hd = cfg.num_kv_heads, cfg.head_dim
-    x = _embed(cfg, params, tokens, cache.k.dtype)
+    x = _embed(cfg, params, tokens, cache.kv.dtype)
     rows = jnp.arange(B)
     at = positions // bs
     blk = jnp.where(active, block_tables[rows, at], 0)
@@ -397,31 +404,30 @@ def decode_step_impl(cfg, params, cache, tokens, positions, block_tables, active
     span_blk = jnp.where(active[:, None], jnp.take_along_axis(block_tables, span // bs, axis=1), 0)
     every_row_dense = W * bs <= sp.dense_len  # static
 
-    def attend(q, k_cache, v_cache, si, tables, lens):
+    def attend(q, kv_cache, si, tables, lens):
         if impl == "xla":
-            return paged_decode_attention_xla(q, k_cache, v_cache, si, tables, lens)
-        return paged_decode_attention(q, k_cache, v_cache, si, tables, lens,
+            return paged_decode_attention_xla(q, kv_cache, si, tables, lens)
+        return paged_decode_attention(q, kv_cache, si, tables, lens,
                                       interpret=(impl == "pallas_interpret"))
 
     def sparse_op(si, lp, cache, u):
         q, k, v = sparse_qkv(u, lp, cfg)                            # [B, KVH, G, hd], [B, KVH*hd] x2
         with jax.named_scope("kv_write"):
-            k_cache = cache.k.at[si, blk, off].set(k)
-            v_cache = cache.v.at[si, blk, off].set(v)
-            mean = k_cache[si, span_blk, span % bs].astype(jnp.float32).mean(axis=1)
+            kv_cache = write_kv_tokens(cache.kv, si, blk, off, k, v)
+            mean = kv_cache[si, span_blk, 0, span % bs].astype(jnp.float32).mean(axis=1)
             ckeys = cache.ckeys.at[si, ck_blk, off // sp.stride].set(mean.astype(k.dtype))
         if every_row_dense:
             with jax.named_scope("attn"):
-                o = attend(q, k_cache, v_cache, si, block_tables, lengths)
+                o = attend(q, kv_cache, si, block_tables, lengths)
         else:
             with jax.named_scope("sparse_select"):
                 tables, lens = sparse.sparse_select(q, ckeys, si, block_tables, positions, sp)
                 lens = jnp.where(jnp.repeat(active, KVH), lens, 0)
             with jax.named_scope("sparse_attn"):
                 o = sparse.own_kv_head(
-                    attend(sparse.per_kv_head(q), k_cache, v_cache, si, tables, lens), KVH)
+                    attend(sparse.per_kv_head(q), kv_cache, si, tables, lens), KVH)
         y = _gate(o.reshape(B, cfg.q_size), u, lp)
-        return y, cache._replace(k=k_cache, v=v_cache, ckeys=ckeys)
+        return y, cache._replace(kv=kv_cache, ckeys=ckeys)
 
     def lightning_op(li, lp, cache, u):
         q, k, v = lightning_qkv(u, lp, cfg, positions)

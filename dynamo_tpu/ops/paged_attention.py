@@ -8,23 +8,27 @@ Why a kernel at all: the XLA formulation gathers the full (bucketed)
 block-table width `W*bs` out of the page pool per layer per step —
 ~3x HBM traffic on padded context (materialize + re-read) regardless of
 each sequence's true length. The kernel instead walks each row's actual
-pages: one DMA per page (a page is contiguous ``[bs, KVH*hd]`` in the
-cache layout), online-softmax accumulation, work proportional to
-``sum(lengths)`` rather than ``B*W*bs``.
+pages: ONE DMA a page (a page is a block's keys and then its values,
+contiguous ``[2, bs, KVH*hd]`` in the cache layout ``[L, N, 2, bs,
+KVH*hd]``; a latent page is ``[bs, Dk]``), online-softmax accumulation,
+work proportional to ``sum(lengths)`` rather than ``B*W*bs``.
 
 Design notes (measured on a v5e by PR 31, PERF.md section 6; the kernel
 alone at the benchmark cells' call shapes is ``chip_smoke.py``'s kernel
 phase):
 
-- The FULL cache ``[L, N, bs, KVH*hd]`` stays in HBM (`pl.ANY`) in its
-  native dense layout (a 5D [.., KVH, hd] layout forced a whole-cache
-  relayout copy per pallas_call — ~9ms/layer measured on v5e, the reason
-  the cache is stored heads-merged). The layer index is a scalar-prefetch
-  operand, so no layer of the pool is ever sliced out. The gather path
-  (``gather_dequant_pages``: prefill's prefix read and the XLA decode and
-  spec-verify below) no longer slices one either: it gathers pages from
-  the stacked pool by (layer, page). Until PR 28 it took a
-  ``dynamic_slice`` of the layer first, a copy of all N pages of it.
+- The FULL cache ``[L, N, 2, bs, KVH*hd]`` stays in HBM (`pl.ANY`) in its
+  native dense layout (a [.., KVH, hd] layout with the heads apart forced a
+  whole-cache relayout copy per pallas_call — ~9ms/layer measured on v5e,
+  the reason the cache is stored heads-merged; the page's ``(bs, KVH*hd)``
+  tiles are what the kernels hold in VMEM, so the part axis ahead of them
+  costs no padded sublane and no lane shuffle at head size 64). The layer
+  index is a scalar-prefetch operand, so no layer of the pool is ever
+  sliced out. The gather path (``gather_kv_pages``: prefill's prefix read
+  and the XLA decode and spec-verify below) no longer slices one either:
+  it gathers pages from the stacked pool by (layer, page), each page once
+  for its K and its V. Until PR 28 it took a ``dynamic_slice`` of the
+  layer first, a copy of all N pages of it.
 - Grid ``(B,)``: one step a row, and inside it a loop over the row's own
   chunks of P pages. A padding row is one empty step and the table's
   width costs nothing. (Until PR 31 the grid was ``(B, W // P)``: 512
@@ -48,22 +52,30 @@ phase):
   in ``--xla_mosaic_dump_to``'s output; 6.6% of a call), ran its softmax
   over 28 of 128 lanes, and masked V over the whole chunk where only a
   row's last chunk has a tail.
-- **What a call costs is its DMA descriptors, and nothing hides them.** A
-  DMA start occupies the core for about 23 ns (10.7k descriptors in the
-  242 us a sessions-shape call takes with its compute left out), and the
-  compute of a 1 MiB chunk 0.6 us; the two add up (318 us a call). A pure
-  delay in place of the compute adds its whole length; starts set between
-  the sub-products of a chunk, and a third buffer slot, both made a call
-  slower. So: a full chunk's starts are one straight line (no counter,
-  branch or table re-read between them: 64 ``pl.when``s a chunk cost the
-  old kernel 100 us a call), its waits one a pool (a DMA semaphore counts
-  bytes), and only a row's last chunk takes loops. What is left is the
-  page: 16 KB descriptors (Qwen, bs=16) cannot pass 720 GB/s and reach
-  553 with the compute beside them (67% of 819 GB/s), 32 KB ones
-  (Mistral) reach 656 (80%), the 40 KB latent ones 520 (63%: 64 query
-  heads make its chunk's compute twice as long). Page bytes =
-  block_size x KVH x hd x 2 (bf16): a larger ``--block-size`` is the
-  remaining lever, and a configuration's.
+- **What a call costs is its pages' transfer plus the core's own time, and
+  nothing hides the second.** With the compute left out a sessions-shape
+  call (5.4k scattered pages, 176 MB) takes 240 us, 730 GB/s, with two
+  16 KB descriptors a page or with one of 32 KB: that is what the DMA
+  engines carry of scattered pages of 16 to 64 KB, not a cost of starting
+  them (PR 31 read it as 23 ns a descriptor; PR 46 halved the descriptors
+  and it did not move). Beside it stands what the core does itself and
+  cannot overlap: a DMA start, about 10 ns (5.4k fewer starts gave back
+  53 us), and the compute of a 1 MiB chunk, 0.6 us. A pure delay in place
+  of the compute adds its whole length; starts set between the
+  sub-products of a chunk, and a third buffer slot, both made a call
+  slower. So: a page is ONE descriptor (K and V side by side in one pool
+  since PR 46: two pools were two), a full chunk's starts are one
+  straight line (no counter, branch or table re-read between them: 64
+  ``pl.when``s a chunk cost the old kernel 100 us a call), its wait one
+  (a DMA semaphore counts bytes), and only a row's last chunk takes loops.
+  A Qwen page (bs=16) as two 16 KB descriptors reached 553 GB/s with the
+  compute beside them (67% of 819 GB/s; 318 us a sessions-shape call), as
+  one of 32 KB 663 (81%; 266 us); Mistral's 64 KB pages 671 (82%; as two
+  of 32 KB: 656, 80%); the 40 KB latent ones 520 (63%: 64 query heads
+  make its chunk's compute twice as long). Page bytes = 2 x block_size x
+  KVH x hd x 2 (bf16): a larger ``--block-size`` is the remaining lever,
+  a configuration's, and worth little now (the kernel is within a tenth
+  of its DMA side alone).
 - A chunk is about 1 MiB of pages (P from the page's bytes, a power of
   two): 2 MiB chunks are no faster at long context and waste more on a
   row's last chunk, which is computed whole however few pages it holds.
@@ -81,6 +93,7 @@ phase):
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -91,8 +104,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-# Most pages a chunk may hold: a full chunk's DMA starts are unrolled, a
-# pool's P of them, three times over in the kernel's text.
+# Most pages a chunk may hold: a full chunk's DMA starts are unrolled, P of
+# them, three times over in the kernel's text.
 _MAX_PAGES_PER_CHUNK = 64
 
 
@@ -133,47 +146,60 @@ def spec_kernel_fits(num_heads: int, positions: int) -> bool:
 # ---------------------------------------------------------------------------
 
 
-def gather_dequant_pages(
-    cache: jax.Array,         # [L, N, bs, KVH*hd] — the stacked pool
-    scale: jax.Array | None,  # [L, N, bs, KVH] fp32 | None
-    layer_idx: jax.Array,     # scalar int32
-    block_tables: jax.Array,  # [B, W] int32
-    KVH: int, hd: int, dtype,
-):
-    """Gather a batch's pages of one layer straight out of the stacked
-    pool and (for int8 storage) dequantize with the per-position-per-head
-    scales → [B, W*bs, KVH, hd] in ``dtype``. This is the one way the XLA
-    paths read pages, and no array of one layer of the pool is formed on
-    the way: slicing the layer out first copied all N pages of it (84 MB
-    at 5,120 blocks, 102 us at the HBM roofline) to read a few hundred.
-    What is materialized is the gathered ``[B, W, bs, KVH*hd]``, which
-    the Pallas kernels avoid too; the int8→float convert rides the
-    gather output, so that copy stays half the bf16 path's bytes."""
-    B, W = block_tables.shape
-    L, N, bs, _ = cache.shape
-    # Pages: the pool viewed as [L*N, bs, KVH*hd], a bitcast of its dense
-    # layout, and one index a page. ``cache[layer_idx, block_tables]`` is
-    # copy-free too, but packs a two-component index vector every layer:
-    # 5.27 against 4.83 ms for 28 layers of K and V on v5e (PERF.md, PR 28).
-    pages = cache.reshape(L * N, bs, KVH * hd)[layer_idx * N + block_tables]
-    pages = pages.reshape(B, W * bs, KVH, hd)
+def _gather_pages(pool: jax.Array, layer_idx: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """A batch's pages of one layer straight out of a stacked pool
+    ``[L, N, *page]`` → ``[B, W, *page]``. No array of one layer of the pool
+    is formed on the way: slicing the layer out first copied all N pages of
+    it (84 MB at 5,120 blocks, 102 us at the HBM roofline) to read a few
+    hundred. The pool is viewed as ``[L*N, *page]``, a bitcast of its dense
+    layout, with one index a page: ``pool[layer_idx, block_tables]`` is
+    copy-free too, but packs a two-component index vector every layer
+    (5.27 against 4.83 ms for 28 layers of K and V on v5e, PERF.md, PR 28)."""
+    L, N = pool.shape[:2]
+    return pool.reshape(L * N, *pool.shape[2:])[layer_idx * N + block_tables]
+
+
+def _dequant(pages: jax.Array, scale: jax.Array | None, layer_idx, block_tables, dtype):
+    """pages [B, W*bs, KVH, hd] as stored → ``dtype``, by the pool of scales
+    ``[L, N, bs, KVH]`` where the storage is int8. Both indices at once: the
+    chip lays the scales' KVH-wide rows out position-minor, so the flat view
+    is no bitcast there (it compiles to a relayout copy of the whole scale
+    pool in every layer). Dequantize in f32 and round ONCE into ``dtype``:
+    multiplying in bf16 would read the same stored byte back as a different
+    value than the Pallas kernel and the host adapters (which also widen to
+    f32), breaking cross-path consistency for the same block."""
     if scale is None:
         return pages
-    # Scales: both indices at once. The chip lays their KVH-wide rows out
-    # position-minor, so the flat view is no bitcast there: it compiles to
-    # a relayout copy of the whole scale pool in every layer.
-    sc = scale[layer_idx, block_tables].reshape(B, W * bs, KVH)
-    # Dequantize in f32 and round ONCE into ``dtype`` — multiplying in
-    # bf16 would read the same stored byte back as a different value
-    # than the Pallas kernel / host adapters (which also widen to f32),
-    # breaking cross-path consistency for the same block.
+    sc = scale[layer_idx, block_tables].reshape(*pages.shape[:3])
     return (pages.astype(jnp.float32) * sc[..., None]).astype(dtype)
+
+
+def gather_kv_pages(
+    kv_cache: jax.Array,        # [L, N, 2, bs, KVH*hd] — a page is K then V
+    k_scale: jax.Array | None,  # [L, N, bs, KVH] fp32 | None
+    v_scale: jax.Array | None,
+    layer_idx: jax.Array,       # scalar int32
+    block_tables: jax.Array,    # [B, W] int32
+    KVH: int, hd: int, dtype,
+):
+    """The one way the XLA paths read K and V pages: each page gathered ONCE
+    (one index a page moves its K and its V) and split afterwards → (k, v),
+    each [B, W*bs, KVH, hd] in ``dtype``; int8 storage dequantizes with the
+    per-position-per-head scales in the same fused expression, so the
+    int8→float convert rides the gather output and that copy stays half the
+    bf16 path's bytes."""
+    B, W = block_tables.shape
+    pages = _gather_pages(kv_cache, layer_idx, block_tables)      # [B, W, 2, bs, KVH*hd]
+    bs = kv_cache.shape[3]
+    return tuple(
+        _dequant(pages[:, :, i].reshape(B, W * bs, KVH, hd), scale, layer_idx, block_tables, dtype)
+        for i, scale in enumerate((k_scale, v_scale))
+    )
 
 
 def paged_decode_attention_xla(
     q: jax.Array,            # [B, KVH, G, hd]
-    k_cache: jax.Array,      # [L, N, bs, KVH*hd]
-    v_cache: jax.Array,
+    kv_cache: jax.Array,     # [L, N, 2, bs, KVH*hd]
     layer_idx: jax.Array,    # scalar int32
     block_tables: jax.Array, # [B, W] int32
     lengths: jax.Array,      # [B] int32 — attend positions [0, length)
@@ -185,8 +211,7 @@ def paged_decode_attention_xla(
     dequantizes in the same fused expression.  Returns [B, KVH, G, hd]
     in q.dtype."""
     B, KVH, G, hd = q.shape
-    pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, q.dtype)
-    pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
+    pk, pv = gather_kv_pages(kv_cache, k_scale, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
     scale = hd ** -0.5
     ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
     mask = jnp.where(ctx[None, :] < lengths[:, None], 0.0, jnp.float32(NEG_INF))
@@ -198,8 +223,7 @@ def paged_decode_attention_xla(
 
 def paged_spec_attention_xla(
     q: jax.Array,            # [B, T, KVH, G, hd] — T consecutive query positions
-    k_cache: jax.Array,      # [L, N, bs, KVH*hd]
-    v_cache: jax.Array,
+    kv_cache: jax.Array,     # [L, N, 2, bs, KVH*hd]
     layer_idx: jax.Array,    # scalar int32
     block_tables: jax.Array, # [B, W] int32
     lengths: jax.Array,      # [B, T] int32 — query t attends [0, lengths[b, t])
@@ -230,8 +254,7 @@ def paged_spec_attention_xla(
     the Pallas upgrade: the gather+dequant happen in-register, no
     materialized relayout copy.)"""
     B, T, KVH, G, hd = q.shape
-    pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, q.dtype)
-    pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
+    pk, pv = gather_kv_pages(kv_cache, k_scale, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
     scale = hd ** -0.5
     ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
     hist_mask = ctx[None, None, :] < lengths[:, :, None]    # [B, T, W*bs]
@@ -268,22 +291,20 @@ def paged_spec_attention_xla(
 # ---------------------------------------------------------------------------
 
 
-def _page_fetch(layer, tables_ref, pools, bufs, sem, P: int, unroll: bool = True):
+def _page_fetch(layer, tables_ref, pool, buf, sem, P: int, unroll: bool = True):
     """→ (issue, wait): the page DMAs a kernel walks a row's table with.
-    ``pools`` are the stacked pools in HBM ``[L, N, bs, lanes]``, ``bufs``
-    their double buffers ``[2, P, bs, lanes]``, ``sem`` DMA semaphores
-    ``[2 slots, a pool]``. A chunk is P consecutive entries of a row's table.
-    ``unroll`` False keeps every start and wait in a loop: some 7 ns a
-    descriptor slower (PERF.md, PR 31), which only a kernel bound by its
-    descriptors feels, and a kernel's text without 2P starts a call site."""
+    ``pool`` is the stacked pool in HBM ``[L, N, *page]``, a page contiguous
+    there (K then V ``[2, bs, lanes]``, or a latent ``[bs, lanes]``), ``buf``
+    its double buffer ``[2, P, *page]``, ``sem`` a DMA semaphore a slot. A
+    page is ONE descriptor whatever it holds; a chunk is P consecutive
+    entries of a row's table. ``unroll`` False keeps every start and wait in
+    a loop: some 7 ns a descriptor slower (PERF.md, PR 31), which only a
+    kernel bound by its descriptors feels, and a kernel's text without P
+    starts a call site."""
 
-    def page_copies(page, slot, p):
-        """The DMA descriptors of pool page ``page`` into place p of buffer
-        ``slot``: one a pool, all of a pool's on one semaphore."""
-        return [
-            pltpu.make_async_copy(pool.at[layer, page], buf.at[slot, p], sem.at[slot, i])
-            for i, (pool, buf) in enumerate(zip(pools, bufs))
-        ]
+    def page_copy(page, slot, p):
+        """The DMA descriptor of pool page ``page`` into place p of buffer ``slot``."""
+        return pltpu.make_async_copy(pool.at[layer, page], buf.at[slot, p], sem.at[slot])
 
     def issue(row, chunk, slot, npages=None):
         """Start the copies of ``npages`` pages of (row, chunk) into buffer
@@ -292,8 +313,7 @@ def _page_fetch(layer, tables_ref, pools, bufs, sem, P: int, unroll: bool = True
         branch between the descriptors; a short one starts what it holds
         in a loop."""
         def start(p, carry=0):
-            for dma in page_copies(tables_ref[row, chunk * P + p], slot, p):
-                dma.start()
+            page_copy(tables_ref[row, chunk * P + p], slot, p).start()
             return carry
 
         def unrolled():
@@ -313,17 +333,14 @@ def _page_fetch(layer, tables_ref, pools, bufs, sem, P: int, unroll: bool = True
 
     def wait(slot, npages=None):
         """Wait for the copies ``issue`` started. A DMA semaphore counts
-        bytes: a full chunk (``npages`` None: known to be) is one wait a
-        pool for all P pages' bytes, a short one a page-sized wait a page."""
+        bytes: a full chunk (``npages`` None: known to be) is one wait for
+        all P pages' bytes, a short one a page-sized wait a page."""
         def whole():
-            for i, (pool, buf) in enumerate(zip(pools, bufs)):
-                pltpu.make_async_copy(
-                    pool.at[layer, pl.ds(0, P)], buf.at[slot], sem.at[slot, i]).wait()
+            pltpu.make_async_copy(pool.at[layer, pl.ds(0, P)], buf.at[slot], sem.at[slot]).wait()
 
         def paged():
             def one(p, carry):
-                for dma in page_copies(0, slot, 0):  # any page: its bytes count
-                    dma.wait()
+                page_copy(0, slot, 0).wait()  # any page: its bytes count
                 return carry
 
             lax.fori_loop(0, npages, one, 0)
@@ -354,23 +371,19 @@ def _mq_kernel(
     tree_slots: int = 0,
     value_dim: int = 0,
 ):
-    # value_dim > 0: a latent (MLA) pool. One pool, no V: a page row is the
-    # shared key, and its first ``value_dim`` lanes are also the value, so
-    # each page is read once.
+    # One pool either way, and a page of it one DMA descriptor. value_dim 0:
+    # a page is K then V, ``[2, bs, KVH*hd]``. value_dim > 0: a latent (MLA)
+    # page ``[bs, Dk]``, a row the shared key, whose first ``value_dim`` lanes
+    # are also the value.
     refs = list(refs)
     q_ref, lenvec_ref = refs[:2]
     refs = refs[2:]
-    anc_ref = None
+    anc_ref = kscale_ref = vscale_ref = ml_scr = None
     if tree_slots:
         anc_ref, refs = refs[0], refs[1:]
-    kscale_ref = vscale_ref = ml_scr = v_hbm = vbuf = None
-    if value_dim:
-        k_hbm, o_ref, kbuf, acc_scr, slot_ref, started_ref, sem = refs
-    elif quantized:
-        (kscale_ref, vscale_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, acc_scr,
-         slot_ref, started_ref, sem, ml_scr) = refs
-    else:
-        k_hbm, v_hbm, o_ref, kbuf, vbuf, acc_scr, slot_ref, started_ref, sem = refs
+    if quantized:
+        kscale_ref, vscale_ref, *refs, ml_scr = refs
+    kv_hbm, o_ref, kvbuf, acc_scr, slot_ref, started_ref, sem = refs
     # q_ref      VMEM [1, H, KVH*hd] — block-diag q, softmax scale folded in:
     #            one ROW a query column (k, t, g), zero outside head k's lanes
     # lenvec_ref VMEM [1, H, 1] int32 — per query column attend length; in
@@ -378,18 +391,18 @@ def _mq_kernel(
     # anc_ref    VMEM [1, H, T] int32 — tree mode: anc[col, s] = query col
     #            may attend in-flight slot s (its ancestor-or-self set)
     # kscale_ref VMEM [1, P, bs, KVH] f32 — this chunk's per-position-per-head scales
-    # k_hbm      ANY  [L, N, bs, KVH*hd]
+    # kv_hbm     ANY  [L, N, 2, bs, KVH*hd] ([2L, N, bs, Dk] latent)
     # o_ref      VMEM [1, H, Dv] — attention out, every head's lanes a row
-    # kbuf/vbuf  VMEM [2, P, bs, KVH*hd] (cache dtype; int8 when quantized)
+    # kvbuf      VMEM [2, P, 2, bs, KVH*hd] (cache dtype; int8 when quantized;
+    #            [2, P, bs, Dk] latent)
     # acc        VMEM [H, Dv] f32
-    # slot/started SMEM [1] int32; sem DMA sems [2 slots, k | v]
+    # slot/started SMEM [1] int32; sem DMA sems [2 slots]
     # ml_scr     VMEM [2, H, 1] f32 — int8 only: max and sum between grid steps
     P = pages_per_chunk
     b = pl.program_id(0)
     B = pl.num_programs(0)
     layer = layer_ref[0]
-    bs = kbuf.shape[2]
-    D = kbuf.shape[3]       # KVH*hd
+    bs, D = kvbuf.shape[-2:]  # D = KVH*hd
     H = q_ref.shape[1]      # query columns KVH*T*G, padded to whole tiles
     hd = head_dim
     KVH = D // hd
@@ -408,8 +421,10 @@ def _mq_kernel(
         rem = rowlen_ref[row] - chunk * CH
         return jnp.minimum(lax.div(rem + bs - 1, bs), P)
 
-    pools, bufs = ([k_hbm], [kbuf]) if v_hbm is None else ([k_hbm, v_hbm], [kbuf, vbuf])
-    issue, wait = _page_fetch(layer, tables_ref, pools, bufs, sem, P)
+    issue, wait = _page_fetch(layer, tables_ref, kv_hbm, kvbuf, sem, P)
+    # Where page p of buffer ``cur`` keeps its value: V's part, or the latent
+    # page itself.
+    value_at = (lambda cur, p: (cur, p)) if value_dim else (lambda cur, p: (cur, p, 1))
 
     def successor(row, ch):
         """The live chunk after (row, ch) in the walk; row B: none."""
@@ -427,11 +442,12 @@ def _mq_kernel(
 
     def attend(c, cur, m_prev, l_prev):
         """The chunk in buffer ``cur`` into the online softmax."""
-        k_chunk = kbuf[cur].reshape(CH, D)
         if value_dim:
+            k_chunk = kvbuf[cur].reshape(CH, D)
             v_chunk = k_chunk[:, :value_dim]
         else:
-            v_chunk = vbuf[cur].reshape(CH, D)
+            k_chunk = kvbuf[cur, :, 0].reshape(CH, D)
+            v_chunk = kvbuf[cur, :, 1].reshape(CH, D)
         if quantized:
             # In-register dequant of the just-landed int8 pages: expand
             # this chunk's [CH, KVH] scales across each head's lanes and
@@ -499,7 +515,7 @@ def _mq_kernel(
 
         def steady():
             # A full chunk whose successor is a full chunk of the same row:
-            # 2P starts, two waits and the compute in one straight line.
+            # P starts, one wait and the compute in one straight line.
             issue(b, c + 1, nxt)
             wait(cur)
             return attend(c, cur, m_prev, l_prev)
@@ -523,19 +539,18 @@ def _mq_kernel(
             # are cleared once they are dequantized: their scales are
             # garbage there too.)
             if not quantized:
-                tail_buf = kbuf if value_dim else vbuf
-
                 @pl.when(after < 0)
                 def _():
                     def clear(p, carry):
-                        tail_buf[cur, p] = jnp.zeros(tail_buf.shape[2:], tail_buf.dtype)
+                        kvbuf[value_at(cur, p)] = jnp.zeros((bs, D), kvbuf.dtype)
                         return carry
 
                     lax.fori_loop(npages, P, clear, 0)
                     held = length - c * CH - (npages - 1) * bs  # tokens in the last page
                     row = lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
-                    page = tail_buf[cur, npages - 1]
-                    tail_buf[cur, npages - 1] = jnp.where(row < held, page, jnp.zeros_like(page))
+                    last = value_at(cur, npages - 1)
+                    page = kvbuf[last]
+                    kvbuf[last] = jnp.where(row < held, page, jnp.zeros_like(page))
 
             return attend(c, cur, m_prev, l_prev)
 
@@ -593,8 +608,8 @@ def _mq_kernel(
 
 def _paged_attention_mq(
     q: jax.Array,            # [B, T, KVH, G, hd]
-    k_cache: jax.Array,      # [L, N, bs, KVH*hd] — dense pages, no
-    v_cache: jax.Array,      #   per-call layout conversion
+    kv_cache: jax.Array,     # [L, N, 2, bs, KVH*hd] — dense pages, no per-call
+                             #   layout conversion ([2L, N, bs, Dk] latent)
     layer_idx: jax.Array,    # scalar int32
     block_tables: jax.Array, # [B, W] int32
     lengths: jax.Array,      # [B, T] int32
@@ -609,10 +624,12 @@ def _paged_attention_mq(
 ) -> jax.Array:
     """Shared Pallas driver: T query positions per row walk the row's
     true pages once. Returns [B, T, KVH, G, hd] in q.dtype
-    ([B, T, 1, G, value_dim] over a latent pool, ``v_cache`` None)."""
+    ([B, T, 1, G, value_dim] over a latent pool)."""
     B, T, KVH, G, hd = q.shape
-    bs = k_cache.shape[2]
-    assert k_cache.shape[3] == KVH * hd, "cache must be [L, N, bs, KVH*hd]"
+    page = kv_cache.shape[2:]  # (2, bs, KVH*hd), or a latent (bs, Dk)
+    bs = page[-2]
+    assert page == ((bs, hd) if value_dim else (2, bs, KVH * hd)), (
+        f"cache must be [L, N, 2, bs, KVH*hd] (latent [2L, N, bs, Dk]), not {kv_cache.shape}")
     W = block_tables.shape[1]
     H = KVH * T * G
     if H > 128:
@@ -621,15 +638,14 @@ def _paged_attention_mq(
             f"or fall back to the XLA gather path"
         )
     quantized = k_scale is not None
-    pools = [k_cache] if value_dim else [k_cache, v_cache]
     # A chunk of about 1 MiB of pages, a power of two of them: what a chunk
     # costs beside its bytes (a loop iteration, a softmax update) is small
     # against their transfer, and a row's last chunk, computed whole
     # however few pages it holds, wastes little (PERF.md, PR 31).
-    page_bytes = len(pools) * bs * KVH * hd * k_cache.dtype.itemsize
+    page_bytes = math.prod(page) * kv_cache.dtype.itemsize
     P = pages_per_chunk or min(1 << max(0, ((1 << 20) // page_bytes).bit_length() - 1),
                                _MAX_PAGES_PER_CHUNK)
-    P = min(P, W, k_cache.shape[1])  # no larger than the table, or the pool
+    P = min(P, W, kv_cache.shape[1])  # no larger than the table, or the pool
     if W % P:  # pad the table so chunks tile it exactly
         block_tables = jnp.pad(block_tables, ((0, 0), (0, P - W % P)))
 
@@ -691,14 +707,15 @@ def _paged_attention_mq(
         sv = lax.dynamic_index_in_dim(v_scale, layer_idx, 0, keepdims=False)
         operands += [sk[block_tables], sv[block_tables]]
         in_specs += [pl.BlockSpec((1, P, bs, KVH), lambda b, c, *_: (b, c, 0, 0))] * 2
-    operands += pools
-    in_specs += [pl.BlockSpec(memory_space=pl.ANY) for _ in pools]
+    operands.append(kv_cache)
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     out_cols = value_dim or KVH * hd
-    scratch = [pltpu.VMEM((2, P, bs, KVH * hd), pool.dtype) for pool in pools] + [
+    scratch = [
+        pltpu.VMEM((2, P, *page), kv_cache.dtype),
         pltpu.VMEM((Hp, out_cols), jnp.float32),
         pltpu.SMEM((1,), jnp.int32),
         pltpu.SMEM((1,), jnp.int32),
-        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SemaphoreType.DMA((2,)),
     ]
     # The grid is the rows alone: a row's chunks are a loop inside its step,
     # so a padding row is one empty step and the table's width costs nothing.
@@ -741,8 +758,7 @@ def _paged_attention_mq(
 )
 def paged_decode_attention(
     q: jax.Array,            # [B, KVH, G, hd]
-    k_cache: jax.Array,      # [L, N, bs, KVH*hd]
-    v_cache: jax.Array,
+    kv_cache: jax.Array,     # [L, N, 2, bs, KVH*hd]
     layer_idx: jax.Array,    # scalar int32
     block_tables: jax.Array, # [B, W] int32
     lengths: jax.Array,      # [B] int32
@@ -758,7 +774,7 @@ def paged_decode_attention(
             f"{KVH * G} query heads > 128 lanes; shard heads (tp) first"
         )
     o = _paged_attention_mq(
-        q[:, None], k_cache, v_cache, layer_idx, block_tables,
+        q[:, None], kv_cache, layer_idx, block_tables,
         jnp.asarray(lengths, jnp.int32)[:, None], k_scale, v_scale,
         pages_per_chunk, interpret,
     )
@@ -771,8 +787,7 @@ def paged_decode_attention(
 )
 def paged_spec_attention(
     q: jax.Array,            # [B, T, KVH, G, hd]
-    k_cache: jax.Array,      # [L, N, bs, KVH*hd]
-    v_cache: jax.Array,
+    kv_cache: jax.Array,     # [L, N, 2, bs, KVH*hd]
     layer_idx: jax.Array,    # scalar int32
     block_tables: jax.Array, # [B, W] int32
     lengths: jax.Array,      # [B, T] int32
@@ -796,7 +811,7 @@ def paged_spec_attention(
     Requires KVH*T*G ≤ 128 lanes; callers fall back to
     ``paged_spec_attention_xla`` beyond that (model.spec_verify does)."""
     return _paged_attention_mq(
-        q, k_cache, v_cache, layer_idx, block_tables, lengths,
+        q, kv_cache, layer_idx, block_tables, lengths,
         k_scale, v_scale, pages_per_chunk, interpret, anc,
     )
 
@@ -813,8 +828,7 @@ def paged_prefill_attention_xla(
     q: jax.Array,            # [B, T, KVH, G, hd] — positions start_pos .. start_pos+T
     k: jax.Array,            # [B, T, KVH, hd] — the chunk's own keys, as computed
     v: jax.Array,
-    k_cache: jax.Array,      # [L, N, bs, KVH*hd]
-    v_cache: jax.Array,
+    kv_cache: jax.Array,     # [L, N, 2, bs, KVH*hd]
     layer_idx: jax.Array,    # scalar int32
     block_tables: jax.Array, # [B, W] int32
     start_pos: jax.Array,    # [B] int32 — first position of the chunk (block-aligned)
@@ -829,9 +843,8 @@ def paged_prefill_attention_xla(
     ``[B, T, KVH, G, W*bs + T]`` whatever the prefix is: the CPU, mesh and
     int8-KV path. Returns [B, T, KVH, G, hd] in q.dtype."""
     B, T, KVH, G, hd = q.shape
-    W, bs = block_tables.shape[1], k_cache.shape[2]
-    pk = gather_dequant_pages(k_cache, k_scale, layer_idx, block_tables, KVH, hd, q.dtype)
-    pv = gather_dequant_pages(v_cache, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
+    W, bs = block_tables.shape[1], kv_cache.shape[3]
+    pk, pv = gather_kv_pages(kv_cache, k_scale, v_scale, layer_idx, block_tables, KVH, hd, q.dtype)
     # Masks (fp32 additive). chunk→chunk: causal, and nothing past the row's
     # true length; chunk→prefix: every query sees all of its row's prefix.
     neg = jnp.float32(-1e9)
@@ -903,12 +916,10 @@ def _prefill_kernel(
     tables_ref,   # [B, W] int32
     # operands
     q_ref,        # VMEM [1, tq, H*hd] — a tile of queries, softmax scale folded in
-    k_hbm,        # ANY  [L, N, bs, KVH*hd]
-    v_hbm,
+    kv_hbm,       # ANY  [L, N, 2, bs, KVH*hd]
     o_ref,        # VMEM [1, tq, H*hd]
     # scratch
-    kbuf,         # VMEM [2, P, bs, KVH*hd] — pages as they land
-    vbuf,
+    kvbuf,        # VMEM [2, P, 2, bs, KVH*hd] — pages as they land, K then V
     kh_scr,       # VMEM [KVH, CH, hd] — the chunk in hand, a head at a time
     vh_scr,
     qs_scr,       # VMEM [KVH, G*tq, hd] — the tile regrouped: a KV head's G query
@@ -918,7 +929,7 @@ def _prefill_kernel(
     l_scr,        # VMEM [KVH, G*tq, 1] f32 — running sum
     hz_scr,       # VMEM [G*tq, 1] int32 — each query row attends [0, horizon)
     slot_ref,     # SMEM [1] int32
-    sem,          # DMA semaphores [2 slots, k | v]
+    sem,          # DMA semaphores [2 slots]
     *,
     pages_per_chunk: int,
 ):
@@ -937,12 +948,12 @@ def _prefill_kernel(
     the kernel ran 5-7% faster at T 2,048 and took three to four times as
     long to compile. The page DMAs are loops too: 2P unrolled starts a call
     site ran 4% faster and cost 1-2 s of Python tracing a program, 19 s of
-    a warm start. The G*tq rows stay ONE operand: in blocks of tq rows the
+    a warm start (counted with two descriptors a page). The G*tq rows stay ONE operand: in blocks of tq rows the
     kernel is 1.6 times slower."""
     P = pages_per_chunk
     b, j = pl.program_id(0), pl.program_id(1)
     tq = q_ref.shape[1]
-    bs = kbuf.shape[2]
+    bs = kvbuf.shape[3]
     KVH, R, hd = qs_scr.shape
     G, CH = R // tq, P * bs
     layer = layer_ref[0]
@@ -953,8 +964,7 @@ def _prefill_kernel(
     # Chunks every query of the tile sees whole: all of it at or before q0.
     nplain = jnp.minimum(lax.div(q0 + 1, CH), nchunks)
 
-    issue, wait = _page_fetch(
-        layer, tables_ref, [k_hbm, v_hbm], [kbuf, vbuf], sem, P, unroll=False)
+    issue, wait = _page_fetch(layer, tables_ref, kv_hbm, kvbuf, sem, P, unroll=False)
 
     def chunk_pages(c):
         return jnp.minimum(lax.div(bound - c * CH + bs - 1, bs), P)
@@ -1028,8 +1038,8 @@ def _prefill_kernel(
 
             wait(cur, chunk_pages(c))
             for k in range(KVH):
-                kh_scr[k] = kbuf[cur, :, :, head_lanes(k)].reshape(CH, hd)
-                vh_scr[k] = vbuf[cur, :, :, head_lanes(k)].reshape(CH, hd)
+                kh_scr[k] = kvbuf[cur, :, 0, :, head_lanes(k)].reshape(CH, hd)
+                vh_scr[k] = kvbuf[cur, :, 1, :, head_lanes(k)].reshape(CH, hd)
             return lax.cond(c < nplain, lambda: attend(c, False), lambda: attend(c, True))
 
         # The bound is read before the loop, so interpret mode can discharge
@@ -1043,8 +1053,7 @@ def _prefill_kernel(
 
 def paged_prefill_attention(
     q: jax.Array,            # [B, T, KVH, G, hd] — positions start_pos .. start_pos+T
-    k_cache: jax.Array,      # [L, N, bs, KVH*hd] — the chunk's K and V already written
-    v_cache: jax.Array,
+    kv_cache: jax.Array,     # [L, N, 2, bs, KVH*hd] — the chunk's K and V already written
     layer_idx: jax.Array,    # scalar int32
     block_tables: jax.Array, # [B, W] int32
     start_pos: jax.Array,    # [B] int32
@@ -1063,14 +1072,14 @@ def paged_prefill_attention(
     form's precisions. Returns [B, T, KVH, G, hd] in q.dtype; rows of
     queries at or past ``true_len`` are unspecified (the caller drops them)."""
     B, T, KVH, G, hd = q.shape
-    bs = k_cache.shape[2]
-    assert k_cache.shape[3] == KVH * hd, "cache must be [L, N, bs, KVH*hd]"
+    bs = kv_cache.shape[3]
+    assert kv_cache.shape[2:] == (2, bs, KVH * hd), "cache must be [L, N, 2, bs, KVH*hd]"
     tq = q_tile or _prefill_tile(T)
     assert T % tq == 0, (T, tq)
     P = pages_per_chunk or min(max(_PREFILL_CHUNK_TOKENS // bs, 1), _MAX_PAGES_PER_CHUNK)
-    P = min(P, k_cache.shape[1])
+    P = min(P, kv_cache.shape[1])
     return _paged_prefill_call(
-        q, k_cache, v_cache, layer_idx, _whole_table(block_tables, P), start_pos, true_len,
+        q, kv_cache, layer_idx, _whole_table(block_tables, P), start_pos, true_len,
         P=P, tq=tq, interpret=interpret,
     )
 
@@ -1087,30 +1096,29 @@ def _whole_table(block_tables: jax.Array, P: int) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("P", "tq", "interpret"))
-def _paged_prefill_call(q, k_cache, v_cache, layer_idx, block_tables, start_pos, true_len,
+def _paged_prefill_call(q, kv_cache, layer_idx, block_tables, start_pos, true_len,
                         *, P: int, tq: int, interpret: bool):
     B, T, KVH, G, hd = q.shape
-    bs = k_cache.shape[2]
+    bs = kv_cache.shape[3]
     R, H = G * tq, KVH * G
 
     tile = pl.BlockSpec((1, tq, H * hd), lambda b, j, *_: (b, j, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, T // tq),
-        in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile,
         scratch_shapes=[
-            pltpu.VMEM((2, P, bs, KVH * hd), k_cache.dtype),
-            pltpu.VMEM((2, P, bs, KVH * hd), v_cache.dtype),
-            pltpu.VMEM((KVH, P * bs, hd), k_cache.dtype),
-            pltpu.VMEM((KVH, P * bs, hd), v_cache.dtype),
+            pltpu.VMEM((2, P, 2, bs, KVH * hd), kv_cache.dtype),
+            pltpu.VMEM((KVH, P * bs, hd), kv_cache.dtype),
+            pltpu.VMEM((KVH, P * bs, hd), kv_cache.dtype),
             pltpu.VMEM((KVH, R, hd), q.dtype),
             pltpu.VMEM((KVH, R, hd), jnp.float32),
             pltpu.VMEM((KVH, R, 1), jnp.float32),
             pltpu.VMEM((KVH, R, 1), jnp.float32),
             pltpu.VMEM((R, 1), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     o = pl.pallas_call(
@@ -1126,8 +1134,7 @@ def _paged_prefill_call(q, k_cache, v_cache, layer_idx, block_tables, start_pos,
         jnp.asarray(true_len, jnp.int32),
         jnp.asarray(block_tables, jnp.int32),
         (q * hd ** -0.5).reshape(B, T, H * hd),
-        k_cache,
-        v_cache,
+        kv_cache,
     )
     return o.reshape(B, T, KVH, G, hd)
 
@@ -1164,7 +1171,7 @@ def latent_decode_attention_xla(
 ) -> jax.Array:
     """Gather-based reference of the latent decode attention → [B, H, value_dim]."""
     B, H, Dk = q.shape
-    pk = gather_dequant_pages(cache, None, layer_idx, block_tables, 1, Dk, q.dtype)[:, :, 0]
+    pk = _gather_pages(cache, layer_idx, block_tables).reshape(B, -1, Dk)  # [B, W*bs, Dk]
     ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
     mask = jnp.where(ctx[None, :] < lengths[:, None], 0.0, jnp.float32(NEG_INF))
     s = jnp.einsum("bhd,bcd->bhc", q, pk).astype(jnp.float32) * scale
@@ -1190,7 +1197,7 @@ def latent_decode_attention(
     one shared key of Dk lanes, value = its first ``value_dim``; each page
     is read once. Returns [B, H, value_dim]."""
     o = _paged_attention_mq(
-        q[:, None, None], cache, None, layer_idx, block_tables,
+        q[:, None, None], cache, layer_idx, block_tables,
         jnp.asarray(lengths, jnp.int32)[:, None], None, None,
         pages_per_chunk, interpret, value_dim=value_dim, scale=scale,
     )
@@ -1218,7 +1225,7 @@ def latent_prefill_attention_xla(
     Returns [B, H, T, Dv] in q_lat.dtype."""
     B, H, T, Dv = q_lat.shape
     Dk = cache.shape[3]
-    pk = gather_dequant_pages(cache, None, layer_idx, block_tables, 1, Dk, q_lat.dtype)[:, :, 0]
+    pk = _gather_pages(cache, layer_idx, block_tables).reshape(B, -1, Dk)  # [B, W*bs, Dk]
     C = pk.shape[1]
     horizon = jnp.minimum(start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None] + 1,
                           true_len[:, None])                       # [B, T]
@@ -1285,7 +1292,7 @@ def _latent_prefill_kernel(
     l_scr,        # VMEM [H*tq, 1] f32 — running sum
     hz_scr,       # VMEM [H*tq, 1] int32 — each query row attends [0, horizon)
     slot_ref,     # SMEM [1] int32
-    sem,          # DMA semaphores [2 slots, 1]
+    sem,          # DMA semaphores [2 slots]
     *,
     pages_per_chunk: int,
     scale: float,
@@ -1314,7 +1321,7 @@ def _latent_prefill_kernel(
     # Chunks every query of the tile sees whole: all of it at or before q0.
     nplain = jnp.minimum(lax.div(q0 + 1, CH), nchunks)
 
-    issue, wait = _page_fetch(layer, tables_ref, [k_hbm], [kbuf], sem, P, unroll=False)
+    issue, wait = _page_fetch(layer, tables_ref, k_hbm, kbuf, sem, P, unroll=False)
 
     def chunk_pages(c):
         return jnp.minimum(lax.div(bound - c * CH + bs - 1, bs), P)
@@ -1448,7 +1455,7 @@ def _latent_prefill_call(q_lat, q_rope, cache, layer_idx, block_tables, start_po
             pltpu.VMEM((R, 1), jnp.float32),
             pltpu.VMEM((R, 1), jnp.int32),
             pltpu.SMEM((1,), jnp.int32),
-            pltpu.SemaphoreType.DMA((2, 1)),
+            pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     return pl.pallas_call(

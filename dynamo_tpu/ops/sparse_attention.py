@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dynamo_tpu.ops.paged_attention import gather_dequant_pages
+from dynamo_tpu.ops.paged_attention import gather_kv_pages
 
 NEG = -1e30
 _PREFILL_Q_TILE = 32  # float32 scores of 32 heads x 32 queries x 24,576 positions are 101 MB
@@ -138,7 +138,7 @@ def own_kv_head(o: jax.Array, KVH: int) -> jax.Array:
     return jnp.einsum("bkkgh->bkgh", o.reshape(B, KVH, *o.shape[1:]))
 
 
-def sparse_prefill_attention(q, k_cache, v_cache, ckeys, layer, block_tables, start_pos, true_len,
+def sparse_prefill_attention(q, kv_cache, ckeys, layer, block_tables, start_pos, true_len,
                              sp: SparseSizes) -> jax.Array:
     """q [B, T, KVH, G, hd] at positions ``start_pos + i``, the chunk's K, V and
     compressed keys already in the pools → [B, T, KVH, G, hd]: each query
@@ -147,8 +147,7 @@ def sparse_prefill_attention(q, k_cache, v_cache, ckeys, layer, block_tables, st
     B, T, KVH, G, hd = q.shape
     W = block_tables.shape[1]
     tq = math.gcd(T, _PREFILL_Q_TILE)
-    pk = gather_dequant_pages(k_cache, None, layer, block_tables, KVH, hd, q.dtype)   # [B, W*bs, KVH, hd]
-    pv = gather_dequant_pages(v_cache, None, layer, block_tables, KVH, hd, q.dtype)
+    pk, pv = gather_kv_pages(kv_cache, None, None, layer, block_tables, KVH, hd, q.dtype)  # [B, W*bs, KVH, hd] x2
     ck = gather_ckeys(ckeys, layer, block_tables, KVH)
     ctx = jnp.arange(W * sp.block, dtype=jnp.int32)
     topk = min(sp.topk, W)

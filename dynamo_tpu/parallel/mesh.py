@@ -168,11 +168,13 @@ class ModelSharding:
                 shardings["lm_head_scale"] = vocab1d
         return shardings
 
-    def cache_spec(self) -> P:
-        # [L, num_blocks, block_size, KVH*hd] — the merged head-dim splits
+    def cache_spec(self, rank: int) -> P:
+        # [L, num_blocks, 2, block_size, KVH*hd] — the merged head-dim splits
         # into tp_kv contiguous [KVH/tp_kv * hd] chunks, i.e. kv heads
-        # grouped exactly as the attention einsums expect.
-        return P(None, None, None, TP_KV_AXIS)
+        # grouped exactly as the attention einsums expect; a page's K and V
+        # parts split alike. The int8 scales [L, num_blocks, block_size, KVH]
+        # are ``rank`` 4: the last axis is the kv-head axis either way.
+        return P(*(None,) * (rank - 1), TP_KV_AXIS)
 
     def batch_spec(self) -> P:
         return P(DP_AXIS)
@@ -186,11 +188,13 @@ class ModelSharding:
         shardings = self.param_shardings(jax.eval_shape(build))
         return jax.jit(build, out_shardings=shardings)()
 
-    def cache_sharding(self) -> NamedSharding:
-        """For ``init_kv_cache(sharding=)``. Pages and int8 scale arrays
-        ([L, N, bs, KVH]) alike: their last axis is the kv-head axis, split
-        over tp_kv, so each shard dequantizes its own heads locally."""
-        return self._ns(*self.cache_spec())
+    def cache_sharding(self, rank: int) -> NamedSharding:
+        """The sharding of a pool of ``rank`` axes; the method itself is what
+        ``init_kv_cache(sharding=)`` takes. Pages ([L, N, 2, bs, KVH*hd]) and
+        int8 scale arrays ([L, N, bs, KVH]) alike: their last axis is the
+        kv-head axis, split over tp_kv, so each shard dequantizes its own
+        heads locally."""
+        return self._ns(*self.cache_spec(rank))
 
     def shard_params(self, params: Any) -> Any:
         if jax.process_count() > 1:

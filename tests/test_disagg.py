@@ -74,9 +74,10 @@ async def collect(engine_like, req, ctx=None):
 def test_extract_inject_roundtrip():
     cache = M.init_kv_cache(CFG, num_blocks=16, block_size=4, dtype=jnp.float32)
     rng = np.random.default_rng(0)
-    k = rng.normal(size=cache.k.shape).astype(np.float32)
-    v = rng.normal(size=cache.v.shape).astype(np.float32)
-    cache = M.KVCache(jnp.asarray(k), jnp.asarray(v))
+    L, N, _, bs, lanes = cache.kv.shape
+    k = rng.normal(size=(L, N, bs, lanes)).astype(np.float32)
+    v = rng.normal(size=(L, N, bs, lanes)).astype(np.float32)
+    cache = M.KVCache(M.fuse_kv(jnp.asarray(k), jnp.asarray(v)))
 
     ids = [3, 7, 2]
     pk, pv = kv_transfer.extract_pages(cache, ids)
@@ -92,8 +93,9 @@ def test_extract_inject_roundtrip():
 
     cache2 = M.init_kv_cache(CFG, num_blocks=16, block_size=4, dtype=jnp.float32)
     cache2 = kv_transfer.inject_pages(cache2, [5, 1, 9], back.k, back.v)
-    got = np.asarray(cache2.k)
+    got, got_v = (np.asarray(a) for a in M.split_kv(cache2.kv))
     np.testing.assert_array_equal(got[:, [5, 1, 9]], k[:, ids])
+    np.testing.assert_array_equal(got_v[:, [5, 1, 9]], v[:, ids])
     assert (got[:, 4] == 0).all()  # untouched block stays zero
 
 

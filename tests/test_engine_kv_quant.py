@@ -97,11 +97,12 @@ def test_host_quantize_matches_device_kv_quantize():
 def test_extract_inject_roundtrip_with_scales():
     cache = M.init_kv_cache(CFG, 16, 4, jnp.float32, kv_quant="int8")
     rng = np.random.default_rng(1)
-    shape = cache.k.shape
+    L, N, _, bs, lanes = cache.kv.shape
+    shape = (L, N, bs, lanes)
     sshape = cache.k_scale.shape
     cache = M.KVCache(
-        jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
-        jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+        M.fuse_kv(jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                  jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)),
         jnp.asarray(np.abs(rng.standard_normal(sshape)) + 1e-3, jnp.float32),
         jnp.asarray(np.abs(rng.standard_normal(sshape)) + 1e-3, jnp.float32),
     )
@@ -123,7 +124,7 @@ def test_extract_inject_roundtrip_with_scales():
 
     cache2 = M.init_kv_cache(CFG, 16, 4, jnp.float32, kv_quant="int8")
     cache2 = kv_transfer.inject_pages(cache2, ids, *back.pages())
-    np.testing.assert_array_equal(np.asarray(cache2.k[:, 5]), np.asarray(cache.k[:, 5]))
+    np.testing.assert_array_equal(np.asarray(cache2.kv[:, 5]), np.asarray(cache.kv[:, 5]))
     np.testing.assert_array_equal(
         np.asarray(cache2.k_scale[:, 9]), np.asarray(cache.k_scale[:, 9])
     )
@@ -142,7 +143,7 @@ def test_adapt_pages_bridges_formats():
     adapted = kv_transfer.adapt_pages((kf, vf), quant_cache, KVH)
     assert len(adapted) == 4 and adapted[0].dtype == np.int8
     out = kv_transfer.inject_pages(quant_cache, [1, 2], *adapted)
-    assert out.k.dtype == jnp.int8
+    assert out.kv.dtype == jnp.int8
 
     float_cache = M.init_kv_cache(CFG, 8, bs, jnp.float32)
     back = kv_transfer.adapt_pages(tuple(adapted), float_cache, KVH)

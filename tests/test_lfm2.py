@@ -122,7 +122,7 @@ def test_a_packed_wave_of_rows_at_their_own_start_pos_agrees_with_the_reference(
     np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(REF.forward(DOC, ref_params, a))[-1], **TOL)
     np.testing.assert_allclose(np.asarray(logits[1]), np.asarray(REF.forward(DOC, ref_params, b))[-1], **TOL)
     # the shared blocks of row 1 are as they were: nothing wrote a sealed block
-    for name in ("k", "v"):
+    for name in ("kv",):
         np.testing.assert_array_equal(np.asarray(getattr(cache, name))[:, 9:11], getattr(before, name)[:, 9:11])
     np.testing.assert_array_equal(np.asarray(cache.conv)[:, :, 9:11], before.conv[:, :, 9:11])
     E = CFG.num_experts
@@ -306,9 +306,9 @@ def test_the_worker_says_what_it_runs_and_counts_where_conv_state_came_from():
 def test_pool_accounting_counts_both_pools():
     args = engine_args(dtype="bfloat16")
     cache = lfm2.init_kv_cache(CFG, args.num_kv_blocks, BS)
-    assert cache.k.shape == cache.v.shape == (1, 24, BS, CFG.kv_size)
+    assert cache.kv.shape == (1, 24, 2, BS, CFG.kv_size) and cache.block_size == BS
     assert cache.conv.shape == (5, 2, 24, CFG.hidden_size)
-    assert cache.k.nbytes + cache.v.nbytes + cache.conv.nbytes == args.num_kv_blocks * args.kv_bytes_per_block()
+    assert cache.kv.nbytes + cache.conv.nbytes == args.num_kv_blocks * args.kv_bytes_per_block()
 
 
 # -- what refuses the block --------------------------------------------------------

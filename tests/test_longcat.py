@@ -429,8 +429,8 @@ def test_pool_accounting_is_in_bytes_of_the_latent_page():
     assert CFG.cache_layers == 2 * CFG.num_layers and CFG.latent_page_width == 128
     assert args.kv_bytes_per_block() == CFG.cache_layers * BS * CFG.latent_page_width * 2
     cache = longcat.init_kv_cache(CFG, args.num_kv_blocks, BS)
-    assert cache.v is None and cache.k.shape == (4, 24, BS, 128)
-    assert cache.k.nbytes == args.num_kv_blocks * args.kv_bytes_per_block()
+    assert cache.kv.shape == (4, 24, BS, 128) and cache.block_size == BS
+    assert cache.kv.nbytes == args.num_kv_blocks * args.kv_bytes_per_block()
 
 
 # -- what refuses the block --------------------------------------------------------

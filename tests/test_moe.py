@@ -76,7 +76,7 @@ def test_moe_ep_sharded_matches_single_device():
     mesh = build_mesh(tp=2, ep=4, cfg=cfg)
     sh = ModelSharding(mesh, cfg)
     params_s = sh.shard_params(jax.tree.map(np.asarray, params))
-    cache_s = M.init_kv_cache(cfg, N, bs, jnp.float32, sharding=sh.cache_sharding())
+    cache_s = M.init_kv_cache(cfg, N, bs, jnp.float32, sharding=sh.cache_sharding)
     out, _ = M.decode_step(cfg, params_s, cache_s, tokens, positions, tables, active)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-4, rtol=2e-4)
 

@@ -69,9 +69,9 @@ def test_kernel_matches_xla(lengths):
     tables = jnp.asarray(rng.integers(1, N, size=(B, W)), jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
     for layer in (0, 2):
-        ref = paged_decode_attention_xla(q, k_cache, v_cache, jnp.int32(layer), tables, lens)
+        ref = paged_decode_attention_xla(q, M.fuse_kv(k_cache, v_cache), jnp.int32(layer), tables, lens)
         out = paged_decode_attention(
-            q, k_cache, v_cache, jnp.int32(layer), tables, lens, interpret=True
+            q, M.fuse_kv(k_cache, v_cache), jnp.int32(layer), tables, lens, interpret=True
         )
         act = np.asarray(lens) > 0
         np.testing.assert_allclose(
@@ -89,9 +89,9 @@ def test_kernel_single_page_chunks():
     q = _mk(rng, (B, KVH, G, hd))
     tables = jnp.asarray(rng.integers(1, N, size=(B, W)), jnp.int32)
     lens = jnp.asarray([32, 7, 9], jnp.int32)
-    ref = paged_decode_attention_xla(q, k_cache, v_cache, jnp.int32(0), tables, lens)
+    ref = paged_decode_attention_xla(q, M.fuse_kv(k_cache, v_cache), jnp.int32(0), tables, lens)
     out = paged_decode_attention(
-        q, k_cache, v_cache, jnp.int32(0), tables, lens,
+        q, M.fuse_kv(k_cache, v_cache), jnp.int32(0), tables, lens,
         pages_per_chunk=1, interpret=True,
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
@@ -109,10 +109,7 @@ def test_decode_step_pallas_matches_xla():
     active = jnp.asarray([True, True, True, False])
 
     cache = M.init_kv_cache(cfg, N, bs, jnp.float32)
-    cache = M.KVCache(
-        jnp.asarray(rng.standard_normal(cache.k.shape), jnp.float32),
-        jnp.asarray(rng.standard_normal(cache.v.shape), jnp.float32),
-    )
+    cache = M.KVCache(jnp.asarray(rng.standard_normal(cache.kv.shape), jnp.float32))
     ref_logits, ref_cache = M.decode_step_impl(
         cfg, params, cache, tokens, positions, tables, active, attn_impl="xla"
     )
@@ -127,7 +124,7 @@ def test_decode_step_pallas_matches_xla():
     # Block 0 is the garbage sink: inactive rows' hidden states (and hence
     # the garbage they scatter) legitimately diverge between impls.
     np.testing.assert_allclose(
-        np.asarray(ref_cache.k)[:, 1:], np.asarray(out_cache.k)[:, 1:], atol=1e-4
+        np.asarray(ref_cache.kv)[:, 1:], np.asarray(out_cache.kv)[:, 1:], atol=1e-4
     )
 
 
@@ -154,10 +151,10 @@ def test_quantized_kernel_matches_quantized_xla_and_f32(lengths):
     act = np.asarray(lengths) > 0
     for layer in (0, 2):
         ref_q = paged_decode_attention_xla(
-            q, kq, vq, jnp.int32(layer), tables, lens, ks, vs
+            q, M.fuse_kv(kq, vq), jnp.int32(layer), tables, lens, ks, vs
         )
         out = paged_decode_attention(
-            q, kq, vq, jnp.int32(layer), tables, lens, ks, vs, interpret=True
+            q, M.fuse_kv(kq, vq), jnp.int32(layer), tables, lens, ks, vs, interpret=True
         )
         np.testing.assert_allclose(
             np.asarray(ref_q)[act], np.asarray(out)[act], atol=2e-5, rtol=2e-5
@@ -165,7 +162,7 @@ def test_quantized_kernel_matches_quantized_xla_and_f32(lengths):
         # vs the f32 path over the explicitly dequantized cache: the
         # in-kernel dequant must BE the dequant, not an approximation.
         ref_f = paged_decode_attention_xla(
-            q, _dequant(kq, ks, KVH, hd), _dequant(vq, vs, KVH, hd),
+            q, M.fuse_kv(_dequant(kq, ks, KVH, hd), _dequant(vq, vs, KVH, hd)),
             jnp.int32(layer), tables, lens,
         )
         np.testing.assert_allclose(
@@ -200,10 +197,10 @@ def test_spec_kernel_matches_xla(S):
     lens = jnp.asarray(lengths, jnp.int32)
     for layer in (0, 1):
         ref = paged_spec_attention_xla(
-            q, k_cache, v_cache, jnp.int32(layer), tables, lens
+            q, M.fuse_kv(k_cache, v_cache), jnp.int32(layer), tables, lens
         )
         out = paged_spec_attention(
-            q, k_cache, v_cache, jnp.int32(layer), tables, lens, interpret=True
+            q, M.fuse_kv(k_cache, v_cache), jnp.int32(layer), tables, lens, interpret=True
         )
         live = np.asarray(lengths) > 0  # dead slots/rows are junk by contract
         np.testing.assert_allclose(
@@ -226,10 +223,10 @@ def test_spec_kernel_quantized_matches_xla(S):
             lengths[b, t] = 5 + 9 * b + t + 1
     lens = jnp.asarray(lengths, jnp.int32)
     ref = paged_spec_attention_xla(
-        q, kq, vq, jnp.int32(1), tables, lens, ks, vs
+        q, M.fuse_kv(kq, vq), jnp.int32(1), tables, lens, ks, vs
     )
     out = paged_spec_attention(
-        q, kq, vq, jnp.int32(1), tables, lens, ks, vs, interpret=True
+        q, M.fuse_kv(kq, vq), jnp.int32(1), tables, lens, ks, vs, interpret=True
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
 
@@ -246,9 +243,9 @@ def test_spec_kernel_single_page_chunks():
     lens = jnp.asarray(
         [[30, 31, 32], [6, 7, 8], [1, 2, 0]], jnp.int32
     )
-    ref = paged_spec_attention_xla(q, k_cache, v_cache, jnp.int32(0), tables, lens)
+    ref = paged_spec_attention_xla(q, M.fuse_kv(k_cache, v_cache), jnp.int32(0), tables, lens)
     out = paged_spec_attention(
-        q, k_cache, v_cache, jnp.int32(0), tables, lens,
+        q, M.fuse_kv(k_cache, v_cache), jnp.int32(0), tables, lens,
         pages_per_chunk=1, interpret=True,
     )
     live = np.asarray(lens) > 0
@@ -283,15 +280,15 @@ def test_tree_mask_chain_reduces_to_linear():
     lens_tree = np.broadcast_to(hist[:, None], (B, T)).copy()
     anc = np.broadcast_to(np.tril(np.ones((T, T), np.int8)), (B, T, T)).copy()
     ref = paged_spec_attention_xla(
-        q, k_cache, v_cache, jnp.int32(0), tables, jnp.asarray(lens_linear)
+        q, M.fuse_kv(k_cache, v_cache), jnp.int32(0), tables, jnp.asarray(lens_linear)
     )
     tree = paged_spec_attention_xla(
-        q, k_cache, v_cache, jnp.int32(0), tables, jnp.asarray(lens_tree),
+        q, M.fuse_kv(k_cache, v_cache), jnp.int32(0), tables, jnp.asarray(lens_tree),
         anc=jnp.asarray(anc),
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(tree), atol=1e-6)
     out = paged_spec_attention(
-        q, k_cache, v_cache, jnp.int32(0), tables, jnp.asarray(lens_tree),
+        q, M.fuse_kv(k_cache, v_cache), jnp.int32(0), tables, jnp.asarray(lens_tree),
         anc=jnp.asarray(anc), interpret=True,
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
@@ -323,11 +320,11 @@ def test_tree_mask_kernel_matches_xla(hist):
     live = np.asarray(anc.any(axis=2))
     for layer in (0, 1):
         ref = paged_spec_attention_xla(
-            q, k_cache, v_cache, jnp.int32(layer), tables, jnp.asarray(lens),
+            q, M.fuse_kv(k_cache, v_cache), jnp.int32(layer), tables, jnp.asarray(lens),
             anc=jnp.asarray(anc),
         )
         out = paged_spec_attention(
-            q, k_cache, v_cache, jnp.int32(layer), tables, jnp.asarray(lens),
+            q, M.fuse_kv(k_cache, v_cache), jnp.int32(layer), tables, jnp.asarray(lens),
             anc=jnp.asarray(anc), interpret=True,
         )
         np.testing.assert_allclose(
@@ -348,18 +345,18 @@ def test_tree_mask_kernel_quantized_and_single_page():
     hist = np.array([9, 16, 2], np.int32)
     lens = np.broadcast_to(hist[:, None], (B, T)).copy()
     ref = paged_spec_attention_xla(
-        q, kq, vq, jnp.int32(1), tables, jnp.asarray(lens), ks, vs,
+        q, M.fuse_kv(kq, vq), jnp.int32(1), tables, jnp.asarray(lens), ks, vs,
         anc=jnp.asarray(anc),
     )
     out = paged_spec_attention(
-        q, kq, vq, jnp.int32(1), tables, jnp.asarray(lens), ks, vs,
+        q, M.fuse_kv(kq, vq), jnp.int32(1), tables, jnp.asarray(lens), ks, vs,
         jnp.asarray(anc), pages_per_chunk=1, interpret=True,
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=2e-5, rtol=2e-5)
     # f32 reference over the dequantized cache: the masked in-kernel
     # dequant must BE the dequant.
     ref_f = paged_spec_attention_xla(
-        q, _dequant(kq, ks, KVH, hd), _dequant(vq, vs, KVH, hd),
+        q, M.fuse_kv(_dequant(kq, ks, KVH, hd), _dequant(vq, vs, KVH, hd)),
         jnp.int32(1), tables, jnp.asarray(lens), anc=jnp.asarray(anc),
     )
     np.testing.assert_allclose(np.asarray(ref_f), np.asarray(out), atol=2e-5, rtol=2e-5)
@@ -462,8 +459,8 @@ def test_walk_edges_match_xla(walk, edge):
         if kind == "decode":
             q = _mk(rng, (B, KVH, G, hd))
             lens = jnp.asarray(lengths)
-            ref = paged_decode_attention_xla(q, k, v, jnp.int32(1), tables, lens, *scales)
-            out = paged_decode_attention(q, k, v, jnp.int32(1), tables, lens, *scales, **kw)
+            ref = paged_decode_attention_xla(q, M.fuse_kv(k, v), jnp.int32(1), tables, lens, *scales)
+            out = paged_decode_attention(q, M.fuse_kv(k, v), jnp.int32(1), tables, lens, *scales, **kw)
             live = lengths > 0
         else:
             q = _mk(rng, (B, T, KVH, G, hd))
@@ -486,9 +483,9 @@ def test_walk_edges_match_xla(walk, edge):
                 anc = jnp.asarray(anc_np)
                 live = np.repeat((lengths > 0)[:, None], T, axis=1)
             ref = paged_spec_attention_xla(
-                q, k, v, jnp.int32(1), tables, jnp.asarray(lens2), *scales, anc=anc)
+                q, M.fuse_kv(k, v), jnp.int32(1), tables, jnp.asarray(lens2), *scales, anc=anc)
             out = paged_spec_attention(
-                q, k, v, jnp.int32(1), tables, jnp.asarray(lens2), *scales, anc, **kw)
+                q, M.fuse_kv(k, v), jnp.int32(1), tables, jnp.asarray(lens2), *scales, anc, **kw)
     out = np.asarray(out)
     assert np.isfinite(out).all()
     np.testing.assert_allclose(np.asarray(ref)[live], out[live], atol=2e-5, rtol=2e-5)
@@ -562,9 +559,9 @@ def _prefill_case(geom, T, W, rows, dtype=jnp.float32, **_):
 def test_prefill_kernel_matches_the_xla_form(name):
     case = PREFILL_CASES[name]
     q, k, v, k_cache, v_cache, layer, tables, start, tlen = _prefill_case(**case)
-    ref = paged_prefill_attention_xla(q, k, v, k_cache, v_cache, layer, tables, start, tlen)
+    ref = paged_prefill_attention_xla(q, k, v, M.fuse_kv(k_cache, v_cache), layer, tables, start, tlen)
     out = paged_prefill_attention(
-        q, k_cache, v_cache, layer, tables, start, tlen,
+        q, M.fuse_kv(k_cache, v_cache), layer, tables, start, tlen,
         pages_per_chunk=case.get("P", 0), q_tile=case.get("tq", 0), interpret=True,
     )
     assert out.dtype == q.dtype and out.shape == q.shape
@@ -591,17 +588,14 @@ def test_prefill_batch_pallas_matches_xla():
     start = jnp.asarray([24, 0, 0], jnp.int32)
     tlen = jnp.asarray([24 + 13, 16, 0], jnp.int32)
     cache = M.init_kv_cache(cfg, N, bs, jnp.float32)
-    cache = M.KVCache(
-        jnp.asarray(rng.standard_normal(cache.k.shape), jnp.float32),
-        jnp.asarray(rng.standard_normal(cache.v.shape), jnp.float32),
-    )
+    cache = M.KVCache(jnp.asarray(rng.standard_normal(cache.kv.shape), jnp.float32))
     ref_logits, ref_cache = M.prefill_batch_impl(
         cfg, params, cache, tokens, tables, start, tlen, attn_impl="xla")
     out_logits, out_cache = M.prefill_batch_impl(
         cfg, params, cache, tokens, tables, start, tlen, attn_impl="pallas_interpret")
     np.testing.assert_allclose(
         np.asarray(ref_logits)[:2], np.asarray(out_logits)[:2], atol=1e-4, rtol=1e-4)
-    for got, want in ((out_cache.k, ref_cache.k), (out_cache.v, ref_cache.v)):
+    for got, want in zip(M.split_kv(out_cache.kv), M.split_kv(ref_cache.kv)):
         np.testing.assert_allclose(np.asarray(want)[:, 1:], np.asarray(got)[:, 1:], atol=1e-4)
 
 
@@ -681,3 +675,80 @@ def test_resolve_prefill_impl():
     assert latent.kv_size % 128 and resolve_prefill_impl("pallas", latent, 32, False) == ("pallas", "")
     impl, why = resolve_prefill_impl("pallas", ModelConfig.preset("longcat-tiny"), 8, False)
     assert impl == "xla" and "latent page row" in why
+
+
+# ---------------------------------------------------------------------------
+# One page, one descriptor (PR 46): K and V of a block side by side in one
+# pool ``[L, N, 2, bs, KVH*hd]``. Every kernel that walks it against its XLA
+# form at each served geometry, over the rows a walk can go wrong at: length
+# 0, exactly one page, a partial last chunk (chunks of 2 pages), and a table
+# wider than the pool has pages (P is cut to the pool).
+# ---------------------------------------------------------------------------
+
+SERVED = {
+    # name: (KVH, G, hd, bs)
+    "qwen_G7_KVH4_bs16": (4, 7, 128, 16),
+    "mistral_G4_KVH8_bs16": (8, 4, 128, 16),
+    "lfm2_hd64_bs32": (8, 4, 64, 32),
+    "sala_KVH2_bs64": (2, 16, 128, 64),
+}
+FUSED_KINDS = ("decode", "decode-int8", "spec", "spec-int8", "tree", "prefill")
+
+
+@pytest.mark.parametrize("kind", FUSED_KINDS)
+@pytest.mark.parametrize("geometry", list(SERVED))
+def test_fused_page_kernels_match_xla_at_served_geometries(geometry, kind):
+    KVH, G, hd, bs = SERVED[geometry]
+    rng = np.random.default_rng(46)
+    L, N, W, P, T = 2, 6, 8, 2, 3          # the table is wider than the pool
+    lengths = np.asarray([0, bs, 3 * bs - 5, 1, W * bs], np.int32)
+    B = len(lengths)
+    tables = jnp.asarray(rng.integers(1, N, size=(B, W)), jnp.int32)
+    walk, _, quant = kind.partition("-")
+    if quant:
+        kq, vq, *scales = _mk_quant_cache(rng, L, N, bs, KVH, hd)
+        kv = M.fuse_kv(kq, vq)
+    else:
+        kv, scales = _mk(rng, (L, N, 2, bs, KVH * hd)), (None, None)
+    assert kv.shape == (L, N, 2, bs, KVH * hd)
+    layer, kw = jnp.int32(1), dict(pages_per_chunk=P, interpret=True)
+    if walk == "decode":
+        q = _mk(rng, (B, KVH, G, hd))
+        ref = paged_decode_attention_xla(q, kv, layer, tables, jnp.asarray(lengths), *scales)
+        out = paged_decode_attention(q, kv, layer, tables, jnp.asarray(lengths), *scales, **kw)
+        live = lengths > 0
+    elif walk == "prefill":
+        # Chunks of T new positions that end where the rows do; the chunk's
+        # own K and V are in the pages already (the XLA form takes them too).
+        Tq = bs
+        start = np.maximum((lengths - 1) // bs * bs, 0).astype(np.int32)
+        q = _mk(rng, (B, Tq, KVH, G, hd))
+        pos = start[:, None] + np.arange(Tq)[None]
+        blk = np.take_along_axis(
+            np.concatenate([np.asarray(tables), np.zeros((B, 2), np.int32)], axis=1), pos // bs, axis=1)
+        k, v = (jnp.asarray(np.asarray(part)[1, blk, pos % bs].reshape(B, Tq, KVH, hd))
+                for part in M.split_kv(kv))
+        ref = paged_prefill_attention_xla(q, k, v, kv, layer, tables, jnp.asarray(start), jnp.asarray(lengths))
+        out = paged_prefill_attention(q, kv, layer, tables, jnp.asarray(start), jnp.asarray(lengths),
+                                      pages_per_chunk=P, interpret=True)
+        live = pos < lengths[:, None]
+    else:
+        if KVH * G * T > 128:  # the spec kernel's 128 query columns: one query position fits
+            T = 1
+        q = _mk(rng, (B, T, KVH, G, hd))
+        if walk == "spec":
+            lens2 = np.maximum(lengths[:, None] - (T - 1) + np.arange(T)[None, :], 0)
+            lens2 = np.where(lengths[:, None] > 0, lens2, 0).astype(np.int32)
+            anc, live = None, lens2 > 0
+        else:
+            lens2 = np.repeat(np.maximum(lengths - T, 0)[:, None], T, axis=1).astype(np.int32)
+            anc1 = _tree_anc([0] * (T - 1), T)
+            anc = jnp.asarray(np.where(lengths[:, None, None] > 0, anc1[None], 0).astype(np.int8))
+            live = np.repeat((lengths > 0)[:, None], T, axis=1)
+        ref = paged_spec_attention_xla(q, kv, layer, tables, jnp.asarray(lens2), *scales, anc=anc)
+        out = paged_spec_attention(q, kv, layer, tables, jnp.asarray(lens2), *scales, anc, **kw)
+    out = np.asarray(out)
+    assert np.isfinite(out[live]).all()
+    np.testing.assert_allclose(np.asarray(ref)[live], out[live], atol=3e-5, rtol=3e-5)
+    if walk != "prefill":  # a row that attends nothing comes out as zeros
+        assert not out[lengths == 0].any()

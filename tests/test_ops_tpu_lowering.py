@@ -48,17 +48,17 @@ def _kernel_case(cfg: ModelConfig, variant: str, *, ctx: int = 4096, B: int = 16
     W, L = ctx // BS, 2
     N = 2 * W
     quant = variant == "int8"
-    pages = S((L, N, BS, KVH * hd), jnp.int8 if quant else jnp.bfloat16)
+    pages = S((L, N, 2, BS, KVH * hd), jnp.int8 if quant else jnp.bfloat16)  # a page: K then V
     scales = S((L, N, BS, KVH), jnp.float32) if quant else None
     layer, tables = S((), jnp.int32), S((B, W), jnp.int32)
     if variant in ("decode", "int8"):
         q, lengths = S((B, KVH, G, hd), jnp.bfloat16), S((B,), jnp.int32)
-        return paged_decode_attention, (q, pages, pages, layer, tables, lengths,
+        return paged_decode_attention, (q, pages, layer, tables, lengths,
                                         scales, scales)
     T = 4
     q, lengths = S((B, T, KVH, G, hd), jnp.bfloat16), S((B, T), jnp.int32)
     anc = S((B, T, T), jnp.int8) if variant == "tree" else None
-    return paged_spec_attention, (q, pages, pages, layer, tables, lengths,
+    return paged_spec_attention, (q, pages, layer, tables, lengths,
                                   None, None, anc)
 
 
@@ -106,6 +106,82 @@ def test_decode_kernel_compiles_at_the_cells_call_shapes(v5e, cell):
     preset, B, W = CELL_CALLS[cell]
     fn, args = _kernel_case(ModelConfig.preset(preset), "decode", ctx=W * BS, B=B, sharding=v5e)
     fn.lower(*args).compile()
+
+
+# The geometries the benchmark serves, with their block sizes (PR 46: a page is
+# a block's K then its V, ``[2, bs, KVH*hd]``, one DMA descriptor): the page's
+# bytes go 32 KB (Qwen) to 64 KB, its rows 512 to 1,024 lanes.
+SERVED_GEOMETRIES = {
+    # name: (KVH, G, hd, bs, rows of a decode call, table width)
+    "qwen_G7_KVH4_bs16": (4, 7, 128, 16, 64, 256),
+    "mistral_G4_KVH8_bs16": (8, 4, 128, 16, 32, 256),
+    "lfm2_hd64_bs32": (8, 4, 64, 32, 128, 128),
+    "sala_KVH2_bs64": (2, 16, 128, 64, 32, 64),
+}
+
+
+@pytest.mark.parametrize("kernel", ["decode", "decode_int8", "prefill"])
+@pytest.mark.parametrize("geometry", list(SERVED_GEOMETRIES))
+def test_fused_page_kernels_compile_for_v5e(v5e, geometry, kernel):
+    """Mosaic takes the page ``[2, bs, KVH*hd]`` as one copy into its place in
+    the chunk buffer at every served geometry, bf16 and int8 (whose tile is
+    32 sublanes: a 16-token part is half of one), in the decode kernel's
+    unrolled starts and in the prefill kernel's loops."""
+    from dynamo_tpu.ops.paged_attention import paged_prefill_attention
+
+    KVH, G, hd, bs, B, W = SERVED_GEOMETRIES[geometry]
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    L, N = 2, 2 * W
+    quant = kernel == "decode_int8"
+    pages = S((L, N, 2, bs, KVH * hd), jnp.int8 if quant else jnp.bfloat16)
+    scales = S((L, N, bs, KVH), jnp.float32) if quant else None
+    if kernel == "prefill":
+        T = 256
+        lowered = jax.jit(paged_prefill_attention).lower(
+            S((1, T, KVH, G, hd), jnp.bfloat16), pages, S((), jnp.int32), S((1, W), jnp.int32),
+            S((1,), jnp.int32), S((1,), jnp.int32))
+    else:
+        lowered = paged_decode_attention.lower(
+            S((B, KVH, G, hd), jnp.bfloat16), pages, S((), jnp.int32), S((B, W), jnp.int32),
+            S((B,), jnp.int32), scales, scales)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+_IN_PLACE = ("scatter", "dynamic-update-slice", "fusion", "custom-call", "while", "conditional", "call")
+_NO_RESULT = ("parameter", "get-tuple-element", "bitcast", "tuple")
+
+
+def _pool_copies(compiled, cache: M.KVCache) -> list[str]:
+    """Instructions of a compiled program whose result has the shape of one of
+    ``cache``'s pools, of one layer of it, or of the K or V part of either,
+    other than what changes a pool in place (the scatters and the fusions
+    that wrap them, the kernels that alias it through, the loop that carries
+    it): a temporary the size of a pool, as the token scatter of
+    ``[2, KVH*hd]`` windows first made (a copy of the whole pool into a
+    token-major layout at every decode call; PERF.md section 6, PR 46)."""
+    def shapes(full):
+        lay = full[1:]
+        return {full, lay, (1, *lay), (full[0] * full[1], *full[2:])}
+
+    big = set()
+    for name, pool in cache._asdict().items():
+        if pool is None:
+            continue
+        big |= shapes(tuple(pool.shape))
+        if name == "kv" and pool.ndim == 5:
+            big |= shapes(tuple(pool.shape[:2] + pool.shape[3:]))
+    big = {"[" + ",".join(map(str, shape)) + "]" for shape in big}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = \S*?(\[[\d,]*\])\S* ([\w-]+)\(", line)
+        if m and m.group(2) not in _NO_RESULT + _IN_PLACE and m.group(1) in big:
+            found.append(line.strip()[:140])
+    return found
+
+
+def _a_layer_of_the_pages(cache: M.KVCache) -> int:
+    """Bytes of one layer of the K and V pages."""
+    return cache.kv.size // cache.kv.shape[0] * cache.kv.dtype.itemsize
 
 
 def _eqns(jaxpr, primitive: str) -> list:
@@ -207,11 +283,11 @@ def test_multi_decode_window_compiles_for_v5e(v5e):
     S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
     params = _int8_params(cfg, S)
     B, K, W, N = 16, 8, 4096 // BS, 4096
-    pages = S((cfg.num_layers, N, BS, cfg.kv_size), jnp.bfloat16)
+    cache = M.KVCache(S((cfg.num_layers, N, 2, BS, cfg.kv_size), jnp.bfloat16))
     i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
     flags = S((B,), jnp.bool_)
     compiled = M.multi_decode.lower(
-        cfg, K, "greedy", 0, params, M.KVCache(pages, pages),
+        cfg, K, "greedy", 0, params, cache,
         i32(B), i32(B), i32(B, W), flags,            # tokens, positions, tables, active
         f32(B), S((B,), jnp.uint32), i32(B),         # temperature, seeds, steps0
         i32(B), f32(B), f32(B), f32(B), i32(B, 1),   # top_k, top_p, penalties
@@ -222,6 +298,12 @@ def test_multi_decode_window_compiles_for_v5e(v5e):
     # a 16 GB chip beside them.
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    # The pool changes in place: a token's K and V go in as rows of lanes
+    # (``write_kv_tokens``), and nothing the window adds is as large as one
+    # layer of the pages (3.7 MB against 134; the first form of the write
+    # added 4.4 GB, the whole pool laid out token-major).
+    assert mem.temp_size_in_bytes < _a_layer_of_the_pages(cache) / 4
+    assert not _pool_copies(compiled, cache)
 
 
 @pytest.mark.parametrize("kv_quant", ["none", "int8"])
@@ -248,9 +330,11 @@ def test_prefill_forms_no_layer_of_the_pool_on_v5e(v5e, kv_quant):
     ).compile().as_text()
 
     L, kv, KVH = cfg.num_layers, cfg.kv_size, cfg.num_kv_heads
-    layer = {f"[{N},{BS},{kv}]", f"[1,{N},{BS},{kv}]", f"[{N},{BS},{KVH}]", f"[1,{N},{BS},{KVH}]"}
-    pool = {f"[{L},{N},{BS},{kv}]", f"[{L * N},{BS},{kv}]", f"[{L},{N},{BS},{KVH}]",
-            f"[{L * N},{BS},{KVH}]"}
+    # (a page is K then V since PR 46: the pool's layer, and its K or V part)
+    layer = {f"[{N},2,{BS},{kv}]", f"[1,{N},2,{BS},{kv}]", f"[{N},{BS},{kv}]", f"[1,{N},{BS},{kv}]",
+             f"[{N},{BS},{KVH}]", f"[1,{N},{BS},{KVH}]"}
+    pool = {f"[{L},{N},2,{BS},{kv}]", f"[{L * N},2,{BS},{kv}]", f"[{L},{N},{BS},{kv}]",
+            f"[{L * N},{BS},{kv}]", f"[{L},{N},{BS},{KVH}]", f"[{L * N},{BS},{KVH}]"}
     found, entry = [], False
     for line in hlo.splitlines():
         if re.match(r"(ENTRY )?%?\S+ \(.*\) -> .* \{$", line):  # a computation's header
@@ -273,9 +357,9 @@ def _prefill_kernel_case(cfg: ModelConfig, T: int, *, Bp: int = 1, W: int = 256,
 
     S = functools.partial(jax.ShapeDtypeStruct, sharding=sharding)
     KVH, hd = cfg.num_kv_heads, cfg.head_dim
-    pages = S((2, 512, BS, KVH * hd), jnp.bfloat16)
+    pages = S((2, 512, 2, BS, KVH * hd), jnp.bfloat16)
     return jax.jit(paged_prefill_attention), (
-        S((Bp, T, KVH, cfg.num_heads // KVH, hd), jnp.bfloat16), pages, pages,
+        S((Bp, T, KVH, cfg.num_heads // KVH, hd), jnp.bfloat16), pages,
         S((), jnp.int32), S((Bp, W), jnp.int32), S((Bp,), jnp.int32), S((Bp,), jnp.int32))
 
 
@@ -314,11 +398,14 @@ def test_prefill_kernel_walks_tiles_not_the_table():
         assert tuple(lhs_contract) == (eqn.invars[0].aval.ndim - 1,), eqn
 
 
-@pytest.mark.parametrize("T", [256, 2048])
-def test_prefill_attends_out_of_the_pages_on_v5e(v5e, T):
+@pytest.mark.parametrize("program,Bp,T", [("prefill_batch", 1, 256), ("prefill_batch", 1, 2048),
+                                          ("prefill", 1, 2048), ("prefill_batch", 4, 128)],
+                         ids=["256", "2048", "prefill-2048", "packed-4x128"])
+def test_prefill_attends_out_of_the_pages_on_v5e(v5e, program, Bp, T):
     """The prefill program at the sessions cell's widths (qwen2-7b int8,
     5,120 blocks, a 256-page table), one row of a 256-token turn and of a
-    2,048-token chunk: the kernel is in it, nothing in it has the table's
+    2,048-token chunk, packed and through the single-row program a long
+    prompt's chunks take, and a pack of four 128-token rows: the kernel is in it, nothing in it has the table's
     ``W*bs`` rows (the two gathers) or ``W*bs + T`` columns (the float32
     scores and their softmax), and what the program adds to its arguments
     stays under 100 MB (the XLA form: 77 MB and 2.1 GB of temporaries,
@@ -329,11 +416,18 @@ def test_prefill_attends_out_of_the_pages_on_v5e(v5e, T):
     cache = jax.tree.map(
         lambda a: S(a.shape, a.dtype), jax.eval_shape(lambda: M.init_kv_cache(cfg, N, BS)))
     i32 = lambda *s: S(s, jnp.int32)  # noqa: E731
-    compiled = M.prefill_batch.lower(
-        cfg, _int8_params(cfg, S), cache, i32(1, T), i32(1, W), i32(1), i32(1),
-        attn_impl="pallas",
-    ).compile()
+    if program == "prefill":
+        compiled = M.prefill.lower(
+            cfg, _int8_params(cfg, S), cache, i32(T), i32(W), i32(), i32(), attn_impl="pallas",
+        ).compile()
+    else:
+        compiled = M.prefill_batch.lower(
+            cfg, _int8_params(cfg, S), cache, i32(Bp, T), i32(Bp, W), i32(Bp), i32(Bp),
+            attn_impl="pallas",
+        ).compile()
     hlo = compiled.as_text()
+    # Whole pages, K and V, in one scatter a layer (``write_kv_pages``), in place.
+    assert not _pool_copies(compiled, cache)
     assert "paged_prefill_attention" in hlo
     assert "paged_decode_attention" not in hlo
     wide = [ln.strip()[:120] for ln in hlo.splitlines()
@@ -568,21 +662,22 @@ def test_paged_kernels_compile_for_v5e_at_head_size_64(v5e, kernel):
     assert kernel_unsupported(cfg, LBS) is None and (cfg.kv_size, cfg.num_heads // cfg.num_kv_heads) == (512, 4)
     S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
     KVH, G, hd, W = 8, 4, 64, 4096 // LBS
-    pages, layer = S((2, 2048, LBS, KVH * hd), jnp.bfloat16), S((), jnp.int32)
+    pages, layer = S((2, 2048, 2, LBS, KVH * hd), jnp.bfloat16), S((), jnp.int32)
     if kernel == "decode":
         B = 128
-        paged_decode_attention.lower(S((B, KVH, G, hd), jnp.bfloat16), pages, pages, layer,
+        paged_decode_attention.lower(S((B, KVH, G, hd), jnp.bfloat16), pages, layer,
                                      S((B, W), jnp.int32), S((B,), jnp.int32)).compile()
         return
     from dynamo_tpu.ops.paged_attention import paged_prefill_attention
 
     T = int(kernel.rsplit("_", 1)[1])
     jax.jit(paged_prefill_attention).lower(
-        S((1, T, KVH, G, hd), jnp.bfloat16), pages, pages, layer, S((1, W), jnp.int32),
+        S((1, T, KVH, G, hd), jnp.bfloat16), pages, layer, S((1, W), jnp.int32),
         S((1,), jnp.int32), S((1,), jnp.int32)).compile()
 
 
-@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk_2048", "prefill_packed_256"])
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk_2048", "prefill_packed_256",
+                                     "prefill_single_2048"])
 def test_lfm2_programs_copy_no_pool_and_no_expert_stack_on_v5e(v5e, program):
     """The jitted programs the LFM2 cell runs, at the published widths and the
     cell's pool (four layers: a dense conv layer and three expert layers, one
@@ -608,17 +703,25 @@ def test_lfm2_programs_copy_no_pool_and_no_expert_stack_on_v5e(v5e, program):
             i32(B), f32(B), f32(B), f32(B), i32(B, 1), flags, i32(B), i32(B + 1),
             None, None, attn_impl="pallas", experts="gmm",
         ).compile()
+    elif program == "prefill_single_2048":  # the program a long prompt's chunks take
+        compiled = lfm2.prefill.lower(
+            cfg, params, cache, i32(2048), i32(W), i32(), i32(), attn_impl="pallas", experts="gmm"
+        ).compile()
     else:
         T = int(program.rsplit("_", 1)[1])
         compiled = lfm2.prefill_batch.lower(
             cfg, params, cache, i32(1, T), i32(1, W), i32(1), i32(1), attn_impl="pallas", experts="gmm"
         ).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+    # Nothing a program adds is as large as the attention layer's pages (369 MB;
+    # the decode window adds 12 MB, the 2,048-token chunk 125).
+    assert compiled.memory_analysis().temp_size_in_bytes < _a_layer_of_the_pages(cache) / 2
+    assert not _pool_copies(compiled, cache)
     hlo = compiled.as_text()
     assert hlo.count("paged_prefill_attention" if program != "decode_window" else "tpu_custom_call") >= 1
     D, E, ie, kv = cfg.hidden_size, cfg.num_experts, cfg.moe_intermediate_size, cfg.kv_size
     La, Lc, K = len(cfg.attn_layers), len(cfg.conv_layers), cfg.conv_state_slots
-    big = {f"[{La},{N},{LBS},{kv}]", f"[{N},{LBS},{kv}]", f"[1,{N},{LBS},{kv}]",          # K or V, a layer
+    big = {f"[{La},{N},2,{LBS},{kv}]", f"[{N},2,{LBS},{kv}]", f"[1,{N},2,{LBS},{kv}]",    # the pages, a layer
+           f"[{La},{N},{LBS},{kv}]", f"[{N},{LBS},{kv}]", f"[1,{N},{LBS},{kv}]",          # their K or V part
            f"[{Lc},{K},{N},{D}]", f"[{K},{N},{D}]", f"[{N},{D}]", f"[1,1,{N},{D}]",        # conv state, a layer, a slot
            f"[{E},{D},{ie}]", f"[{E},{ie},{D}]"}                                          # a layer's expert stack
     found = []
@@ -668,6 +771,7 @@ def test_a_packed_wave_is_one_grouped_product_a_layer_and_no_larger_than_a_singl
     assignment_rows = rows * t * min(cfg.num_experts_per_token, cfg.num_experts)
     assert products == [str(assignment_rows)] * (3 * expert_layers), products
     assert pack.memory_analysis().temp_size_in_bytes <= single.memory_analysis().temp_size_in_bytes
+    assert not _pool_copies(pack, cache)  # a pack's pages go in place as a single row's do
 
 
 def _sala(mixers: tuple[str, ...]) -> ModelConfig:
@@ -679,13 +783,17 @@ def _sala(mixers: tuple[str, ...]) -> ModelConfig:
         scale_emb=12.0, scale_depth=1.4, dim_model_base=256, max_position=24576)
 
 
-@pytest.mark.parametrize("program", ["step_kernel", "decode_window", "prefill_chunk_2048"])
+@pytest.mark.parametrize("program", ["step_kernel", "decode_window", "prefill_chunk_2048",
+                                     "prefill_packed_2x256", "prefill_packed_4x128"])
 def test_sala_programs_compile_for_v5e(v5e, program):
     """What the MiniCPM-SALA cell runs, at the published widths, pages of 64
     tokens, the cell's pools (4,096 blocks, 41 state slots) and its 24,576-token
     table, over a sparse layer, two lightning layers and a sparse layer: the
     lightning step kernel alone (16 rows), the decode window of 16 rows and a
-    2,048-token prefill chunk. The pools change in place: a decode window's
+    2,048-token prefill chunk, and packs of two and four rows (PR 46: with more
+    than one row the read of K's tail behind each chunk, as a window over part
+    of the page's token axis, copied the whole pool token-major; a pack adds
+    210 and 478 MB, the parent's 277 and 478). The pools change in place: a decode window's
     temporaries stay far under one pool's size, and a chunk's under the room
     the configuration's memory arithmetic leaves (its float32 scores are a
     tile of 64 queries over the table's width)."""
@@ -713,12 +821,21 @@ def test_sala_programs_compile_for_v5e(v5e, program):
             i32(B), f32(B), f32(B), f32(B), i32(B, 1), flags, i32(B), i32(B + 1),
             None, None, attn_impl="pallas", state_slots=i32(B, 3),
         ).compile()
-        assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+        assert compiled.memory_analysis().temp_size_in_bytes < _a_layer_of_the_pages(cache) / 4  # 3.7 MB of 268
+        assert not _pool_copies(compiled, cache)
         hlo = compiled.as_text()
         assert "lightning_decode" in hlo and "paged_decode_attention" in hlo or hlo.count("tpu_custom_call") >= 2
+    elif program.startswith("prefill_packed"):
+        rows, t = map(int, program.rsplit("_", 1)[1].split("x"))
+        compiled = sala.prefill_batch.lower(
+            cfg, params, cache, i32(rows, t), i32(rows, W), i32(rows), i32(rows),
+            attn_impl="pallas", state_slots=i32(rows, 6)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+        assert not _pool_copies(compiled, cache)
     else:
         compiled = sala.prefill.lower(
             cfg, params, cache, i32(2048), i32(W), i32(), i32(), None, None,
             attn_impl="pallas", state_slots=i32(6)).compile()
         assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+        assert not _pool_copies(compiled, cache)  # 239 MB of scores and chunked scans, no pool among them
         assert "paged_prefill_attention" in compiled.as_text()  # the dense branch, under dense_len

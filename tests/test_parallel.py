@@ -66,7 +66,7 @@ def test_tp_beyond_kv_heads_matches_single_device():
     ref = run(params, M.init_kv_cache(CFG, 16, bs, jnp.float32))
     mesh = build_mesh(tp=4, cfg=CFG)
     sh = ModelSharding(mesh, CFG)
-    got = run(sh.shard_params(params), M.init_kv_cache(CFG, 16, bs, jnp.float32, sharding=sh.cache_sharding()))
+    got = run(sh.shard_params(params), M.init_kv_cache(CFG, 16, bs, jnp.float32, sharding=sh.cache_sharding))
     np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-4)
 
 
@@ -100,7 +100,7 @@ def test_tp_sharded_prefill_and_decode_match_single_device():
     mesh = build_mesh(tp=2, dp=1)
     sh = ModelSharding(mesh, CFG)
     sharded_params = sh.shard_params(params)
-    cache = M.init_kv_cache(CFG, 16, bs, jnp.float32, sharding=sh.cache_sharding())
+    cache = M.init_kv_cache(CFG, 16, bs, jnp.float32, sharding=sh.cache_sharding)
     got_p, got_d = run(sharded_params, cache)
 
     np.testing.assert_allclose(got_p, ref_p, rtol=2e-4, atol=2e-4)
@@ -139,7 +139,7 @@ mesh = build_mesh(tp=16, cfg=cfg)
 assert mesh.shape == {"dp": 1, "ep": 1, "tp_kv": 8, "tp_rep": 2}, mesh.shape
 sh = ModelSharding(mesh, cfg)
 params = sh.shard_params(M.init_params(cfg, jax.random.PRNGKey(0), jnp.float32))
-cache = M.init_kv_cache(cfg, 16, 4, jnp.float32, sharding=sh.cache_sharding())
+cache = M.init_kv_cache(cfg, 16, 4, jnp.float32, sharding=sh.cache_sharding)
 toks = np.zeros((8,), np.int32); toks[:6] = [3,4,5,6,7,8]
 table = np.zeros((4,), np.int32); table[:2] = [1,2]
 logits, cache = M.prefill(cfg, params, cache, jnp.asarray(toks), jnp.asarray(table),
@@ -155,6 +155,27 @@ print("TP16_OK")
         timeout=240,
     )
     assert "TP16_OK" in out.stdout, out.stdout + out.stderr
+
+
+def test_driver_entry_dryrun_multichip():
+    """``__graft_entry__.dryrun_multichip``: the dp x tp and ep x tp meshes,
+    cache born sharded, one prefill + decode + sample, on 8 virtual CPU
+    devices (subprocess: it pins its whole process to the CPU platform).
+    No other test imports that file, so a change to the cache's
+    constructor would break the entry point unseen."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", "import __graft_entry__ as g; g.dryrun_multichip(8)"],
+        capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))), env=env,
+        timeout=240,
+    )
+    assert "dryrun_multichip ok" in out.stdout, out.stdout + out.stderr
+    assert "MoE decode ran" in out.stdout, out.stdout
 
 
 def test_sharded_engine_matches_unsharded_greedy():

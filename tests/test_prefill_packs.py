@@ -101,6 +101,7 @@ def test_the_chip_is_looked_up_by_kind_and_an_unknown_one_is_a_v5e_and_says_so()
     runner = LocalRunner(args)
     runner.start()
     try:
+        assert f" kv_page_bytes={2 * 8 * cfg.kv_size * 4} attention: " in runner._start_line("")
         assert runner._start_line("").endswith(
             f" prefill_pack<={runner.pack_limit_tokens} tok"
             " (at the v5e's operations a byte: this device_kind has no entry)")

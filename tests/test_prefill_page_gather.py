@@ -1,7 +1,7 @@
 """Prefix pages are read straight out of the stacked KV pool (PR 28).
 
-``gather_dequant_pages`` takes the stacked cache ``[L, N, bs, KVH*hd]`` and
-gathers by (layer, page) at once. The parent sliced one layer out first
+``gather_kv_pages`` takes the stacked cache ``[L, N, 2, bs, KVH*hd]`` (a page
+is K then V, since PR 46) and gathers by (layer, page) at once, each page once. The parent sliced one layer out first
 (``lax.dynamic_index_in_dim``), which on the chip copied all N pages of the
 layer, 84 MB at 5,120 blocks, twice a layer in every prefill call, to read a
 few hundred of them. Two things are pinned here:
@@ -32,17 +32,22 @@ KVH, HD = CFG.num_kv_heads, CFG.head_dim
 KV_KINDS = ("none", "int8")
 
 
-def gather_as_the_parent_did(cache, scale, layer_idx, block_tables, KVH, hd, dtype):
+def gather_as_the_parent_did(kv_cache, k_scale, v_scale, layer_idx, block_tables, KVH, hd, dtype):
     """The plain reference: one layer of the pool sliced out, then its pages
-    picked (``ops/paged_attention.py`` and ``engine/model.py`` at c34b524)."""
+    picked, K's and V's apart (``ops/paged_attention.py`` and
+    ``engine/model.py`` at c34b524, over the two parts of today's page)."""
     B, W = block_tables.shape
-    layer_cache = lax.dynamic_index_in_dim(cache, layer_idx, 0, keepdims=False)
-    pages = layer_cache[block_tables].reshape(B, W * BS, KVH, hd)
-    if scale is None:
-        return pages
-    layer_scale = lax.dynamic_index_in_dim(scale, layer_idx, 0, keepdims=False)
-    sc = layer_scale[block_tables].reshape(B, W * BS, KVH)
-    return (pages.astype(jnp.float32) * sc[..., None]).astype(dtype)
+    layer_cache = lax.dynamic_index_in_dim(kv_cache, layer_idx, 0, keepdims=False)
+
+    def part(i, scale):
+        pages = layer_cache[:, i][block_tables].reshape(B, W * BS, KVH, hd)
+        if scale is None:
+            return pages
+        layer_scale = lax.dynamic_index_in_dim(scale, layer_idx, 0, keepdims=False)
+        sc = layer_scale[block_tables].reshape(B, W * BS, KVH)
+        return (pages.astype(jnp.float32) * sc[..., None]).astype(dtype)
+
+    return part(0, k_scale), part(1, v_scale)
 
 
 @pytest.fixture(scope="module")
@@ -78,13 +83,13 @@ def filled_cache(kv_quant: str, seed: int = 0) -> M.KVCache:
     if kv_quant == "int8":
         sshape = (CFG.num_layers, N, BS, KVH)
         return M.KVCache(
-            jax.random.randint(k1, shape, -127, 128, jnp.int8),
-            jax.random.randint(k2, shape, -127, 128, jnp.int8),
+            M.fuse_kv(jax.random.randint(k1, shape, -127, 128, jnp.int8),
+                      jax.random.randint(k2, shape, -127, 128, jnp.int8)),
             jax.random.uniform(k3, sshape, jnp.float32, 0.001, 0.02),
             jax.random.uniform(k4, sshape, jnp.float32, 0.001, 0.02),
         )
-    return M.KVCache(jax.random.normal(k1, shape, jnp.bfloat16),
-                     jax.random.normal(k2, shape, jnp.bfloat16))
+    return M.KVCache(M.fuse_kv(jax.random.normal(k1, shape, jnp.bfloat16),
+                               jax.random.normal(k2, shape, jnp.bfloat16)))
 
 
 # -- prefill over a cached prefix ---------------------------------------------
@@ -118,7 +123,7 @@ def run_prefill(params, kv_quant: str, rows: int, seed: int = 0):
 @pytest.mark.parametrize("kv_quant", KV_KINDS)
 def test_prefill_over_cached_prefix_is_bit_identical(params, kv_quant, rows, monkeypatch):
     logits, cache = run_prefill(params, kv_quant, rows)
-    monkeypatch.setattr(PA, "gather_dequant_pages", gather_as_the_parent_did)
+    monkeypatch.setattr(PA, "gather_kv_pages", gather_as_the_parent_did)
     want_logits, want_cache = run_prefill(params, kv_quant, rows)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
     assert_same_bits(logits, want_logits)
@@ -140,7 +145,7 @@ def attention_case(kind: str, kv_quant: str):
     if kind == "decode":
         q = jax.random.normal(kq, (B, KVH, G, HD), jnp.bfloat16)
         lengths = jnp.asarray([5, 24, 13], jnp.int32)
-        return PA.paged_decode_attention_xla, (q, cache.k, cache.v, layer, tables, lengths,
+        return PA.paged_decode_attention_xla, (q, cache.kv, layer, tables, lengths,
                                                cache.k_scale, cache.v_scale)
     q = jax.random.normal(kq, (B, T, KVH, G, HD), jnp.bfloat16)
     if kind == "spec":
@@ -149,7 +154,7 @@ def attention_case(kind: str, kv_quant: str):
     else:  # tree: node 0 the root, nodes 1 and 2 its children
         lengths = jnp.asarray([[5] * T, [20] * T, [11] * T], jnp.int32)
         anc = jnp.broadcast_to(jnp.asarray([[1, 0, 0], [1, 1, 0], [1, 0, 1]], jnp.int8), (B, T, T))
-    return PA.paged_spec_attention_xla, (q, cache.k, cache.v, layer, tables, lengths,
+    return PA.paged_spec_attention_xla, (q, cache.kv, layer, tables, lengths,
                                          cache.k_scale, cache.v_scale, anc)
 
 
@@ -158,7 +163,7 @@ def attention_case(kind: str, kv_quant: str):
 def test_xla_attention_is_bit_identical(kind, kv_quant, monkeypatch):
     fn, args = attention_case(kind, kv_quant)
     got = jax.jit(fresh(fn))(*args)
-    monkeypatch.setattr(PA, "gather_dequant_pages", gather_as_the_parent_did)
+    monkeypatch.setattr(PA, "gather_kv_pages", gather_as_the_parent_did)
     want = jax.jit(fresh(fn))(*args)
     assert np.isfinite(np.asarray(got, np.float32)).all()
     assert_same_bits(got, want)
@@ -180,7 +185,7 @@ def output_shapes(jaxpr) -> set[tuple[int, ...]]:
 
 def layer_shapes(kv_quant: str) -> set[tuple[int, ...]]:
     """One layer of the pool, of its scales, and both before the squeeze."""
-    out = {(N, BS, CFG.kv_size), (1, N, BS, CFG.kv_size)}
+    out = {(N, 2, BS, CFG.kv_size), (1, N, 2, BS, CFG.kv_size), (N, BS, CFG.kv_size)}
     if kv_quant == "int8":
         out |= {(N, BS, KVH), (1, N, BS, KVH)}
     return out
@@ -202,12 +207,12 @@ def traced_shapes(program: str, kv_quant: str, params) -> set[tuple[int, ...]]:
 def test_no_equation_outputs_a_layer_of_the_pool(program, kv_quant, params):
     shapes = traced_shapes(program, kv_quant, params)
     if program == "prefill_batch_impl":  # the walk reached the scan body's scatter into the pool
-        assert (CFG.num_layers, N, BS, CFG.kv_size) in shapes
+        assert (CFG.num_layers, N, 2, BS, CFG.kv_size) in shapes
     assert not shapes & layer_shapes(kv_quant)
 
 
 @pytest.mark.parametrize("program", ["prefill_batch_impl", "decode"])
 def test_the_pin_sees_the_parents_slice(program, params, monkeypatch):
     """The walk has teeth: with the parent's way of reading, it finds the layer."""
-    monkeypatch.setattr(PA, "gather_dequant_pages", gather_as_the_parent_did)
-    assert traced_shapes(program, "int8", params) >= {(N, BS, CFG.kv_size), (N, BS, KVH)}
+    monkeypatch.setattr(PA, "gather_kv_pages", gather_as_the_parent_did)
+    assert traced_shapes(program, "int8", params) >= {(N, 2, BS, CFG.kv_size), (N, BS, KVH)}

@@ -125,7 +125,7 @@ def test_a_chunked_prefill_equals_a_single_one(params, ref_params):
     _, two = prefill(params, fresh_cache(), toks, 0, 40, (0, 1, 5))
     lg2, two = prefill(params, two, toks, 40, 61, (5, 2, 0))
     np.testing.assert_allclose(np.asarray(lg2), np.asarray(lg1), **TOL)
-    for name in ("k", "v", "ckeys"):
+    for name in ("kv", "ckeys"):
         a, b = np.asarray(getattr(one, name))[:, 1:9], np.asarray(getattr(two, name))[:, 1:9]
         np.testing.assert_allclose(b.reshape(2, -1, CFG.kv_size)[:, :61 if name != "ckeys" else 30],
                                    a.reshape(2, -1, CFG.kv_size)[:, :61 if name != "ckeys" else 30], **TOL)
@@ -592,9 +592,9 @@ def test_the_worker_says_what_it_runs_and_counts_states_and_choices():
 def test_pool_accounting_counts_every_pool():
     args = engine_args(dtype="bfloat16")
     cache = sala.init_kv_cache(CFG, args.num_kv_blocks, BS, state_slots=args.state_slots)
-    assert cache.k.shape == cache.v.shape == (2, 64, BS, CFG.kv_size) and cache.ckeys.shape == (2, 64, 4, CFG.kv_size)
-    assert cache.state.shape == (4, 11, 4, 32, 32) and cache.state.dtype == cache.k.dtype == jnp.bfloat16
-    assert cache.k.nbytes + cache.v.nbytes + cache.ckeys.nbytes == args.num_kv_blocks * args.kv_bytes_per_block()
+    assert cache.kv.shape == (2, 64, 2, BS, CFG.kv_size) and cache.block_size == BS and cache.ckeys.shape == (2, 64, 4, CFG.kv_size)
+    assert cache.state.shape == (4, 11, 4, 32, 32) and cache.state.dtype == cache.kv.dtype == jnp.bfloat16
+    assert cache.kv.nbytes + cache.ckeys.nbytes == args.num_kv_blocks * args.kv_bytes_per_block()
     assert cache.state.nbytes == args.state_pool_bytes()
     # The state pool is sized from bytes as the pages are: what num_kv_blocks pages take, never under the running pairs.
     big = engine_args(dtype="bfloat16", num_kv_blocks=1024)

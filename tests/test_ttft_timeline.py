@@ -507,6 +507,9 @@ def test_conv_state_and_pool_series_are_pinned_names(fresh_recorder):
     assert counter_of(pages["lfm2-tiny"], "kv_pool_bytes", 'kind="kv"') > 0
     assert counter_of(pages["test-tiny"], "kv_pool_bytes", 'kind="kv"') > 0
     assert 'kind="conv"' not in pages["test-tiny"] and "engine_conv_state_resumes_total{" not in pages["test-tiny"]
+    # kv_page_bytes: what one page descriptor moves, K and V of a block of 8 float32 positions.
+    for preset, page in pages.items():
+        assert f"dynamo_tpu_kv_page_bytes {2 * 8 * ModelConfig.preset(preset).kv_size * 4}" in page
 
 
 def test_prefill_dispatch_rows_series_are_pinned_names(fresh_recorder):
