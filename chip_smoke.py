@@ -102,6 +102,11 @@ KERNEL_CALLS = {
                                rows=[(32, 4033, 4096)]),
     "longcat latent sessions": dict(B=128, W=128, bs=32, Dk=640, H=64, Dv=512, L=8, N=5632,
                                     rows=[(63, 1000, 2600)]),
+    # the dots3 full layers' choice: the indexer's scan over a row's index keys, and the
+    # latent kernel over the 2,048 rows it chose (gathered ahead of it, outside these times)
+    "dots3 index scan": dict(B=32, W=1024, bs=32, di=128, Hi=64, L=3, N=18432, rows=[(18, 16384, 32000)]),
+    "dots3 chosen rows": dict(B=32, W=1024, bs=32, Dk=640, H=128, Dv=512, topk=2048, L=3, N=18432,
+                              rows=[(18, 16384, 32000)]),
 }
 
 # The prefill kernel's call shapes in the dense cells (one row a call: T new
@@ -726,6 +731,7 @@ def kernel_times() -> dict:
     import jax
     import jax.numpy as jnp
 
+    from dynamo_tpu.ops import dsa
     from dynamo_tpu.ops.paged_attention import latent_decode_attention, paged_decode_attention
 
     out = {}
@@ -746,7 +752,26 @@ def kernel_times() -> dict:
             at += n
         tables, lengths = jnp.asarray(tables), jnp.asarray(lens)
         kq, kk = jax.random.split(jax.random.PRNGKey(1))
-        if "Dk" in c:
+        if "di" in c:
+            pool = jax.random.normal(kk, (L, N, bs, c["di"]), jnp.bfloat16)
+            q = jax.random.normal(kq, (B, c["Hi"], c["di"]), jnp.bfloat16)
+            kernel = "dsa_index_scores"
+            need = float(lens.sum()) * c["di"] * 2
+
+            def call(i, q, pool):
+                return dsa.index_scores(q, jnp.ones((B, c["Hi"]), jnp.float32), pool, i, tables, lengths)
+        elif "topk" in c:
+            pool = jax.random.normal(kk, (L, N, bs, c["Dk"]), jnp.bfloat16)
+            q = jax.random.normal(kq, (B, c["H"], c["Dk"]), jnp.bfloat16)
+            kernel = "latent_sparse_decode_attention"
+            need = float((lens > 0).sum()) * c["topk"] * 576 * 2  # the live values of a chosen row
+            picked = jnp.asarray(np.stack([rng.permutation(max(int(n), c["topk"]))[:c["topk"]] for n in lens]), jnp.int32)
+            counts = jnp.where(lengths > 0, c["topk"], 0)
+
+            def call(i, q, pool):
+                return dsa.sparse_decode_attention(q, pool, i, tables, picked, counts,
+                                                   value_dim=c["Dv"], scale=192 ** -0.5)
+        elif "Dk" in c:
             pool = jax.random.normal(kk, (L, N, bs, c["Dk"]), jnp.bfloat16)
             q = jax.random.normal(kq, (B, c["H"], c["Dk"]), jnp.bfloat16)
             kernel = "latent_decode_attention"

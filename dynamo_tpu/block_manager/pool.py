@@ -47,6 +47,29 @@ sink of padded rows) under one policy, which has no knob:
   resumes from is pinned until the wave's prefills are dispatched
   (``unpin_states``): the device's stream is serial, so what is dispatched
   later cannot overwrite what an earlier dispatch still has to read.
+
+**A second lifetime: window blocks** (a ``block="dots3"`` model, engine/dots3.py).
+A window layer keeps a sequence's last ``sliding_window`` positions, so its
+pages cannot live as long as the sequence does: they are blocks of a pool of
+their own, a second ``BlockPool`` with no event sink (the KV events and the
+router's view are of the full-layer chain alone), under the same policy:
+
+- A running sequence holds the window blocks of its last positions (and, in a
+  prefill, of the chunk in flight); the engine gives a block back
+  (``free_sequence``) when a dispatch has moved the window past it. A block that
+  was sealed and registered here under its chain's hash stays as a cached block
+  of this pool's own LRU, any other is free at once. Either way the sequence no
+  longer holds it and no later program of its reads it.
+- What a sequence wrote and passed goes to the LRU's cold end
+  (``free_sequence(cold=True)``): it is the first to be evicted. What may be
+  resumed from goes to the warm end: the blocks a sequence holds when it
+  finishes or is preempted, those before the end of a shared prompt, and those
+  an admission claimed as a hit.
+- A prefix hit is only as deep as the deepest block of the chain that has its
+  full-layer pages **and** whose ``back`` preceding blocks are all cached here
+  (``window_depth``, beside ``snapshot_depth``: one rule, two kinds of second
+  state); the admission claims those (``claim``), prefill resumes there and
+  recomputes the rest through every layer.
 """
 
 from __future__ import annotations
@@ -114,6 +137,10 @@ class BlockPool:
         self._state_pinned: set[int] = set()
         self.state_snapshots = {"chunk_end": 0, "decode_boundary": 0}
         self.state_evictions = 0
+        # Where the blocks sequences gave back went, and the registered blocks
+        # evicted for their page (what a window pool's counters read).
+        self.released = {"cached": 0, "free": 0}
+        self.evictions = 0
 
     # -- events -----------------------------------------------------------
 
@@ -225,6 +252,7 @@ class BlockPool:
     def _evict(self, bid: int) -> None:
         b = self._blocks[bid]
         if b.seq_hash is not None:
+            self.evictions += 1
             self._drop_snapshot(b.seq_hash)
             self._cached.pop(b.seq_hash, None)
             self._emit(KvCacheEvent.removed([b.seq_hash]))
@@ -247,17 +275,50 @@ class BlockPool:
         if b.ref_count == 1:
             self._lru.pop(bid, None)
 
-    def _unref(self, bid: int) -> None:
+    def _unref(self, bid: int, cold: bool = False) -> None:
         b = self._blocks[bid]
         b.ref_count -= 1
         if b.ref_count > 0:
             return
         if b.seq_hash is not None and self.enable_prefix_caching:
-            self._lru[bid] = None  # retained, evictable
-            self._lru.move_to_end(bid)
+            self._lru[bid] = None  # retained, evictable: the newest, or (cold) the first to go
+            self._lru.move_to_end(bid, last=not cold)
+            self.released["cached"] += 1
         else:
             b.seq_hash = None
             self._free.append(bid)
+            self.released["free"] += 1
+
+    # -- window blocks --------------------------------------------------------
+
+    def window_depth(self, seq_hashes: list[int], back: int) -> int:
+        """How deep into the chain ``seq_hashes`` (a full-layer hit) a prefix
+        hit may go in a model with window layers: the deepest block whose
+        ``back`` preceding blocks (all of them, where it has fewer) are cached
+        in THIS pool; 0: none, start from zero."""
+        with self._lock:
+            depth = run = 0
+            for i, h in enumerate(seq_hashes):
+                run = run + 1 if h in self._cached else 0
+                if run >= min(back, i + 1):
+                    depth = i + 1
+            return depth
+
+    def claim(self, seq_hashes: list[int]) -> list[int]:
+        """The cached blocks of ``seq_hashes`` (all cached: ``window_depth``
+        said so under the same lock-holder, the scheduler thread), each with
+        one more holder."""
+        with self._lock:
+            bids = [self._cached[h] for h in seq_hashes]
+            for bid in bids:
+                self._ref(bid)
+            return bids
+
+    @property
+    def num_cached(self) -> int:
+        """Registered blocks no sequence holds (evictable)."""
+        with self._lock:
+            return len(self._lru)
 
     # -- state slots --------------------------------------------------------
 
@@ -390,10 +451,12 @@ class BlockPool:
 
     # -- release ----------------------------------------------------------
 
-    def free_sequence(self, block_ids: list[int]) -> None:
+    def free_sequence(self, block_ids: list[int], cold: bool = False) -> None:
+        """Give blocks back. ``cold``: a registered one becomes the first its
+        LRU evicts, not the last (a window block its sequence wrote and passed)."""
         with self._lock:
             for bid in block_ids:
-                self._unref(bid)
+                self._unref(bid, cold)
 
     def snapshot(self) -> list[tuple[int, int | None]]:
         """All currently-registered (hash, parent_hash) pairs in original

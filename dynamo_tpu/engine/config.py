@@ -115,6 +115,26 @@ class ModelConfig:
     sparse_init_blocks: int = 1
     sparse_window_size: int = 2048
     sparse_dense_len: int = 8192
+    # -- block="dots3" (dots3-note, engine/dots3.py) ------------------------
+    # ``layer_types`` names each layer "full_attention" (MLA over every cached
+    # token, of which a learned indexer keeps ``index_topk`` a query: the
+    # DeepSeek-V3.2 indexer, ``index_n_heads`` heads of ``index_head_dim``
+    # against one key a token) or "sliding_attention" (MLA at the ``swa_*``
+    # sizes over the last ``sliding_window`` positions, the query's own among
+    # them). ``num_dense_layers`` leading layers carry a dense feed-forward,
+    # every later one ``num_shared_experts`` shared experts beside the routed.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    sliding_window: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 10000.0
+    num_shared_experts: int = 0
 
     @property
     def q_size(self) -> int:
@@ -136,7 +156,48 @@ class ModelConfig:
         return -(-self.latent_dim // 128) * 128
 
     @property
+    def swa(self) -> "ModelConfig":
+        """A ``block="dots3"`` model's window layers as a latent geometry of
+        their own: this configuration with the ``swa_*`` sizes in the places
+        the MLA pieces read (heads, ranks, head sizes, rope base)."""
+        return dataclasses.replace(
+            self, num_heads=self.swa_num_heads, q_lora_rank=self.swa_q_lora_rank,
+            kv_lora_rank=self.swa_kv_lora_rank, qk_nope_head_dim=self.swa_qk_nope_head_dim,
+            qk_rope_head_dim=self.swa_qk_rope_head_dim, v_head_dim=self.swa_v_head_dim,
+            rope_theta=self.swa_rope_theta)
+
+    @property
+    def full_layers(self) -> tuple[int, ...]:
+        """The layers of a ``block="dots3"`` model that keep every token."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "full_attention")
+
+    @property
+    def window_layers(self) -> tuple[int, ...]:
+        """Its layers that keep the last ``sliding_window`` positions."""
+        return tuple(i for i, t in enumerate(self.layer_types) if t == "sliding_attention")
+
+    def _dots3_params(self, experts_counted: int) -> int:
+        d, v, ie = self.hidden_size, self.vocab_size, self.moe_intermediate_size or self.intermediate_size
+
+        def mla(c: "ModelConfig") -> int:
+            h = c.num_heads
+            return (d * c.q_lora_rank + c.q_lora_rank * h * (c.qk_nope_head_dim + c.qk_rope_head_dim)
+                    + d * c.latent_dim + c.kv_lora_rank * h * (c.qk_nope_head_dim + c.v_head_dim)
+                    + h * c.v_head_dim * d + d * h + c.q_lora_rank + c.kv_lora_rank + 2 * d)
+
+        indexer = (self.q_lora_rank * self.index_n_heads * self.index_head_dim
+                   + d * self.index_head_dim + d * self.index_n_heads + 2 * self.index_head_dim)
+        n_moe = self.num_layers - self.num_dense_layers
+        ff = (self.num_dense_layers * 3 * d * self.intermediate_size
+              + n_moe * (d * self.router_width + self.router_width
+                         + (self.num_shared_experts + experts_counted) * 3 * d * ie))
+        return (2 * v * d + d + len(self.full_layers) * (mla(self) + indexer)
+                + len(self.window_layers) * mla(self.swa) + ff)
+
+    @property
     def cache_layers(self) -> int:
+        if self.block == "dots3":
+            return len(self.full_layers)
         if self.block == "sala":
             return len(self.sparse_layers)
         if self.block == "lfm2":
@@ -230,6 +291,8 @@ class ModelConfig:
         d, i, v = self.hidden_size, self.intermediate_size, self.vocab_size
         if self.block == "sala":
             return self._sala_params()
+        if self.block == "dots3":
+            return self._dots3_params(self.num_experts)
         if self.block == "lfm2":
             return self._lfm2_params(self.num_experts)
         if self.block == "longcat":
@@ -258,6 +321,9 @@ class ModelConfig:
         d, v = self.hidden_size, self.vocab_size
         if self.block == "lfm2":
             return self._lfm2_params(self.num_experts_per_token)
+        if self.block == "dots3":
+            return self._dots3_params(
+                self.num_experts_per_token * self.num_experts // max(self.router_width, 1))
         if self.block == "longcat":
             # Of a token's top-k, the share that lands on experts held here
             # (zero-compute and absent ones touch no weights).
@@ -365,6 +431,26 @@ class ModelConfig:
                 dim_model_base=32, sparse_kernel_size=4, sparse_kernel_stride=2,
                 sparse_block_size=8, sparse_topk=5, sparse_init_blocks=1,
                 sparse_window_size=8, sparse_dense_len=32,
+            ),
+            # dots3-note block at toy widths (CPU tests): a dense-FFN full layer,
+            # then [full, window, window, window] twice; the indexer keeps 12
+            # tokens a query, the window 7 positions of a second, wider latent;
+            # 4 of 8 experts held, 2 a token, one shared.
+            "dots3-tiny": ModelConfig(
+                name="dots3-tiny", block="dots3", vocab_size=512, published_vocab_size=512,
+                hidden_size=128, intermediate_size=256, num_layers=9, num_heads=4,
+                num_kv_heads=1, head_dim=48, rope_theta=10000.0, tie_embeddings=False,
+                q_lora_rank=64, kv_lora_rank=96, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                v_head_dim=32, mla_scale_q_lora=True, mla_scale_kv_lora=True,
+                layer_types=("full_attention",) + ("full_attention",) + ("sliding_attention",) * 3
+                + ("full_attention",) + ("sliding_attention",) * 3,
+                num_dense_layers=1, index_n_heads=4, index_head_dim=32, index_topk=12,
+                sliding_window=7, swa_num_heads=2, swa_q_lora_rank=64, swa_kv_lora_rank=112,
+                swa_qk_nope_head_dim=48, swa_qk_rope_head_dim=16, swa_v_head_dim=32,
+                swa_rope_theta=5000.0, num_experts=4, num_routed_experts=8, expert_offset=2,
+                num_experts_per_token=2, num_shared_experts=1, moe_intermediate_size=64,
+                routed_scaling_factor=1.0, router_scoring="sigmoid", use_expert_bias=True,
+                norm_topk_prob=True,
             ),
             # Llama-3-70B-class (BASELINE.md north-star target, multi-host)
             "llama-70b": ModelConfig(
@@ -744,6 +830,34 @@ class EngineArgs:
                     f"model {m.name!r} has block='sala' (a matrix state a lightning layer beside "
                     f"the sparse layers' pages), which cannot run with: {'; '.join(refused)}"
                 )
+        if self.model.block == "dots3":
+            m = self.model
+            n_win = next((i for i, t in enumerate(m.layer_types[2:]) if t == "full_attention"),
+                         len(m.layer_types) - 2)
+            period = ("full_attention",) + ("sliding_attention",) * n_win
+            if (len(m.layer_types) != m.num_layers or m.num_dense_layers != 1 or not n_win
+                    or (m.num_layers - 1) % len(period)
+                    or m.layer_types != ("full_attention",) + period * ((m.num_layers - 1) // len(period))):
+                raise ValueError(
+                    f"model {m.name!r}: layer_types must be one leading 'full_attention' layer (the dense "
+                    f"feed-forward's) and whole periods of one 'full_attention' and its 'sliding_attention' "
+                    f"layers after it, {m.num_layers} in all; got {m.layer_types!r}")
+            refused = [
+                what for on, what in (
+                    (self.kv_quant != "none", "--kv-quant int8 (no int8 latent cache or index keys)"),
+                    (self.spec_tokens > 0, "speculation (--spec-tokens; a draft's positions would each choose their own tokens)"),
+                    (self.lora_slots > 0, "LoRA banks (--lora-slots)"),
+                    (self.quant != "none", "--quant int8 (engine/quant.py)"),
+                    (self.tp > 1, "--tp (the latent kernels and the grouped expert product are single-device)"),
+                    (bool(self.host_kv_blocks or self.disk_kv_dir or self.fleet_kv_dir),
+                     "KV tiers (--host-kv-blocks, --disk-kv-dir, --fleet-kv-dir)"),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    f"model {m.name!r} has block='dots3' (latent pages with index keys, and a second pool "
+                    f"of window pages with a lifetime of its own), which cannot run with: {'; '.join(refused)}"
+                )
         if self.max_model_len % self.block_size:
             self.max_model_len = ((self.max_model_len // self.block_size) + 1) * self.block_size
         if self.max_prefill_tokens % self.block_size:
@@ -958,7 +1072,7 @@ class EngineArgs:
             # One pool, 2L cache layers, the row padded to whole lane tiles.
             itemsize = 2 if self.dtype == "bfloat16" else 4
             return m.cache_layers * self.block_size * m.latent_page_width * itemsize
-        if m.block in ("lfm2", "sala"):
+        if m.block in ("lfm2", "sala", "dots3"):
             return sum(self.pool_bytes_per_block().values())
         elems = self.block_size * m.num_kv_heads * m.head_dim
         if self.kv_quant == "int8":
@@ -977,7 +1091,7 @@ class EngineArgs:
         section 6, PR 46)."""
         m = self.model
         itemsize = 2 if self.dtype == "bfloat16" else 4
-        if m.block == "longcat":
+        if m.block in ("longcat", "dots3"):
             return self.block_size * m.latent_page_width * itemsize
         return 2 * self.block_size * m.kv_size * (1 if self.kv_quant == "int8" else itemsize)
 
@@ -992,6 +1106,11 @@ class EngineArgs:
             per_layer = m.kv_size * itemsize * len(m.sparse_layers)
             return {"kv": 2 * self.block_size * per_layer,
                     "ckeys": (self.block_size // m.sparse_kernel_stride) * per_layer}
+        if m.block == "dots3":
+            # The full layers' latents and index keys; the window layers' pool
+            # has blocks of its own: window_pool_bytes.
+            per_token = len(m.full_layers) * self.block_size * itemsize
+            return {"kv": per_token * m.latent_page_width, "ikeys": per_token * m.index_head_dim}
         if m.block != "lfm2":
             return {"kv": self.kv_bytes_per_block()}
         return {
@@ -1021,6 +1140,62 @@ class EngineArgs:
     def state_pool_bytes(self) -> int:
         return self.state_slots * self.state_slot_bytes()
 
+    # -- a block="dots3" model's window pool (0 for any other) -------------
+    # Sized from ``max_num_seqs``, the window and the prefill chunk alone: no
+    # flag. A running sequence holds the blocks of its last ``sliding_window``
+    # positions and of the decode window's steps past them
+    # (``window_table_width``); a prefill chunk those before its first
+    # position and its own (``window_prefill_width``).
+
+    @property
+    def window_back_blocks(self) -> int:
+        """Blocks that hold the ``sliding_window - 1`` positions before a
+        block boundary: what a prefix hit needs resident to resume there."""
+        w = self.model.sliding_window if self.model.block == "dots3" else 0
+        return -(-(w - 1) // self.block_size) if w else 0
+
+    @property
+    def window_table_width(self) -> int:
+        """Entries of a decode row's window table."""
+        if not self.window_back_blocks:
+            return 0
+        return (self.model.sliding_window - 1 + max(self.decode_steps, 1) - 1) // self.block_size + 2
+
+    @property
+    def window_prefill_width(self) -> int:
+        """Entries of a prefill row's window table: the blocks behind its
+        first position and a whole chunk's."""
+        if not self.window_back_blocks:
+            return 0
+        return self.window_back_blocks + self.max_prefill_tokens // self.block_size + 1
+
+    @property
+    def window_blocks(self) -> int:
+        """Blocks of the window pool: every slot's live window and as much
+        again for the boundaries finished turns and shared prompts leave
+        cached, four prefill chunks in flight, and block 0, the sink."""
+        if not self.window_back_blocks:
+            return 0
+        return 2 * self.max_num_seqs * self.window_table_width + 4 * self.window_prefill_width + 1
+
+    def window_bytes_per_block(self) -> int:
+        m = self.model
+        itemsize = 2 if self.dtype == "bfloat16" else 4
+        return len(m.window_layers) * self.block_size * m.swa.latent_page_width * itemsize
+
+    def window_pool_bytes(self) -> int:
+        return self.window_blocks * self.window_bytes_per_block() if self.window_blocks else 0
+
+    @property
+    def state_operand_width(self) -> int:
+        """Columns of the per-row operand a block with a second cache takes
+        beside its page table in a prefill (the runner's ``state=``): a
+        ``block="sala"`` row's six state slots, a ``block="dots3"`` row's
+        window table behind its first block's index; 0 for any other."""
+        if self.model.block == "sala":
+            return 6
+        return 1 + self.window_prefill_width if self.window_back_blocks else 0
+
     def replace(self, **kw) -> "EngineArgs":
         return dataclasses.replace(self, **kw)
 
@@ -1030,5 +1205,6 @@ class EngineArgs:
         per_block = args.kv_bytes_per_block()
         if args.model.block == "sala":
             per_block *= 2  # the state pool takes as many bytes again (state_slots)
+        hbm_bytes_free -= args.window_pool_bytes()  # a block="dots3" model's second pool comes first
         n = int(hbm_bytes_free * utilization) // per_block
         return max(n, args.blocks_per_seq * 2)

@@ -161,6 +161,7 @@ class _Seq:
         "qos", "qos_rank", "arrival",
         "step_base", "mig", "offer_deadline", "traceparent",
         "state_pair", "state_src", "state_chunk", "state_diverge", "state_plen", "state_hi",
+        "window_blocks", "window_claimed", "window_diverge",
     )
 
     def __init__(self, request_id: str, req: PreprocessedRequest, queue: asyncio.Queue):
@@ -189,6 +190,14 @@ class _Seq:
         self.state_diverge = 0  # where its cached pages ended, if deeper than any snapshot
         self.state_plen = 0
         self.state_hi = -1
+        # block="dots3" (block_manager/pool.py, window blocks): the window
+        # pool's block of each of the sequence's blocks it holds one for (index
+        # in the sequence -> block id), which of them it claimed as a hit, and
+        # the block its cached full-layer pages ended at where the window
+        # blocks before it were gone (a shared prompt's end: kept for the next).
+        self.window_blocks: dict[int, int] = {}
+        self.window_claimed: set[int] = set()
+        self.window_diverge = 0
         # Seeded requests are reproducible; others get a per-request seed.
         self.sample_seed = (
             req.sampling.seed if req.sampling.seed is not None else random.getrandbits(31)
@@ -696,6 +705,54 @@ def register_engine_metrics(registry) -> dict:
             "the dense path",
         ),
         registry.counter(
+            "engine_dsa_chosen_tokens_total",
+            "Cached tokens the full layers of a block='dots3' model attended, "
+            "a decode row and step (every full layer of a step counts the "
+            "same, so a step counts once): index_topk where the row sees more, "
+            "every visible token where it does not",
+        ),
+        registry.counter(
+            "engine_dsa_visible_tokens_total",
+            "Cached tokens those decode rows could see (their context)",
+        ),
+        registry.counter(
+            "engine_dsa_dense_rows_total",
+            "Of those decode rows, the ones at or under index_topk visible "
+            "tokens, whose choice is every token",
+        ),
+        registry.counter(
+            "engine_window_blocks_released_total",
+            "Window-pool blocks (block='dots3': the window layers' pages) "
+            "that sequences gave back, by where they went: cached = sealed and "
+            "registered, kept under the chain's hash in the window pool's own "
+            "LRU; free = at once",
+        ),
+        registry.counter(
+            "engine_window_resume_total",
+            "Admissions of such a model that found cached full-layer pages, "
+            "by how deep they could resume: deepest = at the last cached "
+            "block (the window blocks before it were resident); cut_back = at "
+            "an earlier block; miss = from position 0",
+        ),
+        registry.counter(
+            "engine_window_resume_recomputed_tokens_total",
+            "Tokens of cached full-layer pages those admissions computed "
+            "again, through every layer, for want of window blocks",
+        ),
+        registry.counter(
+            "engine_window_pool_evictions_total",
+            "Cached window-pool blocks evicted for their page",
+        ),
+        registry.gauge(
+            "engine_window_pool_used_blocks",
+            "Window-pool blocks running sequences hold",
+        ),
+        registry.gauge(
+            "engine_window_pool_cached_blocks",
+            "Window-pool blocks no sequence holds that stay cached under "
+            "their chain's hash (evictable)",
+        ),
+        registry.counter(
             "moe_assignments_total",
             "Expert assignments (token x top-k x layer) the expert layer "
             "routed, by kind: held = to a routed expert this chip holds "
@@ -782,6 +839,11 @@ class TpuEngine:
             enable_prefix_caching=args.prefix_caching,
             state_slots=args.state_slots,
         )
+        # block="dots3": the window layers' pages, a pool with a lifetime of its
+        # own and no events (the router's view is of the full-layer chain).
+        self.window_pool: BlockPool | None = (
+            BlockPool(args.window_blocks, args.block_size, enable_prefix_caching=args.prefix_caching)
+            if args.window_blocks else None)
         # G2/G3 KV tiers: sealed blocks write through to host (batched per
         # step); prefix misses in HBM onboard from the tiers instead of
         # recomputing (block_manager/tiers.py).
@@ -1002,6 +1064,12 @@ class TpuEngine:
             self._sparse_sizes = SparseSizes.of(self.cfg)
             self.state_stats = {"snapshot": 0, "zero": 0, "cached_tokens": 0, "recomputed_tokens": 0,
                                 "chosen": 0, "visible": 0, "dense": 0}
+        # block="dots3": how deep admissions resumed (engine_window_resume_*)
+        # and what the full layers' decode rows chose (engine_dsa_*); None for
+        # any other block.
+        self.window_stats: dict[str, int] | None = (
+            {"deepest": 0, "cut_back": 0, "miss": 0, "recomputed_tokens": 0,
+             "chosen": 0, "visible": 0, "dense": 0} if self.window_pool is not None else None)
         # Prefill dispatches by their program's rows (a chunk of a chunked
         # prefill is a dispatch of one row): engine_prefill_dispatch_rows_total.
         self.prefill_dispatch_rows: dict[int, int] = collections.defaultdict(int)
@@ -1018,6 +1086,11 @@ class TpuEngine:
                 self._gauges["engine_state_resumes_total"].inc(0, **{"from": origin})
             for why in self.pool.state_snapshots:
                 self._gauges["engine_state_snapshots_total"].inc(0, why=why)
+        if self.window_stats is not None:  # every series from the start, at 0
+            for outcome in ("deepest", "cut_back", "miss"):
+                self._gauges["engine_window_resume_total"].inc(0, outcome=outcome)
+            for to in self.window_pool.released:
+                self._gauges["engine_window_blocks_released_total"].inc(0, to=to)
 
     def _feed(self, name: str, total: float, **labels: str) -> None:
         """Give counter ``name`` what its running total grew by since it
@@ -1050,6 +1123,20 @@ class TpuEngine:
             feed("engine_sparse_blocks_chosen_total", st["chosen"])
             feed("engine_sparse_blocks_visible_total", st["visible"])
             feed("engine_sparse_dense_rows_total", st["dense"])
+        if self.window_stats is not None:
+            ws, wp = self.window_stats, self.window_pool
+            g["kv_pool_bytes"].set(self.args.window_pool_bytes(), kind="window")
+            for outcome in ("deepest", "cut_back", "miss"):
+                feed("engine_window_resume_total", ws[outcome], outcome=outcome)
+            feed("engine_window_resume_recomputed_tokens_total", ws["recomputed_tokens"])
+            for to, n in wp.released.items():
+                feed("engine_window_blocks_released_total", n, to=to)
+            feed("engine_window_pool_evictions_total", wp.evictions)
+            g["engine_window_pool_used_blocks"].set(wp.num_active)
+            g["engine_window_pool_cached_blocks"].set(wp.num_cached)
+            feed("engine_dsa_chosen_tokens_total", ws["chosen"])
+            feed("engine_dsa_visible_tokens_total", ws["visible"])
+            feed("engine_dsa_dense_rows_total", ws["dense"])
         g["engine_kv_quant_enabled"].set(1 if self.args.kv_quant == "int8" else 0)
         g["engine_prefill_pad_ratio"].set(
             self.total_prefill_padded / max(1, self.total_prefilled))
@@ -1458,8 +1545,8 @@ class TpuEngine:
         ktp = req.kv_transfer_params or {}
         if self.cfg.block != "llama" and any(k in ktp for k in (
                 "do_remote_decode", "peer_prefix", "stream_handle", "handle", "pages")):
-            pages = {"longcat": "latent pages", "lfm2": "conv-state pool",
-                     "sala": "state pool"}[self.cfg.block]
+            pages = {"longcat": "latent pages", "lfm2": "conv-state pool", "sala": "state pool",
+                     "dots3": "latent pages, index keys and window pool"}[self.cfg.block]
             yield LLMEngineOutput(
                 finish_reason=FinishReason.ERROR,
                 error="KV transfer (transfer/: disaggregated prefill, peer prefix "
@@ -2090,8 +2177,28 @@ class TpuEngine:
             # A hit is only as deep as the chain's deepest state snapshot.
             n_pages = len(self.pool.match_prefix(hashes_matchable))
             n_state, seq.state_src = self.pool.snapshot_depth(hashes_matchable)
+        if self.window_stats is not None:
+            # A hit is only as deep as the deepest block whose window blocks are resident.
+            n_pages = len(self.pool.match_prefix(hashes_matchable))
+            n_state = self.window_pool.window_depth(hashes_matchable[:n_pages], self.args.window_back_blocks)
         block_ids, n_hit = self.pool.allocate_sequence(hashes_matchable, total_blocks, max_hit=n_state)
-        if n_state is not None:
+        if self.window_stats is not None:
+            first = max(0, n_hit - self.args.window_back_blocks)
+            held = self.window_pool.claim(hashes_matchable[first:n_hit])
+            seq.window_blocks = dict(zip(range(first, n_hit), held))
+            seq.window_claimed = set(seq.window_blocks)
+            seq.window_diverge = n_pages if n_pages > n_hit else 0
+            try:  # the first chunk's blocks now: an admission that cannot have them waits
+                self._window_cover(seq, n_hit * bs, min(plen, n_hit * bs + self.args.max_prefill_tokens) - 1)
+            except NoFreeBlocksError:
+                self._release_window(seq)
+                self.pool.free_sequence(block_ids)
+                raise
+            if n_pages:
+                ws = self.window_stats
+                ws["deepest" if n_hit == n_pages else "cut_back" if n_hit else "miss"] += 1
+                ws["recomputed_tokens"] += (n_pages - n_hit) * bs
+        elif n_state is not None:
             try:
                 seq.state_pair = self.pool.acquire_state_pair()
             except NoFreeBlocksError:
@@ -2298,6 +2405,78 @@ class TpuEngine:
             keep = (pair[b % 2], seq.block_seq.blocks[b].sequence_hash)
         self.pool.release_state_pair(pair, keep)
 
+    # -- block="dots3": the window layers' blocks -----------------------------
+
+    def _window_cover(self, seq: _Seq, first_pos: int, last_pos: int, decoding: bool = False) -> None:
+        """``seq``'s next dispatch writes positions ``[first_pos, last_pos]``:
+        give back the window blocks behind the window of ``first_pos`` (no
+        later program of ``seq`` reads them, and the device's stream is serial,
+        so whoever gets one writes it after every reader dispatched so far) and
+        take blocks up to ``last_pos``'s. The blocks before the end of a shared
+        prompt (``window_diverge``) stay until they are registered, which a
+        chunked prefill's are when it is over: given back before, they would
+        be free, and the next to share the prompt would find nothing. So does,
+        ``decoding``, a block the windows in flight have sealed and no drain has
+        registered yet: its tokens are not on the host, so neither is its hash;
+        it goes, cached, a window or two later. Raises NoFreeBlocksError where
+        the pool cannot give a block."""
+        bs, back = self.args.block_size, self.args.window_back_blocks
+        lo = max(0, first_pos - (self.cfg.sliding_window - 1)) // bs
+        held = seq.window_blocks
+        kept = range(max(seq.window_diverge - back, seq.registered_blocks), seq.window_diverge)
+        self._window_release(seq, [i for i in held if i < lo and i not in kept
+                                   and not (decoding and i >= seq.registered_blocks)])
+        for i in range(max(lo, max(held, default=-1) + 1), last_pos // bs + 1):
+            held[i] = self.window_pool.allocate_block()
+
+    def _window_release(self, seq: _Seq, indices: list[int], final: bool = False) -> None:
+        """Give the window blocks at ``indices`` of ``seq`` back: to the warm
+        end of the pool's LRU what may be resumed from (everything where the
+        sequence stops, ``final``; what it claimed as a hit; the blocks before a
+        shared prompt's end), to the cold end what it wrote and passed."""
+        back = self.args.window_back_blocks
+        warm = [i for i in indices if final or i in seq.window_claimed
+                or seq.window_diverge - back <= i < seq.window_diverge]
+        cold = [i for i in indices if i not in warm]
+        self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in cold], cold=True)
+        self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in warm])
+        seq.window_claimed.difference_update(indices)
+
+    def _release_window(self, seq: _Seq) -> None:
+        """``seq`` stops running (finished, failed or preempted): every window
+        block it holds goes back, the sealed ones registered first, as the
+        boundary its next turn resumes from."""
+        if self.window_pool is None or not seq.window_blocks:
+            return
+        self._register_written_blocks(seq)
+        self._window_release(seq, list(seq.window_blocks), final=True)
+
+    def _window_row(self, seq: _Seq, first_pos: int, width: int) -> np.ndarray:
+        """``seq``'s window table for a dispatch whose first position is
+        ``first_pos`` (engine/dots3.py's ``state_slots`` row): the index of the
+        window's first block, then the blocks from it on."""
+        lo = max(0, first_pos - (self.cfg.sliding_window - 1)) // self.args.block_size
+        row = np.zeros((1 + width,), np.int32)
+        row[0] = lo
+        for i, bid in seq.window_blocks.items():
+            if 0 <= i - lo < width:
+                row[1 + i - lo] = bid
+        return row
+
+    def _decode_window_tables(self, batch: list[_Seq], pos0: list[int], B: int, K: int) -> np.ndarray:
+        """The rows' window tables for a decode dispatch of ``K`` steps from
+        ``pos0`` (``_ensure_block`` has covered them), and what the full
+        layers' rows choose: the engine_dsa_* books."""
+        state = np.zeros((B, 1 + self.args.window_table_width), np.int32)
+        for i, (seq, p0) in enumerate(zip(batch, pos0)):
+            state[i] = self._window_row(seq, p0, self.args.window_table_width)
+        ws, topk = self.window_stats, self.cfg.index_topk
+        seen = np.asarray(pos0)[:, None] + np.arange(1, K + 1)[None, :]  # what each row's steps see
+        ws["visible"] += int(seen.sum())
+        ws["chosen"] += int(np.minimum(seen, topk).sum())
+        ws["dense"] += int((seen <= topk).sum())
+        return state
+
     def _prefill_packed(
         self, members: list[tuple[_Seq, int]], Bp: int, t_pad: int
     ) -> Any:
@@ -2325,6 +2504,11 @@ class TpuEngine:
             for r, (seq, start) in enumerate(members):
                 state_kw["state"][r] = self._prefill_state(seq, start, len(seq.tokens))
             self._count_choices(np.concatenate([np.arange(a + 1, len(s.tokens) + 1) for s, a in members]), W)
+        if self.window_stats is not None:
+            state_kw["state"] = np.zeros((Bp, self.args.state_operand_width), np.int32)
+            for r, (seq, start) in enumerate(members):
+                self._window_cover(seq, start, len(seq.tokens) - 1)
+                state_kw["state"][r] = self._window_row(seq, start, self.args.window_prefill_width)
         self._dispatching()
         ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots, **state_kw)
         self._dispatched(ref.arrs)
@@ -2366,6 +2550,9 @@ class TpuEngine:
             if self.state_stats is not None:
                 state_kw["state"] = np.asarray(self._prefill_state(seq, pos, pos + len(chunk)), np.int32)
                 self._count_choices(np.arange(pos + 1, pos + len(chunk) + 1), W)
+            if self.window_stats is not None:
+                self._window_cover(seq, pos, pos + len(chunk) - 1)
+                state_kw["state"] = self._window_row(seq, pos, self.args.window_prefill_width)
             self._dispatching()
             logits = self._runner.prefill_chunk(
                 toks, table, pos, min(pos + len(chunk), plen),
@@ -2622,6 +2809,8 @@ class TpuEngine:
             return {"error": "live migration cannot carry the conv-state pool"}
         if self.cfg.block == "sala":
             return {"error": "live migration cannot carry the state pool"}
+        if self.cfg.block == "dots3":
+            return {"error": "live migration cannot carry latent pages, index keys and the window pool"}
         seq = next(
             (s for s in self._running if s.request_id == request_id), None
         )
@@ -2844,6 +3033,9 @@ class TpuEngine:
             blk = seq.block_seq.blocks[seq.registered_blocks]
             bid = seq.block_ids[seq.registered_blocks]
             self.pool.register_block(bid, blk.sequence_hash, blk.parent_sequence_hash)
+            if self.window_pool is not None and seq.registered_blocks in seq.window_blocks:
+                self.window_pool.register_block(
+                    seq.window_blocks[seq.registered_blocks], blk.sequence_hash, blk.parent_sequence_hash)
             # Write-through offload: queue the sealed block for the end-of-
             # step batched extract (bounded; duplicates in tiers skipped).
             if (
@@ -2863,6 +3055,11 @@ class TpuEngine:
         while len(seq.block_ids) * self.args.block_size <= last_pos:
             try:
                 seq.block_ids.append(self.pool.allocate_block())
+            except NoFreeBlocksError:
+                return False
+        if self.window_pool is not None:
+            try:
+                self._window_cover(seq, seq.next_write_pos + self._pend(seq), last_pos, decoding=True)
             except NoFreeBlocksError:
                 return False
         return True
@@ -2981,6 +3178,7 @@ class TpuEngine:
             seq.export_pub_blocks = 0
             seq.export = False
         self._release_state(seq)
+        self._release_window(seq)
         self.pool.free_sequence(seq.block_ids)
         seq.block_ids = []
         seq.registered_blocks = 0
@@ -3243,6 +3441,8 @@ class TpuEngine:
         )
         aslots = self._adapter_row_slots(batch, B)
         state_kw = {"state": self._decode_state(batch, pos0, B, K)} if self.state_stats is not None else {}
+        if self.window_stats is not None:
+            state_kw = {"state": self._decode_window_tables(batch, pos0, B, K)}
         self._enter("decode_dispatch")
         self._dispatching()
         ref = self._runner.multi_decode(
@@ -3695,6 +3895,9 @@ class TpuEngine:
         state_kw = {}
         if self.state_stats is not None:
             state_kw["state"] = self._decode_state(batch, [int(p) for p in positions[: len(batch)]], B, 1)
+        if self.window_stats is not None:
+            state_kw["state"] = self._decode_window_tables(
+                batch, [int(p) for p in positions[: len(batch)]], B, 1)
         self._dispatching()
         ref = self._runner.decode_step(tokens, positions, tables, active, aslots, **state_kw)
         self._dispatched(ref.arrs)
@@ -3885,6 +4088,7 @@ class TpuEngine:
                 (b, h) for b, h in self._offload_pending if b not in freed
             ]
         self._release_state(seq)
+        self._release_window(seq)
         self.pool.free_sequence(seq.block_ids)
         seq.block_ids = []
         if not already_posted:

@@ -199,13 +199,21 @@ def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-def mla_project(h: jax.Array, sub: dict, cfg: ModelConfig, positions: jax.Array):
+def mla_query_latent(h: jax.Array, sub: dict, cfg: ModelConfig) -> jax.Array:
+    """h [..., D] → the normed low-rank query latent ``c_q`` [..., q_lora_rank]."""
+    with jax.named_scope("mla_q"):
+        return _rms(jnp.dot(h, sub["w_qa"]), sub["q_norm"], cfg.rms_norm_eps)
+
+
+def mla_project(h: jax.Array, sub: dict, cfg: ModelConfig, positions: jax.Array, c_q=None):
     """h [..., D] at ``positions`` [...] → (q_n [..., H, dn], q_r [..., H, dr],
-    latent [..., latent_dim]): the low-rank query, and the row the cache holds."""
+    latent [..., latent_dim]): the low-rank query, and the row the cache holds.
+    ``c_q``: ``mla_query_latent(h, ..)`` where the caller has it already."""
     D, H = cfg.hidden_size, cfg.num_heads
     dn, dr, rkv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    if c_q is None:
+        c_q = mla_query_latent(h, sub, cfg)
     with jax.named_scope("mla_q"):
-        c_q = _rms(jnp.dot(h, sub["w_qa"]), sub["q_norm"], cfg.rms_norm_eps)
         q_scale = jnp.asarray((D / cfg.q_lora_rank) ** 0.5 if cfg.mla_scale_q_lora else 1.0, h.dtype)
         q_n = (jnp.dot(c_q, sub["w_qn"]) * q_scale).reshape(*h.shape[:-1], H, dn)
         q_r = (jnp.dot(c_q, sub["w_qr"]) * q_scale).reshape(*h.shape[:-1], H, dr)
@@ -405,7 +413,9 @@ def moe(h: jax.Array, valid: jax.Array, lp: dict, cfg: ModelConfig, impl: str):
     N = xt.shape[0]
     per = MOE_CHUNK_ROWS // min(cfg.num_experts_per_token, cfg.num_experts)
     parts = -(-N // per)
-    if parts > 1 and N % parts == 0:
+    while N % parts:  # the fewest equal parts of no more than ``per`` tokens
+        parts += 1
+    if parts > 1:
         y, hist = lax.map(
             lambda a: _moe_tokens(a[0], a[1], lp, cfg, impl),
             (xt.reshape(parts, N // parts, D), vt.reshape(parts, N // parts)),

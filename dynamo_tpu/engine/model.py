@@ -34,6 +34,7 @@ lib/llm/src/tokens.rs so KV identity is consistent framework-wide.
 from __future__ import annotations
 
 import functools
+import importlib
 import sys
 from typing import Any, NamedTuple
 
@@ -81,7 +82,14 @@ class KVCache(NamedTuple):
     layers, their compressed keys under the same block ids (``ckeys``
     ``[sparse layers, N, bs // stride, KVH*hd]``) and the lightning layers'
     matrix states in slots of their own (``state`` ``[lightning layers, S, H,
-    d, d]`` in the cache's dtype), which block_manager/pool.py hands out."""
+    d, d]`` in the cache's dtype), which block_manager/pool.py hands out.
+
+    A ``block="dots3"`` model (engine/dots3.py) has latent pages for its full
+    layers (``kv`` ``[full layers, N, bs, latent_page_width]``), the indexer's
+    keys under the same block ids (``ikeys`` ``[full layers, N, bs,
+    index_head_dim]``) and, for its window layers, a pool with block ids and a
+    lifetime of its own (``window`` ``[window layers, Nw, bs, swa
+    latent_page_width]``), which block_manager/pool.py hands out too."""
 
     kv: jax.Array  # [L, N, 2, bs, KVH*hd] — a page is K then V
     k_scale: jax.Array | None = None  # [L, N, bs, KVH] fp32 — int8 only
@@ -89,6 +97,8 @@ class KVCache(NamedTuple):
     conv: jax.Array | None = None     # block="lfm2" only
     ckeys: jax.Array | None = None    # block="sala" only
     state: jax.Array | None = None    # block="sala" only
+    ikeys: jax.Array | None = None    # block="dots3" only
+    window: jax.Array | None = None   # block="dots3" only
 
     @property
     def block_size(self) -> int:
@@ -244,27 +254,21 @@ def _dot_q(x: jax.Array, lp: dict, name: str) -> jax.Array:
     return jnp.dot(x, w)
 
 
+# The blocks there are: this module's own, then those with a module of their name under engine/.
+BLOCK_MODULES = ("llama", "longcat", "lfm2", "sala", "dots3")
+
+
 def block_module(cfg: ModelConfig):
     """The module that runs ``cfg.block``, chosen once (engine/runner.py):
-    this one, engine/longcat.py, engine/lfm2.py or engine/sala.py. Each has ``init_params``,
+    this one or the ``BLOCK_MODULES`` entry's under engine/. Each has ``init_params``,
     ``init_kv_cache`` and the jitted ``prefill``, ``prefill_batch``,
     ``decode_step`` and ``multi_decode`` under these names; a block that
     routes (``routed_layers(cfg)``) returns a routing histogram after what
     these return."""
-    if cfg.block == "longcat":
-        from dynamo_tpu.engine import longcat
-
-        return longcat
-    if cfg.block == "lfm2":
-        from dynamo_tpu.engine import lfm2
-
-        return lfm2
-    if cfg.block == "sala":
-        from dynamo_tpu.engine import sala
-
-        return sala
-    if cfg.block != "llama":
-        raise ValueError(f"no module runs block={cfg.block!r} (llama, longcat, lfm2, sala)")
+    if cfg.block not in BLOCK_MODULES:
+        raise ValueError(f"no module runs block={cfg.block!r} ({', '.join(BLOCK_MODULES)})")
+    if cfg.block != BLOCK_MODULES[0]:
+        return importlib.import_module(f"dynamo_tpu.engine.{cfg.block}")
     return sys.modules[__name__]
 
 

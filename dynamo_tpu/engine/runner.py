@@ -270,6 +270,7 @@ class LocalRunner:
             kv_quant=self.args.kv_quant,
             sharding=None if sh is None else sh.cache_sharding,
             **({"state_slots": self.args.state_slots} if self.args.state_slots else {}),
+            **({"window_blocks": self.args.window_blocks} if self.args.window_blocks else {}),
         )
         if self.args.lora_slots > 0:
             from dynamo_tpu.engine.lora import bank_shapes
@@ -316,8 +317,9 @@ class LocalRunner:
         W = self.args.blocks_per_seq  # the wide table only
         i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
 
-        def state_kw(rows):  # a block with a state pool takes a row's slots beside its table
-            return {"state_slots": i32((rows, 6))} if self.args.state_slots else {}
+        def state_kw(rows):  # a block with a second cache takes a row's slots (or window table) beside its table
+            width = self.args.state_operand_width
+            return {"state_slots": i32((rows, width))} if width else {}
 
         def work():
             t0 = time.monotonic()
@@ -365,9 +367,11 @@ class LocalRunner:
         impl = resolve_attn_impl(self.args.attn_impl)
         if impl != "pallas":
             return impl, ""
-        limit = (
-            latent_kernel_unsupported if self.cfg.block == "longcat" else kernel_unsupported
-        )(self.cfg, self.args.block_size)
+        if self.cfg.kv_lora_rank:  # latent pages: both geometries of a model that has two
+            geometries = (self.cfg, self.cfg.swa) if self.cfg.block == "dots3" else (self.cfg,)
+            limit = next(filter(None, (latent_kernel_unsupported(g, self.args.block_size) for g in geometries)), None)
+        else:
+            limit = kernel_unsupported(self.cfg, self.args.block_size)
         if limit is None:
             return impl, ""
         if self.args.attn_impl == "pallas":

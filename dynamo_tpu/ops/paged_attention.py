@@ -370,6 +370,7 @@ def _mq_kernel(
     quantized: bool,
     tree_slots: int = 0,
     value_dim: int = 0,
+    window: int = 0,
 ):
     # One pool either way, and a page of it one DMA descriptor. value_dim 0:
     # a page is K then V, ``[2, bs, KVH*hd]``. value_dim > 0: a latent (MLA)
@@ -486,6 +487,8 @@ def _mq_kernel(
         lenvec = lenvec_ref[0]                             # [H, 1]
         pos = c * CH + lax.broadcasted_iota(jnp.int32, (1, CH), 1)
         att = pos < lenvec
+        if window:  # a column attends its last ``window`` positions, its own among them
+            att = att & (pos >= lenvec - window)
         if tree_slots:
             anc = anc_ref[0]                               # [H, T]
             for s_i in range(tree_slots):
@@ -621,6 +624,8 @@ def _paged_attention_mq(
     *,
     value_dim: int = 0,            # latent pool: V = a row's first lanes
     scale: float | None = None,    # softmax scale (default hd ** -0.5)
+    window: int = 0,               # > 0: a query attends its last ``window`` positions only
+    name: str | None = None,       # the kernel's name in a trace (default: the caller's jit)
 ) -> jax.Array:
     """Shared Pallas driver: T query positions per row walk the row's
     true pages once. Returns [B, T, KVH, G, hd] in q.dtype
@@ -727,6 +732,7 @@ def _paged_attention_mq(
     kernel = functools.partial(
         _mq_kernel, pages_per_chunk=P, head_dim=hd, quantized=quantized,
         tree_slots=T if anc is not None else 0, value_dim=value_dim,
+        **({"window": window} if window else {}),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -740,6 +746,7 @@ def _paged_attention_mq(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hp, out_cols), q.dtype),
         interpret=interpret,
+        **({"name": name} if name else {}),
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         rowlen,
@@ -1167,13 +1174,17 @@ def latent_decode_attention_xla(
     layer_idx: jax.Array,    # scalar int32 — cache layer (2*layer + sub-block)
     block_tables: jax.Array, # [B, W] int32
     lengths: jax.Array,      # [B] int32
-    *, value_dim: int, scale: float,
+    *, value_dim: int, scale: float, window: int = 0,
 ) -> jax.Array:
-    """Gather-based reference of the latent decode attention → [B, H, value_dim]."""
+    """Gather-based reference of the latent decode attention → [B, H, value_dim].
+    ``window`` > 0: a row attends its last ``window`` positions only."""
     B, H, Dk = q.shape
     pk = _gather_pages(cache, layer_idx, block_tables).reshape(B, -1, Dk)  # [B, W*bs, Dk]
     ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
-    mask = jnp.where(ctx[None, :] < lengths[:, None], 0.0, jnp.float32(NEG_INF))
+    seen = ctx[None, :] < lengths[:, None]
+    if window:
+        seen &= ctx[None, :] >= lengths[:, None] - window
+    mask = jnp.where(seen, 0.0, jnp.float32(NEG_INF))
     s = jnp.einsum("bhd,bcd->bhc", q, pk).astype(jnp.float32) * scale
     p = jax.nn.softmax(s + mask[:, None, :], axis=-1).astype(q.dtype)
     return jnp.einsum("bhc,bcv->bhv", p, pk[..., :value_dim])
@@ -1181,7 +1192,7 @@ def latent_decode_attention_xla(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("value_dim", "scale", "pages_per_chunk", "interpret"),
+    static_argnames=("value_dim", "scale", "pages_per_chunk", "interpret", "window"),
 )
 def latent_decode_attention(
     q: jax.Array,            # [B, H, Dk]
@@ -1192,14 +1203,17 @@ def latent_decode_attention(
     *, value_dim: int, scale: float,
     pages_per_chunk: int = 0,
     interpret: bool = False,
+    window: int = 0,
 ) -> jax.Array:
     """The multi-query kernel at the latent geometry: H query heads against
     one shared key of Dk lanes, value = its first ``value_dim``; each page
-    is read once. Returns [B, H, value_dim]."""
+    is read once. ``window`` > 0: a row attends its last ``window`` positions
+    only (the table then holds the blocks of those positions and ``lengths``
+    counts from the table's first block). Returns [B, H, value_dim]."""
     o = _paged_attention_mq(
         q[:, None, None], cache, layer_idx, block_tables,
         jnp.asarray(lengths, jnp.int32)[:, None], None, None,
-        pages_per_chunk, interpret, value_dim=value_dim, scale=scale,
+        pages_per_chunk, interpret, value_dim=value_dim, scale=scale, window=window,
     )
     return o[:, 0, 0]
 
@@ -1216,7 +1230,7 @@ def latent_prefill_attention_xla(
     block_tables: jax.Array, # [B, W] int32
     start_pos: jax.Array,    # [B] int32
     true_len: jax.Array,     # [B] int32 — 0: an inactive row
-    *, scale: float,
+    *, scale: float, window: int = 0, keep: jax.Array | None = None,
 ) -> jax.Array:
     """The gather-based form of ``latent_prefill_attention``: the table's
     whole width gathered dense, prefix and chunk alike, and float32 scores
@@ -1229,8 +1243,13 @@ def latent_prefill_attention_xla(
     C = pk.shape[1]
     horizon = jnp.minimum(start_pos[:, None] + jnp.arange(T, dtype=jnp.int32)[None] + 1,
                           true_len[:, None])                       # [B, T]
-    mask = jnp.where(jnp.arange(C, dtype=jnp.int32)[None, None] < horizon[..., None],
-                     0.0, jnp.float32(NEG_INF))                    # [B, T, C]
+    ctx = jnp.arange(C, dtype=jnp.int32)[None, None]
+    seen = ctx < horizon[..., None]
+    if window:
+        seen &= ctx >= horizon[..., None] - window
+    if keep is not None:
+        seen &= keep != 0
+    mask = jnp.where(seen, 0.0, jnp.float32(NEG_INF))              # [B, T, C]
     g = H
     while g > 1 and B * g * T * C > _XLA_SCORE_ELEMS:
         g //= 2
@@ -1282,20 +1301,21 @@ def _latent_prefill_kernel(
     # operands
     ql_ref,       # VMEM [1, H, tq, Dv] — a tile of absorbed queries, head-major: the
     qr_ref,       # VMEM [1, H, tq, Dk - Dv]   latent lanes, and the rope lanes (zero-padded)
-    k_hbm,        # ANY  [2L, N, bs, Dk]
-    o_ref,        # VMEM [1, H, tq, Dv]
+    *refs,        # [keep_ref VMEM [1, tq, W*bs] — nonzero: the query attends that position]
+    # k_hbm,        ANY  [2L, N, bs, Dk]
+    # o_ref,        VMEM [1, H, tq, Dv]
     # scratch
-    q_scr,        # VMEM [H*tq, Dk] — the two side by side, row h*tq + t: ONE left operand
-    kbuf,         # VMEM [2, P, bs, Dk] — pages as they land
-    acc_scr,      # VMEM [H*tq, Dv] f32
-    m_scr,        # VMEM [H*tq, 1] f32 — running max
-    l_scr,        # VMEM [H*tq, 1] f32 — running sum
-    hz_scr,       # VMEM [H*tq, 1] int32 — each query row attends [0, horizon)
-    slot_ref,     # SMEM [1] int32
-    sem,          # DMA semaphores [2 slots]
-    *,
+    # q_scr,        VMEM [H*tq, Dk] — the two side by side, row h*tq + t: ONE left operand
+    # kbuf,         VMEM [2, P, bs, Dk] — pages as they land
+    # acc_scr,      VMEM [H*tq, Dv] f32
+    # m_scr,        VMEM [H*tq, 1] f32 — running max
+    # l_scr,        VMEM [H*tq, 1] f32 — running sum
+    # hz_scr,       VMEM [H*tq, 1] int32 — each query row attends [0, horizon)
+    # slot_ref,     SMEM [1] int32
+    # sem,          DMA semaphores [2 slots]
     pages_per_chunk: int,
     scale: float,
+    window: int = 0,
 ):
     """``_prefill_kernel``'s walk at the latent geometry: one pool, one key
     row all heads share, its first Dv lanes the value. A tile's ``H x tq``
@@ -1306,7 +1326,11 @@ def _latent_prefill_kernel(
     of heads) have them in, so XLA transposes nothing around the call. Scores,
     maximum, sum and accumulator are float32 and never leave VMEM; the softmax
     scale multiplies the float32 scores (the published form's ``s * scale``),
-    not the bf16 queries."""
+    not the bf16 queries. ``window`` > 0: a query attends its last ``window``
+    positions only; a ``keep`` operand: those of its causal context that the
+    operand marks. Either way every chunk takes the masked form."""
+    keep_ref = refs[0] if len(refs) == 11 else None
+    k_hbm, o_ref, q_scr, kbuf, acc_scr, m_scr, l_scr, hz_scr, slot_ref, sem = refs[-10:]
     P = pages_per_chunk
     b, j = pl.program_id(0), pl.program_id(1)
     H, tq, Dv = ql_ref.shape[1:]
@@ -1320,6 +1344,8 @@ def _latent_prefill_kernel(
     nchunks = jnp.where(q0 < true_len, lax.div(bound + CH - 1, CH), 0)
     # Chunks every query of the tile sees whole: all of it at or before q0.
     nplain = jnp.minimum(lax.div(q0 + 1, CH), nchunks)
+    if window or keep_ref is not None:
+        nplain = 0
 
     issue, wait = _page_fetch(layer, tables_ref, k_hbm, kbuf, sem, P, unroll=False)
 
@@ -1358,7 +1384,13 @@ def _latent_prefill_kernel(
             ) * scale                                          # [R, CH]
             if masked:
                 pos = c * CH + lax.broadcasted_iota(jnp.int32, (1, CH), 1)
-                s = jnp.where(pos < hz_scr[...], s, NEG_INF)
+                seen = pos < hz_scr[...]
+                if window:
+                    seen = seen & (pos >= hz_scr[...] - window)
+                s = jnp.where(seen, s, NEG_INF)
+                if keep_ref is not None:
+                    kept = keep_ref[0, :, pl.ds(pl.multiple_of(c * CH, CH), CH)] != 0   # [tq, CH]
+                    s = jnp.where(kept[None], s.reshape(H, tq, CH), NEG_INF).reshape(R, CH)
             m_prev = m_scr[...]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             corr = jnp.exp(m_prev - m_new)                     # [R, 1]
@@ -1406,6 +1438,8 @@ def latent_prefill_attention(
     pages_per_chunk: int = 0,  # 0 → _LATENT_CHUNK_TOKENS a chunk
     q_tile: int = 0,           # 0 → _latent_prefill_tile
     interpret: bool = False,
+    window: int = 0,           # > 0: a query attends its last ``window`` positions only
+    keep: jax.Array | None = None,  # [B, T, W*bs]: nonzero where query t attends that position
 ) -> jax.Array:
     """``paged_prefill_attention`` over latent pages, in the absorbed form
     decode attends in: query ``t`` of row ``b`` sits at ``start_pos[b] + t``
@@ -1417,7 +1451,10 @@ def latent_prefill_attention(
     out by W_kvb once, then 2 x T x H x 320) up to T ~ 157 and 1.6 times it
     at T 256; against the expanded form as XLA ran it (the table's width,
     float32 scores through HBM) it is less work at every T a 4,096-token
-    table allows. Returns [B, H, T, Dv] in q_lat.dtype; rows of queries at or
+    table allows. ``window`` and ``keep`` narrow the causal context (a window
+    layer whose table and positions count from the window's first block; a
+    layer that attends a chosen set): the walk stays that of the context, the
+    mask does the rest. Returns [B, H, T, Dv] in q_lat.dtype; rows of queries at or
     past ``true_len`` are unspecified."""
     B, H, T, Dv = q_lat.shape
     bs = cache.shape[2]
@@ -1426,15 +1463,18 @@ def latent_prefill_attention(
     P = min(P, cache.shape[1])
     tq = q_tile or _latent_prefill_tile(T, H, P * bs)
     assert T % tq == 0, (T, tq)
+    tables = _whole_table(block_tables, P)
+    if keep is not None and keep.shape[2] != tables.shape[1] * bs:
+        keep = jnp.pad(keep, ((0, 0), (0, 0), (0, tables.shape[1] * bs - keep.shape[2])))
     return _latent_prefill_call(
-        q_lat, q_rope, cache, layer_idx, _whole_table(block_tables, P), start_pos, true_len,
-        scale=scale, P=P, tq=tq, interpret=interpret,
+        q_lat, q_rope, cache, layer_idx, tables, start_pos, true_len, keep,
+        scale=scale, P=P, tq=tq, interpret=interpret, window=window,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "P", "tq", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "P", "tq", "interpret", "window"))
 def _latent_prefill_call(q_lat, q_rope, cache, layer_idx, block_tables, start_pos, true_len,
-                         *, scale: float, P: int, tq: int, interpret: bool):
+                         keep=None, *, scale: float, P: int, tq: int, interpret: bool, window: int = 0):
     B, H, T, Dv = q_lat.shape
     bs, Dk = cache.shape[2:]
     R = H * tq
@@ -1445,7 +1485,10 @@ def _latent_prefill_call(q_lat, q_rope, cache, layer_idx, block_tables, start_po
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, T // tq),
-        in_specs=[tile(Dv), tile(Dk - Dv), pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[tile(Dv), tile(Dk - Dv),
+                  *([] if keep is None else
+                    [pl.BlockSpec((1, tq, keep.shape[2]), lambda b, j, *_: (b, j, 0))]),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=tile(Dv),
         scratch_shapes=[
             pltpu.VMEM((R, Dk), q_lat.dtype),
@@ -1459,7 +1502,8 @@ def _latent_prefill_call(q_lat, q_rope, cache, layer_idx, block_tables, start_po
         ],
     )
     return pl.pallas_call(
-        functools.partial(_latent_prefill_kernel, pages_per_chunk=P, scale=scale),
+        functools.partial(_latent_prefill_kernel, pages_per_chunk=P, scale=scale,
+                          **({"window": window} if window else {})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, Dv), q_lat.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_PREFILL_VMEM_BYTES),
@@ -1472,5 +1516,6 @@ def _latent_prefill_call(q_lat, q_rope, cache, layer_idx, block_tables, start_po
         jnp.asarray(block_tables, jnp.int32),
         q_lat,
         q_rope,
+        *([] if keep is None else [keep]),
         cache,
     )

@@ -839,3 +839,78 @@ def test_sala_programs_compile_for_v5e(v5e, program):
         assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
         assert not _pool_copies(compiled, cache)  # 239 MB of scores and chunked scans, no pool among them
         assert "paged_prefill_attention" in compiled.as_text()  # the dense branch, under dense_len
+
+
+# -- the dots3 block: two latent geometries, a chosen set, a window pool of its own --
+
+
+def _dots3():
+    """The benchmark's dots3 configuration at its published widths (a chip's
+    share: 9 of 46 layers, 16 of 256 experts, 19,008 vocabulary rows)."""
+    from chipbench import model_maps
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "chipbench", "configs", "dots3-note-prev-ep16.json")) as f:
+        doc = json.load(f)
+    from chipbench.run import engine_args
+
+    return model_maps.model_config(doc), engine_args(doc)
+
+
+@pytest.mark.parametrize("program", ["index_scores", "chosen_rows", "window_decode", "window_prefill", "keep_prefill",
+                                     "decode_window", "prefill_chunk_2048", "prefill_packed_2x256"])
+def test_dots3_programs_compile_for_v5e(v5e, program):
+    """What the dots3 cell runs, at the published widths, the cell's pools
+    (18,432 blocks of latents and index keys, the window pool) and its
+    32,768-token table: the two new kernels at the decode window's call (32
+    rows), the latent kernels at the second geometry under the window's mask
+    and under a chosen set's, then the decode window, a 2,048-token chunk and a
+    pack of two rows. No program copies a pool (the chosen rows are gathered as
+    rows of lanes, each with its own block and slot), nothing in a prefill has
+    the 128 heads' absorbed queries of a whole chunk, and a chunk's temporaries
+    leave room in 16 GB beside 9.2 GB of weights and 3.4 GB of pools."""
+    from dynamo_tpu.engine import dots3
+    from dynamo_tpu.ops import dsa
+    from dynamo_tpu.ops.paged_attention import latent_decode_attention, latent_prefill_attention
+
+    cfg, args = _dots3()
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32, f32, bf16 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32)), (lambda *s: S(s, jnp.bfloat16))
+    B, W, N, Nw = 32, args.blocks_per_seq, args.num_kv_blocks, args.window_blocks
+    assert (W, Nw, args.window_table_width, args.window_prefill_width) == (1024, 1477, 18, 81)
+    kernels = {
+        "index_scores": lambda: dsa.index_scores.lower(bf16(B, 64, 128), f32(B, 64), bf16(3, N, LBS, 128), i32(), i32(B, W), i32(B)),
+        "chosen_rows": lambda: dsa.sparse_decode_attention.lower(
+            bf16(B, 128, 640), bf16(3, N, LBS, 640), i32(), i32(B, W), i32(B, 2048), i32(B), value_dim=512, scale=0.07),
+        "window_decode": lambda: latent_decode_attention.lower(
+            bf16(B, 64, 1152), bf16(6, Nw, LBS, 1152), i32(), i32(B, 18), i32(B), value_dim=1024, scale=0.06, window=513),
+        "window_prefill": lambda: jax.jit(functools.partial(latent_prefill_attention, scale=0.06, window=513)).lower(
+            bf16(1, 64, 512, 1024), bf16(1, 64, 512, 128), bf16(6, Nw, LBS, 1152), i32(), i32(1, 81), i32(1), i32(1)),
+        "keep_prefill": lambda: jax.jit(functools.partial(latent_prefill_attention, scale=0.07)).lower(
+            bf16(1, 128, 512, 512), bf16(1, 128, 512, 128), bf16(3, N, LBS, 640), i32(), i32(1, W), i32(1), i32(1),
+            keep=bf16(1, 512, W * LBS)),
+    }
+    if program in kernels:
+        assert "tpu_custom_call" in kernels[program]().compile().as_text()
+        return
+    params = _abstract(jax.eval_shape(lambda: dots3.init_params(cfg, jax.random.PRNGKey(0))), S)
+    cache = _abstract(jax.eval_shape(lambda: dots3.init_kv_cache(cfg, N, LBS, window_blocks=Nw)), S)
+    if program == "decode_window":
+        flags = S((B,), jnp.bool_)
+        compiled = dots3.multi_decode.lower(
+            cfg, 8, "greedy", 0, params, cache,
+            i32(B), i32(B), i32(B, W), flags, f32(B), S((B,), jnp.uint32), i32(B),
+            i32(B), f32(B), f32(B), f32(B), i32(B, 1), flags, i32(B), i32(B + 1),
+            None, None, attn_impl="pallas", experts="gmm", state_slots=i32(B, 1 + args.window_table_width),
+        ).compile()
+        hlo = compiled.as_text()
+        assert "dsa_index_scores" in hlo and "latent_sparse_decode_attention" in hlo
+        limit = 1.0e9  # 0.81 GB by the compiler's analysis
+    else:
+        rows, t = (1, 2048) if program == "prefill_chunk_2048" else (2, 256)
+        compiled = dots3.prefill_batch.lower(
+            cfg, params, cache, i32(rows, t), i32(rows, W), i32(rows), i32(rows),
+            attn_impl="pallas", experts="gmm", state_slots=i32(rows, args.state_operand_width)).compile()
+        assert "latent_prefill_attention" in compiled.as_text()
+        limit = 1.6e9  # 1.29 GB at T 2,048: a query block's scores, keys and mask over 32,768 positions, the experts' parts
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+    assert not _pool_copies(compiled, cache)
