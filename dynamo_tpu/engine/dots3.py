@@ -19,9 +19,10 @@ bias``, weights renormalised; the experts held here, the rest left out).
   every cached position against one ``index_head_dim`` key a token and the
   query attends the ``index_topk`` highest; a query that sees no more than that
   attends them all. The choice is made anew at every token, in prefill (a mask
-  over the latent prefill kernel's walk) as in decode (the chosen rows gathered
-  and attended alone). Then a head-wise gate ``o_h * sigmoid(W_g u)_h`` and
-  ``W_o``.
+  over the latent prefill kernel's walk) as in decode (a mask over the decode
+  kernel's walk, or the chosen rows gathered and attended alone, whichever
+  ``dsa.walk_is_cheaper`` of the call's lengths). Then a head-wise gate
+  ``o_h * sigmoid(W_g u)_h`` and ``W_o``.
 - **A window layer** (``"sliding_attention"``): the same equations at the
   ``swa_*`` sizes (``cfg.swa``), a query attending its last ``sliding_window``
   positions, its own among them. No indexer.
@@ -456,28 +457,46 @@ def decode_step_impl(cfg, params, cache, tokens, positions, block_tables, active
                 return latent_decode_attention(q, kv, ci, block_tables, lengths, value_dim=cfg.kv_lora_rank,
                                                scale=scale, interpret=interpret)
 
-            def chosen():
-                with jax.named_scope("dsa_index"):
-                    q_idx, w = index_query(u, c_q, lp, cfg, positions)
+            def chosen(attend_set):
+                """A branch that scores the rows' cached positions and hands
+                the scores to ``attend_set``. The scan is in the branch: its
+                scores reach the choice as the kernel lays them out (the same
+                scores carried into a branch cost ``keep_topk`` 100 us more a
+                layer on the v5e: PERF.md section 5, step 0 of PR 48)."""
+                def branch():
+                    with jax.named_scope("dsa_index"):
+                        q_idx, w = index_query(u, c_q, lp, cfg, positions)
+                        if impl == "xla":
+                            return attend_set(dsa.index_scores_xla(q_idx, w, ik, ci, block_tables, lengths))
+                        return attend_set(dsa.index_scores(q_idx, w, ik, ci, block_tables, lengths, interpret=interpret))
+                return branch
+
+            kw = dict(value_dim=cfg.kv_lora_rank, scale=scale)
+
+            def walk(scores):  # the set as a mask over the walk of the rows' own pages
+                with jax.named_scope("dsa_select"):
+                    keep = dsa.keep_topk(scores, topk)
+                with jax.named_scope("dsa_attend"):
                     if impl == "xla":
-                        scores = dsa.index_scores_xla(q_idx, w, ik, ci, block_tables, lengths)
-                    else:
-                        scores = dsa.index_scores(q_idx, w, ik, ci, block_tables, lengths, interpret=interpret)
+                        return latent_decode_attention_xla(q, kv, ci, block_tables, lengths, keep=keep, **kw)
+                    return dsa.masked_decode_attention(q, kv, ci, block_tables, lengths, keep, interpret=interpret, **kw)
+
+            def gather(scores):  # the set as positions, their rows gathered and attended alone
                 with jax.named_scope("dsa_select"):
                     picked = dsa.select(scores, topk)
                 with jax.named_scope("dsa_attend"):
                     counts = jnp.minimum(lengths, topk)
                     if impl == "xla":
-                        return dsa.sparse_decode_attention_xla(q, kv, ci, block_tables, picked, counts,
-                                                               value_dim=cfg.kv_lora_rank, scale=scale)
-                    return dsa.sparse_decode_attention(q, kv, ci, block_tables, picked, counts,
-                                                       value_dim=cfg.kv_lora_rank, scale=scale, interpret=interpret)
+                        return dsa.sparse_decode_attention_xla(q, kv, ci, block_tables, picked, counts, **kw)
+                    return dsa.sparse_decode_attention(q, kv, ci, block_tables, picked, counts, interpret=interpret, **kw)
 
             with jax.named_scope("mla_attn"):
                 if every_row_dense:
                     o = dense()
-                else:
-                    o = lax.cond(jnp.max(lengths) <= topk, dense, chosen)
+                else:  # no row past index_topk: every token; else the set, by the form the call's lengths make cheaper
+                    walks = dsa.walk_is_cheaper(lengths, B, block_tables.shape[1] * bs, topk)
+                    form = jnp.where(jnp.max(lengths) <= topk, 0, jnp.where(walks, 1, 2))
+                    o = lax.switch(form, [dense, chosen(walk), chosen(gather)])
                 return unabsorb_output(o, lp, cfg), cache._replace(kv=kv, ikeys=ik)
         return attend
 

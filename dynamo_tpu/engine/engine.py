@@ -67,6 +67,7 @@ from dynamo_tpu.engine.grammar import (
 from dynamo_tpu.engine.model import block_module, refuse_block
 from dynamo_tpu.engine.runner import host_ready, start_host_fetch
 from dynamo_tpu.engine.sampler import needs_full, row_needs_full
+from dynamo_tpu.ops import dsa
 from dynamo_tpu.ops.sparse_attention import choice_counts
 from dynamo_tpu.kv_router.protocols import ForwardPassMetrics, KvCacheEvent, KvStats, WorkerStats
 from dynamo_tpu.llm.protocols import (
@@ -721,6 +722,18 @@ def register_engine_metrics(registry) -> dict:
             "tokens, whose choice is every token",
         ),
         registry.counter(
+            "engine_dsa_decode_steps_total",
+            "Decode steps of a block='dots3' model some row of which saw more "
+            "than index_topk tokens: the full layers chose and attended a set",
+        ),
+        registry.counter(
+            "engine_dsa_decode_walk_steps_total",
+            "Of those steps, the ones whose chosen sets were attended as a "
+            "mask over the walk of the rows' own pages (ops/dsa.py: "
+            "walk_is_cheaper of the step's lengths, as the program evaluates "
+            "it); the rest gathered their chosen rows",
+        ),
+        registry.counter(
             "engine_window_blocks_released_total",
             "Window-pool blocks (block='dots3': the window layers' pages) "
             "that sequences gave back, by where they went: cached = sealed and "
@@ -1069,7 +1082,8 @@ class TpuEngine:
         # any other block.
         self.window_stats: dict[str, int] | None = (
             {"deepest": 0, "cut_back": 0, "miss": 0, "recomputed_tokens": 0,
-             "chosen": 0, "visible": 0, "dense": 0} if self.window_pool is not None else None)
+             "chosen": 0, "visible": 0, "dense": 0, "steps": 0, "walk_steps": 0}
+            if self.window_pool is not None else None)
         # Prefill dispatches by their program's rows (a chunk of a chunked
         # prefill is a dispatch of one row): engine_prefill_dispatch_rows_total.
         self.prefill_dispatch_rows: dict[int, int] = collections.defaultdict(int)
@@ -1137,6 +1151,8 @@ class TpuEngine:
             feed("engine_dsa_chosen_tokens_total", ws["chosen"])
             feed("engine_dsa_visible_tokens_total", ws["visible"])
             feed("engine_dsa_dense_rows_total", ws["dense"])
+            feed("engine_dsa_decode_steps_total", ws["steps"])
+            feed("engine_dsa_decode_walk_steps_total", ws["walk_steps"])
         g["engine_kv_quant_enabled"].set(1 if self.args.kv_quant == "int8" else 0)
         g["engine_prefill_pad_ratio"].set(
             self.total_prefill_padded / max(1, self.total_prefilled))
@@ -2463,10 +2479,11 @@ class TpuEngine:
                 row[1 + i - lo] = bid
         return row
 
-    def _decode_window_tables(self, batch: list[_Seq], pos0: list[int], B: int, K: int) -> np.ndarray:
+    def _decode_window_tables(self, batch: list[_Seq], pos0: list[int], B: int, K: int, W: int) -> np.ndarray:
         """The rows' window tables for a decode dispatch of ``K`` steps from
-        ``pos0`` (``_ensure_block`` has covered them), and what the full
-        layers' rows choose: the engine_dsa_* books."""
+        ``pos0`` in the ``B``-row program at a table of ``W`` blocks
+        (``_ensure_block`` has covered them), and what the full layers' rows
+        choose and how each step attends it: the engine_dsa_* books."""
         state = np.zeros((B, 1 + self.args.window_table_width), np.int32)
         for i, (seq, p0) in enumerate(zip(batch, pos0)):
             state[i] = self._window_row(seq, p0, self.args.window_table_width)
@@ -2475,6 +2492,10 @@ class TpuEngine:
         ws["visible"] += int(seen.sum())
         ws["chosen"] += int(np.minimum(seen, topk).sum())
         ws["dense"] += int((seen <= topk).sum())
+        for lengths in seen.T:  # a step's rows, as the program sees them (engine/dots3.py:decode_step_impl)
+            if lengths.max() > topk:
+                ws["steps"] += 1
+                ws["walk_steps"] += bool(dsa.walk_is_cheaper(lengths, B, W * self.args.block_size, topk))
         return state
 
     def _prefill_packed(
@@ -3442,7 +3463,7 @@ class TpuEngine:
         aslots = self._adapter_row_slots(batch, B)
         state_kw = {"state": self._decode_state(batch, pos0, B, K)} if self.state_stats is not None else {}
         if self.window_stats is not None:
-            state_kw = {"state": self._decode_window_tables(batch, pos0, B, K)}
+            state_kw = {"state": self._decode_window_tables(batch, pos0, B, K, W)}
         self._enter("decode_dispatch")
         self._dispatching()
         ref = self._runner.multi_decode(
@@ -3897,7 +3918,7 @@ class TpuEngine:
             state_kw["state"] = self._decode_state(batch, [int(p) for p in positions[: len(batch)]], B, 1)
         if self.window_stats is not None:
             state_kw["state"] = self._decode_window_tables(
-                batch, [int(p) for p in positions[: len(batch)]], B, 1)
+                batch, [int(p) for p in positions[: len(batch)]], B, 1, W)
         self._dispatching()
         ref = self._runner.decode_step(tokens, positions, tables, active, aslots, **state_kw)
         self._dispatched(ref.arrs)

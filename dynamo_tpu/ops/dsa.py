@@ -15,9 +15,17 @@ one token's row.
 - ``select``: the exact ``topk`` of a row (``lax.top_k``: the lower position
   first at a tie), highest first, so a row with fewer visible positions than
   ``topk`` has them all in front.
-- ``sparse_decode_attention`` (``latent_sparse_decode_attention`` in a trace):
-  the chosen rows gathered out of the latent pool into ``[B, topk, Dk]`` and the
-  latent decode kernel over that as a dense table of its own; its XLA twin.
+- A decode row's chosen set reaches the attention in one of two forms, both
+  ``latent_sparse_decode_attention`` in a trace, and ``walk_is_cheaper`` says
+  which a call's rows take:
+  ``sparse_decode_attention``, the gather form: ``select``'s positions, the
+  chosen rows gathered out of the latent pool into ``[B, topk, Dk]`` and the
+  latent decode kernel over that as a dense table of its own (a sort of the
+  table's width and ``topk`` row gathers a bucket row, whatever its length);
+  ``masked_decode_attention``, the walk: ``keep_topk``'s mask as one more
+  condition of the latent decode kernel's walk over the row's own pages (no
+  sort, no gather; what a live row's length costs). Each has its XLA twin
+  (the walk's is ``latent_decode_attention_xla(keep=)``).
 - ``prefill_keep``: a prefill chunk's choice as a mask ``[B, T, W*bs]`` for
   ``latent_prefill_attention(keep=)``: the scores a head at a time into float32
   ``[T, W*bs]``, each query's ``topk``-th highest by a search over the bits
@@ -182,6 +190,56 @@ def sparse_decode_attention(q, cache, layer_idx, block_tables, chosen, counts, *
         q[:, None, None], rows, jnp.int32(0), own, jnp.asarray(counts, jnp.int32)[:, None], None, None,
         0, interpret, value_dim=value_dim, scale=scale, name="latent_sparse_decode_attention")
     return o[:, 0, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale", "interpret"))
+def masked_decode_attention(q, cache, layer_idx, block_tables, lengths, keep, *,
+                            value_dim: int, scale: float, interpret: bool = False):
+    """q [B, H, Dk] over the positions of each row's table that ``keep``
+    [B, W*bs] marks, of the first ``lengths`` [B] → [B, H, value_dim]: the
+    latent decode kernel's walk over the row's own pages
+    (``latent_sparse_decode_attention`` in a trace, as the gather form is: one
+    call attends a call's chosen sets either way), ``keep`` one more condition
+    on a position beside the row's length: a dead row costs a grid step, a live
+    one its length, the table's width nothing. Its XLA twin is
+    ``latent_decode_attention_xla(keep=)``."""
+    o = _paged_attention_mq(
+        q[:, None, None], cache, layer_idx, block_tables, jnp.asarray(lengths, jnp.int32)[:, None], None, None,
+        0, interpret, value_dim=value_dim, scale=scale, keep=keep.astype(jnp.int32),
+        name="latent_sparse_decode_attention")
+    return o[:, 0, 0]
+
+
+# What the two forms cost a full layer's call on a v5e: step 0 of PR 48 (PERF.md
+# section 5: one layer in the 32-row bucket, 8, 18, 24 and 32 rows live at 16,384,
+# 24,576 and 32,768 tokens, 2,048 chosen of a 32,768-position table; device us a
+# call from a profiler trace, twelve shapes). Another device's are not known: it
+# takes these.
+# The masked walk read 371.9 us at 131,072 live tokens and 2,789.9 at 1,048,576,
+# on one line through all twelve: 26 us and 2.64 ns a token a live row holds.
+WALK_NS_PER_TOKEN = 2.64
+# The row gather and the latent kernel over the gathered rows read 1,726 us at 8
+# live rows and 1,860 at 32, for 65,536 gathered rows either way: 25.7 ns a
+# chosen row of every row of the bucket, and 5.6 us a live row; 28.4 ns a chosen
+# row at a full bucket, which is where the two forms meet.
+GATHER_NS_PER_CHOSEN_ROW = 28.4
+# ``select``'s sort read 752.7 us over [32, 32768] whatever is live (0.718 ns a
+# position of the table a bucket row) and ``keep_topk`` 162.6 (0.155): the
+# walk's choice is taken off the gather form's here, so the rule has one term a side.
+SORT_NS_PER_POSITION = 0.563
+
+
+def walk_is_cheaper(lengths, B: int, width: int, topk: int):
+    """Whether a call's rows (``lengths`` [B], 0 a dead row of the bucket's
+    ``B``; a table ``width`` positions wide) attend their chosen sets cheaper by
+    the walk than by the gather. The walk pays for the tokens its live rows
+    hold; the gather form pays ``topk`` row gathers and a sort of the table's
+    width for every row of the bucket, live or not, and nothing for length.
+    Plain arithmetic on ``lengths.sum()`` against a static whole number, so a
+    traced int32 array and the host's numpy array of the same lengths give the
+    same answer."""
+    gather_ns = B * (topk * GATHER_NS_PER_CHOSEN_ROW + width * SORT_NS_PER_POSITION)
+    return lengths.sum() < min(int(gather_ns / WALK_NS_PER_TOKEN), 2 ** 31 - 1)
 
 
 def _sortable(x: jax.Array) -> jax.Array:

@@ -362,7 +362,7 @@ def _mq_kernel(
     rowlen_ref,   # [B] int32 — max attend length per row (chunk walk bound)
     nextrow_ref,  # [B] int32 — the first non-empty row after b (B if none)
     tables_ref,   # [B, W] int32
-    # operands (anc present only in tree mode; kscale/vscale when quantized)
+    # operands (anc present only in tree mode; keep when ``kept``; kscale/vscale when quantized)
     *refs,
     # static
     pages_per_chunk: int,
@@ -371,6 +371,7 @@ def _mq_kernel(
     tree_slots: int = 0,
     value_dim: int = 0,
     window: int = 0,
+    kept: bool = False,
 ):
     # One pool either way, and a page of it one DMA descriptor. value_dim 0:
     # a page is K then V, ``[2, bs, KVH*hd]``. value_dim > 0: a latent (MLA)
@@ -379,9 +380,11 @@ def _mq_kernel(
     refs = list(refs)
     q_ref, lenvec_ref = refs[:2]
     refs = refs[2:]
-    anc_ref = kscale_ref = vscale_ref = ml_scr = None
+    anc_ref = keep_ref = kscale_ref = vscale_ref = ml_scr = None
     if tree_slots:
         anc_ref, refs = refs[0], refs[1:]
+    if kept:
+        keep_ref, refs = refs[0], refs[1:]
     if quantized:
         kscale_ref, vscale_ref, *refs, ml_scr = refs
     kv_hbm, o_ref, kvbuf, acc_scr, slot_ref, started_ref, sem = refs
@@ -391,6 +394,8 @@ def _mq_kernel(
     #            tree mode the per-column HISTORY horizon (slots ride on top)
     # anc_ref    VMEM [1, H, T] int32 — tree mode: anc[col, s] = query col
     #            may attend in-flight slot s (its ancestor-or-self set)
+    # keep_ref   VMEM [1, 1, W*bs] — nonzero: the row's columns attend that
+    #            position of its table (a chosen set), read a chunk at a time
     # kscale_ref VMEM [1, P, bs, KVH] f32 — this chunk's per-position-per-head scales
     # kv_hbm     ANY  [L, N, 2, bs, KVH*hd] ([2L, N, bs, Dk] latent)
     # o_ref      VMEM [1, H, Dv] — attention out, every head's lanes a row
@@ -493,6 +498,8 @@ def _mq_kernel(
             anc = anc_ref[0]                               # [H, T]
             for s_i in range(tree_slots):
                 att = att | ((pos == lenvec + s_i) & (anc[:, s_i:s_i + 1] != 0))
+        if kept:  # of its horizon, a column attends the positions the operand marks
+            att = att & (keep_ref[0, :, pl.ds(pl.multiple_of(c * CH, CH), CH)] != 0)
         s = jnp.where(att, s, NEG_INF)
 
         m_cur = jnp.max(s, axis=1, keepdims=True)          # [H, 1]
@@ -625,11 +632,14 @@ def _paged_attention_mq(
     value_dim: int = 0,            # latent pool: V = a row's first lanes
     scale: float | None = None,    # softmax scale (default hd ** -0.5)
     window: int = 0,               # > 0: a query attends its last ``window`` positions only
+    keep: jax.Array | None = None, # [B, W*bs]: nonzero where the row's queries attend that position
     name: str | None = None,       # the kernel's name in a trace (default: the caller's jit)
 ) -> jax.Array:
     """Shared Pallas driver: T query positions per row walk the row's
     true pages once. Returns [B, T, KVH, G, hd] in q.dtype
-    ([B, T, 1, G, value_dim] over a latent pool)."""
+    ([B, T, 1, G, value_dim] over a latent pool). ``keep`` narrows every
+    horizon to the positions it marks (a chosen set): the walk stays that of
+    the row's length, a masked position's weight is exactly 0."""
     B, T, KVH, G, hd = q.shape
     page = kv_cache.shape[2:]  # (2, bs, KVH*hd), or a latent (bs, Dk)
     bs = page[-2]
@@ -699,6 +709,12 @@ def _paged_attention_mq(
         ).reshape(B, H, T)
         operands.append(jnp.pad(anc_cols, ((0, 0), (0, Hp - H), (0, 0))))
         in_specs.append(row_block(Hp, T))
+    if keep is not None:
+        # A row's whole mask rides as one block of its grid step, the table's
+        # padded width wide, and a chunk reads its own CH lanes of it.
+        C = block_tables.shape[1] * bs
+        operands.append(jnp.pad(keep, ((0, 0), (0, C - keep.shape[1])))[:, None])
+        in_specs.append(row_block(1, C))
     if quantized:
         # Scales are gathered OUTSIDE the kernel ([B, W, bs, KVH] fp32 is
         # 1/head_dim the page bytes) and ride as per-CHUNK VMEM blocks, so
@@ -733,6 +749,7 @@ def _paged_attention_mq(
         _mq_kernel, pages_per_chunk=P, head_dim=hd, quantized=quantized,
         tree_slots=T if anc is not None else 0, value_dim=value_dim,
         **({"window": window} if window else {}),
+        **({"kept": True} if keep is not None else {}),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -1174,16 +1191,19 @@ def latent_decode_attention_xla(
     layer_idx: jax.Array,    # scalar int32 — cache layer (2*layer + sub-block)
     block_tables: jax.Array, # [B, W] int32
     lengths: jax.Array,      # [B] int32
-    *, value_dim: int, scale: float, window: int = 0,
+    *, value_dim: int, scale: float, window: int = 0, keep: jax.Array | None = None,
 ) -> jax.Array:
     """Gather-based reference of the latent decode attention → [B, H, value_dim].
-    ``window`` > 0: a row attends its last ``window`` positions only."""
+    ``window`` > 0: a row attends its last ``window`` positions only; ``keep``
+    [B, W*bs]: of its context, the positions it marks (a chosen set)."""
     B, H, Dk = q.shape
     pk = _gather_pages(cache, layer_idx, block_tables).reshape(B, -1, Dk)  # [B, W*bs, Dk]
     ctx = jnp.arange(pk.shape[1], dtype=jnp.int32)
     seen = ctx[None, :] < lengths[:, None]
     if window:
         seen &= ctx[None, :] >= lengths[:, None] - window
+    if keep is not None:
+        seen &= keep != 0
     mask = jnp.where(seen, 0.0, jnp.float32(NEG_INF))
     s = jnp.einsum("bhd,bcd->bhc", q, pk).astype(jnp.float32) * scale
     p = jax.nn.softmax(s + mask[:, None, :], axis=-1).astype(q.dtype)

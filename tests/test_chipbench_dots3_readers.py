@@ -12,6 +12,7 @@ from chipbench.layer_metrics import (
     dsa_chosen_share,
     dsa_decode_attn_roofline,
     dsa_index_roofline,
+    dsa_walk_share,
     moe_expert_roofline_share,
     window_resume_share,
 )
@@ -26,10 +27,13 @@ P = "dynamo_tpu_engine_"
 MOE = "dynamo_tpu_moe_"
 
 
-def pages(chosen=2048.0 * 40, visible=24000.0 * 40, resumes=(9.0, 1.0, 0.0), touched=480.0, calls=64.0) -> dict:
+def pages(chosen=2048.0 * 40, visible=24000.0 * 40, resumes=(9.0, 1.0, 0.0), touched=480.0, calls=64.0,
+          steps=(30.0, 40.0)) -> dict:
     after = {}
     if chosen is not None:
         after.update({P + "dsa_chosen_tokens_total": chosen, P + "dsa_visible_tokens_total": visible})
+    if steps is not None:
+        after.update({P + "dsa_decode_walk_steps_total": steps[0], P + "dsa_decode_steps_total": steps[1]})
     if resumes is not None:
         after.update({P + f'window_resume_total{{outcome="{o}"}}': n
                       for o, n in zip(("deepest", "cut_back", "miss"), resumes)})
@@ -80,6 +84,8 @@ def test_the_readers_divide_the_yardsticks_least_by_the_kernels_seconds():
     assert moe_expert_roofline_share.read(ctx()) == pytest.approx(100 * 8 * least_call / 0.008)
     assert dsa_chosen_share.read(ctx()) == pytest.approx(100 * 2048 / 24000)
     assert window_resume_share.read(ctx()) == pytest.approx(90.0)
+    assert dsa_walk_share.read(ctx()) == pytest.approx(75.0)
+    assert dsa_walk_share.read(ctx(prom=pages(steps=(0.0, 8.0)))) == 0.0  # every step gathered: a number, not None
     for reader in (dsa_decode_attn_roofline, dsa_index_roofline, moe_expert_roofline_share):
         assert 0 < reader.read(ctx()) <= 100
 
@@ -98,6 +104,9 @@ def test_the_readers_divide_the_yardsticks_least_by_the_kernels_seconds():
     (moe_expert_roofline_share, dict(config=LONGCAT)),
     (dsa_chosen_share, dict(prom=pages(chosen=None))),
     (dsa_chosen_share, dict(prom={})),
+    (dsa_walk_share, dict(prom=pages(steps=None))),      # the parent of the PR that added it: no such series
+    (dsa_walk_share, dict(prom={})),
+    (dsa_walk_share, dict(prom=pages(steps=(0.0, 0.0)))),  # no decode step past index_topk in the window
     (window_resume_share, dict(prom=pages(resumes=None))),
     (window_resume_share, dict(prom=pages(resumes=(0.0, 0.0, 0.0)))),
 ])

@@ -282,6 +282,154 @@ def test_a_row_at_or_under_index_topk_attends_as_dense_mla(impl):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
+# -- a decode row's chosen set, as a mask over the walk or as gathered rows ------------
+
+
+def _tie_scores(lengths, width: int, seed: int):
+    """Scores of few distinct values, so the ``topk``-th is tied many ways and
+    the ties lie on both sides of page boundaries; ``NEG_INF`` past a length."""
+    s = jnp.asarray(np.random.RandomState(seed).randint(-2, 3, (len(lengths), width)).astype(np.float32))
+    return jnp.where(jnp.arange(width)[None] < jnp.asarray(lengths)[:, None], s, dsa.NEG_INF)
+
+
+CHOSEN_CASES = {
+    # lengths of the rows, blocks of their table
+    "rows_under_and_over_index_topk": ([40, 5, CFG.index_topk, 64], 8),
+    "a_dead_row": ([40, 0, 33, 0], 8),
+    "a_table_wider_than_any_row": ([30, 17, 25, 14], 24),
+    "one_row_to_the_tables_end": ([64, 13, 0, 1], 8),
+}
+
+
+@pytest.mark.parametrize("scores", ["distinct", "tied_across_pages"])
+@pytest.mark.parametrize("case", CHOSEN_CASES)
+def test_the_masked_walk_attends_the_set_the_gather_form_attends(case, scores):
+    """``keep_topk``'s mask over the latent decode kernel's walk against the
+    XLA form over ``select``'s gathered rows, and against the gather form's
+    kernel: one set, one softmax, the sums in another order. Both kernels in
+    interpret mode; a dead row's output is nobody's."""
+    lengths, W = CHOSEN_CASES[case]
+    B, topk, width = len(lengths), CFG.index_topk, W * BS
+    pool = _latent_pool(CFG, 1 + B * W, 11)
+    tables = jnp.asarray(np.random.RandomState(3).permutation(np.arange(1, 1 + B * W)).reshape(B, W), jnp.int32)
+    q = jax.random.normal(jax.random.PRNGKey(12), (B, CFG.num_heads, CFG.latent_page_width), jnp.float32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    if scores == "distinct":
+        sc = jnp.where(jnp.arange(width)[None] < lens[:, None], jax.random.normal(jax.random.PRNGKey(13), (B, width)), dsa.NEG_INF)
+    else:
+        sc = _tie_scores(lengths, width, 14)
+        kth = np.sort(np.asarray(sc[0]))[::-1][topk - 1]
+        tied = np.flatnonzero(np.asarray(sc[0]) == kth)
+        assert len(tied) > 2 and tied[0] // BS != tied[-1] // BS  # the k-th score ties across a page boundary
+    picked, counts, keep = dsa.select(sc, topk), jnp.minimum(lens, topk), dsa.keep_topk(sc, topk)
+    kw = dict(value_dim=CFG.kv_lora_rank, scale=0.2)
+    want = dsa.sparse_decode_attention_xla(q, pool, 1, tables, picked, counts, **kw)
+    gathered = dsa.sparse_decode_attention(q, pool, 1, tables, picked, counts, interpret=True, **kw)
+    walked = dsa.masked_decode_attention(q, pool, 1, tables, lens, keep, interpret=True, **kw)
+    twin = pa.latent_decode_attention_xla(q, pool, 1, tables, lens, keep=keep, **kw)
+    live = np.asarray(lens) > 0
+    for got in (walked, twin, gathered):
+        np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live], rtol=2e-5, atol=2e-5)
+    # the mask's live part is the gathered positions, to the position
+    for b in np.flatnonzero(live):
+        n = int(counts[b])
+        assert set(np.flatnonzero(np.asarray(keep[b, :lengths[b]]))) == set(np.asarray(picked[b, :n]).tolist())
+    assert not np.asarray(walked)[~live].any()  # a dead row is one empty grid step
+    over = np.flatnonzero(np.asarray(lens) > topk)
+    if len(over):  # and the choice does cut those rows' context
+        dense = pa.latent_decode_attention_xla(q, pool, 1, tables, lens, **kw)
+        assert np.abs(np.asarray(dense - want))[over].max() > 1e-3
+
+
+def _rule_width(topk: int) -> int:
+    """The least table width, in positions, at which full rows tip the rule to
+    the gather form (``dsa``'s constants: a position's walk against its share
+    of the sort, the chosen rows' gathers whatever the length)."""
+    return int(topk * dsa.GATHER_NS_PER_CHOSEN_ROW / (dsa.WALK_NS_PER_TOKEN - dsa.SORT_NS_PER_POSITION)) + 1
+
+
+def test_walk_is_cheaper_at_the_corners_of_step_0s_table():
+    """The cell's own calls walk; a full bucket at the table's whole width is
+    where the forms meet; the walk grows with what is live and the gather form
+    with the bucket and the table."""
+    B, width, topk = 32, 32768, 2048
+    def rule(live, length, B=B, width=width):
+        return bool(dsa.walk_is_cheaper(np.asarray([length] * live + [0] * (B - live)), B, width, topk))
+    assert rule(18, 24576) and rule(18, 32000) and rule(24, 24576) and rule(8, 32768)  # the cell: 18.7 rows live
+    assert rule(1, 32768) and rule(32, 2049)                                           # one long row; a full bucket of short rows
+    # what is live pays for the walk and what is padded for the gather: 24 rows
+    # of a 24-row call (PR 47's arithmetic) stand nearer the line than 18 of 32
+    full = int(B * (topk * dsa.GATHER_NS_PER_CHOSEN_ROW + width * dsa.SORT_NS_PER_POSITION) / dsa.WALK_NS_PER_TOKEN)
+    assert rule(32, full // 32 - 1) and not rule(32, full // 32 + 1)
+    assert 0.5 < full / (32 * 32768) < 1.5  # about level for a full batch at 32k
+    # the published 524,288 positions: rows that long gather
+    assert not rule(32, 400_000, width=524_288) and rule(2, 400_000, width=524_288)
+    # the traced predicate is the host's, on the same lengths
+    for lengths in ([24576] * 18 + [0] * 14, [32768] * 32, [full // 32 + 1] * 32, [full // 32 - 1] * 32):
+        host = bool(dsa.walk_is_cheaper(np.asarray(lengths), B, width, topk))
+        traced = bool(jax.jit(lambda l: dsa.walk_is_cheaper(l, B, width, topk))(jnp.asarray(lengths, jnp.int32)))
+        assert host == traced, lengths
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("rows", ["short_rows_walk", "long_rows_gather"])
+def test_a_decode_step_gives_the_same_logits_whichever_form_the_rule_takes(rows, impl, monkeypatch):
+    """One decode step of the block over pages a prefill wrote, the rule
+    decided by the rows' lengths alone (short rows in a wide table walk, rows
+    that fill it gather), against the same step with the rule turned round."""
+    topk = CFG.index_topk
+    W = -(-_rule_width(topk) // BS) + 2
+    assert W * BS <= DOC["max_position_embeddings"]
+    long = W * BS - 3
+    lengths = {"short_rows_walk": [40, 21], "long_rows_gather": [long, long - 9]}[rows]
+    params = program_params(REF.weights(doc_for("float32"), 0))
+    cache = dots3.init_kv_cache(CFG, 1 + 2 * W, BS, jnp.float32, window_blocks=1 + 2 * W)
+    tables = jnp.arange(1, 1 + 2 * W, dtype=jnp.int32).reshape(2, W)
+    state = jnp.concatenate([jnp.zeros((2, 1), jnp.int32), tables], axis=1)
+    kw = {"attn_impl": impl, **({"experts": "gmm_interpret"} if impl == "pallas_interpret" else {})}
+    T = -(-max(lengths) // 16) * 16
+    toks = jnp.asarray([_pad(prompt(n - 1, seed=n), T) for n in lengths])
+    _, cache, _ = dots3.prefill_batch(CFG, params, cache, toks, tables, jnp.zeros((2,), jnp.int32),
+                                      jnp.asarray(lengths, jnp.int32) - 1, state_slots=state, attn_impl="xla")
+    args = (jnp.asarray([3, 5], jnp.int32), jnp.asarray(lengths, jnp.int32) - 1, tables, jnp.asarray([True, True]))
+    want_walk = rows == "short_rows_walk"
+    assert bool(dsa.walk_is_cheaper(np.asarray(lengths), 2, W * BS, topk)) == want_walk
+
+    def step():
+        fn = jax.jit(lambda c: dots3.decode_step_impl(CFG, params, c, *args, state_slots=state, **kw)[0])
+        return np.asarray(fn(cache))
+
+    by_rule = step()
+    monkeypatch.setattr(dsa, "walk_is_cheaper", lambda lengths, *a: lengths.sum() < 0 if want_walk else lengths.sum() >= 0)
+    turned = step()
+    np.testing.assert_allclose(by_rule, turned, rtol=2e-4, atol=2e-4)
+    assert np.isfinite(by_rule).all() and np.abs(by_rule).max() > 0.1
+
+
+def test_the_hosts_count_of_walked_steps_is_the_programs_predicate():
+    """``engine_dsa_decode_{steps,walk_steps}_total``: what the scheduler books
+    for a window is the rule on each step's lengths as the program forms them."""
+    from types import SimpleNamespace
+
+    stats = {"chosen": 0, "visible": 0, "dense": 0, "steps": 0, "walk_steps": 0}
+    topk, bs, B, W, K = 2048, 32, 4, 1024, 8
+    engine = SimpleNamespace(window_stats=stats, cfg=SimpleNamespace(index_topk=topk, sliding_window=513),
+                             args=SimpleNamespace(block_size=bs, window_table_width=18),
+                             _window_row=lambda seq, p0, width: np.zeros((1 + width,), np.int32))
+    want_steps = want_walk = 0
+    for pos0 in ([24000, 30000, 100], [2040, 2043], [32700] * 4, [5, 9]):
+        TpuEngine._decode_window_tables(engine, [None] * len(pos0), pos0, B, K, W)
+        for j in range(K):
+            positions = jnp.asarray(pos0 + [0] * (B - len(pos0)), jnp.int32) + j
+            active = jnp.arange(B) < len(pos0)
+            lengths = jnp.where(active, positions + 1, 0)  # engine/dots3.py:decode_step_impl
+            if int(jnp.max(lengths)) > topk:
+                want_steps += 1
+                want_walk += bool(dsa.walk_is_cheaper(lengths, B, W * bs, topk))
+    assert (stats["steps"], stats["walk_steps"]) == (want_steps, want_walk)
+    assert 0 < want_walk < want_steps == 8 + 3 + 8  # four rows at the table's end gather; rows at 2,041 pass index_topk mid-window
+
+
 # -- the shares add up -----------------------------------------------------------
 
 

@@ -856,15 +856,29 @@ def _dots3():
     return model_maps.model_config(doc), engine_args(doc)
 
 
-@pytest.mark.parametrize("program", ["index_scores", "chosen_rows", "window_decode", "window_prefill", "keep_prefill",
-                                     "decode_window", "prefill_chunk_2048", "prefill_packed_2x256"])
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """A compiled program's text by computation: name -> its lines."""
+    out, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.-]+) \(.*\{$", line)
+        if m:
+            cur = out.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    return out
+
+
+@pytest.mark.parametrize("program", ["index_scores", "chosen_rows", "masked_walk", "window_decode", "window_prefill",
+                                     "keep_prefill", "decode_window", "prefill_chunk_2048", "prefill_packed_2x256"])
 def test_dots3_programs_compile_for_v5e(v5e, program):
     """What the dots3 cell runs, at the published widths, the cell's pools
     (18,432 blocks of latents and index keys, the window pool) and its
     32,768-token table: the two new kernels at the decode window's call (32
-    rows), the latent kernels at the second geometry under the window's mask
-    and under a chosen set's, then the decode window, a 2,048-token chunk and a
-    pack of two rows. No program copies a pool (the chosen rows are gathered as
+    rows), the chosen set as a mask over the decode kernel's walk, the latent
+    kernels at the second geometry under the window's mask and under a chosen
+    set's, then the decode window (both forms of the chosen-rows attend, a
+    branch each: the walk's side holds no ``[B, topk, Dk]`` rows), a
+    2,048-token chunk and a pack of two rows. No program copies a pool (the chosen rows are gathered as
     rows of lanes, each with its own block and slot), nothing in a prefill has
     the 128 heads' absorbed queries of a whole chunk, and a chunk's temporaries
     leave room in 16 GB beside 9.2 GB of weights and 3.4 GB of pools."""
@@ -881,6 +895,9 @@ def test_dots3_programs_compile_for_v5e(v5e, program):
         "index_scores": lambda: dsa.index_scores.lower(bf16(B, 64, 128), f32(B, 64), bf16(3, N, LBS, 128), i32(), i32(B, W), i32(B)),
         "chosen_rows": lambda: dsa.sparse_decode_attention.lower(
             bf16(B, 128, 640), bf16(3, N, LBS, 640), i32(), i32(B, W), i32(B, 2048), i32(B), value_dim=512, scale=0.07),
+        "masked_walk": lambda: dsa.masked_decode_attention.lower(
+            bf16(B, 128, 640), bf16(3, N, LBS, 640), i32(), i32(B, W), i32(B), S((B, W * LBS), jnp.bool_),
+            value_dim=512, scale=0.07),
         "window_decode": lambda: latent_decode_attention.lower(
             bf16(B, 64, 1152), bf16(6, Nw, LBS, 1152), i32(), i32(B, 18), i32(B), value_dim=1024, scale=0.06, window=513),
         "window_prefill": lambda: jax.jit(functools.partial(latent_prefill_attention, scale=0.06, window=513)).lower(
@@ -903,7 +920,14 @@ def test_dots3_programs_compile_for_v5e(v5e, program):
             None, None, attn_impl="pallas", experts="gmm", state_slots=i32(B, 1 + args.window_table_width),
         ).compile()
         hlo = compiled.as_text()
-        assert "dsa_index_scores" in hlo and "latent_sparse_decode_attention" in hlo
+        assert "dsa_index_scores" in hlo
+        # The chosen-rows attend is one kernel call a branch of the rule's
+        # ``cond``, in layer 0 and in the scanned full layer: the gather form's
+        # branch holds the gathered rows, the walk's nothing of their size.
+        sides = [lines for lines in _computations(hlo).values()
+                 if any("custom-call(" in ln and "latent_sparse_decode_attention" in ln for ln in lines)]
+        gathered = re.compile(rf"\[{B},{cfg.index_topk},640\]|\[{B * cfg.index_topk},640\]")
+        assert sorted(any(gathered.search(ln) for ln in lines) for lines in sides) == [False, False, True, True]
         limit = 1.0e9  # 0.81 GB by the compiler's analysis
     else:
         rows, t = (1, 2048) if program == "prefill_chunk_2048" else (2, 256)
