@@ -64,7 +64,11 @@ router's view are of the full-layer chain alone), under the same policy:
   (``free_sequence(cold=True)``): it is the first to be evicted. What may be
   resumed from goes to the warm end: the blocks a sequence holds when it
   finishes or is preempted, those before the end of a shared prompt, and those
-  an admission claimed as a hit.
+  an admission claimed as a hit. Of the last, the blocks before a block that a
+  second chain continues from (the full-layer pool's ``hash_fanout``: a
+  document's end, where sessions start over) are given back **spared**
+  (``free_sequence(spare=True)``): the LRU evicts them only when nothing else
+  is left, as a snapshot at a branch point is; at most a quarter of the pool.
 - A prefix hit is only as deep as the deepest block of the chain that has its
   full-layer pages **and** whose ``back`` preceding blocks are all cached here
   (``window_depth``, beside ``snapshot_depth``: one rule, two kinds of second
@@ -141,6 +145,9 @@ class BlockPool:
         # evicted for their page (what a window pool's counters read).
         self.released = {"cached": 0, "free": 0}
         self.evictions = 0
+        # Cached blocks the LRU passes over while it holds any other (a window
+        # pool's blocks before a shared prompt's end).
+        self._spared: set[int] = set()
 
     # -- events -----------------------------------------------------------
 
@@ -239,7 +246,11 @@ class BlockPool:
         if self._free:
             bid = self._free.pop()
         elif self._lru:
-            bid, _ = self._lru.popitem(last=False)  # oldest
+            # Oldest first, a spared one only when nothing else is left.
+            bid = next((b for b in self._lru if b not in self._spared), None) if self._spared else None
+            if bid is None:
+                bid = next(iter(self._lru))
+            del self._lru[bid]
             self._evict(bid)
         else:
             raise NoFreeBlocksError("pool exhausted")
@@ -251,6 +262,7 @@ class BlockPool:
 
     def _evict(self, bid: int) -> None:
         b = self._blocks[bid]
+        self._spared.discard(bid)
         if b.seq_hash is not None:
             self.evictions += 1
             self._drop_snapshot(b.seq_hash)
@@ -274,8 +286,9 @@ class BlockPool:
         b.ref_count += 1
         if b.ref_count == 1:
             self._lru.pop(bid, None)
+            self._spared.discard(bid)
 
-    def _unref(self, bid: int, cold: bool = False) -> None:
+    def _unref(self, bid: int, cold: bool = False, spare: bool = False) -> None:
         b = self._blocks[bid]
         b.ref_count -= 1
         if b.ref_count > 0:
@@ -283,6 +296,8 @@ class BlockPool:
         if b.seq_hash is not None and self.enable_prefix_caching:
             self._lru[bid] = None  # retained, evictable: the newest, or (cold) the first to go
             self._lru.move_to_end(bid, last=not cold)
+            if spare and 4 * len(self._spared) < self.num_blocks:
+                self._spared.add(bid)
             self.released["cached"] += 1
         else:
             b.seq_hash = None
@@ -451,12 +466,14 @@ class BlockPool:
 
     # -- release ----------------------------------------------------------
 
-    def free_sequence(self, block_ids: list[int], cold: bool = False) -> None:
+    def free_sequence(self, block_ids: list[int], cold: bool = False, spare: bool = False) -> None:
         """Give blocks back. ``cold``: a registered one becomes the first its
-        LRU evicts, not the last (a window block its sequence wrote and passed)."""
+        LRU evicts, not the last (a window block its sequence wrote and passed).
+        ``spare``: the LRU passes a registered one over while it holds any that
+        is not spared (a window block before a shared prompt's end)."""
         with self._lock:
             for bid in block_ids:
-                self._unref(bid, cold)
+                self._unref(bid, cold, spare)
 
     def snapshot(self) -> list[tuple[int, int | None]]:
         """All currently-registered (hash, parent_hash) pairs in original
@@ -474,6 +491,7 @@ class BlockPool:
         `cleared` would desync remote radix indexers. → count dropped."""
         with self._lock:
             dropped: list[int] = []
+            self._spared.clear()
             for bid in list(self._lru):
                 self._lru.pop(bid)
                 b = self._blocks[bid]

@@ -40,7 +40,9 @@ that block, so the latent kernels walk the window table as they walk any other.
 **Compile cost does not grow with depth.** Layer 0 alone, then one
 ``lax.scan`` over the periods of one ``[full, window, ...]`` body whose window
 layers are an inner scan: each kind's layer is traced once a program (the full
-layer twice: with the dense feed-forward and with the experts).
+layer twice: with the dense feed-forward and with the experts). The inner scan
+runs over the layer's index and reads ``params["swa"]`` ``[P, n_win, ..]``
+whole: the stack is an operand of neither scan.
 """
 
 from __future__ import annotations
@@ -273,21 +275,25 @@ def _layers(cfg, params, x, cache, positions, valid, full_attend, window_attend,
 
     def period(carry, xs):
         x, cache = carry
-        p, flp, wlps = xs
+        p, flp = xs
         first = p * (1 + n_win)  # this period's first layer among the expert layers
         x, cache, h0 = layer(cfg, cfg, {**flp, **experts, "moe_layer": first}, x, cache, positions, valid,
                              full_attend(1 + p), moe_impl)
 
-        def window_layer(carry, ys):
-            j, wlp = ys
+        def window_layer(carry, j):
+            # Out of the whole stack, where it lies: a period's slice handed to
+            # this scan as its operand is a buffer that the outer body fills
+            # anew every period (694 MB, twice a decode step at the cell's
+            # widths: PERF.md section 6, PR 50).
+            wlp = jax.tree.map(lambda a: a[p, j], params["swa"])
             x, cache, h = layer(cfg, swa, {**wlp, **experts, "moe_layer": first + 1 + j}, *carry, positions,
                                 valid, window_attend(p * n_win + j), moe_impl)
             return (x, cache), h
 
-        (x, cache), hw = lax.scan(window_layer, (x, cache), (jnp.arange(n_win, dtype=jnp.int32), wlps))
+        (x, cache), hw = lax.scan(window_layer, (x, cache), jnp.arange(n_win, dtype=jnp.int32))
         return (x, cache), jnp.concatenate([h0[None], hw])
 
-    (x, cache), hist = lax.scan(period, (x, cache), (jnp.arange(P, dtype=jnp.int32), params["full"], params["swa"]))
+    (x, cache), hist = lax.scan(period, (x, cache), (jnp.arange(P, dtype=jnp.int32), params["full"]))
     return x, cache, hist.reshape(P * (1 + n_win), -1)
 
 

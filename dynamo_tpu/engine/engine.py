@@ -162,7 +162,7 @@ class _Seq:
         "qos", "qos_rank", "arrival",
         "step_base", "mig", "offer_deadline", "traceparent",
         "state_pair", "state_src", "state_chunk", "state_diverge", "state_plen", "state_hi",
-        "window_blocks", "window_claimed", "window_diverge",
+        "window_blocks", "window_claimed", "window_branch", "window_diverge",
     )
 
     def __init__(self, request_id: str, req: PreprocessedRequest, queue: asyncio.Queue):
@@ -193,11 +193,13 @@ class _Seq:
         self.state_hi = -1
         # block="dots3" (block_manager/pool.py, window blocks): the window
         # pool's block of each of the sequence's blocks it holds one for (index
-        # in the sequence -> block id), which of them it claimed as a hit, and
+        # in the sequence -> block id), which of them it claimed as a hit, the
+        # deepest of those that another chain branches off behind (-1: none), and
         # the block its cached full-layer pages ended at where the window
         # blocks before it were gone (a shared prompt's end: kept for the next).
         self.window_blocks: dict[int, int] = {}
         self.window_claimed: set[int] = set()
+        self.window_branch = -1
         self.window_diverge = 0
         # Seeded requests are reproducible; others get a per-request seed.
         self.sample_seed = (
@@ -2203,6 +2205,11 @@ class TpuEngine:
             held = self.window_pool.claim(hashes_matchable[first:n_hit])
             seq.window_blocks = dict(zip(range(first, n_hit), held))
             seq.window_claimed = set(seq.window_blocks)
+            # The deepest block of the claim that a chain other than this one
+            # continues from: a shared prompt's end (a document's, where sessions
+            # start over). The blocks up to it go back spared (``_window_release``).
+            seq.window_branch = max((j for j in range(first, n_hit) if self.pool.hash_fanout(
+                hashes_matchable[j]) - (j + 1 < n_pages) >= 1), default=-1)
             seq.window_diverge = n_pages if n_pages > n_hit else 0
             try:  # the first chunk's blocks now: an admission that cannot have them waits
                 self._window_cover(seq, n_hit * bs, min(plen, n_hit * bs + self.args.max_prefill_tokens) - 1)
@@ -2449,13 +2456,21 @@ class TpuEngine:
         """Give the window blocks at ``indices`` of ``seq`` back: to the warm
         end of the pool's LRU what may be resumed from (everything where the
         sequence stops, ``final``; what it claimed as a hit; the blocks before a
-        shared prompt's end), to the cold end what it wrote and passed."""
+        shared prompt's end), to the cold end what it wrote and passed. What it
+        claimed up to a block that another chain continues from
+        (``window_branch``) the pool's eviction spares while anything else is
+        left: the boundary a turn resumed from is touched again within a think
+        time, a document's only when the next session starts over on it, and
+        between two of those the sessions' turns push it out of a plain LRU
+        (PERF.md section 6, PR 50)."""
         back = self.args.window_back_blocks
-        warm = [i for i in indices if final or i in seq.window_claimed
-                or seq.window_diverge - back <= i < seq.window_diverge]
-        cold = [i for i in indices if i not in warm]
+        shared = [i for i in indices if i <= seq.window_branch]
+        warm = [i for i in indices if i > seq.window_branch and (
+            final or i in seq.window_claimed or seq.window_diverge - back <= i < seq.window_diverge)]
+        cold = [i for i in indices if i not in warm and i not in shared]
         self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in cold], cold=True)
         self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in warm])
+        self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in shared], spare=True)
         seq.window_claimed.difference_update(indices)
 
     def _release_window(self, seq: _Seq) -> None:

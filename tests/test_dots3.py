@@ -157,6 +157,61 @@ def test_a_chunk_of_several_query_blocks_is_the_chunk_at_once(monkeypatch):
     np.testing.assert_allclose(np.asarray(run()), np.asarray(whole), atol=2e-5)
 
 
+def _layers_by_hand(cfg, params, x, cache, positions, valid, full_attend, window_attend, moe_impl):
+    """``dots3._layers`` as a plain loop over the nine layers, a layer's
+    tensors taken out of their stacks by hand: no scan, no operand of one."""
+    P, n_win = dots3.periods(cfg)
+    x, cache, _ = dots3.layer(cfg, cfg, params["first"], x, cache, positions, valid, full_attend(0), moe_impl)
+    hist = []
+    for p in range(P):
+        first = p * (1 + n_win)
+        lp = jax.tree.map(lambda a: a[p], params["full"])
+        x, cache, h = dots3.layer(cfg, cfg, {**lp, **params["experts"], "moe_layer": first}, x, cache, positions,
+                                  valid, full_attend(1 + p), moe_impl)
+        hist.append(h)
+        for j in range(n_win):
+            lp = jax.tree.map(lambda a: a[p, j], params["swa"])
+            x, cache, h = dots3.layer(cfg, cfg.swa, {**lp, **params["experts"], "moe_layer": first + 1 + j}, x, cache,
+                                      positions, valid, window_attend(p * n_win + j), moe_impl)
+            hist.append(h)
+    return x, cache, jnp.stack(hist)
+
+
+@pytest.mark.parametrize("program", ["prefill_batch", "decode_step"])
+def test_the_layers_do_not_depend_on_how_a_window_layer_is_reached(program, monkeypatch):
+    """The two scans, whose inner body indexes the whole ``swa`` stack, against
+    the nine layers one after the other: the same logits and the same three
+    pools bit for bit, the histogram's rows in the layers' order."""
+    params = program_params(REF.weights(doc_for("float32"), 0))
+    lengths = jnp.asarray([45, 29], jnp.int32)
+    tables = jnp.arange(1, 17, dtype=jnp.int32).reshape(2, 8)
+    state = jnp.concatenate([jnp.zeros((2, 1), jnp.int32), tables], axis=1)
+    toks = jnp.asarray([_pad(prompt(int(n), seed=int(n)), 48) for n in lengths])
+    zeros = jnp.zeros((2,), jnp.int32)
+
+    def run():  # the weights an operand, as the engine's programs take them (closed over, XLA folds them as constants)
+        cache = dots3.init_kv_cache(CFG, 32, BS, jnp.float32, window_blocks=32)
+        out = jax.jit(lambda p, c: dots3.prefill_batch_impl(CFG, p, c, toks, tables, zeros, lengths,
+                                                            attn_impl="xla", state_slots=state))(params, cache)
+        if program == "decode_step":  # a step past index_topk and the window, over the pages that prefill wrote
+            out = jax.jit(lambda p, c: dots3.decode_step_impl(
+                CFG, p, c, jnp.asarray([3, 5], jnp.int32), lengths, tables, jnp.asarray([True, True]),
+                attn_impl="xla", state_slots=state))(params, out[1])
+        return out
+
+    logits, cache, hist = run()
+    monkeypatch.setattr(dots3, "_layers", _layers_by_hand)
+    want_logits, want_cache, want_hist = run()
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want_logits))
+    for name in ("kv", "ikeys", "window"):
+        got, want = np.asarray(getattr(cache, name)), np.asarray(getattr(want_cache, name))
+        np.testing.assert_array_equal(got, want)
+        assert np.abs(want[:, 1:]).max() > 0  # written, not the zeros it began as
+    assert hist.shape == (len(dots3.routed_layers(CFG)), CFG.num_experts + longcat.HIST_EXTRA)
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(want_hist))
+    assert len({tuple(row) for row in np.asarray(hist).tolist()}) == len(hist)  # no two rows alike, so their order is held
+
+
 # -- the indexer's choice ----------------------------------------------------------
 
 
@@ -485,6 +540,23 @@ def test_what_a_sequence_wrote_and_passed_is_evicted_before_what_may_be_resumed_
     assert claimed == [bids[0], bids[2]] and pool.num_cached == 0
 
 
+def test_the_lru_passes_a_spared_block_over_until_nothing_else_is_left():
+    pool = BlockPool(10, BS)  # 9 blocks, of which the pool spares at most 3 (a quarter of 10, rounded up)
+    bids = [pool.allocate_block() for _ in range(6)]
+    for i, bid in enumerate(bids):
+        pool.register_block(bid, 100 + i, None)
+    pool.free_sequence(bids[:4], spare=True)  # the oldest of the LRU; the fourth is over the quarter: given back plain
+    pool.free_sequence(bids[4:])
+    assert pool._spared == set(bids[:3]) and pool.num_cached == 6
+    got = [pool.allocate_block() for _ in range(3)]  # the free three first
+    assert pool.evictions == 0 and [pool.allocate_block() for _ in range(3)] == bids[3:]  # then the plain ones, oldest first
+    assert pool.match_prefix([100, 101, 102]) == bids[:3]
+    assert pool.claim([100]) == [bids[0]] and bids[0] not in pool._spared  # a holder takes it out of the spared
+    pool.free_sequence([bids[0]])                                          # given back plain, it is plain: the newest, the first to go
+    assert [pool.allocate_block() for _ in range(3)] == bids[:3] and not pool._spared and pool.evictions == 6
+    pool.free_sequence(got)
+
+
 def greedy(prompt_ids, max_tokens=6, **ktp) -> PreprocessedRequest:
     req = PreprocessedRequest(model="t", token_ids=list(prompt_ids))
     req.sampling.temperature = 0.0
@@ -623,6 +695,42 @@ def test_a_shared_prompts_end_is_kept_for_the_next_to_share_it():
     assert (s1["miss"], s2["miss"], s3["miss"]) == (0, 1, 1) and s3["deepest"] == 1 and s3["cut_back"] == 0
     assert s2["recomputed_tokens"] == 64 == s3["recomputed_tokens"]
     assert t3 == _alone(shared + prompt(30, seed=8), 6) and t2 == _alone(shared + prompt(30, seed=7), 6)
+
+
+def test_a_shared_prompts_end_is_spared_while_the_lru_holds_anything_else():
+    """Of what an admission claimed, the blocks before a block that a second chain
+    continues from (a document's end: the next session to start over on it
+    resumes there) go back spared; a turn's own last boundary, which its next
+    turn touches within a think time, goes back as it did. The LRU then evicts
+    every other block before a spared one, however old that is."""
+    shared = prompt(64, seed=5)
+
+    async def go():
+        engine = await TpuEngine(engine_args()).start()
+        try:
+            for seed in (6, 7):  # two chains leave the document's end
+                await _tokens(engine, greedy(shared + prompt(30, seed=seed), 6))
+            first = shared + prompt(30, seed=8)
+            second = first + await _tokens(engine, greedy(first, 6)) + prompt(13, seed=9)  # claims the shared end
+            await _tokens(engine, greedy(second, 12))                                    # claims its own last boundary
+            wp = engine.window_pool
+            own, end = compute_block_hashes(second, BS)[len(first + [0] * 5) // BS - 1], compute_block_hashes(shared, BS)[-1]
+
+            def squeeze():  # on the scheduler thread: take every block the pool can give
+                spared = {h for h, bid in wp._cached.items() if bid in wp._spared}
+                fanout = engine.pool.hash_fanout(own), engine.pool.hash_fanout(end)
+                taken = [wp.allocate_block() for _ in range(wp.num_free - 1)]
+                left = set(wp._cached)
+                wp.free_sequence(taken)
+                return spared, fanout, left
+            return own, end, await engine.run_on_engine_thread(squeeze), dict(engine.window_stats)
+        finally:
+            await engine.stop()
+
+    own, end, (spared, fanout, left), stats = asyncio.run(go())
+    assert stats["deepest"] == 3 and stats["cut_back"] == 0 == stats["miss"]  # two sessions and the second turn
+    assert fanout == (1, 3)  # the turn's boundary has its one continuation; three chains leave the document's end
+    assert spared == {end} and left == {end}  # the last cached block the pool gives up
 
 
 def test_preempted_sequences_return_and_packed_rows_of_different_depths_agree_with_alone():

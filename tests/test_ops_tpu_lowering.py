@@ -18,6 +18,7 @@ import dataclasses
 import functools
 import glob
 import json
+import math
 import os
 import re
 
@@ -151,6 +152,18 @@ _IN_PLACE = ("scatter", "dynamic-update-slice", "fusion", "custom-call", "while"
 _NO_RESULT = ("parameter", "get-tuple-element", "bitcast", "tuple")
 
 
+def _produced(compiled, shapes, skip: tuple[str, ...]) -> list[str]:
+    """Instructions of a compiled program, other than ``skip``'s kinds, whose
+    result has one of ``shapes``."""
+    want = {"[" + ",".join(map(str, shape)) + "]" for shape in shapes}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = \S*?(\[[\d,]*\])\S* ([\w-]+)\(", line)
+        if m and m.group(2) not in skip and m.group(1) in want:
+            found.append(line.strip()[:140])
+    return found
+
+
 def _pool_copies(compiled, cache: M.KVCache) -> list[str]:
     """Instructions of a compiled program whose result has the shape of one of
     ``cache``'s pools, of one layer of it, or of the K or V part of either,
@@ -170,13 +183,19 @@ def _pool_copies(compiled, cache: M.KVCache) -> list[str]:
         big |= shapes(tuple(pool.shape))
         if name == "kv" and pool.ndim == 5:
             big |= shapes(tuple(pool.shape[:2] + pool.shape[3:]))
-    big = {"[" + ",".join(map(str, shape)) + "]" for shape in big}
-    found = []
-    for line in compiled.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?\S+ = \S*?(\[[\d,]*\])\S* ([\w-]+)\(", line)
-        if m and m.group(2) not in _NO_RESULT + _IN_PLACE and m.group(1) in big:
-            found.append(line.strip()[:140])
-    return found
+    return _produced(compiled, big, _NO_RESULT + _IN_PLACE)
+
+
+def _period_copies(compiled, params) -> list[str]:
+    """Instructions of a compiled dots3 program whose result is one period's
+    share, ``[n_win, ...]`` and over a megabyte, of a tensor of
+    ``params["swa"]`` (``[P, n_win, ...]``): a loop's operand is a buffer, so a
+    period's slice handed to the inner scan as its ``xs`` is written anew
+    every period (fourteen such fusions, 694 MB, twice a decode step:
+    PERF.md section 6, PR 50). Whatever its kind: the copy is a fusion."""
+    stacks = {tuple(t.shape[1:]) for t in jax.tree.leaves(params["swa"])
+              if math.prod(t.shape[1:]) * t.dtype.itemsize > 1 << 20}
+    return _produced(compiled, stacks, _NO_RESULT)
 
 
 def _a_layer_of_the_pages(cache: M.KVCache) -> int:
@@ -869,7 +888,8 @@ def _computations(hlo: str) -> dict[str, list[str]]:
 
 
 @pytest.mark.parametrize("program", ["index_scores", "chosen_rows", "masked_walk", "window_decode", "window_prefill",
-                                     "keep_prefill", "decode_window", "prefill_chunk_2048", "prefill_packed_2x256"])
+                                     "keep_prefill", "decode_window", "prefill_chunk_2048", "prefill_packed_2x256",
+                                     "prefill_packed_4x128"])
 def test_dots3_programs_compile_for_v5e(v5e, program):
     """What the dots3 cell runs, at the published widths, the cell's pools
     (18,432 blocks of latents and index keys, the window pool) and its
@@ -878,10 +898,11 @@ def test_dots3_programs_compile_for_v5e(v5e, program):
     kernels at the second geometry under the window's mask and under a chosen
     set's, then the decode window (both forms of the chosen-rows attend, a
     branch each: the walk's side holds no ``[B, topk, Dk]`` rows), a
-    2,048-token chunk and a pack of two rows. No program copies a pool (the chosen rows are gathered as
-    rows of lanes, each with its own block and slot), nothing in a prefill has
-    the 128 heads' absorbed queries of a whole chunk, and a chunk's temporaries
-    leave room in 16 GB beside 9.2 GB of weights and 3.4 GB of pools."""
+    2,048-token chunk and the cell's packs of two and of four rows. No program copies a pool (the chosen rows are gathered as
+    rows of lanes, each with its own block and slot) or a period's window
+    layers' weights (the inner scan indexes the whole stack), nothing in a
+    prefill has the 128 heads' absorbed queries of a whole chunk, and a chunk's
+    temporaries leave room in 16 GB beside 9.2 GB of weights and 3.4 GB of pools."""
     from dynamo_tpu.engine import dots3
     from dynamo_tpu.ops import dsa
     from dynamo_tpu.ops.paged_attention import latent_decode_attention, latent_prefill_attention
@@ -928,13 +949,16 @@ def test_dots3_programs_compile_for_v5e(v5e, program):
                  if any("custom-call(" in ln and "latent_sparse_decode_attention" in ln for ln in lines)]
         gathered = re.compile(rf"\[{B},{cfg.index_topk},640\]|\[{B * cfg.index_topk},640\]")
         assert sorted(any(gathered.search(ln) for ln in lines) for lines in sides) == [False, False, True, True]
-        limit = 1.0e9  # 0.81 GB by the compiler's analysis
+        limit = 0.2e9  # 0.115 GB by the compiler's analysis (0.81 with the period's copy, 0.69 GB of it)
     else:
-        rows, t = (1, 2048) if program == "prefill_chunk_2048" else (2, 256)
+        rows, t = {"prefill_chunk_2048": (1, 2048), "prefill_packed_2x256": (2, 256), "prefill_packed_4x128": (4, 128)}[program]
         compiled = dots3.prefill_batch.lower(
             cfg, params, cache, i32(rows, t), i32(rows, W), i32(rows), i32(rows),
             attn_impl="pallas", experts="gmm", state_slots=i32(rows, args.state_operand_width)).compile()
         assert "latent_prefill_attention" in compiled.as_text()
-        limit = 1.6e9  # 1.29 GB at T 2,048: a query block's scores, keys and mask over 32,768 positions, the experts' parts
+        # 1.04 GB at T 2,048 (1.29 with the copy): a query block's scores, keys and mask over 32,768 positions,
+        # the experts' parts; the packs 0.31 GB at 2 x 256 and 0.36 at 4 x 128 (0.86 both with it)
+        limit = 1.25e9 if t == 2048 else 0.45e9
     assert compiled.memory_analysis().temp_size_in_bytes < limit
     assert not _pool_copies(compiled, cache)
+    assert not _period_copies(compiled, params)
