@@ -58,12 +58,6 @@ class AdapterSlotPool:
     def resident(self) -> int:
         return len(self._order)
 
-    def resident_ids(self) -> list[str]:
-        return list(self._order)
-
-    def slot_of(self, adapter_id: str) -> int | None:
-        return self._order.get(adapter_id)
-
     def _pop_victim(self) -> tuple[str, int]:
         """Oldest unpinned zero-credit resident; warm entries are spared
         (credit decayed, re-queued MRU) within one bounded scan, pinned

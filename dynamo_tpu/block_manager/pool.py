@@ -21,59 +21,32 @@ contract) and never allocated.
 ``block="sala"`` model, engine/sala.py). A lightning layer's state is a matrix
 a head, megabytes a sequence, so it cannot ride every block as pages do: the
 pool hands out ``state_slots`` slots of the device's state pool (slot 0 is the
-sink of padded rows) under one policy, which has no knob:
-
-- A running sequence owns a **pair** (``acquire_state_pair``): its live state
-  rests in ``pair[(position // block_size) % 2]``, so the decode step that opens
-  a block leaves the block before's end state behind in the other slot.
-- A **snapshot** is a slot tied to a sealed block's hash: "the state after this
-  block's last token" (``take_snapshot``: the caller's next dispatch writes
-  it). A prefill takes one at the last block boundary of every chunk, in place
-  of the one its chunk before took (a sequence's own older snapshots are worth
-  nothing beside its newest), and one where its cached pages ended deeper than
-  any snapshot: there a chain that is already cached and this one part, a
-  shared prompt's end, which the next to share it resumes from. A sequence
-  that finishes or is preempted leaves the one its decode last left, or its
-  live state where it stopped on a boundary (``release_state_pair`` keeps that
-  slot and frees the other).
-- A prefix hit is only as deep as the deepest block of the chain that has its
-  pages **and** a snapshot (``snapshot_depth``); prefill resumes there from the
-  snapshot and recomputes the rest. A chain without one stays usable up to the
-  deepest snapshot before it.
-- Snapshots are evicted by their own LRU when a slot is needed (a hit makes
-  one the newest; a branch point, which two chains or more continue from, goes
-  only when nothing else is left), and with their block when its page is
-  evicted. A snapshot an admission of this wave
-  resumes from is pinned until the wave's prefills are dispatched
-  (``unpin_states``): the device's stream is serial, so what is dispatched
-  later cannot overwrite what an earlier dispatch still has to read.
+sink of padded rows), a **pair** to a running sequence (its live state rests
+in ``pair[(position // block_size) % 2]``, so the decode step that opens a
+block leaves the block before's end state behind in the other slot) and one to
+a **snapshot**, "the state after this sealed block's last token", which a
+prefix hit needs beside the block's page (``snapshot_depth``). Snapshots go by
+their own LRU (a hit makes one the newest; a branch point, which two chains or
+more continue from, goes only when nothing else is left) and with their
+block's page; one an admission of this wave resumes from is pinned until the
+wave's prefills are dispatched (``unpin_states``): the device's stream is
+serial, so what is dispatched later cannot overwrite what an earlier dispatch
+still has to read. When each is taken is ``engine/side.py:StateSlots``'s.
 
 **A second lifetime: window blocks** (a ``block="dots3"`` model, engine/dots3.py).
 A window layer keeps a sequence's last ``sliding_window`` positions, so its
-pages cannot live as long as the sequence does: they are blocks of a pool of
-their own, a second ``BlockPool`` with no event sink (the KV events and the
-router's view are of the full-layer chain alone), under the same policy:
-
-- A running sequence holds the window blocks of its last positions (and, in a
-  prefill, of the chunk in flight); the engine gives a block back
-  (``free_sequence``) when a dispatch has moved the window past it. A block that
-  was sealed and registered here under its chain's hash stays as a cached block
-  of this pool's own LRU, any other is free at once. Either way the sequence no
-  longer holds it and no later program of its reads it.
-- What a sequence wrote and passed goes to the LRU's cold end
-  (``free_sequence(cold=True)``): it is the first to be evicted. What may be
-  resumed from goes to the warm end: the blocks a sequence holds when it
-  finishes or is preempted, those before the end of a shared prompt, and those
-  an admission claimed as a hit. Of the last, the blocks before a block that a
-  second chain continues from (the full-layer pool's ``hash_fanout``: a
-  document's end, where sessions start over) are given back **spared**
-  (``free_sequence(spare=True)``): the LRU evicts them only when nothing else
-  is left, as a snapshot at a branch point is; at most a quarter of the pool.
-- A prefix hit is only as deep as the deepest block of the chain that has its
-  full-layer pages **and** whose ``back`` preceding blocks are all cached here
-  (``window_depth``, beside ``snapshot_depth``: one rule, two kinds of second
-  state); the admission claims those (``claim``), prefill resumes there and
-  recomputes the rest through every layer.
+pages cannot live as long as the sequence does: they are blocks of a second
+``BlockPool`` with no event sink (the KV events and the router's view are of
+the full-layer chain alone). A block given back (``free_sequence``) stays
+cached under its chain's hash if it was registered here, any other is free at
+once: at the LRU's warm end, at its cold end (``cold``: the first to go) or
+**spared** (``spare``: evicted only when nothing else is left, as a snapshot at
+a branch point is; at most a quarter of the pool). A prefix hit is only as
+deep as the deepest block that has its full-layer pages **and** whose ``back``
+preceding blocks are all cached here (``window_depth``, beside
+``snapshot_depth``: one rule, two kinds of second state; the admission claims
+those, ``claim``). Which block goes back when and how is
+``engine/side.py:WindowBlocks``'s.
 """
 
 from __future__ import annotations

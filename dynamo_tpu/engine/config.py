@@ -991,15 +991,12 @@ class EngineArgs:
             return best
         return [sfx]
 
-    def pack_shapes(self, limit: int, row_tokens: int = 0) -> tuple[tuple[int, int], ...]:
+    def pack_shapes(self, limit: int) -> tuple[tuple[int, int], ...]:
         """The packed prefill programs (rows, T) a limit of ``limit`` padded
         tokens a dispatch allows (the runner derives it from the model's
-        weight bytes against its operations a token, ``runner.pack_limit``),
-        a row counting ``row_tokens`` beside its T (``runner.pack_row_tokens``:
-        what the block spends on a row whatever its length):
+        weight bytes against its operations a token, ``runner.pack_limit``):
         for each row count of the pow2 ladder, the ``PACK_T_BUCKETS`` largest
-        T buckets with rows x (T + row_tokens) inside the limit and rows x T
-        inside ``max_prefill_tokens``
+        T buckets with rows x T inside the limit and inside ``max_prefill_tokens``
         (so no pack's temporaries pass the largest single's). Under the
         limit a dispatch is bound by the weights it streams and padding a row
         up costs no second stream, so small T buckets would buy a pack
@@ -1010,12 +1007,12 @@ class EngineArgs:
         the shortest suffixes, programs for pairs of those served 0 to 1
         dispatch a window where they were tried on the chip (PERF.md s.6,
         PR 38), and each is set-up time and device memory."""
-        if PACK_ROWS[-1] * (self.prefill_buckets[0] + row_tokens) > limit:
+        if PACK_ROWS[-1] * self.prefill_buckets[0] > limit:
             return ()
         out = []
         for rows in PACK_ROWS:
             fits = [t for t in self.prefill_buckets
-                    if rows * (t + row_tokens) <= limit and rows * t <= self.max_prefill_tokens]
+                    if rows * t <= min(limit, self.max_prefill_tokens)]
             out += [(rows, t) for t in fits[-PACK_T_BUCKETS:]]
         return tuple(out)
 

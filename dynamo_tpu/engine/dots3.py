@@ -60,6 +60,7 @@ from dynamo_tpu.engine.longcat import (
     mla_project, mla_query_latent, moe, unabsorb_output,
 )
 from dynamo_tpu.engine.model import KVCache, _logits, decode_window, pool_zeros
+from dynamo_tpu.engine.side import WindowBlocks as side_cache  # noqa: F401 — this block's second cache (engine/side.py)
 from dynamo_tpu.ops import dsa
 from dynamo_tpu.ops.paged_attention import (
     latent_decode_attention,
@@ -73,6 +74,7 @@ from dynamo_tpu.ops.paged_attention import (
 Params = dict[str, Any]
 
 START_LINE = " block=dots3"  # what the engine's start line says of this block
+UNCARRIED = ("latent pages, index keys and window pool", "latent pages, index keys and the window pool")
 # The expert bias is drawn at this scale (engine/lfm2.py's, for the same
 # router): a choice made on the scores without it picks other experts.
 EXPERT_BIAS_STD = 0.1

@@ -110,10 +110,6 @@ class TreeDraft:
     def __len__(self) -> int:
         return len(self.tokens)
 
-    @property
-    def num_nodes(self) -> int:
-        return len(self.tokens) + 1
-
     def depths(self) -> list[int]:
         """Per-node depth including the root (depth 0) → [num_nodes]."""
         out = [0]

@@ -67,8 +67,7 @@ from dynamo_tpu.engine.grammar import (
 from dynamo_tpu.engine.model import block_module, refuse_block
 from dynamo_tpu.engine.runner import host_ready, start_host_fetch
 from dynamo_tpu.engine.sampler import needs_full, row_needs_full
-from dynamo_tpu.ops import dsa
-from dynamo_tpu.ops.sparse_attention import choice_counts
+from dynamo_tpu.engine.side import KINDS as SIDE_KINDS
 from dynamo_tpu.kv_router.protocols import ForwardPassMetrics, KvCacheEvent, KvStats, WorkerStats
 from dynamo_tpu.llm.protocols import (
     FinishReason,
@@ -161,8 +160,7 @@ class _Seq:
         "adapter_id", "adapter_slot", "hash_seed",
         "qos", "qos_rank", "arrival",
         "step_base", "mig", "offer_deadline", "traceparent",
-        "state_pair", "state_src", "state_chunk", "state_diverge", "state_plen", "state_hi",
-        "window_blocks", "window_claimed", "window_branch", "window_diverge",
+        "side",
     )
 
     def __init__(self, request_id: str, req: PreprocessedRequest, queue: asyncio.Queue):
@@ -180,27 +178,9 @@ class _Seq:
         self.cancelled = False
         self.preempted = False
         self.prefix_hit_blocks = 0
-        # block="sala" (block_manager/pool.py, state slots): the pair this
-        # sequence's state lives in while it runs, the snapshot slot its
-        # prefill resumes from (0: from zero), the block of the snapshot its
-        # own prefill took last, its length at admission and the highest
-        # position a dispatch has written state for.
-        self.state_pair: tuple[int, int] | None = None
-        self.state_src = 0
-        self.state_chunk: int | None = None
-        self.state_diverge = 0  # where its cached pages ended, if deeper than any snapshot
-        self.state_plen = 0
-        self.state_hi = -1
-        # block="dots3" (block_manager/pool.py, window blocks): the window
-        # pool's block of each of the sequence's blocks it holds one for (index
-        # in the sequence -> block id), which of them it claimed as a hit, the
-        # deepest of those that another chain branches off behind (-1: none), and
-        # the block its cached full-layer pages ended at where the window
-        # blocks before it were gone (a shared prompt's end: kept for the next).
-        self.window_blocks: dict[int, int] = {}
-        self.window_claimed: set[int] = set()
-        self.window_branch = -1
-        self.window_diverge = 0
+        # What the block's second cache holds for this sequence while it runs
+        # (engine/side.py: the kind's own record); None off a residence.
+        self.side: Any = None
         # Seeded requests are reproducible; others get a per-request seed.
         self.sample_seed = (
             req.sampling.seed if req.sampling.seed is not None else random.getrandbits(31)
@@ -661,113 +641,6 @@ def register_engine_metrics(registry) -> dict:
             "the paged attention kernels moves",
         ),
         registry.counter(
-            "engine_state_snapshots_total",
-            "State snapshots taken (block='sala'), by why: chunk_end = a "
-            "prefill chunk ended on a block boundary and left a second copy "
-            "of its state; decode_boundary = a decode step opened a block and "
-            "left the block before's end state behind in the sequence's pair",
-        ),
-        registry.counter(
-            "engine_state_resumes_total",
-            "Admissions of a model with a state pool, by where the lightning "
-            "layers' state came from: snapshot = a cached block's, zero = "
-            "position 0",
-        ),
-        registry.counter(
-            "engine_state_cached_tokens_total",
-            "Prompt tokens an admission of such a model found pages for in "
-            "the prefix cache, whether or not a snapshot let it start there",
-        ),
-        registry.counter(
-            "engine_state_recomputed_tokens_total",
-            "Of those, the tokens past the chain's deepest snapshot: cached "
-            "pages the prefill computed again to rebuild the state",
-        ),
-        registry.counter(
-            "engine_state_snapshot_evictions_total",
-            "Snapshots evicted by their own LRU to free a state slot",
-        ),
-        registry.counter(
-            "engine_sparse_blocks_chosen_total",
-            "Blocks the sparse layers' attention went over, a query position "
-            "(prefill tokens and decode steps; every sparse layer and KV head "
-            "of a position counts the same, so a position counts once), as "
-            "the programs were dispatched: a decode step its chosen table, "
-            "the top-k past dense_len and every visible block under it; a "
-            "prefill that has a row past dense_len every page of its table's "
-            "width, the choice being a mask there, any other its visible "
-            "blocks",
-        ),
-        registry.counter(
-            "engine_sparse_blocks_visible_total",
-            "Blocks those positions could see (their context in blocks)",
-        ),
-        registry.counter(
-            "engine_sparse_dense_rows_total",
-            "Of those positions, the ones at or under dense_len, which took "
-            "the dense path",
-        ),
-        registry.counter(
-            "engine_dsa_chosen_tokens_total",
-            "Cached tokens the full layers of a block='dots3' model attended, "
-            "a decode row and step (every full layer of a step counts the "
-            "same, so a step counts once): index_topk where the row sees more, "
-            "every visible token where it does not",
-        ),
-        registry.counter(
-            "engine_dsa_visible_tokens_total",
-            "Cached tokens those decode rows could see (their context)",
-        ),
-        registry.counter(
-            "engine_dsa_dense_rows_total",
-            "Of those decode rows, the ones at or under index_topk visible "
-            "tokens, whose choice is every token",
-        ),
-        registry.counter(
-            "engine_dsa_decode_steps_total",
-            "Decode steps of a block='dots3' model some row of which saw more "
-            "than index_topk tokens: the full layers chose and attended a set",
-        ),
-        registry.counter(
-            "engine_dsa_decode_walk_steps_total",
-            "Of those steps, the ones whose chosen sets were attended as a "
-            "mask over the walk of the rows' own pages (ops/dsa.py: "
-            "walk_is_cheaper of the step's lengths, as the program evaluates "
-            "it); the rest gathered their chosen rows",
-        ),
-        registry.counter(
-            "engine_window_blocks_released_total",
-            "Window-pool blocks (block='dots3': the window layers' pages) "
-            "that sequences gave back, by where they went: cached = sealed and "
-            "registered, kept under the chain's hash in the window pool's own "
-            "LRU; free = at once",
-        ),
-        registry.counter(
-            "engine_window_resume_total",
-            "Admissions of such a model that found cached full-layer pages, "
-            "by how deep they could resume: deepest = at the last cached "
-            "block (the window blocks before it were resident); cut_back = at "
-            "an earlier block; miss = from position 0",
-        ),
-        registry.counter(
-            "engine_window_resume_recomputed_tokens_total",
-            "Tokens of cached full-layer pages those admissions computed "
-            "again, through every layer, for want of window blocks",
-        ),
-        registry.counter(
-            "engine_window_pool_evictions_total",
-            "Cached window-pool blocks evicted for their page",
-        ),
-        registry.gauge(
-            "engine_window_pool_used_blocks",
-            "Window-pool blocks running sequences hold",
-        ),
-        registry.gauge(
-            "engine_window_pool_cached_blocks",
-            "Window-pool blocks no sequence holds that stay cached under "
-            "their chain's hash (evictable)",
-        ),
-        registry.counter(
             "moe_assignments_total",
             "Expert assignments (token x top-k x layer) the expert layer "
             "routed, by kind: held = to a routed expert this chip holds "
@@ -797,6 +670,9 @@ def register_engine_metrics(registry) -> dict:
             "reads those calls needed",
         ),
     )
+    for kind in SIDE_KINDS:  # one catalog, whichever block runs
+        metrics += tuple(registry.counter(name, text) for name, text in kind.COUNTERS.items())
+        metrics += tuple(registry.gauge(name, text) for name, text in kind.GAUGES.items())
     return {m.name.removeprefix(PREFIX + "_"): m for m in metrics}
 
 
@@ -847,18 +723,17 @@ class TpuEngine:
         self.cfg = args.model
         self._runner = runner or LocalRunner(args, params=params, seed=seed, sharding=sharding)
         self._external_events = event_sink
+        module = block_module(self.cfg)
+        kind = getattr(module, "side_cache", None)
         self.pool = BlockPool(
             args.num_kv_blocks,
             args.block_size,
             event_sink=self._on_pool_event,
             enable_prefix_caching=args.prefix_caching,
-            state_slots=args.state_slots,
+            **(kind.pool_options(args) if kind else {}),
         )
-        # block="dots3": the window layers' pages, a pool with a lifetime of its
-        # own and no events (the router's view is of the full-layer chain).
-        self.window_pool: BlockPool | None = (
-            BlockPool(args.window_blocks, args.block_size, enable_prefix_caching=args.prefix_caching)
-            if args.window_blocks else None)
+        # What the block keeps beside its pages (engine/side.py), if anything.
+        self.side = kind(args, self.pool) if kind else None
         # G2/G3 KV tiers: sealed blocks write through to host (batched per
         # step); prefix misses in HBM onboard from the tiers instead of
         # recomputing (block_manager/tiers.py).
@@ -1058,7 +933,7 @@ class TpuEngine:
         # token fetches; None for a block that routes nothing. The block's
         # module says which layers route (``routed_layers``).
         self.moe_hist: dict[str, np.ndarray] | None = None
-        routed = getattr(block_module(self.cfg), "routed_layers", None)
+        routed = getattr(module, "routed_layers", None)
         self._routed_layers: tuple[int, ...] = routed(self.cfg) if routed else ()
         if self._routed_layers:
             from dynamo_tpu.engine.longcat import HIST_EXTRA
@@ -1069,23 +944,8 @@ class TpuEngine:
         # came from (engine_conv_state_resumes_total); None without such layers.
         self.conv_resumes: dict[str, int] | None = (
             {"cache": 0, "zero": 0, "recompute": 0} if self.cfg.conv_layers else None)
-        # block="sala": where admissions' states came from, the cached tokens
-        # they found and recomputed, and what the sparse layers chose
-        # (engine_state_*, engine_sparse_*); None for any other block.
-        self.state_stats: dict[str, int] | None = None
-        if args.state_slots:
-            from dynamo_tpu.ops.sparse_attention import SparseSizes
-
-            self._sparse_sizes = SparseSizes.of(self.cfg)
-            self.state_stats = {"snapshot": 0, "zero": 0, "cached_tokens": 0, "recomputed_tokens": 0,
-                                "chosen": 0, "visible": 0, "dense": 0}
-        # block="dots3": how deep admissions resumed (engine_window_resume_*)
-        # and what the full layers' decode rows chose (engine_dsa_*); None for
-        # any other block.
-        self.window_stats: dict[str, int] | None = (
-            {"deepest": 0, "cut_back": 0, "miss": 0, "recomputed_tokens": 0,
-             "chosen": 0, "visible": 0, "dense": 0, "steps": 0, "walk_steps": 0}
-            if self.window_pool is not None else None)
+        # None: pages of per-head K and V, which KV transfer and live migration carry.
+        self._uncarried: tuple[str, str] | None = getattr(module, "UNCARRIED", None)
         # Prefill dispatches by their program's rows (a chunk of a chunked
         # prefill is a dispatch of one row): engine_prefill_dispatch_rows_total.
         self.prefill_dispatch_rows: dict[int, int] = collections.defaultdict(int)
@@ -1097,16 +957,8 @@ class TpuEngine:
         self._gauges = register_engine_metrics(registry)
         for source in self.conv_resumes or ():  # every source is a series from the start, at 0
             self._gauges["engine_conv_state_resumes_total"].inc(0, source=source)
-        if self.state_stats is not None:  # every series from the start, at 0
-            for origin in ("snapshot", "zero"):
-                self._gauges["engine_state_resumes_total"].inc(0, **{"from": origin})
-            for why in self.pool.state_snapshots:
-                self._gauges["engine_state_snapshots_total"].inc(0, why=why)
-        if self.window_stats is not None:  # every series from the start, at 0
-            for outcome in ("deepest", "cut_back", "miss"):
-                self._gauges["engine_window_resume_total"].inc(0, outcome=outcome)
-            for to in self.window_pool.released:
-                self._gauges["engine_window_blocks_released_total"].inc(0, to=to)
+        if self.side is not None:
+            self.side.bind_metrics(self._gauges)
 
     def _feed(self, name: str, total: float, **labels: str) -> None:
         """Give counter ``name`` what its running total grew by since it
@@ -1126,35 +978,8 @@ class TpuEngine:
         for kind, per_block in self.args.pool_bytes_per_block().items():
             g["kv_pool_bytes"].set(per_block * self.args.num_kv_blocks, kind=kind)
         g["kv_page_bytes"].set(self.args.kv_page_bytes())
-        if self.state_stats is not None:
-            st = self.state_stats
-            g["kv_pool_bytes"].set(self.args.state_pool_bytes(), kind="state")
-            for origin in ("snapshot", "zero"):
-                feed("engine_state_resumes_total", st[origin], **{"from": origin})
-            for why, n in self.pool.state_snapshots.items():
-                feed("engine_state_snapshots_total", n, why=why)
-            feed("engine_state_cached_tokens_total", st["cached_tokens"])
-            feed("engine_state_recomputed_tokens_total", st["recomputed_tokens"])
-            feed("engine_state_snapshot_evictions_total", self.pool.state_evictions)
-            feed("engine_sparse_blocks_chosen_total", st["chosen"])
-            feed("engine_sparse_blocks_visible_total", st["visible"])
-            feed("engine_sparse_dense_rows_total", st["dense"])
-        if self.window_stats is not None:
-            ws, wp = self.window_stats, self.window_pool
-            g["kv_pool_bytes"].set(self.args.window_pool_bytes(), kind="window")
-            for outcome in ("deepest", "cut_back", "miss"):
-                feed("engine_window_resume_total", ws[outcome], outcome=outcome)
-            feed("engine_window_resume_recomputed_tokens_total", ws["recomputed_tokens"])
-            for to, n in wp.released.items():
-                feed("engine_window_blocks_released_total", n, to=to)
-            feed("engine_window_pool_evictions_total", wp.evictions)
-            g["engine_window_pool_used_blocks"].set(wp.num_active)
-            g["engine_window_pool_cached_blocks"].set(wp.num_cached)
-            feed("engine_dsa_chosen_tokens_total", ws["chosen"])
-            feed("engine_dsa_visible_tokens_total", ws["visible"])
-            feed("engine_dsa_dense_rows_total", ws["dense"])
-            feed("engine_dsa_decode_steps_total", ws["steps"])
-            feed("engine_dsa_decode_walk_steps_total", ws["walk_steps"])
+        if self.side is not None:
+            self.side.feed(g, feed)
         g["engine_kv_quant_enabled"].set(1 if self.args.kv_quant == "int8" else 0)
         g["engine_prefill_pad_ratio"].set(
             self.total_prefill_padded / max(1, self.total_prefilled))
@@ -1561,14 +1386,12 @@ class TpuEngine:
             ).to_dict()
             return
         ktp = req.kv_transfer_params or {}
-        if self.cfg.block != "llama" and any(k in ktp for k in (
+        if self._uncarried is not None and any(k in ktp for k in (
                 "do_remote_decode", "peer_prefix", "stream_handle", "handle", "pages")):
-            pages = {"longcat": "latent pages", "lfm2": "conv-state pool", "sala": "state pool",
-                     "dots3": "latent pages, index keys and window pool"}[self.cfg.block]
             yield LLMEngineOutput(
                 finish_reason=FinishReason.ERROR,
                 error="KV transfer (transfer/: disaggregated prefill, peer prefix "
-                      f"fetch) cannot carry a block={self.cfg.block!r} model's {pages}",
+                      f"fetch) cannot carry a block={self.cfg.block!r} model's {self._uncarried[0]}",
             ).to_dict()
             return
         vocab = self.cfg.vocab_size
@@ -2190,50 +2013,16 @@ class TpuEngine:
         max_hit = (plen - 1) // bs
         hashes_matchable = hashes[:max_hit]
         total_blocks = (plen + bs - 1) // bs
-        n_state = None
-        if self.state_stats is not None:
-            # A hit is only as deep as the chain's deepest state snapshot.
-            n_pages = len(self.pool.match_prefix(hashes_matchable))
-            n_state, seq.state_src = self.pool.snapshot_depth(hashes_matchable)
-        if self.window_stats is not None:
-            # A hit is only as deep as the deepest block whose window blocks are resident.
-            n_pages = len(self.pool.match_prefix(hashes_matchable))
-            n_state = self.window_pool.window_depth(hashes_matchable[:n_pages], self.args.window_back_blocks)
-        block_ids, n_hit = self.pool.allocate_sequence(hashes_matchable, total_blocks, max_hit=n_state)
-        if self.window_stats is not None:
-            first = max(0, n_hit - self.args.window_back_blocks)
-            held = self.window_pool.claim(hashes_matchable[first:n_hit])
-            seq.window_blocks = dict(zip(range(first, n_hit), held))
-            seq.window_claimed = set(seq.window_blocks)
-            # The deepest block of the claim that a chain other than this one
-            # continues from: a shared prompt's end (a document's, where sessions
-            # start over). The blocks up to it go back spared (``_window_release``).
-            seq.window_branch = max((j for j in range(first, n_hit) if self.pool.hash_fanout(
-                hashes_matchable[j]) - (j + 1 < n_pages) >= 1), default=-1)
-            seq.window_diverge = n_pages if n_pages > n_hit else 0
-            try:  # the first chunk's blocks now: an admission that cannot have them waits
-                self._window_cover(seq, n_hit * bs, min(plen, n_hit * bs + self.args.max_prefill_tokens) - 1)
-            except NoFreeBlocksError:
-                self._release_window(seq)
-                self.pool.free_sequence(block_ids)
-                raise
-            if n_pages:
-                ws = self.window_stats
-                ws["deepest" if n_hit == n_pages else "cut_back" if n_hit else "miss"] += 1
-                ws["recomputed_tokens"] += (n_pages - n_hit) * bs
-        elif n_state is not None:
+        depth = found = None
+        if self.side is not None:
+            depth, found = self.side.max_hit(hashes_matchable)
+        block_ids, n_hit = self.pool.allocate_sequence(hashes_matchable, total_blocks, max_hit=depth)
+        if self.side is not None:
             try:
-                seq.state_pair = self.pool.acquire_state_pair()
+                self.side.admit(seq, found, hashes_matchable, n_hit)
             except NoFreeBlocksError:
                 self.pool.free_sequence(block_ids)
                 raise
-            seq.state_plen, seq.state_hi = plen, plen - 1
-            seq.state_chunk = None
-            seq.state_diverge = n_pages * bs if n_pages > n_hit else 0
-            st = self.state_stats
-            st["snapshot" if n_hit else "zero"] += 1
-            st["cached_tokens"] += n_pages * bs
-            st["recomputed_tokens"] += (n_pages - n_hit) * bs
         seq.block_ids = block_ids
         seq.prefix_hit_blocks = n_hit
         seq.block_seq = TokenBlockSequence(prompt, bs, seq.hash_seed)
@@ -2344,174 +2133,23 @@ class TpuEngine:
             arr = self._prefill_packed(members, n_rows, t_pad)
             for row, (seq, start) in enumerate(members):
                 out.append((seq, arr, row))
-        if self.state_stats is not None:
-            self.pool.unpin_states()  # every snapshot the wave resumes from has its reader queued
+        if self.side is not None:
+            self.side.end_wave()
         return out
 
-    def _prefill_state(self, seq: _Seq, start: int, end: int) -> tuple[int, ...]:
-        """The state slots of one prefill dispatch over ``[start, end)`` of
-        ``seq`` (engine/sala.py's ``state_slots`` row): where its state is read
-        from (the snapshot it resumes from at admission's start, its own pair
-        in a later chunk), the slot of its pair the state after ``end - 1``
-        belongs in, and up to two snapshots, each a slot and the tokens of the
-        chunk up to it: where the sequence's cached pages ended (a shared
-        prompt's end) if that lies in this chunk, and the last block the chunk
-        seals."""
-        bs, pair = self.args.block_size, seq.state_pair
-        first = start == seq.prefix_hit_blocks * bs
-        src = seq.state_src if first else pair[((start - 1) // bs) % 2]
-        snaps: list[int] = []
-        for at in dict.fromkeys((seq.state_diverge, end // bs * bs)):
-            slot = 0
-            if start < at <= end:
-                sealed = seq.block_seq.blocks[at // bs - 1].sequence_hash
-                if at == seq.state_diverge:  # a branch point: it stays
-                    slot = self.pool.take_snapshot(sealed, "chunk_end")
-                else:
-                    slot = self.pool.take_snapshot(sealed, "chunk_end", replaces=seq.state_chunk)
-                    seq.state_chunk = sealed if slot else seq.state_chunk
-            snaps += [slot, at - start if slot else 0]
-        snaps += [0, 0] * (2 - len(snaps) // 2)
-        return (src, pair[((end - 1) // bs) % 2], *snaps)
+    def _side_kw(self, operand) -> dict:
+        """How a dispatch hands the runner what the block's second cache gave
+        for it: a runner of a block without one is called as it was."""
+        return {} if operand is None else {"state": operand}
 
-    def _count_choices(self, lengths, table_blocks: int | None = None) -> None:
-        """The sparse counters of one dispatch, whose query positions see
-        ``lengths`` positions each: a decode window's, or a prefill's behind
-        a page table ``table_blocks`` wide."""
-        st = self.state_stats
-        counts = choice_counts(np.asarray(lengths), self._sparse_sizes, table_blocks)
-        for key, n in zip(("chosen", "visible", "dense"), counts):
-            st[key] += n
-
-    def _decode_state(self, batch: list[_Seq], pos0: list[int], B: int, K: int) -> np.ndarray:
-        """The rows' state pairs for a decode dispatch of ``K`` steps from
-        ``pos0``, and the books: how far each row's state has been written,
-        the boundaries its steps cross, what its positions choose."""
-        bs = self.args.block_size
-        state = np.zeros((B, 3), np.int32)
-        lengths = []
-        for i, (seq, p0) in enumerate(zip(batch, pos0)):
-            # The last position anyone will want the state after: the token
-            # before the last the request may have. A finished sequence's
-            # zombie steps past it leave its pair, and its snapshot, alone.
-            stop = min(seq.prompt_len + (seq.stop.max_tokens or self.args.max_model_len),
-                       self.args.max_model_len) - 2
-            state[i] = (*seq.state_pair, stop)
-            last = min(p0 + K - 1, stop)
-            seq.state_hi = max(seq.state_hi, last)
-            self.pool.state_snapshots["decode_boundary"] += sum(
-                1 for p in range(p0, last + 1) if p % bs == 0)
-            lengths += range(p0 + 1, p0 + K + 1)
-        self._count_choices(lengths)
-        return state
-
-    def _release_state(self, seq: _Seq) -> None:
-        """``seq`` stops running (finished, failed or preempted): its pair
-        goes back, but for the slot that holds the state after its last
-        sealed block, which stays as that block's snapshot. That is the slot
-        of block b where the state after b's last position was written by
-        this residence (a decode step, or the prefill's last position) and
-        no later dispatch, a zombie window's among them, has written the
-        slot again: ``state_plen <= (b + 1) bs <= state_hi + 1`` and
-        ``state_hi < (b + 2) bs``."""
-        pair, seq.state_pair = seq.state_pair, None
-        if pair is None:
-            return
-        bs, keep = self.args.block_size, None
-        # The blocks the last window sealed: its drain registered before it
-        # emitted, and a snapshot is worth what its block's pages are.
-        self._register_written_blocks(seq)
-        n = min(len(seq.tokens), seq.kv_written, seq.registered_blocks * bs)
-        b = n // bs - 1
-        if (b >= 0 and seq.block_seq is not None
-                and seq.state_plen <= (b + 1) * bs <= seq.state_hi + 1 and seq.state_hi < (b + 2) * bs):
-            keep = (pair[b % 2], seq.block_seq.blocks[b].sequence_hash)
-        self.pool.release_state_pair(pair, keep)
-
-    # -- block="dots3": the window layers' blocks -----------------------------
-
-    def _window_cover(self, seq: _Seq, first_pos: int, last_pos: int, decoding: bool = False) -> None:
-        """``seq``'s next dispatch writes positions ``[first_pos, last_pos]``:
-        give back the window blocks behind the window of ``first_pos`` (no
-        later program of ``seq`` reads them, and the device's stream is serial,
-        so whoever gets one writes it after every reader dispatched so far) and
-        take blocks up to ``last_pos``'s. The blocks before the end of a shared
-        prompt (``window_diverge``) stay until they are registered, which a
-        chunked prefill's are when it is over: given back before, they would
-        be free, and the next to share the prompt would find nothing. So does,
-        ``decoding``, a block the windows in flight have sealed and no drain has
-        registered yet: its tokens are not on the host, so neither is its hash;
-        it goes, cached, a window or two later. Raises NoFreeBlocksError where
-        the pool cannot give a block."""
-        bs, back = self.args.block_size, self.args.window_back_blocks
-        lo = max(0, first_pos - (self.cfg.sliding_window - 1)) // bs
-        held = seq.window_blocks
-        kept = range(max(seq.window_diverge - back, seq.registered_blocks), seq.window_diverge)
-        self._window_release(seq, [i for i in held if i < lo and i not in kept
-                                   and not (decoding and i >= seq.registered_blocks)])
-        for i in range(max(lo, max(held, default=-1) + 1), last_pos // bs + 1):
-            held[i] = self.window_pool.allocate_block()
-
-    def _window_release(self, seq: _Seq, indices: list[int], final: bool = False) -> None:
-        """Give the window blocks at ``indices`` of ``seq`` back: to the warm
-        end of the pool's LRU what may be resumed from (everything where the
-        sequence stops, ``final``; what it claimed as a hit; the blocks before a
-        shared prompt's end), to the cold end what it wrote and passed. What it
-        claimed up to a block that another chain continues from
-        (``window_branch``) the pool's eviction spares while anything else is
-        left: the boundary a turn resumed from is touched again within a think
-        time, a document's only when the next session starts over on it, and
-        between two of those the sessions' turns push it out of a plain LRU
-        (PERF.md section 6, PR 50)."""
-        back = self.args.window_back_blocks
-        shared = [i for i in indices if i <= seq.window_branch]
-        warm = [i for i in indices if i > seq.window_branch and (
-            final or i in seq.window_claimed or seq.window_diverge - back <= i < seq.window_diverge)]
-        cold = [i for i in indices if i not in warm and i not in shared]
-        self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in cold], cold=True)
-        self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in warm])
-        self.window_pool.free_sequence([seq.window_blocks.pop(i) for i in shared], spare=True)
-        seq.window_claimed.difference_update(indices)
-
-    def _release_window(self, seq: _Seq) -> None:
-        """``seq`` stops running (finished, failed or preempted): every window
-        block it holds goes back, the sealed ones registered first, as the
-        boundary its next turn resumes from."""
-        if self.window_pool is None or not seq.window_blocks:
-            return
-        self._register_written_blocks(seq)
-        self._window_release(seq, list(seq.window_blocks), final=True)
-
-    def _window_row(self, seq: _Seq, first_pos: int, width: int) -> np.ndarray:
-        """``seq``'s window table for a dispatch whose first position is
-        ``first_pos`` (engine/dots3.py's ``state_slots`` row): the index of the
-        window's first block, then the blocks from it on."""
-        lo = max(0, first_pos - (self.cfg.sliding_window - 1)) // self.args.block_size
-        row = np.zeros((1 + width,), np.int32)
-        row[0] = lo
-        for i, bid in seq.window_blocks.items():
-            if 0 <= i - lo < width:
-                row[1 + i - lo] = bid
-        return row
-
-    def _decode_window_tables(self, batch: list[_Seq], pos0: list[int], B: int, K: int, W: int) -> np.ndarray:
-        """The rows' window tables for a decode dispatch of ``K`` steps from
-        ``pos0`` in the ``B``-row program at a table of ``W`` blocks
-        (``_ensure_block`` has covered them), and what the full layers' rows
-        choose and how each step attends it: the engine_dsa_* books."""
-        state = np.zeros((B, 1 + self.args.window_table_width), np.int32)
-        for i, (seq, p0) in enumerate(zip(batch, pos0)):
-            state[i] = self._window_row(seq, p0, self.args.window_table_width)
-        ws, topk = self.window_stats, self.cfg.index_topk
-        seen = np.asarray(pos0)[:, None] + np.arange(1, K + 1)[None, :]  # what each row's steps see
-        ws["visible"] += int(seen.sum())
-        ws["chosen"] += int(np.minimum(seen, topk).sum())
-        ws["dense"] += int((seen <= topk).sum())
-        for lengths in seen.T:  # a step's rows, as the program sees them (engine/dots3.py:decode_step_impl)
-            if lengths.max() > topk:
-                ws["steps"] += 1
-                ws["walk_steps"] += bool(dsa.walk_is_cheaper(lengths, B, W * self.args.block_size, topk))
-        return state
+    def _release_side(self, seq: _Seq) -> None:
+        """``seq`` stops running (finished, failed or preempted): what it
+        holds of the block's second cache goes back, the blocks its last
+        window sealed registered first (its drain registered before it
+        emitted, and what stays cached there is worth what their pages are)."""
+        if seq.side is not None:
+            self._register_written_blocks(seq)
+            self.side.release(seq)
 
     def _prefill_packed(
         self, members: list[tuple[_Seq, int]], Bp: int, t_pad: int
@@ -2534,19 +2172,9 @@ class TpuEngine:
             starts[r] = start
             tlens[r] = len(seq.tokens)
         aslots = self._adapter_row_slots([s for s, _ in members], Bp)
-        state_kw = {}  # a block with a state pool takes its rows' slots; any other runner is called as it was
-        if self.state_stats is not None:
-            state_kw["state"] = np.zeros((Bp, 6), np.int32)
-            for r, (seq, start) in enumerate(members):
-                state_kw["state"][r] = self._prefill_state(seq, start, len(seq.tokens))
-            self._count_choices(np.concatenate([np.arange(a + 1, len(s.tokens) + 1) for s, a in members]), W)
-        if self.window_stats is not None:
-            state_kw["state"] = np.zeros((Bp, self.args.state_operand_width), np.int32)
-            for r, (seq, start) in enumerate(members):
-                self._window_cover(seq, start, len(seq.tokens) - 1)
-                state_kw["state"][r] = self._window_row(seq, start, self.args.window_prefill_width)
+        side = self.side and self.side.prefill_rows([(s, a, len(s.tokens)) for s, a in members], Bp, W)
         self._dispatching()
-        ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots, **state_kw)
+        ref = self._runner.prefill_batch(toks, tables, starts, tlens, aslots, **self._side_kw(side))
         self._dispatched(ref.arrs)
         self.total_prefill_padded += Bp * t_pad
         self.prefill_dispatch_rows[Bp] += 1
@@ -2582,17 +2210,12 @@ class TpuEngine:
             t_pad = self.args.bucket_prefill(len(chunk))
             toks = np.zeros((t_pad,), np.int32)
             toks[: len(chunk)] = chunk
-            state_kw = {}
-            if self.state_stats is not None:
-                state_kw["state"] = np.asarray(self._prefill_state(seq, pos, pos + len(chunk)), np.int32)
-                self._count_choices(np.arange(pos + 1, pos + len(chunk) + 1), W)
-            if self.window_stats is not None:
-                self._window_cover(seq, pos, pos + len(chunk) - 1)
-                state_kw["state"] = self._window_row(seq, pos, self.args.window_prefill_width)
+            side = self.side and self.side.prefill_rows([(seq, pos, pos + len(chunk))], 1, W)
             self._dispatching()
             logits = self._runner.prefill_chunk(
                 toks, table, pos, min(pos + len(chunk), plen),
-                seq.adapter_slot if seq.adapter_slot >= 0 else None, **state_kw,
+                seq.adapter_slot if seq.adapter_slot >= 0 else None,
+                **self._side_kw(None if side is None else side[0]),
             )
             self._dispatched(logits.arrs)
             self.total_prefill_padded += t_pad
@@ -2839,14 +2462,8 @@ class TpuEngine:
     def migration_begin(self, request_id: str) -> dict:
         """Start streaming a running decode's KV. → {"ok", "handle",
         "published"} or {"error"}. Scheduler thread only."""
-        if self.cfg.block == "longcat":
-            return {"error": "live migration cannot carry latent (MLA) pages"}
-        if self.cfg.block == "lfm2":
-            return {"error": "live migration cannot carry the conv-state pool"}
-        if self.cfg.block == "sala":
-            return {"error": "live migration cannot carry the state pool"}
-        if self.cfg.block == "dots3":
-            return {"error": "live migration cannot carry latent pages, index keys and the window pool"}
+        if self._uncarried is not None:
+            return {"error": f"live migration cannot carry {self._uncarried[1]}"}
         seq = next(
             (s for s in self._running if s.request_id == request_id), None
         )
@@ -3069,9 +2686,8 @@ class TpuEngine:
             blk = seq.block_seq.blocks[seq.registered_blocks]
             bid = seq.block_ids[seq.registered_blocks]
             self.pool.register_block(bid, blk.sequence_hash, blk.parent_sequence_hash)
-            if self.window_pool is not None and seq.registered_blocks in seq.window_blocks:
-                self.window_pool.register_block(
-                    seq.window_blocks[seq.registered_blocks], blk.sequence_hash, blk.parent_sequence_hash)
+            if seq.side is not None:
+                self.side.registered(seq, seq.registered_blocks, blk)
             # Write-through offload: queue the sealed block for the end-of-
             # step batched extract (bounded; duplicates in tiers skipped).
             if (
@@ -3093,12 +2709,7 @@ class TpuEngine:
                 seq.block_ids.append(self.pool.allocate_block())
             except NoFreeBlocksError:
                 return False
-        if self.window_pool is not None:
-            try:
-                self._window_cover(seq, seq.next_write_pos + self._pend(seq), last_pos, decoding=True)
-            except NoFreeBlocksError:
-                return False
-        return True
+        return self.side is None or self.side.cover_decode(seq, seq.next_write_pos + self._pend(seq), last_pos)
 
     def _maybe_pressure_offer(self) -> None:
         """Proactive defrag (ISSUE 19 tentpole (d)): when KV pool usage
@@ -3213,8 +2824,7 @@ class TpuEngine:
             seq.export_handle = None
             seq.export_pub_blocks = 0
             seq.export = False
-        self._release_state(seq)
-        self._release_window(seq)
+        self._release_side(seq)
         self.pool.free_sequence(seq.block_ids)
         seq.block_ids = []
         seq.registered_blocks = 0
@@ -3476,15 +3086,13 @@ class TpuEngine:
             if any(s.sampling.top_logprobs for s in batch) else 0
         )
         aslots = self._adapter_row_slots(batch, B)
-        state_kw = {"state": self._decode_state(batch, pos0, B, K)} if self.state_stats is not None else {}
-        if self.window_stats is not None:
-            state_kw = {"state": self._decode_window_tables(batch, pos0, B, K, W)}
+        side = self.side and self.side.decode_rows(batch, pos0, B, K, W)
         self._enter("decode_dispatch")
         self._dispatching()
         ref = self._runner.multi_decode(
             K, mode, tokens, wchain, positions, tables, active,
             temps, seeds, steps0, tks, tps, freqs, press, pen, fold_slots,
-            top_n, aslots, **state_kw,
+            top_n, aslots, **self._side_kw(side),
         )
         self._dispatched(ref.arrs)
         w = _Window(batch, pos0, K, ref, top_n)
@@ -3928,14 +3536,9 @@ class TpuEngine:
             tables[i, : len(seq.block_ids)] = seq.block_ids
             active[i] = True
         aslots = self._adapter_row_slots(batch, B)
-        state_kw = {}
-        if self.state_stats is not None:
-            state_kw["state"] = self._decode_state(batch, [int(p) for p in positions[: len(batch)]], B, 1)
-        if self.window_stats is not None:
-            state_kw["state"] = self._decode_window_tables(
-                batch, [int(p) for p in positions[: len(batch)]], B, 1, W)
+        side = self.side and self.side.decode_rows(batch, [int(p) for p in positions[: len(batch)]], B, 1, W)
         self._dispatching()
-        ref = self._runner.decode_step(tokens, positions, tables, active, aslots, **state_kw)
+        ref = self._runner.decode_step(tokens, positions, tables, active, aslots, **self._side_kw(side))
         self._dispatched(ref.arrs)
         self.total_decode_steps += 1
         self.total_decode_rows_dispatched += B
@@ -4123,8 +3726,7 @@ class TpuEngine:
             self._offload_pending = [
                 (b, h) for b, h in self._offload_pending if b not in freed
             ]
-        self._release_state(seq)
-        self._release_window(seq)
+        self._release_side(seq)
         self.pool.free_sequence(seq.block_ids)
         seq.block_ids = []
         if not already_posted:

@@ -713,9 +713,6 @@ class CompiledGrammar:
             return base | eos_bits
         return base
 
-    def states_visited(self) -> int:
-        return len(self._token_trans)
-
 
 class GrammarCompiler:
     """Schema-hash-keyed cache of CompiledGrammar instances over one

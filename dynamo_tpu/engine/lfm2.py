@@ -74,6 +74,7 @@ from dynamo_tpu.ops.paged_attention import (
 Params = dict[str, Any]
 
 START_LINE = " block=lfm2"  # what the engine's start line says of this block
+UNCARRIED = ("conv-state pool", "the conv-state pool")
 
 # The seeded router's logits have this deviation (a normed stream times
 # w_router), and the expert bias is drawn at this scale: sigmoid scores then

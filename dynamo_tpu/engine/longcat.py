@@ -64,6 +64,7 @@ from dynamo_tpu.ops.paged_attention import (
 
 Params = dict[str, Any]
 
+UNCARRIED = ("latent pages", "latent (MLA) pages")
 # Assignment rows the grouped product is given at a time (tokens x min(top-k,
 # experts held)): 512 tokens of this block's twelve choices, so its 2,048-token
 # chunk is routed in four parts; a block of four choices routes 1,536 tokens

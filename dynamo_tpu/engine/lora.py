@@ -141,14 +141,3 @@ def bank_shapes(cfg: ModelConfig, slots: int, max_rank: int) -> dict[str, tuple]
         shapes[f"{t}a"] = (cfg.num_layers, slots, fan_in, max_rank)
         shapes[f"{t}b"] = (cfg.num_layers, slots, max_rank, fan_out)
     return shapes
-
-
-def adapter_bank_bytes(cfg: ModelConfig, slots: int, max_rank: int,
-                       itemsize: int = 2) -> int:
-    """HBM bytes of the device adapter bank (all targets, both factors) —
-    the G1 footprint the slot count buys."""
-    per_slot = 0
-    for t in LORA_TARGETS:
-        fan_in, fan_out = _target_dims(cfg, t)
-        per_slot += cfg.num_layers * max_rank * (fan_in + fan_out)
-    return slots * per_slot * itemsize

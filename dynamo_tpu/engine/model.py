@@ -264,7 +264,9 @@ def block_module(cfg: ModelConfig):
     ``init_kv_cache`` and the jitted ``prefill``, ``prefill_batch``,
     ``decode_step`` and ``multi_decode`` under these names; a block that
     routes (``routed_layers(cfg)``) returns a routing histogram after what
-    these return."""
+    these return, one with a second cache names its kind (``side_cache``,
+    engine/side.py), and ``UNCARRIED`` is what KV transfer's refusal and live
+    migration's say they cannot carry of its cache."""
     if cfg.block not in BLOCK_MODULES:
         raise ValueError(f"no module runs block={cfg.block!r} ({', '.join(BLOCK_MODULES)})")
     if cfg.block != BLOCK_MODULES[0]:

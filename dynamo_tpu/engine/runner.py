@@ -72,18 +72,6 @@ def pack_limit(cfg, weight_bytes: int, ops_per_byte: float = _OPS_PER_BYTE_DEFAU
     return int(weight_bytes * ops_per_byte / (2 * cfg.active_param_count()))
 
 
-def pack_row_tokens(cfg, context: int) -> int:
-    """What a row of a pack costs beside its padded tokens, in tokens: the
-    operations the block spends on a row whatever its length (the block
-    module's own ``prefill_row_ops`` over the table's ``context``, where it
-    has one) over the operations of a token. 0 for every block today: each
-    one's prefill attention walks only what a row can see (the latent block's
-    since PR 44; its XLA form multiplied the table's 4,096 latents out once a
-    row, 99 tokens' worth)."""
-    row_ops = getattr(M.block_module(cfg), "prefill_row_ops", None)
-    return -(-row_ops(cfg, context) // (2 * cfg.active_param_count())) if row_ops else 0
-
-
 @jax.jit
 def _fold_tokens(last_toks, toks, slots):
     """Scatter sampled tokens into the persistent per-slot buffer (one
@@ -189,7 +177,6 @@ class LocalRunner:
         # 0 under a mesh) and the (rows, T) programs compiled so far, by a
         # thread of this runner's own, off the request path.
         self.pack_limit_tokens = 0
-        self.pack_row_tokens = 0  # and what a row counts beside its T
         self._packed: dict[tuple[int, int], Any] = {}
         self._pack_thread: threading.Thread | None = None
         self._pack_stop = threading.Event()
@@ -290,7 +277,6 @@ class LocalRunner:
             self.pack_limit_tokens = pack_limit(
                 self.cfg, weight_bytes,
                 _OPS_PER_BYTE.get(jax.devices()[0].device_kind, _OPS_PER_BYTE_DEFAULT))
-            self.pack_row_tokens = pack_row_tokens(self.cfg, self.args.max_model_len)
         log.info("engine start: %s", self._start_line(attn_note, prefill_note))
         self._start_pack_compiles()
 
@@ -302,7 +288,7 @@ class LocalRunner:
         alone: the engine dispatches a pack only once its program is in
         ``packed_ready``, so none is ever built inside a request, and the
         worker serves singles meanwhile."""
-        shapes = self.args.pack_shapes(self.pack_limit_tokens, self.pack_row_tokens)
+        shapes = self.args.pack_shapes(self.pack_limit_tokens)
         if not shapes:
             return
 
@@ -446,7 +432,6 @@ class LocalRunner:
             f"dtype={a.dtype} quant={a.quant} kv_quant={a.kv_quant} "
             f"kv_page_bytes={a.kv_page_bytes()} "
             f"attention: prefill={prefill} decode={decode} spec_verify={spec}{block}{experts}{hbm}"
-            f"{f' prefill_pack_row={self.pack_row_tokens}' if self.pack_row_tokens else ''}"
             f" prefill_pack<={self.pack_limit_tokens} tok{assumed}"
         )
 
@@ -711,10 +696,6 @@ class LocalRunner:
         M.refuse_block(self.cfg, "KV page injection (transfer/, tiers, migration)")
         pages = kv_transfer.adapt_pages(pages, self.cache, self.cfg.num_kv_heads)
         self.cache = kv_transfer.inject_pages(self.cache, block_ids, *pages)
-
-    def clear_cache_refs(self) -> None:
-        """Drop chain/sample refs (admin /clear_kv_blocks support)."""
-        self._refs.clear()
 
 
 # ---------------------------------------------------------------------------

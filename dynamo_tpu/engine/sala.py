@@ -62,6 +62,7 @@ from dynamo_tpu.engine.model import (
     write_kv_pages, write_kv_tokens,
 )
 from dynamo_tpu.engine.quant import SALA_LAYER_WEIGHTS
+from dynamo_tpu.engine.side import StateSlots as side_cache  # noqa: F401 — this block's second cache (engine/side.py)
 from dynamo_tpu.ops import sparse_attention as sparse
 from dynamo_tpu.ops.lightning import PREFILL_CHUNK, lightning_decode, lightning_decode_xla, lightning_prefill
 from dynamo_tpu.ops.paged_attention import (
@@ -75,7 +76,7 @@ from dynamo_tpu.ops.paged_attention import (
 Params = dict[str, Any]
 
 START_LINE = " block=sala"  # what the engine's start line says of this block
-STATE_SLOTS = True          # the runner passes ``state_slots`` to every program
+UNCARRIED = ("state pool", "the state pool")
 
 
 

@@ -285,8 +285,3 @@ def global_config() -> Config:
     if _GLOBAL is None:
         _GLOBAL = Config.from_env()
     return _GLOBAL
-
-
-def reset_global_config() -> None:
-    global _GLOBAL
-    _GLOBAL = None

@@ -17,7 +17,6 @@ import json
 import logging
 import os
 import re
-import secrets
 import sys
 import time
 from dataclasses import dataclass
@@ -43,10 +42,6 @@ class TraceContext:
         if version == "ff" or trace_id == "0" * 32 or span_id == "0" * 16:
             return None
         return cls(trace_id=trace_id, parent_span_id=span_id, flags=flags, tracestate=tracestate)
-
-    @classmethod
-    def new_root(cls) -> "TraceContext":
-        return cls(trace_id=secrets.token_hex(16), parent_span_id=secrets.token_hex(8))
 
     # NOTE: span ids within a trace are minted by runtime/tracing.py at
     # actual span boundaries (Span.trace_context()); re-minting one here

@@ -103,12 +103,6 @@ class CreditBudget:
             if delivered:
                 self.charged_bytes[kind] = self.charged_bytes.get(kind, 0) + delivered
 
-    def outstanding(self, kind: str | None = None) -> int:
-        with self._lock:
-            if kind is not None:
-                return self._outstanding.get(kind, 0)
-            return sum(self._outstanding.values())
-
 
 _process_budget: CreditBudget | None = None
 

@@ -20,6 +20,7 @@ from dynamo_tpu.engine import dots3, longcat
 from dynamo_tpu.engine import model as M
 from dynamo_tpu.engine.config import EngineArgs
 from dynamo_tpu.engine.engine import TpuEngine
+from dynamo_tpu.engine.side import WindowBlocks
 from dynamo_tpu.llm.protocols import PreprocessedRequest
 from dynamo_tpu.ops import dsa
 from dynamo_tpu.ops import paged_attention as pa
@@ -468,12 +469,12 @@ def test_the_hosts_count_of_walked_steps_is_the_programs_predicate():
 
     stats = {"chosen": 0, "visible": 0, "dense": 0, "steps": 0, "walk_steps": 0}
     topk, bs, B, W, K = 2048, 32, 4, 1024, 8
-    engine = SimpleNamespace(window_stats=stats, cfg=SimpleNamespace(index_topk=topk, sliding_window=513),
-                             args=SimpleNamespace(block_size=bs, window_table_width=18),
-                             _window_row=lambda seq, p0, width: np.zeros((1 + width,), np.int32))
+    kind = SimpleNamespace(stats=stats, cfg=SimpleNamespace(index_topk=topk, sliding_window=513),
+                           args=SimpleNamespace(block_size=bs, window_table_width=18),
+                           _row=lambda seq, p0, width: np.zeros((1 + width,), np.int32))
     want_steps = want_walk = 0
     for pos0 in ([24000, 30000, 100], [2040, 2043], [32700] * 4, [5, 9]):
-        TpuEngine._decode_window_tables(engine, [None] * len(pos0), pos0, B, K, W)
+        WindowBlocks.decode_rows(kind, [None] * len(pos0), pos0, B, K, W)
         for j in range(K):
             positions = jnp.asarray(pos0 + [0] * (B - len(pos0)), jnp.int32) + j
             active = jnp.arange(B) < len(pos0)
@@ -590,15 +591,15 @@ def _alone(tokens: list[int], n: int) -> list[int]:
 def _poison_what_is_given_back(engine) -> None:
     """After every release, every free block of the window pool is overwritten
     on the device: a program dispatched later that read one would read 1e4s."""
-    release = engine._window_release
+    release = engine.side._release
 
     def poisoned(seq, indices, final=False):
         release(seq, indices, final)
-        free = jnp.asarray(sorted(engine.window_pool._free), jnp.int32)
+        free = jnp.asarray(sorted(engine.side.pool._free), jnp.int32)
         cache = engine._runner.cache
         engine._runner.cache = cache._replace(window=cache.window.at[:, free].set(1e4))
 
-    engine._window_release = poisoned
+    engine.side._release = poisoned
 
 
 @pytest.mark.parametrize("evict", ["nothing", "the_boundary", "everything"])
@@ -616,7 +617,7 @@ def test_a_follow_up_turn_resumes_where_its_window_blocks_are_resident(evict):
         try:
             a = await _tokens(engine, greedy(first, 20))
             second = first + a + prompt(13, seed=2)
-            wp = engine.window_pool
+            wp = engine.side.pool
 
             def drop():  # on the scheduler thread
                 if evict == "everything":
@@ -632,7 +633,7 @@ def test_a_follow_up_turn_resumes_where_its_window_blocks_are_resident(evict):
             b = await _tokens(engine, greedy(second, 8))
             # on the scheduler thread: a stream's last delta is posted before its blocks go back
             active = await engine.run_on_engine_thread(lambda: wp.num_active)
-            return a, second, b, dict(engine.window_stats), dict(wp.released), active
+            return a, second, b, dict(engine.side.stats), dict(wp.released), active
         finally:
             await engine.stop()
 
@@ -657,7 +658,7 @@ def test_what_the_engine_serves_is_the_references_best_at_every_token():
             first = prompt(150, seed=1)
             a = await _tokens(engine, greedy(first, 40))
             second = first + a + prompt(23, seed=2)
-            return first, a, second, await _tokens(engine, greedy(second, 30)), dict(engine.window_stats)
+            return first, a, second, await _tokens(engine, greedy(second, 30)), dict(engine.side.stats)
         finally:
             await engine.stop()
 
@@ -686,7 +687,7 @@ def test_a_shared_prompts_end_is_kept_for_the_next_to_share_it():
             out = []
             for seed, more in ((6, 70), (7, 30), (8, 30)):
                 out.append(await _tokens(engine, greedy(shared + prompt(more, seed=seed), 6)))
-                out.append(dict(engine.window_stats))
+                out.append(dict(engine.side.stats))
             return out
         finally:
             await engine.stop()
@@ -713,7 +714,7 @@ def test_a_shared_prompts_end_is_spared_while_the_lru_holds_anything_else():
             first = shared + prompt(30, seed=8)
             second = first + await _tokens(engine, greedy(first, 6)) + prompt(13, seed=9)  # claims the shared end
             await _tokens(engine, greedy(second, 12))                                    # claims its own last boundary
-            wp = engine.window_pool
+            wp = engine.side.pool
             own, end = compute_block_hashes(second, BS)[len(first + [0] * 5) // BS - 1], compute_block_hashes(shared, BS)[-1]
 
             def squeeze():  # on the scheduler thread: take every block the pool can give
@@ -723,7 +724,7 @@ def test_a_shared_prompts_end_is_spared_while_the_lru_holds_anything_else():
                 left = set(wp._cached)
                 wp.free_sequence(taken)
                 return spared, fanout, left
-            return own, end, await engine.run_on_engine_thread(squeeze), dict(engine.window_stats)
+            return own, end, await engine.run_on_engine_thread(squeeze), dict(engine.side.stats)
         finally:
             await engine.stop()
 
@@ -748,9 +749,9 @@ def test_preempted_sequences_return_and_packed_rows_of_different_depths_agree_wi
                 histories.append(p + await _tokens(engine, greedy(p, 6)) + prompt(9, seed=10 + s))
             n0 = sum(engine.total_preemptions_by.values())
             together = await asyncio.gather(*(_tokens(engine, greedy(h, 40)) for h in histories))
-            wp = engine.window_pool
+            wp = engine.side.pool
             held = await engine.run_on_engine_thread(lambda: wp.num_active)
-            return histories, list(together), sum(engine.total_preemptions_by.values()) - n0, held, dict(engine.window_stats)
+            return histories, list(together), sum(engine.total_preemptions_by.values()) - n0, held, dict(engine.side.stats)
         finally:
             await engine.stop()
 

@@ -46,6 +46,10 @@ async def _start_stack(url: str):
     from dynamo_tpu.engine.engine import TpuEngine
 
     rt = await DistributedRuntime.create(store_url=url)
+    # Under -n 6 on a loaded machine one 200-token grammar request holds this
+    # process 12-15 s, past the default 10 s lease: the worker then leaves
+    # discovery and the next request is a 404. The lease is not what is tested.
+    rt.config.store.lease_ttl = 120.0
     engine = await TpuEngine(EngineArgs(
         model=ModelConfig(), block_size=4, num_kv_blocks=320, max_num_seqs=8,
         max_model_len=256, max_prefill_tokens=128, dtype="float32",

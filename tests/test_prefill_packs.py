@@ -18,7 +18,7 @@ from chipbench import run as chipbench_run
 from dynamo_tpu.engine import model as M
 from dynamo_tpu.engine.config import PACK_ROWS, EngineArgs, ModelConfig
 from dynamo_tpu.engine.engine import TpuEngine
-from dynamo_tpu.engine.runner import _OPS_PER_BYTE, LocalRunner, pack_limit, pack_row_tokens
+from dynamo_tpu.engine.runner import _OPS_PER_BYTE, LocalRunner, pack_limit
 from dynamo_tpu.llm.protocols import PreprocessedRequest
 from dynamo_tpu.runtime.engine import Context
 from dynamo_tpu.runtime.metrics import MetricsRegistry
@@ -66,10 +66,9 @@ def test_the_limit_comes_from_the_models_bytes_and_operations(name, lo, hi, shap
     did not fit). No block has a cost a row beside its tokens now. No program
     holds more than ``max_prefill_tokens``."""
     eargs, limit = published(name)
-    row = pack_row_tokens(eargs.model, eargs.max_model_len)
-    assert lo <= limit <= hi and row == 0
-    assert eargs.pack_shapes(limit, row) == shapes
-    assert all(rows * (t + row) <= limit and rows * t <= eargs.max_prefill_tokens for rows, t in shapes)
+    assert lo <= limit <= hi
+    assert eargs.pack_shapes(limit) == shapes
+    assert all(rows * t <= limit and rows * t <= eargs.max_prefill_tokens for rows, t in shapes)
     turns = [150, 190, 170, 130]   # four session turns: the 192 bucket
     packs = eargs.plan_prefill_packs(turns, shapes)
     if name.startswith("lfm2"):
@@ -86,7 +85,7 @@ def test_the_limit_comes_from_the_models_bytes_and_operations(name, lo, hi, shap
         assert packs == [([i], 1, 192) for i in (1, 2, 0, 3)]
         assert [rows for _, rows, _ in eargs.plan_prefill_packs([40, 30], shapes)] == [1, 1]
         # The same weights in bf16 double the limit, and short suffixes pack.
-        assert (4, 32) in eargs.pack_shapes(2 * limit, row)
+        assert (4, 32) in eargs.pack_shapes(2 * limit)
 
 
 def test_the_chip_is_looked_up_by_kind_and_an_unknown_one_is_a_v5e_and_says_so():
@@ -137,14 +136,15 @@ def test_a_wave_packs_across_t_buckets_into_the_programs_there_are(suffixes, sha
     assert all(rows // 2 < len(idx) <= rows for idx, rows, _ in want)
 
 
-@pytest.mark.parametrize("row", [0, 99])
+@pytest.mark.parametrize("chunk", [2048, 512])
 @pytest.mark.parametrize("limit", [0, 63, 120, 445, 1718, 5000])
-def test_no_pack_shape_passes_the_limit_or_the_largest_single(limit, row):
-    shapes = ARGS.pack_shapes(limit, row)
-    assert all(rows in PACK_ROWS and rows * (t + row) <= limit and rows * t <= 2048
-               and t in ARGS.prefill_buckets for rows, t in shapes)
+def test_no_pack_shape_passes_the_limit_or_the_largest_single(limit, chunk):
+    args = ARGS.replace(max_prefill_tokens=chunk)
+    shapes = args.pack_shapes(limit)
+    assert all(rows in PACK_ROWS and rows * t <= limit and rows * t <= chunk
+               and t in args.prefill_buckets for rows, t in shapes)
     assert len(shapes) <= 2 * len(PACK_ROWS)
-    assert (limit < PACK_ROWS[-1] * (ARGS.prefill_buckets[0] + row)) == (shapes == ())
+    assert (limit < PACK_ROWS[-1] * args.prefill_buckets[0]) == (shapes == ())
 
 
 # -- through the engine ----------------------------------------------------------

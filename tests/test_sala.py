@@ -428,7 +428,7 @@ def greedy(prompt_ids, max_tokens=6, **ktp) -> PreprocessedRequest:
 
 def engine_args(**kw) -> EngineArgs:
     # Windows of 2 steps: a finished sequence's zombie window then stays inside the
-    # block after its last sealed one, as 8 steps do in blocks of 64 (engine._release_state).
+    # block after its last sealed one, as 8 steps do in blocks of 64 (StateSlots.release).
     return EngineArgs(**{**dict(model=CFG, block_size=BS, num_kv_blocks=64, max_num_seqs=4, max_model_len=256,
                                 max_prefill_tokens=32, dtype="float32", decode_steps=2), **kw})
 
@@ -460,9 +460,9 @@ def test_a_follow_up_turn_resumes_from_a_snapshot_and_recomputes_under_a_block(r
             snaps = dict(engine.pool.state_snapshots)
             second = first + a + prompt(13, seed=2)
             b = await _tokens(engine, greedy(second, 8))
-            stats = dict(engine.state_stats)
+            stats = dict(engine.side.stats)
             await _tokens(engine, greedy(first[:64] + prompt(20, seed=3), 4))
-            return a, second, b, snaps, stats, dict(engine.state_stats), engine.pool.num_snapshots
+            return a, second, b, snaps, stats, dict(engine.side.stats), engine.pool.num_snapshots
         finally:
             await engine.stop()
 
@@ -506,7 +506,7 @@ def test_where_cached_pages_end_without_a_snapshot_the_next_prefill_leaves_one()
             await settled()
             await engine.run_on_engine_thread(lambda: [pool._drop_snapshot(h) for h in list(pool._snapshots)])
             await _tokens(engine, greedy(shared + prompt(30, seed=13), 20))
-            second = dict(engine.state_stats)
+            second = dict(engine.side.stats)
             from dynamo_tpu.tokens import compute_block_hashes
             end = compute_block_hashes(shared, BS)[-1]
             await settled()
@@ -520,7 +520,7 @@ def test_where_cached_pages_end_without_a_snapshot_the_next_prefill_leaves_one()
 
             fanout, spared = pool.hash_fanout(end), await engine.run_on_engine_thread(squeeze)
             await _tokens(engine, greedy(shared + prompt(30, seed=14), 4))
-            return second, dict(engine.state_stats), fanout, end in pool._snapshots, spared
+            return second, dict(engine.side.stats), fanout, end in pool._snapshots, spared
         finally:
             await engine.stop()
 
@@ -543,7 +543,7 @@ def test_a_preempted_sequence_returns_through_the_same_path():
             together = await asyncio.gather(*(_tokens(engine, greedy(prompt(40, seed=s), 30)) for s in (1, 2, 3)))
             pool = engine.pool  # on the scheduler thread: a stream's last delta is posted before its pair goes back
             slots = await engine.run_on_engine_thread(lambda: len(pool._state_free) + pool.num_snapshots)
-            return alone, list(together), sum(engine.total_preemptions_by.values()) - n0, slots, dict(engine.state_stats)
+            return alone, list(together), sum(engine.total_preemptions_by.values()) - n0, slots, dict(engine.side.stats)
         finally:
             await engine.stop()
 
