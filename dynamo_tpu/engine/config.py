@@ -135,6 +135,25 @@ class ModelConfig:
     swa_v_head_dim: int = 0
     swa_rope_theta: float = 10000.0
     num_shared_experts: int = 0
+    # -- block="deepseek" (DeepSeek-V2, engine/deepseek.py) ------------------
+    # One MLA attention (low-rank query) and one feed-forward a layer: dense
+    # for the first ``num_dense_layers``, then ``num_experts`` routed experts
+    # (all of them: a mesh holds whole layers) beside ``num_shared_experts``
+    # shared ones. ``topk_method`` "group_limited_greedy" is the router's third
+    # arithmetic (engine/longcat.py:route): softmax, the experts in ``n_group``
+    # consecutive groups, the ``topk_group`` groups of the highest best score
+    # kept, the top-k among their experts. The ``yarn_*`` keys are the
+    # published ``rope_scaling`` of type "yarn" (``yarn_factor`` 0: none),
+    # static: applied at every length (engine/deepseek.py:yarn_inv_freq).
+    topk_method: str = "greedy"
+    n_group: int = 0
+    topk_group: int = 0
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     @property
     def q_size(self) -> int:
@@ -193,6 +212,16 @@ class ModelConfig:
                          + (self.num_shared_experts + experts_counted) * 3 * d * ie))
         return (2 * v * d + d + len(self.full_layers) * (mla(self) + indexer)
                 + len(self.window_layers) * mla(self.swa) + ff)
+
+    def _deepseek_params(self, experts_counted: int) -> int:
+        d, v, h = self.hidden_size, self.vocab_size, self.num_heads
+        ie = self.moe_intermediate_size or self.intermediate_size
+        mla = (d * self.q_lora_rank + self.q_lora_rank * h * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+               + d * self.latent_dim + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
+               + h * self.v_head_dim * d + self.q_lora_rank + self.kv_lora_rank + 2 * d)
+        n_moe = self.num_layers - self.num_dense_layers
+        return (2 * v * d + d + self.num_layers * mla + self.num_dense_layers * 3 * d * self.intermediate_size
+                + n_moe * (d * self.num_experts + (self.num_shared_experts + experts_counted) * 3 * d * ie))
 
     @property
     def cache_layers(self) -> int:
@@ -293,6 +322,8 @@ class ModelConfig:
             return self._sala_params()
         if self.block == "dots3":
             return self._dots3_params(self.num_experts)
+        if self.block == "deepseek":
+            return self._deepseek_params(self.num_experts)
         if self.block == "lfm2":
             return self._lfm2_params(self.num_experts)
         if self.block == "longcat":
@@ -321,6 +352,8 @@ class ModelConfig:
         d, v = self.hidden_size, self.vocab_size
         if self.block == "lfm2":
             return self._lfm2_params(self.num_experts_per_token)
+        if self.block == "deepseek":
+            return self._deepseek_params(self.num_experts_per_token)
         if self.block == "dots3":
             return self._dots3_params(
                 self.num_experts_per_token * self.num_experts // max(self.router_width, 1))
@@ -451,6 +484,21 @@ class ModelConfig:
                 num_experts_per_token=2, num_shared_experts=1, moe_intermediate_size=64,
                 routed_scaling_factor=1.0, router_scoring="sigmoid", use_expert_bias=True,
                 norm_topk_prob=True,
+            ),
+            # DeepSeek-V2 block at toy widths (CPU tests): a dense-FFN layer, then
+            # two expert layers of 16 experts in 4 groups of which 2 are kept, 4
+            # a token, two shared; YaRN on the 16 rope lanes. Every count divides
+            # by 4: the same model runs whole on one device and under --tp 4.
+            "deepseek-tiny": ModelConfig(
+                name="deepseek-tiny", block="deepseek", vocab_size=512, hidden_size=128,
+                intermediate_size=256, num_layers=3, num_heads=8, num_kv_heads=1, head_dim=48,
+                rope_theta=10000.0, rms_norm_eps=1e-6, tie_embeddings=False, max_position=4096,
+                q_lora_rank=64, kv_lora_rank=96, qk_nope_head_dim=32, qk_rope_head_dim=16,
+                v_head_dim=32, num_dense_layers=1, num_experts=16, num_routed_experts=16,
+                num_experts_per_token=4, num_shared_experts=2, moe_intermediate_size=64,
+                routed_scaling_factor=16.0, topk_method="group_limited_greedy", n_group=4,
+                topk_group=2, yarn_factor=40.0, yarn_original_max_position=64, yarn_beta_fast=32.0,
+                yarn_beta_slow=1.0, yarn_mscale=0.707, yarn_mscale_all_dim=0.707,
             ),
             # Llama-3-70B-class (BASELINE.md north-star target, multi-host)
             "llama-70b": ModelConfig(
@@ -858,6 +906,37 @@ class EngineArgs:
                     f"model {m.name!r} has block='dots3' (latent pages with index keys, and a second pool "
                     f"of window pages with a lifetime of its own), which cannot run with: {'; '.join(refused)}"
                 )
+        if self.model.block == "deepseek":
+            m = self.model
+            if (m.topk_method != "group_limited_greedy" or not m.n_group or m.num_experts % m.n_group
+                    or not 0 < m.topk_group <= m.n_group or m.num_dense_layers != 1
+                    or m.num_experts_per_token > m.topk_group * (m.num_experts // max(m.n_group, 1))):
+                raise ValueError(
+                    f"model {m.name!r}: block='deepseek' routes by topk_method 'group_limited_greedy' over "
+                    f"n_group groups that divide its experts, keeps topk_group of them with room for a token's "
+                    f"experts, and has one leading dense layer; got {m.topk_method!r}, {m.num_experts} experts, "
+                    f"n_group {m.n_group}, topk_group {m.topk_group}, {m.num_experts_per_token} a token, "
+                    f"{m.num_dense_layers} dense layers")
+            shared = m.num_shared_experts * (m.moe_intermediate_size or m.intermediate_size)
+            split = {"heads": m.num_heads, "experts": m.num_experts, "vocabulary rows": m.vocab_size,
+                     "dense feed-forward width": m.intermediate_size, "shared experts' width": shared}
+            refused = [
+                what for on, what in (
+                    (self.kv_quant != "none", "--kv-quant int8 (no int8 latent cache)"),
+                    (self.spec_tokens > 0, "speculation (--spec-tokens; spec_verify_impl)"),
+                    (self.lora_slots > 0, "LoRA banks (--lora-slots)"),
+                    (self.quant != "none", "--quant int8 (engine/quant.py)"),
+                    (any(n % self.tp for n in split.values()),
+                     f"--tp {self.tp} (it has to divide " + ", ".join(
+                         f"the {n} {what}" for what, n in split.items() if n % self.tp) + ")"),
+                    (bool(self.host_kv_blocks or self.disk_kv_dir or self.fleet_kv_dir),
+                     "KV tiers (--host-kv-blocks, --disk-kv-dir, --fleet-kv-dir)"),
+                ) if on
+            ]
+            if refused:
+                raise ValueError(
+                    f"model {m.name!r} has block='deepseek' (latent pages, group-limited expert layers "
+                    f"shared over the tp mesh), which cannot run with: {'; '.join(refused)}")
         if self.max_model_len % self.block_size:
             self.max_model_len = ((self.max_model_len // self.block_size) + 1) * self.block_size
         if self.max_prefill_tokens % self.block_size:
@@ -1065,8 +1144,8 @@ class EngineArgs:
         so the real cost is 1 byte/elem + 4/head_dim bytes/elem of scale
         overhead (~3% at head_dim=128 → ~1.94x more blocks per byte)."""
         m = self.model
-        if m.block == "longcat":
-            # One pool, 2L cache layers, the row padded to whole lane tiles.
+        if m.block in ("longcat", "deepseek"):
+            # One pool, 2L cache layers (L for "deepseek"), the row padded to whole lane tiles.
             itemsize = 2 if self.dtype == "bfloat16" else 4
             return m.cache_layers * self.block_size * m.latent_page_width * itemsize
         if m.block in ("lfm2", "sala", "dots3"):
@@ -1088,7 +1167,7 @@ class EngineArgs:
         section 6, PR 46)."""
         m = self.model
         itemsize = 2 if self.dtype == "bfloat16" else 4
-        if m.block in ("longcat", "dots3"):
+        if m.block in ("longcat", "dots3", "deepseek"):
             return self.block_size * m.latent_page_width * itemsize
         return 2 * self.block_size * m.kv_size * (1 if self.kv_quant == "int8" else itemsize)
 

@@ -666,8 +666,15 @@ def register_engine_metrics(registry) -> dict:
         registry.counter(
             "moe_experts_touched_total",
             "Held experts with at least one token, summed over the calls "
-            "of the grouped expert product, by program: the expert weight "
-            "reads those calls needed",
+            "of the grouped expert product, by program (and by chip where "
+            "the expert layers span a mesh): the expert weight reads those "
+            "calls needed",
+        ),
+        registry.counter(
+            "moe_token_groups_total",
+            "Routing groups the experts chosen for a token span, summed over "
+            "tokens x layers (a group-limited router: never over topk_group a "
+            "token). Models whose block routes by groups only",
         ),
     )
     for kind in SIDE_KINDS:  # one catalog, whichever block runs
@@ -938,7 +945,10 @@ class TpuEngine:
         if self._routed_layers:
             from dynamo_tpu.engine.longcat import HIST_EXTRA
 
-            shape = (len(self._routed_layers), self.cfg.num_experts + HIST_EXTRA)
+            # A block whose expert layers span a mesh counts each chip's touched
+            # experts, and the routing groups its tokens span, after those.
+            extra = module.hist_extra(self.args.tp) if hasattr(module, "hist_extra") else HIST_EXTRA
+            shape = (len(self._routed_layers), self.cfg.num_experts + extra)
             self.moe_hist = {p: np.zeros(shape, np.int64) for p in ("prefill", "decode")}
         # Prefill rows of a model with conv layers, by where their conv state
         # came from (engine_conv_state_resumes_total); None without such layers.
@@ -1043,9 +1053,14 @@ class TpuEngine:
             feed("moe_assignments_total", int(absent), kind="absent")
             feed("moe_tokens_routed_total", int(routed))
             for program, hist in self.moe_hist.items():
-                touched, calls = hist[:, E + 3:].sum(axis=0)
-                feed("moe_experts_touched_total", int(touched), program=program)
+                touched, calls, *more = hist[:, E + 3:].sum(axis=0)
                 feed("moe_expert_calls_total", int(calls), program=program)
+                if not more:
+                    feed("moe_experts_touched_total", int(touched), program=program)
+                for chip, n in enumerate(more[:-1]):  # engine/deepseek.py:hist_extra
+                    feed("moe_experts_touched_total", int(n), program=program, chip=str(chip))
+            if both.shape[1] > E + 5:
+                feed("moe_token_groups_total", int(both[:, -1].sum()))
             for (l, e), n in np.ndenumerate(both[:, :E]):
                 feed("moe_expert_tokens_total", int(n), layer=str(self._routed_layers[l]),
                      expert=str(off + e))

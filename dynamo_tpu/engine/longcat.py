@@ -45,7 +45,10 @@ the query and the key, which leaves every dot product as it is.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -188,16 +191,55 @@ def _rms(x: jax.Array, w: jax.Array, eps: float, scale: float = 1.0) -> jax.Arra
     return (norm * w.astype(jnp.float32) * scale).astype(x.dtype)
 
 
-def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_inv_freq(cfg: ModelConfig):
+    """The rope lanes' frequencies under static YaRN scaling (``cfg.yarn_factor``),
+    or None where the model has none: ``f_i = theta^(-2i/dr)`` as they are
+    above the correction range, ``f_i / factor`` below it, a linear ramp
+    between. The range: ``dr ln(L0 / (beta 2 pi)) / (2 ln theta)`` at
+    ``beta_fast`` (floor) and ``beta_slow`` (ceil), clipped to the lanes."""
+    if not cfg.yarn_factor:
+        return None
+    dr, theta, L0 = cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn_original_max_position
+
+    def correction(beta: float) -> float:
+        return dr * math.log(L0 / (beta * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction(cfg.yarn_beta_slow)), dr - 1)
+    f = theta ** (-np.arange(dr // 2, dtype=np.float64) * 2 / dr)
+    ramp = np.clip((np.arange(dr // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return jnp.asarray(f / cfg.yarn_factor * ramp + f * (1.0 - ramp), jnp.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 mscale ln(factor) + 1`` (1 unscaled)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_pairs(x: jax.Array, positions: jax.Array, theta: float, inv_freq=None, mscale: float = 1.0) -> jax.Array:
     """Rotary embedding over neighbouring pairs. x [..., heads, hd] with
-    positions [...]; returns the rotated pairs in half-split order."""
+    positions [...]; returns the rotated pairs in half-split order.
+    ``inv_freq`` [hd / 2]: scaled frequencies in the place of ``theta``'s
+    (``yarn_inv_freq``), cos and sin times ``mscale`` where that is not 1."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angles = positions[..., None].astype(jnp.float32) * inv_freq
     cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     xf = x.astype(jnp.float32).reshape(*x.shape[:-1], half, 2)
     x1, x2 = xf[..., 0], xf[..., 1]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def _yarn(cfg: ModelConfig) -> tuple:
+    """What ``_rope_pairs`` takes after the base: nothing for a model without
+    YaRN, else its frequencies and the ratio of its two magnitude corrections."""
+    if not cfg.yarn_factor:
+        return ()
+    return (yarn_inv_freq(cfg), yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+            / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
 
 
 def mla_query_latent(h: jax.Array, sub: dict, cfg: ModelConfig) -> jax.Array:
@@ -218,12 +260,12 @@ def mla_project(h: jax.Array, sub: dict, cfg: ModelConfig, positions: jax.Array,
         q_scale = jnp.asarray((D / cfg.q_lora_rank) ** 0.5 if cfg.mla_scale_q_lora else 1.0, h.dtype)
         q_n = (jnp.dot(c_q, sub["w_qn"]) * q_scale).reshape(*h.shape[:-1], H, dn)
         q_r = (jnp.dot(c_q, sub["w_qr"]) * q_scale).reshape(*h.shape[:-1], H, dr)
-        q_r = _rope_pairs(q_r, positions, cfg.rope_theta)
+        q_r = _rope_pairs(q_r, positions, cfg.rope_theta, *_yarn(cfg))
     with jax.named_scope("mla_kv_write"):
         kv = jnp.dot(h, sub["w_kva"])
         c_kv = _rms(kv[..., :rkv], sub["kv_norm"], cfg.rms_norm_eps,
                     (D / rkv) ** 0.5 if cfg.mla_scale_kv_lora else 1.0)
-        k_r = _rope_pairs(kv[..., None, rkv:], positions, cfg.rope_theta)[..., 0, :]
+        k_r = _rope_pairs(kv[..., None, rkv:], positions, cfg.rope_theta, *_yarn(cfg))[..., 0, :]
         latent = jnp.concatenate([c_kv, k_r], axis=-1)
     return q_n, q_r, latent
 
@@ -331,8 +373,22 @@ def route(xt: jax.Array, lp: dict, cfg: ModelConfig):
     ``scaling * p``, not renormalised. ``cfg.router_scoring == "sigmoid"``
     (engine/lfm2.py) is the second arithmetic: ``s = sigmoid(logits)``,
     selection on ``s + bias`` where ``use_expert_bias``, weights ``s`` there,
-    over their sum where ``norm_topk_prob``, times the scaling."""
+    over their sum where ``norm_topk_prob``, times the scaling.
+    ``cfg.topk_method == "group_limited_greedy"`` (engine/deepseek.py) is the
+    third: softmax scores, the experts in ``n_group`` consecutive groups, a
+    group's score its best expert's, the ``topk_group`` best groups kept and
+    the top-k taken among their experts alone; weights ``scaling * p``."""
     logits = jnp.dot(xt, lp["w_router"], preferred_element_type=jnp.float32)
+    if cfg.topk_method == "group_limited_greedy":
+        probs = jax.nn.softmax(logits, axis=-1)
+        N, E = probs.shape
+        best = jnp.max(probs.reshape(N, cfg.n_group, E // cfg.n_group), axis=-1)
+        _, groups = lax.top_k(best, cfg.topk_group)                          # [N, topk_group]
+        kept = jnp.any(groups[:, :, None] == jnp.arange(cfg.n_group)[None, None, :], axis=1)  # [N, n_group]
+        allowed = jnp.repeat(kept, E // cfg.n_group, axis=-1)
+        _, topi = lax.top_k(jnp.where(allowed, probs, 0.0), cfg.num_experts_per_token)
+        topw = jnp.take_along_axis(probs, topi, axis=-1) * cfg.routed_scaling_factor
+        return topi, topw
     if cfg.router_scoring == "sigmoid":
         s = jax.nn.sigmoid(logits)
         chosen_on = s + lp["router_bias"][None, :] if cfg.use_expert_bias else s
@@ -350,13 +406,17 @@ def route(xt: jax.Array, lp: dict, cfg: ModelConfig):
 def _moe_tokens(xt, valid, lp: dict, cfg: ModelConfig, impl: str):
     """``lp["moe_gate"|"moe_up"|"moe_down"]`` are every layer's stacks
     [L*E, ..] (``stacked_experts``) and ``lp["moe_layer"]`` says which layer's
-    experts are meant (one layer's own stacks with layer 0 do as well)."""
+    experts are meant (one layer's own stacks with layer 0 do as well);
+    ``lp["expert_offset"]``, where given, is the first expert held in the
+    place of the static ``cfg.expert_offset``."""
     N, D = xt.shape
     E, k = cfg.num_experts, cfg.num_experts_per_token
     n_routed = cfg.num_routed_experts
     with jax.named_scope("moe_route"):
         topi, topw = route(xt, lp, cfg)
-        local = topi - cfg.expert_offset
+        # Under a mesh the first expert held is the chip's own (engine/deepseek.py
+        # puts ``lax.axis_index`` times its share there); else the static offset.
+        local = topi - lp.get("expert_offset", cfg.expert_offset)
         live = valid[:, None]
         held = (local >= 0) & (local < E) & (topi < n_routed) & live
         zero = (topi >= n_routed) & live

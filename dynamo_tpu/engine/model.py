@@ -255,7 +255,7 @@ def _dot_q(x: jax.Array, lp: dict, name: str) -> jax.Array:
 
 
 # The blocks there are: this module's own, then those with a module of their name under engine/.
-BLOCK_MODULES = ("llama", "longcat", "lfm2", "sala", "dots3")
+BLOCK_MODULES = ("llama", "longcat", "lfm2", "sala", "dots3", "deepseek")
 
 
 def block_module(cfg: ModelConfig):

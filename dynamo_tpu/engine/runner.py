@@ -172,6 +172,10 @@ class LocalRunner:
         # What the block's prefill programs take beside their operands: the
         # dense block's attention path (the latent block's has one).
         self._prefill_kw: dict = {}
+        # What a block whose programs are ``shard_map``ped takes beside: the mesh;
+        # and where a dispatch's host operands go under it (``_dev``).
+        self._mesh_kw: dict = {}
+        self._operand_sharding = None
         self.prefill_dispatches = 0  # engine_prefill_attn_dispatch_total
         # Packed prefill: the padded tokens a dispatch may hold (pack_limit;
         # 0 under a mesh) and the (rows, T) programs compiled so far, by a
@@ -211,10 +215,15 @@ class LocalRunner:
 
             self.sharding = ModelSharding(build_mesh(tp=self.args.tp, cfg=self.cfg), self.cfg)
         sh = self.sharding
+        if sh is not None and getattr(self._block, "SHARD_MAPPED", False):
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            self._mesh_kw = {"mesh": sh.mesh}
+            self._operand_sharding = NamedSharding(sh.mesh, PartitionSpec())
         # Before anything is allocated: a refused configuration fails fast.
         self.attn_impl, attn_note = self._resolve_attention()
         self.prefill_attn_impl, prefill_note = self._resolve_prefill_attention(attn_note)
-        self._prefill_kw = {"attn_impl": self.prefill_attn_impl}
+        self._prefill_kw = {"attn_impl": self.prefill_attn_impl, **self._mesh_kw}
         dtype = jnp.dtype(self.args.dtype)
         # Seeded params and the KV pool are BORN sharded (jit with
         # out_shardings): a model or pool sized for the mesh never has to
@@ -246,7 +255,7 @@ class LocalRunner:
             )
         else:
             build = functools.partial(
-                self._block.init_params, self.cfg, jax.random.PRNGKey(self._seed), dtype
+                self._block.init_params, self.cfg, jax.random.PRNGKey(self._seed), dtype, **self._mesh_kw
             )
             self.params = build() if sh is None else sh.born_sharded(build)
         # Scale arrays shard over the same kv-head axis as the cache
@@ -272,7 +281,7 @@ class LocalRunner:
                     self.cfg, self.args.lora_slots, self.args.lora_rank
                 ).items()
             }
-        if sh is None:
+        if sh is None or self._mesh_kw:  # a mesh's shares of bytes and of operations are the same share
             weight_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(self.params))
             self.pack_limit_tokens = pack_limit(
                 self.cfg, weight_bytes,
@@ -296,8 +305,9 @@ class LocalRunner:
             # No sharding: a program lowered for a named device commits its
             # results to it, and the jitted programs, compiled for the
             # uncommitted cache they hand each other, would each compile
-            # again when they met the cache a pack left.
-            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+            # again when they met the cache a pack left. Under a mesh every
+            # program's parameters and cache are committed to it already.
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding if self._mesh_kw else None)
 
         params, cache = jax.tree.map(spec, (self.params, self.cache))
         W = self.args.blocks_per_seq  # the wide table only
@@ -348,8 +358,11 @@ class LocalRunner:
             resolve_attn_impl,
         )
 
-        if self.sharding is not None:
-            return "xla", "mesh: pallas_call is opaque to GSPMD partitioning"
+        if self.sharding is not None and not self._mesh_kw:
+            # block='deepseek' shard_maps its programs, so its kernels are
+            # per-device code and stay; the dense block's are GSPMD's to cut.
+            return "xla", (f"mesh: pallas_call is opaque to GSPMD partitioning, and block={self.cfg.block!r} "
+                           f"programs are not shard_mapped")
         impl = resolve_attn_impl(self.args.attn_impl)
         if impl != "pallas":
             return impl, ""
@@ -417,8 +430,9 @@ class LocalRunner:
         )
         # The grouped expert product's path, where the block has one.
         experts = f" experts={self._block.expert_impl()}" if hasattr(self._block, "expert_impl") else ""
-        # A block added after the lines above were pinned names itself.
-        block = getattr(self._block, "START_LINE", "")
+        # A block added after the lines above were pinned names itself, and
+        # one that spans a mesh inside one shard_map says over how many chips.
+        block = getattr(self._block, "START_LINE", "") + (f" tp={a.tp}" if self._mesh_kw else "")
         # A dp rank is pinned to its chips by the spawner; inside its own
         # TPU world every rank's device ids start at 0 again.
         pinned = os.environ.get("TPU_VISIBLE_CHIPS", "all")
@@ -467,6 +481,16 @@ class LocalRunner:
         ``state_slots``), None for a block without such a pool."""
         return {} if state is None else {"state_slots": jnp.asarray(state, jnp.int32)}
 
+    def _dev(self, *operands):
+        """A dispatch's host operands as device arrays, in order. Under a
+        block's own mesh they go to every chip straight from the host in one
+        transfer: an array made on chip 0 first is copied on from there behind
+        whatever chip 0 is running, and the dispatch waits a window for it
+        (44 ms a decode dispatch with the chips idle: PERF.md section 6, PR 52)."""
+        if self._operand_sharding is None:
+            return tuple(jnp.asarray(x) for x in operands)
+        return tuple(jax.device_put([np.asarray(x) for x in operands], self._operand_sharding))
+
     def _lora_operands(self, adapter_slots):
         """(bank, slots-array) for a dispatch, or (None, None) for the
         exact base-variant trace."""
@@ -504,8 +528,7 @@ class LocalRunner:
         if run is None:
             run = functools.partial(self._prefill_batch, self.cfg, **self._prefill_kw)
         logits, self.cache, hist = run(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(tables),
-            jnp.asarray(starts), jnp.asarray(tlens), bank, slots, **self._state_kw(state))
+            self.params, self.cache, *self._dev(toks, tables, starts, tlens), bank, slots, **self._state_kw(state))
         return self._new_ref((logits,), rid, hist, prefill=True)
 
     def prefill_chunk(self, toks, table, pos, tlen, adapter_slot=None,
@@ -516,8 +539,7 @@ class LocalRunner:
         self.prefill_dispatches += 1
         logits, self.cache, hist = self._prefill(
             self.cfg, self.params, self.cache,
-            jnp.asarray(toks), jnp.asarray(table),
-            jnp.int32(pos), jnp.int32(tlen),
+            *self._dev(toks, table, np.int32(pos), np.int32(tlen)),
             bank, slot,
             **self._prefill_kw, **self._state_kw(state),
         )
@@ -550,22 +572,18 @@ class LocalRunner:
             mask[np.asarray(dst, np.int64)] = True
             srcmap[np.asarray(dst, np.int64)] = src
         bank, aslots = self._lora_operands(adapter_slots)
-        toks_d, logps_d, tvals_d, tids_d, self.cache, hist = self._multi_decode(
-            self.cfg, K, mode, int(top_n), self.params, self.cache,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(active),
-            jnp.asarray(temps), jnp.asarray(seeds), jnp.asarray(steps0),
-            jnp.asarray(tks), jnp.asarray(tps),
-            jnp.asarray(freqs), jnp.asarray(press), jnp.asarray(pen),
-            jnp.asarray(mask), jnp.asarray(srcmap), self._last_toks,
-            bank, aslots,
-            attn_impl=self.attn_impl, **self._state_kw(state),
-        )
         if fold_slots is None:
             fold_slots = np.full((B,), self.args.max_num_seqs, np.int32)
-        self._last_toks = _fold_tokens(
-            self._last_toks, toks_d[-1], jnp.asarray(fold_slots, jnp.int32)
+        *operands, mask_d, src_d, fold_d = self._dev(
+            tokens, positions, tables, active, temps, seeds, steps0, tks, tps, freqs, press, pen,
+            mask, srcmap, np.asarray(fold_slots, np.int32))
+        toks_d, logps_d, tvals_d, tids_d, self.cache, hist = self._multi_decode(
+            self.cfg, K, mode, int(top_n), self.params, self.cache,
+            *operands, mask_d, src_d, self._last_toks,
+            bank, aslots,
+            attn_impl=self.attn_impl, **self._mesh_kw, **self._state_kw(state),
         )
+        self._last_toks = _fold_tokens(self._last_toks, toks_d[-1], fold_d)
         return self._new_ref((toks_d, logps_d, tvals_d, tids_d), rid, hist)
 
     def decode_step(self, tokens, positions, tables, active,
@@ -573,10 +591,9 @@ class LocalRunner:
         bank, aslots = self._lora_operands(adapter_slots)
         logits, self.cache, hist = self._decode_step(
             self.cfg, self.params, self.cache,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(active),
+            *self._dev(tokens, positions, tables, active),
             bank, aslots,
-            attn_impl=self.attn_impl, **self._state_kw(state),
+            attn_impl=self.attn_impl, **self._mesh_kw, **self._state_kw(state),
         )
         return self._new_ref((logits,), rid, hist)
 
@@ -647,19 +664,12 @@ class LocalRunner:
         logits = self.stack_rows(srcs)
         mb = None if masks is None else jnp.asarray(masks, jnp.uint32)
         if full:
-            out = sample_full(
-                logits, jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
-                jnp.asarray(pen), jnp.asarray(freqs), jnp.asarray(press),
-                jnp.asarray(seeds), jnp.asarray(steps), mb,
-            )
+            out = sample_full(logits, *self._dev(temps, tks, tps, pen, freqs, press, seeds, steps), mb)
         else:
-            out = sample_simple(logits, jnp.asarray(temps), jnp.asarray(seeds),
-                                jnp.asarray(steps), mb)
+            out = sample_simple(logits, *self._dev(temps, seeds, steps), mb)
         if fold_slots is not None:
             self._ensure_last_toks()
-            self._last_toks = _fold_tokens(
-                self._last_toks, out, jnp.asarray(fold_slots, jnp.int32)
-            )
+            self._last_toks = _fold_tokens(self._last_toks, out, *self._dev(np.asarray(fold_slots, np.int32)))
         top_ref = None
         if top_n > 0:
             vals, ids = top_k_logprobs(logits, int(top_n))

@@ -46,6 +46,19 @@ TP_REP_AXIS = "tp_rep"
 TP_AXES = (TP_KV_AXIS, TP_REP_AXIS)
 
 
+def block_placement(cfg: ModelConfig):
+    """The module of ``cfg.block`` where it brings its own placement
+    (``param_specs(cfg)`` and ``CACHE_SPEC`` over ``TP_AXES``, for programs that
+    are ``shard_map``ped: engine/deepseek.py), else None: the rules below, which
+    are the dense GQA block's."""
+    if cfg.block == "llama":
+        return None
+    from dynamo_tpu.engine.model import block_module
+
+    module = block_module(cfg)
+    return module if hasattr(module, "param_specs") else None
+
+
 def split_tp(tp: int, cfg: ModelConfig) -> tuple[int, int]:
     """tp → (tp_kv, tp_rep): shard kv heads as far as they divide, then
     replicate. Raises if the residue cannot split the query groups."""
@@ -87,6 +100,14 @@ class ModelSharding:
     def __init__(self, mesh: Mesh, cfg: ModelConfig):
         self.mesh = mesh
         self.cfg = cfg
+        # A block with a placement of its own has been held to what its tp
+        # divides by EngineArgs; the checks below are the dense GQA block's.
+        self.block = block_placement(cfg)
+        if self.block is not None:
+            return
+        if cfg.block != "llama":
+            raise ValueError(f"block={cfg.block!r} brings no placement of its own (param_specs): "
+                             f"it runs on one device")
         tp_kv = mesh.shape[TP_KV_AXIS]
         tp_rep = mesh.shape[TP_REP_AXIS]
         tp = tp_kv * tp_rep
@@ -94,13 +115,13 @@ class ModelSharding:
         if cfg.num_experts and cfg.num_experts % ep:
             raise ValueError(f"num_experts={cfg.num_experts} not divisible by ep={ep}")
         if cfg.num_kv_heads % tp_kv:
-            raise ValueError(f"num_kv_heads={cfg.num_kv_heads} not divisible by tp_kv={tp_kv}")
+            raise ValueError(f"block='llama': num_kv_heads={cfg.num_kv_heads} not divisible by tp_kv={tp_kv}")
         if cfg.num_heads % tp:
-            raise ValueError(f"num_heads={cfg.num_heads} not divisible by tp={tp}")
+            raise ValueError(f"block='llama': num_heads={cfg.num_heads} not divisible by tp={tp}")
         if (cfg.num_heads // cfg.num_kv_heads) % tp_rep:
-            raise ValueError(f"query groups not divisible by tp_rep={tp_rep}")
+            raise ValueError(f"block='llama': query groups not divisible by tp_rep={tp_rep}")
         if cfg.intermediate_size % tp:
-            raise ValueError(f"intermediate_size={cfg.intermediate_size} not divisible by tp={tp}")
+            raise ValueError(f"block='llama': intermediate_size={cfg.intermediate_size} not divisible by tp={tp}")
         if cfg.vocab_size % tp:
             # Vocab sharding falls back to replication on awkward sizes.
             self._vocab_spec = None
@@ -114,6 +135,9 @@ class ModelSharding:
         """Pass the params pytree to include shardings for the optional
         int8 ``*_scale`` leaves (scales follow their weight's OUTPUT-dim
         sharding; row-sharded weights have replicated output dims)."""
+        if self.block is not None:
+            return jax.tree.map(lambda spec: NamedSharding(self.mesh, spec), self.block.param_specs(self.cfg),
+                                is_leaf=lambda x: isinstance(x, P))
         rep = self._ns()
         col = self._ns(None, None, TP_AXES)     # [L, D, out] — shard out
         row = self._ns(None, TP_AXES, None)     # [L, in, D] — shard in
@@ -174,6 +198,8 @@ class ModelSharding:
         # grouped exactly as the attention einsums expect; a page's K and V
         # parts split alike. The int8 scales [L, num_blocks, block_size, KVH]
         # are ``rank`` 4: the last axis is the kv-head axis either way.
+        if self.block is not None:
+            return self.block.CACHE_SPEC
         return P(*(None,) * (rank - 1), TP_KV_AXIS)
 
     def batch_spec(self) -> P:

@@ -822,12 +822,12 @@ def test_mechanisms_refuse_the_block_by_name(what):
 def test_the_runner_takes_its_programs_from_the_blocks_module():
     from dynamo_tpu.engine.runner import LocalRunner
 
-    assert M.block_module(CFG) is dots3 and set(M.BLOCK_MODULES) == {"llama", "longcat", "lfm2", "sala", "dots3"}
+    assert M.block_module(CFG) is dots3 and set(M.BLOCK_MODULES) == {"llama", "longcat", "lfm2", "sala", "dots3", "deepseek"}
     runner = LocalRunner(engine_args())
     runner.start()
     line = runner._start_line("")
     assert "block=dots3" in line and "experts=ragged_dot" in line and "attention: prefill=xla decode=xla" in line
-    with pytest.raises(ValueError, match=r"no module runs block='mamba' \(llama, longcat, lfm2, sala, dots3\)"):
+    with pytest.raises(ValueError, match=r"no module runs block='mamba' \(llama, longcat, lfm2, sala, dots3, deepseek\)"):
         M.block_module(dataclasses.replace(CFG, block="mamba"))
 
 

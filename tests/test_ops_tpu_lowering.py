@@ -962,3 +962,114 @@ def test_dots3_programs_compile_for_v5e(v5e, program):
     assert compiled.memory_analysis().temp_size_in_bytes < limit
     assert not _pool_copies(compiled, cache)
     assert not _period_copies(compiled, params)
+
+
+# -- the DeepSeek block: whole layers over a four-chip host, the kernels inside shard_map --
+
+
+def _deepseek():
+    """The benchmark's DeepSeek-V2 configuration at its published widths (whole
+    layers: 160 experts, 128 heads, 102,400 vocabulary rows; 6 of 60 layers)."""
+    from chipbench import model_maps
+    from chipbench.run import engine_args
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "chipbench", "configs", "deepseek-v2-tp4.json")) as f:
+        doc = json.load(f)
+    return model_maps.model_config(doc), engine_args(doc)
+
+
+@pytest.fixture(scope="module")
+def v5e_host():
+    """The four chips of one described v5e host as the mesh ``--tp 4`` builds."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    from dynamo_tpu.parallel.mesh import DP_AXIS, EP_AXIS, TP_KV_AXIS, TP_REP_AXIS
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or one that wants a chip
+        pytest.skip(f"no v5e topology without a chip: {type(e).__name__}: {e}")
+    return Mesh(np.array(topo.devices).reshape(1, 1, 1, 4), (DP_AXIS, EP_AXIS, TP_KV_AXIS, TP_REP_AXIS))
+
+
+# A chip's calls under --tp 4: 32 of the 128 heads against the whole latent row;
+# the grouped product over a chip's 40 experts of 5 layers in one stack, at the
+# decode window's 128 x 6 assignment rows and a 1,024-token part's.
+@pytest.mark.parametrize("kernel", ["latent_decode", "latent_prefill_1x256", "latent_prefill_1x2048", "latent_prefill_4x128",
+                                    "gmm_gate_768", "gmm_down_768", "gmm_gate_6144", "gmm_down_6144"])
+def test_deepseek_kernels_compile_for_v5e_at_a_chips_share(v5e, kernel):
+    from dynamo_tpu.engine import longcat
+    from dynamo_tpu.ops.paged_attention import latent_decode_attention, latent_kernel_unsupported, latent_prefill_attention
+
+    cfg, args = _deepseek()
+    assert latent_kernel_unsupported(cfg, args.block_size) is None and cfg.latent_page_width == 640
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32, bf16 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.bfloat16))
+    pool = bf16(cfg.cache_layers, args.num_kv_blocks, LBS, 640)
+    H = cfg.num_heads // 4
+    if kernel == "latent_decode":
+        lowered = latent_decode_attention.lower(bf16(128, H, 640), pool, i32(), i32(128, 128), i32(128),
+                                                value_dim=512, scale=0.11)
+    elif kernel.startswith("latent_prefill"):
+        rows, T = map(int, kernel.rsplit("_", 1)[1].split("x"))
+        lowered = jax.jit(functools.partial(latent_prefill_attention, scale=0.11)).lower(
+            bf16(rows, H, T, 512), bf16(rows, H, T, 128), pool, i32(), i32(rows, LW), i32(rows), i32(rows))
+    else:
+        _, matrix, rows = kernel.split("_")
+        k, n = (5120, 1536) if matrix == "gate" else (1536, 5120)
+        # K 5120 by N 1536: whole rows of N beside a quarter of K; N 5120: one K tile beside a quarter of N
+        assert longcat.gmm_tiling(k, n, 2) == ((128, 1280, 1536) if matrix == "gate" else (128, 1536, 1280))
+        lowered = longcat.grouped_expert_matmul.lower(bf16(int(rows), k), bf16(200, k, n), i32(200), impl="gmm")
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunk_2048", "prefill_packed_4x128", "init"])
+def test_deepseek_programs_compile_for_four_v5e_chips(v5e_host, program):
+    """The cell's programs at the published widths, ``shard_map``ped over the
+    host's four chips as ``--tp 4`` places them: a chip's arguments are its
+    share of the weights and the whole latent pool (the file's arithmetic:
+    10.73 + 1.38 GB), the kernels are inside (per-device ``tpu_custom_call``s:
+    the latent walk in layer 0 and in the scanned layer, the three grouped
+    products), the chips exchange (``all-reduce``, ``all-gather``), and what a
+    program adds leaves room in 16 GB."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.engine import deepseek
+    from dynamo_tpu.parallel.mesh import ModelSharding
+
+    cfg, args = _deepseek()
+    sh = ModelSharding(v5e_host, cfg)
+    if program == "init":
+        build = functools.partial(deepseek.init_params, cfg, jax.random.PRNGKey(0), jnp.bfloat16, mesh=v5e_host)
+        compiled = jax.jit(build, out_shardings=sh.param_shardings()).lower().compile()
+        mem = compiled.memory_analysis()
+        assert 10.70e9 < mem.output_size_in_bytes < 10.76e9 and mem.temp_size_in_bytes < 1.0e9
+        return
+    S = functools.partial(jax.ShapeDtypeStruct, sharding=NamedSharding(v5e_host, P()))
+    params = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                          jax.eval_shape(lambda: deepseek.init_params(cfg, jax.random.PRNGKey(0))), sh.param_shardings())
+    cache = _abstract(jax.eval_shape(lambda: deepseek.init_kv_cache(cfg, args.num_kv_blocks, LBS)), S)
+    i32, f32 = (lambda *s: S(s, jnp.int32)), (lambda *s: S(s, jnp.float32))
+    W = args.blocks_per_seq
+    if program == "decode_window":
+        B = 128
+        flags = S((B,), jnp.bool_)
+        compiled = deepseek.multi_decode.lower(
+            cfg, args.decode_steps, "greedy", 0, params, cache,
+            i32(B), i32(B), i32(B, W), flags, f32(B), S((B,), jnp.uint32), i32(B),
+            i32(B), f32(B), f32(B), f32(B), i32(B, 1), flags, i32(B), i32(B + 1),
+            None, None, attn_impl="pallas", experts="gmm", mesh=v5e_host).compile()
+        limit = 0.4e9   # 0.20 GB by the compiler's analysis
+    else:
+        rows, t = {"prefill_chunk_2048": (1, 2048), "prefill_packed_4x128": (4, 128)}[program]
+        compiled = deepseek.prefill_batch.lower(
+            cfg, params, cache, i32(rows, t), i32(rows, W), i32(rows), i32(rows),
+            attn_impl="pallas", experts="gmm", mesh=v5e_host).compile()
+        assert "latent_prefill_attention" in compiled.as_text()
+        limit = 0.7e9   # 0.41 GB at T 2,048, 0.16 at 4 x 128
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    assert hlo.count("tpu_custom_call") == 5 and "all-reduce" in hlo and "all-gather" in hlo
+    assert 12.05e9 < mem.argument_size_in_bytes < 12.20e9
+    assert mem.temp_size_in_bytes < limit
